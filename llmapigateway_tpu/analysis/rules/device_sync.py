@@ -5,9 +5,8 @@ device-sync family has quieter spellings this codebase actually uses:
 ``.block_until_ready()`` on an array, ``np.asarray(...)`` / ``np.array(...)``
 of a JAX value (a synchronous device fetch), and ``float()``/``int()`` of a
 device array. Any of these reachable from a serving-layer ``async def``
-stalls every in-flight SSE stream for a device round trip — through a
-remote-TPU tunnel that is tens of milliseconds per call, and through a
-DEAD tunnel it is forever.
+stalls every in-flight SSE stream for as long as the device takes to
+answer.
 
 Some helpers sync *by design* (e.g. the engine's worker-thread fetch
 paths reached via documented loop-side accessors that only touch host

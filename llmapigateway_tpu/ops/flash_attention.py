@@ -37,10 +37,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from ..utils.jax_compat import shard_map
 
 NEG_INF = -1e30
 
@@ -482,9 +481,11 @@ def make_sharded_cache_attention_fn(mesh, block_s: int | None = None,
     inside ``jit`` on mesh-sharded arrays would force XLA to gather the full
     KV cache onto every chip. Attention is embarrassingly parallel over
     batch (``data`` axis) and KV heads (``model`` axis — cache_sharding's
-    layout), so we go manual over exactly the axes the shapes allow:
+    layout), so the specs shard over exactly the axes the shapes allow:
     ``model`` when heads divide, ``data`` when the batch divides (prefill
-    runs a single slot's [1, ...] row, so batch stays automatic there).
+    runs a single slot's [1, ...] row, so its batch stays replicated).
+    The map itself is manual over EVERY mesh axis: the chip's compiler
+    refuses a Mosaic kernel under a partially-manual map.
     Falls back to the unsharded fn when nothing divides (e.g. 1-chip mesh).
     """
     from jax.sharding import PartitionSpec as P
@@ -502,7 +503,7 @@ def make_sharded_cache_attention_fn(mesh, block_s: int | None = None,
         model = "model" if (msize > 1 and KV % msize == 0 and H % msize == 0) \
             else None
         data = "data" if (dsize > 1 and B % dsize == 0) else None
-        return model, data, {ax for ax in (model, data) if ax}
+        return model, data
 
     def _cache_spec(side, data, model):
         """Per-leaf spec: an int8 {"q","s"} cache leaf carries a 4-D
@@ -515,8 +516,8 @@ def make_sharded_cache_attention_fn(mesh, block_s: int | None = None,
         return val
 
     def attention_fn(q, k_new, v_new, layer_k, layer_v, lengths, active=None):
-        model, data, manual = _axes(q, layer_k)
-        if not manual:
+        model, data = _axes(q, layer_k)
+        if not (model or data):
             return base(q, k_new, v_new, layer_k, layer_v, lengths, active)
 
         head = P(data, None, model, None)       # q / k_new / v_new
@@ -532,12 +533,12 @@ def make_sharded_cache_attention_fn(mesh, block_s: int | None = None,
             mesh=mesh,
             in_specs=(head, head, head, cache, cache, slot, slot),
             out_specs=(P(data, None, model), cache, cache),
-            axis_names=manual, check_vma=False)
+            check_vma=False)
         return f(q, k_new, v_new, layer_k, layer_v, lengths, act)
 
     def decode(q, k_new, v_new, layer_k, layer_v, lengths, active=None):
-        model, data, manual = _axes(q, layer_k)
-        if not manual:
+        model, data = _axes(q, layer_k)
+        if not (model or data):
             return base.decode(q, k_new, v_new, layer_k, layer_v, lengths,
                                active)
         head = P(data, None, model, None)
@@ -550,8 +551,7 @@ def make_sharded_cache_attention_fn(mesh, block_s: int | None = None,
                 base.decode(q_, kn, vn, lk, lv, ln, ac),
             mesh=mesh,
             in_specs=(head, head, head, cache, cache, slot, slot),
-            out_specs=P(data, None, model),
-            axis_names=manual, check_vma=False)
+            out_specs=P(data, None, model), check_vma=False)
         return f(q, k_new, v_new, layer_k, layer_v, lengths, act)
 
     from ..models.llama import insert_kv_stacked

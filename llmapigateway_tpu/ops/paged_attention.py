@@ -607,11 +607,14 @@ def make_paged_attention_fn(page_table: jax.Array, max_seq: int,
     the reference path gathers densely and ignores it) — requires the
     engine's superpage-packed allocator behind the table.
 
-    With a multi-device ``mesh`` the kernels run under ``shard_map`` manual
-    over the ``model`` axis — pages are sharded on their KV-head dim, the
-    page table is replicated (it indexes the pool's unsharded page dim), and
-    the insert scatter stays in XLA/GSPMD. The pool has no batch dim, so
-    there is nothing to go manual over on ``data``.
+    With a multi-device ``mesh`` the kernels run under ``shard_map`` —
+    pages are sharded on their KV-head dim over ``model``, the page table
+    is replicated (it indexes the pool's unsharded page dim), and the
+    insert scatter stays in XLA/GSPMD. The map is manual over EVERY mesh
+    axis, not just ``model``: the chip's compiler refuses a Mosaic kernel
+    under a partially-manual map, even when the other axes have size 1
+    (interpret mode never reaches that check). The pool has no batch dim,
+    so the operands are simply replicated along the other axes.
     """
     from jax.sharding import PartitionSpec as P
 
@@ -661,8 +664,7 @@ def make_paged_attention_fn(page_table: jax.Array, max_seq: int,
                 mesh=mesh,
                 in_specs=(P(None, None, "model", None), pool, pool,
                           P(None, None), P(None)),
-                out_specs=P(None, None, "model"),
-                axis_names={"model"}, check_vma=False)
+                out_specs=P(None, None, "model"), check_vma=False)
             out = f(q, layer_k, layer_v, page_table, lengths)
         else:
             out = paged_prefill_attention(
@@ -701,8 +703,7 @@ def make_paged_attention_fn(page_table: jax.Array, max_seq: int,
                 in_specs=(P(None, "model", None), P(None, "model", None),
                           P(None, "model", None), pool, pool,
                           P(None, None), P(None)),
-                out_specs=P(None, "model"),
-                axis_names={"model"}, check_vma=False)
+                out_specs=P(None, "model"), check_vma=False)
             out = f(q[:, 0], k_new[:, 0], v_new[:, 0], layer_k, layer_v,
                     page_table, n_stale)
         else:

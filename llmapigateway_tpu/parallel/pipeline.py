@@ -38,9 +38,8 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from ..utils.jax_compat import SHARD_MAP_PARTIAL_AUTO_OK, shard_map
 
 from ..models import llama
 from ..models.config import ModelConfig
@@ -287,15 +286,6 @@ def pipelined_forward(params: dict, config: ModelConfig, tokens: jax.Array,
     """
     B, T = tokens.shape
     n_stages = mesh.shape.get("pipe", 1)
-    if (not SHARD_MAP_PARTIAL_AUTO_OK and n_stages > 1
-            and any(n > 1 for ax, n in mesh.shape.items() if ax != "pipe")):
-        # Refuse BEFORE compile: the legacy partial-auto shard_map
-        # miscompiles this schedule combined with a real second mesh axis
-        # (XLA abort, which would take the whole process down).
-        raise NotImplementedError(
-            "pipeline parallelism combined with another sharded mesh axis "
-            "needs jax.shard_map's partial-auto mode (jax >= 0.5); this "
-            "jax build only supports a pure-pipe mesh")
     stage_size(config.n_layers, n_stages)     # validate divisibility
     M = n_microbatches
     if B % M != 0:

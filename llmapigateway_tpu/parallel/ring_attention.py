@@ -24,9 +24,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from ..utils.jax_compat import axis_size, shard_map
 
 NEG_INF = -1e30
 
@@ -69,7 +68,7 @@ def _block_attn_accum(q, k, v, q_off, k_off, m, l, acc, *, causal: bool):
 def _ring_body(q, k, v, *, axis: str, causal: bool):
     """Per-shard ring loop (runs inside shard_map, manual over `axis`)."""
     B, Tl, H, Dh = q.shape
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     q_off = idx * Tl
 

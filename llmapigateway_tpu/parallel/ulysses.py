@@ -19,9 +19,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from ..utils.jax_compat import shard_map
 
 NEG_INF = -1e30
 

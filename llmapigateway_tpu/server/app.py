@@ -281,12 +281,16 @@ def _maybe_run_follower(settings: Settings) -> bool:
 
 
 def _default_local_factory():
-    """Lazily import the TPU engine provider factory (keeps JAX optional for
-    proxy-only deployments)."""
+    """Lazily import the TPU engine provider factory. JAX stays optional
+    for proxy-only deployments: where it is not installed, ``type: local``
+    providers are rejected. Any OTHER failure importing the engine is a
+    broken install and propagates — a warning here would turn it into
+    "every local request quietly goes to the fallback chain"."""
     try:
-        from ..providers.local import make_local_provider
-        return make_local_provider
-    except Exception:
-        logger.warning("local TPU engine unavailable; type=local providers "
-                       "will be rejected", exc_info=True)
+        import jax  # noqa: F401
+    except ModuleNotFoundError:
+        logger.warning("JAX is not installed; type=local providers will "
+                       "be rejected")
         return None
+    from ..providers.local import make_local_provider
+    return make_local_provider

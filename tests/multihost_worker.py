@@ -23,7 +23,6 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 
 import jax  # noqa: E402
 
-jax.config.update("jax_platforms", "cpu")
 jax.distributed.initialize(coordinator_address=f"localhost:{PORT}",
                            num_processes=N_PROC, process_id=PROC_ID)
 

@@ -46,7 +46,6 @@ def _free_port() -> str:
 
 def worker(proc_id: int, n_proc: int, port: str) -> None:
     import jax
-    jax.config.update("jax_platforms", "cpu")
     if n_proc > 1:
         jax.distributed.initialize(
             coordinator_address=f"localhost:{port}",
