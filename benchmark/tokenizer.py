@@ -1,0 +1,71 @@
+"""A tokenizer for random weights: one character for every id of the
+vocabulary, and back.
+
+The program's ``ByteTokenizer`` decodes only ids below 256. Random weights
+pick an id uniformly from the whole vocabulary, so under it 99% of the
+generated tokens decode to nothing, no content frame is ever written, and
+a client can time neither the first token nor the gaps. A served model's
+tokenizer decodes every id to text; this one does the same with the least
+machinery: id ``i`` is the character ``chr(i)`` where that is printable
+ASCII or a newline, else ``chr(0x4000 + i)``. One character is one token
+in both directions, so the client counts the tokens of a frame by the
+length of its text, and a prompt of an exact number of tokens is a string
+of that many characters.
+"""
+from __future__ import annotations
+
+from typing import Sequence
+
+_SHIFT = 0x4000
+
+
+def _plain(i: int) -> bool:
+    return 32 <= i < 127 or i == 10
+
+
+class CharTokenizer:
+    """Implements the program's ``TokenizerLike``. Special ids follow the
+    Mistral vocabulary: 1 begins a sequence, 2 ends one."""
+
+    def __init__(self, vocab_size: int):
+        if not 128 <= vocab_size <= 0xD800 - _SHIFT:
+            raise ValueError(f"vocabulary of {vocab_size} ids does not fit")
+        self.vocab_size = vocab_size
+        self.bos_id: int | None = 1
+        self.eos_ids = {2}
+        self.pad_id = 0
+
+    def encode(self, text: str) -> list[int]:
+        out = []
+        for ch in text:
+            o = ord(ch)
+            i = o - _SHIFT if o >= _SHIFT else o
+            if not 0 <= i < self.vocab_size:
+                raise ValueError(f"character {ch!r} is outside the vocabulary")
+            out.append(i)
+        return out
+
+    def text_of(self, ids: Sequence[int]) -> str:
+        return "".join(chr(i) if _plain(i) else chr(_SHIFT + i)
+                       for i in map(int, ids))
+
+    def decode(self, ids: Sequence[int]) -> str:
+        return self.text_of(i for i in ids if 0 <= i < self.vocab_size)
+
+    def decode_bytes(self, ids: Sequence[int]) -> bytes:
+        return self.decode(ids).encode("utf-8")
+
+    def apply_chat_template(self, messages: list[dict],
+                            add_generation_prompt: bool = True) -> str:
+        # The ByteTokenizer's template, so prompts cost what they cost there.
+        parts = [f"<|{m.get('role', 'user')}|>\n{m.get('content', '')}\n"
+                 for m in messages]
+        if add_generation_prompt:
+            parts.append("<|assistant|>\n")
+        return "".join(parts)
+
+    def template_overhead(self) -> int:
+        """Tokens a one-message prompt costs beyond its content."""
+        bos = 1 if self.bos_id is not None else 0
+        return bos + len(self.apply_chat_template(
+            [{"role": "user", "content": ""}]))
