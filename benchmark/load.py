@@ -13,21 +13,37 @@ The window opens at the same point of the trace every time:
   least 4 steps of some 30 ms), so a burst's worth of tokens — 1% of a
   long-document window — does not fall in or out of the window by a race.
   The edge is computed from the finish's own stamp, not from the moment
-  the harness noticed it.
+  the harness noticed it, also where the loop noticed it late (it parses
+  the next request's body, some 0.2 s for a long document): frames are
+  stamped as they arrive and counted afterwards, so the window opens at
+  the stamp although the harness acts on it later.
 * open loop — exactly ``lead_in_s`` seconds into the fixed schedule.
 
-It closes ``seconds`` later. Load goes on unchanged after the close until
-every request that was due or sent inside the window has its first token
-(bounded by ``DRAIN_LIMIT_S``), so that their time to first token is
-measured under the same load; then whatever is still in flight is hung
-up on.
+At that moment, and not before, ``on_open`` is called, a plain function:
+the harness takes its counter snapshot in it and, traced, starts the
+profiler as a task, which it returns. The player waits for what
+``on_open`` returns only when the run is over, so the profiler's start-up
+neither moves the edge nor lies before it: in the open loop too, counters
+and trace begin where the window does, not where the lead-in does.
+
+It closes ``seconds`` later. The engine streams tokens in bursts (28
+every 0.3 s under long documents, 1% of a 40 s window), and no guard can
+keep the close clear of them; ``metrics.tokens_in_window`` gives a burst
+that straddles an edge to the window by the share of its interval inside.
+
+Load goes on unchanged after the close until every request that was due
+or sent inside the window has its first token, so that their time to
+first token is measured under the same load, and until a frame has
+arrived after the close, so that the burst astride it has an interval to
+be shared out by (both bounded by ``DRAIN_LIMIT_S``); then whatever is
+still in flight is hung up on.
 """
 from __future__ import annotations
 
 import asyncio
 import dataclasses
 import time
-from typing import Awaitable, Callable
+from typing import Any, Awaitable, Callable
 
 from .gateway import Gateway
 from .metrics import RequestLog
@@ -45,12 +61,20 @@ class Played:
     t_close: float
     lateness_ms: list[float]       # open loop: send time minus due time
     drained: bool                  # every in-window request got a token
+    opened: Any = None             # what ``on_open``'s awaitable gave
+    # The longest the event loop overslept one of the player's own naps
+    # inside the window: seconds late, seconds from the open, and the CPU
+    # seconds the whole process used over that nap (next to none: it was
+    # not running at all; the nap's length or more: a thread was computing
+    # and kept the others out). Tells a stalled process from a slow server
+    # (PERF.md, the stalled runs).
+    stall: tuple[float, float, float] = (0.0, 0.0, 0.0)
 
 
 class Player:
     def __init__(self, gateway: Gateway, traffic: Traffic, seed: int,
                  seconds: float,
-                 on_open: Callable[[], Awaitable[None]] | None = None,
+                 on_open: Callable[[], Awaitable[None] | None] | None = None,
                  on_close: Callable[[], Awaitable[None]] | None = None):
         self.g = gateway
         self.traffic = traffic
@@ -66,6 +90,7 @@ class Player:
         self._stop = asyncio.Event()
         self.t_open: float | None = None
         self.t_close: float | None = None
+        self.stall = (0.0, 0.0, 0.0)
 
     # -- the trace ----------------------------------------------------------
     def _entry(self) -> Entry:
@@ -132,32 +157,41 @@ class Player:
         else:
             self._tasks.add(asyncio.ensure_future(self._schedule(t_start)))
             edge = t_start + t.lead_in_s
-        if self.on_open:
-            await self.on_open()
-        # The planned edge, unless opening (starting a trace) overran it.
-        self.t_open = max(edge, time.monotonic())
+        # Closed loop: the stamp decides, however late the loop noticed it.
+        self.t_open = edge
         await asyncio.sleep(max(0.0, self.t_open - time.monotonic()))
+        opening = self.on_open() if self.on_open else None
         self.t_close = self.t_open + self.seconds
         while time.monotonic() < self.t_close:
             await self._check_tasks()
-            await asyncio.sleep(min(0.05, max(
-                0.0, self.t_close - time.monotonic())))
+            nap = min(0.05, max(0.0, self.t_close - time.monotonic()))
+            due, cpu = time.monotonic() + nap, time.process_time()
+            await asyncio.sleep(nap)
+            self.stall = max(self.stall, (
+                time.monotonic() - due, due - self.t_open,
+                time.process_time() - cpu))
         if self.on_close:
             await self.on_close()
         drained = await self._drain()
         await self._hang_up()
+        opened = await opening if opening is not None else None
         return Played(logs=self.logs, t_start=t_start, t_open=self.t_open,
                       t_close=self.t_close, lateness_ms=self.lateness_ms,
-                      drained=drained)
+                      drained=drained, opened=opened, stall=self.stall)
 
     def _waiting_for_first(self) -> list[RequestLog]:
         return [r for r in self.logs
                 if self.t_open <= r.t_ref < self.t_close
                 and r.t_end is None and not r.frames]
 
+    def _burst_astride_close_landed(self) -> bool:
+        return any(r.frames and r.frames[-1][0] >= self.t_close
+                   for r in self.logs)
+
     async def _drain(self) -> bool:
         limit = time.monotonic() + DRAIN_LIMIT_S
-        while self._waiting_for_first():
+        while (self._waiting_for_first()
+               or not self._burst_astride_close_landed()):
             if time.monotonic() > limit:
                 return False
             await self._check_tasks()

@@ -23,8 +23,8 @@ class Measured:
     logs: list[RequestLog]
     t_open: float
     t_close: float
-    trace: Reduced | None            # the profiler's trace, reduced
-    t_trace: tuple[float, float]     # traced span, host monotonic clock
+    trace: Reduced | None            # the profiler's trace, cut to its markers
+    t_trace: tuple[float, float]     # host clock read inside the two markers
     flight: list[dict[str, Any]]     # flight-ring snapshot (whole run)
     counters_open: dict[str, Any]    # engine stats() at window open
     counters_close: dict[str, Any]   # ... and at close

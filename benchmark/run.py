@@ -12,6 +12,20 @@ result: ``correct``, ``attempted``, ``failed``, ``metrics``, ``device``
 and, traced, ``breakdown``. With ``--trace 0`` the metrics are the cell's
 end-to-end metrics, with ``--trace 1`` its per-layer metrics.
 
+The window opens first. The open counter snapshot (``stats()``) is taken
+where it opens and the close snapshot where it closes, in both loops, so
+a counter's delta covers the window and nothing else. A traced run then
+profiles ``TRACE_SECONDS`` from a moment just inside the window
+(``device.trace_offset_s`` after its opening: the profiler's start-up).
+One worker thread makes both profiler calls and writes a marker span
+after the first and before the second (``record_trace``); the reduction
+takes the traced span from the markers, on the profiler's own clock, and
+clips every event to it (``xplane.reduce``), and the host-clock stamps
+read inside the markers bound the client's frames that the kernel
+roofline counts. A trace in which the markers are not found gives no
+``device_trace`` metric and no ``breakdown``; the ``phase: "trace"`` line
+says which it was.
+
 Without the chips the cell asks for, the run fails: exit code 1 and no
 result line. A number from a CPU is never printed under a metric's name.
 """
@@ -34,7 +48,7 @@ from typing import Any          # noqa: E402
 from . import metrics, spec     # noqa: E402
 
 OUT_DIR = "bench_out"           # inside the checkout, git-ignored
-TRACE_SECONDS = 4.0             # of the window, from its opening
+TRACE_SECONDS = 4.0             # of the window, from just inside it
 REHEARSAL_PREFIX = "cpu_rehearsal."
 
 
@@ -127,6 +141,66 @@ def warm_programs(engine, plan: dict[str, list[int]]) -> None:
     engine._d_dirty = True
 
 
+class JaxEvents:
+    """JAX's own duration events (tracing a function, lowering it, compiling
+    it, reading the compilation cache), stamped as they end. The ``window``
+    line lists those inside the window: the program's compile counter does
+    not see a retrace whose executable came from the persistent cache, and a
+    first call that traces and lowers a 32-layer program holds up every
+    Python thread for as long as it takes."""
+
+    def __init__(self) -> None:
+        self.events: list[tuple[float, str, str, float]] = []
+
+    def __call__(self, event: str, duration: float, **fields: Any) -> None:
+        if event.startswith(("/jax/core/compile", "/jax/compilation_cache")):
+            self.events.append((time.monotonic(), event.rsplit("/", 1)[-1],
+                                str(fields.get("fun_name", "")), duration))
+
+    def inside(self, t0: float, t1: float, n: int = 8) -> dict[str, Any]:
+        """How many ended in ``[t0, t1)``, their seconds in all, and the
+        ``n`` longest as ``[seconds from t0, event, function, seconds]``."""
+        evs = [e for e in self.events if t0 <= e[0] < t1]
+        top = sorted(evs, key=lambda e: -e[3])[:n]
+        return {"count": len(evs), "seconds": round(sum(e[3] for e in evs), 3),
+                "longest": [[round(t - t0, 2), ev, fn[:40], round(d, 3)]
+                            for t, ev, fn, d in top]}
+
+
+def record_trace(trace_dir: Path, seconds: float) -> tuple[float, float]:
+    """Profile ``seconds``, all from ONE thread (a worker's, not the event
+    loop's): start the profiler, then open a marker span and read the host
+    clock inside it; sleep the seconds out from that stamp; open the other
+    marker, read the clock, stop the profiler. Nothing the event loop does
+    can come between a profiler call and its stamp."""
+    import jax
+    from .xplane import MARK_CLOSE, MARK_OPEN
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+    with jax.profiler.TraceAnnotation(MARK_OPEN):
+        t0 = time.monotonic()
+    time.sleep(max(0.0, t0 + seconds - time.monotonic()))
+    with jax.profiler.TraceAnnotation(MARK_CLOSE):
+        t1 = time.monotonic()
+    jax.profiler.stop_trace()
+    return t0, t1
+
+
+def trace_facts(reduced) -> tuple[dict[str, Any], dict[str, Any]]:
+    """What the result line takes straight from the reduced trace: the
+    ``device`` block's ``busy_s`` and ``window_s``, and the ``breakdown``.
+    Only from a span the markers bound: a busy time over a window from
+    another clock has been more than the window (ledger, PR 24)."""
+    if not (reduced.marked and reduced.devices):
+        return {}, {}
+    return ({"busy_s": reduced.busy_ns() / 1e9,
+             "window_s": reduced.window_ns / 1e9},
+            {"breakdown": {"device_ops": reduced.top_ops(10),
+                           "idle_gaps": reduced.idle_gaps(10)}})
+
+
 def write_records(path: Path, logs: list[metrics.RequestLog],
                   t_open: float) -> None:
     """One JSON line per request, times in seconds from window open."""
@@ -204,33 +278,23 @@ async def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
         if trace_dir.exists():
             shutil.rmtree(trace_dir)
         marks: dict[str, Any] = {}
-        stop_task: list[asyncio.Task] = []
 
-        async def stop_trace_later() -> None:
-            await asyncio.sleep(TRACE_SECONDS)
-            marks["t_trace1"] = time.monotonic()
-            await asyncio.to_thread(jax.profiler.stop_trace)
-
-        async def on_open() -> None:
+        def on_open() -> asyncio.Future | None:
             marks["stats_open"] = eng.stats()
+            marks["t_stats_open"] = time.monotonic()
             if trace:
-                opts = jax.profiler.ProfileOptions()
-                opts.python_tracer_level = 0
-                opts.host_tracer_level = 2
-                await asyncio.to_thread(
-                    jax.profiler.start_trace, str(trace_dir),
-                    profiler_options=opts)
-                marks["t_trace0"] = time.monotonic()
-                stop_task.append(asyncio.ensure_future(stop_trace_later()))
+                return asyncio.ensure_future(asyncio.to_thread(
+                    record_trace, trace_dir, TRACE_SECONDS))
 
         async def on_close() -> None:
             marks["stats_close"] = eng.stats()
+            marks["t_stats_close"] = time.monotonic()
 
         t_lead = time.monotonic()
         player = Player(g, cell.traffic, seed, seconds, on_open, on_close)
+        jax_events = JaxEvents()
+        jax.monitoring.register_event_duration_secs_listener(jax_events)
         played = await player.play()
-        for task in stop_task:
-            await task
         setup["lead_in_s"] = played.t_open - t_lead
         setup_s = played.t_open - _T0
         flight = eng.flight.snapshot() if eng.flight is not None else []
@@ -261,6 +325,12 @@ async def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
          early_stops=sum(1 for r in logs if r.finished
                          and r.finish_reason != "length"),
          flight_evicted=evicted, compiles_in_window=compiles,
+         counters_span_s=round(
+             marks["t_stats_close"] - marks["t_stats_open"], 4),
+         loop_stall_ms=round(1e3 * played.stall[0], 1),
+         loop_stall_at_s=round(played.stall[1], 2),
+         loop_stall_cpu_s=round(played.stall[2], 3),
+         jax_events=jax_events.inside(played.t_open, played.t_close),
          usage_rows=rows, problems=problems[:5])
     if played.lateness_ms:
         late = sorted(played.lateness_ms)
@@ -280,19 +350,28 @@ async def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
             m["name"]: {"value": values[m["name"]], "unit": m["unit"]}
             for m in cell.end_to_end if m["name"] in values}
     else:
-        reduced = None
-        if "t_trace0" in marks:
-            window_ns = int(1e9 * (marks["t_trace1"] - marks["t_trace0"]))
-            recorded = await asyncio.to_thread(xplane.load, str(trace_dir))
-            if describe_to is not None:
-                describe_to.write_text(json.dumps(
-                    xplane.describe(recorded, 400), indent=1))
-            reduced = await asyncio.to_thread(
-                xplane.reduce, recorded, window_ns)
+        t_trace = played.opened
+        recorded = await asyncio.to_thread(xplane.load, str(trace_dir))
+        if describe_to is not None:
+            describe_to.write_text(json.dumps(
+                xplane.describe(recorded, 400), indent=1))
+        reduced = await asyncio.to_thread(xplane.reduce, recorded)
+        facts, breakdown = trace_facts(reduced)
+        dev.update(facts, trace_offset_s=t_trace[0] - played.t_open)
+        result.update(breakdown)
+        emit("trace", marked=reduced.marked,
+             offset_s=round(dev["trace_offset_s"], 4),
+             host_clock_s=round(t_trace[1] - t_trace[0], 6),
+             profiler_clock_s=reduced.window_ns / 1e9,
+             devices=len(reduced.devices), **facts,
+             **({"gaps_named_s": sum(e - s for s, e in reduced.gaps()) / 1e9}
+                if facts else {}),
+             **({} if reduced.marked else {
+                 "problem": "the marker spans are not in the trace: no "
+                            "device_trace metric, no breakdown"}))
         measured = Measured(
             logs=logs, t_open=played.t_open, t_close=played.t_close,
-            trace=reduced,
-            t_trace=(marks.get("t_trace0", 0.0), marks.get("t_trace1", 0.0)),
+            trace=reduced if reduced.marked else None, t_trace=t_trace,
             flight=flight, counters_open=marks["stats_open"],
             counters_close=marks["stats_close"], slots=eng.B, shape=shape,
             peaks=({} if rehearsal else peaks_for(device["kind"])),
@@ -303,11 +382,6 @@ async def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
             if v is not None:
                 out_metrics[lm.name] = {"value": v, "unit": lm.unit}
         result["metrics"] = out_metrics
-        if reduced is not None and reduced.devices:
-            dev["busy_s"] = reduced.busy_ns() / 1e9
-            dev["window_s"] = reduced.window_ns / 1e9
-            result["breakdown"] = {"device_ops": reduced.top_ops(10),
-                                   "idle_gaps": reduced.idle_gaps(10)}
         shutil.rmtree(trace_dir, ignore_errors=True)
     result["device"] = dev
     if rehearsal:

@@ -24,15 +24,20 @@ def _plain(i: int) -> bool:
 
 
 class CharTokenizer:
-    """Implements the program's ``TokenizerLike``. Special ids follow the
-    Mistral vocabulary: 1 begins a sequence, 2 ends one."""
+    """Implements the program's ``TokenizerLike``. Id 1 begins a sequence,
+    as in the Mistral vocabulary. NO id ends one: random weights emit id 2
+    once in 32000 tokens, by the accident of a seed's prompts and not
+    because an answer is over, and in a closed loop one answer cut short
+    re-phases every request behind it (a long-document run with one such
+    stop read ``ttft_p50_ms`` 3910 for 3248, PERF.md). Every answer is as
+    long as its ``max_tokens``: the same work from every seed."""
 
     def __init__(self, vocab_size: int):
         if not 128 <= vocab_size <= 0xD800 - _SHIFT:
             raise ValueError(f"vocabulary of {vocab_size} ids does not fit")
         self.vocab_size = vocab_size
         self.bos_id: int | None = 1
-        self.eos_ids = {2}
+        self.eos_ids: set[int] = set()
         self.pad_id = 0
 
     def encode(self, text: str) -> list[int]:

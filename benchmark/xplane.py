@@ -29,6 +29,26 @@ How a device plane is read:
 
 An op's key in the breakdown is ``<program>/<scope or ->:<category>``,
 for instance ``prefill_step/attention.paged_prefill:custom-call``.
+
+The traced span. The harness writes two marker spans into the trace, one
+right after the profiler starts (``MARK_OPEN``) and one right before it
+stops (``MARK_CLOSE``). They land on the host plane, on the line of the
+thread that made the profiler calls. The traced span is ``[open.start,
+close.start]`` ON THE PROFILER'S CLOCK, the one the device events are on;
+``window_ns`` is its length, and every device op, program execution and
+host span is clipped to it before anything is summed: an op that straddles
+an edge contributes the part inside, and a program execution counts as the
+part of itself that lies inside. So busy time cannot exceed the window,
+whatever the host's threads were doing around the profiler calls. A trace
+without both markers falls back to the span from the first to the last
+device event (``Reduced.marked`` is false; the harness then prints no
+device metric from it).
+
+An idle gap's key is ``host.<program span>/<runtime span>``: the innermost
+span that covers the gap's middle among those the PROGRAM writes
+(``PROGRAM_SPANS``), or ``-``; then the innermost other span, or ``other``.
+The time before the first op and after the last op of the span is idle and
+is named like any other gap.
 """
 from __future__ import annotations
 
@@ -48,6 +68,12 @@ _COLLECTIVE = re.compile(
 _CONTROL = re.compile(r"^(while|call|conditional)([.\d]*)$")
 _OPS_LINE, _MODULES_LINE = "XLA Ops", "XLA Modules"
 MIN_GAP_NS = 20_000
+MARK_OPEN, MARK_CLOSE = "bench.trace_open", "bench.trace_close"
+# Prefixes of the host spans the program writes around its own phases
+# (``obs/device.py`` ``phase``); everything else on the host plane is the
+# runtime's. The benchmark's own ``bench.`` spans never name a gap.
+PROGRAM_SPANS = ("prefill", "decode", "spec.", "sched.", "engine.")
+_BENCH_SPANS = "bench."
 
 
 @dataclasses.dataclass
@@ -70,13 +96,19 @@ class DeviceTrace:
     name: str
     ops: list[Op]
     modules: list[tuple[int, int, str]]        # (start, end, program)
+    module_parts: list[float]                  # share of each inside the span
 
 
 @dataclasses.dataclass
 class Reduced:
-    window_ns: int
     devices: list[DeviceTrace]
     host: list[tuple[int, int, str]]           # (start, end, name)
+    span: tuple[int, int]                      # traced span, profiler's clock
+    marked: bool                               # span taken from the markers
+
+    @property
+    def window_ns(self) -> int:
+        return self.span[1] - self.span[0]
 
     # -- busy and idle ------------------------------------------------------
     def busy_ns(self) -> float:
@@ -104,11 +136,14 @@ class Reduced:
                 total += op.self_ns
         return total / max(1, len(self.devices))
 
-    def program_events(self, program: str) -> int:
-        """Executions of ``program`` on the first chip."""
+    def program_events(self, program: str) -> float:
+        """Executions of ``program`` on the first chip; one that straddles
+        an edge of the span counts as the part of it that lies inside."""
         if not self.devices:
             return 0
-        return sum(1 for _, _, p in self.devices[0].modules if p == program)
+        d = self.devices[0]
+        return sum(part for (_, _, p), part in zip(d.modules, d.module_parts)
+                   if p == program)
 
     def exposed_collective_ns(self) -> float:
         """Collective time during which no other op ran on that chip."""
@@ -134,25 +169,37 @@ class Reduced:
 
     def idle_gaps(self, n: int = 10) -> list[list[Any]]:
         """Idle time of the first chip by what the host was doing at the
-        middle of each gap: the innermost host span that covers it."""
+        middle of each gap (``_host_at``), the span's two edges included."""
         if not self.devices:
             return []
-        busy = _merge(_intervals(self.devices[0].ops))
         sums: dict[str, int] = defaultdict(int)
-        prev_end = None
-        for s, e in busy:
-            if prev_end is not None and s - prev_end >= MIN_GAP_NS:
-                sums[self._host_at((prev_end + s) // 2)] += s - prev_end
-            prev_end = e
+        for s, e in self.gaps():
+            sums[self._host_at((s + e) // 2)] += e - s
         rows = sorted(sums.items(), key=lambda kv: -kv[1])[:n]
         return [[name, ns / 1e9] for name, ns in rows]
 
+    def gaps(self) -> list[tuple[int, int]]:
+        """The first chip's idle stretches of ``MIN_GAP_NS`` or more, from
+        the span's opening to its close."""
+        prev_end, out = self.span[0], []
+        for s, e in _merge(_intervals(self.devices[0].ops)) + [
+                (self.span[1], self.span[1])]:
+            if s - prev_end >= MIN_GAP_NS:
+                out.append((prev_end, s))
+            prev_end = max(prev_end, e)
+        return out
+
     def _host_at(self, t: int) -> str:
-        best = None
+        """``host.<program span>/<runtime span>`` over the moment ``t``:
+        of each kind the innermost (shortest) span that covers it."""
+        best: dict[bool, tuple[int, str]] = {}
         for s, e, name in self.host:
-            if s <= t < e and (best is None or e - s < best[0]):
-                best = (e - s, name)
-        return f"host.{best[1]}" if best else "host.other"
+            if s <= t < e and not name.startswith(_BENCH_SPANS):
+                kind = name.startswith(PROGRAM_SPANS)
+                if kind not in best or e - s < best[kind][0]:
+                    best[kind] = (e - s, name)
+        return "host.%s/%s" % (best.get(True, (0, "-"))[1],
+                               best.get(False, (0, "other"))[1])
 
 
 @dataclasses.dataclass
@@ -266,33 +313,41 @@ def program_name(module_event: str) -> str:
     return name[4:] if name.startswith("jit_") else name
 
 
-def reduce(trace: Trace, window_ns: int | None = None) -> Reduced:
-    """``window_ns``: the traced window's length by the host clock;
-    without it, the span from the first to the last device event."""
-    devices, host = [], []
-    lo, hi = None, None
+def reduce(trace: Trace) -> Reduced:
+    """The traced span is the one between the two markers, and everything
+    is clipped to it. Without both markers: the span from the first to the
+    last device event, nothing clipped."""
+    host, marks = [], {}
+    for plane in trace.profile.planes:
+        if not plane.name.startswith("/host:CPU"):
+            continue
+        for line in plane.lines:
+            if line.name.startswith("tf_XLA"):
+                continue              # runtime worker pools, not the program
+            for ev in line.events:
+                if ev.name in (MARK_OPEN, MARK_CLOSE):
+                    marks.setdefault(ev.name, int(ev.start_ns))
+                if ev.duration_ns > 0 and "::" not in ev.name:
+                    host.append((int(ev.start_ns),
+                                 int(ev.start_ns + ev.duration_ns),
+                                 _host_name(ev.name)))
+    span = (marks.get(MARK_OPEN), marks.get(MARK_CLOSE))
+    marked = None not in span and span[0] < span[1]
+    devices = []
     for plane in trace.profile.planes:
         name = plane.name
         if name.startswith("/device:") and "TPU" in name.upper() \
                 and "core" not in name.lower():
-            dev = _device(plane, trace.meta.get(name, {}))
+            dev = _device(plane, trace.meta.get(name, {}),
+                          span if marked else None)
             if dev.ops:
                 devices.append(dev)
-                lo = dev.ops[0].start if lo is None else min(
-                    lo, dev.ops[0].start)
-                hi = max(hi or 0, max(o.end for o in dev.ops))
-        elif name.startswith("/host:CPU"):
-            for line in plane.lines:
-                if line.name.startswith("tf_XLA"):
-                    continue          # runtime worker pools, not the program
-                for ev in line.events:
-                    if ev.duration_ns > 0 and "::" not in ev.name:
-                        host.append((int(ev.start_ns),
-                                     int(ev.start_ns + ev.duration_ns),
-                                     _host_name(ev.name)))
-    if window_ns is None:
-        window_ns = (hi - lo) if devices else 0
-    return Reduced(window_ns=int(window_ns), devices=devices, host=host)
+    if marked:
+        host = _cut(host, span)
+    else:
+        span = (min((d.ops[0].start for d in devices), default=0),
+                max((o.end for d in devices for o in d.ops), default=0))
+    return Reduced(devices=devices, host=host, span=span, marked=marked)
 
 
 def _host_name(name: str) -> str:
@@ -304,28 +359,40 @@ def _hlo_name(text: str) -> str:
     return text.split(" ", 1)[0].lstrip("%")
 
 
-def _device(plane: Any, meta: dict[str, dict[str, Any]]) -> DeviceTrace:
-    modules: list[tuple[int, int, str]] = []
+def _device(plane: Any, meta: dict[str, dict[str, Any]],
+            span: tuple[int, int] | None = None) -> DeviceTrace:
+    """One chip's ops and program executions, cut to ``span``. An op's
+    program is found before the cut (by where the op really started); self
+    times are taken after it, on what is left of each op."""
+    whole: list[tuple[int, int, str]] = []
     raw: list[tuple[int, int, str, dict]] = []
     for line in plane.lines:
         if line.name == _MODULES_LINE:
             for ev in line.events:
                 s = int(ev.start_ns)
-                modules.append((s, s + int(ev.duration_ns),
-                                program_name(ev.name)))
+                whole.append((s, s + int(ev.duration_ns),
+                              program_name(ev.name)))
         elif line.name == _OPS_LINE:
             for ev in line.events:
                 s = int(ev.start_ns)
                 raw.append((s, s + int(ev.duration_ns), ev.name,
                             meta.get(ev.name) or {}))
-    modules.sort()
-    starts = [m[0] for m in modules]
+    whole.sort()
+    starts = [m[0] for m in whole]
     raw.sort(key=lambda r: (r[0], -r[1]))
+    programs = []
+    for s, _, _, _ in raw:
+        i = bisect.bisect_right(starts, s) - 1
+        programs.append(whole[i][2] if i >= 0 and s < whole[i][1]
+                        else "unknown")
+    modules = _cut([(s, e, p, e - s) for s, e, p in whole], span)
+    module_parts = [(e - s) / n if n else 1.0 for s, e, _, n in modules]
+    # Stable: an enclosing op cut to the same start stays before its body.
+    cut = sorted(_cut([(*r, p) for r, p in zip(raw, programs)], span),
+                 key=lambda r: (r[0], -r[1]))
     ops: list[Op] = []
     stack: list[Op] = []
-    for s, e, name, stats in raw:
-        i = bisect.bisect_right(starts, s) - 1
-        program = modules[i][2] if i >= 0 and s < modules[i][1] else "unknown"
+    for s, e, name, stats, program in cut:
         short = _hlo_name(name)
         category = str(stats.get("hlo_category") or "").strip().replace(
             " ", "_") or ("custom-call" if " custom-call(" in name
@@ -342,7 +409,18 @@ def _device(plane: Any, meta: dict[str, dict[str, Any]]) -> DeviceTrace:
         ops.append(op)
     for op in ops:
         op.self_ns = max(0, op.self_ns)
-    return DeviceTrace(name=plane.name, ops=ops, modules=modules)
+    return DeviceTrace(name=plane.name, ops=ops,
+                       modules=[m[:3] for m in modules],
+                       module_parts=module_parts)
+
+
+def _cut(rows: list[tuple], span: tuple[int, int] | None) -> list[tuple]:
+    """``(start, end, ...)`` rows cut to ``span``; what lies outside goes."""
+    if span is None:
+        return rows
+    lo, hi = span
+    return [(max(s, lo), min(e, hi), *rest) for s, e, *rest in rows
+            if s < hi and e > lo]
 
 
 def _scope(stats: dict, name: str) -> str:
@@ -404,15 +482,19 @@ def load(trace_dir: str) -> Trace:
 
 def describe(trace: Trace, n: int = 6) -> list[dict]:
     """Planes, lines and the first ``n`` events of each with their own and
-    their metadata's stats — for looking at one trace by hand before
-    trusting the reduction."""
+    their metadata's stats, and wherever on a line the harness's marker
+    spans are — for looking at one trace by hand before trusting the
+    reduction."""
     out = []
     for plane in trace.profile.planes:
         meta = trace.meta.get(plane.name, {})
         for line in plane.lines:
-            evs = []
+            evs, marks = [], []
             count = 0
             for ev in line.events:
+                if ev.name in (MARK_OPEN, MARK_CLOSE):
+                    marks.append({"name": ev.name, "start_ns": ev.start_ns,
+                                  "duration_ns": ev.duration_ns})
                 if count < n:
                     evs.append({"name": ev.name, "start_ns": ev.start_ns,
                                 "duration_ns": ev.duration_ns,
@@ -425,5 +507,5 @@ def describe(trace: Trace, n: int = 6) -> list[dict]:
                                                  ).items()}})
                 count += 1
             out.append({"plane": plane.name, "line": line.name,
-                        "events": count, "first": evs})
+                        "events": count, "first": evs, "marks": marks})
     return out

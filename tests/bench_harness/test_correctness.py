@@ -124,6 +124,15 @@ def test_tokenizer_is_one_character_per_id_both_ways():
         CharTokenizer(100)
 
 
+def test_no_id_ends_an_answer_so_every_seed_does_the_same_work():
+    """Random weights emit any id once in a vocabulary's worth of tokens;
+    an id that ended the answer would cut a seed's request short and, in a
+    closed loop, re-phase every request behind it."""
+    tok = CharTokenizer(32000)
+    assert tok.eos_ids == set() and isinstance(tok.eos_ids, set)
+    assert tok.bos_id == 1 and tok.pad_id == 0
+
+
 def test_exact_routing_is_the_programs_only_where_dispatch_drops_nothing():
     """Eight experts, top-2. The reference never drops a token. The
     program agrees with it to float32 rounding through a 64-token prefill

@@ -4,8 +4,9 @@ import math
 
 import pytest
 
-from benchmark.metrics import (RequestLog, end_to_end, overlapping,
-                               percentile, tokens_in_window, tpot_samples,
+from benchmark.metrics import (BURST_S, RequestLog, bursts, end_to_end,
+                               frames_in_window, overlapping, percentile,
+                               streamed_by, tokens_in_window, tpot_samples,
                                ttft_samples)
 
 
@@ -19,7 +20,9 @@ def req(i, send, frames, end, ok=True, due=None, **kw):
 
 def test_tokens_count_when_streamed_across_both_edges():
     logs = [
-        # straddles the opening edge: 3 of its 5 tokens fall inside
+        # straddles the opening edge: the frames at 100, 101 and 102 are
+        # inside; the token that arrived at 100.0 was made before it and
+        # the one that arrives at 110.0 (below) inside, so 16 either way
         req(0, 90.0, [(98.0, 1), (99.5, 1), (100.0, 1), (101.0, 1),
                       (102.0, 1)], 102.1),
         # wholly inside, one frame carrying a burst of 8
@@ -30,7 +33,8 @@ def test_tokens_count_when_streamed_across_both_edges():
         # still streaming when the run ended: no end, counted all the same
         req(3, 108.5, [(109.0, 2)], None),
     ]
-    assert tokens_in_window(logs, 100.0, 110.0) == 3 + 9 + 2 + 2
+    assert frames_in_window(logs, 100.0, 110.0) == 3 + 9 + 2 + 2
+    assert tokens_in_window(logs, 100.0, 110.0) == 2 + 9 + 3 + 2
     values, counts = end_to_end(logs, 100.0, 110.0)
     assert values["out_tok_s"] == pytest.approx(1.6)
     assert counts["out_tok_s"] == 16
@@ -78,3 +82,59 @@ def test_percentile_is_nearest_rank():
     assert percentile([7.0], 90) == 7.0
     with pytest.raises(ValueError):
         percentile([], 50)
+
+
+def burst_stream(period, per_slot, slots, until):
+    """Every slot gets ``per_slot`` tokens each ``period``, stamped within
+    a few milliseconds of each other, as a decode scan serves them."""
+    logs = [req(k, 0.0, [], None) for k in range(slots)]
+    t = period
+    while t < until:
+        for k, r in enumerate(logs):
+            r.frames += [(t + 0.0004 * k + 0.0001 * j, 1)
+                         for j in range(per_slot)]
+        t += period
+    return logs
+
+
+@pytest.mark.parametrize("phase", [0.0, 0.004, 0.1, 0.296, 0.2999, 0.31])
+def test_a_burst_astride_an_edge_gives_the_share_of_its_interval_inside(
+        phase):
+    """28 tokens every 0.3 s: the rate is 93.3 tokens/s wherever the edges
+    fall against the bursts. Counting frames, a 4.0 s window holds 13 or
+    14 bursts: 7% apart by where its close fell (longdoc: 0.97% of 40 s,
+    the refused check of PR 25)."""
+    logs = burst_stream(0.3, 4, 7, until=12.0)
+    t_open, t_close = 3.05 + phase, 7.05 + phase
+    values, counts = end_to_end(logs, t_open, t_close)
+    assert values["out_tok_s"] == pytest.approx(28 / 0.3, rel=2e-3)
+    assert counts["out_tok_s"] in (13 * 28, 14 * 28)
+    # Whole bursts inside count whole, once: only the two at the edges
+    # are shared out.
+    assert abs(tokens_in_window(logs, t_open, t_close)
+               - frames_in_window(logs, t_open, t_close)) <= 28
+
+
+def test_bursts_are_told_by_their_first_frame_and_accrue_evenly():
+    logs = [req(0, 0.0, [(1.0, 1), (1.003, 1), (2.0, 1), (2.015, 1)], 2.1),
+            req(1, 0.0, [(1.001, 1), (2.001, 2), (2.0 + BURST_S, 1)], 2.1)]
+    stream = bursts(logs)
+    assert stream == [(1.0, 3), (2.0, 4), (2.0 + BURST_S, 1)]
+    assert streamed_by(stream, 0.5) == 0            # nothing before the first
+    assert streamed_by(stream, 1.0) == 0 and streamed_by(stream, 1.0001) > 3
+    assert streamed_by(stream, 1.5) == pytest.approx(3 + 2.0)
+    assert streamed_by(stream, 2.0) == pytest.approx(7.0)
+    assert streamed_by(stream, 9.0) == 8            # all of it, after the last
+    # Monotone and continuous between bursts.
+    ts = [1.0 + 0.01 * i for i in range(1, 100)]
+    vals = [streamed_by(stream, t) for t in ts]
+    assert all(0 < b - a < 0.05 for a, b in zip(vals, vals[1:]))
+
+
+def test_a_stream_without_bursts_reads_as_its_frames_do():
+    """One token every 7 ms: the bins of ``BURST_S`` move no more than a
+    bin's tokens across an edge."""
+    logs = [req(0, 0.0, [(1.0 + 0.007 * i, 1) for i in range(2000)], None)]
+    for t_open, t_close in ((2.0, 9.0), (2.0031, 9.0155), (3.3, 4.3)):
+        assert tokens_in_window(logs, t_open, t_close) == pytest.approx(
+            frames_in_window(logs, t_open, t_close), abs=3.0)

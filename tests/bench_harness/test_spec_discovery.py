@@ -42,6 +42,11 @@ def extended(tmp_path_factory) -> Path:
         "prompt_tokens": {"kind": "uniform", "min": 40, "max": 120,
                           "snap": 8},
         "max_tokens": {"kind": "uniform", "min": 8, "max": 24, "snap": 4}}))
+    (root / "benchmark/traffic/tiny-open.json").write_text(json.dumps({
+        "loop": "open", "rate_rps": 6.0, "lead_in_s": 1.5, "trace_seed": 1,
+        "prompt_tokens": {"kind": "uniform", "min": 40, "max": 120,
+                          "snap": 8},
+        "max_tokens": {"kind": "uniform", "min": 8, "max": 24, "snap": 4}}))
     (root / "benchmark/layer_metrics/sched.prefill_wait_ms.json").write_text(
         json.dumps({"unit": "ms", "reducer": "request_interval_ms",
                     "args": {"from": "t_admitted", "to": "t_first_token"}}))
@@ -50,16 +55,19 @@ def extended(tmp_path_factory) -> Path:
         "name": "tiny-swa", "source": "none",
         "file": "benchmark/configs/tiny-swa.json", "reduced": [],
         "why": "rehearsal"})
-    bench["workloads"].append({
-        "name": "tiny-swa-closed", "config": "tiny-swa",
-        "traffic": "tiny-closed", "chips": 1, "why": "rehearsal"})
+    bench["workloads"] += [
+        {"name": "tiny-swa-closed", "config": "tiny-swa",
+         "traffic": "tiny-closed", "chips": 1, "why": "rehearsal"},
+        {"name": "tiny-swa-open", "config": "tiny-swa",
+         "traffic": "tiny-open", "chips": 1, "why": "rehearsal"}]
     for m in bench["end_to_end"] + bench["per_layer"]:
         if "workloads" in m:
-            m["workloads"].append("tiny-swa-closed")
+            m["workloads"] += ["tiny-swa-closed", "tiny-swa-open"]
     bench["per_layer"].append({
         "name": "sched.prefill_wait_ms", "unit": "ms", "better": "lower",
         "source": "program_span", "layer": "scheduler",
-        "moves": "ttft_p50_ms", "workloads": ["tiny-swa-closed"]})
+        "moves": "ttft_p50_ms",
+        "workloads": ["tiny-swa-closed", "tiny-swa-open"]})
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
     return root
 
@@ -128,10 +136,11 @@ def test_without_a_chip_a_cell_fails_and_prints_no_result():
     assert done.stdout.strip() == ""
 
 
+@pytest.mark.parametrize("workload", ["tiny-swa-closed", "tiny-swa-open"])
 def test_cpu_rehearsal_runs_the_whole_harness_and_names_no_device_metric(
-        extended):
+        extended, workload):
     done = run_benchmark(
-        "--workload", "tiny-swa-closed", "--seed", str(2**31 + 5),
+        "--workload", workload, "--seed", str(2**31 + 5),
         "--seconds", "2", "--trace", "1", "--root", str(extended),
         "--rehearse-cpu")
     assert done.returncode == 0, done.stderr[-3000:]
@@ -149,12 +158,33 @@ def test_cpu_rehearsal_runs_the_whole_harness_and_names_no_device_metric(
         "value"] == 0
     phases = {ln["phase"]: ln for ln in lines[:-1]}
     assert {"start", "engine", "programs", "kernel_parity", "reference",
-            "setup", "window"} <= set(phases)
+            "setup", "window", "trace"} <= set(phases)
+    # Counters and trace open with the window, in the open loop too (its
+    # lead-in is 1.5 s): the snapshots are the window apart, the traced
+    # span begins inside it, is found by its markers and lasts what the
+    # harness asked for, by the profiler's clock as by the host's.
+    assert phases["window"]["counters_span_s"] == pytest.approx(2.0, abs=0.3)
+    assert 0 <= phases["window"]["loop_stall_ms"] < 1500
+    assert phases["window"]["loop_stall_cpu_s"] >= 0
+    # Nothing is traced, lowered or compiled inside the window: the
+    # harness listens to JAX's own events, whatever the program counts.
+    assert phases["window"]["jax_events"] == {
+        "count": 0, "seconds": 0.0, "longest": []}
+    assert phases["setup"]["lead_in_s"] >= (
+        1.5 if workload == "tiny-swa-open" else 0.0)
+    assert 0 <= last["device"]["trace_offset_s"] < 1.5
+    tr = phases["trace"]
+    assert tr["marked"] is True and "problem" not in tr
+    assert tr["profiler_clock_s"] == pytest.approx(tr["host_clock_s"],
+                                                   abs=2e-3)
+    assert 4.0 <= tr["profiler_clock_s"] < 4.2
+    # No TPU plane in a CPU's trace: no device facts, no breakdown.
+    assert not {"busy_s", "window_s"} & set(last["device"])
     assert phases["reference"]["ok"] and phases["reference"]["positions"] > 30
     assert all(c["ok"] for c in phases["kernel_parity"]["cases"])
     assert phases["window"]["compiles_in_window"] == 0
     assert phases["window"]["samples"]["out_tok_s"] > 0
     assert set(phases["setup"]) >= {"engine_build_s", "programs_s",
                                     "correctness_s", "lead_in_s", "setup_s"}
-    records = REPO / "bench_out" / "tiny-swa-closed" / "requests.jsonl"
+    records = REPO / "bench_out" / workload / "requests.jsonl"
     assert len(records.read_text().splitlines()) >= last["attempted"]

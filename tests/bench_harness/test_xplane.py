@@ -109,10 +109,12 @@ def two_chips():
 
 
 def test_busy_idle_collectives_and_gaps_on_two_chips():
-    r = xplane.reduce(two_chips(), window_ns=200_000)
+    r = xplane.reduce(two_chips())
     assert len(r.devices) == 2
-    # Busy: 0-100 (the loop covers its gaps) and 150-170 on both chips.
-    assert r.busy_ns() == 120_000 and r.idle_share() == pytest.approx(0.4)
+    # Busy: 0-100 (the loop covers its gaps) and 150-170 on both chips, of
+    # the 170 from the first to the last device event.
+    assert r.busy_ns() == 120_000 and r.window_ns == 170_000
+    assert r.idle_share() == pytest.approx(50 / 170)
     # The loop's self time is what its body leaves uncovered: 90-100.
     assert r.self_ns(category="while") == 10_000
     assert r.self_ns(scope="decode.mlp", category="convolution_fusion") \
@@ -125,9 +127,144 @@ def test_busy_idle_collectives_and_gaps_on_two_chips():
     assert r.top_ops(2) == [
         ["decode_scan/attention.paged_decode:custom-call", 50_000 / 1e9],
         ["decode_scan/decode.mlp:convolution_fusion", 42_500 / 1e9]]
-    # The 100-150 gap's middle (125) lies in both host spans; the
-    # innermost names it. Runtime worker pools are not the program.
-    assert r.idle_gaps() == [["host.np.asarray(jax.Array)", 50_000 / 1e9]]
+    # The 100-150 gap's middle (125) lies in both host spans: the
+    # program's names it first, the runtime's second. Runtime worker pools
+    # are not the program. No markers: the span is first to last device
+    # event, so there is no idle edge.
+    assert r.idle_gaps() == [
+        ["host.decode/np.asarray(jax.Array)", 50_000 / 1e9]]
+    assert not r.marked and r.span == (0, 170_000)
+
+
+HOST_META = {1: ("bench.trace_open", None, None),
+             2: ("bench.trace_close", None, None),
+             3: ("decode", None, None), 4: ("sched.flush", None, None),
+             5: ("AllocateRawBuffer", None, None),
+             6: ("PjitFunction(decode_scan)", None, None),
+             7: ("ThreadpoolListener::Record", None, None)}
+
+
+def marked(host_lines=None, ops=None, mods=None):
+    """One chip, markers at 50 and 250 us (the span), written by a thread
+    of their own. Device: a module -20..70 whose loop -20..70 holds a
+    matmul -20..40 and the kernel 40..70 (straddles the opening); a
+    kernel-only module 120..140 inside; a module 230..290 (loop, kernel
+    230..260, matmul 260..290) that straddles the close; and a module
+    300..320 wholly after it."""
+    ops = ops or [(2, -20, 90), (3, -20, 60), (5, 40, 30), (5, 120, 20),
+                  (2, 230, 60), (5, 230, 30), (3, 260, 30), (5, 300, 20)]
+    mods = mods or [(1, -20, 90), (1, 120, 20), (1, 230, 60), (1, 300, 20)]
+    shift = 1000                        # offsets in a text proto are unsigned
+    host = plane(3, "/host:CPU", {
+        "python3/marks": [(1, 50 + shift, 0.5), (2, 250 + shift, 0.5)],
+        **{k: [(m, s + shift, d) for m, s, d in v]
+           for k, v in (host_lines or {}).items()}}, HOST_META)
+    dev = plane(1, "/device:TPU:0", {
+        "XLA Modules": [(m, s + shift, d) for m, s, d in mods],
+        "XLA Ops": [(m, s + shift, d) for m, s, d in ops]}, META)
+    return xplane.Trace.from_text_proto(dev + "\n" + host), shift * 1000
+
+
+def test_markers_bound_the_span_and_everything_is_clipped_to_it():
+    trace, t0 = marked()
+    r = xplane.reduce(trace)
+    assert r.marked and r.span == (t0 + 50_000, t0 + 250_000)
+    assert r.window_ns == 200_000
+    dev = r.devices[0]
+    # Clipped: 50-70 of the first module, 120-140, 230-250 of the third;
+    # the fourth lies outside and is gone.
+    assert [(s - t0, e - t0) for s, e, _ in dev.modules] == [
+        (50_000, 70_000), (120_000, 140_000), (230_000, 250_000)]
+    assert all(r.span[0] <= op.start <= op.end <= r.span[1] for op in dev.ops)
+    assert r.busy_ns() == 60_000 and r.busy_ns() <= r.window_ns
+    assert r.idle_share() == pytest.approx(0.7)
+    # Self times are of what is left: the opening loop keeps nothing (its
+    # kernel covers 50-70), the matmul that ended at 40 is gone, the
+    # closing kernel keeps 230-250 and the matmul behind it nothing.
+    assert r.self_ns(category="while") == 0
+    assert r.self_ns(scope="attention.paged_decode") == 60_000
+    assert r.self_ns(scope="decode.mlp") == 0
+    assert sum(op.self_ns for op in dev.ops) == r.busy_ns()
+    # A program execution counts as the part of it inside the span.
+    assert r.program_events("decode_scan") == pytest.approx(
+        20 / 90 + 1 + 20 / 60)
+    assert r.top_ops(1) == [
+        ["decode_scan/attention.paged_decode:custom-call", 60_000 / 1e9]]
+    # Idle: 70-120 and 140-230 (no idle edge here: busy at both markers).
+    assert sum(e - s for s, e in r.gaps()) == 140_000
+
+
+def test_busy_cannot_exceed_the_window_whatever_the_trace_holds():
+    """One op from before the opening to after the close: the stamped
+    window of PR 24's refused run was shorter than what it divided."""
+    trace, _ = marked(ops=[(5, 0, 400)], mods=[(1, 0, 400)])
+    r = xplane.reduce(trace)
+    assert r.busy_ns() == r.window_ns == 200_000
+    assert r.idle_share() == 0.0 and r.idle_gaps() == []
+    assert r.program_events("decode_scan") == pytest.approx(0.5)
+
+
+def test_idle_edges_are_gaps_and_gaps_are_named_program_then_runtime():
+    """Ops only at 100-110 and 180-190: the edges 50-100 and 190-250 are
+    idle like the middle. Names: program span and runtime span, the
+    program's span alone, the runtime's alone, neither; the benchmark's
+    own markers and the worker pools never name a gap."""
+    trace, t0 = marked(
+        ops=[(5, 100, 10), (5, 180, 10)], mods=[(1, 100, 10), (1, 180, 10)],
+        host_lines={
+            "python3/engine": [(3, 40, 50), (4, 60, 20), (5, 70, 8),
+                               (3, 130, 30)],
+            "python3/loop": [(6, 200, 40), (5, 215, 10)],
+            "tf_XLATfrtTpuClient/1": [(7, 0, 400)]})
+    r = xplane.reduce(trace)
+    assert [(s - t0, e - t0) for s, e in r.gaps()] == [
+        (50_000, 100_000), (110_000, 180_000), (190_000, 250_000)]
+    # 50-100, middle 75: decode 40-90 and sched.flush 60-80 cover it, the
+    # inner program span wins; AllocateRawBuffer 70-78 is the runtime's.
+    # 110-180, middle 145: decode 130-160 alone.
+    # 190-250, middle 220: PjitFunction 200-240 and, inside it,
+    # AllocateRawBuffer 215-225; no program span.
+    assert r.idle_gaps() == [
+        ["host.decode/other", 70_000 / 1e9],
+        ["host.-/AllocateRawBuffer", 60_000 / 1e9],
+        ["host.sched.flush/AllocateRawBuffer", 50_000 / 1e9]]
+    assert sum(ns for _, ns in r.idle_gaps()) * 1e9 == pytest.approx(
+        r.window_ns - r.busy_ns())
+    # Neither kind of span over a gap.
+    bare, _ = marked(ops=[(5, 100, 10)], mods=[(1, 100, 10)])
+    assert xplane.reduce(bare).idle_gaps() == [
+        ["host.-/other", 190_000 / 1e9]]
+    # Host spans are cut to the span too.
+    assert all(r.span[0] <= s < e <= r.span[1] for s, e, _ in r.host)
+
+
+def test_a_trace_without_both_markers_gives_the_harness_no_device_facts(
+        recorded):
+    from benchmark import run
+    r = xplane.reduce(recorded)
+    assert not r.marked and r.devices
+    assert r.window_ns == r.span[1] - r.span[0] > 0     # first to last event
+    assert run.trace_facts(r) == ({}, {})
+    facts, breakdown = run.trace_facts(xplane.reduce(marked()[0]))
+    assert facts == {"busy_s": 60_000 / 1e9, "window_s": 200_000 / 1e9}
+    assert set(breakdown["breakdown"]) == {"device_ops", "idle_gaps"}
+    # The close marker before the open one is no span either.
+    swapped = plane(3, "/host:CPU", {"python3": [(2, 10, 1), (1, 20, 1)]},
+                    HOST_META)
+    dev = plane(1, "/device:TPU:0", {"XLA Modules": [(1, 0, 30)],
+                                     "XLA Ops": [(5, 0, 30)]}, META)
+    r = xplane.reduce(xplane.Trace.from_text_proto(dev + "\n" + swapped))
+    assert not r.marked and r.window_ns == 30_000
+
+
+def test_describe_shows_where_the_markers_landed():
+    trace, t0 = marked()
+    lines = {(d["plane"], d["line"]): d for d in xplane.describe(trace, 2)}
+    marks = lines[("/host:CPU", "python3/marks")]["marks"]
+    assert [m["name"] for m in marks] == [xplane.MARK_OPEN,
+                                          xplane.MARK_CLOSE]
+    assert marks[0]["start_ns"] == t0 + 50_000
+    assert lines[("/device:TPU:0", "XLA Ops")]["marks"] == []
 
 
 def test_program_names():
