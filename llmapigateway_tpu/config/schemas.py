@@ -265,15 +265,6 @@ class LocalEngineConfig(BaseModel):
     # backends without allocator stats (CPU reports none); the HBM
     # ledger's gateway_engine_hbm_* gauges report the same numbers.
     hbm_headroom_watermark: float = Field(default=0.0, ge=0.0, lt=1.0)
-    # Phase-annotated profiling (ISSUE 8): host-side jax.profiler
-    # TraceAnnotation markers (prefill / decode / spec.verify) around
-    # every compiled-program dispatch, so on-demand captures
-    # (POST /v1/api/profiler/trace) segment by scheduler phase in
-    # Perfetto. Cost is a few µs per dispatch (the bench's annotation
-    # A/B rung pins it ≤1% on decode); the in-program named_scope
-    # markers (decode.attention / decode.mlp / sampling) are trace-time
-    # metadata and cannot be disabled because they cost nothing.
-    profile_annotations: bool = True
     # Prefill/decode disaggregation (ISSUE 13): two pools, one paged KV
     # pool, zero-copy handoff, goodput-first admission. Default off —
     # the unified scheduler is byte-identical to pre-pool behavior.

@@ -523,13 +523,23 @@ class InferenceEngine:
         from ..obs.flight import FlightRecorder
         self.flight = (FlightRecorder(engine_cfg.flight_ring_size)
                        if engine_cfg.flight_ring_size > 0 else None)
+        # The scheduler's time ledger (ISSUE 26, obs/phases.py): the
+        # loop's wall partitioned into named phases, and each worker-
+        # thread wait into what the worker did with it. Loop-thread only;
+        # the worker reaches its call through `_device_phase`.
+        from ..obs.phases import SchedLedger
+        self._sched = SchedLedger()
+        # What the last compiled prefill dispatch ran (rows, bucket,
+        # tokens, lowest/highest start position, t0, t1): written by the
+        # worker inside _exec_prefill, read by the loop after the await
+        # for the PREFILL flight record.
+        self._last_prefill: tuple | None = None
         # Device observability plane (ISSUE 8): per-kernel cost registry
         # (worker thread records, lock-guarded internally), the HBM
         # memory ledger, and the process-wide XLA compile monitor. The
         # ledger's watermark feeds submit()'s shed path so admission
         # reacts to device memory pressure, not just slots/pages.
         from ..obs.device import HbmLedger, KernelRegistry
-        self.profile_annotations = bool(engine_cfg.profile_annotations)
         self.kernels = KernelRegistry()
         self.ledger: HbmLedger = self._build_ledger()
         self._watermark_sheds = 0                       # guarded-by: loop
@@ -1622,6 +1632,14 @@ class InferenceEngine:
         sup = self.supervisor
         sup.transition("serving", "scheduler loop started")
         sup.heartbeat(self.flight.seq if self.flight is not None else 0)
+        self._sched.start()
+        try:
+            await self._serve(sup)
+        finally:
+            self._sched.stop()
+        logger.info("engine loop stopped")
+
+    async def _serve(self, sup) -> None:
         while not self._stopped:
             # Clear BEFORE stepping: a submit() that lands during the await
             # inside _step sets the event and must not be wiped afterwards
@@ -1650,10 +1668,13 @@ class InferenceEngine:
                 await self._on_step_failure(EngineFailure.classify(e))
                 progressed = True
             if not progressed:
-                await self._work_event.wait()
+                self._sched.switch("parked")
+                try:
+                    await self._work_event.wait()
+                finally:
+                    self._sched.switch("other")
                 sup.heartbeat(self.flight.seq if self.flight is not None
                               else 0)
-        logger.info("engine loop stopped")
 
     async def _on_step_failure(self, failure) -> None:
         """Supervised recovery from a classified step-loop failure
@@ -1871,163 +1892,9 @@ class InferenceEngine:
         n_chunks = 0                  # compiled prefill dispatches this step
         n_tok = 0                     # tokens emitted downstream this step
         spec_acc_n = 0                # accepted draft tokens landed this step
-        # 1. Admit into free slots (dropping requests whose client is gone).
-        #    Paged layout: the FIFO head also needs its full page reservation
-        #    (engine/paged.py policy) — if pages are short it waits at the
-        #    head (no starvation: held pages always return via releases).
-        while True:
-            # Pool capacity gate (ISSUE 13): the unified pool just needs
-            # any free slot; a disaggregated COLD admission needs a free
-            # prefill slot AND a free decode slot to reserve (so the
-            # handoff can never strand a prompt-complete request), while
-            # the direct-to-decode path (warm prefix hit / penalties —
-            # decided below, after the prefix lookup) needs only the
-            # decode slot.
-            cold_ok = bool(self._admit_pool.free) and (
-                self._disagg is None or bool(self._decode_pool.free))
-            if not cold_ok and not (self._disagg is not None
-                                    and self._decode_pool.free):
-                break
-            if self._head is None:
-                if self._queue.empty():
-                    break
-                self._head = self._queue.get_nowait()
-            req = self._head
-            if req.cancelled:
-                req.finish_reason = "cancelled"
-                self._head = None
-                continue
-            if self.paged:
-                total = min(len(req.prompt_ids) + req.max_tokens, self.S)
-                # Radix prefix lookup (ISSUE 6): resident prompt blocks map
-                # into the new slot's table row instead of allocating +
-                # prefilling. Penalty requests bypass the cache — their
-                # token-occurrence counts are rebuilt by prefill, which a
-                # skipped span would leave incomplete. Matched nodes are
-                # pinned here; the pins drop at slot release, or right
-                # below if the request parks instead of admitting.
-                matched, shared_pages, nodes = 0, [], []
-                cache = self._prefix_cache
-                if (cache is not None and req.presence_penalty == 0
-                        and req.frequency_penalty == 0):
-                    t_lk = time.monotonic()
-                    matched, shared_pages, nodes = cache.match(
-                        req.prompt_ids)
-                    req.prefix_lookup_ms = 1000.0 * (time.monotonic()
-                                                     - t_lk)
-                ok = self.allocator.can_admit(
-                    total, ring_pages=self._swa_ring_pages,
-                    shared_pages=len(shared_pages))
-                if not ok and cache is not None:
-                    # Page pressure: reclaim cold cache entries (LRU
-                    # leaves; pinned blocks are untouchable) before
-                    # parking the head — the admission-side half of the
-                    # overload/Retry-After machinery.
-                    short = self.allocator.fresh_shortfall(
-                        total, ring_pages=self._swa_ring_pages,
-                        shared_pages=len(shared_pages))
-                    evicted = cache.evict(short) if short > 0 else 0
-                    if evicted > 0:
-                        if fl is not None:
-                            from ..obs.flight import EVICT
-                            fl.record(EVICT, val=float(evicted),
-                                      free_pages=self.allocator.free_pages)
-                        ok = self.allocator.can_admit(
-                            total, ring_pages=self._swa_ring_pages,
-                            shared_pages=len(shared_pages))
-                if not ok:
-                    if cache is not None:
-                        cache.release_nodes(nodes)
-                    break
-            direct = False
-            if self._disagg is not None:
-                # Direct-to-decode placement (no handoff): a warm prefix
-                # hit whose unmatched tail fits ONE chunk skips the
-                # prefill pool entirely (the matched span never prefills
-                # at all — the composition the radix cache buys), and a
-                # penalty request must build its on-device token counts
-                # on the slot that will decode it (it bypasses the
-                # prefix cache for the same reason, so matched == 0).
-                direct = (req.presence_penalty != 0
-                          or req.frequency_penalty != 0
-                          or (matched > 0
-                              and len(req.prompt_ids) - matched
-                              <= self.prefill_chunk))
-                if not direct and not cold_ok:
-                    # Cold prompt but no prefill slot (or no decode slot
-                    # to reserve): park at the FIFO head, exactly like a
-                    # page-reservation shortfall.
-                    if cache is not None:
-                        cache.release_nodes(nodes)
-                    break
-            if self._disagg is None:
-                target_pool = self._admit_pool
-                req.slot = target_pool.take()
-            elif direct:
-                target_pool = self._decode_pool
-                req.slot = target_pool.take()
-                req.decode_slot = req.slot
-            else:
-                target_pool = self._admit_pool
-                req.slot = target_pool.take()
-                req.decode_slot = self._decode_pool.take()  # reservation
-            req.pool = target_pool.pool_id
-            target_pool.admits += 1
-            self._head = None
-            # Queue-wait gauge (submit → slot admission): the scheduler
-            # half of TTFT — what the prefill-aware burst clamp bounds.
-            # t_admitted also closes the trace's engine.queued phase.
-            req.t_admitted = time.monotonic()
-            wait_ms = 1000.0 * (req.t_admitted - req.t_submit)
-            self._queue_wait_n += 1
-            self._queue_wait_ema_ms = (
-                wait_ms if self._queue_wait_ema_ms is None
-                else 0.8 * self._queue_wait_ema_ms + 0.2 * wait_ms)
-            self._queue_wait_max_ms = max(self._queue_wait_max_ms, wait_ms)
-            if self.spec_k:
-                # New text in this slot: acceptance starts unmeasured.
-                # (Reset at ADMISSION, not release, so stats keep the last
-                # measured rate while the engine drains/idles.) The
-                # per-slot suspension lifts with it — the new request's
-                # text regime owes nothing to its predecessor's.
-                self._spec_ema[req.slot] = np.nan
-                self._spec_suspended[req.slot] = False
-                self._spec_slot_proposed[req.slot] = 0
-                self._spec_slot_accepted[req.slot] = 0
-            if self.paged:
-                self.allocator.allocate(req.slot, total,
-                                        ring_pages=self._swa_ring_pages,
-                                        shared_pages=shared_pages)
-                self._table_dirty = True
-                if self._prefix_cache is not None:
-                    self._prefix_cache.record_lookup(matched)
-                    req.cached_tokens = matched
-                    req.prefix_nodes = nodes
-                if matched and self.spec_k:
-                    # Prompt-lookup history for the skipped span: the
-                    # per-chunk maintenance only covers chunks that
-                    # actually run, and its pos==0 reset never fires on a
-                    # warm admission.
-                    self.hist[req.slot, :] = 0
-                    self.hist[req.slot, :matched] = req.prompt_ids[:matched]
-            # Warm admission starts prefill at the match boundary — the
-            # matched span's prefill FLOPs are skipped outright (the
-            # chunk's attention reads the shared pages through the table,
-            # exactly like a later chunk of a cold prefill).
-            req.prefill_pos = req.cached_tokens
-            self._running[req.slot] = req
-            self._prefilling[req.slot] = req
-            if fl is not None:
-                from ..obs.flight import ADMIT
-                req.flight_admit_seq = fl.record(
-                    ADMIT, slot=req.slot, val=wait_ms,
-                    tokens=req.cached_tokens,
-                    queued=self._queue.qsize() + (1 if self._head else 0),
-                    free_slots=self._free_slot_count(),
-                    free_pages=(self.allocator.free_pages if self.paged
-                                else -1),
-                    pool=req.pool,
-                    rid=req.request_id or None)
+        # 1. Admit into free slots (_admit).
+        with self._sched.span("admit"):
+            self._admit(fl)
 
         t_pf0 = fl.clock() if fl is not None else 0.0
         # 2. Advance each pending prefill by ONE chunk (chunked-prefill
@@ -2055,15 +1922,18 @@ class InferenceEngine:
                     # don't burn one more prefill chunk on a dead client.
                     self._finish(req, "cancelled", emit=False)
                     continue
-                prompt_done = await asyncio.to_thread(
-                    self._prefill_one_chunk, req)
+                with self._sched.wait("prefill_wait"):
+                    prompt_done = await asyncio.to_thread(
+                        self._prefill_one_chunk, req)
                 n_chunks += 1
+                self._record_prefill(fl)
                 if prompt_done:
-                    del self._prefilling[req.slot]
-                    if self._disagg is not None:
-                        self._handoff(req)
-                    n_tok += 1
-                    self._emit_token(req)  # first token, sampled off prefill
+                    with self._sched.span("emit"):
+                        del self._prefilling[req.slot]
+                        if self._disagg is not None:
+                            self._handoff(req)
+                        n_tok += 1
+                        self._emit_token(req)  # first token, off prefill
         else:
             groups: dict[int, list[GenRequest]] = {}
             for req in eligible:
@@ -2088,16 +1958,19 @@ class InferenceEngine:
                         break
                     batch = self.prefill_groups(live)[0]
                     pending = live[len(batch):]
-                    dones = await asyncio.to_thread(
-                        self._prefill_chunk_group, batch)
+                    with self._sched.wait("prefill_wait"):
+                        dones = await asyncio.to_thread(
+                            self._prefill_chunk_group, batch)
                     n_chunks += 1
-                    for req, prompt_done in zip(batch, dones):
-                        if prompt_done:
-                            del self._prefilling[req.slot]
-                            if self._disagg is not None:
-                                self._handoff(req)
-                            n_tok += 1
-                            self._emit_token(req)
+                    self._record_prefill(fl)
+                    with self._sched.span("emit"):
+                        for req, prompt_done in zip(batch, dones):
+                            if prompt_done:
+                                del self._prefilling[req.slot]
+                                if self._disagg is not None:
+                                    self._handoff(req)
+                                n_tok += 1
+                                self._emit_token(req)
 
         n_tok_prefill = n_tok           # first tokens, sampled off prefill
         if self._disagg is not None and fl is not None and n_chunks:
@@ -2283,8 +2156,9 @@ class InferenceEngine:
                 burst = max(1, burst)
                 t_dec0 = fl.clock() if fl is not None else 0.0
                 spec_acc0 = self._spec_accepted_total
-                step_tokens = await asyncio.to_thread(
-                    self._spec_burst, burst, spec_probe)
+                with self._sched.wait("decode_wait"):
+                    step_tokens = await asyncio.to_thread(
+                        self._spec_burst, burst, spec_probe)
                 spec_acc_n = self._spec_accepted_total - spec_acc0
             else:
                 burst = self._burst_depth(busy)
@@ -2305,22 +2179,25 @@ class InferenceEngine:
                 if self._swa_ring_pages:
                     self._swa_rotate(decoding, inflight, burst)
                 t_dec0 = fl.clock() if fl is not None else 0.0
-                step_tokens = await asyncio.to_thread(
-                    self._decode_burst, burst)
+                with self._sched.wait("decode_wait"):
+                    step_tokens = await asyncio.to_thread(
+                        self._decode_burst, burst)
             dec_wall_ms = (1000.0 * (fl.clock() - t_dec0)
                            if fl is not None else 0.0)
-            for tokens in step_tokens:          # in generation order
-                for req in decoding:
-                    if req.done:
-                        continue
-                    tok = int(tokens[req.slot])
-                    if tok < 0:
-                        # Lag-one pipelining: this token array predates the
-                        # slot's current request (masked in _flush_entry).
-                        continue
-                    req.generated.append(tok)
-                    n_tok += 1
-                    self._emit_token(req)
+            with self._sched.span("emit"):
+                for tokens in step_tokens:          # in generation order
+                    for req in decoding:
+                        if req.done:
+                            continue
+                        tok = int(tokens[req.slot])
+                        if tok < 0:
+                            # Lag-one pipelining: this token array predates
+                            # the slot's current request (masked in
+                            # _flush_entry).
+                            continue
+                        req.generated.append(tok)
+                        n_tok += 1
+                        self._emit_token(req)
         progressed = bool(decoding) or bool(self._prefilling)
         if not progressed and self._free_slot_count() and (
                 self._head is not None or not self._queue.empty()):
@@ -2390,6 +2267,182 @@ class InferenceEngine:
                                else float("nan")))
         return progressed
 
+    def _record_prefill(self, fl) -> None:
+        """The PREFILL flight record of the compiled dispatch the await
+        just returned from (loop thread; the worker left the facts in
+        ``_last_prefill``)."""
+        last, self._last_prefill = self._last_prefill, None
+        if fl is None or last is None:
+            return
+        from ..obs import flight as _fl
+        rows, bucket, tokens, pos_lo, pos_hi, t0, t1 = last
+        fl.record(_fl.PREFILL, t=t1, dur_ms=1000.0 * (t1 - t0), depth=rows,
+                  val=float(bucket), tokens=tokens, free_pages=pos_lo,
+                  spec_acc=pos_hi,
+                  pool=_fl.POOL_PREFILL if self._disagg is not None else 0)
+
+    def _admit(self, fl) -> None:
+        """Phase 1 of a scheduler iteration, on the event-loop thread: pop
+        the queue into free slots, reserve pages, look prefixes up, evict
+        under page pressure, leave the ADMIT records."""
+        # Requests whose client is gone are dropped. Paged layout: the
+        # FIFO head also needs its full page reservation (engine/paged.py
+        # policy) — if pages are short it waits at the head (no
+        # starvation: held pages always return via releases).
+        while True:
+            # Pool capacity gate (ISSUE 13): the unified pool just needs
+            # any free slot; a disaggregated COLD admission needs a free
+            # prefill slot AND a free decode slot to reserve (so the
+            # handoff can never strand a prompt-complete request), while
+            # the direct-to-decode path (warm prefix hit / penalties —
+            # decided below, after the prefix lookup) needs only the
+            # decode slot.
+            cold_ok = bool(self._admit_pool.free) and (
+                self._disagg is None or bool(self._decode_pool.free))
+            if not cold_ok and not (self._disagg is not None
+                                    and self._decode_pool.free):
+                break
+            if self._head is None:
+                if self._queue.empty():
+                    break
+                self._head = self._queue.get_nowait()
+            req = self._head
+            if req.cancelled:
+                req.finish_reason = "cancelled"
+                self._head = None
+                continue
+            if self.paged:
+                total = min(len(req.prompt_ids) + req.max_tokens, self.S)
+                # Radix prefix lookup (ISSUE 6): resident prompt blocks map
+                # into the new slot's table row instead of allocating +
+                # prefilling. Penalty requests bypass the cache — their
+                # token-occurrence counts are rebuilt by prefill, which a
+                # skipped span would leave incomplete. Matched nodes are
+                # pinned here; the pins drop at slot release, or right
+                # below if the request parks instead of admitting.
+                matched, shared_pages, nodes = 0, [], []
+                cache = self._prefix_cache
+                if (cache is not None and req.presence_penalty == 0
+                        and req.frequency_penalty == 0):
+                    t_lk = time.monotonic()
+                    matched, shared_pages, nodes = cache.match(
+                        req.prompt_ids)
+                    req.prefix_lookup_ms = 1000.0 * (time.monotonic()
+                                                     - t_lk)
+                ok = self.allocator.can_admit(
+                    total, ring_pages=self._swa_ring_pages,
+                    shared_pages=len(shared_pages))
+                if not ok and cache is not None:
+                    # Page pressure: reclaim cold cache entries (LRU
+                    # leaves; pinned blocks are untouchable) before
+                    # parking the head — the admission-side half of the
+                    # overload/Retry-After machinery.
+                    short = self.allocator.fresh_shortfall(
+                        total, ring_pages=self._swa_ring_pages,
+                        shared_pages=len(shared_pages))
+                    evicted = cache.evict(short) if short > 0 else 0
+                    if evicted > 0:
+                        if fl is not None:
+                            from ..obs.flight import EVICT
+                            fl.record(EVICT, val=float(evicted),
+                                      free_pages=self.allocator.free_pages)
+                        ok = self.allocator.can_admit(
+                            total, ring_pages=self._swa_ring_pages,
+                            shared_pages=len(shared_pages))
+                if not ok:
+                    if cache is not None:
+                        cache.release_nodes(nodes)
+                    break
+            direct = False
+            if self._disagg is not None:
+                # Direct-to-decode placement (no handoff): a warm prefix
+                # hit whose unmatched tail fits ONE chunk skips the
+                # prefill pool entirely (the matched span never prefills
+                # at all — the composition the radix cache buys), and a
+                # penalty request must build its on-device token counts
+                # on the slot that will decode it (it bypasses the
+                # prefix cache for the same reason, so matched == 0).
+                direct = (req.presence_penalty != 0
+                          or req.frequency_penalty != 0
+                          or (matched > 0
+                              and len(req.prompt_ids) - matched
+                              <= self.prefill_chunk))
+                if not direct and not cold_ok:
+                    # Cold prompt but no prefill slot (or no decode slot
+                    # to reserve): park at the FIFO head, exactly like a
+                    # page-reservation shortfall.
+                    if cache is not None:
+                        cache.release_nodes(nodes)
+                    break
+            if self._disagg is None:
+                target_pool = self._admit_pool
+                req.slot = target_pool.take()
+            elif direct:
+                target_pool = self._decode_pool
+                req.slot = target_pool.take()
+                req.decode_slot = req.slot
+            else:
+                target_pool = self._admit_pool
+                req.slot = target_pool.take()
+                req.decode_slot = self._decode_pool.take()  # reservation
+            req.pool = target_pool.pool_id
+            target_pool.admits += 1
+            self._head = None
+            # Queue-wait gauge (submit → slot admission): the scheduler
+            # half of TTFT — what the prefill-aware burst clamp bounds.
+            # t_admitted also closes the trace's engine.queued phase.
+            req.t_admitted = time.monotonic()
+            wait_ms = 1000.0 * (req.t_admitted - req.t_submit)
+            self._queue_wait_n += 1
+            self._queue_wait_ema_ms = (
+                wait_ms if self._queue_wait_ema_ms is None
+                else 0.8 * self._queue_wait_ema_ms + 0.2 * wait_ms)
+            self._queue_wait_max_ms = max(self._queue_wait_max_ms, wait_ms)
+            if self.spec_k:
+                # New text in this slot: acceptance starts unmeasured.
+                # (Reset at ADMISSION, not release, so stats keep the last
+                # measured rate while the engine drains/idles.) The
+                # per-slot suspension lifts with it — the new request's
+                # text regime owes nothing to its predecessor's.
+                self._spec_ema[req.slot] = np.nan
+                self._spec_suspended[req.slot] = False
+                self._spec_slot_proposed[req.slot] = 0
+                self._spec_slot_accepted[req.slot] = 0
+            if self.paged:
+                self.allocator.allocate(req.slot, total,
+                                        ring_pages=self._swa_ring_pages,
+                                        shared_pages=shared_pages)
+                self._table_dirty = True
+                if self._prefix_cache is not None:
+                    self._prefix_cache.record_lookup(matched)
+                    req.cached_tokens = matched
+                    req.prefix_nodes = nodes
+                if matched and self.spec_k:
+                    # Prompt-lookup history for the skipped span: the
+                    # per-chunk maintenance only covers chunks that
+                    # actually run, and its pos==0 reset never fires on a
+                    # warm admission.
+                    self.hist[req.slot, :] = 0
+                    self.hist[req.slot, :matched] = req.prompt_ids[:matched]
+            # Warm admission starts prefill at the match boundary — the
+            # matched span's prefill FLOPs are skipped outright (the
+            # chunk's attention reads the shared pages through the table,
+            # exactly like a later chunk of a cold prefill).
+            req.prefill_pos = req.cached_tokens
+            self._running[req.slot] = req
+            self._prefilling[req.slot] = req
+            if fl is not None:
+                from ..obs.flight import ADMIT
+                req.flight_admit_seq = fl.record(
+                    ADMIT, slot=req.slot, val=wait_ms,
+                    tokens=req.cached_tokens,
+                    queued=self._queue.qsize() + (1 if self._head else 0),
+                    free_slots=self._free_slot_count(),
+                    free_pages=(self.allocator.free_pages if self.paged
+                                else -1),
+                    pool=req.pool,
+                    rid=req.request_id or None)
+
     # -- compute (worker thread; no asyncio objects touched) ------------------
     def _prefill_one_chunk(self, req: GenRequest) -> bool:
         """Run one prompt chunk; returns True when the prompt is complete
@@ -2409,6 +2462,7 @@ class InferenceEngine:
             i += k
         return out
 
+    @_device_phase("sched.prefill_group")
     def _prefill_chunk_group(self, reqs: list[GenRequest]) -> list[bool]:
         """Advance each request by one prompt chunk in ONE compiled call
         (K=1 is the single-request path): K queued prefills pay one
@@ -2464,7 +2518,8 @@ class InferenceEngine:
             # inputs and never fetch; the real token reaches them inside
             # the next decode burst's broadcast state.
             if first_np is None:
-                first_np = np.asarray(first)
+                with _device_phase("sched.fetch"):
+                    first_np = np.asarray(first)
             first_id = int(first_np[i])
             req.generated.append(first_id)
             req.t_first_token = time.monotonic()
@@ -2547,10 +2602,12 @@ class InferenceEngine:
                 kname, "prefill", variant={"bucket": int(bucket), "k": K},
                 cost_fn=_kernel_cost_fn(self._prefill_fn, args))
         t0 = time.monotonic()
-        with _device_phase("prefill", annotate=self.profile_annotations):
+        with _device_phase("prefill"):
             first, self._d_counts, cache = self._prefill_fn(*args)
-        self.kernels.record(kname,
-                            wall_ms=1000.0 * (time.monotonic() - t0))
+        t1 = time.monotonic()
+        self.kernels.record(kname, wall_ms=1000.0 * (t1 - t0))
+        self._last_prefill = (K, int(bucket), sum(len(ch) for ch in chunks),
+                              int(min(poss)), int(max(poss)), t0, t1)
         return first, cache
 
     def _kernel_variant(self, **base) -> dict:
@@ -2588,24 +2645,28 @@ class InferenceEngine:
         step_fn, scans = self._decode_fns[greedy]
         scan_fn = scans.get(n_steps)
         if scan_fn is not None:
-            toks, _, _, self._d_counts, self.cache = scan_fn(
-                self.params, self.cache, self._d_counts, *table, tokens,
-                lengths, active, samp, key)
-            host = np.asarray(toks)
+            with _device_phase("decode"):
+                toks, _, _, self._d_counts, self.cache = scan_fn(
+                    self.params, self.cache, self._d_counts, *table, tokens,
+                    lengths, active, samp, key)
+            with _device_phase("sched.fetch"):
+                host = np.asarray(toks)
             return [host[i] for i in range(n_steps)]
         # Feedback stays as device arrays across the chain (outputs are
         # pinned replicated, so the final fetches are process-local); only
         # the sampled tokens are pulled to host, asynchronously behind the
         # dispatch wave — same policy as the single-process path.
         pending = []
-        for _ in range(n_steps):
-            key, sub = jax.random.split(key)
-            tokens, lengths, self._d_counts, self.cache = step_fn(
-                self.params, self.cache, self._d_counts, *table, tokens,
-                lengths, active, samp, sub)
-            _start_host_copy(tokens)
-            pending.append(tokens)
-        return [np.asarray(t) for t in pending]
+        with _device_phase("decode"):
+            for _ in range(n_steps):
+                key, sub = jax.random.split(key)
+                tokens, lengths, self._d_counts, self.cache = step_fn(
+                    self.params, self.cache, self._d_counts, *table, tokens,
+                    lengths, active, samp, sub)
+                _start_host_copy(tokens)
+                pending.append(tokens)
+        with _device_phase("sched.fetch"):
+            return [np.asarray(t) for t in pending]
 
     def _table_to_publish(self) -> np.ndarray | None:
         """Coordinator side: the page table, but only when it changed since
@@ -2728,6 +2789,7 @@ class InferenceEngine:
             return np.ones((self.B,), bool)
         return ~self._spec_suspended
 
+    @_device_phase("sched.spec_burst")
     def _spec_burst(self, n_steps: int,
                     probe: bool = False) -> list[np.ndarray]:
         """Run `n_steps` speculative draft+verify steps (engine/
@@ -2798,8 +2860,7 @@ class InferenceEngine:
                 self.kernels.register(
                     kname, "spec", variant=self._kernel_variant(depth=n_steps),
                     cost_fn=_kernel_cost_fn(self._spec_scan, args))
-            with _device_phase("spec.verify",
-                               annotate=self.profile_annotations):
+            with _device_phase("spec.verify"):
                 emitted, self.cache, self._d_hist, self._d_tokens, \
                     self._d_lengths = self._spec_scan(*args)
                 _start_host_copy(emitted)
@@ -2832,7 +2893,7 @@ class InferenceEngine:
         outs = []
         kname = "spec.step1"
         t0 = time.monotonic()
-        with _device_phase("spec.verify", annotate=self.profile_annotations):
+        with _device_phase("spec.verify"):
             for _ in range(n_steps):
                 args = (self.params, self.cache, *table, self._d_hist,
                         self._d_tokens, self._d_lengths, self._d_active,
@@ -2845,7 +2906,8 @@ class InferenceEngine:
                     em, _ = self._spec_step(*args)
                 _start_host_copy(em)
                 outs.append(em)
-            host = np.stack([np.asarray(e) for e in outs])
+            with _device_phase("sched.fetch"):
+                host = np.stack([np.asarray(e) for e in outs])
         self.kernels.record(kname, steps=n_steps,
                             wall_ms=1000.0 * (time.monotonic() - t0))
         return pre + self._spec_walk(host, self.active, self.active.copy(),
@@ -2894,21 +2956,25 @@ class InferenceEngine:
         d_ok_dev = self._upload(draft_ok)
         table = (self._device_table(),) if self.paged else ()
         if n_steps == self._spec_scan_len:
-            emitted, self.cache, self._d_hist, self._d_tokens, \
-                self._d_lengths = self._spec_scan(
-                    self.params, self.cache, *table, self._d_hist,
-                    self._d_tokens, self._d_lengths, self._d_active,
-                    d_ok_dev)
-            return np.asarray(emitted)
+            with _device_phase("spec.verify"):
+                emitted, self.cache, self._d_hist, self._d_tokens, \
+                    self._d_lengths = self._spec_scan(
+                        self.params, self.cache, *table, self._d_hist,
+                        self._d_tokens, self._d_lengths, self._d_active,
+                        d_ok_dev)
+            with _device_phase("sched.fetch"):
+                return np.asarray(emitted)
         outs = []
-        for _ in range(n_steps):
-            self._d_tokens, self._d_lengths, self.cache, self._d_hist, \
-                em, _ = self._spec_step(
-                    self.params, self.cache, *table, self._d_hist,
-                    self._d_tokens, self._d_lengths, self._d_active,
-                    d_ok_dev)
-            outs.append(em)
-        return np.stack([np.asarray(e) for e in outs])
+        with _device_phase("spec.verify"):
+            for _ in range(n_steps):
+                self._d_tokens, self._d_lengths, self.cache, self._d_hist, \
+                    em, _ = self._spec_step(
+                        self.params, self.cache, *table, self._d_hist,
+                        self._d_tokens, self._d_lengths, self._d_active,
+                        d_ok_dev)
+                outs.append(em)
+        with _device_phase("sched.fetch"):
+            return np.stack([np.asarray(e) for e in outs])
 
     def _spec_wall_loses(self) -> bool:
         """True when the measured spec wall-clock (ms per emitted token,
@@ -3010,7 +3076,8 @@ class InferenceEngine:
         if entry is None:
             return []
         emitted, _, active_snap, epoch_snap, drafting = entry
-        host = np.asarray(emitted)                       # [n, B, k+1]
+        with _device_phase("sched.fetch"):
+            host = np.asarray(emitted)                   # [n, B, k+1]
         live = active_snap & (epoch_snap == self._slot_epoch)
         return self._spec_walk(host, active_snap, live, drafting=drafting)
 
@@ -3102,7 +3169,8 @@ class InferenceEngine:
         if entry is None:
             return []
         toks_dev, n, active_snap, epoch_snap, len_snap, last_snap = entry
-        host = np.asarray(toks_dev)                      # [n, B]
+        with _device_phase("sched.fetch"):
+            host = np.asarray(toks_dev)                  # [n, B]
         live = active_snap & (epoch_snap == self._slot_epoch)
         for slot in np.nonzero(live)[0]:
             self.last_token[slot] = int(host[-1][slot])
@@ -3229,6 +3297,7 @@ class InferenceEngine:
         self._depth_hist[pick] = self._depth_hist.get(pick, 0) + 1
         return pick
 
+    @_device_phase("sched.decode_burst")
     def _decode_burst(self, n_steps: int) -> list[np.ndarray]:
         """Run `n_steps` chained decode steps; tokens/lengths feed back as
         device arrays (no host round-trip inside the chain) and each step's
@@ -3316,7 +3385,7 @@ class InferenceEngine:
                     kname, "decode",
                     variant=self._kernel_variant(depth=n_steps, greedy=greedy),
                     cost_fn=_kernel_cost_fn(scan_fn, args))
-            with _device_phase("decode", annotate=self.profile_annotations):
+            with _device_phase("decode"):
                 toks, self._d_tokens, self._d_lengths, self._d_counts, \
                     self.cache = scan_fn(*args)
                 _start_host_copy(toks)
@@ -3360,7 +3429,7 @@ class InferenceEngine:
         pending: list[jax.Array] = []
         kname = f"decode.step1.{'greedy' if greedy else 'sampled'}"
         t0 = time.monotonic()
-        with _device_phase("decode", annotate=self.profile_annotations):
+        with _device_phase("decode"):
             for _ in range(n_steps):
                 self._rng, key = jax.random.split(self._rng)
                 args = (self.params, self.cache, self._d_counts, *table,
@@ -3375,7 +3444,8 @@ class InferenceEngine:
                     self.cache = step_fn(*args)
                 _start_host_copy(self._d_tokens)
                 pending.append(self._d_tokens)
-            step_tokens = [np.asarray(t) for t in pending]
+            with _device_phase("sched.fetch"):
+                step_tokens = [np.asarray(t) for t in pending]
         # The fetch above synchronizes, so this wall is honest per call.
         self.kernels.record(kname, steps=n_steps,
                             wall_ms=1000.0 * (time.monotonic() - t0))
@@ -3785,10 +3855,15 @@ class InferenceEngine:
         out["watermark_sheds"] = self._watermark_sheds
         if self._prewarm_error is not None:
             out["prewarm_error"] = self._prewarm_error
+        # The scheduler's time ledger: sched_<phase>_ms_total, ten
+        # monotone counters over the loop's wall since it started.
+        out.update(self._sched.stats())
         from ..obs.device import compile_monitor
         cm = compile_monitor().stats()
-        out["xla_compile_total"] = cm["xla_compile_total"]
-        out["xla_compile_seconds"] = cm["xla_compile_seconds"]
+        for key in ("xla_compile_total", "xla_compile_seconds",
+                    "xla_compile_by_phase", "xla_trace_total",
+                    "xla_trace_ms_total", "xla_trace_by_phase"):
+            out[key] = cm[key]
         if self.spec_k:
             out["spec_draft_len"] = self.spec_k
             # Speculative acceptance telemetry (ROADMAP item 3 stub):

@@ -50,6 +50,7 @@ callables the engine provides.
 from __future__ import annotations
 
 import contextlib
+import contextvars
 import logging
 import math
 import threading
@@ -436,6 +437,27 @@ def worst_kernel(rows: list[dict], min_share_pct: float = 5.0
 
 _phase_local = threading.local()
 
+# The scheduler wait in flight, as its worker thread sees it (obs/phases.py
+# sets it around each ``asyncio.to_thread``, which copies the context).
+# None on every other thread and for direct callers of a worker function.
+worker_call: contextvars.ContextVar[Any] = contextvars.ContextVar(
+    "sched_worker_call", default=None)
+
+
+def worker_kind(span: str) -> str | None:
+    """Which of the scheduler's worker counters (obs/phases.py) a span's
+    wall belongs to. ``sched.fetch`` is a blocking device→host read;
+    ``prefill`` / ``decode`` / ``spec.*`` are the jitted calls with their
+    argument build; any other ``sched.*`` span entered on the worker is its
+    outermost one, where its own wall begins."""
+    if span == "sched.fetch":
+        return "fetch"
+    if span in ("prefill", "decode") or span.startswith("spec."):
+        return "dispatch"
+    if span.startswith("sched."):
+        return "worker_other"
+    return None
+
 
 def current_phase() -> str:
     """The phase tag of the calling thread ("" outside any phase) — what
@@ -446,13 +468,17 @@ def current_phase() -> str:
 @contextlib.contextmanager
 def phase(name: str, annotate: bool = True):
     """Tag the calling thread with a scheduler phase and (when ``annotate``)
-    emit a ``jax.profiler.TraceAnnotation`` so on-demand captures segment
-    by phase in Perfetto. The tag always applies — compile attribution
-    must work even with annotations off; the TraceAnnotation is the only
-    part the ``profile_annotations`` knob (and the bench's annotation A/B
-    rung) toggles."""
+    emit a ``jax.profiler.TraceAnnotation`` so captures segment by phase
+    on the profiler's clock. The tag always applies — compile attribution
+    must work for ``cost_analysis``, the one caller that turns the
+    annotation off. Inside a scheduler wait the span's wall is also the
+    worker counter ``worker_kind`` names, so a span and its counter cannot
+    disagree."""
     prev = getattr(_phase_local, "name", "")
     _phase_local.name = name
+    call = worker_call.get()
+    kind = worker_kind(name) if call is not None else None
+    prev_kind = call.switch(kind) if kind is not None else None
     ctx = None
     if annotate:
         try:
@@ -469,6 +495,8 @@ def phase(name: str, annotate: bool = True):
                 ctx.__exit__(None, None, None)
             except Exception:
                 logger.debug("TraceAnnotation exit failed", exc_info=True)
+        if prev_kind is not None:
+            call.switch(prev_kind)
         _phase_local.name = prev
 
 
@@ -477,9 +505,21 @@ def phase(name: str, annotate: bool = True):
 # ---------------------------------------------------------------------------
 
 # The jax.monitoring event fired once per backend (XLA) compile, with its
-# wall seconds. Trace/lower phases fire their own events; backend compile
-# is the expensive one and the only one that implies a new executable.
+# wall seconds: the expensive one, and the only one that implies a new
+# executable (it fires on a persistent-cache hit too, the read inside it).
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# What a first call pays BEFORE (or without) a backend compile: tracing the
+# function to a jaxpr, lowering it, reading the persistent cache. A retrace
+# whose lowering is already cached fires the first alone — invisible to
+# xla_compile_total, and a 32-layer program holds every Python thread
+# while it traces. (jax 0.9: jax/_src/dispatch.py, jax/_src/compiler.py;
+# compile_time_saved_sec is a saving, not a wall, and is left out.)
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_TRACE_EVENTS = frozenset({
+    _TRACE_EVENT,
+    "/jax/core/compile/jaxpr_to_mlir_module_duration",
+    "/jax/compilation_cache/cache_retrieval_time_sec",
+})
 
 
 class XlaCompileMonitor:
@@ -495,6 +535,22 @@ class XlaCompileMonitor:
         self._total = 0                          # guarded-by: _lock
         self._total_s = 0.0                      # guarded-by: _lock
         self._last: dict[str, Any] | None = None  # guarded-by: _lock
+        self._trace_by_phase: dict[str, list] = {}  # guarded-by: _lock
+        self._traces = 0                         # guarded-by: _lock
+        self._trace_s = 0.0                      # guarded-by: _lock
+
+    def on_trace(self, event: str, dur_s: float) -> None:
+        """A trace, lowering or cache-read event: seconds under the phase
+        that paid for them, counted once per traced function (a nested
+        jit's trace lies inside its caller's and counts again)."""
+        ph = current_phase() or "startup"
+        with self._lock:
+            slot = self._trace_by_phase.setdefault(ph, [0, 0.0])
+            if event == _TRACE_EVENT:
+                slot[0] += 1
+                self._traces += 1
+            slot[1] += dur_s
+            self._trace_s += dur_s
 
     def on_compile(self, dur_s: float) -> None:
         ph = current_phase() or "startup"
@@ -533,6 +589,11 @@ class XlaCompileMonitor:
             }
             if self._last is not None:
                 out["xla_compile_last"] = dict(self._last)
+            out["xla_trace_total"] = self._traces
+            out["xla_trace_ms_total"] = round(1e3 * self._trace_s, 3)
+            out["xla_trace_by_phase"] = {
+                ph: {"count": c, "ms": round(1e3 * s, 3)}
+                for ph, (c, s) in sorted(self._trace_by_phase.items())}
             return out
 
 
@@ -567,6 +628,8 @@ def install_compile_monitor() -> XlaCompileMonitor:
         def listener(name: str, dur_s: float, **kw) -> None:
             if name == _COMPILE_EVENT:
                 mon.on_compile(dur_s)
+            elif name in _TRACE_EVENTS:
+                mon.on_trace(name, dur_s)
         monitoring.register_event_duration_secs_listener(listener)
     except Exception:       # proxy-only deployment without JAX
         logger.debug("jax.monitoring unavailable; compile telemetry off",

@@ -49,10 +49,11 @@ SHED = 4          # admission refused on a full queue (gateway 429 path)
 EVICT = 5         # prefix-cache eviction under page pressure
 PROF = 6          # profiler capture start/stop (ISSUE 8): rid = trace dir
 SUPERVISOR = 7    # engine lifecycle transition (ISSUE 14): flag = state
+PREFILL = 8       # one compiled prefill dispatch (ISSUE 26): what it ran
 
 KIND_NAMES = {STEP: "step", ADMIT: "admit", FINISH: "finish",
               SHED: "shed", EVICT: "evict", PROF: "profile",
-              SUPERVISOR: "supervisor"}
+              SUPERVISOR: "supervisor", PREFILL: "prefill"}
 
 # SUPERVISOR flag values: index into this tuple = the state entered.
 # Mirrors reliability/supervisor.py LIFECYCLE_STATES (order matters —
@@ -90,19 +91,23 @@ _DTYPE = np.dtype([
     ("flag", np.uint8),         # STEP: F_* bits; FINISH: reason code
     ("slot", np.int16),         # lifecycle records; -1 = n/a
     ("depth", np.int16),        # decode burst depth (STEP) / group K
+                                # (PREFILL: rows in the compiled call)
     ("tokens", np.int32),       # tokens emitted (STEP) / generated (FINISH)
+                                # / prompt tokens in the call (PREFILL)
     ("chunks", np.int16),       # prefill chunk dispatches this step
     ("active", np.int16),       # running requests after the step
     ("free_slots", np.int16),
     ("queued", np.int16),       # admission queue depth (+ parked head)
     ("free_pages", np.int32),   # paged pool headroom; -1 = dense layout
+                                # (PREFILL: lowest start position)
     ("fitted_ms", np.float32),  # engine's fitted per-step time (NaN unset)
     ("val", np.float32),        # kind-specific: decode-burst wall ms
                                 # (STEP), queue-wait ms (ADMIT), pages
-                                # evicted (EVICT)
+                                # evicted (EVICT), chunk bucket (PREFILL)
     ("spec_acc", np.int32),     # SPEC steps: accepted draft tokens this
                                 # burst (tokens - spec_acc = what a plain
                                 # burst of the same depth would have made)
+                                # (PREFILL: highest start position)
     ("pool", np.uint8),         # POOL_* tag; 0 = unified scheduler
 ])
 
@@ -154,13 +159,15 @@ class FlightRecorder:
                queued: int = 0, free_pages: int = -1,
                fitted_ms: float = math.nan, val: float = 0.0,
                spec_acc: int = 0, pool: int = 0,
-               rid: str | None = None) -> int:
+               rid: str | None = None, t: float | None = None) -> int:
         """Append one record; returns its sequence number. Scalar stores
-        into preallocated storage only — no per-record allocation."""
+        into preallocated storage only — no per-record allocation. ``t``
+        is the record's end time when it is not now (a PREFILL record is
+        written loop-side after the worker's dispatch ended)."""
         i = self._seq % self.capacity
         cols = self._cols
         cols["seq"][i] = self._seq
-        cols["t"][i] = self.clock()
+        cols["t"][i] = self.clock() if t is None else t
         cols["dur_ms"][i] = dur_ms
         cols["kind"][i] = kind
         cols["flag"][i] = flag
@@ -257,6 +264,16 @@ class FlightRecorder:
                 d["pages_evicted"] = int(row["val"])
                 if row["free_pages"] >= 0:
                     d["free_pages"] = int(row["free_pages"])
+            elif kind == PREFILL:
+                # One compiled prefill dispatch (ISSUE 26): its shape and
+                # where in their prompts its rows started; [t - dur_ms, t]
+                # is the jitted call on the worker thread. What a
+                # paged-prefill roofline needs per call.
+                d["rows"] = int(row["depth"])
+                d["bucket"] = int(row["val"])
+                d["tokens"] = int(row["tokens"])
+                d["pos_lo"] = int(row["free_pages"])
+                d["pos_hi"] = int(row["spec_acc"])
             elif kind == PROF:
                 # Profiler capture boundary (ISSUE 8): the rid carries
                 # the capture's trace directory, so a Perfetto timeline

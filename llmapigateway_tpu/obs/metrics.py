@@ -575,6 +575,14 @@ class GatewayMetrics:
             "gateway_engine_xla_compile_seconds",
             "Cumulative backend-compile wall seconds, by phase.",
             ("phase",))
+        # The scheduler's time ledger (ISSUE 26; obs/phases.py): the
+        # engine loop's wall since it started, partitioned.
+        self.engine_sched_phase_ms_total = r.gauge(
+            "gateway_engine_sched_phase_ms_total",
+            "Milliseconds of the engine loop's wall under each scheduler "
+            "phase: parked / admit / prefill_wait / decode_wait / emit / "
+            "other sum to the loop's wall; hop / dispatch / fetch / "
+            "worker_other sum to the two waits.", ("engine", "phase"))
 
         # -- SLO / goodput attribution plane (ISSUE 7; obs/slo.py) ------------
         self.slo_met_total = r.counter(

@@ -20,6 +20,7 @@ import logging
 from aiohttp import web
 
 from ..obs.metrics import GatewayMetrics
+from ..obs.phases import LOOP_PHASES, WORKER_PHASES
 
 logger = logging.getLogger(__name__)
 
@@ -122,6 +123,11 @@ def make_stats_collector(gw) -> "callable":
                             getattr(metrics, attr).labels(
                                 engine=name, pool=pool_name).set(
                                     val * scale)
+            for ph in LOOP_PHASES + WORKER_PHASES:
+                val = stats.get(f"sched_{ph}_ms_total")
+                if isinstance(val, (int, float)):
+                    metrics.engine_sched_phase_ms_total.labels(
+                        engine=name, phase=ph).set(val)
             total = stats.get("total_pages")
             free = stats.get("free_pages")
             if isinstance(total, (int, float)) and total > 0 \

@@ -28,6 +28,8 @@ Tracks per engine (one trace-event process):
   the state entered (``supervisor:restarting``, ``supervisor:draining``)
   so an incident's RESTART/DRAIN edges bracket the steps they
   interrupted;
+* ``prefill dispatch`` — one slice per compiled prefill call (ISSUE 26's
+  PREFILL record), named ``prefill[rows x bucket]@lowest-highest start``;
 * ``slot N`` — one slice per request's residency in a slot, from its
   admit record to its finish record, named by request id.
 
@@ -48,6 +50,8 @@ TID_SLOT_BASE = 2
 # Per-pool scheduler lanes (ISSUE 13): far above any real slot index so
 # slot tracks and pool tracks can never collide in one process.
 TID_POOL_BASE = 10000
+# Compiled prefill dispatches on the worker thread (ISSUE 26).
+TID_PREFILL = 9999
 POOL_LANE_ORDER = ("prefill", "decode", "unified")
 
 
@@ -87,6 +91,7 @@ def engine_events(engine: str, records: list[dict[str, Any]],
     admits: dict[str, dict[str, Any]] = {}      # rid -> admit record
     slots_seen: set[int] = set()
     pools_seen: set[str] = set()
+    prefill_seen = False
     for rec in records:
         kind = rec.get("kind")
         dur_us = int(round(float(rec.get("dur_ms", 0.0)) * 1000.0))
@@ -103,6 +108,20 @@ def engine_events(engine: str, records: list[dict[str, Any]],
                 "ph": "X", "pid": pid, "tid": tid,
                 "name": _step_name(rec), "cat": "step",
                 "ts": us(rec["t"]) - dur_us, "dur": dur_us,
+                "args": {k: v for k, v in rec.items() if k != "t"},
+            })
+            continue
+        if kind == "prefill":
+            # One compiled prefill dispatch (ISSUE 26): a slice over the
+            # jitted call, named by its shape and start positions.
+            prefill_seen = True
+            events.append({
+                "ph": "X", "pid": pid, "tid": TID_PREFILL,
+                "name": "prefill[%sx%s]@%s-%s" % (
+                    rec.get("rows"), rec.get("bucket"), rec.get("pos_lo"),
+                    rec.get("pos_hi")),
+                "cat": "prefill", "ts": us(rec["t"]) - dur_us,
+                "dur": dur_us,
                 "args": {k: v for k, v in rec.items() if k != "t"},
             })
             continue
@@ -158,6 +177,9 @@ def engine_events(engine: str, records: list[dict[str, Any]],
         if slot >= 0:
             events.append(_meta(pid, TID_SLOT_BASE + slot, "thread_name",
                                 f"slot {slot}"))
+    if prefill_seen:
+        events.append(_meta(pid, TID_PREFILL, "thread_name",
+                            "prefill dispatch"))
     for pool in sorted(pools_seen):
         tid = TID_POOL_BASE + (POOL_LANE_ORDER.index(pool)
                                if pool in POOL_LANE_ORDER
