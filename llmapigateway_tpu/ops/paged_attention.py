@@ -18,12 +18,21 @@ Layout:
   page. Unallocated entries are 0 (trash) and are never read: reads are
   bounded by ``n_valid``.
 
-Kernel structure mirrors ops/flash_attention.py (online-softmax fp32
+Two kernels, two shapes. The PREFILL kernel mirrors
+ops/flash_attention.py (a grid over key blocks, online-softmax fp32
 scratch, ``pl.when`` compute skip) with one addition: the K/V BlockSpec
 index maps translate logical → physical through the scalar-prefetched page
 table, *and* clamp to the last live logical page so dead iterations repeat
-a block index and their HBM→VMEM DMA is elided. That makes decode cost
-proportional to live tokens, not ``S_max`` — the ragged property.
+a block index and their HBM→VMEM DMA is elided. Their DMA, not their grid
+step: a grid step has a fixed price (~0.2–0.4 us on a v5e) whatever it
+moves, and the DECODE kernel used to pay it ``B × KV × NP`` times a call —
+2 048 steps for some tens of live ones, 415 us against a byte floor of
+21 (PERF.md, PR 27). So the decode kernel has no page axis in its grid: a
+program walks each slot's LIVE blocks and only those, copying a block —
+a run of pages for as many of their KV heads as fit a VMEM budget — from
+the pool in HBM into one of two buffers while it attends the one before.
+Its cost is the live tokens' bytes plus a few microseconds a slot — the
+ragged property, by construction and not by elision.
 
 The adapter :func:`make_paged_attention_fn` is built INSIDE the engine's
 jitted step (closing over the traced page table), so ``llama.forward``
@@ -41,8 +50,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..models.config import ModelConfig
-from .flash_attention import (attend_block, self_column_init, shard_map,
-                              unpack_kv_refs)
+from .flash_attention import attend_block, shard_map, unpack_kv_refs
 
 NEG_INF = -1e30
 
@@ -201,54 +209,194 @@ def paged_insert_all(pool_k, pool_v,
 # Decode kernel: q [B, KV, G, Dh] vs pages [P, KV, page, Dh]
 # ---------------------------------------------------------------------------
 
+# What the decode kernel's two K + V (+ scale) buffers may take of VMEM.
+# A buffer holds one block: a run of pages for as many of their KV heads
+# as fit (int8, page 256, Dh 128, 8 heads: 1.25 MiB for both buffers of
+# both sides — all of them). A quarter of the 16 MiB a v5e kernel may
+# use, so the q/out blocks and the body's temporaries (the heads' K and
+# V converted for the dots) have room.
+_DECODE_KV_VMEM_BYTES = 4 * 2 ** 20
+
+
+def _decode_heads_per_block(KV: int, page: int, Dh: int, itemsize: int,
+                            quant: bool, ppb: int) -> int:
+    """How many of a page's KV heads one block of the paged decode kernel
+    holds — pure shape arithmetic over what the call sees: the largest
+    divisor of the local ``KV`` whose K and V buffers ``(ppb, heads, page,
+    Dh)``, two of each, fit ``_DECODE_KV_VMEM_BYTES`` (an int8 pool adds
+    its f32 scale planes, whose unit dim pads to 8 sublanes in VMEM). A
+    block too large for the budget still holds one head."""
+    per_head = ppb * page * Dh * itemsize
+    if quant:
+        per_head += ppb * 8 * page * 4
+    per_head *= 2 * 2                                # K and V, two buffers
+    return max([d for d in range(1, KV + 1)
+                if KV % d == 0 and d * per_head <= _DECODE_KV_VMEM_BYTES],
+               default=1)
+
+
+def _decode_live_blocks(n_valid, bs: int, window: int, n_table_blocks: int):
+    """(first, last) live BLOCK (run of ``bs`` tokens) for a query at
+    position ``n_valid``: the blocks that hold stale keys ``p`` with
+    ``n_valid - p < window`` (``ceil((window - 1) / bs) + 1`` of them at
+    most, however the window is aligned). ``last`` is clamped into the
+    table, so a fresh slot (nothing stale, only the self column counts)
+    still names a block; flash_attention._live_range is the dense twin."""
+    last = jnp.clip((n_valid + bs - 1) // bs - 1, 0, n_table_blocks - 1)
+    if window:
+        first = jnp.minimum(jnp.maximum(n_valid - (window - 1), 0) // bs,
+                            last)
+    else:
+        first = 0
+    return first, last
+
+
+def _attend_heads(q, k, v, ks, vs, mask, m, l, acc):
+    """One online-softmax update of EVERY folded head against one page:
+    flash_attention.attend_block's arithmetic with the heads as the
+    leading batch dimension of both dots (``q`` [heads, G, Dh], ``k``/``v``
+    [heads, page, Dh], int8 scales ``ks``/``vs`` [heads, 1, page] or None,
+    state ``m``/``l`` [heads, G, 1] and ``acc`` [heads, G, Dh]). Per head
+    the operations and their order are attend_block's — int8 K cast to
+    q's dtype for one native MXU pass, the K scale on the scores after the
+    QK dot, the V scale on the probabilities after ``l`` accumulates —
+    so a head's result does not depend on how many heads share the call.
+    On the chip the batched form is what pays: eight heads' dots issued
+    as one op ran 3.6x faster than eight unrolled (PERF.md, PR 27)."""
+    if k.dtype == jnp.int8:
+        k = k.astype(q.dtype)
+    else:
+        q = q.astype(jnp.float32)
+        k = k.astype(jnp.float32)
+    scores = jax.lax.dot_general(
+        q, k, (((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)        # [heads, G, page]
+    scores *= q.shape[-1] ** -0.5
+    if ks is not None:
+        scores = scores * ks
+    scores = mask(scores)
+    m_new = jnp.maximum(m, jnp.max(scores, axis=2, keepdims=True))
+    alpha = jnp.exp(m - m_new)
+    e = jnp.exp(scores - m_new)
+    l = alpha * l + jnp.sum(e, axis=2, keepdims=True)
+    p = e if vs is None else e * vs
+    acc = acc * alpha + jax.lax.dot_general(
+        p, v.astype(jnp.float32), (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)        # [heads, G, Dh]
+    return m_new, l, acc
+
+
 def _paged_decode_kernel(pt_ref, nvalid_ref, q_ref, kn_ref, vn_ref,
-                         *refs, page: int, window: int = 0,
-                         pages_per_block: int = 1):
-    k_ref, ks_ref, v_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = \
-        unpack_kv_refs(refs)
-    b = pl.program_id(0)
-    j = pl.program_id(2)        # run of `pages_per_block` logical pages
-    n_pb = pl.num_programs(2)
+                         *refs, page: int, window: int,
+                         pages_per_block: int, n_table_blocks: int):
+    """One program per group of folded heads walks EVERY slot's live
+    blocks, and only those: the pools stay in HBM and each block is copied
+    into one of two VMEM buffers while the block before it is attended.
+    The walk is one sequence over (slot, block) — a slot's last block
+    prefetches the next slot's first — so the copy engine idles only on
+    the call's very first block. ``refs``: the pool sides in HBM (K, V;
+    int8: K, its scale plane, V, its scale plane), the output block, a
+    VMEM buffer pair per pool side in the same order, and the DMA
+    semaphores ``[buffer, side]``."""
+    n_sides = (len(refs) - 2) // 2       # K, V (int8: + their scale planes)
+    pools, o_ref = refs[:n_sides], refs[n_sides]
+    bufs, sem = refs[n_sides + 1:-1], refs[-1]
+    if n_sides == 4:
+        k_buf, ks_buf, v_buf, vs_buf = bufs
+    else:
+        (k_buf, v_buf), ks_buf, vs_buf = bufs, None, None
+    hb = pl.program_id(0)
+    B, heads = q_ref.shape[0], q_ref.shape[1]
+    ppb = pages_per_block
 
-    @pl.when(j == 0)
-    def _init():
-        self_column_init(q_ref, kn_ref, vn_ref, m_ref, l_ref, acc_ref)
+    def live(b):
+        n_valid = nvalid_ref[b]
+        first, last = _decode_live_blocks(n_valid, ppb * page, window,
+                                          n_table_blocks)
+        return n_valid, first, last - first + 1        # >= 1 block
 
-    n_valid = nvalid_ref[b]
-    # Sliding window (ops/flash_attention.py _decode_kernel is the dense
-    # twin): the query at position n_valid sees stale keys p with
-    # n_valid - p < window, i.e. p >= w0. Pages wholly below w0 skip
-    # compute here AND their HBM→VMEM DMA (the index-map clamp makes them
-    # repeat an in-window physical page) — a windowed paged decode reads
-    # O(window) pages, not O(context): SWA's whole point, compounded.
-    w0 = jnp.maximum(n_valid - (window - 1), 0) if window else 0
-    # Per-page attends over the block's sub-pages, unrolled
-    # (pages_per_block is compile-time): the SAME online-softmax updates
-    # in the SAME order as the per-page kernel, so any pages_per_block is
-    # bit-for-bit with 1 — only the HBM→VMEM DMA granularity changes
-    # (one (ppb·page, Dh) copy instead of ppb (page, Dh) copies).
-    for i in range(pages_per_block):
-        lp = j * pages_per_block + i                   # logical page
-        live = lp * page < n_valid
-        if window:
-            live = live & ((lp + 1) * page > w0)
+    def copies(b, blk, buf):
+        # Gather-free: ONE table lookup per block. The packed-table
+        # promise makes a run's ppb physical pages contiguous from its
+        # first, so one copy per pool side moves the whole run for the
+        # folded heads.
+        p0 = pt_ref[b, blk * ppb]
+        return [pltpu.make_async_copy(
+            pool.at[pl.ds(p0, ppb), pl.ds(hb * heads, heads)],
+            vmem.at[buf], sem.at[buf, side])
+            for side, (pool, vmem) in enumerate(zip(pools, bufs))]
 
-        @pl.when(live)
-        def _block(i=i, lp=lp):
-            def mask(scores):
-                pos = lp * page + jax.lax.broadcasted_iota(
-                    jnp.int32, scores.shape, 1)
-                ok = pos < n_valid
+    for c in copies(0, live(0)[1], 0):
+        c.start()
+
+    def slot(b, walked):
+        n_valid, first, n_blocks = live(b)
+        # Sliding window (ops/flash_attention.py _decode_kernel is the
+        # dense twin): the query at position n_valid sees stale keys p
+        # with n_valid - p < window, i.e. p >= w0.
+        w0 = jnp.maximum(n_valid - (window - 1), 0) if window else 0
+        q = q_ref[b]                                   # [heads, G, Dh]
+        # The SELF column (flash_attention.self_column_init, per head):
+        # m = q·k_new, l = 1, acc = v_new — the current token's K/V never
+        # touched HBM (deferred-insert decode protocol).
+        qf = q.astype(jnp.float32)
+        m = jax.lax.dot_general(
+            qf, kn_ref[b].astype(jnp.float32),
+            (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)        # [heads, G, 1]
+        m *= q.shape[-1] ** -0.5
+        state = (m, jnp.ones_like(m),
+                 jnp.broadcast_to(vn_ref[b].astype(jnp.float32), qf.shape))
+
+        def block(i, state):
+            buf = (walked + i) % 2
+            # Start the NEXT block of the walk — this slot's, or the next
+            # slot's first — into the other buffer, then wait for this one.
+            ends = i == n_blocks - 1
+            nb = jnp.minimum(jnp.where(ends, b + 1, b), B - 1)
+            nblk = jnp.where(ends, live(nb)[1], first + i + 1)
+
+            @pl.when(jnp.logical_not(ends & (b == B - 1)))
+            def _prefetch():
+                for c in copies(nb, nblk, 1 - buf):
+                    c.start()
+            for c in copies(b, first + i, buf):
+                c.wait()
+            # Per-page attends over the block's sub-pages, unrolled
+            # (pages_per_block is compile-time): for each head the SAME
+            # online-softmax updates in the SAME page order as a per-page,
+            # per-head kernel, so any pages_per_block and any head fold
+            # give a head the same result — only what one copy carries
+            # changes. A page with nothing visible (a fresh slot's one
+            # block; a run's tail) is skipped, not masked: it may hold
+            # anything.
+            for sub in range(ppb):
+                lp = (first + i) * ppb + sub           # logical page
+                visible = lp * page < n_valid
                 if window:
-                    ok = ok & (pos >= w0)
-                return jnp.where(ok, scores, NEG_INF)
-            attend_block(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, mask,
-                         ks_ref, vs_ref, sub=i)
+                    visible = visible & ((lp + 1) * page > w0)
 
-    @pl.when(j == n_pb - 1)
-    def _out():
-        l = l_ref[:, :1]                               # >= 1 (self column)
-        o_ref[0, 0] = (acc_ref[:] / l).astype(o_ref.dtype)
+                def attend(state, sub=sub, lp=lp):
+                    def mask(scores):
+                        pos = lp * page + jax.lax.broadcasted_iota(
+                            jnp.int32, scores.shape, 2)
+                        ok = pos < n_valid
+                        if window:
+                            ok = ok & (pos >= w0)
+                        return jnp.where(ok, scores, NEG_INF)
+                    return _attend_heads(
+                        q, k_buf[buf, sub], v_buf[buf, sub],
+                        None if ks_buf is None else ks_buf[buf, sub],
+                        None if vs_buf is None else vs_buf[buf, sub],
+                        mask, *state)
+                state = jax.lax.cond(visible, attend, lambda s: s, state)
+            return state
+
+        _, l, acc = jax.lax.fori_loop(0, n_blocks, block, state)
+        o_ref[b] = (acc / l).astype(o_ref.dtype)       # l >= 1 (self column)
+        return walked + n_blocks
+
+    jax.lax.fori_loop(0, B, slot, 0)
 
 
 def _check_pages_per_block(ppb: int, NP: int, P: int) -> None:
@@ -282,15 +430,24 @@ def paged_decode_attention(q: jax.Array, k_new: jax.Array,
     q: [B, H, Dh] (RoPE applied); k_new/v_new: [B, KV, Dh];
     k_pages/v_pages: [P, KV, page, Dh] or the int8 ``{"q","s"}`` dicts;
     page_table: [B, NP]; n_stale: [B] int32 (the query's position; 0 for a
-    fresh slot). ``window``: sliding-window bound (mistral family; 0 =
-    full) — pages wholly out of window skip compute and DMA, so a
-    windowed decode reads O(window) pages. ``pages_per_block``: fetch a
-    compile-time run of contiguous logical pages per grid step — the
-    K/V block grows to ``(ppb, 1, page, Dh)`` (one pages_per_block×
-    larger HBM→VMEM DMA) and the grid's page dim shrinks by the same
-    factor; requires a PACKED table (see :func:`_check_pages_per_block`).
-    Numerics are bit-for-bit identical across pages_per_block values
-    (per-page attends, unrolled in order). Returns [B, H*Dh].
+    fresh slot). Returns [B, H*Dh].
+
+    One Pallas call, grid ``(KV // heads,)``: a program holds every slot's
+    q / k_new / v_new / out rows for ``heads`` KV heads in VMEM and walks
+    the slots' LIVE blocks — for each slot the ``pages_per_block``-page
+    runs between the first stale key in the window (``window``: the
+    mistral family's bound; 0 = full) and the last stale key — copying
+    each block ``(ppb, heads, page, Dh)`` from the HBM pool while it
+    attends the one before (:func:`_paged_decode_kernel`). ``heads`` is as
+    many of the local KV heads as fit the kernel's VMEM budget
+    (:func:`_decode_heads_per_block` — a function of the shapes and dtypes
+    seen here, nothing configured). The call's time is the live blocks'
+    bytes plus a few microseconds a slot; nothing is paid per table entry
+    or per dead block. ``pages_per_block`` > 1 requires a PACKED table
+    (see :func:`_check_pages_per_block`). Numerics do not depend on
+    pages_per_block or on the head fold (per-page attends in page order,
+    the heads a batch dimension): bit-for-bit where the backend emits the
+    same dot for any batch, as the chip does.
     """
     B, H, Dh = q.shape
     quant = isinstance(k_pages, dict)
@@ -299,81 +456,44 @@ def paged_decode_attention(q: jax.Array, k_new: jax.Array,
     NP = page_table.shape[1]
     ppb = pages_per_block
     _check_pages_per_block(ppb, NP, kq.shape[0])
-    bs = ppb * page                      # tokens per grid step
     G = H // KV
-    qg = q.reshape(B, KV, G, Dh)
-    grid = (B, KV, NP // ppb)
+    heads = _decode_heads_per_block(KV, page, Dh, kq.dtype.itemsize, quant,
+                                    ppb)
 
-    def _live_range(nv_b):
-        """(first, last) live BLOCK (run of ppb logical pages) —
-        out-of-range iterations re-reference a live block so their DMA is
-        elided (pl.when skips their compute); flash_attention._live_range
-        is the dense twin."""
-        last = jnp.maximum((nv_b + bs - 1) // bs - 1, 0)
-        if window:
-            first = jnp.minimum(
-                jnp.maximum(nv_b - (window - 1), 0) // bs, last)
-        else:
-            first = 0
-        return first, last
+    def rows(width):
+        return pl.BlockSpec((B, heads, width, Dh),
+                            lambda hb, pt, nv: (0, hb, 0, 0))
 
-    def _phys_block(pt, b, g):
-        # Gather-free: ONE table lookup per grid step. The packed-table
-        # promise makes the run's first physical page ppb-aligned, so its
-        # superpage id IS the block index along the pool's page dim
-        # (block size ppb ⇒ element offset sp·ppb).
-        p0 = pt[b, g * ppb]
-        return p0 // ppb if ppb > 1 else p0
-
-    def kv_index(b, h, j, pt, nv):
-        first, last = _live_range(nv[b])
-        return _phys_block(pt, b, jnp.clip(j, first, last)), h, 0, 0
-
-    def scale_index(b, h, j, pt, nv):
-        first, last = _live_range(nv[b])
-        return _phys_block(pt, b, jnp.clip(j, first, last)), h, 0, 0
-
-    # Scales are STORED rank-4 [P, KV, 1, page] so the block's trailing
-    # dims are (1, page) — legal under the TPU (8, 128) tiling rule for
-    # any KV (see flash_attention.attend_block) — with no per-call
-    # relayout of the pool-sized scale tensor.
-    kv_spec = pl.BlockSpec((ppb, 1, page, Dh), kv_index)
-    s_spec = pl.BlockSpec((ppb, 1, 1, page), scale_index)
+    # Scales are STORED rank-4 [P, KV, 1, page], so a block's scale plane
+    # is the same slice of the pool as its values (see
+    # flash_attention.attend_block on why the unit dim).
+    kv_buf = pltpu.VMEM((2, ppb, heads, page, Dh), kq.dtype)
+    s_buf = pltpu.VMEM((2, ppb, heads, 1, page), jnp.float32)
     if quant:
         kv_operands = (k_pages["q"], k_pages["s"],
                        v_pages["q"], v_pages["s"])
-        kv_specs = [kv_spec, s_spec, kv_spec, s_spec]
+        buffers = [kv_buf, s_buf, kv_buf, s_buf]
     else:
         kv_operands = (k_pages, v_pages)
-        kv_specs = [kv_spec, kv_spec]
+        buffers = [kv_buf, kv_buf]
 
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, page=page, window=window,
-                          pages_per_block=ppb),
+                          pages_per_block=ppb, n_table_blocks=NP // ppb),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, 1, G, Dh),
-                             lambda b, h, j, pt, nv: (b, h, 0, 0)),
-                pl.BlockSpec((1, 1, 1, Dh),
-                             lambda b, h, j, pt, nv: (b, h, 0, 0)),
-                pl.BlockSpec((1, 1, 1, Dh),
-                             lambda b, h, j, pt, nv: (b, h, 0, 0)),
-                *kv_specs,
-            ],
-            out_specs=pl.BlockSpec((1, 1, G, Dh),
-                                   lambda b, h, j, pt, nv: (b, h, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((G, 128), jnp.float32),
-                pltpu.VMEM((G, 128), jnp.float32),
-                pltpu.VMEM((G, Dh), jnp.float32),
-            ],
+            grid=(KV // heads,),
+            in_specs=[rows(G), rows(1), rows(1),
+                      *[pl.BlockSpec(memory_space=pl.ANY)] * len(kv_operands)],
+            out_specs=rows(G),
+            scratch_shapes=[*buffers,
+                            pltpu.SemaphoreType.DMA((2, len(kv_operands)))],
         ),
         out_shape=jax.ShapeDtypeStruct((B, KV, G, Dh), q.dtype),
         interpret=_interpret_default() if interpret is None else interpret,
     )(page_table.astype(jnp.int32), n_stale.astype(jnp.int32),
-      qg, k_new[:, :, None, :], v_new[:, :, None, :], *kv_operands)
+      q.reshape(B, KV, G, Dh), k_new[:, :, None, :], v_new[:, :, None, :],
+      *kv_operands)
     return out.reshape(B, H * Dh)
 
 

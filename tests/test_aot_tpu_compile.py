@@ -25,7 +25,7 @@ from llmapigateway_tpu.ops import paged_attention as pa        # noqa: E402
 from llmapigateway_tpu.parallel.mesh import build_mesh         # noqa: E402
 
 B, H, KV, DH, PAGE, NP, T = 8, 32, 8, 128, 256, 32, 512
-POOL = B * NP + 2                 # even: the ppb=2 kernels need whole runs
+POOL = B * NP + 4                 # the ppb=2 / 4 kernels need whole runs
 WINDOWS = pytest.mark.parametrize("window", [0, 4096],
                                   ids=["full", "window4096"])
 KV_DTYPES = pytest.mark.parametrize("quant", [False, True],
@@ -88,6 +88,33 @@ def test_paged_decode_compiles_for_v5e(chips, quant, window, ppb):
             *a, window=window, pages_per_block=ppb, interpret=False),
         sds((B, H, DH), jnp.bfloat16), sds((B, KV, DH), jnp.bfloat16),
         sds((B, KV, DH), jnp.bfloat16), side, side, table,
+        sds((B,), jnp.int32))
+
+
+@pytest.mark.parametrize("case", [
+    # (KV, G, ppb, quant, window) -> heads in a block. The module's B,
+    # page 256, Dh 128 and 32 pages a slot are the benchmarked geometry;
+    # int8 with window 4096 IS the benchmarked kernel (also above).
+    ((8, 4, 1, True, 4096), 8),
+    # A bf16 pool in runs of four pages: the VMEM-budget rule folds four
+    # of the eight heads (two programs), and the buffers it sized are
+    # what the chip's compiler must find room for.
+    ((8, 4, 4, False, 0), 4),
+    ((8, 4, 4, True, 4096), 4),
+    # One query row a head (MQA-style grouping), everything folded.
+    ((8, 1, 2, False, 4096), 8),
+], ids=["mistral7b-int8", "bf16-ppb4", "int8-ppb4", "g1-ppb2"])
+def test_paged_decode_head_fold_compiles_for_v5e(chips, case):
+    (kv, g, ppb, quant, window), heads = case
+    assert pa._decode_heads_per_block(
+        kv, PAGE, DH, 1 if quant else 2, quant, ppb) == heads
+    sds, side, table = _shapes(
+        quant, lambda spec: SingleDeviceSharding(chips[0]))
+    _compiled_kernel(
+        lambda *a: pa.paged_decode_attention(
+            *a, window=window, pages_per_block=ppb, interpret=False),
+        sds((B, kv * g, DH), jnp.bfloat16), sds((B, kv, DH), jnp.bfloat16),
+        sds((B, kv, DH), jnp.bfloat16), side, side, table,
         sds((B,), jnp.int32))
 
 

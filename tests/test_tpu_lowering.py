@@ -96,6 +96,38 @@ def test_paged_decode_lowers_for_tpu(quant, window, ppb):
         q, kn, vn, pk, pv, ptab, ns)
 
 
+@pytest.mark.parametrize("geometry", [
+    # (B, KV, G, page, Dh, NP, ppb, quant, window) -> heads in a block
+    # What the benchmark serves: Mistral-7B, int8 pool, all 8 heads fold.
+    ((8, 8, 4, 256, 128, 32, 1, True, 4096), 8),
+    # ... under TP=4, two local KV heads a chip.
+    ((8, 2, 4, 256, 128, 32, 1, True, 4096), 2),
+    # A bf16 pool in runs of four pages: the VMEM budget folds four of
+    # eight heads, so the grid has two programs.
+    ((8, 8, 4, 256, 128, 32, 4, False, 0), 4),
+    ((4, 8, 1, 128, 128, 8, 2, False, 1024), 8),
+], ids=["mistral7b-int8", "mistral7b-int8-tp4", "bf16-ppb4", "g1-ppb2"])
+def test_paged_decode_lowers_at_served_geometry(geometry):
+    """The decode kernel's blocks, buffers and copies at the widths that
+    are served (shapes only — nothing is allocated): Mosaic's layout
+    rules see the folded-head blocks the tiny matrix above cannot have."""
+    (b, kv, g, page, dh, n_pages, ppb, quant, window), heads = geometry
+    pool = b * n_pages + ppb
+    sds = jax.ShapeDtypeStruct
+    if quant:
+        side = {"q": sds((pool, kv, page, dh), jnp.int8),
+                "s": sds((pool, kv, 1, page), jnp.float32)}
+    else:
+        side = sds((pool, kv, page, dh), jnp.bfloat16)
+    assert pa._decode_heads_per_block(
+        kv, page, dh, 1 if quant else 2, quant, ppb) == heads
+    _lower(lambda *a: pa.paged_decode_attention(
+        *a, window=window, pages_per_block=ppb, interpret=False),
+        sds((b, kv * g, dh), jnp.bfloat16), sds((b, kv, dh), jnp.bfloat16),
+        sds((b, kv, dh), jnp.bfloat16), side, side,
+        sds((b, n_pages), jnp.int32), sds((b,), jnp.int32))
+
+
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8kv"])
 @pytest.mark.parametrize("window", [0, 96], ids=["full", "windowed"])
 def test_tp_sharded_decode_wrapper_lowers_for_tpu(quant, window):
