@@ -4,22 +4,25 @@
     against the gather+jnp reference on the same inputs;
 (b) the served path — prefill, then decoding through the paged cache and
     the kernels, over HTTP — against the plain float32 reference forward
-    (``reference/forward.py``) on the engine's own weights;
+    the configuration's file names (``reference/``; absent:
+    ``reference/forward.py``) on the engine's own weights, on a sample
+    drawn as the file's ``"correctness"`` block says (``sampling``);
 (c) every response well formed: frames parse, the usage frame equals the
     tokens streamed, ``[DONE]`` arrives, a usage row is written.
 """
 from __future__ import annotations
 
 import asyncio
+import dataclasses
 import sqlite3
 import time
 from pathlib import Path
+from types import ModuleType
 from typing import Any
 
 import numpy as np
 
 from .metrics import RequestLog
-from .reference.forward import RefConfig, logits as reference_logits
 
 # (a) Kernel against reference on unit-normal inputs, bf16 storage, fp32
 # accumulation (the bound chip_smoke.py has held on v5e since PR 21).
@@ -57,12 +60,62 @@ KERNEL_TOL = 3e-2
 # program claims the publication's mathematics. NOT yet measured on a chip
 # in that form (no cell with experts ships in PR 23): whether int8 noise
 # flipping a token's second expert stays inside 0.25 there is open.
+#
+# Since PR 28 that regime is what a configuration's file STATES about its
+# program (``"correctness": {"exact_up_to_tokens": 64}``), not what the
+# harness concludes from ``n_experts``: a program that routes exactly at
+# every length says nothing and is sampled at whole chunks like any other.
 LOGIT_GAP_TOL = 0.25
 LOGIT_GAP_P50_TOL = 0.05
 DISPATCH_EXACT_TOKENS = 64      # models/mixtral.py make_mlp_fn threshold
 SAMPLE_REQUESTS = 3
 SAMPLE_MAX_TOKENS = 64
 SAMPLE_INDEX = 1 << 30          # beyond any trace entry's index
+
+
+@dataclasses.dataclass(frozen=True)
+class Sampling:
+    """How a configuration's correctness sample is drawn and judged."""
+    exact_up_to_tokens: int | None      # None: whole chunks, served together
+    gap_tol: float
+    gap_p50_tol: float
+
+
+def sampling(config_name: str, config: dict[str, Any]) -> Sampling:
+    """From the ``"correctness"`` block of a configuration's file.
+    ``exact_up_to_tokens``: the program computes the publication's
+    mathematics only in prefill calls of at most that many tokens (a
+    capacity dispatch that drops tokens beyond it), so the sample's prompts
+    are that long and are served one at a time; absent, prompts are whole
+    prefill chunks served together. ``logit_gap_tol`` and
+    ``logit_gap_p50_tol`` (``{"value": x, "why": "..."}``) may state
+    TIGHTER bounds than the defaults, for a configuration served in a
+    finer arithmetic than W8A8; a looser one is an error, so that no file
+    loosens ``correct``."""
+    block = config.get("correctness", {})
+    unknown = set(block) - {"exact_up_to_tokens", "logit_gap_tol",
+                            "logit_gap_p50_tol"}
+    if unknown:
+        raise ValueError(f"{config_name}: unknown correctness keys {unknown}")
+    tols = []
+    for key, default in (("logit_gap_tol", LOGIT_GAP_TOL),
+                         ("logit_gap_p50_tol", LOGIT_GAP_P50_TOL)):
+        stated = block.get(key)
+        if stated is None:
+            tols.append(default)
+            continue
+        if not (isinstance(stated, dict) and stated.get("why")
+                and isinstance(stated.get("value"), (int, float))):
+            raise ValueError(f"{config_name}: {key} is a value with its why")
+        if not 0 <= stated["value"] <= default:
+            raise ValueError(
+                f"{config_name}: {key} {stated['value']} is looser than the "
+                f"benchmark's {default}; a file may only tighten it")
+        tols.append(float(stated["value"]))
+    exact = block.get("exact_up_to_tokens")
+    if exact is not None and not (isinstance(exact, int) and exact >= 8):
+        raise ValueError(f"{config_name}: exact_up_to_tokens {exact!r}")
+    return Sampling(exact, *tols)
 
 
 def kernel_parity(*, n_heads: int, n_kv_heads: int, head_dim: int, page: int,
@@ -123,12 +176,14 @@ def kernel_parity(*, n_heads: int, n_kv_heads: int, head_dim: int, page: int,
     return out
 
 
-async def served_against_reference(gateway, seed: int, prompt_tokens: int
-                                   ) -> dict[str, Any]:
-    """``SAMPLE_REQUESTS`` seeded prompts served at once through HTTP,
-    then the reference over each prompt plus what was served: every
-    generated position checked (the first comes off the prefill, the rest
-    through the decode kernel and the cache)."""
+async def served_against_reference(gateway, seed: int, prompt_tokens: int,
+                                   how: Sampling, reference: ModuleType,
+                                   config: dict[str, Any]) -> dict[str, Any]:
+    """``SAMPLE_REQUESTS`` seeded prompts served through HTTP (at once, or
+    as ``how`` says), then the configuration's ``reference`` over each
+    prompt plus what was served: every generated position checked (the
+    first comes off the prefill, the rest through the decode kernel and
+    the cache)."""
     from .traffic import Entry, prompt_ids
     tok = gateway.tokenizer
     n = prompt_tokens - tok.template_overhead()
@@ -142,10 +197,10 @@ async def served_against_reference(gateway, seed: int, prompt_tokens: int
                                 max_tokens=SAMPLE_MAX_TOKENS),
                      tok.text_of(ids)))
     eng = gateway.engine
-    c = RefConfig.of(eng.model_cfg)
-    if c.n_experts:
+    c = reference.sizes(eng.model_cfg, config)
+    if how.exact_up_to_tokens:
         # One at a time: a prefill call then holds one row of at most
-        # DISPATCH_EXACT_TOKENS tokens, which the program routes exactly.
+        # that many tokens, which the program computes exactly.
         for log, content in logs:
             await gateway.stream_chat(log, content)
     else:
@@ -159,7 +214,7 @@ async def served_against_reference(gateway, seed: int, prompt_tokens: int
             gen = gateway.requests[log.rid]
             served = list(gen.generated)
             seq = np.asarray(list(gen.prompt_ids) + served[:-1], np.int32)
-            ref, _ = reference_logits(eng.params, c, seq, last=len(served))
+            ref = reference.logits(eng.params, c, seq, last=len(served))
             for row, tok_id in zip(ref, served):
                 gaps.append(float(row.max() - row[tok_id]))
                 agree += int(np.argmax(row) == tok_id)
@@ -169,11 +224,11 @@ async def served_against_reference(gateway, seed: int, prompt_tokens: int
     well_formed = [p for log, _ in logs for p in response_problems(log)]
     return {"positions": positions, "argmax_agree": agree,
             "gap_p50": float(np.median(gaps)), "gap_max": float(max(gaps)),
-            "tolerance": LOGIT_GAP_TOL, "tolerance_p50": LOGIT_GAP_P50_TOL,
+            "tolerance": how.gap_tol, "tolerance_p50": how.gap_p50_tol,
             "problems": well_formed,
             "reference_s": round(time.monotonic() - t0, 2),
-            "ok": bool(max(gaps) <= LOGIT_GAP_TOL
-                       and np.median(gaps) <= LOGIT_GAP_P50_TOL
+            "ok": bool(max(gaps) <= how.gap_tol
+                       and np.median(gaps) <= how.gap_p50_tol
                        and not well_formed),
             "logs": [log for log, _ in logs]}
 

@@ -12,6 +12,7 @@ from __future__ import annotations
 import asyncio
 import dataclasses
 import json
+import re
 import time
 from pathlib import Path
 from typing import Any, Callable
@@ -24,6 +25,8 @@ PROVIDER = "local"
 MODEL = "gw/bench"
 
 # Published (Hugging Face config.json) key -> the program's ModelConfig field.
+# A configuration's file extends the table for itself ("preset_fields"), so
+# the published sizes of a new architecture are held to its preset as these.
 HF_KEYS = {
     "hidden_size": "d_model", "num_hidden_layers": "n_layers",
     "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv_heads",
@@ -32,40 +35,121 @@ HF_KEYS = {
     "rms_norm_eps": "rms_eps", "num_local_experts": "n_experts",
     "num_experts_per_tok": "experts_per_token",
     "max_position_embeddings": "max_seq_len"}
-# The only published sizes a configuration may cut: depth.
-REDUCIBLE = {"num_hidden_layers"}
+
+# What may be cut is how MANY layers, experts or vocabulary rows this chip
+# holds, never how wide anything is (model-configs guide, section 4). A
+# width, by its key: a hidden, intermediate, latent, state or projection
+# size, a head size, a rank, a window, a kernel or expansion factor, the
+# experts per token. ``vocab_size`` counts rows and is the one ``_size``
+# that is none.
+_WIDTH = re.compile(r"(_size|_dim|_rank|_width|_window|_factor|_expand)$"
+                    r"|_per_tok|^expand$|^d_|head_dim")
+_EXPERTS_HELD = re.compile(r"^(n|num)_(routed_|local_)?experts$")
+MIN_LAYERS, MIN_EXPERTS, MIN_VOCABULARY_SHARE = 4, 8, 8    # the guide's floors
 
 
-def resolve_preset(config_name: str, config: dict[str, Any]) -> str:
-    """The name of the program preset this configuration runs. The file's
-    published sizes must be the preset's; where the file lists a cut under
-    ``reduced`` (depth only), a derived preset is registered under the
-    configuration's own name — one ``dataclasses.replace`` into the
-    program's table, before the app is built."""
-    from llmapigateway_tpu.models.config import PRESETS
-    base = PRESETS[config["preset"]]
-    reduced = config.get("reduced", {})
-    bad = set(reduced) - REDUCIBLE
-    if bad:
-        raise ValueError(f"{config_name}: only depth may be cut, not {bad}")
+def is_width(key: str) -> bool:
+    return key != "vocab_size" and bool(_WIDTH.search(key))
+
+
+def _cuts(name: str, config: dict[str, Any], table: dict[str, str],
+          base: Any) -> dict[str, Any]:
+    """The preset fields that ``reduced`` changes, each cut held to the
+    guide's floors: depth keeps whole periods of the layer pattern the
+    file states (``layer_kinds``) and four layers or more after the leading
+    dense ones; the experts held are 8 or more, the vocabulary an eighth or
+    more, and both are what one of ``chips_sharing_a_layer`` chips holds.
+    Beside each cut stands the published count (``{"published": n}``); the
+    experts held go to the field the entry names (``"held_in"``) while the
+    router's field keeps the published count."""
+    chips = config.get("chips_sharing_a_layer")
+    if not (isinstance(chips, int) and chips >= 1
+            and config.get("deployment")):
+        raise ValueError(
+            f"{name}: a cut needs its deployment beside it: "
+            f"'chips_sharing_a_layer' (this chip is one of N that share "
+            f"each layer) and 'deployment' in words")
+    kinds = config.get("layer_kinds", {})
     changes = {}
-    for key, field in HF_KEYS.items():
-        if key not in config:
+    for key, entry in config["reduced"].items():
+        if is_width(key):
+            raise ValueError(f"{name}: {key} is a width, and no width is cut")
+        if key not in table or key not in config:
+            raise ValueError(
+                f"{name}: {key} is cut, and the file does not give it or "
+                f"'preset_fields' does not name its field of the preset")
+        held, field = config[key], table[key]
+        published = entry.get("published") if isinstance(entry, dict) else None
+        if not (isinstance(published, int) and 0 < held < published):
+            raise ValueError(f"{name}: {key}={held} is cut and states no "
+                             f"published count above it ({entry!r})")
+        share = -(-published // chips)
+        if field == "n_layers":
+            lead = kinds.get("leading_dense", 0)
+            period = kinds.get("period", 1)
+            if held - lead < max(MIN_LAYERS, period) or (held - lead) % period:
+                raise ValueError(
+                    f"{name}: depth {held} is not whole periods of {period} "
+                    f"layers, {MIN_LAYERS} or more, after {lead} leading")
+        elif field == "vocab_size":
+            if held * MIN_VOCABULARY_SHARE < published or held != share:
+                raise ValueError(
+                    f"{name}: {held} of {published} vocabulary rows is not "
+                    f"one of {chips} chips' share, an eighth or more")
+        elif _EXPERTS_HELD.match(key):
+            if held < MIN_EXPERTS or held != share:
+                raise ValueError(
+                    f"{name}: {held} of {published} experts is not one of "
+                    f"{chips} chips' share, {MIN_EXPERTS} or more")
+            if getattr(base, field) != published:
+                raise ValueError(
+                    f"{name}: {key} is published as {published}, preset "
+                    f"{config['preset']!r} routes over "
+                    f"{field}={getattr(base, field)}")
+            field = entry.get("held_in")
+            if not (isinstance(field, str) and hasattr(base, field)):
+                raise ValueError(
+                    f"{name}: preset {config['preset']!r} has no field "
+                    f"{field!r} to be told how many experts it holds")
+        else:
+            raise ValueError(f"{name}: only depth, the experts held and the "
+                             f"vocabulary may be cut, not {key}")
+        changes[field] = held
+    return changes
+
+
+def resolve_preset(config_name: str, config: dict[str, Any],
+                   presets: dict[str, Any] | None = None) -> str:
+    """The name of the program preset this configuration runs. The file's
+    published sizes must be the preset's (``HF_KEYS`` and the file's own
+    ``preset_fields``); where the file lists cuts under ``reduced``
+    (``_cuts``), a derived preset is registered under the configuration's
+    own name — one ``dataclasses.replace`` into the program's table
+    (``presets``: another table, for tests), before the app is built."""
+    if presets is None:
+        from llmapigateway_tpu.models.config import PRESETS as presets
+    base = presets[config["preset"]]
+    table = dict(HF_KEYS)
+    for key, field in config.get("preset_fields", {}).items():
+        if table.setdefault(key, field) != field:
+            raise ValueError(f"{config_name}: preset_fields moves {key} from "
+                             f"{table[key]!r} to {field!r}")
+        if not hasattr(base, field):
+            raise ValueError(f"{config_name}: preset {config['preset']!r} "
+                             f"has no field {field!r} (for {key})")
+    reduced = config.get("reduced", {})
+    for key, field in table.items():
+        if key not in config or key in reduced:
             continue
-        want = config[key] or 0
-        have = getattr(base, field)
-        if key in reduced:
-            if want != reduced[key]:
-                raise ValueError(f"{config_name}: {key} is {want}, 'reduced' "
-                                 f"says {reduced[key]}")
-            changes[field] = want
-        elif want != have:
+        want, have = config[key] or 0, getattr(base, field)
+        if want != have:
             raise ValueError(
                 f"{config_name}: {key}={want} in the file, preset "
                 f"{config['preset']!r} has {field}={have}")
-    if not changes:
+    if not reduced:
         return config["preset"]
-    PRESETS[config_name] = dataclasses.replace(base, **changes)
+    presets[config_name] = dataclasses.replace(
+        base, **_cuts(config_name, config, table, base))
     return config_name
 
 

@@ -6,6 +6,14 @@ new per-layer metric over an existing reducer is a file, not code.
 A reducer takes what one traced run measured (``Measured``) and its
 arguments, and returns a number, or ``None`` where it finds nothing to
 read: the harness then leaves the metric out of the line.
+
+A new KIND of reducer is a new file too: every module of
+``reducer_files/`` is imported when this one is (and those of a cell's
+own data directory when the cell is loaded, ``spec.load_reducer_files``)
+and registers with ``@reducer``. A new kernel's roofline share is such a
+file: its operation and byte counts from shapes (``Measured.config`` has
+the configuration's file, so its widths), over
+``roofline.least_seconds`` and ``Measured.peaks``.
 """
 from __future__ import annotations
 
@@ -13,7 +21,7 @@ import dataclasses
 import statistics
 from typing import Any, Callable
 
-from . import roofline
+from . import roofline, spec
 from .metrics import RequestLog
 from .xplane import Reduced
 
@@ -32,6 +40,8 @@ class Measured:
     shape: roofline.AttnShape        # per-chip attention shape
     peaks: dict[str, Any]
     peak_hbm_bytes: int | None
+    config: dict[str, Any] = dataclasses.field(default_factory=dict)
+    #                                  the configuration's file, as loaded
 
     def in_window(self) -> list[RequestLog]:
         return [r for r in self.logs if r.t_first is not None
@@ -42,6 +52,10 @@ REDUCERS: dict[str, Callable[[Measured, dict[str, Any]], float | None]] = {}
 
 
 def reducer(fn):
+    if fn.__name__ in REDUCERS:
+        raise ValueError(
+            f"reducer {fn.__name__!r} is registered twice: by "
+            f"{REDUCERS[fn.__name__].__module__} and by {fn.__module__}")
     REDUCERS[fn.__name__] = fn
     return fn
 
@@ -94,7 +108,8 @@ def _kernel_events(m: Measured, scope: str) -> int:
 def program_ms_per_step(m: Measured, a: dict[str, Any]) -> float | None:
     """Device time of the named programs over the decode steps they ran.
     A step is counted in the trace itself: one kernel event of
-    ``step_scope`` per layer per step."""
+    ``step_scope`` per step for every layer that calls the paged kernels
+    (``AttnShape.n_layers``)."""
     if m.trace is None or not m.trace.devices:
         return None
     steps = _kernel_events(m, a["step_scope"]) / m.shape.n_layers
@@ -193,3 +208,6 @@ def counter_delta(m: Measured, a: dict[str, Any]) -> float | None:
     if k not in m.counters_open or k not in m.counters_close:
         return None
     return float(m.counters_close[k] - m.counters_open[k])
+
+
+spec.load_reducer_files(spec.PACKAGE_DIR)      # last: they import this module

@@ -24,6 +24,9 @@ dispatch is exact.
 On a TPU a float32 matrix multiplication runs in reduced precision unless
 asked otherwise, so everything here runs under
 ``jax.default_matmul_precision("highest")``.
+
+This is the reference a configuration gets that names none; ``sizes`` and
+``logits`` are the names of the contract in ``reference/__init__.py``.
 """
 from __future__ import annotations
 
@@ -57,6 +60,12 @@ class RefConfig:
                    window=int(c.sliding_window or 0),
                    n_experts=int(c.n_experts or 0),
                    experts_per_token=int(c.experts_per_token))
+
+
+def sizes(model_cfg: Any, config: dict[str, Any]) -> RefConfig:
+    """The contract's name for ``RefConfig.of``: every size of this family
+    is a field of the program's ``ModelConfig``; the file adds none."""
+    return RefConfig.of(model_cfg)
 
 
 def dequant(w: Any) -> jax.Array:
@@ -157,7 +166,13 @@ def _head(x, norm, w, last: int, eps: float):
 
 
 def logits(params: Any, c: RefConfig, tokens: np.ndarray, last: int
-           ) -> tuple[np.ndarray, np.ndarray]:
+           ) -> np.ndarray:
+    """The contract's ``logits``: the rows of ``logits_and_routing``."""
+    return logits_and_routing(params, c, tokens, last)[0]
+
+
+def logits_and_routing(params: Any, c: RefConfig, tokens: np.ndarray,
+                       last: int) -> tuple[np.ndarray, np.ndarray]:
     """Float32 logits of the LAST ``last`` positions of ``tokens`` [T]
     under the engine's weight tree ``params`` (stacked layers), and which
     experts each token was routed to, per layer [L, T, E]. Layers are

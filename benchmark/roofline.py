@@ -32,7 +32,8 @@ def peaks_for(device_kind: str) -> dict:
 @dataclasses.dataclass(frozen=True)
 class AttnShape:
     """What the attention kernels see of a configuration, on ONE chip."""
-    n_layers: int
+    n_layers: int           # layers that call the paged kernels (of a hybrid
+    #                         model not all: ``spec.paged_attention_layers``)
     n_heads: int            # query heads on this chip
     n_kv_heads: int         # KV heads on this chip
     head_dim: int

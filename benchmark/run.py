@@ -45,7 +45,7 @@ import sys                      # noqa: E402
 from pathlib import Path        # noqa: E402
 from typing import Any          # noqa: E402
 
-from . import metrics, spec     # noqa: E402
+from . import ENGINE_INTERFACE, metrics, spec     # noqa: E402
 
 OUT_DIR = "bench_out"           # inside the checkout, git-ignored
 TRACE_SECONDS = 4.0             # of the window, from just inside it
@@ -84,14 +84,15 @@ def memory_peak(devices) -> int | None:
     return max(peaks) if peaks else None
 
 
-def sample_prompt_tokens(engine) -> int:
+def sample_prompt_tokens(cell: spec.Cell, engine) -> int:
     """Length of the correctness sample's prompts: whole prefill chunks
     (two where they fit), so they cross a chunk and a page and add no
-    program to warm; for a model with experts, the longest call the
-    program routes exactly (``correctness.DISPATCH_EXACT_TOKENS``)."""
-    from .correctness import DISPATCH_EXACT_TOKENS, SAMPLE_MAX_TOKENS
-    if engine.model_cfg.n_experts:
-        return DISPATCH_EXACT_TOKENS
+    program to warm; where the configuration's file says its program is
+    exact only up to a length (``correctness.sampling``), that length."""
+    from .correctness import SAMPLE_MAX_TOKENS, sampling
+    exact = sampling(cell.config_name, cell.config).exact_up_to_tokens
+    if exact:
+        return exact
     chunk = engine.prefill_chunk
     return (2 if 2 * chunk + SAMPLE_MAX_TOKENS < engine.S else 1) * chunk
 
@@ -104,7 +105,7 @@ def reachable_programs(cell: spec.Cell, engine) -> dict[str, list[int]]:
     from .correctness import SAMPLE_REQUESTS
     from .traffic import support
     chunk = engine.prefill_chunk
-    buckets = {_bucket(min(sample_prompt_tokens(engine), chunk), chunk)}
+    buckets = {_bucket(min(sample_prompt_tokens(cell, engine), chunk), chunk)}
     for n in support(cell.traffic.prompt_tokens):
         if n >= chunk:
             buckets.add(chunk)
@@ -127,6 +128,12 @@ def warm_programs(engine, plan: dict[str, list[int]]) -> None:
     fills the jit caches too, so the first real call neither compiles nor
     reads the persistent cache."""
     import numpy as np
+    missing = [name for name in ENGINE_INTERFACE if not hasattr(engine, name)]
+    if missing:
+        raise TypeError(
+            f"the engine lacks {missing}: the benchmark warms, counts and "
+            f"checks through these names, and an engine for another "
+            f"architecture has to keep them (benchmark.ENGINE_INTERFACE)")
     for bucket in plan["prefill_buckets"]:
         for k in plan["prefill_groups"]:
             first, engine.cache = engine._exec_prefill(
@@ -215,12 +222,15 @@ def write_records(path: Path, logs: list[metrics.RequestLog],
             f.write(json.dumps(row) + "\n")
 
 
-def attn_shape(engine):
+def attn_shape(engine, config: dict[str, Any]):
+    """The paged kernels' per-chip shape; ``n_layers`` counts the layers
+    that call them, which the configuration's file may state."""
     from .roofline import AttnShape
     c = engine.model_cfg
     tp = dict(engine.mesh.shape).get("model", 1)
     int8 = engine.kv_quant == "int8"
-    return AttnShape(n_layers=c.n_layers, n_heads=c.n_heads // tp,
+    return AttnShape(n_layers=spec.paged_attention_layers(config, c.n_layers),
+                     n_heads=c.n_heads // tp,
                      n_kv_heads=max(1, c.n_kv_heads // tp),
                      head_dim=c.head_dim, window=int(c.sliding_window or 0),
                      kv_bytes=1 if int8 else 2,
@@ -233,7 +243,7 @@ async def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
                    ) -> dict[str, Any]:
     """Set up, play the window, reduce. Returns the result object."""
     import jax
-    from . import correctness, xplane
+    from . import correctness, reference, xplane
     from .gateway import Gateway, resolve_preset
     from .load import Player
     from .reducers import REDUCERS, Measured
@@ -242,12 +252,18 @@ async def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     setup: dict[str, float] = {"imports_s": time.monotonic() - _T0}
     device = device_facts()
     preset = resolve_preset(cell.config_name, cell.config)
+    how = correctness.sampling(cell.config_name, cell.config)
+    ref_module = reference.load(cell.config, cell.data)
+    scopes = spec.scopes(cell.config)
     engine_cfg = {**cell.config["engine"], "preset": preset}
     async with Gateway(engine_cfg, out / "gateway", local_factory) as g:
         eng = g.engine
         setup.update(g.timings)
+        shape = attn_shape(eng, cell.config)
         emit("engine", preset=preset, layers=eng.model_cfg.n_layers,
-             slots=eng.B, context=eng.S, page=eng.kv_page,
+             paged_layers=shape.n_layers,
+             vocabulary=eng.model_cfg.vocab_size, slots=eng.B,
+             context=eng.S, page=eng.kv_page,
              chunk=eng.prefill_chunk, quant=eng.quant, kv_quant=eng.kv_quant,
              attention=eng.attention_impl,
              mesh={a: n for a, n in eng.mesh.shape.items() if n > 1})
@@ -260,16 +276,19 @@ async def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
              xla_compiles=eng.stats()["xla_compile_total"])
 
         t0 = time.monotonic()
-        shape = attn_shape(eng)
         parity = await asyncio.to_thread(
             correctness.kernel_parity, n_heads=shape.n_heads,
             n_kv_heads=shape.n_kv_heads, head_dim=shape.head_dim,
             page=eng.kv_page, window=shape.window, kv_quant=eng.kv_quant,
             interpret=rehearsal, **({"pages_per_slot": 8, "t": 16}
                                     if rehearsal else {}))
+        if hasattr(ref_module, "kernel_checks"):
+            parity += await asyncio.to_thread(
+                ref_module.kernel_checks, eng, cell.config, rehearsal)
         emit("kernel_parity", cases=parity)
         ref = await correctness.served_against_reference(
-            g, seed, prompt_tokens=sample_prompt_tokens(eng))
+            g, seed, sample_prompt_tokens(cell, eng), how, ref_module,
+            cell.config)
         sample_logs = ref.pop("logs")
         emit("reference", **ref)
         setup["correctness_s"] = time.monotonic() - t0
@@ -355,7 +374,7 @@ async def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
         if describe_to is not None:
             describe_to.write_text(json.dumps(
                 xplane.describe(recorded, 400), indent=1))
-        reduced = await asyncio.to_thread(xplane.reduce, recorded)
+        reduced = await asyncio.to_thread(xplane.reduce, recorded, scopes)
         facts, breakdown = trace_facts(reduced)
         dev.update(facts, trace_offset_s=t_trace[0] - played.t_open)
         result.update(breakdown)
@@ -375,7 +394,7 @@ async def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
             flight=flight, counters_open=marks["stats_open"],
             counters_close=marks["stats_close"], slots=eng.B, shape=shape,
             peaks=({} if rehearsal else peaks_for(device["kind"])),
-            peak_hbm_bytes=peak)
+            peak_hbm_bytes=peak, config=cell.config)
         out_metrics = {}
         for lm in cell.per_layer:
             v = REDUCERS[lm.reducer](measured, lm.args)

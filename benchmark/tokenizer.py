@@ -7,7 +7,11 @@ generated tokens decode to nothing, no content frame is ever written, and
 a client can time neither the first token nor the gaps. A served model's
 tokenizer decodes every id to text; this one does the same with the least
 machinery: id ``i`` is the character ``chr(i)`` where that is printable
-ASCII or a newline, else ``chr(0x4000 + i)``. One character is one token
+ASCII or a newline, else ``chr(0x4000 + i)`` — or, from the id on that
+this would put into the surrogate block (``0xD800``–``0xDFFF``, which
+UTF-8 cannot carry), the character one block further, so vocabularies of
+up to a million ids fit and every id below 38,912 maps as it always has.
+One character is one token
 in both directions, so the client counts the tokens of a frame by the
 length of its text, and a prompt of an exact number of tokens is a string
 of that many characters.
@@ -17,10 +21,19 @@ from __future__ import annotations
 from typing import Sequence
 
 _SHIFT = 0x4000
+_SURROGATES, _AFTER_SURROGATES = 0xD800, 0xE000
+MAX_VOCAB = 1_000_000      # chr(0x4000 + 0x800 + 999_999) < chr(0x10FFFF)
 
 
 def _plain(i: int) -> bool:
     return 32 <= i < 127 or i == 10
+
+
+def _char(i: int) -> str:
+    if _plain(i):
+        return chr(i)
+    o = _SHIFT + i
+    return chr(o if o < _SURROGATES else o + _AFTER_SURROGATES - _SURROGATES)
 
 
 class CharTokenizer:
@@ -33,7 +46,7 @@ class CharTokenizer:
     long as its ``max_tokens``: the same work from every seed."""
 
     def __init__(self, vocab_size: int):
-        if not 128 <= vocab_size <= 0xD800 - _SHIFT:
+        if not 128 <= vocab_size <= MAX_VOCAB:
             raise ValueError(f"vocabulary of {vocab_size} ids does not fit")
         self.vocab_size = vocab_size
         self.bos_id: int | None = 1
@@ -44,6 +57,8 @@ class CharTokenizer:
         out = []
         for ch in text:
             o = ord(ch)
+            if o >= _AFTER_SURROGATES:
+                o -= _AFTER_SURROGATES - _SURROGATES
             i = o - _SHIFT if o >= _SHIFT else o
             if not 0 <= i < self.vocab_size:
                 raise ValueError(f"character {ch!r} is outside the vocabulary")
@@ -51,8 +66,7 @@ class CharTokenizer:
         return out
 
     def text_of(self, ids: Sequence[int]) -> str:
-        return "".join(chr(i) if _plain(i) else chr(_SHIFT + i)
-                       for i in map(int, ids))
+        return "".join(_char(i) for i in map(int, ids))
 
     def decode(self, ids: Sequence[int]) -> str:
         return self.text_of(i for i in ids if 0 <= i < self.vocab_size)

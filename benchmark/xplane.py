@@ -24,8 +24,9 @@ How a device plane is read:
   (``jit_decode_scan(...)``); an op belongs to the module event that
   encloses its start.
 * an op's scope is the innermost ``jax.named_scope`` of ``SCOPES`` found
-  in its metadata; its category is the ``hlo_category`` stat (or the op
-  name without its number).
+  in its metadata (a configuration may put names of its own before them:
+  ``spec.scopes``, handed to ``reduce``); its category is the
+  ``hlo_category`` stat (or the op name without its number).
 
 An op's key in the breakdown is ``<program>/<scope or ->:<category>``,
 for instance ``prefill_step/attention.paged_prefill:custom-call``.
@@ -313,10 +314,11 @@ def program_name(module_event: str) -> str:
     return name[4:] if name.startswith("jit_") else name
 
 
-def reduce(trace: Trace) -> Reduced:
+def reduce(trace: Trace, scopes: tuple[str, ...] = SCOPES) -> Reduced:
     """The traced span is the one between the two markers, and everything
     is clipped to it. Without both markers: the span from the first to the
-    last device event, nothing clipped."""
+    last device event, nothing clipped. An op is filed under the first of
+    ``scopes`` (innermost first) that its metadata names."""
     host, marks = [], {}
     for plane in trace.profile.planes:
         if not plane.name.startswith("/host:CPU"):
@@ -339,7 +341,7 @@ def reduce(trace: Trace) -> Reduced:
         if name.startswith("/device:") and "TPU" in name.upper() \
                 and "core" not in name.lower():
             dev = _device(plane, trace.meta.get(name, {}),
-                          span if marked else None)
+                          span if marked else None, scopes)
             if dev.ops:
                 devices.append(dev)
     if marked:
@@ -360,7 +362,8 @@ def _hlo_name(text: str) -> str:
 
 
 def _device(plane: Any, meta: dict[str, dict[str, Any]],
-            span: tuple[int, int] | None = None) -> DeviceTrace:
+            span: tuple[int, int] | None = None,
+            scopes: tuple[str, ...] = SCOPES) -> DeviceTrace:
     """One chip's ops and program executions, cut to ``span``. An op's
     program is found before the cut (by where the op really started); self
     times are taken after it, on what is left of each op."""
@@ -398,7 +401,7 @@ def _device(plane: Any, meta: dict[str, dict[str, Any]],
             " ", "_") or ("custom-call" if " custom-call(" in name
                           else re.sub(r"[.\d]+$", "", short))
         op = Op(start=s, end=e, self_ns=e - s, program=program,
-                scope=_scope(stats, short), category=category,
+                scope=_scope(stats, short, scopes), category=category,
                 collective=bool(_COLLECTIVE.search(category)
                                 or _COLLECTIVE.search(short)))
         while stack and stack[-1].end <= s:
@@ -423,12 +426,12 @@ def _cut(rows: list[tuple], span: tuple[int, int] | None) -> list[tuple]:
             if s < hi and e > lo]
 
 
-def _scope(stats: dict, name: str) -> str:
+def _scope(stats: dict, name: str, scopes: tuple[str, ...] = SCOPES) -> str:
     """From the op's ``tf_op`` (its named-scope path), else from its HLO
     name (a kernel is named after its scope)."""
     for text in (stats.get("tf_op"), name):
         if isinstance(text, str):
-            for scope in SCOPES:
+            for scope in scopes:
                 if scope in text:
                     return scope
     return ""

@@ -7,6 +7,9 @@ from pathlib import Path
 
 import pytest
 
+from benchmark.gateway import is_width, resolve_preset
+from llmapigateway_tpu.models.config import PRESETS
+
 REPO = Path(__file__).resolve().parents[2]
 BENCH = json.loads((REPO / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -77,8 +80,13 @@ def test_every_configuration_is_used_and_has_its_file():
         assert cfg["source"] == c["source"] and c["source"].startswith(
             "https://")
         assert sorted(cfg["reduced"]) == sorted(c["reduced"])
-        assert not any(k.endswith(("_dim", "_rank")) or "size" in k
-                       for k in c["reduced"])
+        # No width is cut (by the rule, not by a substring: the vocabulary
+        # is a ``_size`` that counts rows), and what is cut keeps the
+        # guide's floors and states its published count and deployment.
+        assert not any(is_width(k) for k in c["reduced"])
+        assert len(c["reduced"]) <= 16
+        assert resolve_preset(c["name"], cfg, dict(PRESETS)) == (
+            c["name"] if c["reduced"] else cfg["preset"])
     for w in BENCH["workloads"]:
         assert (REPO / "benchmark/traffic" / f"{w['traffic']}.json").exists()
 
@@ -100,3 +108,21 @@ def test_a_layer_metric_is_reported_only_beside_the_metric_it_moves():
     # One layer, one spelling.
     layers = {m["layer"] for m in BENCH["per_layer"]}
     assert len({x.lower() for x in layers}) == len(layers)
+
+
+@pytest.mark.parametrize("key, width", [
+    ("hidden_size", True), ("intermediate_size", True),
+    ("moe_intermediate_size", True), ("head_dim", True),
+    ("kda_head_dim", True), ("kv_lora_rank", True), ("v_head_dim", True),
+    ("ssm_state_size", True), ("short_conv_kernel_size", True),
+    ("sliding_window", True), ("mamba_expand", True), ("expand", True),
+    ("num_experts_per_tok", True), ("routed_scaling_factor", True),
+    ("vocab_size", False), ("num_hidden_layers", False),
+    ("n_routed_experts", False), ("num_local_experts", False),
+])
+def test_which_keys_are_widths_is_a_rule(key, width):
+    """The contract's list: a hidden, intermediate, latent, state or
+    projection size, a key that ends in ``_dim`` or ``_rank``, a head
+    size, an expansion factor, the experts per token — and not the
+    vocabulary, which the parent's test refused for holding ``size``."""
+    assert is_width(key) is width

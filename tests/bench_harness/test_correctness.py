@@ -9,7 +9,8 @@ import pytest
 
 from benchmark import correctness
 from benchmark.metrics import RequestLog
-from benchmark.reference.forward import RefConfig, logits
+from benchmark.reference.forward import (RefConfig, logits,
+                                         logits_and_routing)
 from benchmark.tokenizer import CharTokenizer
 
 
@@ -25,7 +26,7 @@ def test_reference_forward_is_the_programs_mathematics(preset):
     cache = llama.KVCache.create(c, 1, 64, jnp.float32)
     served, _ = forward_fn(c)(params, c, jnp.asarray(toks[None], jnp.int32),
                               jnp.zeros((1,), jnp.int32), cache)
-    ref, routed = logits(params, RefConfig.of(c), toks, last=48)
+    ref, routed = logits_and_routing(params, RefConfig.of(c), toks, last=48)
     assert np.abs(np.asarray(served[0]) - ref).max() < 1e-4
     assert routed.shape == (c.n_layers, 48, c.n_experts)
     if c.n_experts:
@@ -42,7 +43,7 @@ def test_reference_reads_the_engines_int8_weight_tree():
     cache = llama.KVCache.create(c, 1, 64, jnp.float32)
     served, _ = forward_fn(c)(params, c, jnp.asarray(toks[None], jnp.int32),
                               jnp.zeros((1,), jnp.int32), cache)
-    ref, _ = logits(params, RefConfig.of(c), toks, last=40)
+    ref = logits(params, RefConfig.of(c), toks, last=40)
     # W8A8 re-quantises activations the reference keeps in float32.
     assert 1e-4 < np.abs(np.asarray(served[0]) - ref).max() < 0.6
 
@@ -106,6 +107,48 @@ def test_a_malformed_response_says_what_is_wrong(change, says):
         or "finish" in says
 
 
+def test_ids_past_the_surrogate_block_fit_and_the_rest_map_as_before():
+    """Stop 9. ``0x4000 + i`` reaches the surrogates at id 38,912; from
+    there on an id takes the character one block further, so a vocabulary
+    of 196,608 (or a million) ids fits. Every id below maps as the parent's
+    tokenizer mapped it: the digests are of the PARENT's ``text_of`` over
+    all 32,000 ids and over all 38,912 it could hold."""
+    import hashlib
+    import json
+    parents = {
+        32000: "6ee8115aa26f243c3275b46dc03027acd73720"
+               "35d16490b6456cf495b4c142ab",
+        38912: "12b2fb35e95eaea13605c5074f71493606218c"
+               "237a793108b84572b1a6bd4f25"}
+    for vocab, digest in parents.items():
+        tok = CharTokenizer(vocab)
+        text = tok.text_of(range(vocab))
+        assert hashlib.sha256(text.encode("utf-8")).hexdigest() == digest
+        assert tok.encode(text) == list(range(vocab))
+    big = CharTokenizer(196608)
+    assert big.text_of(range(38912)) == CharTokenizer(38912).text_of(
+        range(38912))
+    ids = list(range(38900, 38930)) + list(range(0, 196608, 97)) + [196607]
+    text = big.text_of(ids)
+    assert len(text) == len(ids) == len(set(text)) and big.encode(text) == ids
+    assert not any(0xD800 <= ord(ch) < 0xE000 for ch in text)
+    assert big.text_of([38911, 38912]) == chr(0xD7FF) + chr(0xE000)
+    # What a frame does to it: UTF-8 and JSON both ways, one char a token.
+    assert text.encode("utf-8").decode("utf-8") == text
+    assert json.loads(json.dumps({"content": text}))["content"] == text
+    from llmapigateway_tpu.engine.tokenizer import IncrementalDetokenizer
+    detok = IncrementalDetokenizer(big)
+    assert [detok.push(i) for i in (38912, 65, 196607)] == [
+        chr(0xE000), "A", big.text_of([196607])]
+    million = CharTokenizer(1_000_000)
+    assert million.encode(million.text_of([999_999])) == [999_999]
+    assert ord(million.text_of([999_999])) < 0x110000
+    with pytest.raises(ValueError, match="outside the vocabulary"):
+        CharTokenizer(38912).encode(chr(0xE000))
+    with pytest.raises(ValueError, match="does not fit"):
+        CharTokenizer(1_000_001)
+
+
 def test_tokenizer_is_one_character_per_id_both_ways():
     tok = CharTokenizer(32000)
     ids = list(range(3, 32000, 7)) + [10, 65, 127, 255, 256, 16384, 31999]
@@ -161,8 +204,101 @@ def test_exact_routing_is_the_programs_only_where_dispatch_drops_nothing():
         return np.concatenate(rows)
 
     n = correctness.DISPATCH_EXACT_TOKENS
-    exact, routed = logits(params, RefConfig.of(c), toks[:n + 8], last=n + 8)
+    exact, routed = logits_and_routing(params, RefConfig.of(c),
+                                       toks[:n + 8], last=n + 8)
     assert (routed.sum(-1) == 2).all()
     assert np.abs(served(n, n + 8) - exact).max() < 1e-4
-    wide, _ = logits(params, RefConfig.of(c), toks[:128], last=128)
+    wide = logits(params, RefConfig.of(c), toks[:128], last=128)
     assert np.abs(served(128, 128) - wide).max() > 0.1
+
+
+def test_the_default_sample_is_whole_chunks_at_the_benchmarks_bounds():
+    how = correctness.sampling("c", {})
+    assert how == correctness.Sampling(None, correctness.LOGIT_GAP_TOL,
+                                       correctness.LOGIT_GAP_P50_TOL)
+    assert (how.gap_tol, how.gap_p50_tol) == (0.25, 0.05)
+
+
+def test_a_file_states_its_exact_regime_and_tighter_bounds_only():
+    """Stop 2: what decided the sample was ``n_experts``; now it is what
+    the configuration's file says of its program, and a file can tighten
+    the bounds (with its reason) and never loosen them."""
+    why = "bfloat16 weights: no activation is re-quantised"
+    how = correctness.sampling("c", {"n_experts": 8, "correctness": {
+        "exact_up_to_tokens": 64,
+        "logit_gap_tol": {"value": 0.1, "why": why},
+        "logit_gap_p50_tol": {"value": 0.0, "why": why}}})
+    assert how == correctness.Sampling(64, 0.1, 0.0)
+    # Experts alone decide nothing: a program that routes exactly at every
+    # length is sampled at whole chunks like any other.
+    assert correctness.sampling(
+        "c", {"num_local_experts": 320}).exact_up_to_tokens is None
+    for block, says in [
+            ({"logit_gap_tol": {"value": 0.3, "why": why}}, "looser"),
+            ({"logit_gap_p50_tol": {"value": 0.06, "why": why}}, "looser"),
+            ({"logit_gap_tol": {"value": -1, "why": why}}, "looser"),
+            ({"logit_gap_tol": 0.1}, "a value with its why"),
+            ({"logit_gap_tol": {"value": 0.1}}, "a value with its why"),
+            ({"exact_up_to_tokens": 0}, "exact_up_to_tokens 0"),
+            ({"tolerance": 0.5}, "unknown correctness keys")]:
+        with pytest.raises(ValueError, match=says):
+            correctness.sampling("c", {"correctness": block})
+
+
+def test_a_reference_module_keeps_the_contract(tmp_path):
+    """Stop 1: the reference is found by the name in the configuration's
+    file, in the cell's data directory or the benchmark's own, and a file
+    that lacks one of the contract's names is refused where it is loaded."""
+    from benchmark import reference
+    from benchmark.reference import forward
+    assert reference.load({}, tmp_path) is forward
+    assert reference.load({"reference": "forward"}, tmp_path) is forward
+    assert reference.CONTRACT == ("sizes", "logits")
+    c = forward.sizes(_presets()["tiny-mistral-test"], {})
+    assert c == RefConfig.of(_presets()["tiny-mistral-test"])
+    (tmp_path / "reference").mkdir()
+    (tmp_path / "reference/half.py").write_text(
+        "def sizes(model_cfg, config):\n    return None\n")
+    with pytest.raises(TypeError, match=r"lacks \['logits'\]"):
+        reference.load({"reference": "half"}, tmp_path)
+    with pytest.raises(FileNotFoundError, match="reference/nowhere.py"):
+        reference.load({"reference": "nowhere"}, tmp_path)
+
+
+def _presets():
+    from llmapigateway_tpu.models import PRESETS
+    return PRESETS
+
+
+def test_the_second_reference_is_the_programs_mathematics_too():
+    """The rehearsal's architecture (``fixtures/tiny_hybrid``) brings a
+    reference written apart from ``forward.py``. Both agree with the
+    program's forward to float32 rounding on the same float32 weights, and
+    its kernel check passes on them and FAILS on an expert layer that
+    computes something else."""
+    import dataclasses
+    import types
+    from benchmark import spec
+    from llmapigateway_tpu.models import forward_fn, init_fn, llama
+    fixtures = __import__("pathlib").Path(__file__).parent / "fixtures"
+    ref = spec.load_module(fixtures, "tiny_hybrid", "reference")
+    c = dataclasses.replace(_presets()["tiny-moe-test"], n_layers=4,
+                            vocab_size=256)
+    params = init_fn(c)(c, jax.random.PRNGKey(2), jnp.float32)
+    toks = np.random.default_rng(2).integers(0, c.vocab_size, 40)
+    cache = llama.KVCache.create(c, 1, 64, jnp.float32)
+    served, _ = forward_fn(c)(params, c, jnp.asarray(toks[None], jnp.int32),
+                              jnp.zeros((1,), jnp.int32), cache)
+    file = {"num_experts_per_tok": 2}
+    rows = ref.logits(params, ref.sizes(c, file), toks, last=40)
+    assert rows.dtype == np.float32 and rows.shape == (40, 256)
+    assert np.abs(np.asarray(served[0]) - rows).max() < 1e-4
+    assert np.abs(logits(params, RefConfig.of(c), toks, 40) - rows).max() \
+        < 1e-4
+    engine = types.SimpleNamespace(model_cfg=c, params=params)
+    [case] = ref.kernel_checks(engine, file, interpret=True)
+    assert case["kernel"] == "moe_mlp_dense" and case["ok"]
+    assert case["max_abs_err"] < 1e-4
+    # One expert per token where the publication routes to two: not ok.
+    [wrong] = ref.kernel_checks(engine, {"num_experts_per_tok": 1}, True)
+    assert not wrong["ok"] and wrong["max_abs_err"] > 1e-2

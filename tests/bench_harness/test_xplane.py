@@ -271,3 +271,90 @@ def test_program_names():
     assert xplane.program_name("jit_prefill_step(18339941968962930558)") \
         == "prefill_step"
     assert xplane.program_name("jit__threefry_split(1)") == "_threefry_split"
+
+
+def hybrid_trace(layers=8, paged=2, steps=3):
+    """One chip, ``steps`` decode steps of a hybrid model (microseconds):
+    each of ``layers`` layers runs an expert matmul 0-6 under a scope of
+    the CONFIGURATION's (inside ``decode.mlp``); the first ``paged`` of
+    them then call the paged decode kernel 6-8, the others a recurrent
+    state update 6-9 under another of its scopes."""
+    meta = {**META,
+            6: ("%fusion.77 = bf16[8,1280] fusion(...)", "convolution fusion",
+                "jit(decode_scan)/while/body/decode.mlp/decode.experts/dot"),
+            7: ("%fusion.78 = f32[8,64,128,128] fusion(...)", "loop fusion",
+                "jit(decode_scan)/while/body/decode.linear_state/mul")}
+    ops, t = [], 0
+    for _ in range(steps):
+        for layer in range(layers):
+            ops.append((6, t, 6))
+            ops.append((5, t + 6, 2) if layer < paged else (7, t + 6, 3))
+            t += 10
+    mods = [(1, 0, t)]
+    return xplane.Trace.from_text_proto(plane(
+        1, "/device:TPU:0", {"XLA Modules": mods, "XLA Ops": ops}, meta))
+
+
+def test_a_configurations_scopes_come_before_the_benchmarks():
+    """Stop 6: a scope the program adds for a new layer kind fell under
+    the scope around it (or ``-``) and ``scope_share`` of it read nothing.
+    Handed to ``reduce``, innermost first, it files its ops."""
+    from benchmark import spec
+    trace = hybrid_trace()
+    before = xplane.reduce(trace)
+    assert before.self_ns(scope="decode.experts") == 0
+    assert before.self_ns(scope="decode.mlp") == 24 * 6_000
+    assert {op.scope for op in before.devices[0].ops} == {
+        "decode.mlp", "attention.paged_decode", ""}
+    scopes = spec.scopes({"scopes": ["decode.experts",
+                                     "decode.linear_state"]})
+    r = xplane.reduce(trace, scopes)
+    assert r.self_ns(scope="decode.experts") == 24 * 6_000
+    assert r.self_ns(scope="decode.mlp") == 0
+    assert r.self_ns(scope="decode.linear_state") == 18 * 3_000
+    assert r.self_ns(scope="attention.paged_decode") == 6 * 2_000
+    assert "decode_scan/decode.linear_state:loop_fusion" in {
+        op.key for op in r.devices[0].ops}
+    # Absent: the tuple as it is, the reduction as it was.
+    assert xplane.reduce(trace, spec.scopes({})).top_ops(5) == \
+        before.top_ops(5)
+
+
+@pytest.mark.parametrize("layer_kinds, steps_read", [
+    ({"period": 4, "paged_attention": [0]}, 3.0),    # 2 of 8 layers paged
+    ({}, 0.75),         # every layer taken to call the kernel: 4x wrong
+])
+def test_a_step_is_counted_by_the_layers_that_call_the_kernel(
+        layer_kinds, steps_read):
+    """Stop 5: ``step.decode_ms`` counts a step as one kernel event per
+    PAGED layer, and ``kernel.paged_decode_roofline`` multiplies a call's
+    least time by that many layers. With one layer in four paged, the
+    model's depth read both four times wrong."""
+    from benchmark import roofline, spec
+    from benchmark.metrics import RequestLog
+    from benchmark.reducers import REDUCERS, Measured
+    config = {"layer_kinds": layer_kinds} if layer_kinds else {}
+    shape = roofline.AttnShape(
+        n_layers=spec.paged_attention_layers(config, 8), n_heads=8,
+        n_kv_heads=1, head_dim=128, window=0, kv_bytes=2, kv_scale_bytes=0)
+    log = RequestLog(index=0, rid="r", prompt_tokens=1000, max_tokens=4,
+                     frames=[(0.5, 1), (1.5, 3)])
+    m = Measured(logs=[log], t_open=0.0, t_close=2.0,
+                 trace=xplane.reduce(hybrid_trace()), t_trace=(1.0, 2.0),
+                 flight=[], counters_open={}, counters_close={}, slots=8,
+                 shape=shape, peaks=roofline.PEAKS["TPU v5 lite"],
+                 peak_hbm_bytes=None, config=config)
+    total_ms = 3 * (8 * 6 + 2 * 2 + 6 * 3) / 1e3      # self time, no holes
+    ms = REDUCERS["program_ms_per_step"](m, {
+        "step_scope": "attention.paged_decode", "programs": ["decode_scan"]})
+    assert ms == pytest.approx(total_ms / steps_read)
+    # Three tokens after the first landed in the traced span, at contexts
+    # 1000, 1001, 1002: one call each in every paged layer.
+    least = sum(roofline.least_seconds(
+        *roofline.paged_decode_cost([n], shape), m.peaks)[0]
+        for n in (1000, 1001, 1002))
+    share = REDUCERS["kernel_roofline"](m, {
+        "kernel": "paged_decode", "scope": "attention.paged_decode"})
+    assert share == pytest.approx(
+        100 * least * shape.n_layers / (6 * 2e-6))
+    assert m.config is config
