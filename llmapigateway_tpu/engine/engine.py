@@ -462,6 +462,9 @@ class InferenceEngine:
             # own fetch of the same emitted matrix (parallel/multihost.py
             # wire-format notes).
 
+        if model_cfg.n_lin_layers:
+            self._refuse_for_recurrent_state()
+
         self.tokenizer = load_tokenizer(
             engine_cfg.tokenizer_path or engine_cfg.model_path or None,
             vocab_size=model_cfg.vocab_size)
@@ -471,6 +474,7 @@ class InferenceEngine:
         self._enable_debug_nans()
         _enable_compilation_cache(engine_cfg.compilation_cache_dir)
 
+        self._moe_totals = [0, 0]       # survive a rebuild of the state
         t0 = time.monotonic()
         self._init_params()
         t1 = time.monotonic()
@@ -556,6 +560,37 @@ class InferenceEngine:
             on_transition=self._on_lifecycle_transition)
         self._watchdog_task: asyncio.Task | None = None
         self._clean_steps = 0                           # guarded-by: loop
+
+    def _refuse_for_recurrent_state(self) -> None:
+        """What a family with a block of recurrent state per slot
+        (models/hybrid.py) cannot be served with. Each is refused here,
+        at build, with its reason — none is silently switched off."""
+        cfg, why = self.cfg, None
+        if not self.paged:
+            why = ("kv_layout 'contiguous': its softmax layers are served "
+                   "from the page pool only")
+        elif cfg.prefix_cache:
+            why = ("prefix_cache: a cached prefix holds KV pages but not "
+                   "the recurrent state at its end; set prefix_cache false")
+        elif self.spec_k:
+            why = ("spec_draft_len: a rejected draft cannot be rolled out "
+                   "of the recurrent state")
+        elif self.model_cfg.sliding_window:
+            why = ("a sliding window: the page ring is not wired to the "
+                   "pool of the softmax layers")
+        elif self.mesh.size > 1:
+            why = (f"mesh {dict(self.mesh.shape)}: the state block and the "
+                   f"held experts have no sharding rule yet")
+        elif cfg.disaggregation.enabled:
+            why = ("disaggregation: a handoff moves pages between slots, "
+                   "not the state block")
+        elif cfg.model_path:
+            why = ("model_path: no checkpoint mapping for this family "
+                   "(engine/checkpoint.py)")
+        if why:
+            raise ValueError(
+                f"the {self.model_cfg.family!r} family does not support "
+                f"{why}")
 
     def _on_lifecycle_transition(self, frm: str, to: str,
                                  reason: str) -> None:
@@ -644,6 +679,10 @@ class InferenceEngine:
         init = init_fn(c)
 
         def build(k):
+            if c.n_lin_layers:
+                # Quantises each matrix where it is drawn: a period of
+                # this family is 6 GB in bf16 (models/hybrid.py).
+                return init(c, k, dtype=self.dtype, quant=self.quant)
             if not self.quant:
                 return init(c, k, dtype=self.dtype)
             from ..models.quant import quantize_tree
@@ -772,10 +811,26 @@ class InferenceEngine:
             ssh = NamedSharding(
                 self.mesh, P(*psh.spec[:-2], None, psh.spec[-2]))
             side = {"q": psh, "s": ssh} if self.kv_quant else psh
-            self.cache = jax.jit(
-                partial(PagedKVCache.create, c, num_pages, page, self.dtype,
-                        kv_quant=self.kv_quant),
-                out_shardings=PagedKVCache(k=side, v=side))()
+            if c.n_lin_layers:
+                # The pool holds the softmax layers only; beside it a
+                # fixed block of recurrent state and a conv tail per slot
+                # (models/hybrid.py HybridCache). A prefill that starts at
+                # position 0 starts from zero state whatever the block
+                # holds, so release, cancel and rebuild do no state work.
+                from ..models.hybrid import HybridCache
+                rep_sh = NamedSharding(self.mesh, P())
+                self.cache = jax.jit(
+                    partial(HybridCache.create, c, num_pages, page, self.B,
+                            self.dtype, kv_quant=self.kv_quant),
+                    out_shardings=HybridCache(
+                        k=side, v=side, counters=rep_sh,
+                        state=(rep_sh,) * (c.layer_period - 1),
+                        conv=(rep_sh,) * (c.layer_period - 1)))()
+            else:
+                self.cache = jax.jit(
+                    partial(PagedKVCache.create, c, num_pages, page,
+                            self.dtype, kv_quant=self.kv_quant),
+                    out_shardings=PagedKVCache(k=side, v=side))()
             self._d_table = None
             self._table_dirty = True
         else:
@@ -801,6 +856,10 @@ class InferenceEngine:
                 self.cache = llama.KVCache(
                     k=zeros_global(shape, self.dtype, csh),
                     v=zeros_global(shape, self.dtype, csh))
+        # Routed assignments of the decode steps (hybrid family): the
+        # device keeps wrapping int32 totals in the cache, every burst
+        # hands them back beside its tokens, the host sums the deltas.
+        self._moe_seen = np.zeros((2,), np.int64)
         # Host-authoritative per-slot state, mirrored to device each step.
         self.lengths = np.zeros((self.B,), np.int32)
         self.active = np.zeros((self.B,), bool)
@@ -1212,7 +1271,8 @@ class InferenceEngine:
                                       active=active, attention_fn=attn)
         else:
             def call_forward(params, cache, table, tokens, lengths,
-                             active=None, prefill=False, spec=False):
+                             active=None, prefill=False, spec=False,
+                             **rows):
                 # `spec` builds the dedicated verify-capable provider:
                 # T = k+1 then routes through the deferred paged verify
                 # (stale-pool gather + mixed-precision self-block) instead
@@ -1224,7 +1284,18 @@ class InferenceEngine:
                                                pages_per_block=self.kv_ppb,
                                                spec=spec)
                 return family_forward(params, c, tokens, lengths, cache,
-                                      active=active, attention_fn=attn)
+                                      active=active, attention_fn=attn,
+                                      **rows)
+
+        def engine_cache(cache):
+            """The forward's cache as the type the engine holds (a family
+            with state of its own returns its own type whole)."""
+            return cache if c.n_lin_layers else PagedKVCache(
+                k=cache.k, v=cache.v)
+
+        # A family with per-slot state is told which slot each prefill row
+        # is and how many of its tokens are real (the rest pad the bucket).
+        rows_known = c.n_lin_layers > 0
 
         @partial(jax.jit, donate_argnums=(1, 2))
         def prefill_step(params, cache: PagedKVCache, counts: jax.Array,
@@ -1245,8 +1316,10 @@ class InferenceEngine:
             rows_tbl = jnp.concatenate(
                 [jax.lax.dynamic_slice_in_dim(table, slots[k], 1, axis=0)
                  for k in range(K)], axis=0)
-            logits, cache = call_forward(params, cache, rows_tbl, tokens,
-                                         start_len, prefill=True)
+            logits, cache = call_forward(
+                params, cache, rows_tbl, tokens, start_len, prefill=True,
+                **({"slots": slots, "n_valid": last_idx + 1}
+                   if rows_known else {}))
             counts, count_rows = _prefill_counts(
                 counts, tokens, start_len, slots, last_idx)
             rows = jax.lax.with_sharding_constraint(
@@ -1259,7 +1332,7 @@ class InferenceEngine:
             with jax.named_scope("sampling"):
                 first = jax.lax.with_sharding_constraint(
                     sample(rows, samp, key, counts=count_rows), replicated)
-            return first, counts, PagedKVCache(k=cache.k, v=cache.v)
+            return first, counts, engine_cache(cache)
 
         def one_step(params, cache: PagedKVCache, counts: jax.Array,
                      table: jax.Array,
@@ -1287,11 +1360,11 @@ class InferenceEngine:
                 next_tokens = jax.lax.with_sharding_constraint(
                     next_tokens, replicated)
             new_lengths = jnp.where(active, lengths + 1, lengths)
-            return (next_tokens, new_lengths, counts,
-                    PagedKVCache(k=cache.k, v=cache.v))
+            return (next_tokens, new_lengths, counts, engine_cache(cache))
 
         self._prefill_fn = prefill_step
-        self._decode_fns = _decode_programs(one_step, self._burst_depths)
+        self._decode_fns = _decode_programs(one_step, self._burst_depths,
+                                            counters=c.n_lin_layers > 0)
 
         if self.spec_k:
             from .speculative import make_spec_burst, make_spec_step
@@ -3170,7 +3243,13 @@ class InferenceEngine:
             return []
         toks_dev, n, active_snap, epoch_snap, len_snap, last_snap = entry
         with _device_phase("sched.fetch"):
-            host = np.asarray(toks_dev)                  # [n, B]
+            host = np.asarray(toks_dev)                  # [n, B (+ counters)]
+        if host.shape[1] > self.B:
+            seen = host[-1, self.B:].astype(np.int64)
+            for i, d in enumerate((seen - self._moe_seen) & 0xFFFFFFFF):
+                self._moe_totals[i] += int(d)
+            self._moe_seen = seen
+            host = host[:, :self.B]
         live = active_snap & (epoch_snap == self._slot_epoch)
         for slot in np.nonzero(live)[0]:
             self.last_token[slot] = int(host[-1][slot])
@@ -3669,8 +3748,17 @@ class InferenceEngine:
             # loop and must not even look like a device sync (graftlint v2
             # chases this call from the async stats handlers).
             elem = float(np.dtype(self.dtype).itemsize)
-        return int(2 * c.n_layers * c.n_kv_heads * c.head_dim * elem
+        return int(2 * c.n_kv_layers * c.n_kv_heads * c.head_dim * elem
                    * int(live.sum()))
+
+    def _state_bytes(self) -> int:
+        """Bytes of recurrent state and conv tails resident beside the KV
+        pool (0 for a family that keeps none); a decode step reads and
+        writes all of it."""
+        return sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                   for a in jax.tree.leaves(
+                       (getattr(self.cache, "state", ()),
+                        getattr(self.cache, "conv", ()))))
 
     def _build_ledger(self):
         """Static HBM accounting (ISSUE 8): what the engine INTENDS to
@@ -3691,14 +3779,15 @@ class InferenceEngine:
         page = self.kv_page
         if self.paged:
             tokens = self.allocator.num_pages * page
-            page_bytes = 2 * c.n_layers * c.n_kv_heads * page * (
+            page_bytes = 2 * c.n_kv_layers * c.n_kv_heads * page * (
                 c.head_dim * kv_elem + kv_scale)
         else:
             tokens = self.B * self.S
             page_bytes = 0
-        kv_pool = 2 * c.n_layers * c.n_kv_heads * tokens * (
+        kv_pool = 2 * c.n_kv_layers * c.n_kv_heads * tokens * (
             c.head_dim * kv_elem + kv_scale)
         aux = self.B * c.vocab_size * 4          # penalty counts [B, V]
+        aux += self._state_bytes()               # recurrent state, conv tails
         if self.paged:
             aux += int(self.allocator.table.size) * 4   # device page table
         spec = self.B * self.S * 4 if self.spec_k else 0  # device hist
@@ -3744,7 +3833,8 @@ class InferenceEngine:
         def bytes_for(kind: str) -> int | None:
             if kind in ("decode", "spec"):
                 return (self._resident_param_bytes()
-                        + self._kv_bytes_per_step())
+                        + self._kv_bytes_per_step()
+                        + 2 * self._state_bytes())
             return None
         return self.kernels.table(
             bytes_per_step_fn=bytes_for, peak_gbps=self.cfg.hbm_peak_gbps,
@@ -3792,6 +3882,16 @@ class InferenceEngine:
                 # and the bench's shared-prefix rung asserts skipped
                 # prefill from them (not from wall clock).
                 out.update(self._prefix_cache.stats())
+        if self.model_cfg.n_lin_layers:
+            # Recurrent state beside the pool, and the expert layer's
+            # share: the assignments the decode steps routed, and those
+            # that landed on an expert held here (their ratio is the
+            # share of the experts held when routing is even).
+            out["state_bytes_resident"] = self._state_bytes()
+            out["state_slots"] = self.B
+            out["moe_experts_held"] = self.model_cfg.experts_held
+            out["moe_assignments_total"] = self._moe_totals[0]
+            out["moe_assignments_local_total"] = self._moe_totals[1]
         gauge = (self._ema_step_ms_stats
                  if self._ema_step_ms_stats is not None
                  else self._step_ms_estimate())
@@ -3805,7 +3905,8 @@ class InferenceEngine:
         # the measured step time — the number the bench ladder and the
         # stats UI both read, so the 0.478→1.0 roofline trajectory is a
         # reading instead of a post-hoc reconstruction.
-        hbm_bytes = self._resident_param_bytes() + self._kv_bytes_per_step()
+        hbm_bytes = (self._resident_param_bytes()
+                     + self._kv_bytes_per_step() + 2 * self._state_bytes())
         out["hbm_bytes_per_step"] = hbm_bytes
         if gauge:
             out["achieved_gbps"] = round(hbm_bytes / (gauge / 1e3) / 1e9, 1)
@@ -4027,7 +4128,8 @@ def _prefill_counts(counts, tokens, start_len, slots, last_idx):
     return counts, jnp.stack(rows)
 
 
-def _decode_programs(one_step, burst_lens: tuple[int, ...]):
+def _decode_programs(one_step, burst_lens: tuple[int, ...],
+                     counters: bool = False):
     """Build the decode programs from one step body: the per-step program,
     and a fused lax.scan per distinct burst length in ``burst_lens`` — ONE
     dispatch + ONE host fetch per burst instead of per step. Two
@@ -4059,7 +4161,13 @@ def _decode_programs(one_step, burst_lens: tuple[int, ...]):
                     nt, nl, counts, cache = step(
                         params, cache, counts, *table, tokens,
                         lengths, active, samp, sub)
-                    return (cache, counts, nt, nl, key), nt
+                    # ``counters``: the cache carries device-side counters
+                    # (models/hybrid.py) and hands them over beside the
+                    # step's tokens: they ride the burst's one fetch.
+                    row = jnp.concatenate(
+                        [nt, cache.counters.astype(nt.dtype)]
+                    ) if counters else nt
+                    return (cache, counts, nt, nl, key), row
                 (cache, counts, tokens, lengths, key), toks = jax.lax.scan(
                     body, (cache, counts, tokens, lengths, key), None,
                     length=n_burst)

@@ -6,7 +6,7 @@ Llama-3-70B (config 5, multi-host).
 """
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 
 
 @dataclass(frozen=True)
@@ -34,7 +34,7 @@ class RopeScaling:
 
 @dataclass(frozen=True)
 class ModelConfig:
-    family: str = "llama"          # "llama" | "qwen2" | "gemma" | "mixtral"
+    family: str = "llama"   # "llama" | "qwen2" | "gemma" | "mixtral" | "hybrid"
     vocab_size: int = 32000
     d_model: int = 2048
     n_layers: int = 22
@@ -65,6 +65,23 @@ class ModelConfig:
     # MoE (mixtral) fields
     n_experts: int = 0             # 0 → dense
     experts_per_token: int = 2
+    # The "hybrid" family (models/hybrid.py): linear-attention layers with
+    # one softmax layer per period, and an expert layer that may hold only
+    # this chip's share of the experts. The router always scores all
+    # ``n_experts``; experts [first_expert_held, first_expert_held +
+    # n_experts_held) live here and only their part of the result is
+    # computed (0 held = all of them).
+    n_experts_held: int = 0
+    first_expert_held: int = 0
+    d_ff_expert: int = 0           # routed (and shared) expert width
+    n_shared_experts: int = 0      # always-on experts of width d_ff_expert
+    layer_period: int = 0          # layers per period; position 0 is softmax
+    lin_heads: int = 0             # linear-attention heads ...
+    lin_head_dim: int = 0          # ... their key/value size ...
+    lin_conv_taps: int = 0         # ... causal depthwise conv before q/k/v
+    lin_gate_rank: int = 0         # rank of the decay and output gate pairs
+    use_rope: bool = True          # False: no rotary on the softmax layers
+    attn_gate: bool = False        # softmax output gated by sigmoid(W x)
 
     @property
     def head_dim(self) -> int:
@@ -73,6 +90,21 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def experts_held(self) -> int:
+        return self.n_experts_held or self.n_experts
+
+    @property
+    def n_kv_layers(self) -> int:
+        """Layers that keep paged KV: all, or one per period."""
+        return (self.n_layers // self.layer_period if self.layer_period
+                else self.n_layers)
+
+    @property
+    def n_lin_layers(self) -> int:
+        """Layers that keep a recurrent state block per slot."""
+        return self.n_layers - self.n_kv_layers
 
 
 PRESETS: dict[str, ModelConfig] = {
@@ -162,7 +194,33 @@ PRESETS: dict[str, ModelConfig] = {
         family="mixtral", vocab_size=32000, d_model=4096, n_layers=32,
         n_heads=32, n_kv_heads=8, d_ff=14336, rope_theta=1000000.0,
         max_seq_len=32768, n_experts=8, experts_per_token=2),
+    # Solar-Open2-250B (HF: upstage/Solar-Open2-250B) at its PUBLISHED sizes:
+    # 48 layers in periods of 4 (one gated NoPE GQA layer, three gated
+    # delta-rule linear layers), 320 routed experts of width 1280, top-8,
+    # one shared. No chip holds it whole: a deployment states the experts,
+    # depth and vocabulary rows it holds (benchmark/configs/).
+    "solar-open2-250b": ModelConfig(
+        family="hybrid", vocab_size=196608, d_model=4096, n_layers=48,
+        n_heads=64, n_kv_heads=8, head_dim_override=128, d_ff=10240,
+        rope_theta=10000.0, max_seq_len=1048576, n_experts=320,
+        experts_per_token=8, d_ff_expert=1280, n_shared_experts=1,
+        layer_period=4, lin_heads=64, lin_head_dim=128, lin_conv_taps=4,
+        lin_gate_rank=128, use_rope=False, attn_gate=True),
+    # The same pattern at CPU-test size: two periods, 16 experts, top-4.
+    "tiny-hybrid-test": ModelConfig(
+        family="hybrid", vocab_size=512, d_model=64, n_layers=8, n_heads=4,
+        n_kv_heads=2, head_dim_override=16, d_ff=128, max_seq_len=256,
+        n_experts=16, experts_per_token=4, d_ff_expert=32,
+        n_shared_experts=1, layer_period=4, lin_heads=4, lin_head_dim=16,
+        lin_conv_taps=4, lin_gate_rank=8, use_rope=False, attn_gate=True),
 }
+# What ONE v5e chip holds of it as one of 8 that share each layer of a
+# pipeline stage (benchmark/configs/solar-open2-250b-ep8.json): two whole
+# periods, 40 of the 320 experts, an eighth of the vocabulary rows. Every
+# width, the router's 320 outputs and its 8 experts per token stay.
+PRESETS["solar-open2-250b-ep8"] = replace(
+    PRESETS["solar-open2-250b"], n_layers=8, vocab_size=24576,
+    n_experts_held=40)
 
 
 def get_preset(name: str) -> ModelConfig:

@@ -1,0 +1,689 @@
+"""The "hybrid" family (Solar-Open2): layers of two kinds in one forward,
+and an expert layer that holds a share of its experts.
+
+A period of ``layer_period`` layers is one softmax layer — grouped-query
+attention with no rotary embedding, its output gated by ``sigmoid(W x)`` —
+followed by linear-attention layers: a gated delta rule with a per-channel
+decay. Per head the linear layer keeps a state ``S`` [dk, dv]::
+
+    S_t = (I - b_t k_t k_t^T) diag(a_t) S_{t-1} + b_t k_t v_t^T
+    o_t = S_t^T q_t
+
+with ``q, k, v = SiLU(conv(W x))`` (a causal depthwise convolution over
+time), ``q`` and ``k`` L2-normalised per head, ``b_t = 2 sigmoid(W_b x)``
+and ``a_t = exp(-exp(A) softplus(W_f x + bias))``. Every layer's MLP is a
+sparse expert layer: a sigmoid router over ALL ``n_experts``, the top-k
+scores normalised to sum 1, plus shared experts. The layer is told which
+experts it holds (``first_expert_held``, ``n_experts_held``) and computes
+their part of the result only: what the absent experts would add is left
+out (one of several chips that share a layer; on one chip there is no
+exchange). No assignment to a held expert is ever dropped.
+
+TPU-first decisions:
+
+* ``lax.scan`` over PERIODS, one compiled body whatever the depth. Every
+  layer of a period has its own tree stacked ``[P, ...]`` (the softmax
+  layer, and a tuple of the linear ones), so that the body reads each
+  weight through ONE dynamic slice of its leading axis, as
+  ``llama.forward`` does: an inner scan over a period's linear layers
+  hands the chip's compiler a sliced copy of a period's weights (the
+  decode program compiled for a v5e at the published widths: 5.1 GB of
+  temporaries with the inner scan, 2.0 GB without).
+* The softmax layers use the ``attention_fn`` protocol of
+  ``llama.forward`` on a page pool of ``n_layers / layer_period`` layers:
+  both paged kernels serve them unchanged.
+* The cache is the page pool PLUS a fixed block of recurrent state and a
+  convolution tail per slot (``HybridCache``). Prefill gathers the rows of
+  its slots, runs the block-parallel form over sub-chunks of 64 tokens and
+  scatters the rows back; decode rewrites the whole block once a step.
+  State is float32.
+* The expert layer has two exact forms: every held expert on every token
+  (decode, small calls: the weights stream from memory either way), and a
+  grouped product over the assignments sorted by expert, tile by tile,
+  whose trip count is the number of live tiles.
+"""
+from __future__ import annotations
+
+import math
+from functools import partial
+from typing import Any, Callable, NamedTuple
+
+import jax
+import jax.numpy as jnp
+
+from .config import ModelConfig
+from .llama import rms_norm, swiglu_mlp
+from .quant import (head_matmul, mm, moe_mm_batched, quantize_array,
+                    weight_bits)
+
+Params = dict[str, Any]
+HIGHEST = jax.lax.Precision.HIGHEST
+
+KDA_CHUNK = 64          # tokens per sub-chunk of the block-parallel form
+KDA_BLOCK = 16          # tokens per block inside a sub-chunk (exact decays)
+DENSE_MAX_TOKENS = 64   # calls up to this size run every held expert
+GROUP_TILE = 128        # rows per tile of the grouped expert product
+EXPERT_KEYS = ("wg", "wu", "wd")    # the routed experts' matrices
+# Stored int8 under quant (contraction axis second to last): the projections
+# of both layer kinds, the softmax gate, routed and shared experts. NOT the
+# router, the rank-r gate pairs, W_b, the conv taps, A, the bias or the
+# norms: small, and they decide routing and decay.
+QUANT_KEYS = frozenset({"wq", "wk", "wv", "wo", "wgate", "sg", "su", "sd",
+                        *EXPERT_KEYS})
+
+
+class HybridCache(NamedTuple):
+    """``k``, ``v``: the page pool of the softmax layers, as
+    ``PagedKVCache`` ([P, pages, KV, page, Dh], or its int8 dict).
+    ``state`` and ``conv``: the linear layers' recurrent state and the
+    last inputs of their convolutions, one fixed block per slot — a tuple
+    over a period's linear layers of [P, B, H, dk, dv] float32 and of
+    [P, B, taps-1, 3*H*dk]. ``counters``
+    int32 [2]: routed assignments of the DECODE steps so far (all; landing
+    on a held expert), running totals that wrap."""
+    k: Any
+    v: Any
+    state: tuple[jax.Array, ...]
+    conv: tuple[jax.Array, ...]
+    counters: jax.Array
+
+    @classmethod
+    def create(cls, config: ModelConfig, num_pages: int, page_size: int,
+               batch: int, dtype=jnp.bfloat16, kv_quant: str = ""
+               ) -> "HybridCache":
+        from dataclasses import replace
+        from ..ops.paged_attention import PagedKVCache
+        c = config
+        pool = PagedKVCache.create(replace(c, n_layers=c.n_kv_layers),
+                                   num_pages, page_size, dtype, kv_quant)
+        lead, lin = (c.n_kv_layers, batch), range(c.layer_period - 1)
+        return cls(
+            k=pool.k, v=pool.v,
+            state=tuple(jnp.zeros(lead + (c.lin_heads, c.lin_head_dim,
+                                          c.lin_head_dim), jnp.float32)
+                        for _ in lin),
+            conv=tuple(jnp.zeros(lead + (c.lin_conv_taps - 1,
+                                         3 * c.lin_heads * c.lin_head_dim),
+                                 dtype) for _ in lin),
+            counters=jnp.zeros((2,), jnp.int32))
+
+
+# ---------------------------------------------------------------------------
+# Initialization
+# ---------------------------------------------------------------------------
+
+def init_params(config: ModelConfig, key: jax.Array,
+                dtype: jnp.dtype = jnp.bfloat16, quant: str = "") -> Params:
+    """Seeded random params. With ``quant`` every matrix of ``QUANT_KEYS``
+    is quantised where it is drawn, an expert at a time, so that no
+    full-precision copy of a period (6 GB at the published widths) ever
+    exists.
+
+    Layout (P periods of ``per`` layers; D model, H heads of Dh, KV heads;
+    Hl linear heads of dk; r gate rank; E experts of which ``held`` live
+    here, width F; Fs shared width):
+      embed [V, D]; final_norm [D]; lm_head [V, D]
+      layers/attn/{norm [P,D], wq [P,D,H*Dh], wk, wv [P,D,KV*Dh],
+                   wgate [P,D,H*Dh], wo [P,H*Dh,D], mlp/...}
+      layers/lin/(per-1 trees of){norm [P,D], wq, wk, wv [P,D,Hl*dk],
+                  conv [P,taps,3*Hl*dk], wf_down [P,D,r],
+                  wf_up [P,r,Hl*dk], f_bias [P,Hl*dk], a_log [P,Hl],
+                  wbeta [P,D,Hl], wg_down [P,D,r], wg_up [P,r,Hl*dk],
+                  out_norm [P,dk], wo [P,Hl*dk,D], mlp/...}
+      .../mlp/{norm [P,D], router [P,D,E], wg, wu [P,held,D,F],
+               wd [P,held,F,D], sg, su [P,D,Fs], sd [P,Fs,D]}
+    The residual stream is drawn at unit scale and every projection back
+    into it (``wo``, ``wd``, ``sd``) at ``(2 n_layers)^-1/2`` of the usual
+    (the GPT-2 convention): a delta rule with b up to 2 and slow decays
+    answers a relative change of its input with ~3 times that change of
+    its output, and with branches as large as the stream a rounding error
+    doubles every period — the random network would be chaotic, which no
+    trained one is. The decay parameters are drawn so that the median per-channel decay
+    lies in 0.9-0.999 (``a_log = log U(1,8)``, ``softplus(f_bias)`` log-
+    uniform in 0.001-0.05, a small ``wf_up``): with unit-scale gates the
+    layer would forget in a token and no comparison could see its state.
+    """
+    c = config
+    if c.family != "hybrid" or not c.layer_period or c.n_layers % c.layer_period:
+        raise ValueError("hybrid.init_params needs family 'hybrid' and "
+                         "whole periods of layers")
+    if c.use_rope or not c.attn_gate:
+        raise ValueError("the hybrid family's softmax layers carry no "
+                         "rotary embedding and gate their output: use_rope "
+                         "must be False, attn_gate True")
+    per, P = c.layer_period, c.n_kv_layers
+    D, dh, dk, Hl, r = (c.d_model, c.head_dim, c.lin_head_dim, c.lin_heads,
+                        c.lin_gate_rank)
+    F, held = c.d_ff_expert, c.experts_held
+    Fs = c.n_shared_experts * F
+    back = (2 * c.n_layers) ** -0.5     # projections into the residual
+
+    def dense(k, *shape, scale=1.0, name=""):
+        w = (jax.random.normal(k, shape, jnp.float32)
+             * (scale / math.sqrt(shape[-2]))).astype(dtype)
+        if quant and name in QUANT_KEYS:
+            return quantize_array(w, w.ndim - 2,
+                                  bits=weight_bits(quant, f"layers.{name}"))
+        return w
+
+    def mlp(k):
+        ks = jax.random.split(k, 7)
+
+        def expert(ke):
+            kg, ku, kd = jax.random.split(ke, 3)
+            return {"wg": dense(kg, D, F, name="wg"),
+                    "wu": dense(ku, D, F, name="wu"),
+                    "wd": dense(kd, F, D, scale=back, name="wd")}
+        out = jax.lax.map(expert, jax.random.split(ks[0], held))
+        out.update(
+            norm=jnp.ones((D,), dtype), router=dense(ks[1], D, c.n_experts),
+            sg=dense(ks[2], D, Fs, name="sg"),
+            su=dense(ks[3], D, Fs, name="su"),
+            sd=dense(ks[4], Fs, D, scale=back, name="sd"))
+        return out
+
+    def attn_layer(k):
+        ks = jax.random.split(k, 6)
+        return {"norm": jnp.ones((D,), dtype),
+                "wgate": dense(ks[3], D, c.n_heads * dh, name="wgate"),
+                "wq": dense(ks[0], D, c.n_heads * dh, name="wq"),
+                "wk": dense(ks[1], D, c.n_kv_heads * dh, name="wk"),
+                "wv": dense(ks[2], D, c.n_kv_heads * dh, name="wv"),
+                "wo": dense(ks[4], c.n_heads * dh, D, scale=back, name="wo"),
+                "mlp": mlp(ks[5])}
+
+    def lin_layer(k):
+        ks = jax.random.split(k, 13)
+        step = jnp.exp(jax.random.uniform(
+            ks[8], (Hl * dk,), jnp.float32, math.log(1e-3), math.log(5e-2)))
+        return {"norm": jnp.ones((D,), dtype),
+                "wq": dense(ks[0], D, Hl * dk, name="wq"),
+                "wk": dense(ks[1], D, Hl * dk, name="wk"),
+                "wv": dense(ks[2], D, Hl * dk, name="wv"),
+                "conv": jax.random.normal(
+                    ks[3], (c.lin_conv_taps, 3 * Hl * dk), jnp.float32)
+                / math.sqrt(c.lin_conv_taps),
+                "wf_down": dense(ks[4], D, r),
+                "wf_up": dense(ks[5], r, Hl * dk, scale=0.5),
+                # softplus(f_bias) = step
+                "f_bias": jnp.log(jnp.expm1(step)),
+                "a_log": jnp.log(jax.random.uniform(
+                    ks[7], (Hl,), jnp.float32, 1.0, 8.0)),
+                "wbeta": dense(ks[6], D, Hl),
+                "wg_down": dense(ks[9], D, r),
+                "wg_up": dense(ks[10], r, Hl * dk),
+                "out_norm": jnp.ones((dk,), dtype),
+                "wo": dense(ks[11], Hl * dk, D, scale=back, name="wo"),
+                "mlp": mlp(ks[12])}
+
+    def period(k):
+        ka, *kl = jax.random.split(k, per)
+        return {"attn": attn_layer(ka), "lin": tuple(map(lin_layer, kl))}
+
+    k_embed, k_head, k_layers = jax.random.split(key, 3)
+    head = (jax.random.normal(k_head, (c.vocab_size, D), jnp.float32)
+            / math.sqrt(D)).astype(dtype)
+    return {
+        "embed": jax.random.normal(k_embed, (c.vocab_size, D),
+                                   jnp.float32).astype(dtype),
+        "final_norm": jnp.ones((D,), dtype),
+        "lm_head": (quantize_array(head, 1, bits=weight_bits(quant, "lm_head"))
+                    if quant else head),
+        "layers": jax.lax.map(period, jax.random.split(k_layers, P)),
+    }
+
+
+# ---------------------------------------------------------------------------
+# The linear layer
+# ---------------------------------------------------------------------------
+
+def kda_recurrent(q, k, v, log_a, beta, s0):
+    """The recurrence token by token — the definition ``kda_chunked`` and
+    ``kda_decode_update`` are held to, and what ``kda_chunked`` runs for a
+    call that is not whole sub-chunks.
+    q, k [B,T,H,dk] (normalised, q scaled), v [B,T,H,dv], log_a [B,T,H,dk]
+    (<= 0), beta [B,T,H], s0 [B,H,dk,dv]; all float32.
+    Returns (o [B,T,H,dv], s_T)."""
+    def step(s, x):
+        o, s = kda_decode_update(*x, s)
+        return s, o
+    xs = jax.tree.map(lambda a: jnp.moveaxis(a, 1, 0), (q, k, v, log_a, beta))
+    s, o = jax.lax.scan(step, s0, xs)
+    return jnp.moveaxis(o, 0, 1), s
+
+
+def kda_decode_update(q, k, v, log_a, beta, s):
+    """One token: q, k, log_a [B,H,dk], v [B,H,dv], beta [B,H], s
+    [B,H,dk,dv] -> (o [B,H,dv], s_new). Reductions are multiply-and-sum in
+    float32 (a step is bound by reading and writing ``s``); both
+    reductions over the decayed state share its one read:
+    ``o = S_new^T q = S_dec^T q + u (k . q)``."""
+    with jax.named_scope("kda.decode_update"):
+        s_dec = s * jnp.exp(log_a)[..., None]
+        r_k = jnp.sum(s_dec * k[..., None], axis=-2)
+        r_q = jnp.sum(s_dec * q[..., None], axis=-2)
+        u = beta[..., None] * (v - r_k)
+        o = r_q + u * jnp.sum(k * q, axis=-1, keepdims=True)
+        return o, s_dec + k[..., None] * u[..., None, :]
+
+
+def _unit_lower_inverse(a: jax.Array) -> jax.Array:
+    """(I + A)^-1 for strictly lower-triangular A [..., n, n], by forward
+    substitution in float32 (n is a block: 16 rows, unrolled)."""
+    n = a.shape[-1]
+    x = jnp.broadcast_to(jnp.eye(n, dtype=a.dtype), a.shape)
+    for t in range(1, n):
+        row = x[..., t, :] - jnp.sum(a[..., t, :, None] * x, axis=-2)
+        x = x.at[..., t, :].set(row)
+    return x
+
+
+def kda_chunked(q, k, v, log_a, beta, s0, chunk: int = KDA_CHUNK,
+                block: int = KDA_BLOCK):
+    """The block-parallel form of :func:`kda_recurrent` (same arguments and
+    result), over sub-chunks of ``chunk`` tokens that carry ``S``.
+
+    With ``g`` the cumulative log-decay inside a sub-chunk, ``A[t,s] = b_t
+    sum_c k_t k_s e^{g_t-g_s}`` (s < t) and ``N[t,s] = sum_c q_t k_s
+    e^{g_t-g_s}`` (s <= t), the delta values solve ``(I + A) U = b (V -
+    (e^g K) S_0)``, the outputs are ``(e^g Q) S_0 + N U`` and the state
+    leaves as ``e^{g_C} S_0 + (e^{g_C-g} K)^T U``. Every decay is the
+    exponential of a DIFFERENCE of cumulative logs that is <= 0 — never a
+    quotient of cumulative products, which underflows with a fast decay:
+    across blocks of ``block`` tokens the difference is split at the
+    t-block's first cumulative log (both factors <= 1, so the products are
+    two matrix products); inside a block it is formed per channel."""
+    B, T, H, dk = q.shape
+    dv = v.shape[-1]
+    C = min(chunk, T)
+    bl = min(block, C)
+    n, nb = T // C, C // bl
+    if T % C or C % bl:
+        return kda_recurrent(q, k, v, log_a, beta, s0)
+
+    def sub(x):                     # [B,T,H,..] -> [n,B,H,C,..]
+        x = x.reshape(B, n, C, H, *x.shape[3:])
+        return jnp.moveaxis(jnp.moveaxis(x, 3, 2), 1, 0)
+
+    dot = partial(jnp.einsum, precision=HIGHEST)
+    # s lies in a block before t's: [nb, bl, C]
+    earlier = jnp.broadcast_to(
+        jnp.arange(C)[None, None, :] < (jnp.arange(nb) * bl)[:, None, None],
+        (nb, bl, C))
+    upto = jnp.tril(jnp.ones((bl, bl), bool))           # s <= t in a block
+    before = jnp.tril(jnp.ones((bl, bl), bool), -1)     # s < t
+
+    def step(s, x):
+        with jax.named_scope("kda.prefill_chunk"):
+            qc, kc, vc, lc, bc = x                      # [B,H,C,..]
+            g = jnp.cumsum(lc, axis=2)                  # [B,H,C,dk]
+            gb = g.reshape(B, H, nb, bl, dk)
+            qb, kb = (a.reshape(B, H, nb, bl, dk) for a in (qc, kc))
+            bb = bc.reshape(B, H, nb, bl)
+            # Across blocks: split at ref_i = g just before block i.
+            ref = jnp.concatenate(
+                [jnp.zeros_like(gb[:, :, :1, -1]), gb[:, :, :-1, -1]], 2)
+            e_t = jnp.exp(gb - ref[:, :, :, None, :])           # <= 1
+            ks = kc[:, :, None] * jnp.exp(jnp.minimum(
+                ref[:, :, :, None, :] - g[:, :, None, :, :], 0.0))
+            a_x = jnp.where(earlier, bb[..., None] * dot(
+                "bhitc,bhisc->bhits", kb * e_t, ks), 0.0)   # [B,H,nb,bl,C]
+            n_x = jnp.where(earlier, dot(
+                "bhitc,bhisc->bhits", qb * e_t, ks), 0.0)
+            # Inside a block: the decay per channel.
+            kk = kb[:, :, :, None, :, :] * jnp.exp(jnp.minimum(
+                gb[:, :, :, :, None, :] - gb[:, :, :, None, :, :], 0.0))
+            a_d = jnp.where(before, bb[..., None] * jnp.sum(
+                kb[:, :, :, :, None, :] * kk, -1), 0.0)     # [B,H,nb,bl,bl]
+            n_d = jnp.where(upto, jnp.sum(
+                qb[:, :, :, :, None, :] * kk, -1), 0.0)
+            decay = jnp.exp(g)
+            w = bc[..., None] * (vc - dot("bhtc,bhcv->bhtv", kc * decay, s))
+            # (I + A) U = W block by block: the diagonal blocks inverted by
+            # forward substitution, the blocks below them matrix products.
+            inv = _unit_lower_inverse(a_d)
+            wb = w.reshape(B, H, nb, bl, dv)
+            ab = a_x.reshape(B, H, nb, bl, nb, bl)
+            us: list[jax.Array] = []
+            for i in range(nb):
+                rhs = wb[:, :, i]
+                for j in range(i):
+                    rhs = rhs - dot("bhts,bhsv->bhtv", ab[:, :, i, :, j],
+                                    us[j])
+                us.append(dot("bhts,bhsv->bhtv", inv[:, :, i], rhs))
+            ub = jnp.stack(us, axis=2)                  # [B,H,nb,bl,dv]
+            u = ub.reshape(B, H, C, dv)
+            o = (dot("bhtc,bhcv->bhtv", qc * decay, s)
+                 + (dot("bhits,bhsv->bhitv", n_x, u)
+                    + dot("bhits,bhisv->bhitv", n_d, ub)
+                    ).reshape(B, H, C, dv))
+            g_end = g[:, :, -1:, :]
+            s = (jnp.exp(g_end[:, :, 0])[..., None] * s
+                 + dot("bhtc,bhtv->bhcv", kc * jnp.exp(g_end - g), u))
+            return s, o
+
+    s, o = jax.lax.scan(step, s0, tuple(map(sub, (q, k, v, log_a, beta))))
+    # [n,B,H,C,dv] -> [B,T,H,dv]
+    return jnp.moveaxis(jnp.moveaxis(o, 0, 1), 2, 3).reshape(B, T, H, dv), s
+
+
+def _conv_silu(x_ext: jax.Array, taps: jax.Array) -> jax.Array:
+    """Causal depthwise convolution then SiLU. x_ext [B, T+taps-1, C] (the
+    tail of the previous inputs first), taps [taps, C] -> [B, T, C] f32."""
+    n = taps.shape[0]
+    T = x_ext.shape[1] - (n - 1)
+    xf = x_ext.astype(jnp.float32)
+    y = sum(taps[j].astype(jnp.float32) * xf[:, j:j + T] for j in range(n))
+    return jax.nn.silu(y)
+
+
+def _l2norm(x: jax.Array) -> jax.Array:
+    return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
+
+
+def linear_block(h, lp, c: ModelConfig, s0, tail, n_valid, keep):
+    """One linear-attention layer on normalised input ``h`` [B,T,D].
+    ``s0`` [B,H,dk,dv] and ``tail`` [B,taps-1,3*H*dk]: the rows' state on
+    entry. ``n_valid`` [B] (prefill): tokens past it are padding and move
+    neither the state nor the tail (b = 0, a = 1, the tail taken at the
+    true length). ``keep`` [B] bool (decode): rows that are False leave
+    with the state and the tail they came with. Returns (out [B,T,H*dk],
+    state, tail)."""
+    B, T, _ = h.shape
+    H, dk, taps = c.lin_heads, c.lin_head_dim, c.lin_conv_taps
+    f32 = jnp.float32
+    x = jnp.concatenate([mm(h, lp["wq"]), mm(h, lp["wk"]), mm(h, lp["wv"])],
+                        axis=-1)                                # [B,T,3*H*dk]
+    x_ext = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
+    qkv = _conv_silu(x_ext, lp["conv"]).reshape(B, T, 3, H, dk)
+    q = _l2norm(qkv[:, :, 0]) * dk ** -0.5
+    k, v = _l2norm(qkv[:, :, 1]), qkv[:, :, 2]
+    low = partial(jnp.einsum, preferred_element_type=f32)
+    z = low("btr,rf->btf", low("btd,dr->btr", h, lp["wf_down"]).astype(h.dtype),
+            lp["wf_up"]) + lp["f_bias"].astype(f32)
+    log_a = (-jnp.exp(lp["a_log"].astype(f32))[:, None]
+             * jax.nn.softplus(z.reshape(B, T, H, dk)))
+    beta = 2.0 * jax.nn.sigmoid(low("btd,dh->bth", h, lp["wbeta"]))
+    gate = jax.nn.sigmoid(
+        low("btr,rf->btf", low("btd,dr->btr", h, lp["wg_down"]).astype(h.dtype),
+            lp["wg_up"]))
+    if T == 1 and keep is not None:
+        o, s = kda_decode_update(q[:, 0], k[:, 0], v[:, 0], log_a[:, 0],
+                                 beta[:, 0], s0)
+        o = o[:, None]
+        s = jnp.where(keep[:, None, None, None], s, s0)
+        new_tail = jnp.where(keep[:, None, None], x_ext[:, 1:], tail)
+    else:
+        live = jnp.arange(T)[None, :] < n_valid[:, None]        # [B,T]
+        log_a = jnp.where(live[..., None, None], log_a, 0.0)
+        beta = jnp.where(live[..., None], beta, 0.0)
+        o, s = kda_chunked(q, k, v, log_a, beta, s0)
+        new_tail = jax.vmap(lambda row, at: jax.lax.dynamic_slice_in_dim(
+            row, at, taps - 1, axis=0))(x_ext, n_valid)
+    o = rms_norm(o, lp["out_norm"], c.rms_eps)              # per head, f32
+    out = (o.reshape(B, T, H * dk) * gate).astype(h.dtype)
+    return mm(out, lp["wo"]), s, new_tail.astype(tail.dtype)
+
+
+# ---------------------------------------------------------------------------
+# The expert layer
+# ---------------------------------------------------------------------------
+
+def route(hf: jax.Array, router: jax.Array, c: ModelConfig
+          ) -> tuple[jax.Array, jax.Array]:
+    """hf [N,D] float32 (normalised, un-quantised) -> the top-k experts of
+    ALL ``n_experts`` by sigmoid score, [N,k] ids and weights that sum to
+    1 (float32, full-precision product: a rounded score flips the 8th and
+    9th expert)."""
+    scores = jax.nn.sigmoid(jnp.dot(hf, router.astype(jnp.float32),
+                                    precision=HIGHEST))
+    top, idx = jax.lax.top_k(scores, c.experts_per_token)
+    return idx, top / jnp.sum(top, axis=-1, keepdims=True)
+
+
+def held_weights(idx: jax.Array, w: jax.Array, c: ModelConfig) -> jax.Array:
+    """[N,k] routing -> [N,held]: each token's weight on each expert held
+    here, 0 where the expert is not among its top-k."""
+    local = idx - c.first_expert_held
+    hit = local[:, :, None] == jnp.arange(c.experts_held)[None, None, :]
+    return jnp.sum(jnp.where(hit, w[:, :, None], 0.0), axis=1)
+
+
+def _at(tree: Any, i: jax.Array | None) -> Any:
+    """``tree``'s leaves at index ``i`` of their leading axis (None: as
+    they are)."""
+    if i is None:
+        return tree
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False), tree)
+
+
+def experts_dense(x: jax.Array, probs: jax.Array, lp: Params,
+                  period: jax.Array | None = None) -> jax.Array:
+    """Every held expert on every token. x [N,D], probs [N,held] -> [N,D]
+    float32. With ``period`` the experts' matrices are stacked over
+    periods and read at that index."""
+    lp = _at({k: lp[k] for k in EXPERT_KEYS}, period)
+    # The expert axis is a BATCH axis of all three products, so the weights
+    # are read where they lie ([held, D, F]: a product that contracts D
+    # with the experts as a free axis has them re-laid out first, a copy of
+    # every expert's weights a step).
+    xe = jnp.broadcast_to(x, (probs.shape[1], *x.shape))
+    hid = (jax.nn.silu(moe_mm_batched(xe, lp["wg"]))
+           * moe_mm_batched(xe, lp["wu"]))
+    y = moe_mm_batched(hid, lp["wd"])                       # [held,N,D]
+    return jnp.einsum("end,ne->nd", y.astype(jnp.float32), probs)
+
+
+def experts_grouped(x: jax.Array, probs: jax.Array, lp: Params,
+                    per_token: int, tile: int = GROUP_TILE,
+                    period: jax.Array | None = None) -> jax.Array:
+    """The held experts' part of the result with work that follows the
+    assignments: rows are laid out expert by expert in tiles of ``tile``
+    (each expert's group padded to whole tiles), and a loop over the LIVE
+    tiles gathers a tile's tokens, runs its expert and adds the weighted
+    result back. The layout has room for the worst case — every token on
+    ``per_token`` held experts — so nothing is ever dropped; tiles past
+    the live count are not run. x [N,D], probs [N,held] -> [N,D] f32.
+    With ``period`` the experts' matrices are the whole stack over periods
+    and the loop reads ``[period, expert]`` of it in place: a loop handed
+    one period's slice is handed a COPY of it (0.69 ms a matrix a layer on
+    a v5e, 16 ms a prefill call at the published widths)."""
+    N, D = x.shape
+    held = probs.shape[1]
+    routed = probs > 0.0                                    # [N,held]
+    counts = jnp.sum(routed, axis=0, dtype=jnp.int32)       # [held]
+    tiles = (counts + tile - 1) // tile
+    last_tile = jnp.cumsum(tiles)                           # inclusive
+    n_tiles = -(-N * min(per_token, held) // tile) + held   # static bound
+    rows = n_tiles * tile
+    rank = jnp.cumsum(routed, axis=0, dtype=jnp.int32) - 1  # within expert
+    dest = jnp.where(routed, (last_tile - tiles)[None, :] * tile + rank, rows)
+    token = jnp.broadcast_to(jnp.arange(N, dtype=jnp.int32)[:, None],
+                             (N, held))
+    # Padding rows read token N (a zero row) with weight 0.
+    row_token = jnp.full((rows,), N, jnp.int32).at[dest.reshape(-1)].set(
+        token.reshape(-1), mode="drop")
+    row_weight = jnp.zeros((rows,), jnp.float32).at[dest.reshape(-1)].set(
+        probs.reshape(-1), mode="drop")
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(last_tile, jnp.arange(n_tiles), side="right"),
+        held - 1).astype(jnp.int32)
+    x_pad = jnp.concatenate([x, jnp.zeros((1, D), x.dtype)])
+
+    def body(i, out):
+        e = tile_expert[i]
+        at = jax.lax.dynamic_slice_in_dim(row_token, i * tile, tile)
+        wt = jax.lax.dynamic_slice_in_dim(row_weight, i * tile, tile)
+        w = _at(_at({key: lp[key] for key in EXPERT_KEYS}, period), e)
+        y = swiglu_mlp(x_pad[at], w["wg"], w["wu"], w["wd"])
+        return out.at[at].add(wt[:, None] * y.astype(jnp.float32))
+
+    out = jax.lax.fori_loop(0, last_tile[-1], body,
+                            jnp.zeros((N + 1, D), jnp.float32))
+    return out[:N]
+
+
+def moe_block(x: jax.Array, lp: Params, c: ModelConfig,
+              count: jax.Array | None = None,
+              period: jax.Array | None = None
+              ) -> tuple[jax.Array, jax.Array]:
+    """x [B,T,D] (the residual stream) -> (x + MLP(norm(x)), int32 [2]:
+    the routed assignments of rows where ``count`` [B] is True, all and
+    those landing on a held expert; zeros without ``count``). ``period``:
+    the routed experts' matrices (``EXPERT_KEYS``) are stacked over
+    periods and this is the index to read."""
+    B, T, D = x.shape
+    hf = rms_norm(x.astype(jnp.float32), lp["norm"], c.rms_eps)
+    h = hf.astype(x.dtype)
+    with jax.named_scope("moe.experts"):
+        idx, w = route(hf.reshape(B * T, D), lp["router"], c)
+        probs = held_weights(idx, w, c)
+        xf = h.reshape(B * T, D)
+        if B * T <= DENSE_MAX_TOKENS:
+            y = experts_dense(xf, probs, lp, period)
+        else:
+            y = experts_grouped(xf, probs, lp, c.experts_per_token,
+                                period=period)
+        y = y.reshape(B, T, D).astype(x.dtype)
+    with jax.named_scope("moe.shared"):
+        if c.n_shared_experts:
+            y = y + swiglu_mlp(h, lp["sg"], lp["su"], lp["sd"])
+    counted = jnp.zeros((2,), jnp.int32)
+    if count is not None:
+        on = jnp.repeat(count, T)
+        counted = jnp.stack([
+            jnp.sum(on, dtype=jnp.int32) * c.experts_per_token,
+            jnp.sum((probs > 0.0) & on[:, None], dtype=jnp.int32)])
+    return x + y, counted
+
+
+# ---------------------------------------------------------------------------
+# Forward
+# ---------------------------------------------------------------------------
+
+def forward(params: Params, config: ModelConfig, tokens: jax.Array,
+            lengths: jax.Array, cache: HybridCache,
+            active: jax.Array | None = None,
+            attention_fn: Callable | None = None, *,
+            slots: jax.Array | None = None,
+            n_valid: jax.Array | None = None
+            ) -> tuple[jax.Array, HybridCache]:
+    """One forward over new tokens, in the family signature of
+    ``llama.forward`` (tokens [B,T], lengths [B], the cache, ``active``,
+    an ``attention_fn`` over the page pool).
+
+    Decode (T == 1, an ``attention_fn`` with ``.decode``): row b IS slot
+    b; the state of a row with ``active`` False leaves bit-identical.
+    Prefill: row i is slot ``slots[i]``; a row whose ``lengths`` is 0
+    starts from ZERO state whatever the slot's block holds, any other row
+    continues from the block; ``n_valid`` [B] (default T) is each row's
+    true token count, and the padding after it changes nothing.
+    Returns (logits [B,T,V] float32, the cache). A prefill call that
+    states ``n_valid`` wants its rows' LAST real position only (the first
+    sampled token): the head runs on that position alone and every
+    position of the row carries its logits (a broadcast, never
+    materialised; at 8 rows of 512 tokens the full logits are 1.2 GB of
+    temporaries the chip has no room for beside this family's weights).
+    """
+    c = config
+    B, T = tokens.shape
+    dh = c.head_dim
+    if attention_fn is None:
+        raise ValueError("the hybrid family serves from the paged cache: "
+                         "it needs a paged attention_fn")
+    decode_attend = getattr(attention_fn, "decode", None) if T == 1 else None
+    decoding = decode_attend is not None
+    scope = "decode" if decoding else "prefill"
+    last_only = not decoding and n_valid is not None
+    if decoding:
+        s_in, tail_in = cache.state, cache.conv
+        keep = active if active is not None else jnp.ones((B,), bool)
+        count = keep
+        n_valid = jnp.ones((B,), jnp.int32)
+    else:
+        if slots is None:
+            slots = jnp.arange(B, dtype=jnp.int32)
+        n_valid = (jnp.full((B,), T, jnp.int32) if n_valid is None
+                   else n_valid.astype(jnp.int32))
+        fresh = (lengths == 0)
+        s_in = tuple(jnp.where(fresh[:, None, None, None], 0.0, s[:, slots])
+                     for s in cache.state)
+        tail_in = tuple(jnp.where(fresh[:, None, None], 0, t[:, slots])
+                        for t in cache.conv)
+        keep = count = None
+
+    x = jnp.take(params["embed"], tokens, axis=0)               # [B,T,D]
+
+    def softmax_layer(x, lp, layer_k, layer_v):
+        with jax.named_scope(f"{scope}.attention"):
+            h = rms_norm(x, lp["norm"], c.rms_eps)
+            q = mm(h, lp["wq"]).reshape(B, T, c.n_heads, dh)
+            k = mm(h, lp["wk"]).reshape(B, T, c.n_kv_heads, dh)
+            v = mm(h, lp["wv"]).reshape(B, T, c.n_kv_heads, dh)
+            if decoding:
+                attn = decode_attend(q, k, v, layer_k, layer_v, lengths,
+                                     active)
+                ys = (k, v)
+            else:
+                attn, layer_k, layer_v = attention_fn(
+                    q, k, v, layer_k, layer_v, lengths, active)
+                ys = (layer_k, layer_v)
+            gate = jax.nn.sigmoid(mm(h, lp["wgate"]).astype(jnp.float32))
+            attn = (attn.astype(jnp.float32) * gate).astype(x.dtype)
+            return x + mm(attn, lp["wo"]), ys
+
+    # The routed experts' matrices stay OUT of the scanned slices: the
+    # expert layer reads them from the whole stack at the period's index
+    # (``experts_grouped`` says why).
+    layers = params["layers"]
+    every = (layers["attn"], *layers["lin"])
+    held = [{k: lp["mlp"][k] for k in EXPERT_KEYS} for lp in every]
+    rest = [{**lp, "mlp": {k: v for k, v in lp["mlp"].items()
+                           if k not in EXPERT_KEYS}} for lp in every]
+
+    def mlp(x, lp, i, period):
+        with jax.named_scope(f"{scope}.mlp"):
+            return moe_block(x, {**lp, **held[i]}, c, count, period)
+
+    def period_step(x, scanned):
+        period, (attn, *lin), layer_k, layer_v, s0, tail0 = scanned
+        x, ys = softmax_layer(x, attn, layer_k, layer_v)
+        x, counted = mlp(x, attn["mlp"], 0, period)
+        states, tails = [], []
+        for i, (lp, s, tail) in enumerate(zip(lin, s0, tail0), 1):
+            with jax.named_scope(f"{scope}.kda"):
+                h = rms_norm(x, lp["norm"], c.rms_eps)
+                out, s, tail = linear_block(h, lp, c, s, tail, n_valid,
+                                            keep)
+                x = x + out
+            x, more = mlp(x, lp["mlp"], i, period)
+            counted = counted + more
+            states.append(s)
+            tails.append(tail)
+        return x, (ys, tuple(states), tuple(tails), counted)
+
+    x, ((ys_k, ys_v), s_out, tail_out, counts) = jax.lax.scan(
+        period_step, x, (jnp.arange(c.n_kv_layers), rest, cache.k, cache.v,
+                         s_in, tail_in))
+    if decoding:
+        new_k, new_v = attention_fn.insert_all(
+            cache.k, cache.v, ys_k, ys_v, lengths, active)
+        state, conv = s_out, tail_out
+        counters = cache.counters + jnp.sum(counts, axis=0)
+    else:
+        new_k, new_v = ys_k, ys_v
+        state = tuple(s.at[:, slots].set(new)
+                      for s, new in zip(cache.state, s_out))
+        conv = tuple(t.at[:, slots].set(new)
+                     for t, new in zip(cache.conv, tail_out))
+        counters = cache.counters
+
+    if last_only:
+        x = jnp.take_along_axis(x, (n_valid - 1)[:, None, None], axis=1)
+    x = rms_norm(x, params["final_norm"], c.rms_eps)
+    logits = head_matmul(x, params["lm_head"])
+    if last_only:
+        logits = jnp.broadcast_to(logits, (B, T, logits.shape[-1]))
+    return logits, HybridCache(k=new_k, v=new_v, state=state, conv=conv,
+                               counters=counters)

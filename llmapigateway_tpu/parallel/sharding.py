@@ -115,6 +115,9 @@ def param_shardings(params_or_shapes: Any, mesh: Mesh) -> Any:
             path = f"{prefix}{key}"
             if isinstance(val, dict):
                 out[key] = build(val, path + ".")
+            elif isinstance(val, tuple):    # one tree per layer of a period
+                out[key] = tuple(build(v, f"{path}.{i}.")
+                                 for i, v in enumerate(val))
             else:
                 out[key] = NamedSharding(mesh, _spec_for(path, tuple(val.shape), mesh))
         return out

@@ -76,7 +76,14 @@ def test_healthcheck_exit_codes(tmp_path, monkeypatch):
         assert bad.returncode == 1
 
     import asyncio
-    asyncio.get_event_loop().run_until_complete(run())
+    # A loop of its own: whether the thread still HAS a current loop depends
+    # on which test files this worker ran before (an `asyncio.run` leaves
+    # none), and `get_event_loop()` then raises.
+    loop = asyncio.new_event_loop()
+    try:
+        loop.run_until_complete(run())
+    finally:
+        loop.close()
 
 
 def test_entrypoint_checks_all_three_preconditions():
