@@ -482,6 +482,10 @@ class InferenceEngine:
         # What actually serves ("auto" resolved, seq/pipe downgrade
         # applied) — exposed in stats() so a run can assert it.
         self.attention_impl = self._resolve_attention_impl()
+        # Whether the decode programs leave the page pool where it lies
+        # (read by layer, written through aliased operands); static per
+        # engine, set by _compile_paged — exposed in stats() too.
+        self.kv_pool_in_place = False
         self._compile()
         logger.info("engine build: params %.1fs, state+programs %.1fs "
                     "(programs compile lazily on first call)",
@@ -1218,15 +1222,20 @@ class InferenceEngine:
         model forward signature stays cache-layout-agnostic."""
         c = self.model_cfg
         family_forward = forward_fn(c)
-        from ..ops.paged_attention import PagedKVCache, make_paged_attention_fn
+        from ..ops.paged_attention import (PagedKVCache,
+                                           make_paged_attention_fn,
+                                           pool_in_place)
 
         impl = self.attention_impl
         mesh = self.mesh if self.mesh.size > 1 else None
+        # (A pipelined or seq-sharded engine has a mesh, and the reference
+        # path: its own scans and providers slice the pool.)
+        self.kv_pool_in_place = pool_in_place(impl, mesh)
         logger.info("paged KV cache: %d pages × %d tokens, attention=%s"
-                    "%s", self.allocator.num_pages,
+                    "%s, kv_pool_in_place=%s", self.allocator.num_pages,
                     self.allocator.page_size, impl,
                     (f", pages_per_block={self.kv_ppb}"
-                     if self.kv_ppb > 1 else ""))
+                     if self.kv_ppb > 1 else ""), self.kv_pool_in_place)
         S = self.S
 
         replicated = NamedSharding(self.mesh, P())
@@ -3850,6 +3859,7 @@ class InferenceEngine:
             "max_seq_len": self.S,
             "kv_layout": self.cfg.kv_layout,
             "attention": self.attention_impl,
+            "kv_pool_in_place": self.kv_pool_in_place,
         }
         # Supervisor block (ISSUE 14): lifecycle state, restart budget,
         # heartbeat age, recent transitions — the incident story.

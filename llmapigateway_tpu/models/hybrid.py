@@ -594,6 +594,9 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
                          "it needs a paged attention_fn")
     decode_attend = getattr(attention_fn, "decode", None) if T == 1 else None
     decoding = decode_attend is not None
+    # ``.decode_at``: the provider reads the stacked pool at a layer's
+    # index, and the pool stays out of the scanned inputs (llama.forward).
+    decode_at = getattr(attention_fn, "decode_at", None) if decoding else None
     scope = "decode" if decoding else "prefill"
     last_only = not decoding and n_valid is not None
     if decoding:
@@ -615,13 +618,17 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
 
     x = jnp.take(params["embed"], tokens, axis=0)               # [B,T,D]
 
-    def softmax_layer(x, lp, layer_k, layer_v):
+    def softmax_layer(x, lp, layer_k, layer_v, layer):
         with jax.named_scope(f"{scope}.attention"):
             h = rms_norm(x, lp["norm"], c.rms_eps)
             q = mm(h, lp["wq"]).reshape(B, T, c.n_heads, dh)
             k = mm(h, lp["wk"]).reshape(B, T, c.n_kv_heads, dh)
             v = mm(h, lp["wv"]).reshape(B, T, c.n_kv_heads, dh)
-            if decoding:
+            if decode_at is not None:
+                attn = decode_at(q, k, v, cache.k, cache.v, layer, lengths,
+                                 active)
+                ys = (k, v)
+            elif decoding:
                 attn = decode_attend(q, k, v, layer_k, layer_v, lengths,
                                      active)
                 ys = (k, v)
@@ -647,8 +654,8 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
             return moe_block(x, {**lp, **held[i]}, c, count, period)
 
     def period_step(x, scanned):
-        period, (attn, *lin), layer_k, layer_v, s0, tail0 = scanned
-        x, ys = softmax_layer(x, attn, layer_k, layer_v)
+        period, (attn, *lin), (layer_k, layer_v), s0, tail0 = scanned
+        x, ys = softmax_layer(x, attn, layer_k, layer_v, period)
         x, counted = mlp(x, attn["mlp"], 0, period)
         states, tails = [], []
         for i, (lp, s, tail) in enumerate(zip(lin, s0, tail0), 1):
@@ -664,8 +671,9 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
         return x, (ys, tuple(states), tuple(tails), counted)
 
     x, ((ys_k, ys_v), s_out, tail_out, counts) = jax.lax.scan(
-        period_step, x, (jnp.arange(c.n_kv_layers), rest, cache.k, cache.v,
-                         s_in, tail_in))
+        period_step, x, (jnp.arange(c.n_kv_layers), rest,
+                         (None, None) if decode_at is not None
+                         else (cache.k, cache.v), s_in, tail_in))
     if decoding:
         new_k, new_v = attention_fn.insert_all(
             cache.k, cache.v, ys_k, ys_v, lengths, active)

@@ -645,6 +645,11 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
     # Pallas causal kernels) keep insert-then-attend for T>1.
     decode_attend = getattr(attention_fn, "decode", None) if T == 1 else \
         getattr(attention_fn, "verify", None)
+    # A provider with ``.decode_at`` reads the layer-STACKED cache at a
+    # layer's index (ops/paged_attention.py): the cache then stays out of
+    # the scanned inputs too — a scanned slice of it is a COPY of a layer's
+    # whole side, every layer of every step.
+    decode_at = getattr(attention_fn, "decode_at", None) if T == 1 else None
 
     # Phase markers (ISSUE 8): named_scope is trace-time op metadata —
     # zero runtime cost — so profiler captures segment each layer into
@@ -654,14 +659,21 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
     scope = "decode" if decode_attend is not None else "prefill"
 
     def layer_step(x, scanned):
-        lp, layer_k, layer_v = scanned
+        if decode_at is not None:
+            lp, layer = scanned
+        else:
+            lp, layer_k, layer_v = scanned
         # Attention block
         with jax.named_scope(f"{scope}.attention"):
             h = rms_norm(x, lp["attn_norm"], c.rms_eps, c.rms_offset)
             q, k, v = qkv_proj(h, lp, c)
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
-            if decode_attend is not None:
+            if decode_at is not None:
+                attn = decode_at(q, k, v, cache.k, cache.v, layer, lengths,
+                                 active)
+                ys = (k, v)
+            elif decode_attend is not None:
                 attn = decode_attend(q, k, v, layer_k, layer_v, lengths,
                                      active)
                 ys = (k, v)                   # stacked for insert_all below
@@ -680,7 +692,9 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
         return x, ys
 
     x, (ys_k, ys_v) = jax.lax.scan(
-        layer_step, x, (layer_params, cache.k, cache.v))
+        layer_step, x,
+        (layer_params, jnp.arange(c.n_layers)) if decode_at is not None
+        else (layer_params, cache.k, cache.v))
     if decode_attend is not None:
         new_k, new_v = attention_fn.insert_all(
             cache.k, cache.v, ys_k, ys_v, lengths, active)

@@ -9,16 +9,31 @@ counterpart: the reference proxies HTTP and has no KV cache at all
 (SURVEY.md §2b "Serving scheduler" row).
 
 Layout:
-* ``k_pages``/``v_pages``: ``[P, KV, page, Dh]`` — global page pool,
-  head-major within a page. **Physical page 0 is the trash page**: scatter
-  targets for inactive slots and out-of-range positions are redirected
-  there, so masked writes need no branching. The allocator
-  (engine/paged.py) never hands page 0 out.
+* ``k_pages``/``v_pages``: ``[L, P, KV, page, Dh]`` — ONE global page pool
+  stacked over the layers that hold one, head-major within a page (an int8
+  pool adds the per-token scales ``[L, P, KV, 1, page]``). **Physical page
+  0 is the trash page** of every layer: writes of inactive slots and of
+  out-of-range positions are redirected there, so masked writes need no
+  branching. The allocator (engine/paged.py) never hands page 0 out. The
+  prefill path and the reference path see one layer of it, ``[P, KV,
+  page, Dh]``, as the layer scan's slice.
 * ``page_table``: ``[B, NP]`` int32 — slot's logical page j → physical
   page. Unallocated entries are 0 (trash) and are never read: reads are
   bounded by ``n_valid``.
 
-Two kernels, two shapes. The PREFILL kernel mirrors
+The DECODE programs leave the pool where it lies (PR 30). The decode
+kernel takes the whole stacked pool in HBM and the layer's index as a
+prefetched scalar (``.decode_at``), so the layer scan hands it no slice —
+a slice is a copy of a layer's whole side, a cost of the pool's CAPACITY
+paid every layer of every step. The step's new tokens go in through a
+second kernel whose pool operands are its outputs
+(:func:`paged_insert_in_place`, under the scope ``kv.paged_insert``): it
+touches ``L × B`` tiles, and the pool the burst carries keeps the default
+layout — the XLA scatter it replaces (:func:`paged_insert_all`, still the
+reference path and the tests' oracle) made the carried pool take a layout
+of its own liking, which every slice was then re-laid from.
+
+Two attention kernels, two shapes. The PREFILL kernel mirrors
 ops/flash_attention.py (a grid over key blocks, online-softmax fp32
 scratch, ``pl.when`` compute skip) with one addition: the K/V BlockSpec
 index maps translate logical → physical through the scalar-prefetched page
@@ -37,7 +52,8 @@ ragged property, by construction and not by elision.
 The adapter :func:`make_paged_attention_fn` is built INSIDE the engine's
 jitted step (closing over the traced page table), so ``llama.forward``
 needs no signature change: a ``PagedKVCache`` pytree scans over layers
-exactly like the dense cache.
+exactly like the dense cache — and stays OUT of the decode scan where
+the provider carries ``.decode_at``.
 """
 from __future__ import annotations
 
@@ -89,6 +105,21 @@ class PagedKVCache(NamedTuple):
         return k.shape[3]
 
 
+def _insert_positions(page_table: jax.Array, lengths: jax.Array,
+                      active: jax.Array | None, T: int, page: int):
+    """(phys, off), each [B, T] int32: where token t of slot b lands —
+    logical position ``lengths + t`` through the table. Inactive slots and
+    positions past the table's reach name trash page 0."""
+    NP = page_table.shape[1]
+    pos = lengths[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]  # [B,T]
+    logical = jnp.clip(pos // page, 0, NP - 1)
+    phys = jnp.take_along_axis(page_table, logical, axis=1)           # [B,T]
+    ok = (pos // page) < NP
+    if active is not None:
+        ok = ok & active[:, None]
+    return jnp.where(ok, phys, 0), pos % page
+
+
 def paged_insert_kv(layer_k, layer_v,
                     k_new: jax.Array, v_new: jax.Array,
                     page_table: jax.Array, lengths: jax.Array,
@@ -104,19 +135,8 @@ def paged_insert_kv(layer_k, layer_v,
     quant = isinstance(layer_k, dict)
     P, KV, page, Dh = (layer_k["q"] if quant else layer_k).shape
     B, T = k_new.shape[:2]
-    NP = page_table.shape[1]
-
-    pos = lengths[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]  # [B,T]
-    logical = jnp.clip(pos // page, 0, NP - 1)
-    phys = jnp.take_along_axis(page_table, logical, axis=1)           # [B,T]
-    ok = (pos // page) < NP
-    if active is not None:
-        ok = ok & active[:, None]
-    phys = jnp.where(ok, phys, 0)            # trash page for masked writes
-    off = pos % page
-
-    flat_page = phys.reshape(-1)                                      # [B*T]
-    flat_off = off.reshape(-1)
+    flat_page, flat_off = (x.reshape(-1) for x in _insert_positions(  # [B*T]
+        page_table, lengths, active, T, page))
 
     # [P, KV, page(, Dh)] scattered at (page, :, offset(, :)) per token.
     # In-bounds by construction (phys from the table or trash page 0;
@@ -165,17 +185,9 @@ def paged_insert_all(pool_k, pool_v,
     """
     quant = isinstance(pool_k, dict)
     page = (pool_k["q"] if quant else pool_k).shape[3]
-    NP = page_table.shape[1]
     L, B, T = k_news.shape[:3]
-
-    pos = lengths[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]  # [B,T]
-    logical = jnp.clip(pos // page, 0, NP - 1)
-    phys = jnp.take_along_axis(page_table, logical, axis=1)           # [B,T]
-    ok = (pos // page) < NP
-    if active is not None:
-        ok = ok & active[:, None]
-    phys = jnp.where(ok, phys, 0).reshape(-1)                         # [B*T]
-    off = (pos % page).reshape(-1)
+    phys, off = (x.reshape(-1) for x in _insert_positions(   # [B*T] each
+        page_table, lengths, active, T, page))
 
     # Advanced indices (phys, off) are separated by slices, so the indexed
     # result is [B*T, L, KV(, Dh)] — the [L, B, T, ...] new tokens
@@ -203,6 +215,167 @@ def paged_insert_all(pool_k, pool_v,
              "s": scatter_s(pool_v["s"], vs)},
         )
     return (scatter(pool_k, k_news), scatter(pool_v, v_news))
+
+
+# ---------------------------------------------------------------------------
+# Write kernel: the new tokens of every layer into the pool, in place
+# ---------------------------------------------------------------------------
+
+# Rows of a page the write kernel reads, changes and writes back around a
+# token's row: a whole packed tile of the narrowest pool dtype (int8: 32
+# rows share a tile's sublane words), so every copy is tile-aligned.
+_INSERT_TILE_ROWS = 32
+# What a program of the write kernel may take of VMEM for its slots.
+_INSERT_VMEM_BYTES = 4 * 2 ** 20
+
+
+def _paged_insert_kernel(phys_ref, off_ref, *refs, T: int, rows: int,
+                         quant: bool):
+    """Program ``(layer, chunk of slots)``: for each token ``t`` in turn,
+    copy the ``rows``-row tile around every slot's target row (and, int8,
+    the page's scale plane) from the pool in HBM, put the new row in, and
+    copy it back — the copies of a round started together and waited for
+    once. Slots own disjoint pages, so a round's tiles are disjoint; a
+    slot's OWN tokens share tiles, hence one round a token. The trash
+    page takes every masked row (several may race there: it is never
+    read). ``refs``: the new values ``[1, bc, T, KV, 1, Dh]`` (int8: each
+    followed by its scales ``[1, bc, T, KV, 1, 1]``) for K then V, the
+    pool sides in HBM (unused: they ARE the outputs), the output pool
+    sides, a VMEM buffer per side, and the DMA semaphores
+    ``[side, slot]``."""
+    n = 4 if quant else 2
+    news, pools = refs[:n], refs[2 * n:3 * n]
+    bufs, sem = refs[3 * n:4 * n], refs[4 * n]
+    layer, chunk = pl.program_id(0), pl.program_id(1)
+    bc = news[0].shape[1]
+
+    def target(b, t):
+        row = (chunk * bc + b) * T + t
+        return phys_ref[row], off_ref[row]
+
+    def copies(b, t, back: bool):
+        phys, off = target(b, t)
+        start = pl.multiple_of(off // rows * rows, rows)
+        out = []
+        for side, (pool, buf) in enumerate(zip(pools, bufs)):
+            if quant and side % 2:                  # a scale plane
+                hbm = pool.at[layer, phys]
+            else:
+                hbm = pool.at[layer, phys, :, pl.ds(start, rows), :]
+            src, dst = (buf.at[b], hbm) if back else (hbm, buf.at[b])
+            out.append(pltpu.make_async_copy(src, dst, sem.at[side, b]))
+        return out
+
+    def put(buf, b, new, at, axis):
+        # In 32 bits: the packed dtypes (int8, bf16) have no select of
+        # their own on every chip generation; both widenings are exact.
+        wide = jnp.int32 if jnp.issubdtype(buf.dtype, jnp.integer) \
+            else jnp.float32
+        old = buf[b].astype(wide)
+        hit = jax.lax.broadcasted_iota(jnp.int32, old.shape, axis) == at
+        buf[b] = jnp.where(hit, new.astype(wide), old).astype(buf.dtype)
+
+    def puts(b, t):
+        _, off = target(b, t)
+        for side, (new, buf) in enumerate(zip(news, bufs)):
+            if quant and side % 2:
+                put(buf, b, new[0, b, t], off, 2)              # [KV,1,page]
+            else:
+                put(buf, b, new[0, b, t], off % rows, 1)       # [KV,rows,Dh]
+
+    def each_slot(step):
+        # A loop, not bc unrolled copies of the body: the kernel is traced
+        # and lowered once a decode program, and set-up pays for its size.
+        jax.lax.fori_loop(0, bc, lambda b, carry: (step(b), carry)[1], 0)
+
+    def move(t, back: bool):
+        """Every slot's copies of round ``t``: started together, then
+        waited for."""
+        def start(b):
+            for c in copies(b, t, back):
+                c.start()
+
+        def wait(b):
+            for c in copies(b, t, back):
+                c.wait()
+        each_slot(start)
+        each_slot(wait)
+
+    for t in range(T):
+        move(t, back=False)
+        each_slot(lambda b: puts(b, t))
+        move(t, back=True)
+
+
+def paged_insert_in_place(pool_k, pool_v, k_news: jax.Array,
+                          v_news: jax.Array, page_table: jax.Array,
+                          lengths: jax.Array, active: jax.Array | None, *,
+                          interpret: bool | None = None):
+    """:func:`paged_insert_all` as a Pallas call whose pool operands ARE
+    its outputs (``input_output_aliases``): the pool stays where it lies,
+    in the layout the decode kernel reads, and a step writes ``L × B × T``
+    rows of it. The XLA scatter it stands in for made the burst's carried
+    pool take a layout of the scatter's liking, which every layer of
+    every step then paid to undo (PERF.md, PR 30). Same arguments, same
+    positions (:func:`_insert_positions`), the same bytes in the pool off
+    the trash page: quantisation stays outside, by ``quantize_kv``."""
+    quant = isinstance(pool_k, dict)
+    kq = pool_k["q"] if quant else pool_k
+    L, _, KV, page, Dh = kq.shape
+    B, T = k_news.shape[1:3]
+    rows = min(_INSERT_TILE_ROWS, page)
+    phys, off = _insert_positions(page_table, lengths, active, T, page)
+
+    if quant:
+        from ..models.llama import quantize_kv
+        (knq, kns), (vnq, vns) = quantize_kv(k_news), quantize_kv(v_news)
+        news = (knq[..., None, :], kns[..., None, None].astype(jnp.float32),
+                vnq[..., None, :], vns[..., None, None].astype(jnp.float32))
+        pools = (pool_k["q"], pool_k["s"], pool_v["q"], pool_v["s"])
+    else:
+        news = (k_news.astype(kq.dtype)[..., None, :],
+                v_news.astype(kq.dtype)[..., None, :])
+        pools = (pool_k, pool_v)
+    # Slots a program holds: as many as keep its VMEM — per slot and side
+    # a tile, the new rows (a one-row block pads to a whole 4 KiB tile a
+    # head, and the pipeline holds two) and, int8, the same again for a
+    # scale plane and the new scales — within the budget.
+    row_pad = 2 * T * 4096 * -(-Dh // 128)
+    per_slot = 2 * KV * (rows * Dh * kq.dtype.itemsize + row_pad
+                         + (8 * page * 4 + 2 * T * 4096 if quant else 0))
+    bc = max(d for d in range(1, B + 1)
+             if B % d == 0 and (d == 1 or d * per_slot <= _INSERT_VMEM_BYTES))
+
+    def new_spec(x):
+        return pl.BlockSpec((1, bc, *x.shape[2:]),
+                            lambda l, c, phys, off: (l, c, 0, 0, 0, 0))
+
+    tile = pltpu.VMEM((bc, KV, rows, Dh), kq.dtype)
+    plane = pltpu.VMEM((bc, KV, 1, page), jnp.float32)
+    buffers = [tile, plane, tile, plane] if quant else [tile, tile]
+
+    out = pl.pallas_call(
+        functools.partial(_paged_insert_kernel, T=T, rows=rows, quant=quant),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(L, B // bc),
+            in_specs=[*map(new_spec, news),
+                      *[pl.BlockSpec(memory_space=pl.ANY)] * len(pools)],
+            out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
+            scratch_shapes=[*buffers,
+                            pltpu.SemaphoreType.DMA((len(pools), bc))],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype) for p in pools],
+        # Operand i of the call (the two position vectors included) is
+        # output i - 2 - len(news).
+        input_output_aliases={2 + len(news) + i: i
+                              for i in range(len(pools))},
+        interpret=_interpret_default() if interpret is None else interpret,
+    )(phys.reshape(-1).astype(jnp.int32), off.reshape(-1).astype(jnp.int32),
+      *news, *pools)
+    if quant:
+        return ({"q": out[0], "s": out[1]}, {"q": out[2], "s": out[3]})
+    return out[0], out[1]
 
 
 # ---------------------------------------------------------------------------
@@ -286,18 +459,19 @@ def _attend_heads(q, k, v, ks, vs, mask, m, l, acc):
     return m_new, l, acc
 
 
-def _paged_decode_kernel(pt_ref, nvalid_ref, q_ref, kn_ref, vn_ref,
-                         *refs, page: int, window: int,
+def _paged_decode_kernel(pt_ref, nvalid_ref, layer_ref, q_ref, kn_ref,
+                         vn_ref, *refs, page: int, window: int,
                          pages_per_block: int, n_table_blocks: int):
     """One program per group of folded heads walks EVERY slot's live
     blocks, and only those: the pools stay in HBM and each block is copied
     into one of two VMEM buffers while the block before it is attended.
     The walk is one sequence over (slot, block) — a slot's last block
     prefetches the next slot's first — so the copy engine idles only on
-    the call's very first block. ``refs``: the pool sides in HBM (K, V;
-    int8: K, its scale plane, V, its scale plane), the output block, a
-    VMEM buffer pair per pool side in the same order, and the DMA
-    semaphores ``[buffer, side]``."""
+    the call's very first block. ``refs``: the LAYER-STACKED pool sides in
+    HBM (K, V; int8: K, its scale plane, V, its scale plane), of which
+    only layer ``layer_ref[0]`` is read, the output block, a VMEM buffer
+    pair per pool side in the same order, and the DMA semaphores
+    ``[buffer, side]``."""
     n_sides = (len(refs) - 2) // 2       # K, V (int8: + their scale planes)
     pools, o_ref = refs[:n_sides], refs[n_sides]
     bufs, sem = refs[n_sides + 1:-1], refs[-1]
@@ -308,6 +482,7 @@ def _paged_decode_kernel(pt_ref, nvalid_ref, q_ref, kn_ref, vn_ref,
     hb = pl.program_id(0)
     B, heads = q_ref.shape[0], q_ref.shape[1]
     ppb = pages_per_block
+    layer = layer_ref[0]
 
     def live(b):
         n_valid = nvalid_ref[b]
@@ -322,7 +497,7 @@ def _paged_decode_kernel(pt_ref, nvalid_ref, q_ref, kn_ref, vn_ref,
         # folded heads.
         p0 = pt_ref[b, blk * ppb]
         return [pltpu.make_async_copy(
-            pool.at[pl.ds(p0, ppb), pl.ds(hb * heads, heads)],
+            pool.at[layer, pl.ds(p0, ppb), pl.ds(hb * heads, heads)],
             vmem.at[buf], sem.at[buf, side])
             for side, (pool, vmem) in enumerate(zip(pools, bufs))]
 
@@ -421,6 +596,7 @@ def paged_decode_attention(q: jax.Array, k_new: jax.Array,
                            v_new: jax.Array, k_pages, v_pages,
                            page_table: jax.Array,
                            n_stale: jax.Array, *,
+                           layer: jax.Array | int = 0,
                            window: int = 0,
                            pages_per_block: int = 1,
                            interpret: bool | None = None) -> jax.Array:
@@ -428,9 +604,15 @@ def paged_decode_attention(q: jax.Array, k_new: jax.Array,
     token (self column folded into the online-softmax init).
 
     q: [B, H, Dh] (RoPE applied); k_new/v_new: [B, KV, Dh];
-    k_pages/v_pages: [P, KV, page, Dh] or the int8 ``{"q","s"}`` dicts;
-    page_table: [B, NP]; n_stale: [B] int32 (the query's position; 0 for a
-    fresh slot). Returns [B, H*Dh].
+    k_pages/v_pages: the layer-stacked pool ``[L, P, KV, page, Dh]`` (or
+    the int8 ``{"q","s"}`` dicts) of which ``layer`` (a traced scalar: the
+    layer scan's index) is read WHERE IT LIES — the pool operands stay in
+    HBM and a block's copy names the layer, so a scan over layers hands
+    the kernel no slice of the pool (a slice is a copy of a layer's whole
+    side, paid for by the pool's CAPACITY every layer and step: PERF.md,
+    PR 30). A rank-4 side ``[P, KV, page, Dh]`` is one layer (a free
+    reshape, layer 0). page_table: [B, NP]; n_stale: [B] int32 (the
+    query's position; 0 for a fresh slot). Returns [B, H*Dh].
 
     One Pallas call, grid ``(KV // heads,)``: a program holds every slot's
     q / k_new / v_new / out rows for ``heads`` KV heads in VMEM and walks
@@ -451,20 +633,23 @@ def paged_decode_attention(q: jax.Array, k_new: jax.Array,
     """
     B, H, Dh = q.shape
     quant = isinstance(k_pages, dict)
+    if (k_pages["q"] if quant else k_pages).ndim == 4:
+        k_pages, v_pages = jax.tree.map(lambda x: x[None],
+                                        (k_pages, v_pages))
     kq = k_pages["q"] if quant else k_pages
-    KV, page = kq.shape[1], kq.shape[2]
+    KV, page = kq.shape[2], kq.shape[3]
     NP = page_table.shape[1]
     ppb = pages_per_block
-    _check_pages_per_block(ppb, NP, kq.shape[0])
+    _check_pages_per_block(ppb, NP, kq.shape[1])
     G = H // KV
     heads = _decode_heads_per_block(KV, page, Dh, kq.dtype.itemsize, quant,
                                     ppb)
 
     def rows(width):
         return pl.BlockSpec((B, heads, width, Dh),
-                            lambda hb, pt, nv: (0, hb, 0, 0))
+                            lambda hb, pt, nv, layer: (0, hb, 0, 0))
 
-    # Scales are STORED rank-4 [P, KV, 1, page], so a block's scale plane
+    # Scales are STORED [L, P, KV, 1, page], so a block's scale plane
     # is the same slice of the pool as its values (see
     # flash_attention.attend_block on why the unit dim).
     kv_buf = pltpu.VMEM((2, ppb, heads, page, Dh), kq.dtype)
@@ -481,7 +666,7 @@ def paged_decode_attention(q: jax.Array, k_new: jax.Array,
         functools.partial(_paged_decode_kernel, page=page, window=window,
                           pages_per_block=ppb, n_table_blocks=NP // ppb),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(KV // heads,),
             in_specs=[rows(G), rows(1), rows(1),
                       *[pl.BlockSpec(memory_space=pl.ANY)] * len(kv_operands)],
@@ -492,6 +677,7 @@ def paged_decode_attention(q: jax.Array, k_new: jax.Array,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, Dh), q.dtype),
         interpret=_interpret_default() if interpret is None else interpret,
     )(page_table.astype(jnp.int32), n_stale.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1),
       q.reshape(B, KV, G, Dh), k_new[:, :, None, :], v_new[:, :, None, :],
       *kv_operands)
     return out.reshape(B, H * Dh)
@@ -709,6 +895,16 @@ def _paged_reference_core(q, dense_k, dense_v, lengths, active, T,
     return out.reshape(B, T, H * Dh).astype(q.dtype)
 
 
+def pool_in_place(impl: str, mesh=None) -> bool:
+    """Whether :func:`make_paged_attention_fn` builds the decode path that
+    leaves the page pool where it lies — ``.decode_at`` reading the
+    stacked pool by layer and ``.insert_all`` writing it through aliased
+    operands. The kernels on one device do; the reference path gathers,
+    and under a mesh the pool's per-layer slices stay (the write kernel
+    has no shard_map wrapper yet)."""
+    return impl == "pallas" and mesh is None
+
+
 def make_paged_attention_fn(page_table: jax.Array, max_seq: int,
                             impl: str = "pallas",
                             block_t: int | None = None,
@@ -739,6 +935,7 @@ def make_paged_attention_fn(page_table: jax.Array, max_seq: int,
     from jax.sharding import PartitionSpec as P
 
     msize = mesh.shape.get("model", 1) if mesh is not None else 1
+    in_place = pool_in_place(impl, mesh)
 
     _dequant_dense = dequant_gathered
 
@@ -799,10 +996,20 @@ def make_paged_attention_fn(page_table: jax.Array, max_seq: int,
             return _decode(q, k_new, v_new, layer_k, layer_v, lengths,
                            active)
 
-    def _decode(q, k_new, v_new, layer_k, layer_v, lengths, active=None):
+    def decode_at(q, k_new, v_new, pool_k, pool_v, layer, lengths,
+                  active=None):
+        """:func:`decode` over the layer-STACKED pool: layer ``layer`` (the
+        layer scan's traced index) is read where it lies, so the scan
+        keeps the pool out of its inputs and slices nothing."""
+        with jax.named_scope("attention.paged_decode"):
+            return _decode(q, k_new, v_new, pool_k, pool_v, lengths,
+                           active, layer=layer)
+
+    def _decode(q, k_new, v_new, layer_k, layer_v, lengths, active=None,
+                layer=0):
         B, T, H, Dh = q.shape
         quant = isinstance(layer_k, dict)
-        KV = (layer_k["q"] if quant else layer_k).shape[1]
+        KV = (layer_k["q"] if quant else layer_k).shape[-3]
         n_stale = lengths if active is None else jnp.where(active, lengths, 0)
         if impl == "reference":
             # dense_decode_attention is dict-aware: the gathered int8
@@ -829,7 +1036,7 @@ def make_paged_attention_fn(page_table: jax.Array, max_seq: int,
         else:
             out = paged_decode_attention(
                 q[:, 0], k_new[:, 0], v_new[:, 0], layer_k, layer_v,
-                page_table, n_stale, window=window,
+                page_table, n_stale, layer=layer, window=window,
                 pages_per_block=pages_per_block, interpret=interpret)
         return out[:, None, :]
 
@@ -860,11 +1067,21 @@ def make_paged_attention_fn(page_table: jax.Array, max_seq: int,
                                           window=window)
 
     def insert_all(pool_k, pool_v, k_news, v_news, lengths, active):
+        if in_place:
+            # A scope of its own, and none of benchmark/xplane.py SCOPES:
+            # ``attention.paged_decode`` must keep ONE custom call a paged
+            # layer a step (step.decode_ms counts steps by them).
+            with jax.named_scope("kv.paged_insert"):
+                return paged_insert_in_place(
+                    pool_k, pool_v, k_news, v_news, page_table, lengths,
+                    active, interpret=interpret)
         return paged_insert_all(pool_k, pool_v, k_news, v_news,
                                 page_table, lengths, active)
 
     attention_fn.decode = decode
     attention_fn.insert_all = insert_all
+    if in_place:
+        attention_fn.decode_at = decode_at
     if spec:
         # Spec-only provider: a `.verify` on the SHARED provider would
         # reroute every prefill chunk (T > 1) through the deferred path;

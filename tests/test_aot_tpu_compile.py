@@ -17,6 +17,7 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")   # or libtpu logs to /tmp
 
 import jax                                          # noqa: E402
 import jax.numpy as jnp                             # noqa: E402
+import numpy as np                                  # noqa: E402
 import pytest                                       # noqa: E402
 from jax.sharding import (NamedSharding,            # noqa: E402
                           PartitionSpec as P, SingleDeviceSharding)
@@ -156,3 +157,194 @@ def test_tp4_attention_compiles_on_the_engine_mesh(chips, quant, kind):
             return fn.decode(q, kn, vn, pk, pv, lengths, active)
         return fn(q, kn, vn, pk, pv, lengths, active)[0]
     _compiled_kernel(attend, *args, table)
+
+
+# ---------------------------------------------------------------------------
+# PR 30: the pool stays where it lies — the stacked read, the aliased write,
+# and the decode program that holds no copy of the pool
+# ---------------------------------------------------------------------------
+
+# What the benchmark's two configurations serve: (layers that hold a pool,
+# pages, KV heads, query heads, slots, table width, window).
+SERVED = {
+    "mistral-7b": (32, 169, 8, 32, 8, 32, 4096),
+    "solar-open2-ep8": (2, 1025, 8, 64, 32, 32, 0),
+}
+
+
+def _stacked(chips, geometry):
+    layers, pages, kv, heads, slots, width, window = SERVED[geometry]
+    one = SingleDeviceSharding(chips[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    side = {"q": sds((layers, pages, kv, PAGE, DH), jnp.int8),
+            "s": sds((layers, pages, kv, 1, PAGE), jnp.float32)}
+    return sds, side, sds((slots, width), jnp.int32)
+
+
+@pytest.mark.parametrize("geometry", list(SERVED))
+def test_stacked_decode_compiles_at_the_served_geometry(chips, geometry):
+    """The decode kernel on the whole layer-stacked int8 pool, the layer a
+    traced scalar: no slice of the pool is among its operands."""
+    layers, pages, kv, heads, slots, width, window = SERVED[geometry]
+    sds, side, table = _stacked(chips, geometry)
+    compiled = jax.jit(
+        lambda q, kn, vn, pk, pv, tbl, n, layer: pa.paged_decode_attention(
+            q, kn, vn, pk, pv, tbl, n, layer=layer, window=window,
+            interpret=False)).lower(
+        sds((slots, heads, DH), jnp.bfloat16),
+        sds((slots, kv, DH), jnp.bfloat16),
+        sds((slots, kv, DH), jnp.bfloat16), side, side, table,
+        sds((slots,), jnp.int32), sds((), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
+@pytest.mark.parametrize("tokens", [1, 5], ids=["decode", "verify5"])
+@pytest.mark.parametrize("geometry", list(SERVED))
+def test_in_place_write_compiles_at_the_served_geometry(chips, geometry,
+                                                        tokens):
+    """The write kernel with the pool donated: its four pool operands are
+    its outputs (all of the pool's bytes aliased, no temporary)."""
+    layers, pages, kv, heads, slots, width, window = SERVED[geometry]
+    sds, side, table = _stacked(chips, geometry)
+    new = sds((layers, slots, tokens, kv, DH), jnp.bfloat16)
+    compiled = jax.jit(
+        lambda *a: pa.paged_insert_in_place(*a, interpret=False),
+        donate_argnums=(0, 1)).lower(
+        side, side, new, new, table, sds((slots,), jnp.int32),
+        sds((slots,), jnp.bool_)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    memory = compiled.memory_analysis()
+    pool_bytes = 2 * layers * pages * kv * PAGE * (DH + 4)
+    assert memory.alias_size_in_bytes == pool_bytes
+    assert memory.temp_size_in_bytes < 2 ** 20
+
+
+def _loop_arrays(text: str, at_least: int) -> list[tuple[str, str, str]]:
+    """(instruction, opcode, line) for every instruction inside the
+    program's loops — the while bodies and what they call, fused
+    computations excluded: their insides are not materialised — whose
+    result holds an array of ``at_least`` bytes or more."""
+    import re
+    width = {"s8": 1, "u8": 1, "pred": 1, "bf16": 2, "f16": 2, "s16": 2,
+             "f32": 4, "s32": 4, "u32": 4}
+    bodies: dict[str, list[str]] = {}
+    name = None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            name = head.group(1)
+            bodies[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name:
+            bodies[name].append(line)
+    calls = {n: {c for ln in lines if " fusion(" not in ln
+                 for c in re.findall(
+                     r"(?:body|condition|to_apply|calls)=%?([\w.\-]+)", ln)}
+             for n, lines in bodies.items()}
+    todo = [b for lines in bodies.values() for ln in lines
+            if " while(" in ln for b in re.findall(r"body=%?([\w.\-]+)", ln)]
+    assert todo, "the program has no loop"
+    inside: set[str] = set()
+    while todo:
+        n = todo.pop()
+        if n not in inside and n in bodies:
+            inside.add(n)
+            todo += calls[n]
+    found = []
+    for n in sorted(inside):
+        for ln in bodies[n]:
+            m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\(?[^=]*?\)?) "
+                         r"([\w\-]+)\(", ln)
+            if not m or m.group(3) in ("parameter", "get-tuple-element",
+                                       "tuple", "while", "bitcast"):
+                continue
+            sizes = [int(np.prod([int(d) for d in dims.split(",") if d]))
+                     * width[dt]
+                     for dt, dims in re.findall(
+                         r"\b([a-z]+\d+|pred)\[([\d,]*)\]", m.group(2))
+                     if dt in width]
+            if sizes and max(sizes) >= at_least:
+                found.append((m.group(1), m.group(3), ln.strip()))
+    return found
+
+
+def test_decode_scan_leaves_the_pool_where_it_lies(chips, monkeypatch):
+    """Tentpole item 4 of PR 30, read from the compiled program: a
+    two-layer, two-step ``decode_scan`` of the ENGINE'S OWN step (its
+    ``_compile_paged`` on a stand-in that carries what it reads) at
+    Mistral-7B's widths, int8 weights and pool, compiled for the described
+    chip. Inside its loops nothing but the aliased write produces an array
+    the size of a layer's pool side; the carried pool has the default
+    layout; the temporaries are smaller than one layer's K + V. (At the
+    parent of PR 30 this fails three ways: a ``dynamic-slice`` fusion and
+    a ``copy_bitcast`` fusion a layer and side, two whole-pool scatter
+    fusions a step, the carried layout ``{4,2,3,1,0}``, 1.1 GB of
+    temporaries.) The pool is 513 pages — two layers of 169 would fit the
+    chip's 128 MiB of VMEM, where the compiler then parks the WHOLE pool
+    with a copy in and out a step: an artefact of a two-layer model."""
+    import re
+    import types
+    from dataclasses import replace
+
+    from llmapigateway_tpu.engine.engine import InferenceEngine
+    from llmapigateway_tpu.engine.sampling import SamplingParams
+    from llmapigateway_tpu.models import PRESETS
+
+    # The kernels are chosen for the CPU backend the process runs on; the
+    # program is compiled for the chip.
+    monkeypatch.setattr(pa, "_interpret_default", lambda: False)
+    slots, pages, depth = 8, 513, 2
+    config = replace(PRESETS["mistral-7b"], n_layers=2)
+    mesh = build_mesh({}, devices=chips[:1])
+    engine = types.SimpleNamespace(
+        model_cfg=config, quant="int8", dtype=jnp.bfloat16, mesh=mesh,
+        attention_impl="pallas", kv_ppb=1, S=8192, B=slots, pipe_n=1,
+        seq_n=1, spec_k=0, decode_burst=depth, _burst_depths=(depth,),
+        allocator=types.SimpleNamespace(num_pages=pages, page_size=PAGE))
+    InferenceEngine._compile_paged(engine)
+    assert engine.kv_pool_in_place
+    init, key = InferenceEngine._random_init_program(engine)
+    placed = NamedSharding(mesh, P())
+
+    def shapes(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=placed), tree)
+
+    def vec(dtype):
+        return jax.ShapeDtypeStruct((slots,), dtype, sharding=placed)
+    cache = shapes(jax.eval_shape(lambda: pa.PagedKVCache.create(
+        config, pages, PAGE, jnp.bfloat16, "int8")))
+    sampling = SamplingParams(
+        temperature=vec(jnp.float32), top_p=vec(jnp.float32),
+        top_k=vec(jnp.int32), presence_penalty=vec(jnp.float32),
+        frequency_penalty=vec(jnp.float32))
+    rng = jax.random.key(0)
+    compiled = engine._decode_fns[True][1][depth].lower(
+        shapes(jax.eval_shape(init, key)), cache,
+        jax.ShapeDtypeStruct((slots, config.vocab_size), jnp.int32,
+                             sharding=placed),
+        jax.ShapeDtypeStruct((slots, 32), jnp.int32, sharding=placed),
+        vec(jnp.int32), vec(jnp.int32), vec(jnp.bool_), sampling,
+        jax.ShapeDtypeStruct(rng.shape, rng.dtype)).compile()
+
+    text = compiled.as_text()
+    side = pages * config.n_kv_heads * PAGE * DH          # int8: bytes
+    big = _loop_arrays(text, side)
+    writes = [ln for _, op, ln in big if op == "custom-call"
+              and "kv.paged_insert" in ln and "output_to_operand_aliasing="
+              "{{0}: (6, {}), {1}: (7, {}), {2}: (8, {}), {3}: (9, {})}"
+              in ln]
+    assert len(writes) == 1, big
+    assert len(big) == 1, [(n, op) for n, op, _ in big]
+    # One decode kernel a layer a step under its scope: the layer scan's
+    # body holds one, and the write is filed elsewhere.
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"[^\n]*"
+                          r"attention\.paged_decode", text)) == 1
+    carried = re.findall(r"s8\[2,%d,8,256,128\]\{([\d,]+)" % pages, text)
+    assert carried and set(carried) == {"4,3,2,1,0"}, set(carried)
+    layer_kv = 2 * pages * config.n_kv_heads * PAGE * (DH + 4)
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_kv

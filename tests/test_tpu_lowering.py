@@ -24,6 +24,10 @@ from llmapigateway_tpu.ops import paged_attention as pa
 from llmapigateway_tpu.ops.flash_attention import (
     flash_decode_attention, flash_prefill_attention)
 
+# The int8 pools the benchmark's two configurations serve (the file that
+# COMPILES the same kernels for the described chip states them).
+from test_aot_tpu_compile import SERVED as STACKED
+
 B, KV, G, S, Dh, T = 2, 4, 2, 256, 128, 128
 H = KV * G
 P, PAGE, NP = 16, 128, 2
@@ -126,6 +130,33 @@ def test_paged_decode_lowers_at_served_geometry(geometry):
         sds((b, kv * g, dh), jnp.bfloat16), sds((b, kv, dh), jnp.bfloat16),
         sds((b, kv, dh), jnp.bfloat16), side, side,
         sds((b, n_pages), jnp.int32), sds((b,), jnp.int32))
+
+
+@pytest.mark.parametrize("kernel", ["read", "write", "write-verify5"])
+@pytest.mark.parametrize("geometry", list(STACKED))
+def test_stacked_pool_kernels_lower_at_served_geometry(geometry, kernel):
+    """PR 30's two kernels over the layer-STACKED pool (shapes only): the
+    decode kernel reading layer ``layer`` of it in place, and the write
+    kernel whose pool operands are its outputs."""
+    layers, pages, kv, heads, slots, width, window = STACKED[geometry]
+    sds = jax.ShapeDtypeStruct
+    side = {"q": sds((layers, pages, kv, 256, 128), jnp.int8),
+            "s": sds((layers, pages, kv, 1, 256), jnp.float32)}
+    table, ints = sds((slots, width), jnp.int32), sds((slots,), jnp.int32)
+    if kernel == "read":
+        _lower(lambda q, kn, vn, pk, pv, tbl, n, layer:
+               pa.paged_decode_attention(q, kn, vn, pk, pv, tbl, n,
+                                         layer=layer, window=window,
+                                         interpret=False),
+               sds((slots, heads, 128), jnp.bfloat16),
+               sds((slots, kv, 128), jnp.bfloat16),
+               sds((slots, kv, 128), jnp.bfloat16), side, side, table, ints,
+               sds((), jnp.int32))
+    else:
+        new = sds((layers, slots, 1 if kernel == "write" else 5, kv, 128),
+                  jnp.bfloat16)
+        _lower(lambda *a: pa.paged_insert_in_place(*a, interpret=False),
+               side, side, new, new, table, ints, sds((slots,), jnp.bool_))
 
 
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8kv"])
