@@ -19,6 +19,10 @@ the client. The window is ``[t_open, t_close)`` on the same clock.
   (last frame - first frame) / (tokens - 1); the metric is the median.
 * ``ttft_ms``: first content frame minus the due time (open loop) or the
   send time (closed loop), over requests due/sent inside the window.
+  ``ttft_mid_ms`` is the mean of that sample from its first quartile to
+  its third (``mid_mean``): the median of a few dozen requests is ONE
+  request's time, and which request stands in the middle changes from run
+  to run; the mean of the middle half moves with all of them.
 
 A failed request (non-200, error frame, no ``[DONE]``, no usage frame)
 counts in ``failed`` and as missing every percentile it would have been
@@ -28,6 +32,7 @@ from __future__ import annotations
 
 import bisect
 import dataclasses
+import functools
 import math
 from typing import Any, Iterable
 
@@ -87,6 +92,18 @@ def percentile(values: Iterable[float], q: float) -> float:
         raise ValueError("percentile of an empty sample")
     rank = max(1, math.ceil(q / 100.0 * len(xs)))
     return xs[rank - 1]
+
+
+def mid_mean(values: Iterable[float]) -> float:
+    """Mean of the sample from its nearest-rank first quartile to its
+    third, both included; +inf (a failure inside that range) makes it
+    +inf. Raises on an empty sample."""
+    xs = sorted(values)
+    if not xs:
+        raise ValueError("mid_mean of an empty sample")
+    lo, hi = (max(1, math.ceil(q * len(xs))) for q in (0.25, 0.75))
+    mid = xs[lo - 1:hi]
+    return sum(mid) / len(mid)
 
 
 BURST_S = 0.02      # frames this close to a burst's first frame are of it
@@ -180,12 +197,14 @@ def end_to_end(logs: list[RequestLog], t_open: float, t_close: float
     counts = {"out_tok_s": frames_in_window(logs, t_open, t_close)}
     tpot = tpot_samples(logs, t_open, t_close)
     ttft = ttft_samples(logs, t_open, t_close)
-    for name, sample, q in (("tpot_p50_ms", tpot, 50),
-                            ("ttft_p50_ms", ttft, 50),
-                            ("ttft_p90_ms", ttft, 90)):
+    p50, p90 = (functools.partial(percentile, q=q) for q in (50, 90))
+    for name, sample, stat in (("tpot_p50_ms", tpot, p50),
+                               ("ttft_p50_ms", ttft, p50),
+                               ("ttft_p90_ms", ttft, p90),
+                               ("ttft_mid_ms", ttft, mid_mean)):
         counts[name] = len(sample)
         if sample:
-            v = percentile(sample, q)
+            v = stat(sample)
             if math.isfinite(v):
                 values[name] = v
     return values, counts

@@ -29,11 +29,13 @@ Schema of a traffic file (every key but ``loop``, ``trace_seed``,
                     belongs to session i mod n, whose prompts all start
                     with the same p tokens
     temperature     sampling temperature (default 0: greedy)
-    why, source, assumed
+    why, source, assumed, calibration
                     not parameters: why the mix exists, the public trace
                     or dataset its numbers come from (with the quantiles
-                    taken from it), and every number that is this
-                    benchmark's own choice
+                    taken from it), every number that is this
+                    benchmark's own choice, and the sweep an open loop's
+                    ``rate_rps`` is arithmetic on (``knee_rps``, the
+                    commit swept, the rates, seconds a rate)
 
 A length spec is ``{"kind": "cycle", "values": [...]}``,
 ``{"kind": "uniform", "min", "max"}`` or ``{"kind": "lognormal",
@@ -83,7 +85,8 @@ class Traffic:
     @classmethod
     def load(cls, path: Path) -> "Traffic":
         raw = json.loads(Path(path).read_text())
-        for note in ("why", "source", "assumed"):   # for the reader
+        for note in ("why", "source", "assumed",     # for the reader
+                     "calibration"):
             raw.pop(note, None)
         known = {f.name for f in dataclasses.fields(cls)} - {"name"}
         unknown = set(raw) - known

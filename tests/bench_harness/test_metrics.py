@@ -5,9 +5,9 @@ import math
 import pytest
 
 from benchmark.metrics import (BURST_S, RequestLog, bursts, end_to_end,
-                               frames_in_window, overlapping, percentile,
-                               streamed_by, tokens_in_window, tpot_samples,
-                               ttft_samples)
+                               frames_in_window, mid_mean, overlapping,
+                               percentile, streamed_by, tokens_in_window,
+                               tpot_samples, ttft_samples)
 
 
 def req(i, send, frames, end, ok=True, due=None, **kw):
@@ -82,6 +82,47 @@ def test_percentile_is_nearest_rank():
     assert percentile([7.0], 90) == 7.0
     with pytest.raises(ValueError):
         percentile([], 50)
+
+
+@pytest.mark.parametrize("ttfts_ms, failures, want", [
+    # even count: ranks 2..6 of 8 (nearest rank: ceil(n/4), ceil(3n/4))
+    ([100, 200, 300, 400, 500, 600, 700, 800], 0, 400.0),
+    # odd count: ranks 2..6 of 7
+    ([700, 100, 600, 200, 500, 300, 400], 0, 400.0),
+    # one sample, and two: the quartiles fall on what there is
+    ([250], 0, 250.0),
+    ([100, 300], 0, 200.0),
+    # a failure past the third quartile: ranks 3..8 of 10 are all served
+    ([100, 200, 300, 400, 500, 600, 700, 800, 900], 1, 550.0),
+    # failures inside the range (ranks 2..6 of 8, two of them +inf): left out
+    ([100, 200, 300, 400, 500], 3, None),
+    # nothing due in the window
+    ([], 0, None),
+], ids=["even", "odd", "one", "two", "failure-outside", "failure-inside",
+        "empty"])
+def test_ttft_mid_is_the_mean_between_the_nearest_rank_quartiles(
+        ttfts_ms, failures, want):
+    logs = [req(i, 10.0 + i, [(10.0 + i + ms / 1000.0, 1)], 30.0)
+            for i, ms in enumerate(ttfts_ms)]
+    logs += [req(100 + k, 10.5 + k, [], 11.0 + k, ok=False)
+             for k in range(failures)]
+    sample = ttft_samples(logs, 0.0, 50.0)
+    values, counts = end_to_end(logs, 0.0, 50.0)
+    assert counts["ttft_mid_ms"] == len(sample) == len(ttfts_ms) + failures
+    if want is None:
+        assert "ttft_mid_ms" not in values
+        if not sample:
+            with pytest.raises(ValueError):
+                mid_mean(sample)
+    else:
+        assert values["ttft_mid_ms"] == pytest.approx(want)
+        assert mid_mean(sample) == pytest.approx(want)
+        # Between the two quartiles it is the mean of.
+        assert (percentile(sample, 25) <= values["ttft_mid_ms"]
+                <= percentile(sample, 75))
+    # The median beside it is as it was.
+    if sample and math.isfinite(percentile(sample, 50)):
+        assert values["ttft_p50_ms"] == percentile(sample, 50)
 
 
 def burst_stream(period, per_slot, slots, until):

@@ -108,6 +108,24 @@ def test_a_traffic_file_with_an_unknown_key_is_refused(tmp_path):
         Traffic.load(path)
 
 
+def test_the_open_loop_rate_is_four_fifths_of_its_recorded_knee(tmp_path):
+    """``chat-rate``'s ``rate_rps`` is arithmetic on the sweep its
+    ``calibration`` block records, which is for the reader: ``load`` drops
+    it, and still refuses a key it does not know beside it."""
+    raw = json.loads((TRAFFIC / "chat-rate.json").read_text())
+    cal = raw["calibration"]
+    assert raw["rate_rps"] == round(0.8 * cal["knee_rps"], 2)
+    assert cal["knee_rps"] in cal["rates"] and cal["step_seconds"] == 30
+    assert len(cal["commit"]) >= 7
+    t = Traffic.load(TRAFFIC / "chat-rate.json")
+    assert t.rate_rps == raw["rate_rps"] and not hasattr(t, "calibration")
+    path = tmp_path / "chat-rate.json"
+    path.write_text(json.dumps({**raw, "calibrated": cal}))
+    with pytest.raises(ValueError,
+                       match=r"unknown traffic keys \['calibrated'\]"):
+        Traffic.load(path)
+
+
 @pytest.mark.parametrize("mix", MIXES)
 def test_a_mix_names_its_source_and_what_it_assumed(mix):
     """A mix says which public trace its numbers come from, with the
