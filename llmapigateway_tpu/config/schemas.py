@@ -26,8 +26,7 @@ class DisaggregationConfig(BaseModel):
     a decode pool over ONE shared paged KV pool; a completed prefill
     hands its KV to the decode pool by allocator refcount transfer (zero
     device copies). Requires ``kv_layout: paged``; incompatible with
-    multihost, seq/pipe sharding, speculative decoding and SWA ring mode
-    (rejected at engine build).
+    speculative decoding and SWA ring mode (rejected at engine build).
     """
     model_config = ConfigDict(extra="forbid")
 
@@ -77,6 +76,22 @@ class SupervisorConfig(BaseModel):
     drain_deadline_ms: float = Field(default=10000.0, gt=0.0)
 
 
+# The mesh axes the engine shards over, slowest → fastest varying
+# (parallel/mesh.py lays devices out in this order).
+MESH_AXES = ("data", "expert", "model")
+
+
+def check_mesh_axes(sizes: dict[str, int]) -> None:
+    """Refuse a mesh that names an axis the program does not have: a
+    configuration file is input from outside, and an axis dropped in
+    silence would serve on fewer chips than the file says."""
+    for ax in sizes:
+        if ax not in MESH_AXES:
+            raise ValueError(
+                f"unknown mesh axis {ax!r}: the axes are "
+                f"{', '.join(MESH_AXES)}")
+
+
 class LocalEngineConfig(BaseModel):
     """Engine settings for a ``type: local`` provider entry.
 
@@ -89,8 +104,16 @@ class LocalEngineConfig(BaseModel):
     model_path: str = ""            # HF checkpoint dir (safetensors); "" → random init
     preset: str | None = None       # named config (e.g. "tinyllama-1.1b") when no checkpoint
     dtype: str = "bfloat16"
-    # Mesh geometry: axis name -> size. Product must equal device count used.
+    # Mesh geometry: axis name (one of MESH_AXES) -> size. Product must
+    # equal device count used.
     mesh: dict[str, int] = Field(default_factory=dict)   # e.g. {"data":1,"model":8}
+
+    @field_validator("mesh")
+    @classmethod
+    def _known_axes(cls, v: dict[str, int]) -> dict[str, int]:
+        check_mesh_axes(v)
+        return v
+
     max_batch_size: int = 8
     max_seq_len: int = 4096
     # Paged is THE serving path since 0.19 (ISSUE 6): page-pool KV with
@@ -142,8 +165,6 @@ class LocalEngineConfig(BaseModel):
     # to a compiled K rung {1,2,4,8}). A K-batch pays one dispatch for
     # K chunks; each (bucket, K) pair costs one lazily-compiled
     # program. 1 disables.
-    # Multihost always runs K=1 (coordinator/follower programs must
-    # stay bit-identical while followers replay per-slot frames).
     prefill_batch: int = 8
     decode_burst: int = 8           # chained decode steps per host sync
     # Burst depth while new work is waiting (prefill interleave): deep
@@ -169,14 +190,10 @@ class LocalEngineConfig(BaseModel):
     # Engages only while every active slot is greedy; while any
     # temperature>0 request is active the whole batch is served through
     # the normal (unaccelerated) decode path. Works with both KV
-    # layouts and composes with seq/pipe sharding (the verify forward's
-    # S-reductions partition under GSPMD / run through the staged
-    # block) AND with multi-host serving (OP_SPEC command stream,
-    # per-process hist mirrors) AND with kv_quant='int8' (the verify
-    # self-block is mixed-precision: off-diagonal drafts go through the
-    # same quantize→dequantize plain decode reads, preserving the
-    # exact-greedy guarantee; only seq-sharded PAGED + int8 + spec is
-    # rejected at build).
+    # layouts and with kv_quant='int8' (the verify self-block is
+    # mixed-precision: off-diagonal drafts go through the same
+    # quantize→dequantize plain decode reads, preserving the
+    # exact-greedy guarantee).
     spec_draft_len: int = 0
     # Adaptive drafting gate: a speculative step is a T=k+1 verify forward
     # (~1.2-1.3x a T=1 step's device time), so drafting only pays while
@@ -227,14 +244,9 @@ class LocalEngineConfig(BaseModel):
     # per-head int8 (+ fp32 scales, ~6% overhead) — halves KV bandwidth
     # AND capacity footprint, the long-context/high-concurrency lever.
     # Works with both KV layouts (a paged int8 pool packs 2x the tokens)
-    # and composes with `quant`; seq/pipe sharding and speculation are
-    # rejected at engine build (v1).
+    # and composes with `quant` and with speculation.
     kv_quant: str = ""              # "" | "int8"
     attention: str = "auto"         # "auto" | "pallas" | "reference"
-    # Attention pattern for a seq-sharded mesh: "ring" rotates KV blocks over
-    # ICI (works for any head count); "ulysses" all-to-alls heads<->sequence
-    # (cheaper collective when n_kv_heads >= seq axis size).
-    seq_attention: str = "ring"     # "ring" | "ulysses"
     tokenizer_path: str | None = None
     # Persistent XLA compilation cache: a second engine init skips the
     # trace+compile. JAX_COMPILATION_CACHE_DIR in the environment wins and

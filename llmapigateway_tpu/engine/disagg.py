@@ -125,13 +125,6 @@ class DisaggController:
                 "disaggregation requires kv_layout='paged': the KV "
                 "handoff is a page-table refcount transfer; a contiguous "
                 "cache would need a real device copy")
-        if engine._bridge.enabled:
-            raise ValueError("disaggregation is single-host only (v1): "
-                             "followers replay one command stream and "
-                             "have no pool scheduler")
-        if engine.seq_n > 1 or engine.pipe_n > 1:
-            raise ValueError("disaggregation does not compose with seq/"
-                             "pipe sharding (v1)")
         if engine.spec_k:
             raise ValueError(
                 "disaggregation + spec_draft_len is not supported (v1): "

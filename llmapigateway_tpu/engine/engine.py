@@ -50,8 +50,7 @@ every matmul weight as per-channel int8 (W8A8 on the MXU's native int8
 path — decode is weight-bandwidth-bound, so ~2× tok/s) and ``kv_quant``
 stores K/V as per-token int8 (halves KV bandwidth and capacity; both
 layouts). Both are plain ``{"q","s"}`` dict leaves in the params/cache
-pytrees, so sharding, scanning, and multihost transport treat them
-uniformly.
+pytrees, so sharding and scanning treat them uniformly.
 """
 from __future__ import annotations
 
@@ -281,6 +280,10 @@ class InferenceEngine:
         self.dtype = jnp.bfloat16 if engine_cfg.dtype == "bfloat16" else \
             jnp.dtype(engine_cfg.dtype)
 
+        if jax.process_count() > 1:
+            raise ValueError(
+                f"the engine serves from one process; this one is "
+                f"{jax.process_index()} of {jax.process_count()}")
         self.mesh = build_mesh(MeshSpec(sizes=dict(engine_cfg.mesh)), devices)
         self.B = engine_cfg.max_batch_size
         self.S = min(engine_cfg.max_seq_len, model_cfg.max_seq_len)
@@ -318,75 +321,6 @@ class InferenceEngine:
         self.kv_page = max(1, min(engine_cfg.kv_page_size, self.S))
         self._swa_ring_pages = 0        # set by the paged+SWA init branch
         self._swa_margin = 0            # in-flight burst margin, tokens
-        # Sequence parallelism (SURVEY.md §5 long-context): with a `seq`
-        # mesh axis, the KV cache's S dim is sharded across chips and
-        # prefill runs ONE whole-prompt ring-attention program instead of
-        # chunk-at-a-time (a chunk's KV insert would straddle shards; the
-        # ring sees every block exactly once with compute/ICI overlap).
-        self.seq_n = self.mesh.shape.get("seq", 1)
-        self.seq_attention = engine_cfg.seq_attention
-        if self.seq_n > 1:
-            if self.paged:
-                # Paged × seq: the pool's page dim shards over `seq` with
-                # POSITION-BANDED allocation (engine/paged.py) so every
-                # chip's S-shard of the gathered dense view reads only
-                # local pages; band boundaries must fall on pages.
-                if self.S % (self.seq_n * self.kv_page):
-                    raise ValueError(
-                        f"paged × seq needs max_seq_len {self.S} divisible "
-                        f"by seq × page = "
-                        f"{self.seq_n * self.kv_page}")
-                # (SWA × seq — paged or not — is rejected by the
-                # sliding-window guardrail below.)
-            if self.S % self.seq_n:
-                raise ValueError(
-                    f"max_seq_len {self.S} must be divisible by the seq "
-                    f"axis size {self.seq_n}")
-            if self.seq_attention not in ("ring", "ulysses"):
-                raise ValueError(
-                    f"unknown seq_attention {self.seq_attention!r}; "
-                    f"expected ring | ulysses")
-            if self.seq_attention == "ulysses" and (
-                    model_cfg.n_heads % self.seq_n
-                    or model_cfg.n_kv_heads % self.seq_n):
-                # Ulysses all-to-alls the head dim across the seq axis —
-                # impossible when heads don't divide. Ring is always legal;
-                # fall back rather than refuse the whole engine.
-                logger.warning(
-                    "seq_attention=ulysses needs heads divisible by the "
-                    "seq axis (H=%d, KV=%d, seq=%d); falling back to ring",
-                    model_cfg.n_heads, model_cfg.n_kv_heads, self.seq_n)
-                self.seq_attention = "ring"
-            # One prefill program covering the whole prompt: chunking is
-            # disabled (TTFT tradeoff: a long prompt occupies the engine
-            # for one full-prefill program instead of interleaving).
-            self.prefill_chunk = self.S
-
-        # Multi-host: process 0 runs the scheduler and publishes every
-        # compiled-program call; followers replay (parallel/multihost.py).
-        # Paged layout: the page table rides the command stream (followers
-        # have no allocator), sized here so the wire width is fixed.
-        from ..parallel.multihost import HostBridge
-        page = self.kv_page
-        self._bridge = HostBridge(
-            self.B, self.prefill_chunk,
-            table_slots=(self.S + page - 1) // page if self.paged else 0)
-        self._published_table: np.ndarray | None = None
-        # Pipeline parallelism: with a `pipe` axis the compiled programs run
-        # the GPipe schedule (parallel/pipeline.py) — params and KV cache
-        # shard their layer dim per stage, activations hop stage-to-stage
-        # via ppermute. Decode splits the slot batch into `pipe`
-        # microbatches when divisible (else M=1: correct, bubble-heavy).
-        self.pipe_n = self.mesh.shape.get("pipe", 1)
-        if self.pipe_n > 1:
-            if self.seq_n > 1:
-                raise ValueError("mesh axes pipe and seq cannot be "
-                                 "combined (pick PP or SP, not both)")
-            if model_cfg.n_layers % self.pipe_n:
-                raise ValueError(
-                    f"n_layers {model_cfg.n_layers} not divisible by "
-                    f"pipe={self.pipe_n} stages")
-
         # Int8 weight quantization (models/quant.py): validated here so a
         # bad config fails at engine build (→ provider error → fallback),
         # not mid-load.
@@ -400,55 +334,6 @@ class InferenceEngine:
         if self.kv_quant not in ("", "int8"):
             raise ValueError(f"unknown kv_quant {self.kv_quant!r}; "
                              f"expected '' | 'int8'")
-        if self.kv_quant:
-            # Composes with seq sharding (ring/ulysses attend fresh q/k/v;
-            # the S-sharded insert/decode paths are quantization-aware)
-            # AND with pipeline sharding (the staged block tree-maps its
-            # batch slicing over {q, s} cache leaves — parallel/
-            # pipeline.py, closing VERDICT r3 item 7).
-            # Speculative decoding composes since the verify self-block
-            # went mixed-precision (models/llama.py dense_verify_attention
-            # + the paged deferred verify): drafted tokens at u < t go
-            # through the same quantize→dequantize the insert path
-            # applies, the diagonal stays full precision like the decode
-            # self-column — so greedy output with spec on is exactly the
-            # spec-off sequence. Two combos remain unimplemented, both
-            # because their verify rides the insert-then-attend chunk
-            # path (no ``.verify`` provider), which reads even the draft
-            # self token quantized: the seq-sharded PAGED engine, and any
-            # pipeline-sharded engine (parallel/pipeline.py stage blocks
-            # verify as a chunk by design).
-            if (engine_cfg.spec_draft_len and self.paged
-                    and self.seq_n > 1):
-                raise ValueError(
-                    "kv_quant='int8' + spec_draft_len + seq-sharded "
-                    "paged cache is not supported: the seq-paged verify "
-                    "rides the chunk path, which reads the draft self "
-                    "token quantized (breaking exact-greedy parity). "
-                    "Use kv_layout='contiguous' with seq sharding, or "
-                    "drop seq sharding for the paged layout")
-            if engine_cfg.spec_draft_len and self.pipe_n > 1:
-                raise ValueError(
-                    "kv_quant='int8' + spec_draft_len + pipeline "
-                    "sharding is not supported: the staged block "
-                    "verifies drafts on the chunk path, which reads the "
-                    "draft self token quantized (breaking exact-greedy "
-                    "parity). Drop pipe sharding or kv_quant for "
-                    "speculative runs")
-
-        # Sliding-window attention (mistral family): the windowed dense
-        # paths, the windowed flash kernels, AND the windowed paged
-        # kernels all carry the bound — a windowed paged decode reads
-        # O(window) *pages* (ops/paged_attention.py), compounding the SWA
-        # bandwidth win with paging's capacity win. Full GSPMD DP/TP/PP
-        # and speculation compose. Only seq sharding is excluded:
-        # ring/ulysses attention is unwindowed (and a 4k-window model
-        # has no sequence long enough to need S sharded).
-        if model_cfg.sliding_window and self.seq_n > 1:
-            raise ValueError(
-                "sliding-window models do not compose with seq "
-                "sharding (v1: ring/ulysses attention is unwindowed)")
-
         # Prompt-lookup speculative decoding (engine/speculative.py).
         self.spec_k = max(0, engine_cfg.spec_draft_len)
         if self.spec_k:
@@ -456,11 +341,6 @@ class InferenceEngine:
                 raise ValueError(
                     f"spec_draft_len must be one of 1, 3, 7 (verify width "
                     f"k+1 must be a power of two), got {self.spec_k}")
-            # Multihost composes: OP_SPEC rides the command stream, every
-            # process maintains a bit-identical hist mirror, and the
-            # data-dependent advances are derived on each host from its
-            # own fetch of the same emitted matrix (parallel/multihost.py
-            # wire-format notes).
 
         if model_cfg.n_lin_layers:
             self._refuse_for_recurrent_state()
@@ -479,8 +359,8 @@ class InferenceEngine:
         self._init_params()
         t1 = time.monotonic()
         self._init_state()
-        # What actually serves ("auto" resolved, seq/pipe downgrade
-        # applied) — exposed in stats() so a run can assert it.
+        # What actually serves ("auto" resolved) — exposed in stats() so a
+        # run can assert it.
         self.attention_impl = self._resolve_attention_impl()
         # Whether the decode programs leave the page pool where it lies
         # (read by layer, written through aliased operands); static per
@@ -613,7 +493,6 @@ class InferenceEngine:
     def _init_params(self) -> None:
         c = self.model_cfg
         t0 = time.monotonic()
-        from ..parallel.multihost import put_global
         if self.cfg.model_path:
             from .checkpoint import _np_dtype, load_checkpoint
             from ..parallel.sharding import spec_for_param
@@ -622,7 +501,7 @@ class InferenceEngine:
 
             def put(path: str, arr: np.ndarray) -> jax.Array:
                 # ".q"/".s" quantized sub-leaves get their own rules.
-                return put_global(
+                return jax.device_put(
                     arr, spec_for_param(path, tuple(arr.shape), self.mesh))
 
             def preprocess(path: str, arr: np.ndarray):
@@ -660,9 +539,7 @@ class InferenceEngine:
             # params materialize directly in their GSPMD layout (no host
             # copy, no host→device transfer), and the whole init lands in
             # the persistent compilation cache — the eager per-op form
-            # compiled ~10 one-off programs on every cold start. Multihost:
-            # same program + same key on every process → identical values,
-            # each process computing only its addressable shards.
+            # compiled ~10 one-off programs on every cold start.
             init, key = self._random_init_program()
             self.params = init(key)
             jax.block_until_ready(self.params)
@@ -717,8 +594,7 @@ class InferenceEngine:
 
             page = self.kv_page
             per_slot = (self.S + page - 1) // page
-            n_bands = self.seq_n if self.seq_n > 1 else 1
-            # Sliding-window RING reservation (single host/stage/band):
+            # Sliding-window RING reservation (one device):
             # the windowed kernels never read below pos − window, so a
             # ring of O(window) physical pages serves ANY context length —
             # ensure_mapped recycles each slot's oldest dead page onto the
@@ -726,9 +602,7 @@ class InferenceEngine:
             # granularity). Margins: in-flight lag-one bursts may still
             # read one burst below the current floor, and dispatch writes
             # run one burst/chunk ahead.
-            if (c.sliding_window and self.mesh.size == 1
-                    and self.pipe_n == 1 and n_bands == 1
-                    and not self._bridge.enabled):
+            if c.sliding_window and self.mesh.size == 1:
                 # ONE copy of the margin: _swa_rotate's recycle floor
                 # must stay in lockstep with the capacity the ring was
                 # sized for, or rotation exhausts mid-stream.
@@ -751,9 +625,7 @@ class InferenceEngine:
             ppb_req = max(1, self.cfg.kv_pages_per_block)
             if ppb_req > 1:
                 why = None
-                if n_bands > 1:
-                    why = "seq-banded pool (positions band per chip)"
-                elif self._swa_ring_pages:
+                if self._swa_ring_pages:
                     why = "SWA page ring (mappings rotate per page)"
                 elif per_slot % ppb_req:
                     why = (f"pages per slot ({per_slot}) not divisible "
@@ -768,10 +640,9 @@ class InferenceEngine:
                     logger.warning(
                         "kv_pages_per_block=%d falls back to per-page "
                         "blocks: %s", ppb_req, why)
-            # One trash page per band (seq-sharded pools redirect masked
-            # writes shard-locally); a PACKED pool reserves the whole
-            # trash superpage instead.
-            n_trash = self.kv_ppb if self.kv_ppb > 1 else n_bands
+            # One trash page; a PACKED pool reserves the whole trash
+            # superpage instead.
+            n_trash = self.kv_ppb
             # The most pages one slot ever holds — the ring where it
             # runs, else the whole context — sizes the derived pool: every
             # slot can hold a max-footprint sequence at once either way.
@@ -783,27 +654,19 @@ class InferenceEngine:
                     f"kv_num_pages={num_pages} cannot hold one "
                     f"max-footprint sequence ({min_hold} pages of {page})")
             self.allocator = PageAllocator(num_pages, page, self.B, self.S,
-                                           n_bands=n_bands,
                                            pages_per_block=self.kv_ppb)
             # Radix prefix cache (ISSUE 6): cross-request KV reuse over
             # the pool, block = one superpage run so the multi-page
             # kernels apply to shared pages unchanged. Gated to the
             # geometries where page identity is stable for a sequence's
-            # lifetime: single-band (a banded pool's pages are
-            # chip-local), non-SWA (ring rotation re-targets pages;
-            # windowed attention never re-reads old prefixes anyway),
-            # single-host (followers replay the broadcast table but hold
-            # no allocator/cache state to mirror the index).
-            if (self.cfg.prefix_cache and n_bands == 1
-                    and not self._swa_ring_pages and not c.sliding_window
-                    and not self._bridge.enabled):
+            # lifetime: non-SWA (ring rotation re-targets pages; windowed
+            # attention never re-reads old prefixes anyway).
+            if (self.cfg.prefix_cache and not self._swa_ring_pages
+                    and not c.sliding_window):
                 from .prefix_cache import RadixPrefixCache
                 self._prefix_cache = RadixPrefixCache(
                     self.allocator, block_tokens=self.kv_ppb * page)
-            psh = paged_cache_sharding(
-                self.mesh, c.n_kv_heads,
-                n_layers=c.n_layers if self.pipe_n > 1 else None,
-                num_pages=num_pages if n_bands > 1 else None)
+            psh = paged_cache_sharding(self.mesh, c.n_kv_heads)
             # Layout owned by PagedKVCache.create (the one copy of the
             # int8 {q,s} scheme); value leaves shard via psh, the rank-4
             # [.., KV, 1, page] scale planes via the same spec with the
@@ -838,28 +701,27 @@ class InferenceEngine:
             self._d_table = None
             self._table_dirty = True
         else:
-            from ..parallel.multihost import zeros_global
-            csh = cache_sharding(
-                self.mesh, c.n_kv_heads, self.B,
-                max_seq=self.S if self.seq_n > 1 else None,
-                n_layers=c.n_layers if self.pipe_n > 1 else None)
+            csh = cache_sharding(self.mesh, c.n_kv_heads, self.B)
             shape = (c.n_layers, self.B, c.n_kv_heads, self.S, c.head_dim)
+
+            def zeros(shape, dtype, sharding):
+                return jax.device_put(jnp.zeros(shape, dtype), sharding)
             if self.kv_quant == "int8":
                 # int8 values + per-token fp32 scales, stored rank-4
                 # [L, B, KV, 1, S] (models/llama.py KVCache): the value
                 # sharding with the S axis moved last (head_dim dropped,
-                # None for the unit dim) — a seq-sharded S stays sharded.
+                # None for the unit dim).
                 ssh = NamedSharding(
                     self.mesh, P(*csh.spec[:-2], None, csh.spec[-2]))
                 def qz():
-                    return {"q": zeros_global(shape, jnp.int8, csh),
-                            "s": zeros_global(shape[:-2] + (1, shape[-2]),
-                                              jnp.float32, ssh)}
+                    return {"q": zeros(shape, jnp.int8, csh),
+                            "s": zeros(shape[:-2] + (1, shape[-2]),
+                                       jnp.float32, ssh)}
                 self.cache = llama.KVCache(k=qz(), v=qz())
             else:
                 self.cache = llama.KVCache(
-                    k=zeros_global(shape, self.dtype, csh),
-                    v=zeros_global(shape, self.dtype, csh))
+                    k=zeros(shape, self.dtype, csh),
+                    v=zeros(shape, self.dtype, csh))
         # Routed assignments of the decode steps (hybrid family): the
         # device keeps wrapping int32 totals in the cache, every burst
         # hands them back beside its tokens, the host sums the deltas.
@@ -877,9 +739,8 @@ class InferenceEngine:
         # [B, V] int32, DEVICE-authoritative (prefill resets a slot's row
         # and counts the prompt; the general decode path counts each
         # step's INPUT token — so the count visible when sampling token
-        # t+1 covers prompt + generated through t, and multihost
-        # followers stay bit-identical without broadcasting sampled
-        # tokens). The greedy fast path passes it through untouched:
+        # t+1 covers prompt + generated through t). The greedy fast path
+        # passes it through untouched:
         # stale rows are harmless because a row's counts only matter to
         # its OWN request's penalties, and penalty requests are (a)
         # reset at admission and (b) force the general path.
@@ -887,8 +748,7 @@ class InferenceEngine:
             np.zeros((self.B, self.model_cfg.vocab_size), np.int32),
             NamedSharding(self.mesh, P()))
         # Typed PRNG key end-to-end (the legacy raw-uint32 path is slated to
-        # become an error in future JAX); the multihost broadcast bit-casts
-        # via key_data/wrap_key_data at the wire boundary only.
+        # become an error in future JAX).
         self._rng = jax.random.key(int(time.time() * 1e3) % (2**31))
         # Device-resident mirrors for the chained decode loop; re-uploaded
         # (once) whenever host slot state changes.
@@ -989,9 +849,8 @@ class InferenceEngine:
             # are masked on device (deterministic 1 token/step), its EMA
             # freezes at the suspended value, and the batch-mean gate
             # above excludes it. Suspended slots re-probe together every
-            # spec_probe_interval spec rounds (the probe bit rides the
-            # OP_SPEC command in multihost so every process masks
-            # identically). Per-slot proposed/accepted counters feed the
+            # spec_probe_interval spec rounds. Per-slot proposed/accepted
+            # counters feed the
             # /metrics gauges and stats(); lifetime totals survive slot
             # release.
             self.spec_floor = min(1.0, max(
@@ -1045,27 +904,6 @@ class InferenceEngine:
             model_forward = family_forward
         else:
             model_forward = partial(family_forward, attention_fn=attention_fn)
-        if self.seq_n > 1:
-            # Whole-prompt prefill attends via the configured seq pattern —
-            # ring (K/V blocks rotate over ICI; any head count) or Ulysses
-            # (two all-to-alls reshard heads<->sequence; cheaper when heads
-            # divide the axis). Decode keeps the dense path — GSPMD
-            # partitions its S-reductions over the sharded cache.
-            # model_forward above stays the DECODE forward.
-            prefill_forward = partial(
-                family_forward,
-                attention_fn=_seq_prefill_attention_fn(
-                    self.mesh, self.seq_attention))
-        elif self.pipe_n > 1:
-            # Both compiled programs run the GPipe schedule: decode splits
-            # the B slots into `pipe` microbatches (when divisible);
-            # prefill's single-slot row degrades to M=1 (correct,
-            # bubble-heavy — prefill cost is dominated by FLOPs anyway).
-            model_forward = _pipelined_family_forward(self.mesh, self.pipe_n)
-            prefill_forward = model_forward
-        else:
-            prefill_forward = model_forward
-
         replicated = NamedSharding(self.mesh, P())
 
         @partial(jax.jit, donate_argnums=(1, 2))
@@ -1088,10 +926,7 @@ class InferenceEngine:
             into one dispatch, as before. Per-k cache rows move via
             unrolled dynamic slices (NOT a gather: the B axis may be
             sharded over `data`, and dynamic_slice is the op GSPMD
-            already partitions correctly for the K=1 path).
-            Multihost followers always run K=1 (see _step): batched
-            grouping is a compile-shape choice, and coordinator/follower
-            programs must stay bit-identical."""
+            already partitions correctly for the K=1 path)."""
             K = tokens.shape[0]
 
             def rows_of(side):
@@ -1102,7 +937,7 @@ class InferenceEngine:
                          for k in range(K)], axis=1), side)
             row_cache = llama.KVCache(k=rows_of(cache.k),
                                       v=rows_of(cache.v))
-            logits, row_cache = prefill_forward(
+            logits, row_cache = model_forward(
                 params, c, tokens, start_len, row_cache)
 
             def scatter(full, rows):
@@ -1140,9 +975,7 @@ class InferenceEngine:
             body; both compiled programs below are built from it. Returns
             (next_tokens, new_lengths, cache) so the token/length feedback
             loop stays ON DEVICE across steps — host fetches happen
-            asynchronously, steps behind. Sampled tokens are pinned
-            replicated so the host fetch is local on every process
-            of a multi-host mesh. ``greedy=True`` compiles the
+            asynchronously, steps behind. ``greedy=True`` compiles the
             argmax-only variant — it skips the full-vocab sort the general
             sampler pays per step; the scheduler picks it whenever every
             active slot has temperature 0 AND zero penalties (the common
@@ -1199,19 +1032,6 @@ class InferenceEngine:
         if impl not in ("auto", "pallas", "reference"):
             raise ValueError(f"unknown attention impl {impl!r}; "
                              f"expected auto | pallas | reference")
-        if self.seq_n > 1 or self.pipe_n > 1:
-            # The Pallas kernels address a full-extent local cache; with S
-            # sharded over `seq` (or the pipelined schedule, which fixes
-            # its own dense per-stage attention) the path is the
-            # GSPMD-partitioned dense reference.
-            if impl == "pallas":
-                logger.warning("attention=pallas is not available with a "
-                               "seq- or pipe-sharded engine; using reference")
-            else:
-                logger.info("attention: reference (seq/pipe-sharded engine "
-                            "— Pallas kernels need a full-extent local "
-                            "cache)")
-            return "reference"
         if impl == "auto":
             return "pallas" if jax.default_backend() == "tpu" else "reference"
         return impl
@@ -1228,8 +1048,6 @@ class InferenceEngine:
 
         impl = self.attention_impl
         mesh = self.mesh if self.mesh.size > 1 else None
-        # (A pipelined or seq-sharded engine has a mesh, and the reference
-        # path: its own scans and providers slice the pool.)
         self.kv_pool_in_place = pool_in_place(impl, mesh)
         logger.info("paged KV cache: %d pages × %d tokens, attention=%s"
                     "%s, kv_pool_in_place=%s", self.allocator.num_pages,
@@ -1240,61 +1058,21 @@ class InferenceEngine:
 
         replicated = NamedSharding(self.mesh, P())
 
-        if self.pipe_n > 1:
-            # Paged × PP: the pool's layer dim is staged over `pipe`
-            # (paged_cache_sharding) and the GPipe schedule slices TABLE
-            # rows per microbatch instead of cache rows — the attention
-            # builder must be identity-stable for the pipeline's program
-            # memo, hence ONE partial per engine.
-            make_attn = partial(make_paged_attention_fn, max_seq=S,
-                                impl=impl, mesh=mesh,
-                                window=c.sliding_window,
-                                pages_per_block=self.kv_ppb)
-            pipe_fwd = _pipelined_family_forward(self.mesh, self.pipe_n,
-                                                 make_attention=make_attn)
-
-            def call_forward(params, cache, table, tokens, lengths,
-                             active=None, prefill=False):
-                return pipe_fwd(params, c, tokens, lengths, cache,
-                                active=active, table=table)
-        elif self.seq_n > 1:
-            # Paged × seq: whole-prompt prefill attends via ring/ulysses
-            # over the fresh q/k/v (no cache read) and writes through the
-            # shard_map'd BANDED scatter; decode gathers each chip's
-            # local pages into the dense S-sharded view and runs the
-            # dict-aware deferred dense attention under GSPMD — the same
-            # partitioning story as the dense seq engine
-            # (ops/paged_attention.make_seq_paged_attention_fn).
-            from ..ops.paged_attention import make_seq_paged_attention_fn
-            seq_kind = self.seq_attention
-            eng_mesh = self.mesh
-
-            def call_forward(params, cache, table, tokens, lengths,
-                             active=None, prefill=False):
-                attn = make_seq_paged_attention_fn(table, max_seq=S,
-                                                   mesh=eng_mesh)
-                if prefill:
-                    attn = _seq_paged_prefill_attention_fn(
-                        eng_mesh, seq_kind, attn)
-                return family_forward(params, c, tokens, lengths, cache,
-                                      active=active, attention_fn=attn)
-        else:
-            def call_forward(params, cache, table, tokens, lengths,
-                             active=None, prefill=False, spec=False,
-                             **rows):
-                # `spec` builds the dedicated verify-capable provider:
-                # T = k+1 then routes through the deferred paged verify
-                # (stale-pool gather + mixed-precision self-block) instead
-                # of the chunk path — required for int8 greedy parity and
-                # skips the per-layer pool scatters either way.
-                attn = make_paged_attention_fn(table, max_seq=S, impl=impl,
-                                               mesh=mesh,
-                                               window=c.sliding_window,
-                                               pages_per_block=self.kv_ppb,
-                                               spec=spec)
-                return family_forward(params, c, tokens, lengths, cache,
-                                      active=active, attention_fn=attn,
-                                      **rows)
+        def call_forward(params, cache, table, tokens, lengths,
+                         active=None, spec=False, **rows):
+            # `spec` builds the dedicated verify-capable provider:
+            # T = k+1 then routes through the deferred paged verify
+            # (stale-pool gather + mixed-precision self-block) instead
+            # of the chunk path — required for int8 greedy parity and
+            # skips the per-layer pool scatters either way.
+            attn = make_paged_attention_fn(table, max_seq=S, impl=impl,
+                                           mesh=mesh,
+                                           window=c.sliding_window,
+                                           pages_per_block=self.kv_ppb,
+                                           spec=spec)
+            return family_forward(params, c, tokens, lengths, cache,
+                                  active=active, attention_fn=attn,
+                                  **rows)
 
         def engine_cache(cache):
             """The forward's cache as the type the engine holds (a family
@@ -1326,7 +1104,7 @@ class InferenceEngine:
                 [jax.lax.dynamic_slice_in_dim(table, slots[k], 1, axis=0)
                  for k in range(K)], axis=0)
             logits, cache = call_forward(
-                params, cache, rows_tbl, tokens, start_len, prefill=True,
+                params, cache, rows_tbl, tokens, start_len,
                 **({"slots": slots, "n_valid": last_idx + 1}
                    if rows_known else {}))
             counts, count_rows = _prefill_counts(
@@ -1380,14 +1158,8 @@ class InferenceEngine:
 
             def make_fwd(tbl):
                 def fwd(params, c_, tokens, lengths, cache, active=None):
-                    # Only the single-host paged path has the dedicated
-                    # verify provider; the seq- and pipe-sharded
-                    # call_forwards verify on their chunk paths (exact
-                    # for bf16 KV; int8 combos are rejected at build).
-                    kw = ({"spec": True}
-                          if self.seq_n == 1 and self.pipe_n == 1 else {})
                     return call_forward(params, cache, tbl, tokens,
-                                        lengths, active=active, **kw)
+                                        lengths, active=active, spec=True)
                 return fwd
 
             self._spec_scan_len = max(
@@ -1528,13 +1300,6 @@ class InferenceEngine:
 
     # -- public API ----------------------------------------------------------
     async def start(self) -> None:
-        if self._bridge.enabled and self._bridge._shutdown_sent:
-            # Terminal in multihost mode: followers exited on SHUTDOWN, so
-            # a restarted coordinator's first publish would hang forever in
-            # the collective (advisor r1, medium).
-            raise RuntimeError(
-                "multihost engine is terminal after stop(); restart the "
-                "whole fleet to serve again")
         if self.supervisor.state == "failed":
             raise EngineUnavailable(
                 "engine is failed (restart budget exhausted or fatal "
@@ -1590,11 +1355,6 @@ class InferenceEngine:
         if self._prev_debug_nans is not None:
             jax.config.update("jax_debug_nans", self._prev_debug_nans)
             self._prev_debug_nans = None
-        # Only after the loop has fully drained: an in-flight burst's
-        # DECODE broadcast racing SHUTDOWN from another thread could reach
-        # followers out of order and strand them mid-collective.
-        if self._bridge.enabled:
-            await asyncio.to_thread(self._bridge.publish_shutdown)
         # Flush terminal deltas so no consumer awaits a stream forever.
         for req in list(self._running.values()):
             req.out_queue.put_nowait(Delta(error="engine stopped"))
@@ -1777,22 +1537,6 @@ class InferenceEngine:
             req.out_queue.put_nowait(
                 Delta(error=f"engine failure: {failure}"))
             self._release(req)
-        if self._bridge.enabled:
-            # Multihost: a local re-init would silently desync the
-            # followers' cache shards (they saw no failure) and every
-            # later SPMD call would compute garbage. The only safe
-            # recovery is fleet shutdown; the gateway's fallback chain
-            # takes over (provider error → remote).
-            logger.error("multihost engine failure is fatal: "
-                         "shutting the fleet down")
-            sup.transition("failed", f"multihost {failure.kind} failure")
-            self._stopped = True
-            self._fail_queued(f"engine failure: {failure}")
-            # Safe here: the failed burst's own broadcast completed
-            # before its execution raised, and no other publisher runs
-            # concurrently with this handler.
-            await asyncio.to_thread(self._bridge.publish_shutdown)
-            return
         if failure.kind == "fatal" or not sup.can_restart():
             reason = ("fatal failure (restart would loop on it)"
                       if failure.kind == "fatal" else
@@ -1819,7 +1563,7 @@ class InferenceEngine:
 
     def _fail_queued(self, msg: str) -> None:
         """Flush queued-but-unstarted admissions with terminal errors —
-        only on the no-recovery paths (failed / multihost shutdown)."""
+        only on the no-recovery (failed) paths."""
         if self._head is not None:
             self._head.out_queue.put_nowait(Delta(error=msg))
             self._head = None
@@ -1984,19 +1728,14 @@ class InferenceEngine:
         #    chunk — SURVEY.md §7 hard part (6)). Same-bucket chunks group
         #    into ONE compiled call (batched admission — one dispatch
         #    for the group, see _prefill_chunk_group), the group
-        #    size snapped down to a compiled K rung. Multihost runs K=1:
-        #    followers replay per-slot PREFILL frames, and coordinator/
-        #    follower programs must stay bit-identical. The seq-sharded
-        #    engine also runs K=1 (its prefill is one whole-prompt ring
-        #    program; admission concurrency is not its regime).
+        #    size snapped down to a compiled K rung.
         eligible: list[GenRequest] = []
         for slot, req in list(self._prefilling.items()):
             if req.cancelled:
                 self._finish(req, "cancelled", emit=False)
                 continue
             eligible.append(req)
-        batch_k = (1 if self._bridge.enabled or self.seq_n > 1
-                   else self._prefill_k_rungs[0])
+        batch_k = self._prefill_k_rungs[0]
         if batch_k <= 1 or len(eligible) <= 1:
             for req in eligible:
                 if req.cancelled:
@@ -2103,18 +1842,13 @@ class InferenceEngine:
             # spec_probe_interval rounds — so enabling speculation in
             # config is safe for non-repetitive traffic.
             spec_probe = False
-            if spec_now and self._spec_wall_gate_on \
-                    and not self._bridge.enabled:
+            if spec_now and self._spec_wall_gate_on:
                 # Baseline probe: the wall gate needs a NORMAL-path step
                 # time to compare against, and spec-open traffic never
                 # runs normal bursts. Two consecutive normal rounds (a
                 # steady same-depth pair is what lands a wall sample),
                 # immediately while no baseline exists, then refreshed
-                # every 8*spec_probe_interval spec rounds. Multihost is
-                # excluded: its bursts run synchronously through the
-                # bridge (no lag-one walls are ever sampled), so the
-                # wall gate is inert there and the probe would pin
-                # spec_now=False forever on a never-measured baseline.
+                # every 8*spec_probe_interval spec rounds.
                 if self._spec_base_rounds > 0:
                     self._spec_base_rounds -= 1
                     spec_now = False
@@ -2549,10 +2283,8 @@ class InferenceEngine:
         """Advance each request by one prompt chunk in ONE compiled call
         (K=1 is the single-request path): K queued prefills pay one
         dispatch. The scheduler's grouper
-        guarantees every request here shares one compile bucket
-        and that multihost runs K=1 only (followers replay per-slot
-        PREFILL frames; coordinator/follower programs must stay
-        bit-identical). Returns per-request prompt-complete flags."""
+        guarantees every request here shares one compile bucket.
+        Returns per-request prompt-complete flags."""
         slots, poss, chunks, samps = [], [], [], []
         for req in reqs:
             slot = req.slot
@@ -2576,8 +2308,6 @@ class InferenceEngine:
             if self.fault_plan:
                 self.fault_plan.on_prefill()
             self._spec_hist_chunk(slot, pos, chunk)
-            self._bridge.publish_prefill(slot, pos, chunk,
-                                         table=self._table_to_publish())
             slots.append(slot)
             poss.append(pos)
             chunks.append(chunk)
@@ -2595,10 +2325,7 @@ class InferenceEngine:
                 continue
             # Prompt complete: the first token was sampled inside the
             # prefill program (see prefill_step) — ONE host fetch for the
-            # whole group completes the TTFT path. Followers of a
-            # multi-host mesh ran the same program with dummy sampling
-            # inputs and never fetch; the real token reaches them inside
-            # the next decode burst's broadcast state.
+            # whole group completes the TTFT path.
             if first_np is None:
                 with _device_phase("sched.fetch"):
                     first_np = np.asarray(first)
@@ -2608,10 +2335,8 @@ class InferenceEngine:
             self.lengths[req.slot] = len(req.prompt_ids)
             self.last_token[req.slot] = first_id
             # (Token history for prompt-lookup drafting is maintained per
-            # CHUNK above — identically on multihost followers, so every
-            # process's hist mirror stays bit-identical at all times; the
-            # first generated token is the input at P, written by the
-            # spec step that consumes it.)
+            # CHUNK above; the first generated token is the input at P,
+            # written by the spec step that consumes it.)
             self.active[req.slot] = True
             self.samp_temperature[req.slot] = req.temperature
             self.samp_top_p[req.slot] = req.top_p
@@ -2624,17 +2349,15 @@ class InferenceEngine:
 
     def _exec_prefill(self, slot, pos, chunk,
                       samp=None, key: jax.Array | None = None):
-        """The one compiled-prefill call — identical on coordinator and
-        followers (np/uncommitted inputs are auto-replicated, so the same
-        call works single-process and across a multi-host mesh; followers
-        pass no sampling state and ignore the sampled token — the cache
-        update is input-value-identical either way).
+        """The one compiled-prefill call. A caller that only fills the
+        cache (the benchmark's warm-up, a profile) passes no sampling
+        state and ignores the sampled token.
 
         ``slot``/``pos``/``chunk``/``samp`` are scalars-and-one-chunk for
         the K=1 path, or equal-length lists for BATCHED admission (the
         scheduler's grouper). The compile bucket is derived here, from
-        chunk lengths and engine config, so coordinator/followers/bench
-        can never disagree on it; batches share one bucket (the grouper
+        chunk lengths and engine config, so scheduler and bench can
+        never disagree on it; batches share one bucket (the grouper
         only batches same-bucket chunks). Clamped so pos+bucket never
         exceeds the cache extent S for ANY row: XLA clamps
         dynamic_update_slice starts, so an overrunning padded chunk
@@ -2651,13 +2374,6 @@ class InferenceEngine:
         bucket = min(_bucket(max(len(ch) for ch in chunks),
                              self.prefill_chunk),
                      self.S - max(poss))
-        if self.seq_n > 1:
-            # Ring attention shards the chunk's T dim over `seq`: round the
-            # bucket up to a multiple of the axis size (pads are causally
-            # invisible to real positions; their K/V lands beyond `lengths`
-            # in the documented undefined zone).
-            bucket = min(-(-bucket // self.seq_n) * self.seq_n,
-                         self.S - max(poss))
         padded = np.zeros((K, bucket), np.int32)
         for i, ch in enumerate(chunks):
             padded[i, :len(ch)] = ch
@@ -2704,169 +2420,21 @@ class InferenceEngine:
             base["ppb"] = self.kv_ppb
         return base
 
-    def _exec_decode(self, n_steps: int, state: dict) -> list[np.ndarray]:
-        """Run a burst from broadcast-packed host state (multihost path) —
-        identical on coordinator and followers."""
-        samp = SamplingParams(temperature=state["temperature"],
-                              top_p=state["top_p"], top_k=state["top_k"],
-                              presence_penalty=state["presence"],
-                              frequency_penalty=state["frequency"])
-        tokens = state["last_token"]
-        lengths = state["lengths"]
-        active = state["active"]
-        key = jax.random.wrap_key_data(
-            jnp.asarray(state["key"], jnp.uint32))
-        table = (self._device_table(),) if self.paged else ()
-        # Greedy fast path: computed from the broadcast state, so every
-        # process of a multi-host mesh picks the same program.
-        act = np.asarray(state["active"])
-        greedy = not bool(
-            np.any(np.asarray(state["temperature"])[act] > 0)
-            or np.any(np.asarray(state["presence"])[act] != 0)
-            or np.any(np.asarray(state["frequency"])[act] != 0))
-        step_fn, scans = self._decode_fns[greedy]
-        scan_fn = scans.get(n_steps)
-        if scan_fn is not None:
-            with _device_phase("decode"):
-                toks, _, _, self._d_counts, self.cache = scan_fn(
-                    self.params, self.cache, self._d_counts, *table, tokens,
-                    lengths, active, samp, key)
-            with _device_phase("sched.fetch"):
-                host = np.asarray(toks)
-            return [host[i] for i in range(n_steps)]
-        # Feedback stays as device arrays across the chain (outputs are
-        # pinned replicated, so the final fetches are process-local); only
-        # the sampled tokens are pulled to host, asynchronously behind the
-        # dispatch wave — same policy as the single-process path.
-        pending = []
-        with _device_phase("decode"):
-            for _ in range(n_steps):
-                key, sub = jax.random.split(key)
-                tokens, lengths, self._d_counts, self.cache = step_fn(
-                    self.params, self.cache, self._d_counts, *table, tokens,
-                    lengths, active, samp, sub)
-                _start_host_copy(tokens)
-                pending.append(tokens)
-        with _device_phase("sched.fetch"):
-            return [np.asarray(t) for t in pending]
-
-    def _table_to_publish(self) -> np.ndarray | None:
-        """Coordinator side: the page table, but only when it changed since
-        the last publish (admission/release mutate it between compiled
-        calls; followers apply it before executing the op)."""
-        if not (self.paged and self._bridge.enabled):
-            return None
-        if (self._published_table is not None
-                and np.array_equal(self.allocator.table,
-                                   self._published_table)):
-            return None
-        self._published_table = self.allocator.table.copy()
-        return self._published_table
-
-    def _apply_table(self, table: np.ndarray | None) -> None:
-        """Follower side: adopt the broadcast page table as local truth."""
-        if table is not None:
-            self.allocator.table[:, :] = table
-            self._table_dirty = True
-
     def _spec_hist_chunk(self, slot: int, pos: int,
                          chunk: np.ndarray) -> None:
         """Per-chunk token-history maintenance for prompt-lookup drafting
-        — the ONE copy, run identically on the coordinator (from the
-        scheduler) and on followers (from the replay loop), so every
-        process's hist mirror is bit-identical at every moment (a spec
-        upload may happen while another slot is mid-prefill)."""
+        (a spec upload may happen while another slot is mid-prefill)."""
         if not self.spec_k:
             return
         if pos == 0:
             self.hist[slot, :] = 0
-            # Per-slot adaptive-drafting state resets HERE (not only at
-            # coordinator admission): the suspension mirror now feeds
-            # DEVICE data (the draft_ok mask), so it must evolve
-            # bit-identically on every multihost process — and followers
-            # only observe an admission through its first prefill chunk.
-            # (Warm admissions skip pos==0, but the prefix cache is
-            # single-host-only and the coordinator also resets at
-            # admission.)
-            self._spec_ema[slot] = np.nan
-            self._spec_suspended[slot] = False
-            self._spec_slot_proposed[slot] = 0
-            self._spec_slot_accepted[slot] = 0
         self.hist[slot, pos:pos + len(chunk)] = chunk
-
-    def _follow_prefill(self, slot: int, pos: int, chunk: np.ndarray,
-                        table: np.ndarray | None = None) -> None:
-        self._apply_table(table)
-        self._spec_hist_chunk(slot, pos, chunk)
-        _, self.cache = self._exec_prefill(slot, pos, chunk)
-
-    def _follow_decode(self, n_steps: int, state: dict,
-                       table: np.ndarray | None = None) -> None:
-        self._apply_table(table)
-        self.lengths[:] = state["lengths"]
-        self.active[:] = state["active"]
-        self.last_token[:] = state["last_token"]
-        step_tokens = self._exec_decode(n_steps, state)
-        # Same mirror advance as the coordinator (incl. the spec hist) so
-        # a later spec reupload sees bit-identical host state.
-        self._advance_after_decode(n_steps, step_tokens)
-
-    def _advance_after_decode(self, n_steps: int,
-                              step_tokens: list[np.ndarray]) -> None:
-        """Shared multihost post-decode mirror advance: lengths,
-        last_token, and — on speculative engines — the prompt-lookup
-        history (otherwise a mixed-mode engine's hist would silently go
-        stale and a later spec reupload would diverge from the device
-        chain)."""
-        for slot in np.nonzero(self.active)[0]:
-            if self.spec_k:
-                L = int(self.lengths[slot])
-                if L < self.S:
-                    self.hist[slot, L] = int(self.last_token[slot])
-                m = min(n_steps, self.S - (L + 1))
-                for t in range(m):
-                    self.hist[slot, L + 1 + t] = int(step_tokens[t][slot])
-            self.last_token[slot] = int(step_tokens[-1][slot])
-        self.lengths[self.active] += n_steps
-        if self.spec_k:
-            self._d_hist_fresh = False
-
-    def _follow_spec(self, n_steps: int, flags: int, state: dict,
-                     table: np.ndarray | None = None) -> None:
-        """Replay one speculative burst: sync host mirrors from the
-        command state, execute the identical program (rebuilding device
-        mirrors from the local hist on a reupload), and walk the fetched
-        emitted matrix so lengths/last_token/hist advance exactly as on
-        the coordinator. ``flags`` packs bit 0 = reupload, bit 1 = probe
-        (per-slot suspension lifted for this burst); the drafting mask
-        itself is derived locally — the suspension mirror evolves only
-        inside _spec_walk, identically on every process."""
-        reupload = bool(flags & 1)
-        probe = bool(flags >> 1 & 1)
-        self._apply_table(table)
-        self.lengths[:] = state["lengths"]
-        self.active[:] = state["active"]
-        self.last_token[:] = state["last_token"]
-        d_ok = self._spec_draft_ok(probe)
-        host = self._exec_spec(n_steps, state if reupload else None,
-                               draft_ok=d_ok)
-        self._spec_walk(host, self.active.copy(), self.active.copy(),
-                        drafting=d_ok)
-
-    def run_follower(self) -> None:
-        """Blocking replay loop for follower processes (process_index > 0)
-        of a multi-host deployment: execute every compiled call the
-        coordinator publishes, until shutdown."""
-        self._bridge.follow(self._follow_prefill, self._follow_decode,
-                            self._follow_spec if self.spec_k else None)
 
     def _spec_draft_ok(self, probe: bool) -> np.ndarray:
         """The per-slot drafting mask for one spec burst: every slot
         drafts unless per-slot suspension is on (spec_acceptance_floor)
         and the slot is suspended; a PROBE burst re-enables every slot
-        for one re-measure. Identical on every multihost process: the
-        suspension mirror only changes inside _spec_walk (shared), and
-        the probe bit rides the OP_SPEC command."""
+        for one re-measure."""
         if self.spec_floor <= 0 or probe:
             return np.ones((self.B,), bool)
         return ~self._spec_suspended
@@ -2888,36 +2456,6 @@ class InferenceEngine:
         handles raggedness)."""
         if self.fault_plan:
             self.fault_plan.on_decode()
-        if self._bridge.enabled:
-            # Multihost: synchronous per burst (like the decode path) —
-            # publish the command, run the identical program on every
-            # process, and walk the fetched emitted matrix so all hosts'
-            # mirrors stay bit-identical. The hist never rides the wire:
-            # every process maintains its own mirror (see
-            # _spec_hist_chunk / _spec_walk); a reupload rebuilds the
-            # device hist from it on both sides. The per-slot drafting
-            # mask is derived from the suspension mirror (identical on
-            # every process — it evolves only through _spec_walk); only
-            # the PROBE bit rides the wire, because the probe cadence
-            # lives in the coordinator's scheduler.
-            reupload = self._d_dirty or not self._d_hist_fresh
-            self._rng, key = jax.random.split(self._rng)
-            packed = self._bridge.pack_decode_state(
-                self.lengths, self.active, self.last_token,
-                self.samp_top_k, self.samp_temperature, self.samp_top_p,
-                self.samp_presence, self.samp_frequency,
-                np.asarray(jax.random.key_data(key)))
-            self._bridge.publish_spec(n_steps, reupload, packed,
-                                      table=self._table_to_publish(),
-                                      probe=probe)
-            state = self._bridge.unpack_decode_state(packed)
-            d_ok = self._spec_draft_ok(probe)
-            host = self._exec_spec(n_steps, state if reupload else None,
-                                   draft_ok=d_ok)
-            self._d_dirty = False
-            self._d_hist_fresh = True
-            return self._spec_walk(host, self.active.copy(),
-                                   self.active.copy(), drafting=d_ok)
         # A mixed-mode engine may have a normal burst in flight (the batch
         # just turned all-greedy): land it first so mirrors are exact.
         pre = self._flush_pending()
@@ -2925,7 +2463,8 @@ class InferenceEngine:
             # Upload needs exact host mirrors — land any in-flight spec
             # burst before reading them.
             pre += self._flush_spec_pending()
-            self._spec_upload()
+            self._upload_slot_state()
+            self._d_hist = self._upload(self.hist)
             self._d_dirty = False
             self._d_hist_fresh = True
 
@@ -2995,68 +2534,26 @@ class InferenceEngine:
         return pre + self._spec_walk(host, self.active, self.active.copy(),
                                      drafting=d_ok)
 
-    def _spec_upload(self, state: dict | None = None) -> None:
-        """Rebuild EVERY device mirror for the speculative chain — the ONE
-        copy for the single-process path (from the engine's own host
-        mirrors) and the multihost path (from the broadcast slot state;
-        the hist always comes from the LOCAL bit-identical mirror).
-        Includes the sampler mirrors: a later spec→normal mode switch
-        (e.g. the cache-end fallback) must not hand _decode_burst a
-        never-built _d_samp — a None there retraces the decode program
-        with a different pytree structure (full XLA compile
-        mid-serving)."""
-        s = state or {}
-
-        def up(key, mirror, dtype):
-            return self._upload(np.asarray(s.get(key, mirror), dtype))
-        self._d_tokens = up("last_token", self.last_token, np.int32)
-        self._d_lengths = up("lengths", self.lengths, np.int32)
-        self._d_active = up("active", self.active, bool)
-        self._d_hist = self._upload(self.hist)
+    def _upload_slot_state(self) -> None:
+        """Rebuild the device mirrors of the per-slot host state, each
+        pinned to the SAME replicated sharding the compiled programs
+        produce — a plain jnp.asarray upload carries SingleDeviceSharding
+        while the program outputs fed back next burst carry
+        NamedSharding(mesh, P()), and that aval mismatch silently
+        recompiled the whole burst program on the first post-upload call.
+        The speculative chain uploads the sampler mirrors too: a later
+        spec→normal mode switch (e.g. the cache-end fallback) must not
+        hand _decode_burst a never-built _d_samp — a None there retraces
+        the decode program with a different pytree structure."""
+        self._d_tokens = self._upload(self.last_token)
+        self._d_lengths = self._upload(self.lengths)
+        self._d_active = self._upload(self.active)
         self._d_samp = SamplingParams(
-            temperature=up("temperature", self.samp_temperature, np.float32),
-            top_p=up("top_p", self.samp_top_p, np.float32),
-            top_k=up("top_k", self.samp_top_k, np.int32),
-            presence_penalty=up("presence", self.samp_presence, np.float32),
-            frequency_penalty=up("frequency", self.samp_frequency,
-                                 np.float32))
-
-    def _exec_spec(self, n_steps: int, state: dict | None,
-                   draft_ok: np.ndarray | None = None) -> np.ndarray:
-        """The one compiled-speculative-burst call — identical on
-        coordinator and followers. ``state`` non-None = reupload: rebuild
-        every device mirror (incl. the hist, from the LOCAL bit-identical
-        host mirror) from the broadcast slot state; None = chain the
-        device arrays from the previous burst. ``draft_ok`` is the
-        per-slot drafting mask (None = every slot drafts). Returns the
-        fetched emitted matrix [n_steps, B, k+1] (synchronous — multihost
-        has no lag-one)."""
-        if state is not None:
-            self._spec_upload(state)
-        if draft_ok is None:
-            draft_ok = np.ones((self.B,), bool)
-        d_ok_dev = self._upload(draft_ok)
-        table = (self._device_table(),) if self.paged else ()
-        if n_steps == self._spec_scan_len:
-            with _device_phase("spec.verify"):
-                emitted, self.cache, self._d_hist, self._d_tokens, \
-                    self._d_lengths = self._spec_scan(
-                        self.params, self.cache, *table, self._d_hist,
-                        self._d_tokens, self._d_lengths, self._d_active,
-                        d_ok_dev)
-            with _device_phase("sched.fetch"):
-                return np.asarray(emitted)
-        outs = []
-        with _device_phase("spec.verify"):
-            for _ in range(n_steps):
-                self._d_tokens, self._d_lengths, self.cache, self._d_hist, \
-                    em, _ = self._spec_step(
-                        self.params, self.cache, *table, self._d_hist,
-                        self._d_tokens, self._d_lengths, self._d_active,
-                        d_ok_dev)
-                outs.append(em)
-        with _device_phase("sched.fetch"):
-            return np.stack([np.asarray(e) for e in outs])
+            temperature=self._upload(self.samp_temperature),
+            top_p=self._upload(self.samp_top_p),
+            top_k=self._upload(self.samp_top_k),
+            presence_penalty=self._upload(self.samp_presence),
+            frequency_penalty=self._upload(self.samp_frequency))
 
     def _spec_wall_loses(self) -> bool:
         """True when the measured spec wall-clock (ms per emitted token,
@@ -3176,8 +2673,7 @@ class InferenceEngine:
         drafts were masked to -1), so its rows carry NO acceptance signal
         — the EMA is frozen and proposal counters skip it. The suspension
         mirror itself is re-derived here (ratio = (ema-1)/k against
-        spec_acceptance_floor), which keeps it bit-identical across
-        multihost processes: every process runs the same walk."""
+        spec_acceptance_floor)."""
         kp1 = self.spec_k + 1
         if drafting is None:
             drafting = np.ones((self.B,), bool)
@@ -3397,24 +2893,6 @@ class InferenceEngine:
         worth when a flush was forced)."""
         if self.fault_plan:
             self.fault_plan.on_decode()
-        if self._bridge.enabled:
-            # Multihost: broadcast the full slot state + rng key every
-            # burst (a few [B] vectors — negligible next to the decode
-            # itself) so coordinator and followers build bit-identical
-            # program inputs; then run the same _exec_decode both sides.
-            self._rng, key = jax.random.split(self._rng)
-            packed = self._bridge.pack_decode_state(
-                self.lengths, self.active, self.last_token, self.samp_top_k,
-                self.samp_temperature, self.samp_top_p, self.samp_presence,
-                self.samp_frequency,
-                np.asarray(jax.random.key_data(key)))
-            self._bridge.publish_decode(n_steps, packed,
-                                        table=self._table_to_publish())
-            step_tokens = self._exec_decode(
-                n_steps, self._bridge.unpack_decode_state(packed))
-            self._advance_after_decode(n_steps, step_tokens)
-            return step_tokens
-
         pre: list[np.ndarray] = []
         if self.spec_k:
             # Mode switch (a sampled request joined): land any in-flight
@@ -3426,22 +2904,7 @@ class InferenceEngine:
             # in-flight burst must land first: the upload below reads the
             # host `last_token` mirror, which that burst's tokens update.
             pre += self._flush_pending()
-            # Upload once, pinned to the SAME replicated sharding the
-            # compiled programs produce — a plain jnp.asarray upload
-            # carries SingleDeviceSharding while the program outputs fed
-            # back next burst carry NamedSharding(mesh, P()), and that
-            # aval mismatch silently recompiled the whole burst program on
-            # the first post-upload call (the r2 bench's "64.5 ms/step"
-            # was mostly this one recompile).
-            self._d_tokens = self._upload(self.last_token)
-            self._d_lengths = self._upload(self.lengths)
-            self._d_active = self._upload(self.active)
-            self._d_samp = SamplingParams(
-                temperature=self._upload(self.samp_temperature),
-                top_p=self._upload(self.samp_top_p),
-                top_k=self._upload(self.samp_top_k),
-                presence_penalty=self._upload(self.samp_presence),
-                frequency_penalty=self._upload(self.samp_frequency))
+            self._upload_slot_state()
             self._d_dirty = False
 
         table = (self._device_table(),) if self.paged else ()
@@ -3879,9 +3342,7 @@ class InferenceEngine:
         if self.paged:
             out["free_pages"] = self.allocator.free_pages
             out["total_pages"] = (self.allocator.num_pages
-                                  - (self.allocator.pages_per_block
-                                     if self.allocator.pages_per_block > 1
-                                     else self.allocator.n_bands))
+                                  - self.allocator.pages_per_block)
             out["page_size"] = self.allocator.page_size
             if self.kv_ppb > 1:
                 out["pages_per_block"] = self.kv_ppb
@@ -4031,27 +3492,6 @@ class InferenceEngine:
         return out
 
 
-def _pipelined_family_forward(mesh, n_stages: int, make_attention=None):
-    """family-forward adapter running the GPipe schedule
-    (parallel/pipeline.py) — same signature contract as llama.forward, so
-    the engine's prefill/decode step bodies don't change. Microbatch count
-    adapts to the call's batch: `n_stages` when divisible (the schedule's
-    sweet spot), else 1 — the ONE copy of that policy for both the dense
-    and the paged pipelines. ``make_attention`` + the ``table`` kwarg
-    switch the schedule to paged mode (parallel/pipeline.py)."""
-    from ..parallel.pipeline import pipelined_forward
-
-    def fwd(params, c, tokens, lengths, cache, active=None,
-            attention_fn=None, mlp_fn=None, table=None):
-        B = tokens.shape[0]
-        M = n_stages if B % n_stages == 0 else 1
-        return pipelined_forward(params, c, tokens, lengths, cache, mesh,
-                                 M, active=active,
-                                 make_attention=make_attention, table=table)
-
-    return fwd
-
-
 def _spec_verify_attention_fn(base, window: int = 0):
     """Attention provider for the speculative verify forward: the engine's
     configured attention (``base``; None = family default), extended with
@@ -4075,56 +3515,12 @@ def _spec_verify_attention_fn(base, window: int = 0):
     return attn
 
 
-def _seq_paged_prefill_attention_fn(mesh, kind, base):
-    """Whole-prompt prefill for the PAGED seq engine: same ring/ulysses
-    collective attention as the dense twin below (prefill starts at
-    position 0, so the chunk is the full visible context — no cache
-    read), but writes land through the seq-paged provider's shard_map'd
-    banded scatter (``base.insert``)."""
-    from ..parallel.ring_attention import ring_attention
-    from ..parallel.ulysses import ulysses_attention
-
-    op = ring_attention if kind == "ring" else ulysses_attention
-
-    def attention_fn(q, k_new, v_new, layer_k, layer_v, lengths, active=None):
-        B, T, H, Dh = q.shape
-        attn = op(q, k_new, v_new, mesh, axis="seq", causal=True)
-        layer_k, layer_v = base.insert(layer_k, layer_v, k_new, v_new,
-                                       lengths, active)
-        return attn.reshape(B, T, H * Dh), layer_k, layer_v
-
-    return attention_fn
-
-
-def _seq_prefill_attention_fn(mesh, kind: str = "ring"):
-    """Whole-prompt prefill attention for a seq-sharded engine: causal
-    attention over the chunk itself (prefill always starts at position 0 in
-    seq mode, so the chunk IS the full visible context — no prior cache to
-    attend), plus the standard local KV insert into the S-sharded cache.
-    ``kind`` picks the collective pattern: "ring" (n-1 ppermute hops, any
-    head count) or "ulysses" (2 all-to-alls, needs heads % seq == 0)."""
-    from ..parallel.ring_attention import ring_attention
-    from ..parallel.ulysses import ulysses_attention
-
-    op = ring_attention if kind == "ring" else ulysses_attention
-
-    def attention_fn(q, k_new, v_new, layer_k, layer_v, lengths, active=None):
-        B, T, H, Dh = q.shape
-        attn = op(q, k_new, v_new, mesh, axis="seq", causal=True)
-        layer_k, layer_v = llama.insert_kv(layer_k, layer_v, k_new, v_new,
-                                           lengths, active)
-        return attn.reshape(B, T, H * Dh), layer_k, layer_v
-
-    return attention_fn
-
-
 def _prefill_counts(counts, tokens, start_len, slots, last_idx):
     """Penalty-count maintenance for a prefill chunk group: reset each
     slot's row at prompt start (start_len == 0), add the chunk's REAL
     tokens (bucket pads masked via last_idx), and return (updated
     counts [B, V], the K updated rows [K, V] — the penalty source for
-    this program's folded first-token sampling). Multihost-safe: every
-    input is broadcast state, so follower counts stay bit-identical."""
+    this program's folded first-token sampling)."""
     K, C = tokens.shape
     pos_ok = (jnp.arange(C)[None, :] <= last_idx[:, None]).astype(jnp.int32)
     rows = []
@@ -4194,7 +3590,7 @@ _dummy_key: jax.Array | None = None
 
 def _DUMMY_KEY() -> jax.Array:
     """A fixed typed PRNG key for calls whose sampled output is ignored
-    (multi-host followers, bench prefill) — cached so the input aval is
+    (the benchmark's warm-up, bench prefill) — cached so the input aval is
     identical across calls (no recompiles)."""
     global _dummy_key
     if _dummy_key is None:

@@ -29,37 +29,16 @@ import numpy as np
 
 
 class PageAllocator:
-    """``n_bands > 1`` = SEQUENCE-BANDED allocation (paged × seq
-    sharding): the pool's page dim is sharded over the ``seq`` mesh axis
-    into ``n_bands`` equal shards, and a slot's logical page ``j``
-    (covering positions ``[j·page, (j+1)·page)``) must be a PHYSICAL page
-    owned by the shard whose position band contains it — so every chip's
-    S-shard of the gathered dense view reads only LOCAL pages. The first
-    physical page of EVERY band is that chip's trash page (masked scatter
-    redirect must stay shard-local) and is never allocated."""
+    """The host page table, the free lists and the group refcounts."""
 
     def __init__(self, num_pages: int, page_size: int, batch: int,
-                 max_seq: int, n_bands: int = 1,
-                 pages_per_block: int = 1):
-        if num_pages < 2 * n_bands:
-            raise ValueError(f"need at least {2 * n_bands} pages "
-                             f"({n_bands} band trash pages reserved)")
-        if num_pages % n_bands:
-            raise ValueError(f"num_pages {num_pages} not divisible by "
-                             f"{n_bands} bands")
-        if n_bands > 1 and max_seq % (n_bands * page_size):
-            # Single-band pools keep the legacy ceil-division tolerance
-            # for non-page-aligned max_seq; banding needs exact alignment.
-            raise ValueError(
-                f"max_seq {max_seq} must be a multiple of n_bands × "
-                f"page_size = {n_bands * page_size} (band boundaries must "
-                f"fall on page boundaries)")
+                 max_seq: int, pages_per_block: int = 1):
+        if num_pages < 2:
+            raise ValueError("need at least 2 pages (the trash page is "
+                             "reserved)")
         self.page_size = page_size
         self.num_pages = num_pages
-        self.n_bands = n_bands
-        self.band_pages = num_pages // n_bands      # physical pages per band
         self.pages_per_slot = (max_seq + page_size - 1) // page_size
-        self.slot_band_pages = self.pages_per_slot // n_bands
         # SUPERPAGE PACKING (pages_per_block > 1): allocation happens in
         # aligned runs of `pages_per_block` contiguous physical pages, and
         # every aligned group of logical pages maps onto one such run —
@@ -71,9 +50,6 @@ class PageAllocator:
         # per slot (pages_needed rounds up to whole runs).
         self.pages_per_block = max(1, pages_per_block)
         if self.pages_per_block > 1:
-            if n_bands > 1:
-                raise ValueError("superpage packing is single-band only "
-                                 "(paged × seq keeps per-page blocks)")
             if num_pages % self.pages_per_block:
                 raise ValueError(
                     f"num_pages {num_pages} not divisible by "
@@ -83,22 +59,19 @@ class PageAllocator:
                     f"pages_per_slot {self.pages_per_slot} not divisible "
                     f"by pages_per_block {self.pages_per_block} (table "
                     f"rows must split into whole runs)")
-        # Per-band free lists, excluding each band's trash page (its first
-        # physical id). LIFO: recently-freed pages are likely still warm.
-        # Packed pools instead keep a LIFO of free SUPERPAGE ids (group 0,
-        # the trash group, excluded).
+        # The free list, excluding the trash page (physical id 0). LIFO:
+        # recently-freed pages are likely still warm. Packed pools instead
+        # keep a LIFO of free SUPERPAGE ids (group 0, the trash group,
+        # excluded).
         if self.pages_per_block > 1:
-            self._free = [[]]
+            self._free: list[int] = []
             self._free_sp: list[int] = list(
                 range(num_pages // self.pages_per_block - 1, 0, -1))
         else:
-            self._free = [
-                list(range((b + 1) * self.band_pages - 1,
-                           b * self.band_pages, -1))
-                for b in range(n_bands)]
+            self._free = list(range(num_pages - 1, 0, -1))
             self._free_sp = []
         # [B, NP] physical page per (slot, logical page); 0 = unallocated
-        # (0 is band 0's trash page, never a real mapping).
+        # (0 is the trash page, never a real mapping).
         self.table = np.zeros((batch, self.pages_per_slot), np.int32)
         self._held: dict[int, list[int]] = {}
         # Group refcounts (group id = page // group_pages): how many
@@ -116,10 +89,7 @@ class PageAllocator:
     def free_pages(self) -> int:
         if self.pages_per_block > 1:
             return len(self._free_sp) * self.pages_per_block
-        return sum(len(f) for f in self._free)
-
-    def _band_of(self, logical_page: int) -> int:
-        return logical_page // self.slot_band_pages
+        return len(self._free)
 
     def pages_needed(self, total_tokens: int, ring_pages: int = 0) -> int:
         need = (min(total_tokens, self.pages_per_slot * self.page_size)
@@ -141,18 +111,12 @@ class PageAllocator:
             return True
         if self.pages_per_block > 1:
             return fresh // self.pages_per_block <= len(self._free_sp)
-        if self.n_bands == 1:
-            return fresh <= len(self._free[0])
-        return all(
-            sum(1 for j in range(need) if self._band_of(j) == b)
-            <= len(self._free[b])
-            for b in range(self.n_bands))
+        return fresh <= len(self._free)
 
     def fresh_shortfall(self, total_tokens: int, ring_pages: int = 0,
                         shared_pages: int = 0) -> int:
         """How many pages short the free pool is of admitting this request
-        — what the engine asks the prefix cache to evict under pressure.
-        Single-band pools only (where sharing/eviction exist)."""
+        — what the engine asks the prefix cache to evict under pressure."""
         need = self.pages_needed(total_tokens, ring_pages) - shared_pages
         return max(0, need - self.free_pages)
 
@@ -165,7 +129,7 @@ class PageAllocator:
                  shared_pages: Iterable[int] = ()) -> bool:
         """Reserve a slot's pages for its lifetime. False if insufficient.
 
-        ``ring_pages`` (sliding-window models, single band only): hold at
+        ``ring_pages`` (sliding-window models): hold at
         most that many pages — the whole-lifetime guarantee still stands
         because :meth:`ensure_mapped` recycles the slot's own dead pages
         instead of allocating, so the holding never grows.
@@ -177,9 +141,6 @@ class PageAllocator:
         span's KV is served without allocation or prefill."""
         if slot in self._held:
             raise ValueError(f"slot {slot} already holds pages")
-        if ring_pages and self.n_bands > 1:
-            raise ValueError("ring reservation is single-band only "
-                             "(SWA × seq is rejected at engine build)")
         if ring_pages and self.pages_per_block > 1:
             # Ring rotation remaps one page at a time, which would break
             # the aligned-run invariant; the engine disables packing on
@@ -188,9 +149,9 @@ class PageAllocator:
                              "superpage packing")
         shared = list(shared_pages)
         if shared:
-            if ring_pages or self.n_bands > 1:
-                raise ValueError("prefix sharing is single-band, "
-                                 "non-ring only (engine gates the cache)")
+            if ring_pages:
+                raise ValueError("prefix sharing is non-ring only "
+                                 "(engine gates the cache)")
             if len(shared) % self.group_pages:
                 raise ValueError("shared prefix must be whole groups")
         need = self.pages_needed(total_tokens, ring_pages)
@@ -207,8 +168,7 @@ class PageAllocator:
             # sps[g]·ppb + i, aligned and contiguous per run.
             fresh = [sp * ppb + i for sp in sps for i in range(ppb)]
         else:
-            fresh = [self._free[self._band_of(j)].pop()
-                     for j in range(len(shared), need)]
+            fresh = [self._free.pop() for _ in range(fresh_n)]
         for g in self._groups_of(shared):
             if g not in self._ref:
                 raise ValueError(f"shared group {g} is not live")
@@ -250,7 +210,7 @@ class PageAllocator:
             if self.pages_per_block > 1:
                 self._free_sp.append(g)
             else:
-                self._free[g // self.band_pages].append(g)
+                self._free.append(g)
 
     def ensure_mapped(self, slot: int, last_logical: int,
                       dead_before: int) -> bool:
@@ -321,9 +281,9 @@ class PageAllocator:
     def check_invariants(self, pinned: Iterable[int] = ()) -> None:
         """Test hook: every non-trash group is either free or refcounted by
         exactly its holders (slots mapping it + the cache pin, passed as
-        the pinned page list); table rows agree with holdings; banded
-        pages stay in their position band; packed holdings are aligned
-        whole runs; no group is lost or double-freed."""
+        the pinned page list); table rows agree with holdings; packed
+        holdings are aligned whole runs; no group is lost or
+        double-freed."""
         held = [p for pages in self._held.values() for p in pages]
         if self.pages_per_block > 1:
             ppb = self.pages_per_block
@@ -340,8 +300,8 @@ class PageAllocator:
                     assert run == list(range(run[0], run[0] + ppb)), \
                         "non-contiguous superpage run"
         else:
-            free = [p for f in self._free for p in f]
-            trash = {b * self.band_pages for b in range(self.n_bands)}
+            free = list(self._free)
+            trash = {0}
         # Refcount truth: each live group's count equals its holders.
         expect: dict[int, int] = {}
         for pages in self._held.values():
@@ -355,9 +315,8 @@ class PageAllocator:
         assert not (free_groups & set(self._ref)), "group both free and live"
         assert not (trash & set(held + free)), "trash page leaked"
         n_groups = self.num_pages // self.group_pages
-        n_trash_groups = 1 if self.pages_per_block > 1 else self.n_bands
-        assert len(free_groups) + len(self._ref) == n_groups - \
-            n_trash_groups, "group lost"
+        assert len(free_groups) + len(self._ref) == n_groups - 1, \
+            "group lost"
         for slot, pages in self._held.items():
             row = self.table[slot]
             if slot in self._ring_slots:
@@ -368,6 +327,3 @@ class PageAllocator:
                 continue
             assert list(row[:len(pages)]) == pages, "table/holding mismatch"
             assert (row[len(pages):] == 0).all()
-            for j, p in enumerate(pages):
-                assert p // self.band_pages == self._band_of(j), \
-                    f"page {p} outside its position band"

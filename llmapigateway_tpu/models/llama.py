@@ -308,7 +308,7 @@ def dense_decode_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
     but the cache write is deferred so the layer scan never copies cache
     blocks through its ys (see :func:`insert_kv_stacked`). The two-piece
     softmax is computed explicitly (no [S+1] concat) so every S-reduction
-    stays a clean sharded reduction under GSPMD for seq-sharded caches.
+    stays a clean sharded reduction under GSPMD for sharded caches.
 
     q [B,1,H,Dh]; k_new/v_new [B,1,KV,Dh]; layer_k/v [B,KV,S,Dh] (stale;
     or the int8 ``{"q","s"}`` dict — scales fold into scores/probs).
@@ -577,9 +577,9 @@ def swiglu_mlp(x: jax.Array, wg: jax.Array, wu: jax.Array,
 def qkv_proj(h: jax.Array, lp: dict, config: ModelConfig
              ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Q/K/V projections with the optional qwen2-family bias, RoPE NOT yet
-    applied. THE one copy of this block — the sequential layer scan and the
-    pipeline-parallel staged block both call it (the bias was once added to
-    only one of the two, silently forking the model). ``"bq" in lp`` is
+    applied. THE one copy of this block (the bias was once added to only
+    one of two copies, silently forking the model): the layer scan calls
+    it, and so would any other block. ``"bq" in lp`` is
     static at trace time. h [B, T, D] → q [B,T,H,Dh], k/v [B,T,KV,Dh]."""
     c = config
     B, T = h.shape[0], h.shape[1]

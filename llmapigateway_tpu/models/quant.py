@@ -14,7 +14,7 @@ Scheme (standard dynamic W8A8, no calibration data needed):
   ``w [D, F]`` (contract over D) the scale is ``s [F] = max|w[:, f]|/127``
   stored fp32; a quantized weight is the sub-dict ``{"q": int8, "s": fp32}``
   in the params tree (a plain pytree — ``lax.scan`` over stacked layers,
-  GSPMD sharding, and multihost broadcast all see ordinary leaves).
+  and GSPMD sharding see ordinary leaves).
 * **Activations**: symmetric per-row dynamic int8, computed inside the
   compiled step (``max|x|`` over the contraction dim — XLA fuses this with
   the surrounding elementwise work). Row scales commute with the matmul, so

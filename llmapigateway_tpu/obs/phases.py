@@ -20,7 +20,7 @@ clock):
 
 The call reaches the worker through a context variable
 (``asyncio.to_thread`` copies the context), so a direct call of a worker
-function — a test, the bench, a multihost follower — finds none and pays
+function — a test, the bench — finds none and pays
 one ``ContextVar.get`` per span.
 """
 from __future__ import annotations

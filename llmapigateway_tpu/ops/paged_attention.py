@@ -583,7 +583,7 @@ def _check_pages_per_block(ppb: int, NP: int, P: int) -> None:
     caller's promise; the engine's superpage-packing allocator
     (engine/paged.py ``pages_per_block``) is the one producer that
     guarantees it, and the engine falls back to per-page blocks whenever
-    it can't (SWA ring, seq banding, non-divisible geometry)."""
+    it can't (SWA ring, non-divisible geometry)."""
     if ppb < 1:
         raise ValueError(f"pages_per_block must be >= 1, got {ppb}")
     if ppb > 1 and (NP % ppb or P % ppb):
@@ -1087,166 +1087,4 @@ def make_paged_attention_fn(page_table: jax.Array, max_seq: int,
         # reroute every prefill chunk (T > 1) through the deferred path;
         # the engine builds a dedicated instance for spec bursts.
         attention_fn.verify = verify
-    return attention_fn
-
-
-# ---------------------------------------------------------------------------
-# Sequence-sharded paged attention (paged × seq composition)
-# ---------------------------------------------------------------------------
-
-def _seq_local_table(page_table: jax.Array, seq_n: int,
-                     band_pages: int) -> jax.Array:
-    """Translate the replicated GLOBAL page table into THIS chip's local
-    ids (inside a shard_map over ``seq``). The banded allocator
-    (engine/paged.py) guarantees logical page ``j`` lives in band
-    ``j // (NP_slot/seq_n)``; entries outside this chip's band — and
-    unallocated zeros — map to local page 0, the chip's OWN trash page
-    (band base), so masked scatter redirects stay shard-local."""
-    c = jax.lax.axis_index("seq")
-    spb = page_table.shape[1] // seq_n            # logical pages per band
-    band = jnp.arange(page_table.shape[1], dtype=jnp.int32) // spb
-    local = page_table - c * band_pages
-    return jnp.where((band[None, :] == c) & (local > 0)
-                     & (local < band_pages), local, 0)
-
-
-def _leaf_specs(side):
-    """Per-leaf shard_map specs for a pool side (dict-aware: the rank-4
-    [P, KV, 1, page] scale plane has the SAME rank and page-dim position
-    as its value): the page dim — 0 for a per-layer side, 1 for a
-    stacked [L, ...] one — rides the ``seq`` axis."""
-    from jax.sharding import PartitionSpec as P
-    if isinstance(side, dict):
-        nd = side["q"].ndim
-    else:
-        nd = side.ndim
-    ax = 0 if nd == 4 else 1                      # per-layer vs stacked [L,…]
-    def spec(ndim):
-        parts = [None] * ndim
-        parts[ax] = "seq"
-        return P(*parts)
-    if isinstance(side, dict):
-        return {"q": spec(nd), "s": spec(nd)}
-    return spec(nd)
-
-
-def make_seq_paged_attention_fn(page_table: jax.Array, max_seq: int, mesh):
-    """attention_fn for a SEQ-SHARDED paged engine (llama.forward
-    contract + the deferred ``.decode``/``.insert_all`` protocol).
-
-    The pool's PAGE dim is sharded over the ``seq`` mesh axis and pages
-    are position-banded (engine/paged.py), so each chip's slice of the
-    dense view reads only LOCAL pages: a shard_map gather materializes
-    the per-layer dense [B, KV, S, Dh] view S-SHARDED over ``seq`` (no
-    collective — the out_spec just declares the sharding), and the
-    standard dense deferred attention partitions its S-reductions under
-    GSPMD exactly like the dense seq engine. Writes run a shard_map'd
-    paged scatter against the chip-local table translation (out-of-band
-    and masked writes land on the chip's own trash page).
-
-    jnp/GSPMD math only (v1): correctness-complete; the paged kernels
-    don't run under a seq sharding yet."""
-    from jax.sharding import PartitionSpec as P
-
-    from ..models.llama import dense_decode_attention
-
-    seq_n = mesh.shape["seq"]
-
-    def _gather_local(pool, tbl):
-        """One chip's dense S-shard from its local pool shard."""
-        lt = _seq_local_table(tbl, seq_n, _band_pages(pool))
-        c = jax.lax.axis_index("seq")
-        spb = tbl.shape[1] // seq_n
-        cols = jax.lax.dynamic_slice_in_dim(lt, c * spb, spb, 1)  # [B, spb]
-
-        def g(leaf):
-            picked = jnp.take(leaf, cols, axis=0)   # [B, spb, KV, page(,Dh)]
-            picked = jnp.moveaxis(picked, 1, 2)     # [B, KV, spb, page(,Dh)]
-            B = cols.shape[0]
-            KV, page = leaf.shape[1], leaf.shape[2]
-            return picked.reshape(B, KV, spb * page, *leaf.shape[3:])
-        if isinstance(pool, dict):
-            # Scale leaf [Pl, KV, 1, page]: gather through its squeezed
-            # rank-3 view, return the dense stored form [B, KV, 1, S].
-            return {"q": g(pool["q"]),
-                    "s": g(pool["s"][:, :, 0, :])[:, :, None, :]}
-        return g(pool)
-
-    def _band_pages(pool):
-        leaf = pool["q"] if isinstance(pool, dict) else pool
-        return leaf.shape[0]        # inside shard_map: the LOCAL shard size
-
-    def gather_view(pool_layer):
-        """[B, KV, S, Dh] dense view, sharded on S over ``seq``."""
-        def out_spec(side):
-            if isinstance(side, dict):
-                return {"q": P(None, None, "seq", None),
-                        "s": P(None, None, None, "seq")}
-            return P(None, None, "seq", None)
-        return shard_map(
-            _gather_local, mesh=mesh,
-            in_specs=(_leaf_specs(pool_layer), P()),
-            out_specs=out_spec(pool_layer),
-            axis_names={"seq"}, check_vma=False)(pool_layer, page_table)
-
-    def _insert_local(lk, lv, kn, vn, tbl, lengths, active):
-        lt = _seq_local_table(tbl, seq_n, _band_pages(lk))
-        return paged_insert_kv(lk, lv, kn, vn, lt, lengths, active)
-
-    def sharded_insert(layer_k, layer_v, k_new, v_new, lengths, active):
-        act = jnp.ones(lengths.shape, bool) if active is None else active
-        return shard_map(
-            _insert_local, mesh=mesh,
-            in_specs=(_leaf_specs(layer_k), _leaf_specs(layer_v),
-                      P(), P(), P(), P(), P()),
-            out_specs=(_leaf_specs(layer_k),
-                       _leaf_specs(layer_v)),
-            axis_names={"seq"}, check_vma=False)(
-            layer_k, layer_v, k_new, v_new, page_table, lengths, act)
-
-    def attention_fn(q, k_new, v_new, layer_k, layer_v, lengths,
-                     active=None):
-        """Chunk path (insert-then-attend over the gathered view; used by
-        the speculative verify — seq prefill rides ring attention via the
-        engine's prefill provider instead)."""
-        B, T, H, Dh = q.shape
-        layer_k, layer_v = sharded_insert(layer_k, layer_v, k_new, v_new,
-                                          lengths, active)
-        dk = gather_view(layer_k)
-        dv = gather_view(layer_v)
-
-        out = _paged_reference_core(q, dequant_gathered(dk, q.dtype),
-                                    dequant_gathered(dv, q.dtype),
-                                    lengths, active, T)
-        return out, layer_k, layer_v
-
-    def decode(q, k_new, v_new, layer_k, layer_v, lengths, active=None):
-        """Deferred decode: gather the stale dense view (local, no
-        collective), then the dict-aware dense decode attention — GSPMD
-        partitions its S-reductions over the ``seq`` sharding."""
-        dk = gather_view(layer_k)
-        dv = gather_view(layer_v)
-        n = lengths if active is None else jnp.where(active, lengths, 0)
-        return dense_decode_attention(q, k_new, v_new, dk, dv, n, None)
-
-    def _insert_all_local(pk, pv, kns, vns, tbl, lengths, active):
-        lt = _seq_local_table(tbl, seq_n,
-                              (pk["q"] if isinstance(pk, dict) else
-                               pk).shape[1])
-        return paged_insert_all(pk, pv, kns, vns, lt, lengths, active)
-
-    def insert_all(pool_k, pool_v, k_news, v_news, lengths, active):
-        act = jnp.ones(lengths.shape, bool) if active is None else active
-        return shard_map(
-            _insert_all_local, mesh=mesh,
-            in_specs=(_leaf_specs(pool_k), _leaf_specs(pool_v),
-                      P(), P(), P(), P(), P()),
-            out_specs=(_leaf_specs(pool_k),
-                       _leaf_specs(pool_v)),
-            axis_names={"seq"}, check_vma=False)(
-            pool_k, pool_v, k_news, v_news, page_table, lengths, act)
-
-    attention_fn.decode = decode
-    attention_fn.insert_all = insert_all
-    attention_fn.insert = sharded_insert    # ring-prefill write hook
     return attention_fn

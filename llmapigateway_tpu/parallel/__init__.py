@@ -1,14 +1,10 @@
 from .mesh import build_mesh, MeshSpec
-from .pipeline import pipelined_forward
-from .ring_attention import ring_attention
 from .sharding import (
     batch_sharding,
     cache_sharding,
     paged_cache_sharding,
     param_shardings,
 )
-from .ulysses import ulysses_attention
 
 __all__ = ["build_mesh", "MeshSpec", "param_shardings", "cache_sharding",
-           "paged_cache_sharding", "batch_sharding", "pipelined_forward",
-           "ring_attention", "ulysses_attention"]
+           "paged_cache_sharding", "batch_sharding"]

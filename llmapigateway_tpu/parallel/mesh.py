@@ -1,36 +1,29 @@
 """Device mesh construction.
 
-The communication backend of this framework is XLA collectives over ICI
-(intra-slice) and DCN (inter-host) — the TPU-native equivalent of the
-NCCL/MPI tier a GPU framework would carry (SURVEY.md §2b, §5 "Distributed
-communication backend"). A :class:`jax.sharding.Mesh` with named axes is the
-single abstraction everything shards over:
+The communication backend of this framework is XLA collectives over ICI —
+the TPU-native equivalent of the NCCL/MPI tier a GPU framework would carry
+(SURVEY.md §2b, §5 "Distributed communication backend"). A
+:class:`jax.sharding.Mesh` with named axes is the single abstraction
+everything shards over:
 
   axes: ``data`` (DP, batch dim) · ``model`` (TP, weight columns/rows)
-        · ``expert`` (EP, MoE experts) · ``seq`` (SP, ring attention)
+        · ``expert`` (EP, MoE experts)
 
-Multi-host: call :func:`init_distributed` first (wraps
-``jax.distributed.initialize``); mesh axes spanning hosts ride DCN, axes
-within a slice ride ICI. Keep ``model``/``seq`` inside a slice, put
-``data`` across slices — collectives then match link bandwidth.
+One process drives every device of the mesh (the engine refuses to build
+in a process that is one of several).
 """
 from __future__ import annotations
 
 import logging
-import os
 from dataclasses import dataclass, field
 
 import jax
 import numpy as np
 from jax.sharding import Mesh
 
-logger = logging.getLogger(__name__)
+from ..config.schemas import MESH_AXES, check_mesh_axes
 
-AXIS_ORDER = ("pipe", "data", "expert", "seq", "model")   # slowest → fastest
-# `pipe` (PP stages) is outermost: stage-to-stage traffic is one activation
-# hand-off per microbatch tick — the least-frequent collective — so it is
-# the axis to lay across hosts/DCN; `model` stays innermost on adjacent ICI
-# neighbors.
+logger = logging.getLogger(__name__)
 
 
 @dataclass
@@ -42,7 +35,8 @@ class MeshSpec:
     auto_model: bool = True
 
     def resolve(self, n_devices: int) -> dict[str, int]:
-        sizes = {ax: int(self.sizes.get(ax, 1)) for ax in AXIS_ORDER}
+        check_mesh_axes(self.sizes)
+        sizes = {ax: int(self.sizes.get(ax, 1)) for ax in MESH_AXES}
         known = 1
         for ax, s in sizes.items():
             if s <= 0:
@@ -66,7 +60,7 @@ def build_mesh(spec: MeshSpec | dict[str, int] | None = None,
     """Build a mesh over the given (default: all) devices.
 
     Device order: JAX returns devices in row-major ICI order; reshaping to
-    (data, expert, seq, model) keeps the fastest-varying axis (`model` — the
+    (data, expert, model) keeps the fastest-varying axis (`model` — the
     axis with the most collective traffic) on adjacent ICI neighbors.
     """
     if isinstance(spec, dict):
@@ -74,19 +68,11 @@ def build_mesh(spec: MeshSpec | dict[str, int] | None = None,
     spec = spec or MeshSpec()
     devices = devices if devices is not None else jax.devices()
     sizes = spec.resolve(len(devices))
-    shape = tuple(sizes[ax] for ax in AXIS_ORDER)
+    shape = tuple(sizes[ax] for ax in MESH_AXES)
     arr = np.array(devices).reshape(shape)
-    mesh = Mesh(arr, AXIS_ORDER)
+    mesh = Mesh(arr, MESH_AXES)
     logger.info("mesh: %s over %d %s devices",
                 {ax: s for ax, s in sizes.items() if s > 1} or {"single": 1},
                 len(devices), devices[0].platform)
     return mesh
 
-
-def init_distributed() -> None:
-    """Initialize multi-host JAX (DCN) when launched under a multi-host
-    runtime. Safe no-op for single-process runs."""
-    if os.environ.get("JAX_COORDINATOR_ADDRESS"):
-        jax.distributed.initialize()
-        logger.info("jax.distributed initialized: process %d/%d",
-                    jax.process_index(), jax.process_count())

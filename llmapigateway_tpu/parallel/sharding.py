@@ -55,43 +55,39 @@ def _spec_for(path: str, shape: tuple[int, ...], mesh: Mesh) -> P:
         if base == "lm_head":                       # [V]
             return P(_axis(mesh, "model", shape[0]))
         key = base.split(".", 1)[1] if base.startswith("layers.") else base
-        lp = _axis(mesh, "pipe", shape[0])
         if key in ("wq", "wk", "wv", "wg", "wu"):   # column-parallel [L, out]
             if len(shape) == 3:                     # MoE expert [L, E, F]
-                return P(lp, _axis(mesh, "expert", shape[1]),
+                return P(None, _axis(mesh, "expert", shape[1]),
                          _axis(mesh, "model", shape[2]))
-            return P(lp, _axis(mesh, "model", shape[1]))
+            return P(None, _axis(mesh, "model", shape[1]))
         if key in ("wo", "wd"):                     # row-parallel: out replicated
             if len(shape) == 3:                     # MoE expert [L, E, D]
-                return P(lp, _axis(mesh, "expert", shape[1]), None)
-            return P(lp, None)
+                return P(None, _axis(mesh, "expert", shape[1]), None)
+            return P(None, None)
         return P()
     if path == "embed" or path == "lm_head":
         return P(_axis(mesh, "model", shape[0]), None)
     if path in ("final_norm",):
         return P(None)
     if path.startswith("layers."):
-        key = path.split(".", 1)[1]
-        # Stacked layer dim (dim 0) shards over `pipe` when PP is on: each
-        # stage holds a contiguous block of layers (parallel/pipeline.py).
-        lp = _axis(mesh, "pipe", shape[0])
+        key = path.split(".", 1)[1]     # dim 0 is the stacked layer dim
         if key in ("attn_norm", "mlp_norm"):
-            return P(lp, None)
+            return P(None, None)
         if key == "router":                       # [L, D, E]
-            return P(lp, None, None)
+            return P(None, None, None)
         if key in ("bq", "bk", "bv"):             # [L, out] column bias
-            return P(lp, _axis(mesh, "model", shape[1]))
+            return P(None, _axis(mesh, "model", shape[1]))
         n = len(shape)
         if key in ("wq", "wk", "wv", "wg", "wu"):
             if n == 4:                            # MoE expert: [L, E, D, F]
-                return P(lp, _axis(mesh, "expert", shape[1]), None,
+                return P(None, _axis(mesh, "expert", shape[1]), None,
                          _axis(mesh, "model", shape[3]))
-            return P(lp, None, _axis(mesh, "model", shape[2]))
+            return P(None, None, _axis(mesh, "model", shape[2]))
         if key in ("wo", "wd"):
             if n == 4:                            # [L, E, F, D]
-                return P(lp, _axis(mesh, "expert", shape[1]),
+                return P(None, _axis(mesh, "expert", shape[1]),
                          _axis(mesh, "model", shape[2]), None)
-            return P(lp, _axis(mesh, "model", shape[1]), None)
+            return P(None, _axis(mesh, "model", shape[1]), None)
     logger.debug("no sharding rule for %s %s; replicating", path, shape)
     return P()
 
@@ -128,35 +124,20 @@ def spec_for_param(path: str, shape: tuple[int, ...], mesh: Mesh) -> NamedShardi
     return NamedSharding(mesh, _spec_for(path, shape, mesh))
 
 
-def cache_sharding(mesh: Mesh, n_kv_heads: int, batch: int,
-                   max_seq: int | None = None,
-                   n_layers: int | None = None) -> NamedSharding:
+def cache_sharding(mesh: Mesh, n_kv_heads: int, batch: int
+                   ) -> NamedSharding:
     """KV cache [L, B, KV, S, Dh] (head-major): batch on data, KV heads on
-    model; in a sequence-parallel engine S shards on ``seq`` (ring prefill
-    writes each shard locally, decode reductions are GSPMD-partitioned);
-    in a pipelined engine L shards on ``pipe`` so each stage holds only its
-    own layers' cache (matching parallel/pipeline.py's stage specs)."""
+    model."""
     return NamedSharding(mesh, P(
-        _axis(mesh, "pipe", n_layers) if n_layers else None,
-        _axis(mesh, "data", batch),
-        _axis(mesh, "model", n_kv_heads),
-        _axis(mesh, "seq", max_seq) if max_seq else None, None))
-
-
-def paged_cache_sharding(mesh: Mesh, n_kv_heads: int,
-                         n_layers: int | None = None,
-                         num_pages: int | None = None) -> NamedSharding:
-    """Paged pool [L, P, KV, page, Dh]: KV heads on model. The page dim is
-    a global pool indexed by the (replicated) page table — unsharded,
-    EXCEPT in a seq-sharded engine, where it rides ``seq`` with
-    position-banded allocation (engine/paged.py: every chip's S-shard
-    reads only local pages). In a pipelined engine the layer dim stages
-    over ``pipe`` (each stage holds its own layers' pages), mirroring the
-    dense cache_sharding."""
-    return NamedSharding(mesh, P(
-        _axis(mesh, "pipe", n_layers) if n_layers else None,
-        _axis(mesh, "seq", num_pages) if num_pages else None,
+        None, _axis(mesh, "data", batch),
         _axis(mesh, "model", n_kv_heads), None, None))
+
+
+def paged_cache_sharding(mesh: Mesh, n_kv_heads: int) -> NamedSharding:
+    """Paged pool [L, P, KV, page, Dh]: KV heads on model. The page dim is
+    a global pool indexed by the (replicated) page table — unsharded."""
+    return NamedSharding(mesh, P(
+        None, None, _axis(mesh, "model", n_kv_heads), None, None))
 
 
 def batch_sharding(mesh: Mesh, batch: int) -> NamedSharding:

@@ -111,24 +111,6 @@ class ProviderRegistry:
                 if cached and cached[0] == fingerprint:
                     return cached[1]
                 if cached:
-                    if getattr(details, "type", None) == "local":
-                        from ..parallel.multihost import is_multihost
-                        if is_multihost():
-                            # A multihost engine is terminal: retiring it
-                            # broadcasts SHUTDOWN and the followers exit, so
-                            # a rebuilt coordinator engine would hang forever
-                            # in its first collective (advisor r1, medium).
-                            # Keep serving with the old engine and say so —
-                            # adopting the new fingerprint so this logs once
-                            # and the fast path resumes, not per-request.
-                            logger.error(
-                                "providers.json change for local provider "
-                                "%r ignored: multihost engines cannot be "
-                                "rebuilt in-process (followers replay one "
-                                "command stream); restart the fleet to "
-                                "apply the new engine config", name)
-                            self._cache[name] = (fingerprint, cached[1])
-                            return cached[1]
                     # Config changed: in-flight streams may still hold the
                     # old provider's pooled client — close it only after
                     # they can possibly have finished.

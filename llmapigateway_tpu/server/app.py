@@ -237,8 +237,6 @@ def run(settings: Settings | None = None) -> None:
     settings = settings or Settings.from_env()
     from ..utils.logging_setup import configure_logging
     configure_logging(settings.logs_dir or "logs", settings.log_level)
-    if _maybe_run_follower(settings):
-        return
     try:
         app = build_app(settings, local_factory=_default_local_factory())
     except Exception as e:
@@ -246,38 +244,6 @@ def run(settings: Settings | None = None) -> None:
         raise SystemExit(1)
     web.run_app(app, host=settings.gateway_host, port=settings.gateway_port,
                 access_log=None)
-
-
-def _maybe_run_follower(settings: Settings) -> bool:
-    """Multi-host deployment (JAX_COORDINATOR_ADDRESS set): process 0 runs
-    the HTTP frontend; every other process builds the SAME local engine and
-    replays the coordinator's compiled-program calls over DCN until
-    shutdown (SURVEY.md §7 hard part (4); parallel/multihost.py)."""
-    import os
-    if not os.environ.get("JAX_COORDINATOR_ADDRESS"):
-        return False
-    from ..parallel.mesh import init_distributed
-    from ..parallel import multihost as mh
-    init_distributed()
-    if not mh.is_multihost() or mh.is_coordinator():
-        return False         # coordinator serves HTTP as usual
-    from ..config.loader import ConfigLoader
-    from ..engine.engine import InferenceEngine
-    loader = ConfigLoader(settings.config_dir or ".",
-                          fallback_provider=settings.fallback_provider)
-    local = [(name, d) for name, d in loader.providers.items()
-             if d.type == "local" and d.engine is not None]
-    if len(local) != 1:
-        raise SystemExit(
-            f"multihost follower needs exactly one local provider in "
-            f"providers.json, found {len(local)}")
-    import jax
-    name, details = local[0]
-    logger.info("follower %s: building engine for provider %r",
-                jax.process_index(), name)
-    engine = InferenceEngine(details.engine)
-    engine.run_follower()
-    return True
 
 
 def _default_local_factory():

@@ -17,7 +17,7 @@ if "xla_force_host_platform_device_count" not in _flags:
 
 # Tests never use the persistent XLA compilation cache
 # (jax_enable_compilation_cache=False, set through its environment form so
-# the suite's child processes — bench-smoke, multihost workers, parity
+# the suite's child processes — bench-smoke, parity
 # reruns — inherit it): siblings run with different XLA flag sets and must
 # not exchange programs, and tiny-test compiles are sub-second, so nothing
 # of value is lost. The engine's cache PLACEMENT has its own tests
@@ -140,30 +140,17 @@ _PARITY_RERUN_TESTS = {
     "test_swa_ring_serves_full_context_from_small_pool",
     # test_kv_quant.py
     "test_engine_pallas_with_kv_quant_matches_reference",
-    "test_pipelined_engine_with_kv_quant",
-    "test_seq_sharded_engine_with_kv_quant",
     # test_model_mistral.py
-    "test_engine_swa_composes_with_pp_and_spec",
+    "test_engine_swa_composes_with_spec",
     "test_engine_swa_paged_pallas_matches_reference",
     "test_engine_swa_paged_sharded_pallas_matches_reference",
     "test_engine_swa_paged_spec_ring_matches_reference",
     "test_engine_swa_pallas_matches_reference",
     "test_engine_swa_sharded_pallas_matches_reference",
-    # test_quant.py
-    "test_seq_sharded_engine_with_quant_matches_single_device",
     # test_speculative.py
     "test_adaptive_gate_closes_on_low_acceptance",
-    "test_spec_composes_with_seq_and_pipe_sharding",
     "test_spec_engine_serves_sampled_via_normal_path",
     "test_spec_greedy_parity", "test_spec_greedy_parity_paged",
-    # test_pipeline.py
-    "test_engine_serves_with_pipeline_stages",
-    "test_engine_pipe_with_paged_kv",
-    "test_engine_serves_moe_with_pipeline_and_expert_axes",
-    # test_sequence_parallel.py
-    "test_engine_serves_seq_sharded_prompt",
-    "test_engine_serves_ulysses_seq_mode",
-    "test_engine_seq_mode_with_paged_kv",
 }
 
 

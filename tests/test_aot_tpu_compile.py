@@ -302,8 +302,8 @@ def test_decode_scan_leaves_the_pool_where_it_lies(chips, monkeypatch):
     mesh = build_mesh({}, devices=chips[:1])
     engine = types.SimpleNamespace(
         model_cfg=config, quant="int8", dtype=jnp.bfloat16, mesh=mesh,
-        attention_impl="pallas", kv_ppb=1, S=8192, B=slots, pipe_n=1,
-        seq_n=1, spec_k=0, decode_burst=depth, _burst_depths=(depth,),
+        attention_impl="pallas", kv_ppb=1, S=8192, B=slots, spec_k=0,
+        decode_burst=depth, _burst_depths=(depth,),
         allocator=types.SimpleNamespace(num_pages=pages, page_size=PAGE))
     InferenceEngine._compile_paged(engine)
     assert engine.kv_pool_in_place

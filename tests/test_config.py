@@ -38,6 +38,27 @@ def test_local_provider_entry():
     assert providers["local_tpu"].engine.mesh == {"data": 1, "model": 8}
 
 
+@pytest.mark.parametrize("engine,message", [
+    ({"mesh": {"pipe": 2, "model": 4}},
+     "unknown mesh axis 'pipe': the axes are data, expert, model"),
+    ({"mesh": {"seq": 8}},
+     "unknown mesh axis 'seq': the axes are data, expert, model"),
+    ({"mesh": {"tensor": 8}},
+     "unknown mesh axis 'tensor': the axes are data, expert, model"),
+    ({"seq_attention": "ulysses"}, "seq_attention"),
+])
+def test_local_provider_refuses_a_mode_the_engine_no_longer_has(engine,
+                                                                message):
+    """A ``providers.json`` written for pipeline or sequence parallelism
+    is refused when it is loaded — by axis name, with the axes that exist
+    — and does not serve on fewer chips than it says; an option that is
+    gone is an unknown field (``extra="forbid"``)."""
+    with pytest.raises(ConfigError, match=message) as err:
+        parse_providers([{"local_tpu": {"type": "local", "engine": {
+            "preset": "tinyllama-1.1b", **engine}}}])
+    assert "provider 'local_tpu' invalid" in str(err.value)
+
+
 def test_local_provider_requires_engine():
     with pytest.raises(ConfigError, match="requires 'engine'"):
         parse_providers([{"bad": {"type": "local"}}])

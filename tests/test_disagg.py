@@ -12,6 +12,7 @@ from llmapigateway_tpu.config.schemas import LocalEngineConfig
 from llmapigateway_tpu.engine.engine import (
     EngineOverloaded, GenRequest, InferenceEngine)
 from llmapigateway_tpu.obs.flight import POOL_DECODE, POOL_PREFILL
+from tests.mesh_parity import serve
 
 
 def _cfg(disagg=False, prefill_slots=1, **kw):
@@ -324,3 +325,16 @@ async def test_pool_stats_shape_and_prediction_fields(pooled_engine):
     assert pools["prefill"].get("predicted_ttft_ms", 0) > 0
     assert st["disagg_handoffs"] >= 1
     assert st["disagg_handoff_pages"] >= 1
+
+
+async def test_disaggregated_pools_on_a_model_mesh_match_one_device():
+    """Prefill and decode pools over one SHARDED page pool: a handoff is
+    a refcount transfer in the host allocator, so it is the same on a
+    mesh — the tokens are the one-device engine's, and handoffs ran."""
+    kw = dict(kv_page_size=16,
+              disaggregation={"enabled": True, "prefill_slots": 1})
+    ref, _ = await serve({}, **kw)
+    got, eng = await serve({"model": 2}, **kw)
+    assert got == ref
+    assert eng.stats()["disagg_handoffs"] >= 2
+    eng._prefix_cache.check_invariants()

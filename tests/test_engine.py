@@ -719,3 +719,24 @@ async def test_queue_wait_and_clamp_surface_in_stats_under_load():
             pass
     finally:
         await eng.stop()
+
+
+def test_engine_refuses_to_build_in_one_process_of_several(monkeypatch):
+    """The engine serves from ONE process that drives every device of its
+    mesh: a process started as one of several (``jax.distributed``) is
+    refused at build, before anything is placed."""
+    monkeypatch.setattr(jax, "process_count", lambda: 2)
+    monkeypatch.setattr(jax, "process_index", lambda: 1)
+    with pytest.raises(ValueError, match="the engine serves from one "
+                       "process; this one is 1 of 2"):
+        InferenceEngine(LocalEngineConfig(preset="tiny-test"))
+
+
+def test_engine_build_refuses_an_unknown_mesh_axis():
+    """Past the configuration's own check (a ``mesh`` assigned after
+    validation, a caller that builds the mesh itself): the mesh builder
+    refuses the axis too, so nothing is silently served on one chip."""
+    cfg = LocalEngineConfig(preset="tiny-test")
+    cfg.mesh = {"pipe": 2}
+    with pytest.raises(ValueError, match="unknown mesh axis 'pipe'"):
+        InferenceEngine(cfg, devices=jax.devices("cpu")[:2])

@@ -13,6 +13,7 @@ import pytest
 from llmapigateway_tpu.config.schemas import LocalEngineConfig
 from llmapigateway_tpu.models import llama
 from llmapigateway_tpu.models.config import get_preset
+from tests.mesh_parity import serve, split_dims
 
 
 def test_quantize_kv_roundtrip_bound():
@@ -242,113 +243,6 @@ async def test_engine_pallas_with_kv_quant_matches_reference():
     assert got.finish_reason == ref.finish_reason
 
 
-async def test_seq_sharded_engine_with_kv_quant():
-    """kv_quant composes with sequence parallelism: the ring prefill
-    attends fresh q/k/v, the S-sharded {q,s} cache leaves take the
-    quantizing insert, and GSPMD partitions the dict-aware decode. The
-    seq=4 engine must match the single-device int8-cache engine exactly
-    (same quantized values, per-chip fp math on replicated weights)."""
-    from llmapigateway_tpu.engine.engine import GenRequest, InferenceEngine
-    from tests.conftest import cpu_devices
-
-    async def run(mesh, devs):
-        cfg = LocalEngineConfig(kv_layout="contiguous",
-        preset="tiny-test", max_batch_size=2,
-                                max_seq_len=128, prefill_chunk=32,
-                                dtype="float32", decode_burst=2,
-                                kv_quant="int8", mesh=mesh,
-                                attention="reference",
-                                prewarm_sampler_variants=False,
-                                compilation_cache_dir="off")
-        eng = InferenceEngine(cfg, devices=devs)
-        await eng.start()
-        req = GenRequest(prompt_ids=list(range(2, 40)), max_tokens=6,
-                         temperature=0.0)
-        await eng.submit(req)
-        async for _ in eng.stream(req):
-            pass
-        await eng.stop()
-        return req
-
-    ref = await run({}, [cpu_devices()[0]])
-    got = await run({"seq": 4}, cpu_devices()[:4])
-    assert got.generated == ref.generated
-    assert got.finish_reason == ref.finish_reason
-
-
-async def test_pipelined_engine_with_kv_quant():
-    """kv_quant composes with PIPELINE parallelism (VERDICT r3 item 7):
-    the staged block tree-maps its microbatch slicing over the {q,s}
-    cache leaves and attends them via the quant-aware dense attention.
-    The pipe=2 engine must match the single-device int8-cache engine
-    exactly (same quantized values, fp32 math, replicated weights)."""
-    from llmapigateway_tpu.engine.engine import GenRequest, InferenceEngine
-    from tests.conftest import cpu_devices
-
-    async def run(mesh, devs):
-        cfg = LocalEngineConfig(kv_layout="contiguous",
-        preset="tiny-test", max_batch_size=2,
-                                max_seq_len=128, prefill_chunk=32,
-                                dtype="float32", decode_burst=2,
-                                kv_quant="int8", mesh=mesh,
-                                attention="reference",
-                                prewarm_sampler_variants=False,
-                                compilation_cache_dir="off")
-        eng = InferenceEngine(cfg, devices=devs)
-        await eng.start()
-        req = GenRequest(prompt_ids=list(range(2, 40)), max_tokens=6,
-                         temperature=0.0)
-        await eng.submit(req)
-        async for _ in eng.stream(req):
-            pass
-        await eng.stop()
-        return req, eng
-
-    ref, _ = await run({}, [cpu_devices()[0]])
-    got, eng = await run({"pipe": 2}, cpu_devices()[:2])
-    assert got.generated == ref.generated
-    assert got.finish_reason == ref.finish_reason
-    # The staged cache really is int8 with layer-sharded leaves.
-    assert eng.cache.k["q"].dtype == jnp.int8
-
-
-def test_pipelined_forward_with_kv_quant_parity():
-    """pipelined_forward over an int8 {q,s} cache matches the sequential
-    forward over an identically-quantized cache — logits AND the cache
-    contents written back (both paths quantize at insert time)."""
-    from llmapigateway_tpu.models import llama
-    from llmapigateway_tpu.models.config import get_preset
-    from llmapigateway_tpu.parallel.mesh import MeshSpec, build_mesh
-    from llmapigateway_tpu.parallel.pipeline import pipelined_forward
-    from tests.conftest import cpu_devices
-
-    cfg = get_preset("tiny-test")
-    params = llama.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
-    mesh = build_mesh(MeshSpec(sizes={"pipe": 2}, auto_model=False),
-                      cpu_devices()[:2])
-    B, T, S = 2, 8, 32
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (B, T), 0,
-                                cfg.vocab_size)
-    lengths = jnp.zeros((B,), jnp.int32)
-
-    def fresh():
-        return llama.KVCache.create(cfg, B, S, jnp.float32, kv_quant="int8")
-
-    ref, ref_cache = llama.forward(params, cfg, tokens, lengths, fresh())
-    got, got_cache = pipelined_forward(params, cfg, tokens, lengths,
-                                       fresh(), mesh, 2)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               rtol=1e-4, atol=1e-4)
-    # Compare the VALID cache prefix [0, T) only: positions ≥ lengths are
-    # the documented undefined zone, and the pipeline's bubble ticks park
-    # their writes at the row tail (clamp-to-tail trick) by design.
-    np.testing.assert_array_equal(np.asarray(got_cache.k["q"])[..., :T, :],
-                                  np.asarray(ref_cache.k["q"])[..., :T, :])
-    np.testing.assert_allclose(np.asarray(got_cache.k["s"])[..., :T],
-                               np.asarray(ref_cache.k["s"])[..., :T],
-                               rtol=1e-6, atol=1e-7)
-
-
 @pytest.mark.parametrize("kv_quant", ["", "int8"])
 def test_paged_sharded_adapter_matches_reference(setup, kv_quant):
     """The paged adapter's shard_map branch (model-axis manual kernels)
@@ -409,27 +303,21 @@ def test_kv_quant_guardrails():
         InferenceEngine(LocalEngineConfig(kv_layout=layout,
                                           kv_quant="int8", spec_draft_len=3,
                                           **base))
-    # The one remaining hole: the seq-sharded PAGED verify rides the
-    # chunk path, which reads even the draft self token quantized —
-    # exact-greedy parity can't hold, so the build must refuse.
-    with pytest.raises(ValueError, match="seq-sharded"):
-        InferenceEngine(
-            LocalEngineConfig(kv_layout="paged", kv_quant="int8",
-                              spec_draft_len=3, mesh={"seq": 4},
-                              preset="tiny-test", max_batch_size=1,
-                              max_seq_len=256, kv_page_size=16,
-                              compilation_cache_dir="off"),
-            devices=cpu_devices()[:4])
-    # Same hole under pipeline sharding, either layout: the staged
-    # block verifies drafts on the chunk path by design
-    # (parallel/pipeline.py — no .verify provider), so int8+spec+pipe
-    # must refuse at build too.
-    for layout in ("contiguous", "paged"):
-        with pytest.raises(ValueError, match="pipeline"):
-            InferenceEngine(
-                LocalEngineConfig(kv_layout=layout, kv_quant="int8",
-                                  spec_draft_len=3, mesh={"pipe": 2},
-                                  preset="tiny-test", max_batch_size=1,
-                                  max_seq_len=256, kv_page_size=16,
-                                  compilation_cache_dir="off"),
-                devices=cpu_devices()[:2])
+
+
+@pytest.mark.parametrize("chips", [2, 4])
+async def test_int8_pool_on_a_model_mesh_matches_one_device(chips):
+    """The int8 page pool served tensor-parallel. On two chips the model's
+    two KV heads split, one a chip, and the scale planes with them. On
+    four they do not divide: the pool is PLACED whole on every chip
+    (test_sharding.py), and how a step hands it back is the partitioner's
+    choice, so only the tokens are held to."""
+    ref, _ = await serve({}, kv_quant="int8", kv_page_size=16)
+    got, eng = await serve({"model": chips}, kv_quant="int8",
+                           kv_page_size=16)
+    assert got == ref
+    assert eng.cache.k["q"].dtype == jnp.int8
+    if chips == 2:
+        assert split_dims(eng.cache.k["q"]) == (2,)
+        assert split_dims(eng.cache.k["s"]) == (2,)
+    assert not eng.kv_pool_in_place          # a mesh keeps the sliced read

@@ -16,6 +16,7 @@ from llmapigateway_tpu.models import llama
 from llmapigateway_tpu.models.config import ModelConfig, get_preset
 
 from tests.conftest import cpu_devices
+from tests.mesh_parity import CYCLING, serve
 
 
 def test_window_mask_ignores_old_keys():
@@ -129,12 +130,10 @@ async def test_engine_serves_sliding_window_model():
     assert eng.model_cfg.sliding_window == 16
 
 
-async def test_engine_swa_composes_with_pp_and_spec():
-    """The windowed dense paths thread through the pipelined block AND
-    the speculative verify — tokens must match the plain engine's."""
+async def test_engine_swa_composes_with_spec():
+    """The windowed dense paths thread through the speculative verify —
+    tokens must match the plain engine's."""
     ref, _ = await _serve({}, [cpu_devices()[0]])
-    pp, _ = await _serve({"pipe": 2}, cpu_devices()[:2])
-    assert pp.generated == ref.generated
     spec, eng = await _serve({}, [cpu_devices()[0]], spec_draft_len=3)
     assert spec.generated == ref.generated
     assert eng._spec_steps_done > 0          # speculation really engaged
@@ -168,12 +167,13 @@ async def test_engine_swa_paged_pallas_matches_reference():
 
 
 def test_swa_guardrails():
-    with pytest.raises(ValueError, match="seq"):
-        InferenceEngine(LocalEngineConfig(kv_layout="contiguous",
-        
-            preset="tiny-mistral-test", max_batch_size=1, max_seq_len=64,
-            mesh={"seq": 4}, compilation_cache_dir="off"),
-            devices=cpu_devices()[:4])
+    """A window was once refused on a ``seq`` mesh at engine build; the
+    axis is gone, and the configuration itself refuses it by name."""
+    with pytest.raises(ValueError, match="unknown mesh axis 'seq'"):
+        LocalEngineConfig(
+            kv_layout="contiguous", preset="tiny-mistral-test",
+            max_batch_size=1, max_seq_len=64, mesh={"seq": 4},
+            compilation_cache_dir="off")
 
 
 async def test_engine_swa_paged_spec_ring_matches_reference():
@@ -225,3 +225,19 @@ async def test_engine_swa_paged_sharded_pallas_matches_reference():
     assert eng.paged and eng.model_cfg.sliding_window == 16
     assert eng.mesh.shape.get("model") == 2
     assert eng._resolve_attention_impl() == "pallas"
+
+
+async def test_engine_swa_spec_on_a_model_mesh_matches_one_device():
+    """A window with speculation, from the page pool, tensor-parallel.
+    One device serves it from the page ring; a mesh switches the ring off
+    (every slot reserves its whole context) — pinned here as it is — and
+    the tokens are the same either way, with drafts really accepted."""
+    kw = dict(preset="tiny-mistral-test", kv_page_size=16, max_tokens=40,
+              spec_draft_len=3, spec_min_tokens_per_step=0.0,
+              decode_burst=8, prompts=CYCLING)
+    ref, one = await serve({}, **kw)
+    got, eng = await serve({"model": 2}, **kw)
+    assert got == ref
+    assert one._swa_ring_pages > 0 and eng._swa_ring_pages == 0
+    assert eng._spec_tokens_out > eng._spec_steps_done > 0
+    eng.allocator.check_invariants()

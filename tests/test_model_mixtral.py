@@ -10,6 +10,7 @@ from llmapigateway_tpu.models import llama, mixtral
 from llmapigateway_tpu.models.config import ModelConfig, get_preset
 from llmapigateway_tpu.parallel.mesh import MeshSpec, build_mesh
 from llmapigateway_tpu.parallel.sharding import param_shardings
+from tests.mesh_parity import serve
 
 CFG = ModelConfig(family="mixtral", vocab_size=128, d_model=32, n_layers=2,
                   n_heads=4, n_kv_heads=2, d_ff=64, max_seq_len=64,
@@ -146,3 +147,17 @@ async def test_engine_serves_moe_preset():
         assert len(req.generated) >= 1
     finally:
         await eng.stop()
+
+
+async def test_engine_serves_moe_on_an_expert_and_model_mesh():
+    """A sparse-expert engine on `expert` = 2 × `model` = 2 through the
+    real scheduler: the experts split over one axis and their width over
+    the other, and the tokens are the one-device engine's."""
+    kw = dict(preset="tiny-moe-test", kv_page_size=16)
+    ref, _ = await serve({}, **kw)
+    got, eng = await serve({"expert": 2, "model": 2}, **kw)
+    assert got == ref
+    assert tuple(eng.params["layers"]["wg"].sharding.spec) == (
+        None, "expert", None, "model")
+    assert tuple(eng.params["layers"]["wd"].sharding.spec) == (
+        None, "expert", "model", None)

@@ -23,6 +23,7 @@ from llmapigateway_tpu.ops.paged_attention import (
     paged_decode_attention,
     paged_prefill_attention,
 )
+from tests.mesh_parity import serve
 
 PPB = 4            # pack for the largest variant; 1/2/4 all divide it
 
@@ -188,3 +189,17 @@ def test_packed_allocator_rounds_reservations_to_runs():
     # Ring reservations don't compose with packing (engine disables it).
     with pytest.raises(ValueError, match="ring"):
         alloc.allocate(1, 100, ring_pages=2)
+
+
+async def test_engine_multipage_blocks_on_a_model_mesh_match_one_device():
+    """`kv_pages_per_block` 4 under a mesh: the multi-page kernels
+    (interpret mode) run under `shard_map` over `model`, on tables the
+    packing allocator laid out, and serve the one-device engine's
+    tokens."""
+    kw = dict(kv_page_size=8, kv_pages_per_block=4, attention="pallas")
+    ref, _ = await serve({}, **kw)
+    got, eng = await serve({"model": 2}, **kw)
+    assert got == ref
+    assert eng.kv_ppb == 4 and eng.attention_impl == "pallas"
+    assert eng.stats()["pages_per_block"] == 4
+    eng._prefix_cache.check_invariants()

@@ -15,6 +15,7 @@ from llmapigateway_tpu.config.settings import Settings
 from llmapigateway_tpu.engine.engine import GenRequest, InferenceEngine
 from llmapigateway_tpu.providers.local import LocalProvider
 from llmapigateway_tpu.server.app import GatewayApp, build_app
+from tests.mesh_parity import serve
 
 # Greedy decode on the deterministic tiny-test weights (PRNGKey(0) init)
 # collapses into a single-token repetition loop on this prompt — the
@@ -171,3 +172,17 @@ async def test_http_penalties_default_to_zero(tmp_path, http_factory):
         {"messages": [{"role": "user", "content": "x"}],
          "presence_penalty": 1.5, "frequency_penalty": -0.5})
     assert req.presence_penalty == 1.5 and req.frequency_penalty == -0.5
+
+
+async def test_penalties_on_a_model_mesh_match_one_device():
+    """Penalised greedy requests tensor-parallel: the general sampler runs
+    over logits gathered from a vocabulary split four ways, against the
+    replicated count rows, and picks what one device picks."""
+    kw = dict(kv_page_size=16, request_kw=dict(presence_penalty=0.8,
+                                               frequency_penalty=0.4))
+    plain, _ = await serve({}, kv_page_size=16)
+    ref, _ = await serve({}, **kw)
+    got, eng = await serve({"model": 4}, **kw)
+    assert got == ref
+    assert ref != plain                      # the penalties really bit
+    assert eng.params["lm_head"].sharding.spec[0] == "model"

@@ -25,6 +25,7 @@ from llmapigateway_tpu.engine.engine import (FaultPlan, GenRequest,
                                              InferenceEngine)
 from llmapigateway_tpu.engine.paged import PageAllocator
 from llmapigateway_tpu.engine.prefix_cache import RadixPrefixCache
+from tests.mesh_parity import serve, split_dims
 
 PAGE = 16
 
@@ -307,3 +308,17 @@ def test_shared_pages_must_be_whole_groups():
         alloc.allocate(1, 64, shared_pages=alloc.table[0][:2].tolist())
     with pytest.raises(ValueError, match="not live"):
         alloc.allocate(1, 64, shared_pages=[28, 29, 30, 31])
+
+
+async def test_prefix_cache_on_a_model_mesh_matches_one_device():
+    """The prefix cache over a pool whose heads are split on `model`: the
+    second round of the same two prompts maps resident pages (whole
+    blocks of each prompt) and generates what one device generates."""
+    ref, _ = await serve({}, kv_page_size=16, rounds=2)
+    got, eng = await serve({"model": 2}, kv_page_size=16, rounds=2)
+    assert got == ref and got[:2] == got[2:]
+    stats = eng.stats()
+    assert stats["prefix_hits_total"] == 2
+    assert stats["prefix_cached_tokens_total"] == 64       # 32 + 32
+    assert split_dims(eng.cache.k) == (2,)           # KV heads
+    eng._prefix_cache.check_invariants()

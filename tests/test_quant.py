@@ -21,6 +21,7 @@ from llmapigateway_tpu.models.quant import (
     contract_axis_for, is_quantized, mm, quantize_array, quantize_tree)
 
 from tests.conftest import cpu_devices
+from tests.mesh_parity import serve
 
 
 def test_quantize_roundtrip_error_bound():
@@ -125,31 +126,6 @@ def test_sharded_quant_forward_matches_single_device(quant_setup):
         sharded, cfg, tokens, lengths, cache)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-3, atol=2e-3)
-
-
-def test_pipelined_forward_with_quant(quant_setup):
-    """quant + pipeline parallelism: the staged block and the lm_head must
-    both go through the plain-or-quantized dispatch (regression: the
-    pipeline's logits einsum once received the raw {"q","s"} head dict)."""
-    from llmapigateway_tpu.parallel.mesh import MeshSpec, build_mesh
-    from llmapigateway_tpu.parallel.pipeline import pipelined_forward
-
-    cfg, _, _ = quant_setup          # tiny-test: n_layers=2 → pipe=2
-    params = quantize_tree(
-        llama.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32), cfg)
-    mesh = build_mesh(MeshSpec(sizes={"pipe": 2}, auto_model=False),
-                      cpu_devices()[:2])
-    B, T, S = 2, 8, 32
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (B, T), 0,
-                                cfg.vocab_size)
-    lengths = jnp.zeros((B,), jnp.int32)
-    ref, _ = llama.forward(params, cfg, tokens, lengths,
-                           llama.KVCache.create(cfg, B, S, jnp.float32))
-    got, _ = pipelined_forward(params, cfg, tokens, lengths,
-                               llama.KVCache.create(cfg, B, S, jnp.float32),
-                               mesh, 2)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               rtol=1e-4, atol=1e-4)
 
 
 @pytest.mark.parametrize("preset", ["tiny-test", "tiny-qwen-test",
@@ -347,37 +323,6 @@ def test_moe_sharded_quant_forward_matches():
                                rtol=2e-3, atol=2e-3)
 
 
-async def test_seq_sharded_engine_with_quant_matches_single_device():
-    """Weight quant composes with sequence parallelism: a ring-attention
-    seq=4 engine with int8 weights produces the single-device quantized
-    engine's exact greedy tokens (weights replicate over `seq`; the int8
-    dots are unsharded per-chip math, so parity is exact)."""
-    from llmapigateway_tpu.engine.engine import GenRequest, InferenceEngine
-
-    async def run(mesh, devs):
-        cfg = LocalEngineConfig(kv_layout="contiguous",
-        preset="tiny-test", max_batch_size=2,
-                                max_seq_len=128, prefill_chunk=32,
-                                dtype="float32", decode_burst=2,
-                                quant="int8", mesh=mesh,
-                                attention="reference",
-                                prewarm_sampler_variants=False,
-                                compilation_cache_dir="off")
-        eng = InferenceEngine(cfg, devices=devs)
-        await eng.start()
-        req = GenRequest(prompt_ids=list(range(2, 40)), max_tokens=6,
-                         temperature=0.0)
-        await eng.submit(req)
-        async for _ in eng.stream(req):
-            pass
-        await eng.stop()
-        return req
-
-    ref = await run({}, [cpu_devices()[0]])
-    got = await run({"seq": 4}, cpu_devices()[:4])
-    assert got.generated == ref.generated
-
-
 def test_moe_engine_e2e_with_quant():
     from llmapigateway_tpu.engine.engine import GenRequest, InferenceEngine
 
@@ -566,3 +511,23 @@ def test_moe_engine_e2e_with_int4():
 
     req = asyncio.run(run())
     assert len(req.generated) == 8
+
+
+@pytest.mark.parametrize("quant", ["int8", "int4"])
+async def test_quantized_weights_on_a_model_mesh_match_one_device(quant):
+    """Quantized weights served tensor-parallel: the {q, s} leaves are
+    placed by the ".q" / ".s" rules on `model` = 4 (a row-parallel
+    matmul's integer partial sums are reduced before its scale is
+    applied) and two requests decode together, token for token as on one
+    device."""
+    ref, _ = await serve({}, quant=quant, kv_page_size=16)
+    got, eng = await serve({"model": 4}, quant=quant, kv_page_size=16)
+    assert got == ref
+    layers = eng.params["layers"]
+    assert layers["wq"]["q"].dtype == (jnp.int8 if quant == "int8"
+                                       else jnp.int4)
+    assert layers["wq"]["q"].sharding.spec[2] == "model"
+    assert layers["wq"]["s"].sharding.spec[1] == "model"
+    assert layers["wd"]["q"].sharding.spec[1] == "model"
+    assert "model" not in tuple(layers["wd"]["s"].sharding.spec)
+    assert eng.params["lm_head"]["q"].sharding.spec[0] == "model"

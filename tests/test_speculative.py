@@ -12,6 +12,7 @@ import pytest
 from llmapigateway_tpu.config.schemas import LocalEngineConfig
 from llmapigateway_tpu.engine.engine import GenRequest, InferenceEngine
 from llmapigateway_tpu.engine.speculative import draft_from_history
+from tests.mesh_parity import CYCLING, serve
 
 
 def _engine(spec=0, **kw):
@@ -375,41 +376,6 @@ async def test_baseline_probe_gives_up_when_no_wall_sample_possible():
 def test_spec_config_guardrails():
     with pytest.raises(ValueError, match="1, 3, 7"):
         _engine(spec=4)
-
-
-@pytest.mark.parametrize("mesh,n_dev", [({"seq": 4}, 4), ({"pipe": 2}, 2)])
-async def test_spec_composes_with_seq_and_pipe_sharding(mesh, n_dev):
-    """Speculation over a seq-sharded or pipelined engine: the verify
-    forward's deferred attention partitions its S-reductions under GSPMD
-    (seq) / runs through the staged block (pipe), the replicated history
-    drafts on-device, and the output is still EXACTLY the greedy
-    sequence — with real acceptance (> 1 token per spec step)."""
-    rng = np.random.default_rng(1)     # this seed's greedy continuation
-    prompt = list(np.tile(rng.integers(2, 500, 4), 10))   # cycles early
-
-    async def run(m, devs, spec):
-        # busy depth == idle depth: see _engine — parity across engines
-        # must not depend on the prefill/first-decode-round busy race.
-        cfg = LocalEngineConfig(preset="tiny-test", max_batch_size=2,
-                                max_seq_len=256, prefill_chunk=32,
-                                dtype="float32", decode_burst=8,
-                                decode_burst_busy=8,
-                                spec_draft_len=spec, mesh=m,
-                                attention="reference",
-                                prewarm_sampler_variants=False,
-                                compilation_cache_dir="off",
-                                kv_layout="contiguous")
-        eng = InferenceEngine(cfg, devices=devs)
-        req = await _gen(eng, prompt, max_tokens=24)
-        await eng.stop()
-        return req, eng
-
-    cpus = jax.devices("cpu")
-    ref, _ = await run({}, cpus[:1], 0)
-    got, eng = await run(mesh, cpus[:n_dev], 3)
-    assert got.generated == ref.generated, (got.generated, ref.generated)
-    assert eng._spec_steps_done > 0
-    assert eng._spec_tokens_out > eng._spec_steps_done   # real acceptance
 
 
 async def test_spec_engine_recovers_from_injected_fault():
@@ -794,3 +760,20 @@ async def test_cancel_during_inflight_spec_burst_no_leaks():
         assert fs["flight_admits"] == fs["flight_finishes"]
     finally:
         await eng.stop()
+
+
+@pytest.mark.parametrize("mesh,layout", [({"model": 4}, "paged"),
+                                         ({"model": 2}, "contiguous")])
+async def test_spec_on_a_model_mesh_matches_one_device(mesh, layout):
+    """Speculation served tensor-parallel, from either cache layout: the
+    verify forward runs over sharded heads (paged: the deferred verify
+    with the pool whole on each of four chips; contiguous: heads split
+    over two), the history drafts on-device, and the output is the
+    one-device engine's — with real acceptance."""
+    kw = dict(spec_draft_len=3, spec_min_tokens_per_step=0.0,
+              max_tokens=24, kv_layout=layout, kv_page_size=16,
+              decode_burst=8, prompts=CYCLING)
+    ref, _ = await serve({}, **kw)
+    got, eng = await serve(mesh, **kw)
+    assert got == ref
+    assert eng._spec_tokens_out > eng._spec_steps_done > 0

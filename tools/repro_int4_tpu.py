@@ -50,8 +50,8 @@ COMMON = textwrap.dedent("""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     L, D, F = 4, 512, 1024          # small but tiled like the real leaves
-    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1, 1, 1, 1),
-                ("pipe", "data", "expert", "seq", "model"))
+    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1, 1),
+                ("data", "expert", "model"))
     sh3 = NamedSharding(mesh, P(None, None, None))
 
     def quantize(w):                # per-out-channel int4, engine scheme
