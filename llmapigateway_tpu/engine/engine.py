@@ -362,9 +362,9 @@ class InferenceEngine:
         # What actually serves ("auto" resolved) — exposed in stats() so a
         # run can assert it.
         self.attention_impl = self._resolve_attention_impl()
-        # Whether the decode programs leave the page pool where it lies
-        # (read by layer, written through aliased operands); static per
-        # engine, set by _compile_paged — exposed in stats() too.
+        # Whether the decode programs and prefill_step leave the page pool
+        # where it lies (read by layer, written through aliased operands);
+        # static per engine, set by _compile_paged — exposed in stats() too.
         self.kv_pool_in_place = False
         self._compile()
         logger.info("engine build: params %.1fs, state+programs %.1fs "

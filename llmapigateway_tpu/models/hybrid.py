@@ -594,9 +594,13 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
                          "it needs a paged attention_fn")
     decode_attend = getattr(attention_fn, "decode", None) if T == 1 else None
     decoding = decode_attend is not None
-    # ``.decode_at``: the provider reads the stacked pool at a layer's
-    # index, and the pool stays out of the scanned inputs (llama.forward).
+    # ``.decode_at`` / ``.prefill_at``: the provider reads the stacked pool
+    # at a layer's index, and the pool stays out of the scanned inputs —
+    # in prefill it is the scan's carry, written in place (llama.forward).
     decode_at = getattr(attention_fn, "decode_at", None) if decoding else None
+    prefill_at = None if decoding or T == 1 else \
+        getattr(attention_fn, "prefill_at", None)
+    by_index = decode_at is not None or prefill_at is not None
     scope = "decode" if decoding else "prefill"
     last_only = not decoding and n_valid is not None
     if decoding:
@@ -618,27 +622,31 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
 
     x = jnp.take(params["embed"], tokens, axis=0)               # [B,T,D]
 
-    def softmax_layer(x, lp, layer_k, layer_v, layer):
+    def softmax_layer(x, pool, lp, at):
+        """``at``: the period's index, or its (K, V) slice of the pool;
+        ``pool``: the stacked pool under ``prefill_at``."""
         with jax.named_scope(f"{scope}.attention"):
             h = rms_norm(x, lp["norm"], c.rms_eps)
             q = mm(h, lp["wq"]).reshape(B, T, c.n_heads, dh)
             k = mm(h, lp["wk"]).reshape(B, T, c.n_kv_heads, dh)
             v = mm(h, lp["wv"]).reshape(B, T, c.n_kv_heads, dh)
-            if decode_at is not None:
-                attn = decode_at(q, k, v, cache.k, cache.v, layer, lengths,
+            ys = (k, v)
+            if prefill_at is not None:
+                attn, pool_k, pool_v = prefill_at(q, k, v, *pool, at,
+                                                  lengths, active)
+                pool, ys = (pool_k, pool_v), None
+            elif decode_at is not None:
+                attn = decode_at(q, k, v, cache.k, cache.v, at, lengths,
                                  active)
-                ys = (k, v)
             elif decoding:
-                attn = decode_attend(q, k, v, layer_k, layer_v, lengths,
-                                     active)
-                ys = (k, v)
+                attn = decode_attend(q, k, v, *at, lengths, active)
             else:
-                attn, layer_k, layer_v = attention_fn(
-                    q, k, v, layer_k, layer_v, lengths, active)
+                attn, layer_k, layer_v = attention_fn(q, k, v, *at, lengths,
+                                                      active)
                 ys = (layer_k, layer_v)
             gate = jax.nn.sigmoid(mm(h, lp["wgate"]).astype(jnp.float32))
             attn = (attn.astype(jnp.float32) * gate).astype(x.dtype)
-            return x + mm(attn, lp["wo"]), ys
+            return x + mm(attn, lp["wo"]), pool, ys
 
     # The routed experts' matrices stay OUT of the scanned slices: the
     # expert layer reads them from the whole stack at the period's index
@@ -653,9 +661,11 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
         with jax.named_scope(f"{scope}.mlp"):
             return moe_block(x, {**lp, **held[i]}, c, count, period)
 
-    def period_step(x, scanned):
-        period, (attn, *lin), (layer_k, layer_v), s0, tail0 = scanned
-        x, ys = softmax_layer(x, attn, layer_k, layer_v, period)
+    def period_step(carry, scanned):
+        x, pool = carry
+        period, (attn, *lin), sides, s0, tail0 = scanned
+        x, pool, ys = softmax_layer(x, pool, attn,
+                                    period if by_index else sides)
         x, counted = mlp(x, attn["mlp"], 0, period)
         states, tails = [], []
         for i, (lp, s, tail) in enumerate(zip(lin, s0, tail0), 1):
@@ -668,19 +678,20 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
             counted = counted + more
             states.append(s)
             tails.append(tail)
-        return x, (ys, tuple(states), tuple(tails), counted)
+        return (x, pool), (ys, tuple(states), tuple(tails), counted)
 
-    x, ((ys_k, ys_v), s_out, tail_out, counts) = jax.lax.scan(
-        period_step, x, (jnp.arange(c.n_kv_layers), rest,
-                         (None, None) if decode_at is not None
-                         else (cache.k, cache.v), s_in, tail_in))
+    (x, pool), (ys, s_out, tail_out, counts) = jax.lax.scan(
+        period_step,
+        (x, (cache.k, cache.v) if prefill_at is not None else None),
+        (jnp.arange(c.n_kv_layers), rest,
+         None if by_index else (cache.k, cache.v), s_in, tail_in))
     if decoding:
         new_k, new_v = attention_fn.insert_all(
-            cache.k, cache.v, ys_k, ys_v, lengths, active)
+            cache.k, cache.v, *ys, lengths, active)
         state, conv = s_out, tail_out
         counters = cache.counters + jnp.sum(counts, axis=0)
     else:
-        new_k, new_v = ys_k, ys_v
+        new_k, new_v = pool if prefill_at is not None else ys
         state = tuple(s.at[:, slots].set(new)
                       for s, new in zip(cache.state, s_out))
         conv = tuple(t.at[:, slots].set(new)

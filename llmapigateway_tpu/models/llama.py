@@ -648,8 +648,14 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
     # A provider with ``.decode_at`` reads the layer-STACKED cache at a
     # layer's index (ops/paged_attention.py): the cache then stays out of
     # the scanned inputs too — a scanned slice of it is a COPY of a layer's
-    # whole side, every layer of every step.
+    # whole side, every layer of every step. ``.prefill_at`` is the chunk
+    # path's twin (insert-then-attend, so only where there is no
+    # ``.verify``): the stacked cache is the scan's CARRY, written in place
+    # and attended at the layer's index, and never among the ys.
     decode_at = getattr(attention_fn, "decode_at", None) if T == 1 else None
+    prefill_at = getattr(attention_fn, "prefill_at", None) \
+        if T > 1 and decode_attend is None else None
+    by_index = decode_at is not None or prefill_at is not None
 
     # Phase markers (ISSUE 8): named_scope is trace-time op metadata —
     # zero runtime cost — so profiler captures segment each layer into
@@ -658,29 +664,29 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
     # the insert-then-attend chunk path.
     scope = "decode" if decode_attend is not None else "prefill"
 
-    def layer_step(x, scanned):
-        if decode_at is not None:
-            lp, layer = scanned
-        else:
-            lp, layer_k, layer_v = scanned
+    def layer_step(carry, scanned):
+        x, pool = carry           # pool: the stacked cache, under prefill_at
+        lp, at = scanned          # at: the layer's index, or its (K, V) slice
         # Attention block
         with jax.named_scope(f"{scope}.attention"):
             h = rms_norm(x, lp["attn_norm"], c.rms_eps, c.rms_offset)
             q, k, v = qkv_proj(h, lp, c)
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
-            if decode_at is not None:
-                attn = decode_at(q, k, v, cache.k, cache.v, layer, lengths,
+            ys = (k, v)                       # stacked for insert_all below
+            if prefill_at is not None:
+                attn, pool_k, pool_v = prefill_at(q, k, v, *pool, at,
+                                                  lengths, active)
+                pool, ys = (pool_k, pool_v), None
+            elif decode_at is not None:
+                attn = decode_at(q, k, v, cache.k, cache.v, at, lengths,
                                  active)
-                ys = (k, v)
             elif decode_attend is not None:
-                attn = decode_attend(q, k, v, layer_k, layer_v, lengths,
-                                     active)
-                ys = (k, v)                   # stacked for insert_all below
+                attn = decode_attend(q, k, v, *at, lengths, active)
             else:
-                attn, layer_k, layer_v = attention_fn(
-                    q, k, v, layer_k, layer_v, lengths, active)
-                ys = (layer_k, layer_v)
+                attn, layer_k, layer_v = attention_fn(q, k, v, *at, lengths,
+                                                      active)
+                ys = (layer_k, layer_v)       # the written slices
             x = x + mm(attn, lp["wo"])
         # MLP block
         with jax.named_scope(f"{scope}.mlp"):
@@ -689,17 +695,20 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
                 x = x + custom_mlp(h, lp)
             else:
                 x = x + swiglu_mlp(h, lp["wg"], lp["wu"], lp["wd"], c.act)
-        return x, ys
+        return (x, pool), ys
 
-    x, (ys_k, ys_v) = jax.lax.scan(
-        layer_step, x,
-        (layer_params, jnp.arange(c.n_layers)) if decode_at is not None
-        else (layer_params, cache.k, cache.v))
-    if decode_attend is not None:
+    (x, pool), ys = jax.lax.scan(
+        layer_step,
+        (x, (cache.k, cache.v) if prefill_at is not None else None),
+        (layer_params, jnp.arange(c.n_layers) if by_index
+         else (cache.k, cache.v)))
+    if prefill_at is not None:
+        new_k, new_v = pool
+    elif decode_attend is not None:
         new_k, new_v = attention_fn.insert_all(
-            cache.k, cache.v, ys_k, ys_v, lengths, active)
+            cache.k, cache.v, *ys, lengths, active)
     else:
-        new_k, new_v = ys_k, ys_v
+        new_k, new_v = ys
 
     x = rms_norm(x, params["final_norm"], c.rms_eps, c.rms_offset)
     head = _select_head(params, c)

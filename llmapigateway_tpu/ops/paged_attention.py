@@ -15,23 +15,28 @@ Layout:
   0 is the trash page** of every layer: writes of inactive slots and of
   out-of-range positions are redirected there, so masked writes need no
   branching. The allocator (engine/paged.py) never hands page 0 out. The
-  prefill path and the reference path see one layer of it, ``[P, KV,
-  page, Dh]``, as the layer scan's slice.
+  reference path, a mesh and the speculative ``.verify`` see one layer of
+  it, ``[P, KV, page, Dh]``, as the layer scan's slice.
 * ``page_table``: ``[B, NP]`` int32 — slot's logical page j → physical
   page. Unallocated entries are 0 (trash) and are never read: reads are
   bounded by ``n_valid``.
 
-The DECODE programs leave the pool where it lies (PR 30). The decode
-kernel takes the whole stacked pool in HBM and the layer's index as a
-prefetched scalar (``.decode_at``), so the layer scan hands it no slice —
-a slice is a copy of a layer's whole side, a cost of the pool's CAPACITY
-paid every layer of every step. The step's new tokens go in through a
-second kernel whose pool operands are its outputs
-(:func:`paged_insert_in_place`, under the scope ``kv.paged_insert``): it
-touches ``L × B`` tiles, and the pool the burst carries keeps the default
-layout — the XLA scatter it replaces (:func:`paged_insert_all`, still the
-reference path and the tests' oracle) made the carried pool take a layout
-of its own liking, which every slice was then re-laid from.
+The step programs leave the pool where it lies (decode: PR 30; prefill:
+PR 34). The kernels take the whole stacked pool in HBM and the layer's
+index as a prefetched scalar (``.decode_at``, ``.prefill_at``), so the
+layer scan hands them no slice — a slice is a copy of a layer's whole
+side, a cost of the pool's CAPACITY paid every layer of every step or
+chunk. New tokens go in through kernels whose pool operands are their
+outputs, under the scope ``kv.paged_insert``: a decode step's after the
+layer scan (:func:`paged_insert_in_place`, ``L × B`` tiles), a prefill
+chunk's inside it, where the pool is the scan's CARRY
+(:func:`paged_insert_chunk_in_place`, whole tiles copied HBM to HBM and
+only a ragged first or last tile patched). The pool the programs carry
+keeps the default layout — the XLA scatters they replace
+(:func:`paged_insert_all`, :func:`paged_insert_kv`: still the write of the
+reference path, of a mesh and of ``.verify``, and the tests' oracle) made
+it take a layout of their own liking, which every slice was then re-laid
+from.
 
 Two attention kernels, two shapes. The PREFILL kernel mirrors
 ops/flash_attention.py (a grid over key blocks, online-softmax fp32
@@ -52,8 +57,8 @@ ragged property, by construction and not by elision.
 The adapter :func:`make_paged_attention_fn` is built INSIDE the engine's
 jitted step (closing over the traced page table), so ``llama.forward``
 needs no signature change: a ``PagedKVCache`` pytree scans over layers
-exactly like the dense cache — and stays OUT of the decode scan where
-the provider carries ``.decode_at``.
+exactly like the dense cache — and stays OUT of the scanned inputs where
+the provider carries ``.decode_at`` / ``.prefill_at``.
 """
 from __future__ import annotations
 
@@ -229,6 +234,33 @@ _INSERT_TILE_ROWS = 32
 _INSERT_VMEM_BYTES = 4 * 2 ** 20
 
 
+def _wide(dtype):
+    """The 32-bit type a write kernel selects in: the packed dtypes (int8,
+    bf16) have no select of their own on every chip generation, and both
+    widenings are exact."""
+    return jnp.int32 if jnp.issubdtype(dtype, jnp.integer) else jnp.float32
+
+
+def _pool_sides(pool_k, pool_v, k_new, v_new):
+    """(new values, pool sides) as the write kernels take them, K then V,
+    an int8 side as its values followed by its float32 scales — quantised
+    here, by ``quantize_kv``, as the XLA scatters do."""
+    if isinstance(pool_k, dict):
+        from ..models.llama import quantize_kv
+        (knq, kns), (vnq, vns) = quantize_kv(k_new), quantize_kv(v_new)
+        return ((knq, kns.astype(jnp.float32), vnq, vns.astype(jnp.float32)),
+                (pool_k["q"], pool_k["s"], pool_v["q"], pool_v["s"]))
+    return ((k_new.astype(pool_k.dtype), v_new.astype(pool_k.dtype)),
+            (pool_k, pool_v))
+
+
+def _pool_of(sides):
+    """(pool_k, pool_v) back from a write kernel's output sides."""
+    if len(sides) == 4:
+        return ({"q": sides[0], "s": sides[1]}, {"q": sides[2], "s": sides[3]})
+    return sides[0], sides[1]
+
+
 def _paged_insert_kernel(phys_ref, off_ref, *refs, T: int, rows: int,
                          quant: bool):
     """Program ``(layer, chunk of slots)``: for each token ``t`` in turn,
@@ -267,10 +299,7 @@ def _paged_insert_kernel(phys_ref, off_ref, *refs, T: int, rows: int,
         return out
 
     def put(buf, b, new, at, axis):
-        # In 32 bits: the packed dtypes (int8, bf16) have no select of
-        # their own on every chip generation; both widenings are exact.
-        wide = jnp.int32 if jnp.issubdtype(buf.dtype, jnp.integer) \
-            else jnp.float32
+        wide = _wide(buf.dtype)
         old = buf[b].astype(wide)
         hit = jax.lax.broadcasted_iota(jnp.int32, old.shape, axis) == at
         buf[b] = jnp.where(hit, new.astype(wide), old).astype(buf.dtype)
@@ -326,16 +355,10 @@ def paged_insert_in_place(pool_k, pool_v, k_news: jax.Array,
     rows = min(_INSERT_TILE_ROWS, page)
     phys, off = _insert_positions(page_table, lengths, active, T, page)
 
-    if quant:
-        from ..models.llama import quantize_kv
-        (knq, kns), (vnq, vns) = quantize_kv(k_news), quantize_kv(v_news)
-        news = (knq[..., None, :], kns[..., None, None].astype(jnp.float32),
-                vnq[..., None, :], vns[..., None, None].astype(jnp.float32))
-        pools = (pool_k["q"], pool_k["s"], pool_v["q"], pool_v["s"])
-    else:
-        news = (k_news.astype(kq.dtype)[..., None, :],
-                v_news.astype(kq.dtype)[..., None, :])
-        pools = (pool_k, pool_v)
+    news, pools = _pool_sides(pool_k, pool_v, k_news, v_news)
+    # Values [L, B, T, KV, 1, Dh], scales [L, B, T, KV, 1, 1].
+    news = tuple(x[..., None, :] if x.ndim == 5 else x[..., None, None]
+                 for x in news)
     # Slots a program holds: as many as keep its VMEM — per slot and side
     # a tile, the new rows (a one-row block pads to a whole 4 KiB tile a
     # head, and the pipeline holds two) and, int8, the same again for a
@@ -373,9 +396,206 @@ def paged_insert_in_place(pool_k, pool_v, k_news: jax.Array,
         interpret=_interpret_default() if interpret is None else interpret,
     )(phys.reshape(-1).astype(jnp.int32), off.reshape(-1).astype(jnp.int32),
       *news, *pools)
-    if quant:
-        return ({"q": out[0], "s": out[1]}, {"q": out[2], "s": out[3]})
-    return out[0], out[1]
+    return _pool_of(out)
+
+
+def _paged_insert_chunk_kernel(layer_ref, start_ref, tile_phys_ref,
+                               tile_off_ref, page_phys_ref, *refs, T: int,
+                               rows: int, page: int, n_tiles: int,
+                               n_pages: int, quant: bool):
+    """Program ``slot``: the slot's ``T`` new rows, which lie in the pool
+    at ``[start, start + T)`` of its logical sequence, go in as WHOLE
+    ``rows``-row tiles — one copy a tile and side from the new values to
+    the pool, HBM to HBM, all started and then all waited for. Only a
+    tile the run covers in part (its first, its last) and, int8, a
+    page's scale plane are read, changed and written back. The new
+    values arrive cut into the pool's own tiles (``tile j`` holds the
+    rows of absolute tile ``start // rows + j``: the caller shifted them),
+    so no copy is misaligned whatever ``start`` is. ``refs``: the new
+    values ``[K, n_tiles, KV, rows, Dh]`` (int8: each followed by its
+    scale planes ``[K, n_pages, KV, 1, page]``) for K then V, the pool
+    sides in HBM (unused: they ARE the outputs), the output pool sides,
+    per side a VMEM pair (the pool's piece, the new one) and the DMA
+    semaphores ``[side, whole tiles | patches]``."""
+    n = 4 if quant else 2
+    news, pools = refs[:n], refs[2 * n:3 * n]
+    bufs, sem = refs[3 * n:4 * n], refs[4 * n]
+    slot = pl.program_id(0)
+    layer, start = layer_ref[0], start_ref[slot]
+    sides = range(n)
+    planes = [side for side in sides if quant and side % 2]
+    values = [side for side in sides if side not in planes]
+
+    def tile_rows(j):
+        """(first, covered wholly, covered in part) for tile ``j``: the
+        index among the new rows of the tile's first row."""
+        first = j * rows - start % rows
+        whole = (first >= 0) & (first + rows <= T)
+        return first, whole, (first < T) & jnp.logical_not(whole)
+
+    def tile_in_pool(side, j):
+        at = slot * n_tiles + j
+        off = pl.multiple_of(tile_off_ref[at], rows)
+        return pools[side].at[layer, tile_phys_ref[at], :,
+                              pl.ds(off, rows), :]
+
+    def whole_tile(side, j):
+        return pltpu.make_async_copy(news[side].at[slot, j],
+                                     tile_in_pool(side, j), sem.at[side, 0])
+
+    def patch(piece, first, axis):
+        """Read the pool's pieces and the new ones, put the new rows
+        (index ``first + i`` within ``[0, T)``) over the old, write back.
+        ``piece``: side -> (the pool's piece, the new one)."""
+        def both(run):
+            for side, (old, new) in piece.items():
+                run(pltpu.make_async_copy(old, bufs[side].at[0],
+                                          sem.at[side, 1]))
+                run(pltpu.make_async_copy(new, bufs[side].at[1],
+                                          sem.at[side, 1]))
+        both(lambda c: c.start())
+        both(lambda c: c.wait())
+        for side in piece:
+            buf = bufs[side]
+            wide = _wide(buf.dtype)
+            old, new = buf[0].astype(wide), buf[1].astype(wide)
+            i = first + jax.lax.broadcasted_iota(jnp.int32, old.shape, axis)
+            buf[0] = jnp.where((i >= 0) & (i < T), new, old
+                               ).astype(buf.dtype)
+        back = [pltpu.make_async_copy(bufs[side].at[0], old, sem.at[side, 1])
+                for side, (old, _) in piece.items()]
+        for c in back:
+            c.start()
+        for c in back:
+            c.wait()
+
+    def each(count, step):
+        # Loops, not unrolled copies of the body: the kernel is traced and
+        # lowered once a prefill bucket, and set-up pays for its size.
+        jax.lax.fori_loop(0, count, lambda i, carry: (step(i), carry)[1], 0)
+
+    def send(j):
+        first, whole, part = tile_rows(j)
+
+        @pl.when(whole)
+        def _whole():
+            for side in values:
+                whole_tile(side, j).start()
+
+        @pl.when(part)
+        def _part():
+            patch({side: (tile_in_pool(side, j), news[side].at[slot, j])
+                   for side in values}, first, 1)
+
+    def plane(i):
+        first = i * page - start % page
+
+        @pl.when(first < T)
+        def _plane():
+            phys = page_phys_ref[slot * n_pages + i]
+            patch({side: (pools[side].at[layer, phys],
+                          news[side].at[slot, i]) for side in planes},
+                  first, 2)
+
+    def settle(j):
+        @pl.when(tile_rows(j)[1])
+        def _whole():
+            for side in values:
+                whole_tile(side, j).wait()
+
+    each(n_tiles, send)
+    if planes:
+        each(n_pages, plane)
+    each(n_tiles, settle)
+
+
+def paged_insert_chunk_in_place(pool_k, pool_v, k_new: jax.Array,
+                                v_new: jax.Array, page_table: jax.Array,
+                                lengths: jax.Array,
+                                active: jax.Array | None, *,
+                                layer: jax.Array | int = 0,
+                                interpret: bool | None = None):
+    """:func:`paged_insert_kv` on ONE layer of the stacked pool, as a
+    Pallas call whose pool operands ARE its outputs
+    (``input_output_aliases``): the prefill layer scan carries the pool
+    and a chunk's rows go into layer ``layer`` (a traced scalar) where
+    the pool lies. :func:`paged_insert_in_place` is general in ``T`` only
+    by one read-modify-write round a token; this moves whole tiles
+    (:func:`_paged_insert_chunk_kernel`).
+
+    pool_k/v: [L, P, KV, page, Dh] (or the int8 ``{"q","s"}`` dicts);
+    k_new/v_new: [K, T, KV, Dh]; page_table: [K, NP]; lengths: [K] — any
+    start, any ``T``. Same positions (:func:`_insert_positions`: inactive
+    rows, unmapped pages and positions past the table's reach land on
+    trash page 0) and the same bytes in the pool off the trash page:
+    quantisation stays outside, by ``quantize_kv``. Outside the kernel
+    too, in XLA on the chunk's own rows: each slot's rows are shifted to
+    where they sit in their first tile (first page, for the scales) and
+    laid head-major, so that the kernel copies tiles and never rows."""
+    quant = isinstance(pool_k, dict)
+    kq = pool_k["q"] if quant else pool_k
+    KV, page, Dh = kq.shape[2:]
+    K, T = k_new.shape[:2]
+    rows = min(_INSERT_TILE_ROWS, page)
+    if page % rows:
+        raise ValueError(f"page size {page} is not a multiple of {rows}")
+    n_tiles, n_pages = -(-T // rows) + 1, -(-T // page) + 1
+    phys, off = _insert_positions(page_table, lengths, active, T, page)
+    lengths = lengths.astype(jnp.int32)
+
+    def pieces(x, size, count):
+        """x [K, T, ...] -> [K, count, size, ...]: slot b's row t at row
+        ``lengths[b] % size + t`` of its ``count`` pieces, zeros around."""
+        blank = jnp.zeros((count * size, *x.shape[2:]), x.dtype)
+        return jnp.stack([
+            jax.lax.dynamic_update_slice_in_dim(blank, x[b],
+                                                lengths[b] % size, axis=0)
+            for b in range(K)]).reshape(K, count, size, *x.shape[2:])
+
+    def piece_of(x, size, count):
+        """x [K, T] of the rows -> [K * count] of the pieces (a piece's
+        rows share a page; a piece past the run is never looked at)."""
+        t = jnp.arange(count, dtype=jnp.int32)[None, :] * size \
+            - (lengths % size)[:, None]
+        return jnp.take_along_axis(x, jnp.clip(t, 0, T - 1),
+                                   axis=1).reshape(-1).astype(jnp.int32)
+
+    def tiles(x):                              # -> [K, n_tiles, KV, rows, Dh]
+        return pieces(x, rows, n_tiles).transpose(0, 1, 3, 2, 4)
+
+    def scale_planes(x):                       # -> [K, n_pages, KV, 1, page]
+        return pieces(x, page, n_pages
+                      ).transpose(0, 1, 3, 2)[:, :, :, None, :]
+
+    news, pools = _pool_sides(pool_k, pool_v, k_new, v_new)
+    news = tuple(tiles(x) if x.ndim == 4 else scale_planes(x) for x in news)
+    tile = pltpu.VMEM((2, KV, rows, Dh), kq.dtype)
+    plane = pltpu.VMEM((2, KV, 1, page), jnp.float32)
+    buffers = [tile, plane, tile, plane] if quant else [tile, tile]
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    scalars = (jnp.asarray(layer, jnp.int32).reshape(1), lengths,
+               piece_of(phys, rows, n_tiles),
+               piece_of(off, rows, n_tiles) // rows * rows,
+               piece_of(phys, page, n_pages))
+
+    out = pl.pallas_call(
+        functools.partial(_paged_insert_chunk_kernel, T=T, rows=rows,
+                          page=page, n_tiles=n_tiles, n_pages=n_pages,
+                          quant=quant),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=(K,),
+            in_specs=[hbm] * (len(news) + len(pools)),
+            out_specs=[hbm] * len(pools),
+            scratch_shapes=[*buffers,
+                            pltpu.SemaphoreType.DMA((len(pools), 2))],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype) for p in pools],
+        input_output_aliases={len(scalars) + len(news) + i: i
+                              for i in range(len(pools))},
+        interpret=_interpret_default() if interpret is None else interpret,
+    )(*scalars, *news, *pools)
+    return _pool_of(out)
 
 
 # ---------------------------------------------------------------------------
@@ -687,9 +907,11 @@ def paged_decode_attention(q: jax.Array, k_new: jax.Array,
 # Prefill kernel: q [B, T, H, Dh] vs pages, causal from per-slot start
 # ---------------------------------------------------------------------------
 
-def _paged_prefill_kernel(pt_ref, start_ref, q_ref, *refs,
+def _paged_prefill_kernel(pt_ref, start_ref, layer_ref, q_ref, *refs,
                           block_t: int, page: int, window: int = 0,
                           pages_per_block: int = 1):
+    # ``layer_ref`` is read by the K/V index maps alone: the blocks arrive
+    # with the layer dim squeezed, so the body is what it was.
     k_ref, ks_ref, v_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = \
         unpack_kv_refs(refs)
     b = pl.program_id(0)
@@ -743,15 +965,23 @@ def _paged_prefill_kernel(pt_ref, start_ref, q_ref, *refs,
 
 def paged_prefill_attention(q: jax.Array, k_pages, v_pages,
                             page_table: jax.Array,
-                            start: jax.Array, *, block_t: int = 128,
+                            start: jax.Array, *,
+                            layer: jax.Array | int = 0,
+                            block_t: int = 128,
                             window: int = 0,
                             pages_per_block: int = 1,
                             interpret: bool | None = None) -> jax.Array:
     """Causal chunk attention over the page pool (keys already inserted).
 
     q: [B, T, H, Dh] at absolute positions ``start + t``;
-    k_pages/v_pages: [P, KV, page, Dh] or the int8 ``{"q","s"}`` dicts;
-    page_table: [B, NP]; start: [B]. ``window``: sliding-window bound
+    k_pages/v_pages: the layer-stacked pool ``[L, P, KV, page, Dh]`` (or
+    the int8 ``{"q","s"}`` dicts) of which ``layer`` (a traced scalar: the
+    layer scan's index) is read WHERE IT LIES — one more prefetched
+    scalar, which the K/V and scale index maps return for a leading block
+    dimension of 1, so a scan over layers hands the kernel no slice of
+    the pool (:func:`paged_decode_attention` says what a slice costs). A
+    rank-4 side ``[P, KV, page, Dh]`` is one layer (a free reshape, layer
+    0). page_table: [B, NP]; start: [B]. ``window``: sliding-window bound
     (0 = full causal) — out-of-window pages skip compute and DMA.
     ``pages_per_block``: run of contiguous logical pages fetched per
     inner-loop step (same packed-table contract and bit-for-bit
@@ -760,11 +990,14 @@ def paged_prefill_attention(q: jax.Array, k_pages, v_pages,
     """
     B, T, H, Dh = q.shape
     quant = isinstance(k_pages, dict)
+    if (k_pages["q"] if quant else k_pages).ndim == 4:
+        k_pages, v_pages = jax.tree.map(lambda x: x[None],
+                                        (k_pages, v_pages))
     kq = k_pages["q"] if quant else k_pages
-    KV, page = kq.shape[1], kq.shape[2]
+    KV, page = kq.shape[2], kq.shape[3]
     NP = page_table.shape[1]
     ppb = pages_per_block
-    _check_pages_per_block(ppb, NP, kq.shape[0])
+    _check_pages_per_block(ppb, NP, kq.shape[1])
     bs = ppb * page
     G = H // KV
     block_t = min(block_t, T)
@@ -788,18 +1021,18 @@ def paged_prefill_attention(q: jax.Array, k_pages, v_pages,
         p0 = pt[b, g * ppb]
         return p0 // ppb if ppb > 1 else p0
 
-    def kv_index(b, h, t, j, pt, st):
+    def kv_index(b, h, t, j, pt, st, layer):
         first, last = _live_range(st[b], t)
-        return _phys_block(pt, b, jnp.clip(j, first, last)), h // G, 0, 0
+        return (layer[0], _phys_block(pt, b, jnp.clip(j, first, last)),
+                h // G, 0, 0)
 
-    def scale_index(b, h, t, j, pt, st):
-        first, last = _live_range(st[b], t)
-        return _phys_block(pt, b, jnp.clip(j, first, last)), h // G, 0, 0
+    def q_index(b, h, t, j, pt, st, layer):
+        return b, h, t, 0
 
-    # Stored rank-4 [P, KV, 1, page] scale layout — see
-    # paged_decode_attention.
-    kv_spec = pl.BlockSpec((ppb, 1, page, Dh), kv_index)
-    s_spec = pl.BlockSpec((ppb, 1, 1, page), scale_index)
+    # Stored rank-5 [L, P, KV, 1, page] scale layout — see
+    # paged_decode_attention. The layer dim is squeezed out of the blocks.
+    kv_spec = pl.BlockSpec((None, ppb, 1, page, Dh), kv_index)
+    s_spec = pl.BlockSpec((None, ppb, 1, 1, page), kv_index)
     if quant:
         kv_operands = (k_pages["q"], k_pages["s"],
                        v_pages["q"], v_pages["s"])
@@ -812,15 +1045,11 @@ def paged_prefill_attention(q: jax.Array, k_pages, v_pages,
         functools.partial(_paged_prefill_kernel, block_t=block_t, page=page,
                           window=window, pages_per_block=ppb),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, 1, block_t, Dh),
-                             lambda b, h, t, j, pt, st: (b, h, t, 0)),
-                *kv_specs,
-            ],
-            out_specs=pl.BlockSpec((1, 1, block_t, Dh),
-                                   lambda b, h, t, j, pt, st: (b, h, t, 0)),
+            in_specs=[pl.BlockSpec((1, 1, block_t, Dh), q_index),
+                      *kv_specs],
+            out_specs=pl.BlockSpec((1, 1, block_t, Dh), q_index),
             scratch_shapes=[
                 pltpu.VMEM((block_t, 128), jnp.float32),
                 pltpu.VMEM((block_t, 128), jnp.float32),
@@ -830,7 +1059,7 @@ def paged_prefill_attention(q: jax.Array, k_pages, v_pages,
         out_shape=jax.ShapeDtypeStruct((B, H, T, Dh), q.dtype),
         interpret=_interpret_default() if interpret is None else interpret,
     )(page_table.astype(jnp.int32), start.astype(jnp.int32),
-      qh, *kv_operands)
+      jnp.asarray(layer, jnp.int32).reshape(1), qh, *kv_operands)
     return out.transpose(0, 2, 1, 3).reshape(B, T, H * Dh)
 
 
@@ -896,12 +1125,14 @@ def _paged_reference_core(q, dense_k, dense_v, lengths, active, T,
 
 
 def pool_in_place(impl: str, mesh=None) -> bool:
-    """Whether :func:`make_paged_attention_fn` builds the decode path that
-    leaves the page pool where it lies — ``.decode_at`` reading the
-    stacked pool by layer and ``.insert_all`` writing it through aliased
-    operands. The kernels on one device do; the reference path gathers,
-    and under a mesh the pool's per-layer slices stay (the write kernel
-    has no shard_map wrapper yet)."""
+    """Whether :func:`make_paged_attention_fn` builds the paths that leave
+    the page pool where it lies — ``.decode_at`` and ``.prefill_at``
+    reading the stacked pool by layer, ``.insert_all`` and ``.prefill_at``
+    writing it through aliased operands. The kernels on one device do;
+    the reference path gathers, and under a mesh the pool's per-layer
+    slices stay (the write kernels have no shard_map wrapper yet). The
+    speculative provider's ``.verify`` defers its insert and keeps the
+    slices too: the forwards take it before ``.prefill_at``."""
     return impl == "pallas" and mesh is None
 
 
@@ -948,6 +1179,9 @@ def make_paged_attention_fn(page_table: jax.Array, max_seq: int,
             return {"q": val, "s": P(None, "model", None, None)}
         return val
 
+    def _block_t(T):
+        return block_t if block_t is not None else min(T & (-T), 128)
+
     def attention_fn(q, k_new, v_new, layer_k, layer_v, lengths, active=None):
         # Phase marker (ISSUE 8): trace-time metadata so captures name
         # the paged kernels inside the layer's attention scope.
@@ -972,7 +1206,7 @@ def make_paged_attention_fn(page_table: jax.Array, max_seq: int,
             return out, layer_k, layer_v
         shard = msize > 1 and KV % msize == 0 and H % msize == 0
         pool = _pool_spec(layer_k)
-        bt = block_t if block_t is not None else min(T & (-T), 128)
+        bt = _block_t(T)
         if shard:
             f = shard_map(
                 lambda q_, k_, v_, pt_, st_: paged_prefill_attention(
@@ -989,6 +1223,28 @@ def make_paged_attention_fn(page_table: jax.Array, max_seq: int,
                 block_t=bt, window=window,
                 pages_per_block=pages_per_block, interpret=interpret)
         return out, layer_k, layer_v
+
+    def prefill_at(q, k_new, v_new, pool_k, pool_v, layer, lengths,
+                   active=None):
+        """``attention_fn`` over the layer-STACKED pool, which the layer
+        scan carries: the chunk's rows are written into layer ``layer``
+        (the scan's traced index) where the pool lies, and the written
+        pool is attended at that index — insert-then-attend, the chunk's
+        own keys read back as the bytes that were written, so the pool
+        and every token are the sliced path's. Returns (attn, pool_k,
+        pool_v), the pool whole."""
+        # The write keeps the scope PR 30's carries, outside
+        # ``attention.paged_prefill``: ONE attention custom call a layer.
+        with jax.named_scope("kv.paged_insert"):
+            pool_k, pool_v = paged_insert_chunk_in_place(
+                pool_k, pool_v, k_new, v_new, page_table, lengths, active,
+                layer=layer, interpret=interpret)
+        with jax.named_scope("attention.paged_prefill"):
+            out = paged_prefill_attention(
+                q, pool_k, pool_v, page_table, lengths, layer=layer,
+                block_t=_block_t(q.shape[1]), window=window,
+                pages_per_block=pages_per_block, interpret=interpret)
+        return out, pool_k, pool_v
 
     def decode(q, k_new, v_new, layer_k, layer_v, lengths, active=None):
         """Deferred-decode: stale pool + self column, no insert."""
@@ -1082,6 +1338,7 @@ def make_paged_attention_fn(page_table: jax.Array, max_seq: int,
     attention_fn.insert_all = insert_all
     if in_place:
         attention_fn.decode_at = decode_at
+        attention_fn.prefill_at = prefill_at
     if spec:
         # Spec-only provider: a `.verify` on the SHARED provider would
         # reroute every prefill chunk (T > 1) through the deferred path;

@@ -172,6 +172,10 @@ SERVED = {
 }
 
 
+# Rows of a prefill call (``prefill_batch``) at those geometries.
+PREFILL_ROWS = {"mistral-7b": 1, "solar-open2-ep8": 4}
+
+
 def _stacked(chips, geometry):
     layers, pages, kv, heads, slots, width, window = SERVED[geometry]
     one = SingleDeviceSharding(chips[0])
@@ -201,6 +205,27 @@ def test_stacked_decode_compiles_at_the_served_geometry(chips, geometry):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
 
 
+@pytest.mark.parametrize("geometry", list(SERVED))
+def test_stacked_prefill_compiles_at_the_served_geometry(chips, geometry):
+    """PR 34: the prefill kernel on the whole layer-stacked int8 pool, a
+    512-token chunk a row, the layer a traced scalar: no slice of the pool
+    is among its operands (the transposes of q and of the output are the
+    only temporaries)."""
+    layers, pages, kv, heads, slots, width, window = SERVED[geometry]
+    sds, side, _ = _stacked(chips, geometry)
+    rows = PREFILL_ROWS[geometry]
+    compiled = jax.jit(
+        lambda q, pk, pv, tbl, start, layer: pa.paged_prefill_attention(
+            q, pk, pv, tbl, start, layer=layer, window=window,
+            interpret=False)).lower(
+        sds((rows, T, heads, DH), jnp.bfloat16), side, side,
+        sds((rows, width), jnp.int32), sds((rows,), jnp.int32),
+        sds((), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 4 * rows * T * heads * DH * 2
+
+
 @pytest.mark.parametrize("tokens", [1, 5], ids=["decode", "verify5"])
 @pytest.mark.parametrize("geometry", list(SERVED))
 def test_in_place_write_compiles_at_the_served_geometry(chips, geometry,
@@ -220,6 +245,33 @@ def test_in_place_write_compiles_at_the_served_geometry(chips, geometry,
     pool_bytes = 2 * layers * pages * kv * PAGE * (DH + 4)
     assert memory.alias_size_in_bytes == pool_bytes
     assert memory.temp_size_in_bytes < 2 ** 20
+
+
+@pytest.mark.parametrize("tokens", [8, 16, 96, 512])
+@pytest.mark.parametrize("geometry", list(SERVED))
+def test_chunk_write_compiles_at_the_served_geometry(chips, geometry, tokens):
+    """PR 34: the chunk write with the pool donated, at a traced layer's
+    index: its four pool operands are its outputs (all of the pool's bytes
+    aliased), and the temporaries are the chunk's own rows cut into tiles
+    — nothing of the pool's size."""
+    layers, pages, kv, heads, slots, width, window = SERVED[geometry]
+    sds, side, _ = _stacked(chips, geometry)
+    rows = PREFILL_ROWS[geometry]
+    new = sds((rows, tokens, kv, DH), jnp.bfloat16)
+    compiled = jax.jit(
+        lambda pk, pv, kn, vn, tbl, start, active, layer:
+        pa.paged_insert_chunk_in_place(pk, pv, kn, vn, tbl, start, active,
+                                       layer=layer, interpret=False),
+        donate_argnums=(0, 1)).lower(
+        side, side, new, new, sds((rows, width), jnp.int32),
+        sds((rows,), jnp.int32), sds((rows,), jnp.bool_),
+        sds((), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes \
+        == 2 * layers * pages * kv * PAGE * (DH + 4)
+    # Against a layer's side of 44 MB (Mistral) or 268 MB (the expert cell).
+    assert memory.temp_size_in_bytes < 16 * rows * (tokens + PAGE) * kv * DH
 
 
 def _loop_arrays(text: str, at_least: int) -> list[tuple[str, str, str]]:
@@ -272,32 +324,24 @@ def _loop_arrays(text: str, at_least: int) -> list[tuple[str, str, str]]:
     return found
 
 
-def test_decode_scan_leaves_the_pool_where_it_lies(chips, monkeypatch):
-    """Tentpole item 4 of PR 30, read from the compiled program: a
-    two-layer, two-step ``decode_scan`` of the ENGINE'S OWN step (its
-    ``_compile_paged`` on a stand-in that carries what it reads) at
-    Mistral-7B's widths, int8 weights and pool, compiled for the described
-    chip. Inside its loops nothing but the aliased write produces an array
-    the size of a layer's pool side; the carried pool has the default
-    layout; the temporaries are smaller than one layer's K + V. (At the
-    parent of PR 30 this fails three ways: a ``dynamic-slice`` fusion and
-    a ``copy_bitcast`` fusion a layer and side, two whole-pool scatter
-    fusions a step, the carried layout ``{4,2,3,1,0}``, 1.1 GB of
-    temporaries.) The pool is 513 pages — two layers of 169 would fit the
-    chip's 128 MiB of VMEM, where the compiler then parks the WHOLE pool
-    with a copy in and out a step: an artefact of a two-layer model."""
-    import re
+def _two_layer_engine(chips, monkeypatch, slots: int, pages: int,
+                      depth: int):
+    """The ENGINE'S OWN step programs (its ``_compile_paged`` on a stand-in
+    that carries what it reads) at Mistral-7B's widths, two layers, int8
+    weights and pool, with the shapes of what every program takes first
+    (params, cache, penalty counts, page table) placed on the described
+    chip. The pool is 513 pages — two layers of 169 would fit the chip's
+    128 MiB of VMEM, where the compiler then parks the WHOLE pool with a
+    copy in and out: an artefact of a two-layer model."""
     import types
     from dataclasses import replace
 
     from llmapigateway_tpu.engine.engine import InferenceEngine
-    from llmapigateway_tpu.engine.sampling import SamplingParams
     from llmapigateway_tpu.models import PRESETS
 
     # The kernels are chosen for the CPU backend the process runs on; the
     # program is compiled for the chip.
     monkeypatch.setattr(pa, "_interpret_default", lambda: False)
-    slots, pages, depth = 8, 513, 2
     config = replace(PRESETS["mistral-7b"], n_layers=2)
     mesh = build_mesh({}, devices=chips[:1])
     engine = types.SimpleNamespace(
@@ -313,38 +357,105 @@ def test_decode_scan_leaves_the_pool_where_it_lies(chips, monkeypatch):
     def shapes(tree):
         return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
             s.shape, s.dtype, sharding=placed), tree)
+    cache = shapes(jax.eval_shape(lambda: pa.PagedKVCache.create(
+        config, pages, PAGE, jnp.bfloat16, "int8")))
+    state = (shapes(jax.eval_shape(init, key)), cache,
+             jax.ShapeDtypeStruct((slots, config.vocab_size), jnp.int32,
+                                  sharding=placed),
+             jax.ShapeDtypeStruct((slots, 32), jnp.int32, sharding=placed))
+    return engine, config, state, placed
+
+
+def _holds_no_copy_of_the_pool(compiled, config, pages: int, write: str,
+                               attend: str) -> None:
+    """Inside the compiled program's loops nothing but the aliased write
+    (a custom call under ``kv.paged_insert`` whose outputs are its pool
+    operands, numbered from ``write``) produces an array the size of a
+    layer's pool side; the layer scan's body holds one attention kernel
+    under the scope ``attend``; the carried pool has the default layout;
+    the temporaries are smaller than one layer's K + V."""
+    import re
+    text = compiled.as_text()
+    side = pages * config.n_kv_heads * PAGE * DH          # int8: bytes
+    big = _loop_arrays(text, side)
+    writes = [ln for _, op, ln in big if op == "custom-call"
+              and "kv.paged_insert" in ln
+              and "output_to_operand_aliasing=" + write in ln]
+    assert len(writes) == 1, big
+    assert len(big) == 1, [(n, op) for n, op, _ in big]
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"[^\n]*"
+                          + re.escape(attend), text)) == 1
+    carried = re.findall(r"s8\[2,%d,8,256,128\]\{([\d,]+)" % pages, text)
+    assert carried and set(carried) == {"4,3,2,1,0"}, set(carried)
+    layer_kv = 2 * pages * config.n_kv_heads * PAGE * (DH + 4)
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_kv
+
+
+def test_decode_scan_leaves_the_pool_where_it_lies(chips, monkeypatch):
+    """Tentpole item 4 of PR 30, read from the compiled program: a
+    two-layer, two-step ``decode_scan`` of the ENGINE'S OWN step at
+    Mistral-7B's widths, int8 weights and pool, compiled for the described
+    chip. Inside its loops nothing but the aliased write produces an array
+    the size of a layer's pool side; the carried pool has the default
+    layout; the temporaries are smaller than one layer's K + V. (At the
+    parent of PR 30 this fails three ways: a ``dynamic-slice`` fusion and
+    a ``copy_bitcast`` fusion a layer and side, two whole-pool scatter
+    fusions a step, the carried layout ``{4,2,3,1,0}``, 1.1 GB of
+    temporaries.)"""
+    from llmapigateway_tpu.engine.sampling import SamplingParams
+
+    slots, pages, depth = 8, 513, 2
+    engine, config, state, placed = _two_layer_engine(
+        chips, monkeypatch, slots, pages, depth)
 
     def vec(dtype):
         return jax.ShapeDtypeStruct((slots,), dtype, sharding=placed)
-    cache = shapes(jax.eval_shape(lambda: pa.PagedKVCache.create(
-        config, pages, PAGE, jnp.bfloat16, "int8")))
     sampling = SamplingParams(
         temperature=vec(jnp.float32), top_p=vec(jnp.float32),
         top_k=vec(jnp.int32), presence_penalty=vec(jnp.float32),
         frequency_penalty=vec(jnp.float32))
     rng = jax.random.key(0)
     compiled = engine._decode_fns[True][1][depth].lower(
-        shapes(jax.eval_shape(init, key)), cache,
-        jax.ShapeDtypeStruct((slots, config.vocab_size), jnp.int32,
-                             sharding=placed),
-        jax.ShapeDtypeStruct((slots, 32), jnp.int32, sharding=placed),
-        vec(jnp.int32), vec(jnp.int32), vec(jnp.bool_), sampling,
+        *state, vec(jnp.int32), vec(jnp.int32), vec(jnp.bool_), sampling,
         jax.ShapeDtypeStruct(rng.shape, rng.dtype)).compile()
-
-    text = compiled.as_text()
-    side = pages * config.n_kv_heads * PAGE * DH          # int8: bytes
-    big = _loop_arrays(text, side)
-    writes = [ln for _, op, ln in big if op == "custom-call"
-              and "kv.paged_insert" in ln and "output_to_operand_aliasing="
-              "{{0}: (6, {}), {1}: (7, {}), {2}: (8, {}), {3}: (9, {})}"
-              in ln]
-    assert len(writes) == 1, big
-    assert len(big) == 1, [(n, op) for n, op, _ in big]
     # One decode kernel a layer a step under its scope: the layer scan's
     # body holds one, and the write is filed elsewhere.
-    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"[^\n]*"
-                          r"attention\.paged_decode", text)) == 1
-    carried = re.findall(r"s8\[2,%d,8,256,128\]\{([\d,]+)" % pages, text)
-    assert carried and set(carried) == {"4,3,2,1,0"}, set(carried)
-    layer_kv = 2 * pages * config.n_kv_heads * PAGE * (DH + 4)
-    assert compiled.memory_analysis().temp_size_in_bytes < layer_kv
+    _holds_no_copy_of_the_pool(
+        compiled, config, pages,
+        "{{0}: (6, {}), {1}: (7, {}), {2}: (8, {}), {3}: (9, {})}",
+        "attention.paged_decode")
+
+
+def test_prefill_step_leaves_the_pool_where_it_lies(chips, monkeypatch):
+    """PR 34, the twin of the test above for the chunk path: the engine's
+    own ``prefill_step`` at Mistral-7B's widths, two layers, int8 pool of
+    513 pages, one row of bucket 512, compiled for the described chip.
+    The stacked pool is the layer scan's CARRY: inside the loop nothing
+    but the aliased chunk write produces an array the size of a layer's
+    pool side, there is one attention kernel under
+    ``attention.paged_prefill``, the carried pool keeps the default
+    layout and the temporaries are under one layer's K + V. (At the
+    parent of PR 34 the scan is handed the pool's per-layer slices and
+    returns them as its ys, and this fails three ways: the loop holds no
+    aliased write and TEN arrays of a pool side or more — a layer and
+    side a ``constant_dynamic-slice`` fusion, a ``copy_bitcast`` fusion
+    into the scatter's layout ``{3,1,2,0}``, the scatter's fusion and a
+    ``copy`` back, then two ``copy_dynamic-update-slice`` fusions of the
+    whole stacked pool — and 1.69 GB of temporaries against the 0.28 GB
+    of a layer's K + V.)"""
+    slots, pages, bucket = 8, 513, 512
+    engine, config, state, placed = _two_layer_engine(
+        chips, monkeypatch, slots, pages, 2)
+    rng = jax.random.key(0)
+
+    def row(dtype, *shape):
+        return jax.ShapeDtypeStruct((1, *shape), dtype)
+    compiled = engine._prefill_fn.lower(
+        *state, row(jnp.int32, bucket), row(jnp.int32), row(jnp.int32),
+        row(jnp.int32), row(jnp.float32), row(jnp.float32),
+        row(jnp.int32), row(jnp.float32), row(jnp.float32),
+        jax.ShapeDtypeStruct(rng.shape, rng.dtype)).compile()
+    _holds_no_copy_of_the_pool(
+        compiled, config, pages,
+        "{{0}: (9, {}), {1}: (10, {}), {2}: (11, {}), {3}: (12, {})}",
+        "attention.paged_prefill")
