@@ -144,11 +144,11 @@ def load_checkpoint(model_dir: str | Path, config: ModelConfig,
     dict; each sub-leaf is then stacked and placed under ``path.key``.
     Default: cast to ``dtype``.
     """
-    if config.family == "hybrid":
+    if config.layer_period:
         raise ValueError(
-            "no checkpoint mapping for the 'hybrid' family: the published "
-            "tensor names of its linear-attention and expert layers are "
-            "not known here; it is served on seeded random weights")
+            f"no checkpoint mapping for the {config.family!r} family: the "
+            f"published tensor names of its attention and expert layers "
+            f"are not known here; it is served on seeded random weights")
     model_dir = Path(model_dir)
     shards = _discover_shards(model_dir)
     put = put or (lambda path, arr: jnp.asarray(arr))

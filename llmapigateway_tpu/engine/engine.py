@@ -319,7 +319,6 @@ class InferenceEngine:
         # the DEFAULT layout, small test/dev engines would otherwise carry
         # 256-token pages for 64-token contexts).
         self.kv_page = max(1, min(engine_cfg.kv_page_size, self.S))
-        self._swa_ring_pages = 0        # set by the paged+SWA init branch
         self._swa_margin = 0            # in-flight burst margin, tokens
         # Int8 weight quantization (models/quant.py): validated here so a
         # bad config fails at engine build (→ provider error → fallback),
@@ -344,6 +343,8 @@ class InferenceEngine:
 
         if model_cfg.n_lin_layers:
             self._refuse_for_recurrent_state()
+        if len(model_cfg.cache_groups) > 1:
+            self._refuse_for_cache_groups()
 
         self.tokenizer = load_tokenizer(
             engine_cfg.tokenizer_path or engine_cfg.model_path or None,
@@ -354,7 +355,7 @@ class InferenceEngine:
         self._enable_debug_nans()
         _enable_compilation_cache(engine_cfg.compilation_cache_dir)
 
-        self._moe_totals = [0, 0]       # survive a rebuild of the state
+        self._moe_totals = [0, 0, 0]    # survive a rebuild of the state
         t0 = time.monotonic()
         self._init_params()
         t1 = time.monotonic()
@@ -476,6 +477,49 @@ class InferenceEngine:
                 f"the {self.model_cfg.family!r} family does not support "
                 f"{why}")
 
+    def _refuse_for_cache_groups(self) -> None:
+        """What a family whose softmax layers fall into SEVERAL cache
+        groups (window and global layers in one model) cannot be served
+        with — refused at build, each with its reason."""
+        cfg, why = self.cfg, None
+        if not self.paged:
+            why = ("kv_layout 'contiguous': a dense cache keeps every "
+                   "layer's whole context; the groups are page pools")
+        elif cfg.prefix_cache:
+            why = ("prefix_cache: the ring re-targets a windowed group's "
+                   "pages, and a cached prefix would need the global "
+                   "group's pages AND the window's last tokens; set "
+                   "prefix_cache false")
+        elif self.spec_k:
+            why = ("spec_draft_len: the verify path reads one pool at one "
+                   "window")
+        elif self.mesh.size > 1:
+            why = (f"mesh {dict(self.mesh.shape)}: the page ring runs on "
+                   f"one device, and the experts have no sharding rule yet")
+        elif cfg.disaggregation.enabled:
+            why = ("disaggregation: a handoff cannot move a ring slot "
+                   "(PageAllocator.transfer)")
+        elif cfg.model_path:
+            why = ("model_path: no checkpoint mapping for this family "
+                   "(engine/checkpoint.py)")
+        if why:
+            raise ValueError(
+                f"the {self.model_cfg.family!r} family does not support "
+                f"{why}")
+
+    @property
+    def allocator(self):
+        """The first cache group's allocator: the only one wherever a
+        feature needs a single page table (prefix cache, disaggregation,
+        speculation — each refused beside several groups)."""
+        return self.kv_groups.groups[0].allocator
+
+    @property
+    def _swa_ring_pages(self) -> int:
+        """Pages a slot holds in the windowed group's ring (0: no ring)."""
+        return max((g.ring_pages for g in self.kv_groups), default=0) \
+            if self.paged else 0
+
     def _on_lifecycle_transition(self, frm: str, to: str,
                                  reason: str) -> None:
         """Supervisor transition hook: mirror the lifecycle edge into
@@ -560,7 +604,7 @@ class InferenceEngine:
         init = init_fn(c)
 
         def build(k):
-            if c.n_lin_layers:
+            if c.layer_period:
                 # Quantises each matrix where it is drawn: a period of
                 # this family is 6 GB in bf16 (models/hybrid.py).
                 return init(c, k, dtype=self.dtype, quant=self.quant)
@@ -594,28 +638,33 @@ class InferenceEngine:
 
             page = self.kv_page
             per_slot = (self.S + page - 1) // page
-            # Sliding-window RING reservation (one device):
-            # the windowed kernels never read below pos − window, so a
-            # ring of O(window) physical pages serves ANY context length —
-            # ensure_mapped recycles each slot's oldest dead page onto the
-            # next logical page (mistral's rolling buffer, at page
-            # granularity). Margins: in-flight lag-one bursts may still
-            # read one burst below the current floor, and dispatch writes
-            # run one burst/chunk ahead.
-            if c.sliding_window and self.mesh.size == 1:
-                # ONE copy of the margin: _swa_rotate's recycle floor
-                # must stay in lockstep with the capacity the ring was
-                # sized for, or rotation exhausts mid-stream.
-                self._swa_margin = self.decode_burst * (self.spec_k + 1)
-                span = max(self.prefill_chunk, self._swa_margin)
-                ring = -(-(c.sliding_window + self._swa_margin + span)
-                         // page) + 2
-                if ring < per_slot:
-                    self._swa_ring_pages = ring
-                    logger.info(
-                        "paged SWA ring: %d pages/slot (window %d) instead "
-                        "of %d — steady-state KV footprint is O(window)",
-                        ring, c.sliding_window, per_slot)
+            # Sliding-window RING reservation (one device), per WINDOWED
+            # cache group: the windowed kernels never read below pos −
+            # window, so a ring of O(window) physical pages serves ANY
+            # context length — ensure_mapped recycles each slot's oldest
+            # dead page onto the next logical page (mistral's rolling
+            # buffer, at page granularity). Margins: in-flight lag-one
+            # bursts may still read one burst below the current floor, and
+            # dispatch writes run one burst/chunk ahead. A global group
+            # holds the whole context.
+            # ONE copy of the margin: _swa_rotate's recycle floor must
+            # stay in lockstep with the capacity the ring was sized for,
+            # or rotation exhausts mid-stream.
+            self._swa_margin = self.decode_burst * (self.spec_k + 1)
+            span = max(self.prefill_chunk, self._swa_margin)
+
+            def ring_for(window: int) -> int:
+                if not window or self.mesh.size > 1:
+                    return 0
+                ring = -(-(window + self._swa_margin + span) // page) + 2
+                if ring >= per_slot:
+                    return 0
+                logger.info(
+                    "paged SWA ring: %d pages/slot (window %d) instead "
+                    "of %d — steady-state KV footprint is O(window)",
+                    ring, window, per_slot)
+                return ring
+            rings = [ring_for(w) for w, _ in c.cache_groups]
             # Multi-page kernel blocking (kv_pages_per_block): resolve the
             # requested run length against what the pool can actually
             # pack — the allocator's superpage runs are what license the
@@ -625,7 +674,7 @@ class InferenceEngine:
             ppb_req = max(1, self.cfg.kv_pages_per_block)
             if ppb_req > 1:
                 why = None
-                if self._swa_ring_pages:
+                if any(rings):
                     why = "SWA page ring (mappings rotate per page)"
                 elif per_slot % ppb_req:
                     why = (f"pages per slot ({per_slot}) not divisible "
@@ -643,26 +692,35 @@ class InferenceEngine:
             # One trash page; a PACKED pool reserves the whole trash
             # superpage instead.
             n_trash = self.kv_ppb
-            # The most pages one slot ever holds — the ring where it
-            # runs, else the whole context — sizes the derived pool: every
-            # slot can hold a max-footprint sequence at once either way.
-            min_hold = self._swa_ring_pages or per_slot
-            num_pages = self.cfg.kv_num_pages or (
-                self.B * min_hold + n_trash)
-            if num_pages - n_trash < min_hold:
-                raise ValueError(
-                    f"kv_num_pages={num_pages} cannot hold one "
-                    f"max-footprint sequence ({min_hold} pages of {page})")
-            self.allocator = PageAllocator(num_pages, page, self.B, self.S,
-                                           pages_per_block=self.kv_ppb)
+            from .paged import CacheGroup, CacheGroups
+            periods = c.n_layers // max(1, c.layer_period)
+            groups = []
+            for (window, positions), ring in zip(c.cache_groups, rings):
+                # The most pages one slot ever holds — the ring where it
+                # runs, else the whole context — sizes the derived pool:
+                # every slot can hold a max-footprint sequence at once
+                # either way. (kv_num_pages sizes every group's pool.)
+                min_hold = ring or per_slot
+                num_pages = self.cfg.kv_num_pages or (
+                    self.B * min_hold + n_trash)
+                if num_pages - n_trash < min_hold:
+                    raise ValueError(
+                        f"kv_num_pages={num_pages} cannot hold one "
+                        f"max-footprint sequence ({min_hold} pages of "
+                        f"{page})")
+                groups.append(CacheGroup(
+                    periods * len(positions), window, ring,
+                    PageAllocator(num_pages, page, self.B, self.S,
+                                  pages_per_block=self.kv_ppb)))
+            self.kv_groups = CacheGroups(groups)
+            num_pages = self.allocator.num_pages
             # Radix prefix cache (ISSUE 6): cross-request KV reuse over
             # the pool, block = one superpage run so the multi-page
             # kernels apply to shared pages unchanged. Gated to the
             # geometries where page identity is stable for a sequence's
             # lifetime: non-SWA (ring rotation re-targets pages; windowed
             # attention never re-reads old prefixes anyway).
-            if (self.cfg.prefix_cache and not self._swa_ring_pages
-                    and not c.sliding_window):
+            if self.cfg.prefix_cache and not c.sliding_window:
                 from .prefix_cache import RadixPrefixCache
                 self._prefix_cache = RadixPrefixCache(
                     self.allocator, block_tokens=self.kv_ppb * page)
@@ -678,27 +736,32 @@ class InferenceEngine:
             ssh = NamedSharding(
                 self.mesh, P(*psh.spec[:-2], None, psh.spec[-2]))
             side = {"q": psh, "s": ssh} if self.kv_quant else psh
-            if c.n_lin_layers:
-                # The pool holds the softmax layers only; beside it a
-                # fixed block of recurrent state and a conv tail per slot
+            if c.layer_period:
+                # A pool a cache group, of the softmax layers only;
+                # beside them a fixed block of recurrent state and a conv
+                # tail per slot for every linear layer of a period
                 # (models/hybrid.py HybridCache). A prefill that starts at
                 # position 0 starts from zero state whatever the block
                 # holds, so release, cancel and rebuild do no state work.
                 from ..models.hybrid import HybridCache
                 rep_sh = NamedSharding(self.mesh, P())
+                n_lin = c.layer_period - len(c.softmax_positions)
+                sides = (side,) * len(self.kv_groups)
                 self.cache = jax.jit(
-                    partial(HybridCache.create, c, num_pages, page, self.B,
-                            self.dtype, kv_quant=self.kv_quant),
+                    partial(HybridCache.create, c,
+                            tuple(g.allocator.num_pages
+                                  for g in self.kv_groups),
+                            page, self.B, self.dtype,
+                            kv_quant=self.kv_quant),
                     out_shardings=HybridCache(
-                        k=side, v=side, counters=rep_sh,
-                        state=(rep_sh,) * (c.layer_period - 1),
-                        conv=(rep_sh,) * (c.layer_period - 1)))()
+                        k=sides, v=sides, counters=rep_sh,
+                        state=(rep_sh,) * n_lin, conv=(rep_sh,) * n_lin))()
             else:
                 self.cache = jax.jit(
                     partial(PagedKVCache.create, c, num_pages, page,
                             self.dtype, kv_quant=self.kv_quant),
                     out_shardings=PagedKVCache(k=side, v=side))()
-            self._d_table = None
+            self._d_tables: tuple | None = None
             self._table_dirty = True
         else:
             csh = cache_sharding(self.mesh, c.n_kv_heads, self.B)
@@ -725,7 +788,7 @@ class InferenceEngine:
         # Routed assignments of the decode steps (hybrid family): the
         # device keeps wrapping int32 totals in the cache, every burst
         # hands them back beside its tokens, the host sums the deltas.
-        self._moe_seen = np.zeros((2,), np.int64)
+        self._moe_seen = np.zeros((3,), np.int64)
         # Host-authoritative per-slot state, mirrored to device each step.
         self.lengths = np.zeros((self.B,), np.int32)
         self.active = np.zeros((self.B,), bool)
@@ -1058,35 +1121,40 @@ class InferenceEngine:
 
         replicated = NamedSharding(self.mesh, P())
 
-        def call_forward(params, cache, table, tokens, lengths,
+        windows = [w for w, _ in c.cache_groups]
+
+        def call_forward(params, cache, tables, tokens, lengths,
                          active=None, spec=False, **rows):
+            # A provider a cache group, over the group's page table and
+            # at its window (static in every kernel call it makes); a
+            # family of one group is handed the provider itself.
             # `spec` builds the dedicated verify-capable provider:
             # T = k+1 then routes through the deferred paged verify
             # (stale-pool gather + mixed-precision self-block) instead
             # of the chunk path — required for int8 greedy parity and
             # skips the per-layer pool scatters either way.
-            attn = make_paged_attention_fn(table, max_seq=S, impl=impl,
-                                           mesh=mesh,
-                                           window=c.sliding_window,
-                                           pages_per_block=self.kv_ppb,
-                                           spec=spec)
+            attn = tuple(make_paged_attention_fn(
+                table, max_seq=S, impl=impl, mesh=mesh, window=window,
+                pages_per_block=self.kv_ppb, spec=spec)
+                for table, window in zip(tables, windows))
             return family_forward(params, c, tokens, lengths, cache,
-                                  active=active, attention_fn=attn,
-                                  **rows)
+                                  active=active,
+                                  attention_fn=(attn if len(attn) > 1
+                                                else attn[0]), **rows)
 
         def engine_cache(cache):
             """The forward's cache as the type the engine holds (a family
             with state of its own returns its own type whole)."""
-            return cache if c.n_lin_layers else PagedKVCache(
+            return cache if c.layer_period else PagedKVCache(
                 k=cache.k, v=cache.v)
 
-        # A family with per-slot state is told which slot each prefill row
-        # is and how many of its tokens are real (the rest pad the bucket).
-        rows_known = c.n_lin_layers > 0
+        # A period family is told which slot each prefill row is and how
+        # many of its tokens are real (the rest pad the bucket).
+        rows_known = c.layer_period > 0
 
         @partial(jax.jit, donate_argnums=(1, 2))
         def prefill_step(params, cache: PagedKVCache, counts: jax.Array,
-                         table: jax.Array,
+                         tables: tuple[jax.Array, ...],
                          tokens: jax.Array, start_len: jax.Array,
                          slots: jax.Array, last_idx: jax.Array,
                          samp_t: jax.Array, samp_p: jax.Array,
@@ -1100,9 +1168,9 @@ class InferenceEngine:
             K rows are sliced unrolled (same GSPMD-partitioned op as the
             K=1 path)."""
             K = tokens.shape[0]
-            rows_tbl = jnp.concatenate(
+            rows_tbl = tuple(jnp.concatenate(
                 [jax.lax.dynamic_slice_in_dim(table, slots[k], 1, axis=0)
-                 for k in range(K)], axis=0)
+                 for k in range(K)], axis=0) for table in tables)
             logits, cache = call_forward(
                 params, cache, rows_tbl, tokens, start_len,
                 **({"slots": slots, "n_valid": last_idx + 1}
@@ -1122,7 +1190,7 @@ class InferenceEngine:
             return first, counts, engine_cache(cache)
 
         def one_step(params, cache: PagedKVCache, counts: jax.Array,
-                     table: jax.Array,
+                     tables: tuple[jax.Array, ...],
                      tokens: jax.Array, lengths: jax.Array,
                      active: jax.Array, samp: SamplingParams,
                      key: jax.Array, *, greedy: bool = False):
@@ -1134,7 +1202,7 @@ class InferenceEngine:
             if not greedy:
                 counts = counts.at[jnp.arange(counts.shape[0]),
                                    tokens].add(active.astype(jnp.int32))
-            logits, cache = call_forward(params, cache, table,
+            logits, cache = call_forward(params, cache, tables,
                                          tokens[:, None], lengths,
                                          active=active)
             with jax.named_scope("sampling"):
@@ -1151,12 +1219,12 @@ class InferenceEngine:
 
         self._prefill_fn = prefill_step
         self._decode_fns = _decode_programs(one_step, self._burst_depths,
-                                            counters=c.n_lin_layers > 0)
+                                            counters=c.layer_period > 0)
 
         if self.spec_k:
             from .speculative import make_spec_burst, make_spec_step
 
-            def make_fwd(tbl):
+            def make_fwd(tbl):          # the tables, a tuple over groups
                 def fwd(params, c_, tokens, lengths, cache, active=None):
                     return call_forward(params, cache, tbl, tokens,
                                         lengths, active=active, spec=True)
@@ -1200,9 +1268,9 @@ class InferenceEngine:
             return jax.ShapeDtypeStruct(
                 x.shape, x.dtype, sharding=getattr(x, "sharding", None))
         rep = NamedSharding(self.mesh, P())
-        table_a = (jax.ShapeDtypeStruct(
-            self.allocator.table.shape, jnp.int32, sharding=rep),
-        ) if self.paged else ()
+        table_a = (tuple(jax.ShapeDtypeStruct(
+            g.allocator.table.shape, jnp.int32, sharding=rep)
+            for g in self.kv_groups),) if self.paged else ()
         return ((jax.tree.map(aval, self.params),
                  jax.tree.map(aval, self.cache),
                  aval(self._d_counts), *table_a),
@@ -1259,11 +1327,21 @@ class InferenceEngine:
         return jax.device_put(np.array(host),
                               NamedSharding(self.mesh, P()))
 
-    def _device_table(self) -> jax.Array:
-        if self._table_dirty or self._d_table is None:
-            self._d_table = self._upload(self.allocator.table)
-            self._table_dirty = False
-        return self._d_table
+    def _device_tables(self) -> tuple[jax.Array, ...]:
+        """The device page tables, one a cache group: a group's table is
+        uploaded again where it changed (``_table_dirty``: every group's,
+        by admission, release or handoff; ``CacheGroup.dirty``: its own,
+        by ring rotation too)."""
+        old = self._d_tables or (None,) * len(self.kv_groups)
+        new = []
+        for g, table in zip(self.kv_groups, old):
+            if self._table_dirty or g.dirty or table is None:
+                table = self._upload(g.allocator.table)
+                g.dirty = False
+            new.append(table)
+        self._d_tables = tuple(new)
+        self._table_dirty = False
+        return self._d_tables
 
     def _pick_attention(self):
         """Dense-cache attention_fn for the resolved impl ("reference" →
@@ -2145,26 +2223,23 @@ class InferenceEngine:
                         req.prompt_ids)
                     req.prefix_lookup_ms = 1000.0 * (time.monotonic()
                                                      - t_lk)
-                ok = self.allocator.can_admit(
-                    total, ring_pages=self._swa_ring_pages,
-                    shared_pages=len(shared_pages))
+                ok = self.kv_groups.can_admit(
+                    total, shared_pages=len(shared_pages))
                 if not ok and cache is not None:
                     # Page pressure: reclaim cold cache entries (LRU
                     # leaves; pinned blocks are untouchable) before
                     # parking the head — the admission-side half of the
                     # overload/Retry-After machinery.
-                    short = self.allocator.fresh_shortfall(
-                        total, ring_pages=self._swa_ring_pages,
-                        shared_pages=len(shared_pages))
+                    short = self.kv_groups.fresh_shortfall(
+                        total, shared_pages=len(shared_pages))
                     evicted = cache.evict(short) if short > 0 else 0
                     if evicted > 0:
                         if fl is not None:
                             from ..obs.flight import EVICT
                             fl.record(EVICT, val=float(evicted),
                                       free_pages=self.allocator.free_pages)
-                        ok = self.allocator.can_admit(
-                            total, ring_pages=self._swa_ring_pages,
-                            shared_pages=len(shared_pages))
+                        ok = self.kv_groups.can_admit(
+                            total, shared_pages=len(shared_pages))
                 if not ok:
                     if cache is not None:
                         cache.release_nodes(nodes)
@@ -2225,10 +2300,8 @@ class InferenceEngine:
                 self._spec_slot_proposed[req.slot] = 0
                 self._spec_slot_accepted[req.slot] = 0
             if self.paged:
-                self.allocator.allocate(req.slot, total,
-                                        ring_pages=self._swa_ring_pages,
+                self.kv_groups.allocate(req.slot, total,
                                         shared_pages=shared_pages)
-                self._table_dirty = True
                 if self._prefix_cache is not None:
                     self._prefix_cache.record_lookup(matched)
                     req.cached_tokens = matched
@@ -2299,12 +2372,7 @@ class InferenceEngine:
                 # below the chunk's window floor (no in-flight margin: a
                 # prefilling slot has no decode burst of its own in flight,
                 # and cross-slot bursts touch only their own table rows).
-                page = self.allocator.page_size
-                dead = max(0, pos - self.model_cfg.sliding_window + 1) \
-                    // page
-                if self.allocator.ensure_mapped(
-                        slot, (pos + len(chunk) - 1) // page, dead):
-                    self._table_dirty = True
+                self.kv_groups.rotate(slot, pos + len(chunk) - 1, pos)
             if self.fault_plan:
                 self.fault_plan.on_prefill()
             self._spec_hist_chunk(slot, pos, chunk)
@@ -2377,7 +2445,7 @@ class InferenceEngine:
         padded = np.zeros((K, bucket), np.int32)
         for i, ch in enumerate(chunks):
             padded[i, :len(ch)] = ch
-        table = (self._device_table(),) if self.paged else ()
+        table = (self._device_tables(),) if self.paged else ()
         if key is None:
             key = _DUMMY_KEY()
         args = (self.params, self.cache, self._d_counts, *table, padded,
@@ -2470,7 +2538,7 @@ class InferenceEngine:
 
         d_ok = self._spec_draft_ok(probe)
         d_ok_dev = self._upload(d_ok)
-        table = (self._device_table(),) if self.paged else ()
+        table = (self._device_tables(),) if self.paged else ()
         if n_steps == self._spec_scan_len:
             t0 = time.monotonic()
             args = (self.params, self.cache, *table, self._d_hist,
@@ -2785,16 +2853,10 @@ class InferenceEngine:
         of margin — an undelivered lag-one burst may still read near its
         own, older floor. Runs on the event-loop thread (same as
         admission), before the worker-thread dispatch reads the table."""
-        page = self.allocator.page_size
-        w = self.model_cfg.sliding_window
-        changed = False
         for r in decoding:
             pos = int(self.lengths[r.slot]) + inflight
-            dead = max(0, pos - self._swa_margin - w + 1) // page
-            changed |= self.allocator.ensure_mapped(
-                r.slot, (pos + advance) // page, dead)
-        if changed:
-            self._table_dirty = True
+            self.kv_groups.rotate(r.slot, pos + advance,
+                                  pos - self._swa_margin)
 
     def _all_greedy(self) -> bool:
         """True when every ACTIVE slot is plain-greedy: temperature 0 and
@@ -2907,7 +2969,7 @@ class InferenceEngine:
             self._upload_slot_state()
             self._d_dirty = False
 
-        table = (self._device_table(),) if self.paged else ()
+        table = (self._device_tables(),) if self.paged else ()
         # Greedy fast path: when every active slot decodes at temperature 0
         # with zero penalties (the common case), run the argmax-only
         # program — the general sampler's full-vocab sort costs
@@ -3141,8 +3203,7 @@ class InferenceEngine:
             self._slot_epoch[req.slot] += 1
             self._d_dirty = True
             if self.paged:
-                self.allocator.release(req.slot)
-                self._table_dirty = True
+                self.kv_groups.release(req.slot)
 
     def _handoff(self, req: GenRequest) -> None:
         """Promote a just-completed prefill into the decode pool
@@ -3211,8 +3272,13 @@ class InferenceEngine:
         roofline model — achieved GB/s = (weights + this) / step time."""
         c = self.model_cfg
         live = self.lengths[self.active].astype(np.int64)
-        if c.sliding_window:
-            live = np.minimum(live, c.sliding_window)
+        # Token reads summed over the layers of every cache group, each
+        # clamped to the group's window.
+        periods = c.n_kv_layers // len(c.softmax_positions)
+        reads = sum(
+            periods * len(ps)
+            * int((np.minimum(live, w) if w else live).sum())
+            for w, ps in c.cache_groups)
         if self.kv_quant:
             elem = 1.0 + 4.0 / c.head_dim
         else:
@@ -3220,8 +3286,7 @@ class InferenceEngine:
             # loop and must not even look like a device sync (graftlint v2
             # chases this call from the async stats handlers).
             elem = float(np.dtype(self.dtype).itemsize)
-        return int(2 * c.n_kv_layers * c.n_kv_heads * c.head_dim * elem
-                   * int(live.sum()))
+        return int(2 * c.n_kv_heads * c.head_dim * elem * reads)
 
     def _state_bytes(self) -> int:
         """Bytes of recurrent state and conv tails resident beside the KV
@@ -3249,19 +3314,23 @@ class InferenceEngine:
         else:
             kv_elem, kv_scale = int(np.dtype(self.dtype).itemsize), 0
         page = self.kv_page
+        token_bytes = 2 * c.n_kv_heads * (c.head_dim * kv_elem + kv_scale)
+        kv_pools: dict[str, int] = {}
         if self.paged:
-            tokens = self.allocator.num_pages * page
-            page_bytes = 2 * c.n_kv_layers * c.n_kv_heads * page * (
-                c.head_dim * kv_elem + kv_scale)
+            for g in self.kv_groups:
+                name = f"window{g.window}" if g.window else "global"
+                kv_pools[name] = (g.layers * g.allocator.num_pages * page
+                                  * token_bytes)
+            kv_pool = sum(kv_pools.values())
+            page_bytes = self.kv_groups.groups[0].layers * page * token_bytes
         else:
-            tokens = self.B * self.S
+            kv_pool = c.n_kv_layers * self.B * self.S * token_bytes
             page_bytes = 0
-        kv_pool = 2 * c.n_kv_layers * c.n_kv_heads * tokens * (
-            c.head_dim * kv_elem + kv_scale)
         aux = self.B * c.vocab_size * 4          # penalty counts [B, V]
         aux += self._state_bytes()               # recurrent state, conv tails
-        if self.paged:
-            aux += int(self.allocator.table.size) * 4   # device page table
+        if self.paged:                           # device page tables
+            aux += sum(int(g.allocator.table.size)
+                       for g in self.kv_groups) * 4
         spec = self.B * self.S * 4 if self.spec_k else 0  # device hist
 
         def tracked() -> int:
@@ -3274,7 +3343,7 @@ class InferenceEngine:
                             else leaf.dtype.itemsize)
                 total += int(np.prod(leaf.shape) * itemsize)
             for extra in (self._d_counts, getattr(self, "_d_hist", None),
-                          self._d_table if self.paged else None):
+                          *((self._d_tables or ()) if self.paged else ())):
                 if extra is not None:
                     total += int(np.prod(extra.shape)
                                  * extra.dtype.itemsize)
@@ -3292,7 +3361,8 @@ class InferenceEngine:
             local = None
         return HbmLedger(
             weights=self._resident_param_bytes(), kv_pool=kv_pool,
-            aux=aux, spec=spec, page_bytes=page_bytes, tracked_fn=tracked,
+            aux=aux, spec=spec, page_bytes=page_bytes, kv_pools=kv_pools,
+            tracked_fn=tracked,
             mem_fn=lambda: device_memory_stats(local))
 
     def kernel_table(self) -> list[dict[str, Any]]:
@@ -3344,6 +3414,12 @@ class InferenceEngine:
             out["total_pages"] = (self.allocator.num_pages
                                   - self.allocator.pages_per_block)
             out["page_size"] = self.allocator.page_size
+            # The cache groups (engine/paged.py): one pool, page table
+            # and allocator a group of layers that keep the same KV; the
+            # pages the rings re-targeted, monotone.
+            out["kv_groups"] = [g.stats() for g in self.kv_groups]
+            out["kv_ring_recycled_total"] = sum(
+                g.recycled for g in self.kv_groups)
             if self.kv_ppb > 1:
                 out["pages_per_block"] = self.kv_ppb
             if self._prefix_cache is not None:
@@ -3354,15 +3430,20 @@ class InferenceEngine:
                 # prefill from them (not from wall clock).
                 out.update(self._prefix_cache.stats())
         if self.model_cfg.n_lin_layers:
-            # Recurrent state beside the pool, and the expert layer's
-            # share: the assignments the decode steps routed, and those
-            # that landed on an expert held here (their ratio is the
-            # share of the experts held when routing is even).
+            # Recurrent state beside the pool.
             out["state_bytes_resident"] = self._state_bytes()
             out["state_slots"] = self.B
+        if self.model_cfg.layer_period:
+            # The expert layer's share: the assignments the decode steps
+            # routed, those that landed on an expert held here (their
+            # ratio is the share of the experts held when routing is
+            # even), and over layers and steps the held experts that at
+            # least one active row was assigned to (the experts a step
+            # has to stream).
             out["moe_experts_held"] = self.model_cfg.experts_held
             out["moe_assignments_total"] = self._moe_totals[0]
             out["moe_assignments_local_total"] = self._moe_totals[1]
+            out["moe_experts_hit_total"] = self._moe_totals[2]
         gauge = (self._ema_step_ms_stats
                  if self._ema_step_ms_stats is not None
                  else self._step_ms_estimate())

@@ -213,23 +213,23 @@ class PageAllocator:
                 self._free.append(g)
 
     def ensure_mapped(self, slot: int, last_logical: int,
-                      dead_before: int) -> bool:
+                      dead_before: int) -> int:
         """Ring-mode slots: extend the mapping through ``last_logical`` by
         recycling the slot's OLDEST mapped pages, which must lie strictly
         below ``dead_before`` (logical pages wholly below the attention
         window's floor — the windowed kernels' index-map clamp guarantees
         they are never read again, and the recycled page's stale contents
         are fully overwritten as positions advance through it). Returns
-        True when the table row changed (callers flip the device-table
-        dirty bit). No-op for whole-lifetime slots."""
+        the number of pages re-targeted: non-zero when the table row
+        changed (callers flip the device-table dirty bit). No-op for
+        whole-lifetime slots."""
         if slot not in self._ring_slots:
-            return False
+            return 0
         row = self.table[slot]
         last_logical = min(last_logical, self.pages_per_slot - 1)
         nz = np.nonzero(row)[0]
         hi = int(nz[-1])
         oldest_i = 0
-        changed = False
         for j in range(hi + 1, last_logical + 1):
             old = int(nz[oldest_i])
             if old >= dead_before:
@@ -241,8 +241,7 @@ class PageAllocator:
             row[j] = row[old]
             row[old] = 0
             oldest_i += 1
-            changed = True
-        return changed
+        return oldest_i
 
     def transfer(self, src_slot: int, dst_slot: int) -> list[int]:
         """Move ``src_slot``'s entire holding to ``dst_slot`` — the
@@ -327,3 +326,99 @@ class PageAllocator:
                 continue
             assert list(row[:len(pages)]) == pages, "table/holding mismatch"
             assert (row[len(pages):] == 0).all()
+
+
+class CacheGroup:
+    """The softmax layers of a model that keep the same KV: how many they
+    are (``layers``, stacked in ONE page pool), their window (0: the whole
+    context), the pages a slot holds when the ring runs (``ring_pages``,
+    0: the whole context's), their allocator with its host page table,
+    and whether that table changed since its last upload (``dirty``)."""
+
+    def __init__(self, layers: int, window: int, ring_pages: int,
+                 allocator: PageAllocator):
+        self.layers = layers
+        self.window = window
+        self.ring_pages = ring_pages
+        self.allocator = allocator
+        self.recycled = 0           # pages ensure_mapped re-targeted
+        self.dirty = True
+
+    @property
+    def pages_per_slot(self) -> int:
+        """The most pages one slot ever holds here."""
+        return self.ring_pages or self.allocator.pages_per_slot
+
+    def stats(self) -> dict[str, int]:
+        a = self.allocator
+        return {"layers": self.layers, "window": self.window,
+                "pages": a.num_pages - a.pages_per_block,
+                "pages_free": a.free_pages,
+                "pages_per_slot": self.pages_per_slot}
+
+
+class CacheGroups:
+    """Every cache group of an engine behind the admission calls of ONE
+    allocator: a request is admitted into every group or into none, and
+    leaves them all. Mistral is one windowed group, a model without a
+    window one global group, a model that mixes both has one of each
+    (``ModelConfig.cache_groups``). ``shared_pages`` (a prefix-cache hit)
+    belong to the first group: the engine builds no prefix cache beside
+    several groups."""
+
+    def __init__(self, groups: list[CacheGroup]):
+        self.groups = list(groups)
+
+    def __iter__(self):
+        return iter(self.groups)
+
+    def __len__(self) -> int:
+        return len(self.groups)
+
+    def can_admit(self, total_tokens: int, shared_pages: int = 0) -> bool:
+        return all(g.allocator.can_admit(total_tokens, g.ring_pages,
+                                         shared_pages if i == 0 else 0)
+                   for i, g in enumerate(self.groups))
+
+    def fresh_shortfall(self, total_tokens: int,
+                        shared_pages: int = 0) -> int:
+        return max(g.allocator.fresh_shortfall(
+            total_tokens, g.ring_pages, shared_pages if i == 0 else 0)
+            for i, g in enumerate(self.groups))
+
+    def allocate(self, slot: int, total_tokens: int,
+                 shared_pages: Iterable[int] = ()) -> bool:
+        """Reserve the slot's pages in every group, or in none."""
+        shared = list(shared_pages)
+        if not self.can_admit(total_tokens, len(shared)):
+            return False
+        for i, g in enumerate(self.groups):
+            g.allocator.allocate(slot, total_tokens, g.ring_pages,
+                                 shared if i == 0 else ())
+            g.dirty = True
+        return True
+
+    def release(self, slot: int) -> None:
+        for g in self.groups:
+            g.allocator.release(slot)
+            g.dirty = True
+
+    def rotate(self, slot: int, last_pos: int, floor_pos: int) -> None:
+        """WINDOWED groups only: map the slot's pages through position
+        ``last_pos`` by recycling its pages wholly below the window of
+        position ``floor_pos`` (``PageAllocator.ensure_mapped``). A global
+        group's table is never touched."""
+        for g in self.groups:
+            if not g.ring_pages:
+                continue
+            page = g.allocator.page_size
+            n = g.allocator.ensure_mapped(
+                slot, last_pos // page,
+                max(0, floor_pos - g.window + 1) // page)
+            if n:
+                g.recycled += n
+                g.dirty = True
+
+    def check_invariants(self) -> None:
+        for g in self.groups:
+            g.allocator.check_invariants()

@@ -6,7 +6,7 @@ from .config import ModelConfig, PRESETS, get_preset
 def forward_fn(config: ModelConfig):
     """The forward callable for a family, uniform signature:
     (params, config, tokens, lengths, cache, active=None) → (logits, cache)."""
-    if config.family == "hybrid":
+    if config.layer_period:         # the period families
         from . import hybrid
         return hybrid.forward
     if config.is_moe:
@@ -18,7 +18,7 @@ def forward_fn(config: ModelConfig):
 
 def init_fn(config: ModelConfig):
     """Random-init callable for a family: (config, key, dtype) → params."""
-    if config.family == "hybrid":
+    if config.layer_period:
         from . import hybrid
         return hybrid.init_params
     if config.is_moe:

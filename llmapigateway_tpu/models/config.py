@@ -34,7 +34,8 @@ class RopeScaling:
 
 @dataclass(frozen=True)
 class ModelConfig:
-    family: str = "llama"   # "llama" | "qwen2" | "gemma" | "mixtral" | "hybrid"
+    # "llama" | "qwen2" | "gemma" | "mixtral" | "hybrid" | "smallthinker"
+    family: str = "llama"
     vocab_size: int = 32000
     d_model: int = 2048
     n_layers: int = 22
@@ -82,6 +83,31 @@ class ModelConfig:
     lin_gate_rank: int = 0         # rank of the decay and output gate pairs
     use_rope: bool = True          # False: no rotary on the softmax layers
     attn_gate: bool = False        # softmax output gated by sigmoid(W x)
+    # The layer pattern as data, per position of a period (empty: every
+    # softmax layer alike, as ``sliding_window`` and ``use_rope`` say).
+    # Without ``lin_heads`` every position of a period is a softmax layer.
+    window_layout: tuple[int, ...] = ()   # 1: attends inside sliding_window
+    rope_layout: tuple[int, ...] = ()     # 1: rotary on q and k
+    # The period families' expert layer: how the router scores ("sigmoid":
+    # top-k of the sigmoids, normalised; "softmax": top-k of the logits,
+    # softmax over the selected), the experts' gate activation, and whether
+    # the router reads the block's input (before attention, un-normalised)
+    # instead of the MLP's own normalised input.
+    moe_router: str = "sigmoid"
+    moe_act: str = "silu"                 # "silu" | "relu"
+    router_reads_block_input: bool = False
+
+    def __post_init__(self):
+        for name in ("window_layout", "rope_layout"):
+            layout = getattr(self, name)
+            if layout and len(layout) != max(1, self.layer_period):
+                raise ValueError(f"{name} {layout} is not one entry a "
+                                 f"position of a period of "
+                                 f"{max(1, self.layer_period)}")
+        if self.moe_router not in ("sigmoid", "softmax"):
+            raise ValueError(f"unknown moe_router {self.moe_router!r}")
+        if self.moe_act not in ("silu", "relu"):
+            raise ValueError(f"unknown moe_act {self.moe_act!r}")
 
     @property
     def head_dim(self) -> int:
@@ -96,9 +122,44 @@ class ModelConfig:
         return self.n_experts_held or self.n_experts
 
     @property
+    def softmax_positions(self) -> tuple[int, ...]:
+        """The positions of a period that are softmax layers (they keep
+        paged KV): position 0 where the others are linear-attention
+        layers, else every one."""
+        if self.layer_period and not self.lin_heads:
+            return tuple(range(self.layer_period))
+        return (0,)
+
+    def window_at(self, position: int) -> int:
+        """The window of the softmax layer at ``position`` of a period
+        (0: the whole context)."""
+        if self.window_layout and not self.window_layout[position]:
+            return 0
+        return self.sliding_window
+
+    def rope_at(self, position: int) -> bool:
+        return bool(self.rope_layout[position] if self.rope_layout
+                    else self.use_rope)
+
+    @property
+    def cache_groups(self) -> tuple[tuple[int, tuple[int, ...]], ...]:
+        """The softmax layers by the KV they must keep: (window, positions
+        of a period) for each distinct window, in order of first position.
+        A group's layers share one page pool, one page table a slot and
+        one allocator (engine/paged.py CacheGroups): a windowed group may
+        recycle a slot's pages below the window, a global one (window 0)
+        keeps the whole context."""
+        groups: dict[int, list[int]] = {}
+        for p in self.softmax_positions:
+            groups.setdefault(self.window_at(p), []).append(p)
+        return tuple((w, tuple(ps)) for w, ps in groups.items())
+
+    @property
     def n_kv_layers(self) -> int:
-        """Layers that keep paged KV: all, or one per period."""
-        return (self.n_layers // self.layer_period if self.layer_period
+        """Layers that keep paged KV: all, or the softmax positions of
+        every period."""
+        return (self.n_layers // self.layer_period
+                * len(self.softmax_positions) if self.layer_period
                 else self.n_layers)
 
     @property
@@ -214,6 +275,24 @@ PRESETS: dict[str, ModelConfig] = {
         n_shared_experts=1, layer_period=4, lin_heads=4, lin_head_dim=16,
         lin_conv_taps=4, lin_gate_rank=8, use_rope=False, attn_gate=True),
 }
+# SmallThinker-21BA3B-Instruct (HF: PowerInfer/SmallThinker-21BA3B-Instruct)
+# at its PUBLISHED sizes: 52 layers in periods of 4 — a global layer without
+# rotary embedding, then three rotary layers inside a 4096 window — each
+# followed by 64 ReLU-gated experts of width 768, top-6 by a softmax router
+# that reads the block's input. Two cache groups (ModelConfig.cache_groups).
+PRESETS["smallthinker-21b"] = ModelConfig(
+    family="smallthinker", vocab_size=151936, d_model=2560, n_layers=52,
+    n_heads=28, n_kv_heads=4, head_dim_override=128, d_ff=768,
+    rope_theta=1500000.0, rms_eps=1e-6, max_seq_len=16384,
+    sliding_window=4096, n_experts=64, experts_per_token=6, d_ff_expert=768,
+    layer_period=4, window_layout=(0, 1, 1, 1), rope_layout=(0, 1, 1, 1),
+    moe_router="softmax", moe_act="relu", router_reads_block_input=True)
+# The same pattern at CPU-test size: two periods, 8 experts, top-3.
+PRESETS["tiny-smallthinker-test"] = replace(
+    PRESETS["smallthinker-21b"], vocab_size=512, d_model=64, n_layers=8,
+    n_heads=4, n_kv_heads=2, head_dim_override=16, d_ff=32,
+    rope_theta=10000.0, max_seq_len=256, sliding_window=16, n_experts=8,
+    experts_per_token=3, d_ff_expert=32)
 # What ONE v5e chip holds of it as one of 8 that share each layer of a
 # pipeline stage (benchmark/configs/solar-open2-250b-ep8.json): two whole
 # periods, 40 of the 320 experts, an eighth of the vocabulary rows. Every
@@ -221,6 +300,11 @@ PRESETS: dict[str, ModelConfig] = {
 PRESETS["solar-open2-250b-ep8"] = replace(
     PRESETS["solar-open2-250b"], n_layers=8, vocab_size=24576,
     n_experts_held=40)
+# What ONE v5e chip holds of SmallThinker as the first of three pipeline
+# stages (benchmark/configs/smallthinker-21b-pp3.json): five whole periods,
+# every layer whole — all 64 experts, every head, the whole vocabulary.
+PRESETS["smallthinker-21b-pp3"] = replace(
+    PRESETS["smallthinker-21b"], n_layers=20)
 
 
 def get_preset(name: str) -> ModelConfig:

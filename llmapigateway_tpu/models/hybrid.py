@@ -19,6 +19,14 @@ their part of the result only: what the absent experts would add is left
 out (one of several chips that share a layer; on one chip there is no
 exchange). No assignment to a held expert is ever dropped.
 
+The same forward serves a period made of softmax layers ONLY
+(``ModelConfig.softmax_positions``; SmallThinker): each position of a
+period says whether it attends inside the sliding window and whether it
+rotates q and k (``window_layout``, ``rope_layout``), the layers are
+grouped by the KV they must keep (``cache_groups``: one page pool, page
+table and provider a group), the router may score by softmax and read the
+block's input, and the experts' gate may be a ReLU.
+
 TPU-first decisions:
 
 * ``lax.scan`` over PERIODS, one compiled body whatever the depth. Every
@@ -52,7 +60,8 @@ import jax
 import jax.numpy as jnp
 
 from .config import ModelConfig
-from .llama import rms_norm, swiglu_mlp
+from .llama import (_GATE_ACTS, apply_rope, rms_norm, rope_tables,
+                    swiglu_mlp)
 from .quant import (head_matmul, mm, moe_mm_batched, quantize_array,
                     weight_bits)
 
@@ -73,14 +82,18 @@ QUANT_KEYS = frozenset({"wq", "wk", "wv", "wo", "wgate", "sg", "su", "sd",
 
 
 class HybridCache(NamedTuple):
-    """``k``, ``v``: the page pool of the softmax layers, as
-    ``PagedKVCache`` ([P, pages, KV, page, Dh], or its int8 dict).
+    """``k``, ``v``: the page pools of the softmax layers, a tuple over
+    ``ModelConfig.cache_groups``, each as ``PagedKVCache``'s ([layers of
+    the group, pages of the group, KV, page, Dh], or its int8 dict; a
+    group's layer ``j`` of period ``p`` lies at ``p * n + j``).
     ``state`` and ``conv``: the linear layers' recurrent state and the
     last inputs of their convolutions, one fixed block per slot — a tuple
     over a period's linear layers of [P, B, H, dk, dv] float32 and of
     [P, B, taps-1, 3*H*dk]. ``counters``
-    int32 [2]: routed assignments of the DECODE steps so far (all; landing
-    on a held expert), running totals that wrap."""
+    int32 [3]: of the DECODE steps so far, the routed assignments (all;
+    landing on a held expert) and, summed over layers, the held experts
+    that at least one active row was assigned to — running totals that
+    wrap."""
     k: Any
     v: Any
     state: tuple[jax.Array, ...]
@@ -88,24 +101,32 @@ class HybridCache(NamedTuple):
     counters: jax.Array
 
     @classmethod
-    def create(cls, config: ModelConfig, num_pages: int, page_size: int,
-               batch: int, dtype=jnp.bfloat16, kv_quant: str = ""
-               ) -> "HybridCache":
+    def create(cls, config: ModelConfig, num_pages: int | tuple[int, ...],
+               page_size: int, batch: int, dtype=jnp.bfloat16,
+               kv_quant: str = "") -> "HybridCache":
+        """``num_pages``: the pages of each cache group's pool (one
+        number: of every group's)."""
         from dataclasses import replace
         from ..ops.paged_attention import PagedKVCache
         c = config
-        pool = PagedKVCache.create(replace(c, n_layers=c.n_kv_layers),
-                                   num_pages, page_size, dtype, kv_quant)
-        lead, lin = (c.n_kv_layers, batch), range(c.layer_period - 1)
+        if isinstance(num_pages, int):
+            num_pages = (num_pages,) * len(c.cache_groups)
+        periods = c.n_layers // c.layer_period
+        pools = [PagedKVCache.create(
+            replace(c, n_layers=periods * len(positions)), pages, page_size,
+            dtype, kv_quant)
+            for (_, positions), pages in zip(c.cache_groups, num_pages)]
+        lead = (periods, batch)
+        lin = range(c.layer_period - len(c.softmax_positions))
         return cls(
-            k=pool.k, v=pool.v,
+            k=tuple(p.k for p in pools), v=tuple(p.v for p in pools),
             state=tuple(jnp.zeros(lead + (c.lin_heads, c.lin_head_dim,
                                           c.lin_head_dim), jnp.float32)
                         for _ in lin),
             conv=tuple(jnp.zeros(lead + (c.lin_conv_taps - 1,
                                          3 * c.lin_heads * c.lin_head_dim),
                                  dtype) for _ in lin),
-            counters=jnp.zeros((2,), jnp.int32))
+            counters=jnp.zeros((3,), jnp.int32))
 
 
 # ---------------------------------------------------------------------------
@@ -125,6 +146,8 @@ def init_params(config: ModelConfig, key: jax.Array,
       embed [V, D]; final_norm [D]; lm_head [V, D]
       layers/attn/{norm [P,D], wq [P,D,H*Dh], wk, wv [P,D,KV*Dh],
                    wgate [P,D,H*Dh], wo [P,H*Dh,D], mlp/...}
+                   (``wgate`` with ``attn_gate`` only; a period of several
+                   softmax layers: a tuple of such trees, one a position)
       layers/lin/(per-1 trees of){norm [P,D], wq, wk, wv [P,D,Hl*dk],
                   conv [P,taps,3*Hl*dk], wf_down [P,D,r],
                   wf_up [P,r,Hl*dk], f_bias [P,Hl*dk], a_log [P,Hl],
@@ -132,6 +155,7 @@ def init_params(config: ModelConfig, key: jax.Array,
                   out_norm [P,dk], wo [P,Hl*dk,D], mlp/...}
       .../mlp/{norm [P,D], router [P,D,E], wg, wu [P,held,D,F],
                wd [P,held,F,D], sg, su [P,D,Fs], sd [P,Fs,D]}
+               (``sg``, ``su``, ``sd`` with shared experts only)
     The residual stream is drawn at unit scale and every projection back
     into it (``wo``, ``wd``, ``sd``) at ``(2 n_layers)^-1/2`` of the usual
     (the GPT-2 convention): a delta rule with b up to 2 and slow decays
@@ -144,14 +168,15 @@ def init_params(config: ModelConfig, key: jax.Array,
     layer would forget in a token and no comparison could see its state.
     """
     c = config
-    if c.family != "hybrid" or not c.layer_period or c.n_layers % c.layer_period:
-        raise ValueError("hybrid.init_params needs family 'hybrid' and "
+    if not c.layer_period or c.n_layers % c.layer_period:
+        raise ValueError("hybrid.init_params needs a layer_period and "
                          "whole periods of layers")
-    if c.use_rope or not c.attn_gate:
+    if c.lin_heads and (c.use_rope or not c.attn_gate):
         raise ValueError("the hybrid family's softmax layers carry no "
                          "rotary embedding and gate their output: use_rope "
                          "must be False, attn_gate True")
-    per, P = c.layer_period, c.n_kv_layers
+    per, P = c.layer_period, c.n_layers // c.layer_period
+    n_soft = len(c.softmax_positions)
     D, dh, dk, Hl, r = (c.d_model, c.head_dim, c.lin_head_dim, c.lin_heads,
                         c.lin_gate_rank)
     F, held = c.d_ff_expert, c.experts_held
@@ -176,16 +201,18 @@ def init_params(config: ModelConfig, key: jax.Array,
                     "wd": dense(kd, F, D, scale=back, name="wd")}
         out = jax.lax.map(expert, jax.random.split(ks[0], held))
         out.update(
-            norm=jnp.ones((D,), dtype), router=dense(ks[1], D, c.n_experts),
-            sg=dense(ks[2], D, Fs, name="sg"),
-            su=dense(ks[3], D, Fs, name="su"),
-            sd=dense(ks[4], Fs, D, scale=back, name="sd"))
+            norm=jnp.ones((D,), dtype), router=dense(ks[1], D, c.n_experts))
+        if Fs:
+            out.update(sg=dense(ks[2], D, Fs, name="sg"),
+                       su=dense(ks[3], D, Fs, name="su"),
+                       sd=dense(ks[4], Fs, D, scale=back, name="sd"))
         return out
 
     def attn_layer(k):
         ks = jax.random.split(k, 6)
-        return {"norm": jnp.ones((D,), dtype),
-                "wgate": dense(ks[3], D, c.n_heads * dh, name="wgate"),
+        gate = {"wgate": dense(ks[3], D, c.n_heads * dh, name="wgate")
+                } if c.attn_gate else {}
+        return {"norm": jnp.ones((D,), dtype), **gate,
                 "wq": dense(ks[0], D, c.n_heads * dh, name="wq"),
                 "wk": dense(ks[1], D, c.n_kv_heads * dh, name="wk"),
                 "wv": dense(ks[2], D, c.n_kv_heads * dh, name="wv"),
@@ -217,8 +244,10 @@ def init_params(config: ModelConfig, key: jax.Array,
                 "mlp": mlp(ks[12])}
 
     def period(k):
-        ka, *kl = jax.random.split(k, per)
-        return {"attn": attn_layer(ka), "lin": tuple(map(lin_layer, kl))}
+        ks = list(jax.random.split(k, per))
+        soft = tuple(map(attn_layer, ks[:n_soft]))
+        return {"attn": soft if n_soft > 1 else soft[0],
+                "lin": tuple(map(lin_layer, ks[n_soft:]))}
 
     k_embed, k_head, k_layers = jax.random.split(key, 3)
     head = (jax.random.normal(k_head, (c.vocab_size, D), jnp.float32)
@@ -431,13 +460,17 @@ def linear_block(h, lp, c: ModelConfig, s0, tail, n_valid, keep):
 
 def route(hf: jax.Array, router: jax.Array, c: ModelConfig
           ) -> tuple[jax.Array, jax.Array]:
-    """hf [N,D] float32 (normalised, un-quantised) -> the top-k experts of
-    ALL ``n_experts`` by sigmoid score, [N,k] ids and weights that sum to
-    1 (float32, full-precision product: a rounded score flips the 8th and
-    9th expert)."""
-    scores = jax.nn.sigmoid(jnp.dot(hf, router.astype(jnp.float32),
-                                    precision=HIGHEST))
-    top, idx = jax.lax.top_k(scores, c.experts_per_token)
+    """hf [N,D] float32 (un-quantised) -> the top-k experts of ALL
+    ``n_experts``, [N,k] ids and weights that sum to 1 (float32,
+    full-precision product: a rounded score flips the 8th and 9th
+    expert). ``moe_router`` "sigmoid": by sigmoid score, the selected
+    scores normalised; "softmax": by logit, softmax over the selected (=
+    softmax over all, renormalised on the selected)."""
+    logits = jnp.dot(hf, router.astype(jnp.float32), precision=HIGHEST)
+    if c.moe_router == "softmax":
+        top, idx = jax.lax.top_k(logits, c.experts_per_token)
+        return idx, jax.nn.softmax(top, axis=-1)
+    top, idx = jax.lax.top_k(jax.nn.sigmoid(logits), c.experts_per_token)
     return idx, top / jnp.sum(top, axis=-1, keepdims=True)
 
 
@@ -459,17 +492,18 @@ def _at(tree: Any, i: jax.Array | None) -> Any:
 
 
 def experts_dense(x: jax.Array, probs: jax.Array, lp: Params,
-                  period: jax.Array | None = None) -> jax.Array:
+                  period: jax.Array | None = None, act: str = "silu"
+                  ) -> jax.Array:
     """Every held expert on every token. x [N,D], probs [N,held] -> [N,D]
     float32. With ``period`` the experts' matrices are stacked over
-    periods and read at that index."""
+    periods and read at that index. ``act``: the gate's activation."""
     lp = _at({k: lp[k] for k in EXPERT_KEYS}, period)
     # The expert axis is a BATCH axis of all three products, so the weights
     # are read where they lie ([held, D, F]: a product that contracts D
     # with the experts as a free axis has them re-laid out first, a copy of
     # every expert's weights a step).
     xe = jnp.broadcast_to(x, (probs.shape[1], *x.shape))
-    hid = (jax.nn.silu(moe_mm_batched(xe, lp["wg"]))
+    hid = (_GATE_ACTS[act](moe_mm_batched(xe, lp["wg"]))
            * moe_mm_batched(xe, lp["wu"]))
     y = moe_mm_batched(hid, lp["wd"])                       # [held,N,D]
     return jnp.einsum("end,ne->nd", y.astype(jnp.float32), probs)
@@ -477,7 +511,8 @@ def experts_dense(x: jax.Array, probs: jax.Array, lp: Params,
 
 def experts_grouped(x: jax.Array, probs: jax.Array, lp: Params,
                     per_token: int, tile: int = GROUP_TILE,
-                    period: jax.Array | None = None) -> jax.Array:
+                    period: jax.Array | None = None, act: str = "silu"
+                    ) -> jax.Array:
     """The held experts' part of the result with work that follows the
     assignments: rows are laid out expert by expert in tiles of ``tile``
     (each expert's group padded to whole tiles), and a loop over the LIVE
@@ -516,7 +551,7 @@ def experts_grouped(x: jax.Array, probs: jax.Array, lp: Params,
         at = jax.lax.dynamic_slice_in_dim(row_token, i * tile, tile)
         wt = jax.lax.dynamic_slice_in_dim(row_weight, i * tile, tile)
         w = _at(_at({key: lp[key] for key in EXPERT_KEYS}, period), e)
-        y = swiglu_mlp(x_pad[at], w["wg"], w["wu"], w["wd"])
+        y = swiglu_mlp(x_pad[at], w["wg"], w["wu"], w["wd"], act)
         return out.at[at].add(wt[:, None] * y.astype(jnp.float32))
 
     out = jax.lax.fori_loop(0, last_tile[-1], body,
@@ -526,35 +561,41 @@ def experts_grouped(x: jax.Array, probs: jax.Array, lp: Params,
 
 def moe_block(x: jax.Array, lp: Params, c: ModelConfig,
               count: jax.Array | None = None,
-              period: jax.Array | None = None
+              period: jax.Array | None = None,
+              route_on: jax.Array | None = None
               ) -> tuple[jax.Array, jax.Array]:
-    """x [B,T,D] (the residual stream) -> (x + MLP(norm(x)), int32 [2]:
-    the routed assignments of rows where ``count`` [B] is True, all and
-    those landing on a held expert; zeros without ``count``). ``period``:
-    the routed experts' matrices (``EXPERT_KEYS``) are stacked over
-    periods and this is the index to read."""
+    """x [B,T,D] (the residual stream) -> (x + MLP(norm(x)), int32 [3]:
+    of rows where ``count`` [B] is True the routed assignments, all and
+    those landing on a held expert, and the held experts with at least one
+    of them; zeros without ``count``). ``period``: the routed experts'
+    matrices (``EXPERT_KEYS``) are stacked over periods and this is the
+    index to read. ``route_on`` [B,T,D]: what the router reads instead of
+    the MLP's normalised input (the block's input, before attention)."""
     B, T, D = x.shape
     hf = rms_norm(x.astype(jnp.float32), lp["norm"], c.rms_eps)
     h = hf.astype(x.dtype)
     with jax.named_scope("moe.experts"):
-        idx, w = route(hf.reshape(B * T, D), lp["router"], c)
+        seen = hf if route_on is None else route_on.astype(jnp.float32)
+        idx, w = route(seen.reshape(B * T, D), lp["router"], c)
         probs = held_weights(idx, w, c)
         xf = h.reshape(B * T, D)
         if B * T <= DENSE_MAX_TOKENS:
-            y = experts_dense(xf, probs, lp, period)
+            y = experts_dense(xf, probs, lp, period, c.moe_act)
         else:
             y = experts_grouped(xf, probs, lp, c.experts_per_token,
-                                period=period)
+                                period=period, act=c.moe_act)
         y = y.reshape(B, T, D).astype(x.dtype)
     with jax.named_scope("moe.shared"):
         if c.n_shared_experts:
             y = y + swiglu_mlp(h, lp["sg"], lp["su"], lp["sd"])
-    counted = jnp.zeros((2,), jnp.int32)
+    counted = jnp.zeros((3,), jnp.int32)
     if count is not None:
         on = jnp.repeat(count, T)
+        landed = (probs > 0.0) & on[:, None]
         counted = jnp.stack([
             jnp.sum(on, dtype=jnp.int32) * c.experts_per_token,
-            jnp.sum((probs > 0.0) & on[:, None], dtype=jnp.int32)])
+            jnp.sum(landed, dtype=jnp.int32),
+            jnp.sum(jnp.any(landed, axis=0), dtype=jnp.int32)])
     return x + y, counted
 
 
@@ -565,13 +606,15 @@ def moe_block(x: jax.Array, lp: Params, c: ModelConfig,
 def forward(params: Params, config: ModelConfig, tokens: jax.Array,
             lengths: jax.Array, cache: HybridCache,
             active: jax.Array | None = None,
-            attention_fn: Callable | None = None, *,
+            attention_fn: Callable | tuple[Callable, ...] | None = None, *,
             slots: jax.Array | None = None,
             n_valid: jax.Array | None = None
             ) -> tuple[jax.Array, HybridCache]:
     """One forward over new tokens, in the family signature of
     ``llama.forward`` (tokens [B,T], lengths [B], the cache, ``active``,
-    an ``attention_fn`` over the page pool).
+    an ``attention_fn`` over the page pool — or one a cache group, in the
+    order of ``ModelConfig.cache_groups``: each closes over its group's
+    page table and window).
 
     Decode (T == 1, an ``attention_fn`` with ``.decode``): row b IS slot
     b; the state of a row with ``active`` False leaves bit-identical.
@@ -592,15 +635,24 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
     if attention_fn is None:
         raise ValueError("the hybrid family serves from the paged cache: "
                          "it needs a paged attention_fn")
-    decode_attend = getattr(attention_fn, "decode", None) if T == 1 else None
-    decoding = decode_attend is not None
+    fns = attention_fn if isinstance(attention_fn, tuple) else (attention_fn,)
+    groups = c.cache_groups
+    if len(fns) != len(groups):
+        raise ValueError(f"{len(groups)} cache groups need a provider each, "
+                         f"got {len(fns)}")
+    # Where a softmax position's KV lies: (its group, its place among the
+    # group's layers of a period, how many of those there are).
+    place = {p: (g, j, len(ps)) for g, (_, ps) in enumerate(groups)
+             for j, p in enumerate(ps)}
+    n_soft = len(c.softmax_positions)
+    decoding = T == 1 and getattr(fns[0], "decode", None) is not None
     # ``.decode_at`` / ``.prefill_at``: the provider reads the stacked pool
     # at a layer's index, and the pool stays out of the scanned inputs —
     # in prefill it is the scan's carry, written in place (llama.forward).
-    decode_at = getattr(attention_fn, "decode_at", None) if decoding else None
-    prefill_at = None if decoding or T == 1 else \
-        getattr(attention_fn, "prefill_at", None)
-    by_index = decode_at is not None or prefill_at is not None
+    by_decode_at = decoding and hasattr(fns[0], "decode_at")
+    by_prefill_at = (not decoding and T > 1
+                     and hasattr(fns[0], "prefill_at"))
+    by_index = by_decode_at or by_prefill_at
     scope = "decode" if decoding else "prefill"
     last_only = not decoding and n_valid is not None
     if decoding:
@@ -621,77 +673,124 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
         keep = count = None
 
     x = jnp.take(params["embed"], tokens, axis=0)               # [B,T,D]
+    if any(c.rope_at(p) for p in c.softmax_positions):
+        cos, sin = rope_tables(lengths[:, None] + jnp.arange(T)[None, :],
+                               dh, c.rope_theta, c.rope_scaling)
 
-    def softmax_layer(x, pool, lp, at):
-        """``at``: the period's index, or its (K, V) slice of the pool;
-        ``pool``: the stacked pool under ``prefill_at``."""
-        with jax.named_scope(f"{scope}.attention"):
+    def softmax_layer(x, pool, lp, at, position):
+        """``at``: the layer's index in its group's pool, or its (K, V)
+        slice of that pool; ``pool``: the group's stacked pool (under
+        ``prefill_at`` the carried one)."""
+        fn = fns[place[position][0]]
+        kind = "attn.window" if c.window_at(position) else "attn.global"
+        with jax.named_scope(f"{scope}.attention"), jax.named_scope(kind):
             h = rms_norm(x, lp["norm"], c.rms_eps)
             q = mm(h, lp["wq"]).reshape(B, T, c.n_heads, dh)
             k = mm(h, lp["wk"]).reshape(B, T, c.n_kv_heads, dh)
             v = mm(h, lp["wv"]).reshape(B, T, c.n_kv_heads, dh)
+            if c.rope_at(position):
+                q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
             ys = (k, v)
-            if prefill_at is not None:
-                attn, pool_k, pool_v = prefill_at(q, k, v, *pool, at,
-                                                  lengths, active)
+            if by_prefill_at:
+                attn, pool_k, pool_v = fn.prefill_at(q, k, v, *pool, at,
+                                                     lengths, active)
                 pool, ys = (pool_k, pool_v), None
-            elif decode_at is not None:
-                attn = decode_at(q, k, v, cache.k, cache.v, at, lengths,
-                                 active)
+            elif by_decode_at:
+                attn = fn.decode_at(q, k, v, *pool, at, lengths, active)
             elif decoding:
-                attn = decode_attend(q, k, v, *at, lengths, active)
+                attn = fn.decode(q, k, v, *at, lengths, active)
             else:
-                attn, layer_k, layer_v = attention_fn(q, k, v, *at, lengths,
-                                                      active)
+                attn, layer_k, layer_v = fn(q, k, v, *at, lengths, active)
                 ys = (layer_k, layer_v)
-            gate = jax.nn.sigmoid(mm(h, lp["wgate"]).astype(jnp.float32))
-            attn = (attn.astype(jnp.float32) * gate).astype(x.dtype)
+            if c.attn_gate:
+                gate = jax.nn.sigmoid(mm(h, lp["wgate"]).astype(jnp.float32))
+                attn = (attn.astype(jnp.float32) * gate).astype(x.dtype)
             return x + mm(attn, lp["wo"]), pool, ys
 
     # The routed experts' matrices stay OUT of the scanned slices: the
     # expert layer reads them from the whole stack at the period's index
     # (``experts_grouped`` says why).
     layers = params["layers"]
-    every = (layers["attn"], *layers["lin"])
+    soft = layers["attn"] if n_soft > 1 else (layers["attn"],)
+    every = (*soft, *layers["lin"])     # in the order of a period
     held = [{k: lp["mlp"][k] for k in EXPERT_KEYS} for lp in every]
     rest = [{**lp, "mlp": {k: v for k, v in lp["mlp"].items()
                            if k not in EXPERT_KEYS}} for lp in every]
 
-    def mlp(x, lp, i, period):
+    def mlp(x, lp, i, period, x_in):
         with jax.named_scope(f"{scope}.mlp"):
-            return moe_block(x, {**lp, **held[i]}, c, count, period)
+            return moe_block(x, {**lp, **held[i]}, c, count, period,
+                             x_in if c.router_reads_block_input else None)
+
+    pools = tuple(zip(cache.k, cache.v))       # a (K, V) pair a group
+
+    def by_period(pool, n):
+        """A group's pool [P*n, ...] as the scan slices it: [P, n, ...]."""
+        return pool if n == 1 else jax.tree.map(
+            lambda a: a.reshape(a.shape[0] // n, n, *a.shape[1:]), pool)
 
     def period_step(carry, scanned):
-        x, pool = carry
-        period, (attn, *lin), sides, s0, tail0 = scanned
-        x, pool, ys = softmax_layer(x, pool, attn,
-                                    period if by_index else sides)
-        x, counted = mlp(x, attn["mlp"], 0, period)
+        x, carried = carry
+        period, lps, sides, s0, tail0 = scanned
+        new = [[None] * len(ps) for _, ps in groups]
+        counted = 0
+        for i in range(n_soft):
+            g, j, n = place[i]
+            if by_index:
+                at = period if n == 1 else period * n + j
+                pool = carried[g] if by_prefill_at else pools[g]
+            else:
+                pool = None
+                at = sides[g] if n == 1 else jax.tree.map(
+                    lambda a: a[j], sides[g])
+            x_in = x
+            x, pool, new[g][j] = softmax_layer(x, pool, lps[i], at, i)
+            if by_prefill_at:
+                carried = (*carried[:g], pool, *carried[g + 1:])
+            x, more = mlp(x, lps[i]["mlp"], i, period, x_in)
+            counted = counted + more
         states, tails = [], []
-        for i, (lp, s, tail) in enumerate(zip(lin, s0, tail0), 1):
+        for i, (lp, s, tail) in enumerate(zip(lps[n_soft:], s0, tail0),
+                                          n_soft):
+            x_in = x
             with jax.named_scope(f"{scope}.kda"):
                 h = rms_norm(x, lp["norm"], c.rms_eps)
                 out, s, tail = linear_block(h, lp, c, s, tail, n_valid,
                                             keep)
                 x = x + out
-            x, more = mlp(x, lp["mlp"], i, period)
+            x, more = mlp(x, lp["mlp"], i, period, x_in)
             counted = counted + more
             states.append(s)
             tails.append(tail)
-        return (x, pool), (ys, tuple(states), tuple(tails), counted)
+        return (x, carried), (new, tuple(states), tuple(tails), counted)
 
-    (x, pool), (ys, s_out, tail_out, counts) = jax.lax.scan(
-        period_step,
-        (x, (cache.k, cache.v) if prefill_at is not None else None),
-        (jnp.arange(c.n_kv_layers), rest,
-         None if by_index else (cache.k, cache.v), s_in, tail_in))
+    (x, carried), (new, s_out, tail_out, counts) = jax.lax.scan(
+        period_step, (x, pools if by_prefill_at else None),
+        (jnp.arange(c.n_layers // c.layer_period), rest,
+         None if by_index else tuple(
+             by_period(pool, len(ps)) for pool, (_, ps) in zip(pools, groups)),
+         s_in, tail_in))
+
+    def of_group(parts):
+        """A group's per-position results [P, ...] in the pool's layer
+        order [P*n, ...]."""
+        if len(parts) == 1:
+            return parts[0]
+        return jax.tree.map(
+            lambda *a: jnp.stack(a, 1).reshape(-1, *a[0].shape[1:]), *parts)
+
+    if by_prefill_at:
+        new_pools = carried
+    elif decoding:
+        new_pools = tuple(
+            fn.insert_all(*pool, *of_group(parts), lengths, active)
+            for fn, pool, parts in zip(fns, pools, new))
+    else:
+        new_pools = tuple(of_group(parts) for parts in new)
     if decoding:
-        new_k, new_v = attention_fn.insert_all(
-            cache.k, cache.v, *ys, lengths, active)
         state, conv = s_out, tail_out
         counters = cache.counters + jnp.sum(counts, axis=0)
     else:
-        new_k, new_v = pool if prefill_at is not None else ys
         state = tuple(s.at[:, slots].set(new)
                       for s, new in zip(cache.state, s_out))
         conv = tuple(t.at[:, slots].set(new)
@@ -704,5 +803,6 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
     logits = head_matmul(x, params["lm_head"])
     if last_only:
         logits = jnp.broadcast_to(logits, (B, T, logits.shape[-1]))
-    return logits, HybridCache(k=new_k, v=new_v, state=state, conv=conv,
-                               counters=counters)
+    return logits, HybridCache(k=tuple(p[0] for p in new_pools),
+                               v=tuple(p[1] for p in new_pools),
+                               state=state, conv=conv, counters=counters)

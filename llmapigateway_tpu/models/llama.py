@@ -562,6 +562,7 @@ def windowed_dense_attention(window: int):
 _GATE_ACTS = {
     "silu": jax.nn.silu,                                      # llama/qwen2
     "gelu_tanh": partial(jax.nn.gelu, approximate=True),      # gemma GeGLU
+    "relu": jax.nn.relu,                    # ReGLU experts (models/hybrid.py)
 }
 
 

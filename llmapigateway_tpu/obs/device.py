@@ -115,6 +115,7 @@ class HbmLedger:
 
     def __init__(self, *, weights: int, kv_pool: int, aux: int = 0,
                  spec: int = 0, page_bytes: int = 0,
+                 kv_pools: dict[str, int] | None = None,
                  tracked_fn: Callable[[], int] | None = None,
                  mem_fn: Callable[[], dict | None] | None = None,
                  mem_ttl_s: float = 0.5,
@@ -124,6 +125,9 @@ class HbmLedger:
         self.aux = int(aux)
         self.spec = int(spec)
         self.page_bytes = int(page_bytes)   # K+V bytes of ONE physical page
+        # ``kv_pool`` by cache group ("global", "window4096"): one pool a
+        # group of softmax layers that keep the same KV.
+        self.kv_pools = dict(kv_pools or {})
         self.tracked_fn = tracked_fn
         self.mem_fn = mem_fn or device_memory_stats
         self.mem_ttl_s = mem_ttl_s
@@ -165,6 +169,8 @@ class HbmLedger:
             "hbm_aux_bytes": self.aux,
             "hbm_ledger_bytes": self.static_total,
         }
+        if len(self.kv_pools) > 1:
+            out["hbm_kv_pools_bytes"] = dict(self.kv_pools)
         if self.spec:
             out["hbm_spec_bytes"] = self.spec
         if self.page_bytes and prefix_resident_pages:

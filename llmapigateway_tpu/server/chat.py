@@ -1,8 +1,9 @@
 """POST /v1/chat/completions — the gateway's core endpoint.
 
 Thin HTTP shim over the routing engine (unlike the reference, whose handler
-contains the whole fallback loop — ``api/v1/chat.py:41-198``). Body is parsed
-as json5 for parity with the reference's lenient parsing (``chat.py:41``).
+contains the whole fallback loop — ``api/v1/chat.py:41-198``). A body that is
+JSON is parsed as JSON; what is not falls to json5, for parity with the
+reference's lenient parsing (``chat.py:41``).
 Streaming responses are committed (200, SSE headers) only after routing has
 produced a primed stream, so upstream failures still fell back.
 
@@ -16,6 +17,7 @@ keeps the reference's **503**.
 from __future__ import annotations
 
 import functools
+import json
 import logging
 import math
 
@@ -32,11 +34,24 @@ from .middleware import client_api_key
 logger = logging.getLogger(__name__)
 
 
+def _parse_body(body: str):
+    """Strict JSON first: ``json`` joins an escaped surrogate pair
+    (``"\\ud83d\\ude00"``, what every ``ensure_ascii`` client sends for a
+    character beyond U+FFFF) into its character, which json5 0.15 leaves as
+    two lone surrogates that no tokenizer can encode — and it parses a
+    140 kB prompt in 0.5 ms where json5 holds the event loop for a
+    second. Only a body that is not JSON (comments, trailing commas,
+    single quotes) is parsed leniently."""
+    try:
+        return json.loads(body)
+    except ValueError:
+        return json5.loads(body)
+
+
 async def chat_completions(request: web.Request) -> web.StreamResponse:
     gw = request.app["gateway"]
     try:
-        body = await request.text()
-        payload = json5.loads(body)
+        payload = _parse_body(await request.text())
         if not isinstance(payload, dict):
             raise ValueError("body must be a JSON object")
     except Exception as e:

@@ -362,7 +362,8 @@ def _two_layer_engine(chips, monkeypatch, slots: int, pages: int,
     state = (shapes(jax.eval_shape(init, key)), cache,
              jax.ShapeDtypeStruct((slots, config.vocab_size), jnp.int32,
                                   sharding=placed),
-             jax.ShapeDtypeStruct((slots, 32), jnp.int32, sharding=placed))
+             # the page tables: one a cache group, Mistral has one
+             (jax.ShapeDtypeStruct((slots, 32), jnp.int32, sharding=placed),))
     return engine, config, state, placed
 
 

@@ -206,8 +206,11 @@ def test_prefill_in_chunks_then_decode_matches_the_reference(
     else:       # the bulk, as in the full forward's int8 case
         assert np.median(errs.max(-1)) <= 0.08
     # 4 steps x 2 decoding slots x top-4 x 8 layers; about half land here.
-    total, local = np.asarray(cache.counters)
+    # Of the 8 held experts a layer, those 2 rows x top-4 reached: 1 to 8
+    # a layer a step, summed over 8 layers and 4 steps.
+    total, local, hit = np.asarray(cache.counters)
     assert total == 4 * 2 * 4 * 8 and 0.3 * total < local < 0.7 * total
+    assert 4 * 8 <= hit <= min(local, 4 * 8 * 8)
 
 
 def test_dropping_the_carried_state_moves_the_logits_past_every_tolerance():

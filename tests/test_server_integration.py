@@ -5,6 +5,7 @@ import asyncio
 import json
 from pathlib import Path
 
+import pytest
 from aiohttp.test_utils import TestClient, TestServer
 
 from llmapigateway_tpu.config.loader import ConfigLoader
@@ -96,6 +97,26 @@ async def test_nonstreaming_chat(tmp_path):
         # Upstream saw the provider-real model name and bearer key.
         assert g.up.requests[0]["model"] == "real-a"
         assert g.up.headers_seen[0]["Authorization"] == "Bearer TESTKEY"
+
+
+@pytest.mark.parametrize("body, content", [
+    # An ``ensure_ascii`` client's escaped surrogate pair is ONE character
+    # (json5 alone left two lone surrogates: a tokenizer counted two tokens
+    # or could not encode them at all).
+    (json.dumps({"model": "gw/chat", "messages": [
+        {"role": "user", "content": "hi \U0001F600 \U00029000"}]}),
+     "hi \U0001F600 \U00029000"),
+    # What is not JSON is still parsed leniently.
+    ("{model: 'gw/chat', /* c */ messages: [{role: 'user', "
+     "content: 'hi',},],}", "hi")], ids=["surrogate-pair", "json5"])
+async def test_chat_body_is_json_first_then_json5(tmp_path, body, content):
+    assert "\\ud83d\\ude00" in body or "'" in body
+    async with Gateway(tmp_path) as g:
+        resp = await g.client.post(
+            "/v1/chat/completions", data=body,
+            headers={**g.headers(), "Content-Type": "application/json"})
+        assert resp.status == 200
+        assert g.up.requests[0]["messages"][0]["content"] == content
 
 
 async def test_streaming_chat(tmp_path):
