@@ -419,10 +419,16 @@ class InferenceEngine:
         from ..obs.phases import SchedLedger
         self._sched = SchedLedger()
         # What the last compiled prefill dispatch ran (rows, bucket,
-        # tokens, lowest/highest start position, t0, t1): written by the
-        # worker inside _exec_prefill, read by the loop after the await
-        # for the PREFILL flight record.
+        # tokens, lowest/highest start position, KV pages walked, t0,
+        # t1): written by the worker inside _exec_prefill, read by the
+        # loop after the await for the PREFILL flight record.
         self._last_prefill: tuple | None = None
+        # The paged prefill kernel's walk, counted at dispatch (ISSUE
+        # 37): over rows and cache groups, the pages a call's row-blocks
+        # copy and attend a KV head, and the table entries a grid with a
+        # page axis stepped through. Monotone; worker thread.
+        self._prefill_pages_walked = 0
+        self._prefill_pages_table = 0
         # Device observability plane (ISSUE 8): per-kernel cost registry
         # (worker thread records, lock-guarded internally), the HBM
         # memory ledger, and the process-wide XLA compile monitor. The
@@ -2169,10 +2175,10 @@ class InferenceEngine:
         if fl is None or last is None:
             return
         from ..obs import flight as _fl
-        rows, bucket, tokens, pos_lo, pos_hi, t0, t1 = last
+        rows, bucket, tokens, pos_lo, pos_hi, walked, t0, t1 = last
         fl.record(_fl.PREFILL, t=t1, dur_ms=1000.0 * (t1 - t0), depth=rows,
                   val=float(bucket), tokens=tokens, free_pages=pos_lo,
-                  spec_acc=pos_hi,
+                  spec_acc=pos_hi, chunks=min(walked, 32767),
                   pool=_fl.POOL_PREFILL if self._disagg is not None else 0)
 
     def _admit(self, fl) -> None:
@@ -2473,8 +2479,35 @@ class InferenceEngine:
         t1 = time.monotonic()
         self.kernels.record(kname, wall_ms=1000.0 * (t1 - t0))
         self._last_prefill = (K, int(bucket), sum(len(ch) for ch in chunks),
-                              int(min(poss)), int(max(poss)), t0, t1)
+                              int(min(poss)), int(max(poss)),
+                              self._count_prefill_walk(poss, int(bucket)),
+                              t0, t1)
         return first, cache
+
+    def _count_prefill_walk(self, poss: list[int], bucket: int) -> int:
+        """Add one dispatch to the walk's two totals and return its
+        walked pages: per row and cache group what
+        ``ops.paged_attention.prefill_pages_walked`` counts for the
+        row-block the kernel's own rule picks at this bucket (host
+        integer arithmetic; a dense cache walks no pages)."""
+        if not self.paged:
+            return 0
+        from ..ops import paged_attention as pa
+        c, page = self.model_cfg, self.allocator.page_size
+        itemsize = jnp.dtype(self.dtype).itemsize
+        bt = pa.prefill_block_shape(
+            bucket, c.n_heads // c.n_kv_heads, c.n_kv_heads, page,
+            c.head_dim, itemsize, 1 if self.kv_quant else itemsize,
+            bool(self.kv_quant), self.kv_ppb)[0]
+        walked = 0
+        for g in self.kv_groups:
+            w, t = pa.prefill_pages_walked(
+                poss, bucket, bt, page, g.window,
+                g.allocator.pages_per_slot, self.kv_ppb)
+            walked += w
+            self._prefill_pages_table += t
+        self._prefill_pages_walked += walked
+        return walked
 
     def _kernel_variant(self, **base) -> dict:
         """Registry variant dict for a decode/spec kernel: the caller's
@@ -3420,6 +3453,9 @@ class InferenceEngine:
             out["kv_groups"] = [g.stats() for g in self.kv_groups]
             out["kv_ring_recycled_total"] = sum(
                 g.recycled for g in self.kv_groups)
+            # The paged prefill kernel's walk (_count_prefill_walk).
+            out["prefill_kv_pages_walked_total"] = self._prefill_pages_walked
+            out["prefill_kv_pages_table_total"] = self._prefill_pages_table
             if self.kv_ppb > 1:
                 out["pages_per_block"] = self.kv_ppb
             if self._prefix_cache is not None:

@@ -95,6 +95,7 @@ _DTYPE = np.dtype([
     ("tokens", np.int32),       # tokens emitted (STEP) / generated (FINISH)
                                 # / prompt tokens in the call (PREFILL)
     ("chunks", np.int16),       # prefill chunk dispatches this step
+                                # (PREFILL: KV pages the kernel walked)
     ("active", np.int16),       # running requests after the step
     ("free_slots", np.int16),
     ("queued", np.int16),       # admission queue depth (+ parked head)
@@ -274,6 +275,7 @@ class FlightRecorder:
                 d["tokens"] = int(row["tokens"])
                 d["pos_lo"] = int(row["free_pages"])
                 d["pos_hi"] = int(row["spec_acc"])
+                d["pages_walked"] = int(row["chunks"])
             elif kind == PROF:
                 # Profiler capture boundary (ISSUE 8): the rid carries
                 # the capture's trace directory, so a Perfetto timeline

@@ -38,21 +38,29 @@ reference path, of a mesh and of ``.verify``, and the tests' oracle) made
 it take a layout of their own liking, which every slice was then re-laid
 from.
 
-Two attention kernels, two shapes. The PREFILL kernel mirrors
-ops/flash_attention.py (a grid over key blocks, online-softmax fp32
-scratch, ``pl.when`` compute skip) with one addition: the K/V BlockSpec
-index maps translate logical → physical through the scalar-prefetched page
-table, *and* clamp to the last live logical page so dead iterations repeat
-a block index and their HBM→VMEM DMA is elided. Their DMA, not their grid
-step: a grid step has a fixed price (~0.2–0.4 us on a v5e) whatever it
-moves, and the DECODE kernel used to pay it ``B × KV × NP`` times a call —
-2 048 steps for some tens of live ones, 415 us against a byte floor of
-21 (PERF.md, PR 27). So the decode kernel has no page axis in its grid: a
-program walks each slot's LIVE blocks and only those, copying a block —
-a run of pages for as many of their KV heads as fit a VMEM budget — from
-the pool in HBM into one of two buffers while it attends the one before.
-Its cost is the live tokens' bytes plus a few microseconds a slot — the
-ragged property, by construction and not by elision.
+Two attention kernels, one shape: neither has a page axis in its grid. A
+grid step has a fixed price (~0.3–0.4 us on a v5e when it moves nothing,
+~0.7 when it attends one page for one head) and a kernel that steps through
+the page TABLE pays it for every entry, live or dead: the decode kernel
+paid ``B × KV × NP`` steps a call — 2 048 for some tens of live ones, 415
+us against a byte floor of 21 (PERF.md, PR 27) — and the prefill kernel
+``B × H × T/128 × NP``, 4 096 for one 512-token Mistral row, each ONE query
+head's 128 rows against ONE page fetched 16 times over (2.2–2.3 ms a layer
+call, 7% of its roofline: PERF.md, PR 37). So both leave the pools in HBM
+and WALK: a program copies a block — a run of pages for as many of their
+KV heads as fit a VMEM budget — into one of two buffers while it attends
+the one before, from the first live block to the last and no further. The
+decode kernel's program walks each slot's live blocks for one query
+position; the prefill kernel's program (one a row and group of KV heads)
+walks, for each row-block of query positions (512 rows a folded head), the
+blocks between
+the window's floor for its first query and the diagonal of its last, with
+a KV head's whole query group folded into the rows that meet each page
+(``G × bt`` rows a dot, the heads a batch dimension), and builds a mask
+only on the pages a mask can change (the diagonal's and the floor's). The
+cost is the live tokens' bytes (decode) or the visible pairs' arithmetic
+(prefill) plus a few microseconds a program — the ragged property, by
+construction and not by elision.
 
 The adapter :func:`make_paged_attention_fn` is built INSIDE the engine's
 jitted step (closing over the traced page table), so ``llama.forward``
@@ -71,7 +79,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..models.config import ModelConfig
-from .flash_attention import attend_block, shard_map, unpack_kv_refs
+from .flash_attention import shard_map
 
 NEG_INF = -1e30
 
@@ -611,6 +619,31 @@ def paged_insert_chunk_in_place(pool_k, pool_v, k_new: jax.Array,
 _DECODE_KV_VMEM_BYTES = 4 * 2 ** 20
 
 
+def _kv_block_bytes(page: int, Dh: int, itemsize: int, quant: bool,
+                    ppb: int) -> int:
+    """VMEM bytes of ONE KV head's side of a block of ``ppb`` pages: the
+    values and, int8, the f32 scale planes, whose unit dim pads to 8
+    sublanes."""
+    return ppb * (page * Dh * itemsize + (8 * page * 4 if quant else 0))
+
+
+def _walk_buffers(k_pages, v_pages, ppb: int, heads: int):
+    """(pool operands, VMEM buffer pairs) of a kernel that walks blocks
+    ``(ppb, heads, page, Dh)`` of the stacked pool: K, V — int8: K, its
+    scale plane, V, its scale plane. Scales are STORED [L, P, KV, 1,
+    page], so a block's scale plane is the same slice of the pool as its
+    values (see flash_attention.attend_block on why the unit dim)."""
+    quant = isinstance(k_pages, dict)
+    kq = k_pages["q"] if quant else k_pages
+    page, Dh = kq.shape[3:]
+    kv_buf = pltpu.VMEM((2, ppb, heads, page, Dh), kq.dtype)
+    if not quant:
+        return (k_pages, v_pages), [kv_buf, kv_buf]
+    s_buf = pltpu.VMEM((2, ppb, heads, 1, page), jnp.float32)
+    return ((k_pages["q"], k_pages["s"], v_pages["q"], v_pages["s"]),
+            [kv_buf, s_buf, kv_buf, s_buf])
+
+
 def _decode_heads_per_block(KV: int, page: int, Dh: int, itemsize: int,
                             quant: bool, ppb: int) -> int:
     """How many of a page's KV heads one block of the paged decode kernel
@@ -619,10 +652,8 @@ def _decode_heads_per_block(KV: int, page: int, Dh: int, itemsize: int,
     Dh)``, two of each, fit ``_DECODE_KV_VMEM_BYTES`` (an int8 pool adds
     its f32 scale planes, whose unit dim pads to 8 sublanes in VMEM). A
     block too large for the budget still holds one head."""
-    per_head = ppb * page * Dh * itemsize
-    if quant:
-        per_head += ppb * 8 * page * 4
-    per_head *= 2 * 2                                # K and V, two buffers
+    # K and V, two buffers each.
+    per_head = 2 * 2 * _kv_block_bytes(page, Dh, itemsize, quant, ppb)
     return max([d for d in range(1, KV + 1)
                 if KV % d == 0 and d * per_head <= _DECODE_KV_VMEM_BYTES],
                default=1)
@@ -644,18 +675,18 @@ def _decode_live_blocks(n_valid, bs: int, window: int, n_table_blocks: int):
     return first, last
 
 
-def _attend_heads(q, k, v, ks, vs, mask, m, l, acc):
-    """One online-softmax update of EVERY folded head against one page:
-    flash_attention.attend_block's arithmetic with the heads as the
-    leading batch dimension of both dots (``q`` [heads, G, Dh], ``k``/``v``
-    [heads, page, Dh], int8 scales ``ks``/``vs`` [heads, 1, page] or None,
-    state ``m``/``l`` [heads, G, 1] and ``acc`` [heads, G, Dh]). Per head
-    the operations and their order are attend_block's — int8 K cast to
-    q's dtype for one native MXU pass, the K scale on the scores after the
-    QK dot, the V scale on the probabilities after ``l`` accumulates —
-    so a head's result does not depend on how many heads share the call.
-    On the chip the batched form is what pays: eight heads' dots issued
-    as one op ran 3.6x faster than eight unrolled (PERF.md, PR 27)."""
+# The two halves of one online-softmax update, either side of the mask.
+# ``inline=True`` as jnp's own functions have it: the traced jaxpr is
+# CACHED by shapes and replayed into the kernel being traced, so the
+# kernel's jaxpr is what calling the body would give, eqn for eqn — but
+# a kernel that attends on two paths (masked and not), and every program
+# after the first with a block shape, does not run this Python again.
+# Set-up traces the prefill kernel once a (bucket, rows) program: the 16
+# of a chat cell warm up 1.3 s sooner for it on the chip's host, where
+# the kernel's trace is what a warm set-up pays (PERF.md, PR 37).
+
+@functools.partial(jax.jit, inline=True)
+def _scaled_scores(q, k, ks):
     if k.dtype == jnp.int8:
         k = k.astype(q.dtype)
     else:
@@ -667,7 +698,11 @@ def _attend_heads(q, k, v, ks, vs, mask, m, l, acc):
     scores *= q.shape[-1] ** -0.5
     if ks is not None:
         scores = scores * ks
-    scores = mask(scores)
+    return scores
+
+
+@functools.partial(jax.jit, inline=True)
+def _softmax_update(scores, v, vs, m, l, acc):
     m_new = jnp.maximum(m, jnp.max(scores, axis=2, keepdims=True))
     alpha = jnp.exp(m - m_new)
     e = jnp.exp(scores - m_new)
@@ -677,6 +712,26 @@ def _attend_heads(q, k, v, ks, vs, mask, m, l, acc):
         p, v.astype(jnp.float32), (((2,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32)        # [heads, G, Dh]
     return m_new, l, acc
+
+
+def _attend_heads(q, k, v, ks, vs, mask, m, l, acc):
+    """One online-softmax update of EVERY folded head against one page:
+    flash_attention.attend_block's arithmetic with the heads as the
+    leading batch dimension of both dots (``q`` [heads, G, Dh], ``k``/``v``
+    [heads, page, Dh], int8 scales ``ks``/``vs`` [heads, 1, page] or None,
+    state ``m``/``l`` [heads, G, 1] and ``acc`` [heads, G, Dh]; the
+    prefill kernel's rows are ``G x bt``; ``mask`` None where every score
+    is visible, which is what an all-true mask selects). Per head
+    the operations and their order are attend_block's — int8 K cast to
+    q's dtype for one native MXU pass, the K scale on the scores after the
+    QK dot, the V scale on the probabilities after ``l`` accumulates —
+    so a head's result does not depend on how many heads share the call.
+    On the chip the batched form is what pays: eight heads' dots issued
+    as one op ran 3.6x faster than eight unrolled (PERF.md, PR 27)."""
+    scores = _scaled_scores(q, k, ks)
+    if mask is not None:
+        scores = mask(scores)
+    return _softmax_update(scores, v, vs, m, l, acc)
 
 
 def _paged_decode_kernel(pt_ref, nvalid_ref, layer_ref, q_ref, kn_ref,
@@ -869,18 +924,7 @@ def paged_decode_attention(q: jax.Array, k_new: jax.Array,
         return pl.BlockSpec((B, heads, width, Dh),
                             lambda hb, pt, nv, layer: (0, hb, 0, 0))
 
-    # Scales are STORED [L, P, KV, 1, page], so a block's scale plane
-    # is the same slice of the pool as its values (see
-    # flash_attention.attend_block on why the unit dim).
-    kv_buf = pltpu.VMEM((2, ppb, heads, page, Dh), kq.dtype)
-    s_buf = pltpu.VMEM((2, ppb, heads, 1, page), jnp.float32)
-    if quant:
-        kv_operands = (k_pages["q"], k_pages["s"],
-                       v_pages["q"], v_pages["s"])
-        buffers = [kv_buf, s_buf, kv_buf, s_buf]
-    else:
-        kv_operands = (k_pages, v_pages)
-        buffers = [kv_buf, kv_buf]
+    kv_operands, buffers = _walk_buffers(k_pages, v_pages, ppb, heads)
 
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, page=page, window=window,
@@ -907,67 +951,241 @@ def paged_decode_attention(q: jax.Array, k_new: jax.Array,
 # Prefill kernel: q [B, T, H, Dh] vs pages, causal from per-slot start
 # ---------------------------------------------------------------------------
 
+# The paged prefill kernel's block: the query rows of ONE folded KV head
+# (``G x bt``) and of all a program's heads that meet a page in one pass,
+# and what the program may take of VMEM by :func:`_prefill_vmem_bytes`'
+# count, which is generous (a v5e kernel is lent 16 MiB unless it asks:
+# the call asks for ``_PREFILL_VMEM_LIMIT_BYTES``). From two sweeps on
+# the chip over row-blocks of 32-512 positions and 1-8 heads at the
+# served folds of 4, 7 and 8 (PERF.md, PR 37): one KV head a program is
+# 1.4-1.6x slower than two at any row-block (inferred: a lone head's
+# dots and vector passes wait for each other, a second head's fill the
+# gaps); two heads of ~512 rows are within a tenth of the best shape
+# found at every fold; and the compiler unrolls a pass into one
+# instruction a vector register, so a kernel's COMPILE follows the block
+# (3.2-3.9 s at 4 096 rows against 0.3-0.5 at 1 024: a checkout's first
+# set-up, once a prefill program). A warm set-up does not see the block:
+# there the kernel costs its Python trace (see _scaled_scores).
+_PREFILL_HEAD_ROWS = 512
+_PREFILL_BLOCK_ROWS = 1024
+_PREFILL_VMEM_BYTES = 16 * 2 ** 20
+_PREFILL_VMEM_LIMIT_BYTES = 32 * 2 ** 20
+
+
+def _prefill_vmem_bytes(bt: int, heads: int, T: int, G: int, page: int,
+                        Dh: int, q_itemsize: int, kv_itemsize: int,
+                        quant: bool, ppb: int) -> int:
+    """What a program of the paged prefill kernel holds in VMEM with
+    ``bt`` query positions a row-block and ``heads`` KV heads folded."""
+    rows = heads * G * bt
+    # Scores, their exponentials and the scaled probabilities (float32
+    # [rows, page] each); the accumulator; m and l (a lane-padded column
+    # each).
+    body = rows * (3 * page * 4 + Dh * 4 + 2 * 128 * 4)
+    q_and_out = 2 * 2 * heads * G * T * Dh * q_itemsize
+    # K and V, two buffers each, and a page of each converted for the dots.
+    pages = heads * (2 * 2 * _kv_block_bytes(page, Dh, kv_itemsize, quant,
+                                             ppb)
+                     + page * Dh * (q_itemsize + 4))
+    return body + q_and_out + pages
+
+
+def prefill_block_shape(T: int, G: int, KV: int, page: int, Dh: int,
+                        q_itemsize: int, kv_itemsize: int, quant: bool,
+                        ppb: int, block_t: int | None = None
+                        ) -> tuple[int, int]:
+    """(query positions a row-block, KV heads a program) of the paged
+    prefill kernel — pure shape arithmetic over what the call sees. A
+    block is ``heads x G x bt`` query rows against one copy of each
+    head's page. ``bt`` is the largest power-of-two divisor of ``T``
+    that keeps a head's ``G x bt`` rows within ``_PREFILL_HEAD_ROWS`` (a
+    caller's ``block_t`` is taken as given), ``heads`` the largest
+    divisor of the local ``KV`` that keeps the block within
+    ``_PREFILL_BLOCK_ROWS`` — the small buckets fold every head — and
+    both give way, ``bt`` down to eight positions and ``heads`` to one,
+    until the program fits ``_PREFILL_VMEM_BYTES``."""
+    def fits(bt, heads):
+        return _prefill_vmem_bytes(bt, heads, T, G, page, Dh, q_itemsize,
+                                   kv_itemsize, quant, ppb
+                                   ) <= _PREFILL_VMEM_BYTES
+    if block_t is None:
+        block_t = T & -T
+        while block_t > 8 and not (G * block_t <= _PREFILL_HEAD_ROWS
+                                   and fits(block_t, 1)):
+            block_t //= 2
+    heads = max([d for d in range(1, KV + 1)
+                 if KV % d == 0 and fits(block_t, d)
+                 and d * G * block_t <= _PREFILL_BLOCK_ROWS], default=1)
+    return block_t, heads
+
+
+def _prefill_live_blocks(first_q, bt: int, bs: int, window: int,
+                         n_table_blocks: int, xp=jnp):
+    """(first, last) live BLOCK (run of ``bs`` tokens) for the ``bt``
+    queries from position ``first_q``: causal upper bound (the block of
+    the last query's own key, clamped into the table), window lower bound
+    (the block of the first key the FIRST query sees) — every key any of
+    the queries can see lies between them, and ``first <= last`` always.
+    ``xp``: ``jnp`` in the kernel, ``numpy`` where the engine counts the
+    walk on the host (:func:`prefill_pages_walked`)."""
+    last = xp.minimum((first_q + bt - 1) // bs, n_table_blocks - 1)
+    if window:
+        first = xp.minimum(xp.maximum(first_q - (window - 1), 0) // bs, last)
+    else:
+        first = last * 0
+    return first, last
+
+
+def prefill_pages_walked(starts, T: int, bt: int, page: int, window: int,
+                         n_table_pages: int, ppb: int = 1) -> tuple[int, int]:
+    """(pages walked, table entries) of one paged prefill call over rows
+    that start at ``starts``: summed over rows and their ``T // bt``
+    row-blocks, the pages between the first and the last live block of
+    :func:`_prefill_live_blocks` — what the kernel copies and attends a
+    KV head — and the table's width, which is what a grid with a page
+    axis stepped through. Host integer arithmetic (numpy)."""
+    import numpy as np
+    first_q = (np.asarray(starts, np.int64)[:, None]
+               + np.arange(T // bt, dtype=np.int64)[None, :] * bt)
+    first, last = _prefill_live_blocks(first_q, bt, ppb * page, window,
+                                       n_table_pages // ppb, xp=np)
+    return int((last - first + 1).sum()) * ppb, first_q.size * n_table_pages
+
+
 def _paged_prefill_kernel(pt_ref, start_ref, layer_ref, q_ref, *refs,
-                          block_t: int, page: int, window: int = 0,
-                          pages_per_block: int = 1):
-    # ``layer_ref`` is read by the K/V index maps alone: the blocks arrive
-    # with the layer dim squeezed, so the body is what it was.
-    k_ref, ks_ref, v_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = \
-        unpack_kv_refs(refs)
-    b = pl.program_id(0)
-    t = pl.program_id(2)
-    j = pl.program_id(3)        # run of `pages_per_block` logical pages
-    n_pb = pl.num_programs(3)
+                          block_t: int, page: int, window: int,
+                          pages_per_block: int, n_table_blocks: int):
+    """Program ``(row b, group of folded KV heads)``: for each row-block of
+    ``block_t`` query positions in turn, walk the blocks of pages its
+    queries can see (:func:`_prefill_live_blocks`) and only those. The
+    pools stay in HBM; a block — a run of pages for the program's heads —
+    is copied into one of two VMEM buffers while the block before it is
+    attended, and the walk is one sequence over (row-block, block): a
+    row-block's last block prefetches the next row-block's first.
+    ``q_ref``/``o_ref``: ``[1, T // block_t, heads, G * block_t, Dh]``, a
+    head's ``G`` query heads folded into the rows (row ``g * block_t + i``
+    is query head ``g`` at position ``first_q + i``). ``refs``: the
+    LAYER-STACKED pool sides in HBM (K, V; int8: K, its scale plane, V,
+    its scale plane), of which only layer ``layer_ref[0]`` is read, the
+    output block, a VMEM buffer pair per pool side in the same order,
+    the online-softmax state (m, l, acc) and the DMA semaphores
+    ``[buffer, side]``."""
+    n_sides = (len(refs) - 5) // 2       # K, V (int8: + their scale planes)
+    pools, o_ref = refs[:n_sides], refs[n_sides]
+    bufs = refs[n_sides + 1:2 * n_sides + 1]
+    m_ref, l_ref, acc_ref, sem = refs[2 * n_sides + 1:]
+    if n_sides == 4:
+        k_buf, ks_buf, v_buf, vs_buf = bufs
+    else:
+        (k_buf, v_buf), ks_buf, vs_buf = bufs, None, None
+    b, hb = pl.program_id(0), pl.program_id(1)
+    n_row_blocks, heads = q_ref.shape[1], q_ref.shape[2]
+    bt, ppb = block_t, pages_per_block
+    layer, start = layer_ref[0], start_ref[b]
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+    def live(t):
+        first_q = start + t * bt
+        first, last = _prefill_live_blocks(first_q, bt, ppb * page, window,
+                                           n_table_blocks)
+        return first_q, first, last - first + 1        # >= 1 block
 
-    start = start_ref[b]
-    first_q_pos = start + t * block_t
-    last_q_pos = first_q_pos + (block_t - 1)
+    def copies(blk, buf):
+        # One table lookup a block: the packed-table promise makes a
+        # run's ppb physical pages contiguous from its first (see
+        # _paged_decode_kernel).
+        p0 = pt_ref[b, blk * ppb]
+        return [pltpu.make_async_copy(
+            pool.at[layer, pl.ds(p0, ppb), pl.ds(hb * heads, heads)],
+            vmem.at[buf], sem.at[buf, side])
+            for side, (pool, vmem) in enumerate(zip(pools, bufs))]
 
-    # Causal upper bound; with a sliding window also a lower bound — a
-    # page is dead unless its last key position is within `window` of the
-    # block's FIRST query (flash_attention._chunk_kernel is the dense
-    # twin). Dead pages skip compute and DMA (index-map clamp). Per-page
-    # attends unrolled over the block's sub-pages keep any
-    # pages_per_block bit-for-bit with the per-page kernel (see
-    # _paged_decode_kernel).
-    for i in range(pages_per_block):
-        lp = j * pages_per_block + i                   # logical page
-        live = lp * page <= last_q_pos
-        if window:
-            live = live & ((lp + 1) * page - 1 > first_q_pos - window)
+    for c in copies(live(0)[1], 0):
+        c.start()
 
-        @pl.when(live)
-        def _block(i=i, lp=lp):
-            def mask(scores):
-                q_pos = first_q_pos + jax.lax.broadcasted_iota(
-                    jnp.int32, scores.shape, 0)
-                s_pos = lp * page + jax.lax.broadcasted_iota(
-                    jnp.int32, scores.shape, 1)
-                ok = s_pos <= q_pos
+    def row_block(t, walked):
+        first_q, first, n_blocks = live(t)
+        last_q = first_q + (bt - 1)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        def attend(buf, sub, mask):
+            m, l, acc = _attend_heads(
+                q_ref[0, t], k_buf[buf, sub], v_buf[buf, sub],
+                None if ks_buf is None else ks_buf[buf, sub],
+                None if vs_buf is None else vs_buf[buf, sub],
+                mask, m_ref[...], l_ref[...], acc_ref[...])
+            m_ref[...], l_ref[...], acc_ref[...] = m, l, acc
+
+        def block(i, carry):
+            buf = (walked + i) % 2
+            # Start the NEXT block of the walk — this row-block's, or the
+            # next one's first — into the other buffer, then wait for
+            # this one.
+            ends = i == n_blocks - 1
+            nblk = jnp.where(
+                ends, live(jnp.minimum(t + 1, n_row_blocks - 1))[1],
+                first + i + 1)
+
+            @pl.when(jnp.logical_not(ends & (t == n_row_blocks - 1)))
+            def _prefetch():
+                for c in copies(nblk, 1 - buf):
+                    c.start()
+            for c in copies(first + i, buf):
+                c.wait()
+            # Per-page attends over the block's sub-pages, unrolled
+            # (pages_per_block is compile-time), in ascending logical
+            # order: a row's updates are the per-page, per-head kernel's
+            # whatever the block shape. A page none of the queries sees
+            # (a run's tail, a run's head below the window) is skipped.
+            for sub in range(ppb):
+                lo = ((first + i) * ppb + sub) * page  # the page's first key
+                hi = lo + (page - 1)                   # ... and its last
+                visible = lo <= last_q
+                # Below the diagonal of EVERY row (and inside every
+                # row's window): the mask would select every score, so
+                # none is built — only the diagonal pages and the
+                # window's floor pages pay for iota, compare and select.
+                whole = hi <= first_q
                 if window:
-                    ok = ok & (s_pos > q_pos - window)
-                return jnp.where(ok, scores, NEG_INF)
-            attend_block(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, mask,
-                         ks_ref, vs_ref, sub=i)
+                    visible = visible & (hi > first_q - window)
+                    whole = whole & (lo > last_q - window)
 
-    @pl.when(j == n_pb - 1)
-    def _out():
-        l = l_ref[:, :1]
-        o_ref[0, 0] = (acc_ref[:] / jnp.where(l == 0.0, 1.0, l)
+                def mask(scores, lo=lo):
+                    row = jax.lax.broadcasted_iota(jnp.int32,
+                                                   scores.shape, 1)
+                    q_pos = first_q + (row & (bt - 1) if bt & (bt - 1) == 0
+                                       else row % bt)
+                    s_pos = lo + jax.lax.broadcasted_iota(
+                        jnp.int32, scores.shape, 2)
+                    ok = s_pos <= q_pos
+                    if window:
+                        ok = ok & (s_pos > q_pos - window)
+                    return jnp.where(ok, scores, NEG_INF)
+
+                @pl.when(visible & whole)
+                def _whole(sub=sub):
+                    attend(buf, sub, None)
+
+                @pl.when(visible & jnp.logical_not(whole))
+                def _edge(sub=sub, mask=mask):
+                    attend(buf, sub, mask)
+            return carry
+
+        jax.lax.fori_loop(0, n_blocks, block, 0)
+        l = l_ref[...]
+        o_ref[0, t] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
                        ).astype(o_ref.dtype)
+        return walked + n_blocks
+
+    jax.lax.fori_loop(0, n_row_blocks, row_block, 0)
 
 
 def paged_prefill_attention(q: jax.Array, k_pages, v_pages,
                             page_table: jax.Array,
                             start: jax.Array, *,
                             layer: jax.Array | int = 0,
-                            block_t: int = 128,
+                            block_t: int | None = None,
                             window: int = 0,
                             pages_per_block: int = 1,
                             interpret: bool | None = None) -> jax.Array:
@@ -976,17 +1194,35 @@ def paged_prefill_attention(q: jax.Array, k_pages, v_pages,
     q: [B, T, H, Dh] at absolute positions ``start + t``;
     k_pages/v_pages: the layer-stacked pool ``[L, P, KV, page, Dh]`` (or
     the int8 ``{"q","s"}`` dicts) of which ``layer`` (a traced scalar: the
-    layer scan's index) is read WHERE IT LIES — one more prefetched
-    scalar, which the K/V and scale index maps return for a leading block
-    dimension of 1, so a scan over layers hands the kernel no slice of
-    the pool (:func:`paged_decode_attention` says what a slice costs). A
-    rank-4 side ``[P, KV, page, Dh]`` is one layer (a free reshape, layer
-    0). page_table: [B, NP]; start: [B]. ``window``: sliding-window bound
-    (0 = full causal) — out-of-window pages skip compute and DMA.
-    ``pages_per_block``: run of contiguous logical pages fetched per
-    inner-loop step (same packed-table contract and bit-for-bit
-    guarantee as :func:`paged_decode_attention`).
-    Returns [B, T, H*Dh].
+    layer scan's index) is read WHERE IT LIES — the pool operands stay in
+    HBM and a block's copy names the layer, so a scan over layers hands
+    the kernel no slice of the pool (:func:`paged_decode_attention` says
+    what a slice costs). A rank-4 side ``[P, KV, page, Dh]`` is one layer
+    (a free reshape, layer 0). page_table: [B, NP]; start: [B].
+    ``window``: sliding-window bound (0 = full causal). Returns
+    [B, T, H*Dh].
+
+    One Pallas call, grid ``(B, KV // heads)`` — no page axis and no
+    query-head axis. A program holds one row's q and out for ``heads`` KV
+    heads, each head's ``G = H // KV`` query heads folded into the rows
+    of its blocks, and for each row-block of ``bt`` query positions walks
+    the LIVE blocks of pages (:func:`_prefill_live_blocks`: up to the
+    last query's own key, from the first key the first query's window
+    holds), copying each block ``(ppb, heads, page, Dh)`` from the HBM
+    pool while it attends the one before (:func:`_paged_prefill_kernel`):
+    a page is fetched once a KV head a row-block and meets ``G x bt``
+    query rows in one dot, and only the pages on a row-block's diagonal
+    or at its window's floor are masked. ``bt`` and ``heads`` are
+    arithmetic over the shapes and dtypes seen here
+    (:func:`prefill_block_shape`); ``block_t`` overrides ``bt`` (the
+    tests' way to compare block shapes). The call's time is the visible
+    (query, key) pairs' arithmetic — the float32 probabilities-times-V
+    dot first, then the vector passes over the score tile — plus a few
+    microseconds a program; nothing is paid per table entry or per dead
+    page. ``pages_per_block`` > 1 requires a PACKED table (see
+    :func:`_check_pages_per_block`). A row's result does not depend on
+    ``bt``, ``heads`` or ``pages_per_block`` (per-page updates in page
+    order; a page a row sees nothing of leaves its state as it was).
     """
     B, T, H, Dh = q.shape
     quant = isinstance(k_pages, dict)
@@ -998,69 +1234,47 @@ def paged_prefill_attention(q: jax.Array, k_pages, v_pages,
     NP = page_table.shape[1]
     ppb = pages_per_block
     _check_pages_per_block(ppb, NP, kq.shape[1])
-    bs = ppb * page
     G = H // KV
-    block_t = min(block_t, T)
-    if T % block_t:
-        raise ValueError(f"T={T} not a multiple of block_t={block_t}")
-    qh = q.transpose(0, 2, 1, 3)
-    grid = (B, H, T // block_t, NP // ppb)
+    if block_t is not None:
+        block_t = min(block_t, T)
+        if T % block_t:
+            raise ValueError(f"T={T} not a multiple of block_t={block_t}")
+    bt, heads = prefill_block_shape(T, G, KV, page, Dh, q.dtype.itemsize,
+                                    kq.dtype.itemsize, quant, ppb, block_t)
+    nT, rows = T // bt, G * bt
+    # [B, T, H, Dh] -> [B, nT, KV, G * bt, Dh]: a KV head's query heads
+    # side by side in a block's rows.
+    qb = q.reshape(B, nT, bt, KV, G, Dh).transpose(0, 1, 3, 4, 2, 5
+                                                   ).reshape(B, nT, KV, rows, Dh)
 
-    def _live_range(st_b, t):
-        first_q = st_b + t * block_t
-        last = (first_q + block_t - 1) // bs
-        if window:
-            first = jnp.minimum(
-                jnp.maximum(first_q - (window - 1), 0) // bs, last)
-        else:
-            first = 0
-        return first, last
-
-    def _phys_block(pt, b, g):
-        # Gather-free superpage lookup — see paged_decode_attention.
-        p0 = pt[b, g * ppb]
-        return p0 // ppb if ppb > 1 else p0
-
-    def kv_index(b, h, t, j, pt, st, layer):
-        first, last = _live_range(st[b], t)
-        return (layer[0], _phys_block(pt, b, jnp.clip(j, first, last)),
-                h // G, 0, 0)
-
-    def q_index(b, h, t, j, pt, st, layer):
-        return b, h, t, 0
-
-    # Stored rank-5 [L, P, KV, 1, page] scale layout — see
-    # paged_decode_attention. The layer dim is squeezed out of the blocks.
-    kv_spec = pl.BlockSpec((None, ppb, 1, page, Dh), kv_index)
-    s_spec = pl.BlockSpec((None, ppb, 1, 1, page), kv_index)
-    if quant:
-        kv_operands = (k_pages["q"], k_pages["s"],
-                       v_pages["q"], v_pages["s"])
-        kv_specs = [kv_spec, s_spec, kv_spec, s_spec]
-    else:
-        kv_operands = (k_pages, v_pages)
-        kv_specs = [kv_spec, kv_spec]
+    q_spec = pl.BlockSpec((1, nT, heads, rows, Dh),
+                          lambda b, hb, pt, st, layer: (b, 0, hb, 0, 0))
+    kv_operands, buffers = _walk_buffers(k_pages, v_pages, ppb, heads)
 
     out = pl.pallas_call(
-        functools.partial(_paged_prefill_kernel, block_t=block_t, page=page,
-                          window=window, pages_per_block=ppb),
+        functools.partial(_paged_prefill_kernel, block_t=bt, page=page,
+                          window=window, pages_per_block=ppb,
+                          n_table_blocks=NP // ppb),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=grid,
-            in_specs=[pl.BlockSpec((1, 1, block_t, Dh), q_index),
-                      *kv_specs],
-            out_specs=pl.BlockSpec((1, 1, block_t, Dh), q_index),
-            scratch_shapes=[
-                pltpu.VMEM((block_t, 128), jnp.float32),
-                pltpu.VMEM((block_t, 128), jnp.float32),
-                pltpu.VMEM((block_t, Dh), jnp.float32),
-            ],
+            grid=(B, KV // heads),
+            in_specs=[q_spec,
+                      *[pl.BlockSpec(memory_space=pl.ANY)] * len(kv_operands)],
+            out_specs=q_spec,
+            scratch_shapes=[*buffers,
+                            pltpu.VMEM((heads, rows, 1), jnp.float32),
+                            pltpu.VMEM((heads, rows, 1), jnp.float32),
+                            pltpu.VMEM((heads, rows, Dh), jnp.float32),
+                            pltpu.SemaphoreType.DMA((2, len(kv_operands)))],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, H, T, Dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qb.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_PREFILL_VMEM_LIMIT_BYTES),
         interpret=_interpret_default() if interpret is None else interpret,
     )(page_table.astype(jnp.int32), start.astype(jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1), qh, *kv_operands)
-    return out.transpose(0, 2, 1, 3).reshape(B, T, H * Dh)
+      jnp.asarray(layer, jnp.int32).reshape(1), qb, *kv_operands)
+    return out.reshape(B, nT, KV, G, bt, Dh).transpose(0, 1, 4, 2, 3, 5
+                                                       ).reshape(B, T, H * Dh)
 
 
 # ---------------------------------------------------------------------------
@@ -1179,9 +1393,6 @@ def make_paged_attention_fn(page_table: jax.Array, max_seq: int,
             return {"q": val, "s": P(None, "model", None, None)}
         return val
 
-    def _block_t(T):
-        return block_t if block_t is not None else min(T & (-T), 128)
-
     def attention_fn(q, k_new, v_new, layer_k, layer_v, lengths, active=None):
         # Phase marker (ISSUE 8): trace-time metadata so captures name
         # the paged kernels inside the layer's attention scope.
@@ -1206,11 +1417,10 @@ def make_paged_attention_fn(page_table: jax.Array, max_seq: int,
             return out, layer_k, layer_v
         shard = msize > 1 and KV % msize == 0 and H % msize == 0
         pool = _pool_spec(layer_k)
-        bt = _block_t(T)
         if shard:
             f = shard_map(
                 lambda q_, k_, v_, pt_, st_: paged_prefill_attention(
-                    q_, k_, v_, pt_, st_, block_t=bt, window=window,
+                    q_, k_, v_, pt_, st_, block_t=block_t, window=window,
                     pages_per_block=pages_per_block, interpret=interpret),
                 mesh=mesh,
                 in_specs=(P(None, None, "model", None), pool, pool,
@@ -1220,7 +1430,7 @@ def make_paged_attention_fn(page_table: jax.Array, max_seq: int,
         else:
             out = paged_prefill_attention(
                 q, layer_k, layer_v, page_table, lengths,
-                block_t=bt, window=window,
+                block_t=block_t, window=window,
                 pages_per_block=pages_per_block, interpret=interpret)
         return out, layer_k, layer_v
 
@@ -1242,7 +1452,7 @@ def make_paged_attention_fn(page_table: jax.Array, max_seq: int,
         with jax.named_scope("attention.paged_prefill"):
             out = paged_prefill_attention(
                 q, pool_k, pool_v, page_table, lengths, layer=layer,
-                block_t=_block_t(q.shape[1]), window=window,
+                block_t=block_t, window=window,
                 pages_per_block=pages_per_block, interpret=interpret)
         return out, pool_k, pool_v
 

@@ -132,6 +132,36 @@ def test_paged_prefill_compiles_for_v5e(chips, quant, window, ppb):
         sds((B,), jnp.int32))
 
 
+@pytest.mark.parametrize("case", [
+    # (query heads, KV heads, tokens) -> (bt, KV heads a program).
+    # SmallThinker: a fold of SEVEN (448 rows a head: whole sublanes, not
+    # whole MXU tiles), two heads a program ...
+    ((28, 4, T), (64, 2)),
+    # ... and the smallest bucket at each served fold.
+    ((28, 4, 8), (8, 4)),
+    ((32, 8, 8), (8, 8)),
+    ((64, 8, 8), (8, 8)),
+], ids=["28over4-t512", "28over4-t8", "32over8-t8", "64over8-t8"])
+@WINDOWS
+def test_paged_prefill_fold_compiles_for_v5e(chips, window, case):
+    """PR 37: the block shapes the rule picks at the served folds are what
+    the chip's compiler must find room for (int8 pool, as served)."""
+    (heads, kv, tokens), shape = case
+    assert pa.prefill_block_shape(tokens, heads // kv, kv, PAGE, DH, 2, 1,
+                                  True, 1) == shape
+    one = SingleDeviceSharding(chips[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    side = {"q": sds((POOL, kv, PAGE, DH), jnp.int8),
+            "s": sds((POOL, kv, 1, PAGE), jnp.float32)}
+    _compiled_kernel(
+        lambda *a: pa.paged_prefill_attention(
+            *a, window=window, interpret=False),
+        sds((2, tokens, heads, DH), jnp.bfloat16), side, side,
+        sds((2, NP), jnp.int32), sds((2,), jnp.int32))
+
+
 @KV_DTYPES
 @pytest.mark.parametrize("kind", ["decode", "prefill"])
 def test_tp4_attention_compiles_on_the_engine_mesh(chips, quant, kind):
@@ -205,15 +235,18 @@ def test_stacked_decode_compiles_at_the_served_geometry(chips, geometry):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
 
 
+@pytest.mark.parametrize("tokens", [8, T])
 @pytest.mark.parametrize("geometry", list(SERVED))
-def test_stacked_prefill_compiles_at_the_served_geometry(chips, geometry):
+def test_stacked_prefill_compiles_at_the_served_geometry(chips, geometry,
+                                                         tokens):
     """PR 34: the prefill kernel on the whole layer-stacked int8 pool, a
-    512-token chunk a row, the layer a traced scalar: no slice of the pool
-    is among its operands (the transposes of q and of the output are the
-    only temporaries)."""
+    512-token chunk a row (PR 37: and the smallest bucket), the layer a
+    traced scalar: no slice of the pool is among its operands (the
+    transposes of q and of the output are the only temporaries)."""
     layers, pages, kv, heads, slots, width, window = SERVED[geometry]
     sds, side, _ = _stacked(chips, geometry)
     rows = PREFILL_ROWS[geometry]
+    T = tokens
     compiled = jax.jit(
         lambda q, pk, pv, tbl, start, layer: pa.paged_prefill_attention(
             q, pk, pv, tbl, start, layer=layer, window=window,
@@ -460,3 +493,53 @@ def test_prefill_step_leaves_the_pool_where_it_lies(chips, monkeypatch):
         compiled, config, pages,
         "{{0}: (9, {}), {1}: (10, {}), {2}: (11, {}), {3}: (12, {})}",
         "attention.paged_prefill")
+
+
+def test_prefill_step_attends_a_layer_in_one_call_with_no_page_axis(
+        chips, monkeypatch):
+    """PR 37, read from the traced ``prefill_step`` of the same two-layer
+    engine: under ``attention.paged_prefill`` the layer scan's body holds
+    ONE Pallas call (the benchmark counts chunks by them); every pool
+    side reaches it un-sliced — the whole stacked ``[L, P, KV, page, Dh]``
+    pool (and scale planes), left in HBM — and its grid is ``(rows,
+    KV // heads)``: no axis steps through the table's 32 pages or the 32
+    query heads (the parent's grid was ``(1, 32, 4, 32)``)."""
+    slots, pages, bucket = 8, 513, 512
+    engine, config, state, _ = _two_layer_engine(
+        chips, monkeypatch, slots, pages, 2)
+    rng = jax.random.key(0)
+
+    def row(dtype, *shape):
+        return jax.ShapeDtypeStruct((1, *shape), dtype)
+    jaxpr = jax.make_jaxpr(engine._prefill_fn)(
+        *state, row(jnp.int32, bucket), row(jnp.int32), row(jnp.int32),
+        row(jnp.int32), row(jnp.float32), row(jnp.float32),
+        row(jnp.int32), row(jnp.float32), row(jnp.float32),
+        jax.ShapeDtypeStruct(rng.shape, rng.dtype))
+
+    def calls(jp, scans=0):
+        for eqn in jp.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn, scans
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from calls(sub, scans + (eqn.primitive.name == "scan"))
+    attends = [(eqn, scans) for eqn, scans in calls(jaxpr.jaxpr)
+               if "attention.paged_prefill" in str(eqn.source_info.name_stack)]
+    assert len(attends) == 1 and attends[0][1] == 1, attends
+    eqn = attends[0][0]
+    bt, heads = pa.prefill_block_shape(
+        bucket, config.n_heads // config.n_kv_heads, config.n_kv_heads,
+        PAGE, DH, 2, 1, True, 1)
+    assert tuple(eqn.params["grid_mapping"].grid) \
+        == (1, config.n_kv_heads // heads)
+    pool = {(2, pages, config.n_kv_heads, PAGE, DH): 0,
+            (2, pages, config.n_kv_heads, 1, PAGE): 0}
+    for var in eqn.invars:
+        if var.aval.shape in pool:
+            pool[var.aval.shape] += 1
+    assert list(pool.values()) == [2, 2], pool       # K, V; their scales
+    # In HBM, whole: a BlockSpec cuts q and out alone into blocks.
+    spaces = [str(bm.transformed_block_aval.memory_space)
+              for bm in eqn.params["grid_mapping"].block_mappings]
+    assert spaces == ["None"] + ["any"] * 4 + ["None"], spaces
+

@@ -182,7 +182,8 @@ async def test_contexts_past_window_and_ring_are_served_as_the_reference(
     pass the ring's 56 tokens in prefill, a third passes it while decoding;
     every served token stands at the reference's maximum, the rings turned,
     the global group kept every page, and every slot left both groups."""
-    before = engine.stats()["kv_ring_recycled_total"]
+    at_start = engine.stats()
+    before = at_start["kv_ring_recycled_total"]
     reqs = await asyncio.gather(
         generate(engine, prompt(100, 1), 12),
         generate(engine, prompt(70, 2), 20),
@@ -194,6 +195,21 @@ async def test_contexts_past_window_and_ring_are_served_as_the_reference(
     # Pages past the ring: ceil(112 / 8) - 7, ceil(90 / 8) - 7 and
     # ceil(80 / 8) - 7 at the least (a burst maps a little ahead).
     assert st["kv_ring_recycled_total"] - before >= 7 + 5 + 3
+    # The paged prefill kernel's walk, counted in BOTH groups (ISSUE 37):
+    # chunks of 16 and a last bucket of 8 a prompt, one row-block each;
+    # the global group walks up to the chunk's last page, the windowed
+    # one from its window's floor, and a grid with a page axis stepped
+    # through both tables of 16.
+    from llmapigateway_tpu.ops.paged_attention import prefill_pages_walked
+    chunks = [(pos, 16 if n - pos >= 16 else 8)
+              for n in (100, 70, 50) for pos in range(0, n, 16)]
+    walked = sum(prefill_pages_walked([pos], t, t, 8, window, 16)[0]
+                 for pos, t in chunks for window in (0, 16))
+    assert st["prefill_kv_pages_walked_total"] \
+        - at_start["prefill_kv_pages_walked_total"] == walked
+    assert st["prefill_kv_pages_table_total"] \
+        - at_start["prefill_kv_pages_table_total"] == len(chunks) * 2 * 16
+    assert 2 * walked < len(chunks) * 2 * 16
     assert [g["pages_free"] for g in st["kv_groups"]] == [3 * WHOLE, 3 * RING]
     engine.kv_groups.check_invariants()
     assert st["moe_assignments_total"] == st["moe_assignments_local_total"] > 0

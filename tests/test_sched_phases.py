@@ -470,11 +470,11 @@ def test_the_annotation_option_is_gone():
 def test_prefill_record_snapshot():
     rec = fl.FlightRecorder(capacity=16, clock=FakeClock(50.0))
     seq = rec.record(fl.PREFILL, t=49.5, dur_ms=166.8, depth=2, val=512.0,
-                     tokens=900, free_pages=1024, spec_acc=4096)
+                     tokens=900, free_pages=1024, spec_acc=4096, chunks=58)
     assert rec.snapshot() == [{
         "seq": seq, "t": 49.5, "kind": "prefill", "dur_ms": 166.8,
         "rows": 2, "bucket": 512, "tokens": 900, "pos_lo": 1024,
-        "pos_hi": 4096}]
+        "pos_hi": 4096, "pages_walked": 58}]
     # No lifecycle counter moves, and the STEP record's shape is untouched.
     assert rec.stats()["flight_admits"] == 0
     rec.record(fl.STEP, flag=fl.F_PREFILL, chunks=1, dur_ms=1.0)
@@ -483,11 +483,12 @@ def test_prefill_record_snapshot():
 
 async def test_engine_leaves_one_prefill_record_per_dispatch(engine):
     try:
-        before = engine.flight.seq
+        before, stats = engine.flight.seq, engine.stats()
         # 70 tokens in chunks of 32, on a prompt no earlier test left in
         # the prefix cache.
         await _run_one(engine, range(90, 20, -1), 4)
         snap = engine.flight.snapshot(since=before - 1)
+        after = engine.stats()
     finally:
         await engine.stop()
     pre = [r for r in snap if r["kind"] == "prefill"]
@@ -499,6 +500,14 @@ async def test_engine_leaves_one_prefill_record_per_dispatch(engine):
     assert [r["bucket"] for r in pre] == [32, 32, 8]
     assert all(r["rows"] == 1 and r["pos_hi"] == r["pos_lo"] for r in pre)
     assert all(r["dur_ms"] > 0 for r in pre)
+    # The paged prefill kernel's walk (ISSUE 37), pages of 16 and a table
+    # of 8: each chunk walks up to the page of its last token, where a
+    # grid with a page axis stepped through the table.
+    assert [r["pages_walked"] for r in pre] == [2, 4, 5]
+    assert after["prefill_kv_pages_walked_total"] \
+        - stats["prefill_kv_pages_walked_total"] == 11
+    assert after["prefill_kv_pages_table_total"] \
+        - stats["prefill_kv_pages_table_total"] == 3 * 8
     # Each lies on the ring's one timeline, before the step that ran it.
     ts = [r["t"] for r in snap]
     assert ts == sorted(ts)

@@ -204,3 +204,21 @@ def test_paged_prefill_lowers_for_tpu(quant, window, ppb):
     _lower(lambda *a: pa.paged_prefill_attention(
         *a, window=window, pages_per_block=ppb, interpret=False),
         qp, pk, pv, ptab, st)
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8kv"])
+@pytest.mark.parametrize("window", [0, 96], ids=["full", "windowed"])
+@pytest.mark.parametrize("t", [8, T], ids=["t8", "t128"])
+def test_paged_prefill_fold_of_seven_lowers_for_tpu(quant, window, t):
+    """PR 37: SmallThinker's 28 query heads over 4 KV heads — a fold of
+    SEVEN, so a block's rows are a multiple of 8 sublanes and not of 128
+    — and the smallest prefill bucket, where every KV head folds into
+    one program."""
+    key = jax.random.PRNGKey(0)
+    qp = jax.random.normal(key, (B, t, 7 * KV, Dh), jnp.bfloat16)
+    pk, pv = _paged_kv(quant)
+    ptab = jnp.array([[2, 3], [4, 5]], jnp.int32)
+    st = jnp.array([0, 64], jnp.int32)
+    _lower(lambda *a: pa.paged_prefill_attention(
+        *a, window=window, interpret=False), qp, pk, pv, ptab, st)
+
