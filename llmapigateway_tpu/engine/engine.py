@@ -345,6 +345,8 @@ class InferenceEngine:
             self._refuse_for_recurrent_state()
         if len(model_cfg.cache_groups) > 1:
             self._refuse_for_cache_groups()
+        if model_cfg.is_mla:
+            self._refuse_for_latent_cache()
 
         self.tokenizer = load_tokenizer(
             engine_cfg.tokenizer_path or engine_cfg.model_path or None,
@@ -429,6 +431,12 @@ class InferenceEngine:
         # page axis stepped through. Monotone; worker thread.
         self._prefill_pages_walked = 0
         self._prefill_pages_table = 0
+        # A latent layer's keys attended (ISSUE 38): per layer, summed
+        # over calls, counted at dispatch — a decode step's over the
+        # active slots (each sees its context and itself), a prefill
+        # call's over its rows and positions. Monotone; worker thread.
+        self._mla_decode_keys = 0
+        self._mla_prefill_keys = 0
         # Device observability plane (ISSUE 8): per-kernel cost registry
         # (worker thread records, lock-guarded internally), the HBM
         # memory ledger, and the process-wide XLA compile monitor. The
@@ -508,6 +516,45 @@ class InferenceEngine:
         elif cfg.model_path:
             why = ("model_path: no checkpoint mapping for this family "
                    "(engine/checkpoint.py)")
+        if why:
+            raise ValueError(
+                f"the {self.model_cfg.family!r} family does not support "
+                f"{why}")
+
+    def _refuse_for_latent_cache(self) -> None:
+        """What a family whose cache group is a LATENT pool (one pool of
+        ``latent_width`` numbers a token, models/mla.py) cannot be served
+        with yet — refused at build, each with its reason."""
+        cfg, why = self.cfg, None
+        if not self.paged:
+            why = ("kv_layout 'contiguous': the latent cache is a page "
+                   "pool, and no dense layout of it exists")
+        elif self.kv_quant:
+            why = ("kv_quant 'int8': the latent pool is bfloat16; an int8 "
+                   "latent needs scale planes the latent kernel does not "
+                   "read. Set kv_quant ''")
+        elif cfg.prefix_cache:
+            why = ("prefix_cache: the radix cache shares K/V pages, and "
+                   "has no rule yet for sharing latent pages; set "
+                   "prefix_cache false")
+        elif self.spec_k:
+            why = ("spec_draft_len: the verify path reads a K and a V "
+                   "pool, not a latent one")
+        elif self.mesh.size > 1:
+            why = (f"mesh {dict(self.mesh.shape)}: the latent pool has one "
+                   f"key head, which no axis divides, and the held experts "
+                   f"have no sharding rule yet")
+        elif cfg.disaggregation.enabled:
+            why = ("disaggregation: a handoff of latent pages between "
+                   "pools is not wired")
+        elif cfg.model_path:
+            why = ("model_path: no checkpoint mapping for this family "
+                   "(engine/checkpoint.py)")
+        elif (self.kv_page % 128 and jax.default_backend() == "tpu"
+              and self._resolve_attention_impl() == "pallas"):
+            why = (f"kv_page_size {self.kv_page}: a latent page lies "
+                   f"token-minor, and the chip's kernels move whole tiles "
+                   f"of 128 lanes")
         if why:
             raise ValueError(
                 f"the {self.model_cfg.family!r} family does not support "
@@ -717,7 +764,9 @@ class InferenceEngine:
                 groups.append(CacheGroup(
                     periods * len(positions), window, ring,
                     PageAllocator(num_pages, page, self.B, self.S,
-                                  pages_per_block=self.kv_ppb)))
+                                  pages_per_block=self.kv_ppb),
+                    kind="latent" if c.is_mla else "kv",
+                    token_bytes=self._kv_token_bytes()))
             self.kv_groups = CacheGroups(groups)
             num_pages = self.allocator.num_pages
             # Radix prefix cache (ISSUE 6): cross-request KV reuse over
@@ -752,7 +801,9 @@ class InferenceEngine:
                 from ..models.hybrid import HybridCache
                 rep_sh = NamedSharding(self.mesh, P())
                 n_lin = c.layer_period - len(c.softmax_positions)
-                sides = (side,) * len(self.kv_groups)
+                k_sh = v_sh = (side,) * len(self.kv_groups)
+                if c.is_mla:        # ONE latent pool, no V side
+                    k_sh, v_sh = (rep_sh,), ()
                 self.cache = jax.jit(
                     partial(HybridCache.create, c,
                             tuple(g.allocator.num_pages
@@ -760,7 +811,7 @@ class InferenceEngine:
                             page, self.B, self.dtype,
                             kv_quant=self.kv_quant),
                     out_shardings=HybridCache(
-                        k=sides, v=sides, counters=rep_sh,
+                        k=k_sh, v=v_sh, counters=rep_sh,
                         state=(rep_sh,) * n_lin, conv=(rep_sh,) * n_lin))()
             else:
                 self.cache = jax.jit(
@@ -1111,6 +1162,7 @@ class InferenceEngine:
         model forward signature stays cache-layout-agnostic."""
         c = self.model_cfg
         family_forward = forward_fn(c)
+        from ..ops.latent_attention import LatentAttention
         from ..ops.paged_attention import (PagedKVCache,
                                            make_paged_attention_fn,
                                            pool_in_place)
@@ -1139,10 +1191,13 @@ class InferenceEngine:
             # (stale-pool gather + mixed-precision self-block) instead
             # of the chunk path — required for int8 greedy parity and
             # skips the per-layer pool scatters either way.
-            attn = tuple(make_paged_attention_fn(
-                table, max_seq=S, impl=impl, mesh=mesh, window=window,
-                pages_per_block=self.kv_ppb, spec=spec)
-                for table, window in zip(tables, windows))
+            if c.is_mla:
+                attn = (LatentAttention(tables[0], S, impl),)
+            else:
+                attn = tuple(make_paged_attention_fn(
+                    table, max_seq=S, impl=impl, mesh=mesh, window=window,
+                    pages_per_block=self.kv_ppb, spec=spec)
+                    for table, window in zip(tables, windows))
             return family_forward(params, c, tokens, lengths, cache,
                                   active=active,
                                   attention_fn=(attn if len(attn) > 1
@@ -2495,10 +2550,17 @@ class InferenceEngine:
         from ..ops import paged_attention as pa
         c, page = self.model_cfg, self.allocator.page_size
         itemsize = jnp.dtype(self.dtype).itemsize
-        bt = pa.prefill_block_shape(
-            bucket, c.n_heads // c.n_kv_heads, c.n_kv_heads, page,
-            c.head_dim, itemsize, 1 if self.kv_quant else itemsize,
-            bool(self.kv_quant), self.kv_ppb)[0]
+        if c.is_mla:
+            from ..ops.latent_attention import latent_block_t
+            bt = latent_block_t(bucket, c.n_heads)
+            # Keys a layer's call attends: row b's query t sees pos + t + 1.
+            self._mla_prefill_keys += sum(
+                bucket * int(p) + bucket * (bucket + 1) // 2 for p in poss)
+        else:
+            bt = pa.prefill_block_shape(
+                bucket, c.n_heads // c.n_kv_heads, c.n_kv_heads, page,
+                c.head_dim, itemsize, 1 if self.kv_quant else itemsize,
+                bool(self.kv_quant), self.kv_ppb)[0]
         walked = 0
         for g in self.kv_groups:
             w, t = pa.prefill_pages_walked(
@@ -3040,6 +3102,7 @@ class InferenceEngine:
                 self.lengths.copy(), self.last_token.copy())
             # Host length mirror advances at DISPATCH time — the burst-
             # capping logic in _step must see the device-true lengths.
+            self._count_mla_decode(n_steps)
             self.lengths[self.active] += n_steps
             if self.spec_k:
                 self._d_hist_fresh = False
@@ -3106,10 +3169,20 @@ class InferenceEngine:
                 for t in range(m):
                     self.hist[slot, L + 1 + t] = int(step_tokens[t][slot])
             self.last_token[slot] = int(step_tokens[-1][slot])
+        self._count_mla_decode(n_steps)
         self.lengths[self.active] += n_steps
         if self.spec_k:
             self._d_hist_fresh = False
         return pre + step_tokens
+
+    def _count_mla_decode(self, n_steps: int) -> None:
+        """Add a burst of ``n_steps`` to a latent layer's decode keys:
+        step ``i`` of an active slot at length ``n`` sees ``n + i + 1``."""
+        if self.model_cfg.is_mla:
+            live = self.lengths[self.active].astype(np.int64)
+            self._mla_decode_keys += int(
+                n_steps * live.sum()
+                + live.size * n_steps * (n_steps + 1) // 2)
 
     # -- emission / lifecycle (event-loop thread only) ------------------------
     def _emit_token(self, req: GenRequest) -> None:
@@ -3297,6 +3370,17 @@ class InferenceEngine:
             self._param_bytes_cache = b
         return b
 
+    def _kv_token_bytes(self) -> int:
+        """Bytes a token keeps in ONE layer of a cache group's pool: K and
+        V of every KV head (int8: with their float32 scales), or a latent
+        layer's one row."""
+        c = self.model_cfg
+        itemsize = int(np.dtype(self.dtype).itemsize)
+        if c.is_mla:
+            return c.latent_width * itemsize
+        elem, scale = (1, 4) if self.kv_quant else (itemsize, 0)
+        return 2 * c.n_kv_heads * (c.head_dim * elem + scale)
+
     def _kv_bytes_per_step(self) -> int:
         """HBM bytes one decode step reads from the KV cache: the live
         (window-clamped) stale prefix of every active slot, K and V, at
@@ -3312,14 +3396,10 @@ class InferenceEngine:
             periods * len(ps)
             * int((np.minimum(live, w) if w else live).sum())
             for w, ps in c.cache_groups)
-        if self.kv_quant:
-            elem = 1.0 + 4.0 / c.head_dim
-        else:
-            # np.dtype, not jnp: host metadata — stats() runs on the event
-            # loop and must not even look like a device sync (graftlint v2
-            # chases this call from the async stats handlers).
-            elem = float(np.dtype(self.dtype).itemsize)
-        return int(2 * c.n_kv_heads * c.head_dim * elem * reads)
+        # np.dtype (in _kv_token_bytes), not jnp: host metadata — stats()
+        # runs on the event loop and must not even look like a device sync
+        # (graftlint v2 chases this call from the async stats handlers).
+        return self._kv_token_bytes() * reads
 
     def _state_bytes(self) -> int:
         """Bytes of recurrent state and conv tails resident beside the KV
@@ -3342,16 +3422,13 @@ class InferenceEngine:
         ``tracked_fn`` sums."""
         from ..obs.device import HbmLedger, device_memory_stats
         c = self.model_cfg
-        if self.kv_quant:
-            kv_elem, kv_scale = 1, 4        # int8 K/V + fp32/token scale
-        else:
-            kv_elem, kv_scale = int(np.dtype(self.dtype).itemsize), 0
         page = self.kv_page
-        token_bytes = 2 * c.n_kv_heads * (c.head_dim * kv_elem + kv_scale)
+        token_bytes = self._kv_token_bytes()
         kv_pools: dict[str, int] = {}
         if self.paged:
             for g in self.kv_groups:
-                name = f"window{g.window}" if g.window else "global"
+                name = ("latent" if g.kind == "latent" else
+                        f"window{g.window}" if g.window else "global")
                 kv_pools[name] = (g.layers * g.allocator.num_pages * page
                                   * token_bytes)
             kv_pool = sum(kv_pools.values())
@@ -3480,6 +3557,9 @@ class InferenceEngine:
             out["moe_assignments_total"] = self._moe_totals[0]
             out["moe_assignments_local_total"] = self._moe_totals[1]
             out["moe_experts_hit_total"] = self._moe_totals[2]
+        if self.model_cfg.is_mla:
+            out["mla_decode_keys_total"] = self._mla_decode_keys
+            out["mla_prefill_keys_total"] = self._mla_prefill_keys
         gauge = (self._ema_step_ms_stats
                  if self._ema_step_ms_stats is not None
                  else self._step_ms_estimate())
@@ -3821,4 +3901,6 @@ def _parse_rope_scaling(block: dict | None):
         low_freq_factor=float(block.get("low_freq_factor", 1.0)),
         high_freq_factor=float(block.get("high_freq_factor", 4.0)),
         original_max_seq=int(block.get("original_max_position_embeddings",
-                                       8192)))
+                                       8192)),
+        **{k: float(block[k]) for k in ("beta_fast", "beta_slow", "mscale",
+                                        "mscale_all_dim") if k in block})

@@ -333,10 +333,23 @@ class CacheGroup:
     are (``layers``, stacked in ONE page pool), their window (0: the whole
     context), the pages a slot holds when the ring runs (``ring_pages``,
     0: the whole context's), their allocator with its host page table,
-    and whether that table changed since its last upload (``dirty``)."""
+    and whether that table changed since its last upload (``dirty``).
+    ``kind`` is the pool's shape — "kv": a K and a V pool ``[layers,
+    pages, KV, page, Dh]`` (ops/paged_attention.py); "latent": ONE pool
+    ``[layers, pages, latent_width, page]`` (ops/latent_attention.py) —
+    and ``token_bytes`` what a token keeps in one of its layers. Pages,
+    tables and admission are the same for both."""
 
     def __init__(self, layers: int, window: int, ring_pages: int,
-                 allocator: PageAllocator):
+                 allocator: PageAllocator, kind: str = "kv",
+                 token_bytes: int = 0):
+        if kind not in ("kv", "latent"):
+            raise ValueError(f"unknown cache group kind {kind!r}")
+        if kind == "latent" and (window or ring_pages):
+            raise ValueError("a latent cache group keeps the whole context: "
+                             "no window, no ring")
+        self.kind = kind
+        self.token_bytes = token_bytes
         self.layers = layers
         self.window = window
         self.ring_pages = ring_pages
@@ -351,7 +364,8 @@ class CacheGroup:
 
     def stats(self) -> dict[str, int]:
         a = self.allocator
-        return {"layers": self.layers, "window": self.window,
+        return {"kind": self.kind, "layers": self.layers,
+                "window": self.window, "token_bytes": self.token_bytes,
                 "pages": a.num_pages - a.pages_per_block,
                 "pages_free": a.free_pages,
                 "pages_per_slot": self.pages_per_slot}

@@ -6,6 +6,7 @@ Llama-3-70B (config 5, multi-host).
 """
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field, replace
 
 
@@ -17,24 +18,56 @@ class RopeScaling:
     wavelengths divided by ``factor``, short ones untouched, smooth
     interpolation between ``low_freq_factor``/``high_freq_factor`` bands of
     the ``original_max_seq`` context). ``linear`` — uniform position
-    interpolation (every frequency divided by ``factor``).
+    interpolation (every frequency divided by ``factor``). ``yarn`` — by
+    parts: a pair that turns more than ``beta_fast`` times inside
+    ``original_max_seq`` keeps its frequency, one that turns less than
+    ``beta_slow`` times is divided by ``factor``, a linear ramp over the
+    pair index between; cos and sin are scaled by ``mscale`` over
+    ``mscale_all_dim`` (each ``0.1 m ln(factor) + 1``), and where
+    ``mscale_all_dim`` is set the softmax scale by its square
+    (:meth:`softmax_mscale`).
     """
-    rope_type: str = "llama3"      # "llama3" | "linear"
+    rope_type: str = "llama3"      # "llama3" | "linear" | "yarn"
     factor: float = 8.0
     low_freq_factor: float = 1.0
     high_freq_factor: float = 4.0
     original_max_seq: int = 8192
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
     def __post_init__(self):
-        if self.rope_type not in ("llama3", "linear"):
+        if self.rope_type not in ("llama3", "linear", "yarn"):
             raise ValueError(
                 f"unsupported rope_scaling type {self.rope_type!r}; "
-                f"supported: llama3, linear")
+                f"supported: llama3, linear, yarn")
+
+    @staticmethod
+    def _yarn_mscale(factor: float, m: float) -> float:
+        return 0.1 * m * math.log(factor) + 1.0 if factor > 1 and m else 1.0
+
+    @property
+    def table_mscale(self) -> float:
+        """What YaRN multiplies cos and sin by (1 for the other types)."""
+        if self.rope_type != "yarn":
+            return 1.0
+        return (self._yarn_mscale(self.factor, self.mscale)
+                / self._yarn_mscale(self.factor, self.mscale_all_dim))
+
+    @property
+    def softmax_mscale(self) -> float:
+        """What YaRN multiplies the attention scores by: the square of the
+        ``mscale_all_dim`` magnitude (1 where it is not set)."""
+        if self.rope_type != "yarn":
+            return 1.0
+        return self._yarn_mscale(self.factor, self.mscale_all_dim) ** 2
 
 
 @dataclass(frozen=True)
 class ModelConfig:
     # "llama" | "qwen2" | "gemma" | "mixtral" | "hybrid" | "smallthinker"
+    # | "mistral4"
     family: str = "llama"
     vocab_size: int = 32000
     d_model: int = 2048
@@ -96,6 +129,21 @@ class ModelConfig:
     moe_router: str = "sigmoid"
     moe_act: str = "silu"                 # "silu" | "relu"
     router_reads_block_input: bool = False
+    # Latent attention (models/mla.py; 0 ranks: none). Queries come through
+    # a normed bottleneck of ``q_lora_rank``; keys and values through ONE
+    # normed latent of ``kv_lora_rank`` a token, which with one rotary key
+    # of ``qk_rope_head_dim`` shared by all heads is all the cache keeps
+    # (``latent_width`` numbers a token a layer: ops/latent_attention.py).
+    # A head's query and key are ``qk_nope_head_dim`` un-rotated numbers
+    # beside the rotary ones; its value has ``v_head_dim``.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_interleave: bool = False   # rotary pairs (2i, 2i+1), not (i, i+half)
+    # Queries scaled by 1 + beta ln(1 + floor(pos / original_max_seq)).
+    query_scale_beta: float = 0.0
 
     def __post_init__(self):
         for name in ("window_layout", "rope_layout"):
@@ -116,6 +164,16 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_width(self) -> int:
+        """Numbers a latent-attention layer caches a token: the normed
+        latent and the one rotated key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
 
     @property
     def experts_held(self) -> int:
@@ -305,6 +363,42 @@ PRESETS["solar-open2-250b-ep8"] = replace(
 # every layer whole — all 64 experts, every head, the whole vocabulary.
 PRESETS["smallthinker-21b-pp3"] = replace(
     PRESETS["smallthinker-21b"], n_layers=20)
+
+
+# Mistral-Small-4-119B-2603 (HF: mistralai/Mistral-Small-4-119B-2603) at its
+# PUBLISHED sizes: 36 layers of latent attention (32 heads of 64 un-rotated +
+# 64 rotary query/key numbers and 128 value numbers through a 1024 query and
+# a 256 key/value bottleneck; YaRN x128 over 8192, interleaved pairs,
+# position-scaled queries) and 128 softmax-routed experts of width 2048,
+# top-4, beside one shared expert. One global cache group of the latent kind.
+PRESETS["mistral-small4-119b"] = ModelConfig(
+    family="mistral4", vocab_size=131072, d_model=4096, n_layers=36,
+    n_heads=32, n_kv_heads=32, head_dim_override=128, d_ff=12288,
+    rope_theta=10000.0, rms_eps=1e-6, max_seq_len=1048576,
+    rope_scaling=RopeScaling(rope_type="yarn", factor=128.0,
+                             original_max_seq=8192, beta_fast=32.0,
+                             beta_slow=1.0, mscale=1.0, mscale_all_dim=1.0),
+    n_experts=128, experts_per_token=4, d_ff_expert=2048, n_shared_experts=1,
+    layer_period=1, moe_router="softmax", q_lora_rank=1024, kv_lora_rank=256,
+    qk_nope_head_dim=64, qk_rope_head_dim=64, v_head_dim=128,
+    rope_interleave=True, query_scale_beta=0.1)
+# The same block at CPU-test size: YaRN x8 over 32 positions, so a context
+# of a few pages crosses the original length.
+PRESETS["tiny-mistral4-test"] = replace(
+    PRESETS["mistral-small4-119b"], vocab_size=512, d_model=64, n_layers=4,
+    n_heads=4, n_kv_heads=4, head_dim_override=16, d_ff=128, max_seq_len=256,
+    rope_scaling=RopeScaling(rope_type="yarn", factor=8.0,
+                             original_max_seq=32, beta_fast=4.0,
+                             beta_slow=1.0, mscale=1.0, mscale_all_dim=1.0),
+    n_experts=16, experts_per_token=2, d_ff_expert=32, q_lora_rank=32,
+    kv_lora_rank=32, qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=16)
+# What ONE v5e chip holds of it as one of 4 that share each layer of a
+# 3-stage pipeline (benchmark/configs/mistral-small4-119b-ep4.json): 12
+# layers, 32 of the 128 experts, a quarter of the vocabulary rows. Every
+# width, the router's 128 outputs and its 4 experts per token stay.
+PRESETS["mistral-small4-119b-ep4"] = replace(
+    PRESETS["mistral-small4-119b"], n_layers=12, vocab_size=32768,
+    n_experts_held=32)
 
 
 def get_preset(name: str) -> ModelConfig:

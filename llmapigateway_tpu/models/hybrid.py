@@ -27,6 +27,12 @@ grouped by the KV they must keep (``cache_groups``: one page pool, page
 table and provider a group), the router may score by softmax and read the
 block's input, and the experts' gate may be a ReLU.
 
+And it serves a period of ONE latent-attention layer (``ModelConfig.is_mla``;
+Mistral-Small-4): the attention sub-block is ``models/mla.py``'s, its cache
+group a latent pool (``HybridCache.k`` holds it, ``v`` is empty) that BOTH
+step programs carry through the scan and write in place, and the expert
+layer is the one above with a softmax router and a shared expert.
+
 TPU-first decisions:
 
 * ``lax.scan`` over PERIODS, one compiled body whatever the depth. Every
@@ -59,6 +65,7 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from . import mla
 from .config import ModelConfig
 from .llama import (_GATE_ACTS, apply_rope, rms_norm, rope_tables,
                     swiglu_mlp)
@@ -85,7 +92,9 @@ class HybridCache(NamedTuple):
     """``k``, ``v``: the page pools of the softmax layers, a tuple over
     ``ModelConfig.cache_groups``, each as ``PagedKVCache``'s ([layers of
     the group, pages of the group, KV, page, Dh], or its int8 dict; a
-    group's layer ``j`` of period ``p`` lies at ``p * n + j``).
+    group's layer ``j`` of period ``p`` lies at ``p * n + j``). A latent
+    layer's group is ONE pool (``ops/latent_attention.py``: [layers,
+    pages, latent_width, page]) in ``k``, and ``v`` is ``()``.
     ``state`` and ``conv``: the linear layers' recurrent state and the
     last inputs of their convolutions, one fixed block per slot — a tuple
     over a period's linear layers of [P, B, H, dk, dv] float32 and of
@@ -112,6 +121,12 @@ class HybridCache(NamedTuple):
         if isinstance(num_pages, int):
             num_pages = (num_pages,) * len(c.cache_groups)
         periods = c.n_layers // c.layer_period
+        if c.is_mla:
+            from ..ops.latent_attention import create_latent_pool
+            return cls(k=(create_latent_pool(
+                c.n_layers, num_pages[0], page_size, c.latent_width, dtype),),
+                v=(), state=(), conv=(),
+                counters=jnp.zeros((3,), jnp.int32))
         pools = [PagedKVCache.create(
             replace(c, n_layers=periods * len(positions)), pages, page_size,
             dtype, kv_quant)
@@ -153,6 +168,8 @@ def init_params(config: ModelConfig, key: jax.Array,
                   wf_up [P,r,Hl*dk], f_bias [P,Hl*dk], a_log [P,Hl],
                   wbeta [P,D,Hl], wg_down [P,D,r], wg_up [P,r,Hl*dk],
                   out_norm [P,dk], wo [P,Hl*dk,D], mlp/...}
+      layers/attn of a latent layer: models/mla.py ``init_layer``'s tree,
+                   stacked [P, ...], with its mlp/...
       .../mlp/{norm [P,D], router [P,D,E], wg, wu [P,held,D,F],
                wd [P,held,F,D], sg, su [P,D,Fs], sd [P,Fs,D]}
                (``sg``, ``su``, ``sd`` with shared experts only)
@@ -186,7 +203,7 @@ def init_params(config: ModelConfig, key: jax.Array,
     def dense(k, *shape, scale=1.0, name=""):
         w = (jax.random.normal(k, shape, jnp.float32)
              * (scale / math.sqrt(shape[-2]))).astype(dtype)
-        if quant and name in QUANT_KEYS:
+        if quant and name in QUANT_KEYS | mla.QUANT_KEYS:
             return quantize_array(w, w.ndim - 2,
                                   bits=weight_bits(quant, f"layers.{name}"))
         return w
@@ -210,6 +227,9 @@ def init_params(config: ModelConfig, key: jax.Array,
 
     def attn_layer(k):
         ks = jax.random.split(k, 6)
+        if c.is_mla:
+            return {**mla.init_layer(c, ks[:5], dense, dtype),
+                    "mlp": mlp(ks[5])}
         gate = {"wgate": dense(ks[3], D, c.n_heads * dh, name="wgate")
                 } if c.attn_gate else {}
         return {"norm": jnp.ones((D,), dtype), **gate,
@@ -645,13 +665,15 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
     place = {p: (g, j, len(ps)) for g, (_, ps) in enumerate(groups)
              for j, p in enumerate(ps)}
     n_soft = len(c.softmax_positions)
-    decoding = T == 1 and getattr(fns[0], "decode", None) is not None
+    decoding = T == 1 and (c.is_mla or getattr(fns[0], "decode", None)
+                           is not None)
     # ``.decode_at`` / ``.prefill_at``: the provider reads the stacked pool
     # at a layer's index, and the pool stays out of the scanned inputs —
     # in prefill it is the scan's carry, written in place (llama.forward).
+    # A latent layer's pool is the carry of BOTH programs' scans.
     by_decode_at = decoding and hasattr(fns[0], "decode_at")
-    by_prefill_at = (not decoding and T > 1
-                     and hasattr(fns[0], "prefill_at"))
+    by_prefill_at = c.is_mla or (not decoding and T > 1
+                                 and hasattr(fns[0], "prefill_at"))
     by_index = by_decode_at or by_prefill_at
     scope = "decode" if decoding else "prefill"
     last_only = not decoding and n_valid is not None
@@ -682,6 +704,11 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
         slice of that pool; ``pool``: the group's stacked pool (under
         ``prefill_at`` the carried one)."""
         fn = fns[place[position][0]]
+        if c.is_mla:
+            with jax.named_scope(f"{scope}.attention"), \
+                    jax.named_scope("attn.mla"):
+                return (*mla.mla_block(x, lp, c, pool, at, fn, lengths,
+                                       active), None)
         kind = "attn.window" if c.window_at(position) else "attn.global"
         with jax.named_scope(f"{scope}.attention"), jax.named_scope(kind):
             h = rms_norm(x, lp["norm"], c.rms_eps)
@@ -722,7 +749,8 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
             return moe_block(x, {**lp, **held[i]}, c, count, period,
                              x_in if c.router_reads_block_input else None)
 
-    pools = tuple(zip(cache.k, cache.v))       # a (K, V) pair a group
+    # A (K, V) pair a group; a latent group's ONE pool.
+    pools = tuple(cache.k) if c.is_mla else tuple(zip(cache.k, cache.v))
 
     def by_period(pool, n):
         """A group's pool [P*n, ...] as the scan slices it: [P, n, ...]."""
@@ -803,6 +831,7 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
     logits = head_matmul(x, params["lm_head"])
     if last_only:
         logits = jnp.broadcast_to(logits, (B, T, logits.shape[-1]))
-    return logits, HybridCache(k=tuple(p[0] for p in new_pools),
-                               v=tuple(p[1] for p in new_pools),
-                               state=state, conv=conv, counters=counters)
+    k, v = (tuple(new_pools), ()) if c.is_mla else (
+        tuple(p[0] for p in new_pools), tuple(p[1] for p in new_pools))
+    return logits, HybridCache(k=k, v=v, state=state, conv=conv,
+                               counters=counters)

@@ -19,6 +19,7 @@ attention/norm blocks Mixtral reuses (models/mixtral.py).
 """
 from __future__ import annotations
 
+import math
 from functools import lru_cache, partial
 from typing import Any, Callable, NamedTuple
 
@@ -110,16 +111,35 @@ def rope_tables(positions: jax.Array, head_dim: int, theta: float,
     half = head_dim // 2
     freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
     if scaling is not None:
-        freqs = _scale_rope_freqs(freqs, scaling)
+        freqs = _scale_rope_freqs(freqs, scaling, theta)
     angles = positions.astype(jnp.float32)[..., None] * freqs   # [..., half]
+    if scaling is not None and scaling.table_mscale != 1.0:
+        return (jnp.cos(angles) * scaling.table_mscale,
+                jnp.sin(angles) * scaling.table_mscale)
     return jnp.cos(angles), jnp.sin(angles)
 
 
-def _scale_rope_freqs(freqs: jax.Array, scaling) -> jax.Array:
+def _scale_rope_freqs(freqs: jax.Array, scaling,
+                      theta: float = 10000.0) -> jax.Array:
     """Apply HF-convention rope_scaling to the inverse-frequency vector
-    (matches transformers' _compute_llama3_parameters numerics)."""
+    (matches transformers' _compute_llama3_parameters numerics; ``yarn``:
+    its _compute_yarn_parameters, the correction range floored and ceiled)."""
     if scaling.rope_type == "linear":
         return freqs / scaling.factor
+    if scaling.rope_type == "yarn":
+        half = freqs.shape[0]
+
+        def pair_turning(turns: float) -> float:
+            """The (fractional) pair index that turns ``turns`` times
+            inside the original context."""
+            return (half * math.log(scaling.original_max_seq
+                                    / (turns * 2.0 * math.pi))
+                    / math.log(theta))
+        low = max(math.floor(pair_turning(scaling.beta_fast)), 0)
+        high = min(math.ceil(pair_turning(scaling.beta_slow)), half - 1)
+        ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                        / max(high - low, 1e-3), 0.0, 1.0)
+        return freqs / scaling.factor * ramp + freqs * (1.0 - ramp)
     # llama3: long wavelengths (beyond the original context's low-freq band)
     # are slowed by `factor`; short ones kept; the middle band interpolates.
     old_ctx = float(scaling.original_max_seq)

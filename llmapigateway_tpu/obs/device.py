@@ -169,7 +169,9 @@ class HbmLedger:
             "hbm_aux_bytes": self.aux,
             "hbm_ledger_bytes": self.static_total,
         }
-        if len(self.kv_pools) > 1:
+        # A pool a cache group where the model has several, or one of a
+        # kind of its own (a latent pool).
+        if len(self.kv_pools) > 1 or "latent" in self.kv_pools:
             out["hbm_kv_pools_bytes"] = dict(self.kv_pools)
         if self.spec:
             out["hbm_spec_bytes"] = self.spec

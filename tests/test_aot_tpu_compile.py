@@ -543,3 +543,46 @@ def test_prefill_step_attends_a_layer_in_one_call_with_no_page_axis(
               for bm in eqn.params["grid_mapping"].block_mappings]
     assert spaces == ["None"] + ["any"] * 4 + ["None"], spaces
 
+
+
+# PR 38: the latent pool's kernels at the served geometry — 12 layers of
+# 8 slots x 128 pages of [320, 256] bfloat16 (2.0 GB), 32 query heads.
+
+@pytest.mark.parametrize("rows, tokens", [(1, 512), (4, 512), (8, 1)],
+                         ids=["chunk", "four-chunks", "decode"])
+def test_latent_kernels_compile_at_the_served_geometry(chips, rows, tokens):
+    """The in-place write with the pool donated (every byte aliased, the
+    temporaries the call's own rows cut into tiles) and the absorbed
+    attention kernel on the whole stacked pool at a traced layer's index,
+    as a prefill chunk (2 048 query rows a program) and as a decode step
+    (32): the chip's compiler finds room for both, and no slice of the
+    pool is among the operands."""
+    from llmapigateway_tpu.ops import latent_attention as la
+    layers, pages, width, value, heads, table = 12, 8 * 128 + 1, 320, 256, 32, 128
+    place = SingleDeviceSharding(chips[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=place)
+    pool = sds((layers, pages, width, PAGE), jnp.bfloat16)
+    tbl, start = sds((rows, table), jnp.int32), sds((rows,), jnp.int32)
+    write = jax.jit(
+        lambda pool, new, tbl, start, active, layer:
+        la.latent_insert_in_place(pool, new, tbl, start, active,
+                                  layer=layer, interpret=False),
+        donate_argnums=(0,)).lower(
+        pool, sds((rows, tokens, width), jnp.bfloat16), tbl, start,
+        sds((rows,), jnp.bool_), sds((), jnp.int32)).compile()
+    assert "tpu_custom_call" in write.as_text()
+    memory = write.memory_analysis()
+    assert memory.alias_size_in_bytes == layers * pages * width * PAGE * 2
+    assert memory.temp_size_in_bytes < 8 * rows * (tokens + 2 * 128) * width
+    attend = jax.jit(
+        lambda q, pool, tbl, start, layer: la.latent_paged_attention(
+            q, pool, tbl, start, value_width=value, layer=layer,
+            interpret=False)).lower(
+        sds((rows, tokens, heads, width), jnp.bfloat16), pool, tbl, start,
+        sds((), jnp.int32)).compile()
+    assert "tpu_custom_call" in attend.as_text()
+    assert la.latent_block_t(tokens, heads) == min(tokens, 64)
+    # q in, the latent-wide out, and nothing the size of a layer's pool.
+    assert attend.memory_analysis().temp_size_in_bytes < pages * width * PAGE

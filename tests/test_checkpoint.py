@@ -259,5 +259,11 @@ def test_rope_scaling_unsupported_type_rejected(tmp_path):
     assert _parse_rope_scaling(None) is None
     assert _parse_rope_scaling({"rope_type": "default"}) is None
     assert _parse_rope_scaling({"type": "linear", "factor": 2.0}).factor == 2.0
-    with pytest.raises(ValueError, match="unsupported rope_scaling"):
-        _parse_rope_scaling({"rope_type": "yarn", "factor": 4.0})
+    yarn = _parse_rope_scaling({"rope_type": "yarn", "factor": 4.0,
+                                "beta_fast": 16, "mscale_all_dim": 1})
+    assert (yarn.rope_type, yarn.factor, yarn.beta_fast, yarn.beta_slow,
+            yarn.mscale_all_dim) == ("yarn", 4.0, 16.0, 1.0, 1.0)
+    with pytest.raises(ValueError, match="unsupported rope_scaling type "
+                                         "'longrope'; supported: llama3, "
+                                         "linear, yarn"):
+        _parse_rope_scaling({"rope_type": "longrope", "factor": 4.0})

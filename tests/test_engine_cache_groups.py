@@ -154,14 +154,15 @@ def test_one_group_configurations_run_through_the_same_calls():
 
 def test_the_engine_builds_two_groups_and_names_them(engine):
     st = engine.stats()
+    token = 2 * 2 * 16 * 4                      # K+V, 2 heads of 16, f32
     assert st["kv_groups"] == [
-        {"layers": 2, "window": 0, "pages": 3 * WHOLE,
-         "pages_free": 3 * WHOLE, "pages_per_slot": WHOLE},
-        {"layers": 6, "window": 16, "pages": 3 * RING,
-         "pages_free": 3 * RING, "pages_per_slot": RING}]
+        {"kind": "kv", "layers": 2, "window": 0, "token_bytes": token,
+         "pages": 3 * WHOLE, "pages_free": 3 * WHOLE,
+         "pages_per_slot": WHOLE},
+        {"kind": "kv", "layers": 6, "window": 16, "token_bytes": token,
+         "pages": 3 * RING, "pages_free": 3 * RING, "pages_per_slot": RING}]
     assert st["kv_ring_recycled_total"] == 0
     assert st["moe_experts_held"] == 8
-    token = 2 * 2 * 16 * 4                      # K+V, 2 heads of 16, f32
     assert st["hbm_kv_pools_bytes"] == {
         "global": 2 * (3 * WHOLE + 1) * 8 * token,
         "window16": 6 * (3 * RING + 1) * 8 * token}
@@ -315,3 +316,163 @@ def test_the_checkpoint_loader_refuses_the_family():
     with pytest.raises(ValueError, match="no checkpoint mapping for the "
                                          "'smallthinker' family"):
         load_checkpoint("/nonexistent", TINY)
+
+
+# -- a cache group of the LATENT kind (ISSUE 38) -------------------------------
+# The Mistral-Small-4 family: one global group whose pool is ONE latent pool
+# (ops/latent_attention.py), behind the same allocator, tables and admission.
+
+from benchmark.reference import mistral4 as mref  # noqa: E402
+
+from test_model_mistral4 import TINY as MLA  # noqa: E402
+from test_model_mistral4 import file_of as mla_file  # noqa: E402
+
+LATENT = {**BASE, "preset": "tiny-mistral4-test"}
+
+
+@pytest.fixture(scope="module")
+def latent(stop_engine):
+    eng = InferenceEngine(LocalEngineConfig(**LATENT),
+                          devices=[jax.devices("cpu")[0]])
+    yield eng
+    stop_engine(eng)
+
+
+def test_a_latent_group_is_a_kind_not_a_window():
+    """The new kind keeps pages, tables and admission and changes the
+    pool's shape alone; it has no window and no ring."""
+    group = CacheGroup(4, 0, 0, PageAllocator(33, 8, 2, 128), kind="latent",
+                       token_bytes=160)
+    groups = CacheGroups([group])
+    assert groups.allocate(0, 128) and groups.allocate(1, 128)
+    assert not groups.can_admit(8)
+    groups.rotate(0, 99, 80)                    # nothing to rotate
+    assert group.recycled == 0
+    groups.check_invariants()
+    groups.release(0)
+    groups.check_invariants()
+    assert group.stats() == {
+        "kind": "latent", "layers": 4, "window": 0, "token_bytes": 160,
+        "pages": 32, "pages_free": 16, "pages_per_slot": 16}
+    for bad in ({"window": 16}, {"ring_pages": 7}):
+        args = {"window": 0, "ring_pages": 0, **bad}
+        with pytest.raises(ValueError, match="keeps the whole context"):
+            CacheGroup(4, args["window"], args["ring_pages"],
+                       PageAllocator(33, 8, 2, 128), kind="latent")
+    with pytest.raises(ValueError, match="unknown cache group kind"):
+        CacheGroup(4, 0, 0, PageAllocator(33, 8, 2, 128), kind="dense")
+
+
+def test_the_engine_builds_one_latent_group_and_accounts_for_it(latent):
+    st = latent.stats()
+    token = MLA.latent_width * 4                # one row, float32
+    assert st["kv_groups"] == [
+        {"kind": "latent", "layers": 4, "window": 0, "token_bytes": token,
+         "pages": 3 * WHOLE, "pages_free": 3 * WHOLE,
+         "pages_per_slot": WHOLE}]
+    pool = latent.cache.k[0]
+    assert latent.cache.v == () and pool.shape == (
+        4, 3 * WHOLE + 1, MLA.latent_width, 8)
+    assert st["hbm_kv_pools_bytes"] == {
+        "latent": 4 * (3 * WHOLE + 1) * 8 * token}
+    assert st["hbm_kv_pool_bytes"] == pool.size * 4
+    assert (st["mla_decode_keys_total"], st["mla_prefill_keys_total"]) == (
+        0, 0)
+    assert st["moe_experts_held"] == 16
+
+
+async def test_requests_of_mixed_lengths_serve_at_the_references_maximum(
+        latent):
+    """Four requests on three slots, through the scheduler, chunked
+    prefill, bursts and the latent pool; three of them pass the tiny
+    rotary's original context of 32."""
+    before = latent.stats()
+    done = await asyncio.gather(*[
+        generate(latent, prompt(n, seed), 12)
+        for seed, n in ((1, 70), (2, 33), (3, 20), (4, 90))])
+    sizes = mref.sizes(MLA, mla_file(MLA))
+    for req in done:
+        assert len(req.generated) == 12
+        seq = np.asarray(list(req.prompt_ids) + req.generated[:-1], np.int32)
+        # Off the event loop: the reference compiles a program a length.
+        rows = await asyncio.to_thread(mref.logits, latent.params, sizes,
+                                       seq, len(req.generated))
+        assert max(float(row.max() - row[t]) for row, t in zip(
+            rows, req.generated)) <= GAP_TOL
+    after = latent.stats()
+    # Every prompt token and every decoded token but a request's last
+    # attended its context once a layer: sum over positions of (p + 1),
+    # plus what a prefill bucket pads its tail chunk with.
+    exact = sum(sum(range(1, len(r.prompt_ids) + 11 + 1)) for r in done)
+    counted = (after["mla_prefill_keys_total"] + after["mla_decode_keys_total"]
+               - before["mla_prefill_keys_total"]
+               - before["mla_decode_keys_total"])
+    assert exact <= counted <= 1.35 * exact
+    latent.kv_groups.check_invariants()
+    assert all(g["pages_free"] == g["pages"] for g in after["kv_groups"])
+
+
+def test_served_past_the_original_context_on_an_idle_engine(latent):
+    """``served_past_8192`` (benchmark/reference/mistral4.py) as the
+    harness calls it in set-up: 48 tokens here (the tiny original context
+    of 32 and a chunk of 16), 8 decode steps, every key counted, the
+    state as the warm-up left it."""
+    case = mref.served_past_8192(latent, mla_file(MLA))
+    assert case["ok"] and (case["tokens"], case["positions"]) == (48, 9)
+    assert case["keys_attended"] == 56 * 57 // 2
+    assert case["max_abs_err"] <= GAP_TOL
+    assert not latent.active.any() and not latent.lengths.any()
+    assert latent._d_dirty
+    latent.kv_groups.check_invariants()
+
+
+def test_the_latent_pool_rides_the_in_place_path_of_both_programs(
+        stop_engine):
+    """With the Pallas kernels the pool is donated, carried through the
+    layer scan of ``prefill_step`` AND of the decode programs, written by
+    an aliased custom call under ``kv.latent_insert`` and attended under
+    ``attention.latent``, all inside ``attn.mla``."""
+    eng = InferenceEngine(
+        LocalEngineConfig(**{**LATENT, "attention": "pallas",
+                             "max_batch_size": 2}),
+        devices=[jax.devices("cpu")[0]])
+    try:
+        assert eng.stats()["kv_pool_in_place"] is True
+        state, key = eng._state_avals()
+        import jax.numpy as jnp
+
+        def row(dtype, *shape):
+            return jax.ShapeDtypeStruct((1, *shape), dtype)
+        lowered = eng._prefill_fn.lower(
+            *state, row(jnp.int32, 16), row(jnp.int32), row(jnp.int32),
+            row(jnp.int32), row(jnp.float32), row(jnp.float32),
+            row(jnp.int32), row(jnp.float32), row(jnp.float32), key)
+        # The pool, the penalty counts and the counters come back in place.
+        assert lowered.as_text().count("tf.aliasing_output") >= 3
+        text = lowered.as_text(debug_info=True)
+        for name in ("attn.mla", "kv.latent_insert", "attention.latent",
+                     "moe.experts", "moe.shared"):
+            assert name in text
+    finally:
+        stop_engine(eng)
+
+
+@pytest.mark.parametrize("change, says", [
+    ({"kv_quant": "int8"}, "kv_quant 'int8': the latent pool is bfloat16"),
+    ({"prefix_cache": True}, "prefix_cache: the radix cache shares K/V"),
+    ({"spec_draft_len": 3}, "spec_draft_len: the verify path reads a K"),
+    ({"kv_layout": "contiguous"}, "kv_layout 'contiguous': the latent "
+                                  "cache is a page pool"),
+    ({"mesh": {"model": 2}}, "mesh .*the latent pool has one key head"),
+    ({"disaggregation": {"enabled": True, "prefill_slots": 1}},
+     "disaggregation: a handoff of latent pages"),
+    ({"model_path": "/nonexistent/checkpoint"},
+     "model_path: no checkpoint mapping"),
+])
+def test_what_the_latent_family_cannot_be_served_with_is_refused_at_build(
+        change, says):
+    devices = jax.devices("cpu")[:2] if "mesh" in change else None
+    with pytest.raises(ValueError, match=f"'mistral4' family does not "
+                                         f"support {says}"):
+        InferenceEngine(LocalEngineConfig(**{**LATENT, **change}),
+                        devices=devices or [jax.devices("cpu")[0]])
