@@ -357,7 +357,8 @@ class InferenceEngine:
         self._enable_debug_nans()
         _enable_compilation_cache(engine_cfg.compilation_cache_dir)
 
-        self._moe_totals = [0, 0, 0]    # survive a rebuild of the state
+        from ..models.hybrid import N_COUNTERS
+        self._moe_totals = [0] * N_COUNTERS     # survive a rebuild of the state
         t0 = time.monotonic()
         self._init_params()
         t1 = time.monotonic()
@@ -842,10 +843,11 @@ class InferenceEngine:
                 self.cache = llama.KVCache(
                     k=zeros(shape, self.dtype, csh),
                     v=zeros(shape, self.dtype, csh))
-        # Routed assignments of the decode steps (hybrid family): the
-        # device keeps wrapping int32 totals in the cache, every burst
-        # hands them back beside its tokens, the host sums the deltas.
-        self._moe_seen = np.zeros((3,), np.int64)
+        # Routed assignments of the decode steps and tiles of the prefill
+        # calls (hybrid family): the device keeps wrapping int32 totals in
+        # the cache, every burst hands them back beside its tokens, the
+        # host sums the deltas.
+        self._moe_seen = np.zeros((len(self._moe_totals),), np.int64)
         # Host-authoritative per-slot state, mirrored to device each step.
         self.lengths = np.zeros((self.B,), np.int32)
         self.active = np.zeros((self.B,), bool)
@@ -3557,6 +3559,12 @@ class InferenceEngine:
             out["moe_assignments_total"] = self._moe_totals[0]
             out["moe_assignments_local_total"] = self._moe_totals[1]
             out["moe_experts_hit_total"] = self._moe_totals[2]
+            # The grouped product of the prefill calls: the tiles of
+            # GROUP_TILE rows it ran and the rows they held — rows over
+            # GROUP_TILE x tiles is how full a tile ran. Seen at the next
+            # burst's fetch.
+            out["moe_tiles_run_total"] = self._moe_totals[3]
+            out["moe_tile_rows_total"] = self._moe_totals[4]
         if self.model_cfg.is_mla:
             out["mla_decode_keys_total"] = self._mla_decode_keys
             out["mla_prefill_keys_total"] = self._mla_prefill_keys

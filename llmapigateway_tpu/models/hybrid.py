@@ -54,7 +54,11 @@ TPU-first decisions:
 * The expert layer has two exact forms: every held expert on every token
   (decode, small calls: the weights stream from memory either way), and a
   grouped product over the assignments sorted by expert, tile by tile,
-  whose trip count is the number of live tiles.
+  whose trip count is the number of live tiles. The grouped product
+  quantises its rows once, before the loop, and brings the results back
+  to the tokens by a gather after the loop or by the loop's own add,
+  whichever the share of the experts held makes cheaper
+  (``combine_form``).
 """
 from __future__ import annotations
 
@@ -69,8 +73,8 @@ from . import mla
 from .config import ModelConfig
 from .llama import (_GATE_ACTS, apply_rope, rms_norm, rope_tables,
                     swiglu_mlp)
-from .quant import (head_matmul, mm, moe_mm_batched, quantize_array,
-                    weight_bits)
+from .quant import (_dynamic_int8, head_matmul, is_quantized, mm, mm_q8,
+                    moe_mm_batched, quantize_array, weight_bits)
 
 Params = dict[str, Any]
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -79,6 +83,7 @@ KDA_CHUNK = 64          # tokens per sub-chunk of the block-parallel form
 KDA_BLOCK = 16          # tokens per block inside a sub-chunk (exact decays)
 DENSE_MAX_TOKENS = 64   # calls up to this size run every held expert
 GROUP_TILE = 128        # rows per tile of the grouped expert product
+N_COUNTERS = 5          # HybridCache.counters, moe_block's counted vector
 EXPERT_KEYS = ("wg", "wu", "wd")    # the routed experts' matrices
 # Stored int8 under quant (contraction axis second to last): the projections
 # of both layer kinds, the softmax gate, routed and shared experts. NOT the
@@ -99,10 +104,11 @@ class HybridCache(NamedTuple):
     last inputs of their convolutions, one fixed block per slot — a tuple
     over a period's linear layers of [P, B, H, dk, dv] float32 and of
     [P, B, taps-1, 3*H*dk]. ``counters``
-    int32 [3]: of the DECODE steps so far, the routed assignments (all;
-    landing on a held expert) and, summed over layers, the held experts
-    that at least one active row was assigned to — running totals that
-    wrap."""
+    int32 [``N_COUNTERS``], running totals that wrap: of the DECODE steps
+    so far, the routed assignments (all; landing on a held expert) and,
+    summed over layers, the held experts that at least one active row was
+    assigned to; then, of every call that took the grouped expert product
+    (a prefill chunk), the tiles it ran and the rows they held."""
     k: Any
     v: Any
     state: tuple[jax.Array, ...]
@@ -126,7 +132,7 @@ class HybridCache(NamedTuple):
             return cls(k=(create_latent_pool(
                 c.n_layers, num_pages[0], page_size, c.latent_width, dtype),),
                 v=(), state=(), conv=(),
-                counters=jnp.zeros((3,), jnp.int32))
+                counters=jnp.zeros((N_COUNTERS,), jnp.int32))
         pools = [PagedKVCache.create(
             replace(c, n_layers=periods * len(positions)), pages, page_size,
             dtype, kv_quant)
@@ -141,7 +147,7 @@ class HybridCache(NamedTuple):
             conv=tuple(jnp.zeros(lead + (c.lin_conv_taps - 1,
                                          3 * c.lin_heads * c.lin_head_dim),
                                  dtype) for _ in lin),
-            counters=jnp.zeros((3,), jnp.int32))
+            counters=jnp.zeros((N_COUNTERS,), jnp.int32))
 
 
 # ---------------------------------------------------------------------------
@@ -529,54 +535,145 @@ def experts_dense(x: jax.Array, probs: jax.Array, lp: Params,
     return jnp.einsum("end,ne->nd", y.astype(jnp.float32), probs)
 
 
-def experts_grouped(x: jax.Array, probs: jax.Array, lp: Params,
-                    per_token: int, tile: int = GROUP_TILE,
-                    period: jax.Array | None = None, act: str = "silu"
-                    ) -> jax.Array:
+class GroupedLayout(NamedTuple):
+    """Where the routed rows lie when they are sorted by expert in tiles:
+    ``row_token`` [rows] the token a row holds (``N``: a padding row),
+    ``dest`` [N,k] the row each of a token's assignments landed in
+    (``rows``: it landed on no held expert), ``tile_expert`` [n_tiles]
+    the expert a tile belongs to, ``counted`` int32 [2] the tiles that
+    hold rows (they come first) and the rows they hold."""
+    row_token: jax.Array
+    dest: jax.Array
+    tile_expert: jax.Array
+    counted: jax.Array
+
+
+def grouped_layout(idx: jax.Array, held: int, tile: int) -> GroupedLayout:
+    """idx [N,k]: each token's experts, numbered from the first one held
+    (anything outside ``[0, held)`` is not held here). Each expert's
+    group is padded to whole tiles; the layout has room for the worst
+    case — every token on ``min(k, held)`` held experts, a partial tile an
+    expert — so nothing is ever dropped."""
+    N, k = idx.shape
+    landed = (idx >= 0) & (idx < held)                      # [N,k]
+    routed = jnp.any(idx[:, :, None] == jnp.arange(held), axis=1)
+    counts = jnp.sum(routed, axis=0, dtype=jnp.int32)       # [held]
+    tiles = (counts + tile - 1) // tile
+    last_tile = jnp.cumsum(tiles)                           # inclusive
+    n_tiles = -(-N * min(k, held) // tile) + held           # static bound
+    rows = n_tiles * tile
+    rank = jnp.cumsum(routed, axis=0, dtype=jnp.int32) - 1  # within expert
+    row = ((last_tile - tiles) * tile)[None, :] + rank      # [N,held]
+    dest = jnp.where(landed, jnp.take_along_axis(
+        row, jnp.clip(idx, 0, held - 1), axis=1), rows)
+    token = jnp.broadcast_to(jnp.arange(N, dtype=jnp.int32)[:, None], (N, k))
+    row_token = jnp.full((rows,), N, jnp.int32).at[dest.reshape(-1)].set(
+        token.reshape(-1), mode="drop")
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(last_tile, jnp.arange(n_tiles), side="right"),
+        held - 1).astype(jnp.int32)
+    return GroupedLayout(row_token, dest, tile_expert,
+                         jnp.stack([last_tile[-1], jnp.sum(counts)]))
+
+
+def combine_form(n_rows: int, k: int, held: int, n_experts: int,
+                 tile: int = GROUP_TILE) -> str:
+    """How ``experts_grouped`` brings the tiles' results back to the
+    tokens, from what a call can see of itself. "gather": after the loop
+    every token gathers the rows of its ``k`` assignments —
+    ``n_rows * k`` row reads, whatever landed. "add": every tile adds its
+    rows into a float32 carry — a read-modify-write of ``tile`` rows a
+    live tile, padding included; by expectation a partial tile an expert
+    held and the whole tiles of the ``held / n_experts`` of the
+    assignments that land. With every expert held each read is a row that
+    landed; with a share held ``1 / share`` reads find one, but a small
+    call still runs a tile an expert. A slot read costs 70-100 ns and an
+    added row ~230 (``tools/probe_experts.py``, PERF.md section 5), the
+    sorted buffer's zeros go with the reads, and the two forms met where
+    the reads were 4/3 of the added rows. One layer call on a v5e, int8,
+    ms (gather | add; in brackets the form before PR 39): all 64 held,
+    top-6, 1 024 rows 1.79 | 2.34 (2.81), 128 rows 1.38 | 1.87 (1.89);
+    32 of 128, top-4, 2 048 rows 2.48 | 2.52 (2.93), 1 024 rows 1.88 |
+    2.35 (2.56); 40 of 320, top-8, 2 048 rows 3.41 | 2.62 (3.03), 1 536
+    rows 2.75 | 2.50 (2.79), 1 024 rows 1.86 | 2.38 (2.59), 128 rows
+    1.59 | 2.04 (2.06): the rule picks the cheaper form at each of the
+    sixteen calls measured."""
+    adds = tile * (held + n_rows * k * held // (n_experts * tile))
+    return "gather" if 3 * n_rows * k <= 4 * adds else "add"
+
+
+def _expert_rows(xt: tuple, m: Params, act: str, dtype) -> jax.Array:
+    """One expert's gated MLP on a tile's rows. ``xt``: the rows, or the
+    int8 rows and their scales (``_dynamic_int8``'s pair) for int8
+    matrices ``m``; the hidden activation is quantised here."""
+    up = ((lambda w: mm_q8(*xt, w, dtype)) if len(xt) == 2
+          else (lambda w: xt[0] @ w))
+    return mm(_GATE_ACTS[act](up(m["wg"])) * up(m["wu"]), m["wd"])
+
+
+def experts_grouped(x: jax.Array, idx: jax.Array, w: jax.Array, lp: Params,
+                    held: int, tile: int = GROUP_TILE,
+                    period: jax.Array | None = None, act: str = "silu",
+                    combine: str = "gather") -> tuple[jax.Array, jax.Array]:
     """The held experts' part of the result with work that follows the
-    assignments: rows are laid out expert by expert in tiles of ``tile``
-    (each expert's group padded to whole tiles), and a loop over the LIVE
-    tiles gathers a tile's tokens, runs its expert and adds the weighted
-    result back. The layout has room for the worst case — every token on
-    ``per_token`` held experts — so nothing is ever dropped; tiles past
-    the live count are not run. x [N,D], probs [N,held] -> [N,D] f32.
+    assignments. x [N,D]; idx, w [N,k]: each token's experts, numbered
+    from the first one held, and their weights -> ([N,D] float32, int32
+    [2]: the tiles run and the rows they held).
+
+    Rows are laid out expert by expert in tiles of ``tile``
+    (``grouped_layout``) and a loop runs the LIVE tiles only. ``x`` is
+    quantised ONCE, before the loop (per-row quantisation commutes with a
+    gather of rows, so every product sees the numbers ``mm`` would give
+    it; the hidden activation is quantised in its tile, where alone it
+    exists); a tile gathers its int8 rows and their scales and runs its
+    expert. ``combine`` (``combine_form``) "gather": the tile writes its
+    result where it lies in expert order — a contiguous
+    ``dynamic_update_slice``, no read, no index vector, no dependence on
+    the tile before — and after the loop every token gathers the rows its
+    assignments landed in and sums them, weighted, in float32: nothing in
+    the loop scatters. "add": the tile adds its weighted rows into a
+    float32 [N + 1, D] carry (padding rows all name row N; telling the
+    add that a tile's rows are ascending and unique, on dummy rows for
+    the padding, made it dearer: 3.79 against 2.62 ms).
+
     With ``period`` the experts' matrices are the whole stack over periods
     and the loop reads ``[period, expert]`` of it in place: a loop handed
     one period's slice is handed a COPY of it (0.69 ms a matrix a layer on
     a v5e, 16 ms a prefill call at the published widths)."""
     N, D = x.shape
-    held = probs.shape[1]
-    routed = probs > 0.0                                    # [N,held]
-    counts = jnp.sum(routed, axis=0, dtype=jnp.int32)       # [held]
-    tiles = (counts + tile - 1) // tile
-    last_tile = jnp.cumsum(tiles)                           # inclusive
-    n_tiles = -(-N * min(per_token, held) // tile) + held   # static bound
-    rows = n_tiles * tile
-    rank = jnp.cumsum(routed, axis=0, dtype=jnp.int32) - 1  # within expert
-    dest = jnp.where(routed, (last_tile - tiles)[None, :] * tile + rank, rows)
-    token = jnp.broadcast_to(jnp.arange(N, dtype=jnp.int32)[:, None],
-                             (N, held))
-    # Padding rows read token N (a zero row) with weight 0.
-    row_token = jnp.full((rows,), N, jnp.int32).at[dest.reshape(-1)].set(
-        token.reshape(-1), mode="drop")
-    row_weight = jnp.zeros((rows,), jnp.float32).at[dest.reshape(-1)].set(
-        probs.reshape(-1), mode="drop")
-    tile_expert = jnp.minimum(
-        jnp.searchsorted(last_tile, jnp.arange(n_tiles), side="right"),
-        held - 1).astype(jnp.int32)
+    lay = grouped_layout(idx, held, tile)
+    rows = lay.row_token.shape[0]
+    stack = {key: lp[key] for key in EXPERT_KEYS}
+    # Padding rows read token N: a zero row, whose result is zero.
     x_pad = jnp.concatenate([x, jnp.zeros((1, D), x.dtype)])
+    src = _dynamic_int8(x_pad) if is_quantized(stack["wg"]) else (x_pad,)
 
-    def body(i, out):
-        e = tile_expert[i]
-        at = jax.lax.dynamic_slice_in_dim(row_token, i * tile, tile)
-        wt = jax.lax.dynamic_slice_in_dim(row_weight, i * tile, tile)
-        w = _at(_at({key: lp[key] for key in EXPERT_KEYS}, period), e)
-        y = swiglu_mlp(x_pad[at], w["wg"], w["wu"], w["wd"], act)
-        return out.at[at].add(wt[:, None] * y.astype(jnp.float32))
+    def run(i):
+        """Tile ``i``: the tokens of its rows, their results [tile, D]."""
+        m = _at(_at(stack, period), lay.tile_expert[i])
+        at = jax.lax.dynamic_slice_in_dim(lay.row_token, i * tile, tile)
+        return at, _expert_rows(tuple(a[at] for a in src), m, act, x.dtype)
 
-    out = jax.lax.fori_loop(0, last_tile[-1], body,
-                            jnp.zeros((N + 1, D), jnp.float32))
-    return out[:N]
+    if combine == "add":
+        row_weight = jnp.zeros((rows,), jnp.float32).at[
+            lay.dest.reshape(-1)].set(w.reshape(-1), mode="drop")
+
+        def add(i, out):
+            at, y = run(i)
+            wt = jax.lax.dynamic_slice_in_dim(row_weight, i * tile, tile)
+            return out.at[at].add(wt[:, None] * y.astype(jnp.float32))
+        out = jax.lax.fori_loop(0, lay.counted[0], add,
+                                jnp.zeros((N + 1, D), jnp.float32))
+        return out[:N], lay.counted
+
+    def write(i, ys):
+        return jax.lax.dynamic_update_slice_in_dim(ys, run(i)[1], i * tile, 0)
+    # One more row than the tiles fill: the zero row that assignments to
+    # experts not held here read.
+    ys = jax.lax.fori_loop(0, lay.counted[0], write,
+                           jnp.zeros((rows + 1, D), x.dtype))
+    out = jnp.sum(w[:, :, None] * ys[lay.dest].astype(jnp.float32), axis=1)
+    return out, lay.counted
 
 
 def moe_block(x: jax.Array, lp: Params, c: ModelConfig,
@@ -584,13 +681,15 @@ def moe_block(x: jax.Array, lp: Params, c: ModelConfig,
               period: jax.Array | None = None,
               route_on: jax.Array | None = None
               ) -> tuple[jax.Array, jax.Array]:
-    """x [B,T,D] (the residual stream) -> (x + MLP(norm(x)), int32 [3]:
-    of rows where ``count`` [B] is True the routed assignments, all and
-    those landing on a held expert, and the held experts with at least one
-    of them; zeros without ``count``). ``period``: the routed experts'
-    matrices (``EXPERT_KEYS``) are stacked over periods and this is the
-    index to read. ``route_on`` [B,T,D]: what the router reads instead of
-    the MLP's normalised input (the block's input, before attention)."""
+    """x [B,T,D] (the residual stream) -> (x + MLP(norm(x)), int32
+    [``N_COUNTERS``]: of rows where ``count`` [B] is True the routed
+    assignments, all and those landing on a held expert, and the held
+    experts with at least one of them (zeros without ``count``); then the
+    tiles the grouped product ran and the rows they held (zeros from the
+    dense form)). ``period``: the routed experts' matrices
+    (``EXPERT_KEYS``) are stacked over periods and this is the index to
+    read. ``route_on`` [B,T,D]: what the router reads instead of the
+    MLP's normalised input (the block's input, before attention)."""
     B, T, D = x.shape
     hf = rms_norm(x.astype(jnp.float32), lp["norm"], c.rms_eps)
     h = hf.astype(x.dtype)
@@ -601,22 +700,26 @@ def moe_block(x: jax.Array, lp: Params, c: ModelConfig,
         xf = h.reshape(B * T, D)
         if B * T <= DENSE_MAX_TOKENS:
             y = experts_dense(xf, probs, lp, period, c.moe_act)
+            tiled = jnp.zeros((2,), jnp.int32)
         else:
-            y = experts_grouped(xf, probs, lp, c.experts_per_token,
-                                period=period, act=c.moe_act)
+            y, tiled = experts_grouped(
+                xf, idx - c.first_expert_held, w, lp, c.experts_held,
+                period=period, act=c.moe_act,
+                combine=combine_form(B * T, c.experts_per_token,
+                                     c.experts_held, c.n_experts))
         y = y.reshape(B, T, D).astype(x.dtype)
     with jax.named_scope("moe.shared"):
         if c.n_shared_experts:
             y = y + swiglu_mlp(h, lp["sg"], lp["su"], lp["sd"])
-    counted = jnp.zeros((3,), jnp.int32)
+    routed = jnp.zeros((3,), jnp.int32)
     if count is not None:
         on = jnp.repeat(count, T)
         landed = (probs > 0.0) & on[:, None]
-        counted = jnp.stack([
+        routed = jnp.stack([
             jnp.sum(on, dtype=jnp.int32) * c.experts_per_token,
             jnp.sum(landed, dtype=jnp.int32),
             jnp.sum(jnp.any(landed, axis=0), dtype=jnp.int32)])
-    return x + y, counted
+    return x + y, jnp.concatenate([routed, tiled])
 
 
 # ---------------------------------------------------------------------------
@@ -817,13 +920,12 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
         new_pools = tuple(of_group(parts) for parts in new)
     if decoding:
         state, conv = s_out, tail_out
-        counters = cache.counters + jnp.sum(counts, axis=0)
     else:
         state = tuple(s.at[:, slots].set(new)
                       for s, new in zip(cache.state, s_out))
         conv = tuple(t.at[:, slots].set(new)
                      for t, new in zip(cache.conv, tail_out))
-        counters = cache.counters
+    counters = cache.counters + jnp.sum(counts, axis=0)
 
     if last_only:
         x = jnp.take_along_axis(x, (n_valid - 1)[:, None, None], axis=1)

@@ -162,17 +162,24 @@ def _dynamic_int8(x: jax.Array) -> tuple[jax.Array, jax.Array]:
     return xq, xs
 
 
+def mm_q8(xq: jax.Array, xs: jax.Array, w: dict, dtype) -> jax.Array:
+    """``mm`` on rows that are int8 already (``_dynamic_int8``'s pair): a
+    caller that sends the same rows through several products, or gathers
+    rows of a matrix it quantised whole, pays for the rounding once.
+    Per-row quantisation commutes with a gather of rows."""
+    acc = jax.lax.dot_general(
+        xq, w["q"], (((xq.ndim - 1,), (0,)), ((), ())),
+        preferred_element_type=jnp.int32)
+    y = acc.astype(jnp.float32) * xs * w["s"]
+    return y.astype(dtype)
+
+
 def mm(x: jax.Array, w: Any) -> jax.Array:
     """``x [..., D] @ w [D, F]`` where ``w`` is a plain array or a quantized
     ``{"q", "s"}`` dict. Result in ``x.dtype`` either way."""
     if not is_quantized(w):
         return x @ w
-    xq, xs = _dynamic_int8(x)
-    acc = jax.lax.dot_general(
-        xq, w["q"], (((x.ndim - 1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)
-    y = acc.astype(jnp.float32) * xs * w["s"]
-    return y.astype(x.dtype)
+    return mm_q8(*_dynamic_int8(x), w, x.dtype)
 
 
 def moe_mm_dense(x: jax.Array, w: Any) -> jax.Array:

@@ -154,6 +154,45 @@ async def test_an_engine_that_holds_half_the_experts():
         await eng.stop()
 
 
+async def test_the_tiles_of_the_grouped_product_are_counted():
+    """A prefill call of more than ``DENSE_MAX_TOKENS`` positions takes the
+    grouped product and counts its tiles and the rows they held; a smaller
+    call and the decode bursts run every held expert and count none; the
+    totals survive a rebuild of the state, as their neighbours do."""
+    from llmapigateway_tpu.models import hybrid
+    eng = await asyncio.to_thread(_mk_engine, prefill_chunk=128)
+    try:
+        assert eng.stats()["moe_tiles_run_total"] == 0
+        await generate(eng, prompt(20, 5), 12)      # a bucket of 32: dense
+        small = eng.stats()
+        assert small["moe_assignments_total"] > 0
+        assert small["moe_tiles_run_total"] == small["moe_tile_rows_total"] == 0
+        await generate(eng, prompt(100, 6), 12)     # one call of 128 rows
+        st = eng.stats()
+        layers, k = eng.model_cfg.n_layers, eng.model_cfg.experts_per_token
+        assert st["moe_tile_rows_total"] == 128 * k * layers    # all held
+        least = -(-128 * k // hybrid.GROUP_TILE)
+        assert least * layers <= st["moe_tiles_run_total"] \
+            <= (least + eng.model_cfg.experts_held) * layers
+        assert st["moe_assignments_total"] > small["moe_assignments_total"]
+        await generate(eng, prompt(24, 7), 12)      # decode bursts only
+        after = eng.stats()
+        assert after["moe_assignments_total"] > st["moe_assignments_total"]
+        assert after["moe_tiles_run_total"] == st["moe_tiles_run_total"]
+        await eng.stop()
+        eng._rebuild_state()
+        assert int(np.asarray(eng.cache.counters).sum()) == 0
+        kept = eng.stats()
+        for key in ("moe_tiles_run_total", "moe_tile_rows_total",
+                    "moe_assignments_total", "moe_assignments_local_total",
+                    "moe_experts_hit_total"):
+            assert kept[key] == after[key] > 0
+        await generate(eng, prompt(100, 8), 12)
+        assert eng.stats()["moe_tiles_run_total"] > after["moe_tiles_run_total"]
+    finally:
+        await eng.stop()
+
+
 @pytest.mark.parametrize("change, says", [
     ({"prefix_cache": True}, "prefix_cache: a cached prefix holds KV pages"),
     ({"spec_draft_len": 3}, "spec_draft_len: a rejected draft"),

@@ -208,7 +208,7 @@ def test_prefill_in_chunks_then_decode_matches_the_reference(
     # 4 steps x 2 decoding slots x top-4 x 8 layers; about half land here.
     # Of the 8 held experts a layer, those 2 rows x top-4 reached: 1 to 8
     # a layer a step, summed over 8 layers and 4 steps.
-    total, local, hit = np.asarray(cache.counters)
+    total, local, hit = np.asarray(cache.counters)[:3]
     assert total == 4 * 2 * 4 * 8 and 0.3 * total < local < 0.7 * total
     assert 4 * 8 <= hit <= min(local, 4 * 8 * 8)
 
@@ -284,41 +284,161 @@ def test_the_shares_of_all_chips_add_up_to_the_uncut_layer():
     assert float(jnp.abs(routed[1]).max()) > 0.01
 
 
-def test_no_assignment_is_dropped_when_every_token_picks_the_same_experts():
-    """512 tokens that all choose experts 2, 3, 5, 6 (a capacity dispatch
-    at factor 2 would drop three quarters of them): the grouped product
-    runs 4 tiles for each, equals running every expert on every token, and
-    equals the reference."""
-    c = TINY
-    lp = dict(_mlp_of(params_of(c)))
+def _routed(c, lp, n: int, routing: str):
+    """(x [n,D], the layer's weights with the router that routes them).
+    "even": the drawn router on drawn rows. "uneven": every token picks
+    the four experts 1, 2, 5 and 9 after the first one held (a capacity
+    dispatch at factor 2 would drop three quarters of them), so the first
+    expert held gets no row and the second every row."""
+    if routing == "even":
+        return jax.random.normal(jax.random.PRNGKey(9), (n, c.d_model)), lp
     d = jax.random.normal(jax.random.PRNGKey(3), (c.d_model,))
-    x = (d + 0.05 * jax.random.normal(jax.random.PRNGKey(4),
-                                      (512, c.d_model)))
-    chosen = jnp.zeros((c.n_experts,)).at[jnp.array([2, 3, 5, 6])].set(1.0)
-    lp["router"] = jnp.outer(d, 2.0 * chosen - 1.0) / jnp.linalg.norm(d)
+    x = d + 0.05 * jax.random.normal(jax.random.PRNGKey(4), (n, c.d_model))
+    picked = (c.first_expert_held + jnp.array([1, 2, 5, 9])) % c.n_experts
+    chosen = jnp.zeros((c.n_experts,)).at[picked].set(1.0)
+    return x, {**lp, "router": jnp.outer(d, 2.0 * chosen - 1.0)
+               / jnp.linalg.norm(d)}
+
+
+@pytest.mark.parametrize("routing", ["even", "uneven"])
+@pytest.mark.parametrize("tile", [16, 128])
+@pytest.mark.parametrize("first, held", [(0, 16), (8, 4), (4, 2)],
+                         ids=["all", "quarter", "eighth"])
+def test_the_grouped_product_is_the_dense_one(first, held, tile, routing):
+    """All, a quarter and an eighth of 16 experts held; 200 rows, not a
+    multiple of either tile. Under the uneven routing one held expert has
+    no row and one has 200 (13 tiles of 16, 2 of 128): no assignment is
+    dropped, the grouped product equals running every held expert on every
+    token — combined the way ``combine_form`` says for such a call (the
+    add for an eighth held and for a quarter in tiles of 16, the gather
+    elsewhere) — and the block (tiles of 128) equals the reference."""
+    c = dataclasses.replace(TINY, n_experts_held=held,
+                            first_expert_held=first)
+    x, lp = _routed(c, _share(_mlp_of(params_of(TINY)), first, held), 200,
+                    routing)
     hf = hybrid.rms_norm(x, lp["norm"], c.rms_eps)
     idx, w = hybrid.route(hf, lp["router"], c)
-    assert set(np.unique(np.asarray(idx))) == {2, 3, 5, 6}
     probs = hybrid.held_weights(idx, w, c)
-    grouped = jax.jit(lambda: hybrid.experts_grouped(
-        hf, probs, lp, c.experts_per_token))()
-    dense = hybrid.experts_dense(hf, probs, lp)
-    np.testing.assert_allclose(grouped, dense, atol=2e-5)
-    got = hybrid.moe_block(x[None], lp, c)[0][0] - x      # the grouped path
-    want = ref.expert_mlp(x, lp, sizes_of(c))
-    np.testing.assert_allclose(got, want, atol=5e-5)
-
-
-def test_uneven_routing_through_the_grouped_product_matches_dense():
-    c = dataclasses.replace(TINY, n_experts_held=8, first_expert_held=8)
-    lp = _share(_mlp_of(params_of(TINY)), 8, 8)
-    x = jax.random.normal(jax.random.PRNGKey(9), (200, c.d_model))
-    idx, w = hybrid.route(x, lp["router"], c)
-    probs = hybrid.held_weights(idx, w, c)
-    grouped = hybrid.experts_grouped(x, probs, lp, c.experts_per_token,
-                                     tile=16)
-    np.testing.assert_allclose(grouped, hybrid.experts_dense(x, probs, lp),
+    counts = np.asarray(jnp.sum(probs > 0, axis=0))
+    if routing == "uneven":
+        assert counts[0] == 0 and counts[1] == 200 > tile
+    grouped, tiles = jax.jit(lambda: hybrid.experts_grouped(
+        hf, idx - first, w, lp, held, tile=tile,
+        combine=hybrid.combine_form(200, c.experts_per_token, held,
+                                    c.n_experts, tile)))()
+    assert list(np.asarray(tiles)) == [np.sum(-(-counts // tile)),
+                                       counts.sum()]
+    np.testing.assert_allclose(grouped, hybrid.experts_dense(hf, probs, lp),
                                atol=2e-5)
+    got, counted = hybrid.moe_block(x[None], lp, c)      # the grouped path
+    np.testing.assert_allclose(got[0] - x, ref.expert_mlp(x, lp, sizes_of(c)),
+                               atol=5e-5)
+    assert list(np.asarray(counted)) == [
+        0, 0, 0, int(np.sum(-(-counts // hybrid.GROUP_TILE))), counts.sum()]
+
+
+# What the form before PR 39 (a float32 carry that every tile gathered into
+# and scatter-added out of, each tile quantising its own rows) stood from
+# ``experts_dense`` on the same int8 tree, read on that tree's parent at
+# these shapes: float32 rows 4.5e-8 / 2.2e-8 / 2.2e-8 (all / a quarter / an
+# eighth held; results of size 0.45 / 0.31 / 0.24), bfloat16 rows 3.443e-3
+# / 3.080e-3 / 2.226e-3 (both forms round each expert's result to bfloat16,
+# in another order of fused operations than the dense form's).
+INT8_DISTANCE = {(jnp.float32, 16): 1e-7, (jnp.float32, 4): 1e-7,
+                 (jnp.float32, 2): 1e-7, (jnp.bfloat16, 16): 3.45e-3,
+                 (jnp.bfloat16, 4): 3.09e-3, (jnp.bfloat16, 2): 2.23e-3}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("first, held", [(0, 16), (8, 4), (4, 2)],
+                         ids=["all", "quarter", "eighth"])
+def test_rows_quantised_once_stand_no_further_from_dense(first, held, dtype):
+    """int8 weights: the grouped product quantises its rows ONCE, before
+    the loop, and every tile gathers int8 rows and their scales; per-row
+    quantisation commutes with a gather, so under either combine it stands
+    from the dense form no further than the form that quantised in every
+    tile did."""
+    c = dataclasses.replace(TINY, n_experts_held=held,
+                            first_expert_held=first)
+    lp = _share(_mlp_of(params_of(TINY, dtype, "int8")), first, held)
+    assert is_quantized(lp["wg"])
+    x = jax.random.normal(jax.random.PRNGKey(9), (200, c.d_model)
+                          ).astype(dtype)
+    idx, w = hybrid.route(x.astype(jnp.float32), lp["router"], c)
+    dense = hybrid.experts_dense(x, hybrid.held_weights(idx, w, c), lp)
+    for tile, combine in ((16, "gather"), (128, "gather"), (16, "add"),
+                          (128, "add")):
+        grouped, _ = hybrid.experts_grouped(x, idx - first, w, lp, held,
+                                            tile=tile, combine=combine)
+        assert float(jnp.abs(grouped - dense).max()) \
+            <= INT8_DISTANCE[dtype, held]
+
+
+@pytest.mark.parametrize("preset, rows, form", [
+    ("smallthinker-21b-pp3", 1024, "gather"),       # all 64 held, top-6
+    ("smallthinker-21b-pp3", 128, "gather"),
+    ("mistral-small4-119b-ep4", 2048, "gather"),    # 32 of 128, top-4
+    ("mistral-small4-119b-ep4", 512, "gather"),
+    ("solar-open2-250b-ep8", 2048, "add"),          # 40 of 320, top-8
+    ("solar-open2-250b-ep8", 1536, "add"),
+    ("solar-open2-250b-ep8", 1024, "gather"),
+    ("solar-open2-250b-ep8", 128, "gather"),
+])
+def test_the_combine_follows_the_reads_against_the_added_rows(preset, rows,
+                                                              form):
+    """``combine_form``'s docstring, at the three expert cells' presets
+    and the calls the probe measured: every token gathers its rows after
+    the loop unless that is more than 4/3 as many row reads as the rows
+    the loop's add would touch — which only the cell that holds an eighth
+    of its experts reaches, in its fullest calls."""
+    c = get_preset(preset)
+    assert hybrid.combine_form(rows, c.experts_per_token, c.experts_held,
+                               c.n_experts) == form
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def test_nothing_scatters_in_the_tile_loop_and_rows_are_quantised_once():
+    """The traced ``experts_grouped`` at cell 5's shape (1 024 rows of
+    2 560, 64 experts of width 768 held of 64, top-6, int8, the stack read
+    at a period): ONE loop; no scatter of any kind in its body; outside
+    it exactly one rounding to int8, of the rows and their zero row;
+    inside it the only rounding is the hidden activation's, which exists
+    only there. A read-modify-write of the carry cannot return unseen."""
+    N, D, F, E, k = 1024, 2560, 768, 64, 6
+    sds = jax.ShapeDtypeStruct
+
+    def stack(din, dout):
+        return {"q": sds((2, E, din, dout), jnp.int8),
+                "s": sds((2, E, dout), jnp.float32)}
+    lp = {"wg": stack(D, F), "wu": stack(D, F), "wd": stack(F, D)}
+    jaxpr = jax.make_jaxpr(
+        lambda x, idx, w, lp, period: hybrid.experts_grouped(
+            x, idx, w, lp, E, period=period, act="relu"))(
+        sds((N, D), jnp.bfloat16), sds((N, k), jnp.int32),
+        sds((N, k), jnp.float32), lp, sds((), jnp.int32)).jaxpr
+    loops = [e for e in jaxpr.eqns if e.primitive.name == "while"]
+    assert len(loops) == 1
+    body = list(_eqns(loops[0].params["body_jaxpr"].jaxpr))
+    assert not [e for e in body if "scatter" in e.primitive.name]
+    assert any(e.primitive.name == "dynamic_update_slice" for e in body)
+
+    def rounded(eqns):
+        return [e.invars[0].aval.shape for e in eqns
+                if e.primitive.name == "round"]
+    assert rounded(body) == [(hybrid.GROUP_TILE, F)]
+    outside = [e for e in _eqns(jaxpr) if not any(e is b for b in body)]
+    assert rounded(outside) == [(N + 1, D)]
+    # The carry is the sorted-order buffer in the rows' dtype, not a
+    # float32 image of the result.
+    carried = [v.aval for v in loops[0].outvars if v.aval.ndim == 2]
+    assert [(a.shape[1], a.dtype) for a in carried] == [(D, jnp.bfloat16)]
 
 
 # -- initialisation and presets -----------------------------------------------

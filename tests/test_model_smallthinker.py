@@ -178,7 +178,7 @@ def test_prefill_in_chunks_then_decode_matches_the_reference(
         assert np.median(errs.max(-1)) <= 0.08
     # 4 steps x 2 decoding slots x top-3 x 8 layers, all held here; of the
     # 8 experts a layer the 2 rows x top-3 reach 3 to 6.
-    total, local, hit = np.asarray(cache.counters)
+    total, local, hit = np.asarray(cache.counters)[:3]
     assert total == local == 4 * 2 * 3 * 8
     assert 4 * 8 * 3 <= hit <= 4 * 8 * 6
 
