@@ -72,6 +72,7 @@ from ..config.schemas import LocalEngineConfig
 from ..models import forward_fn, init_fn, llama
 from ..models.config import ModelConfig, get_preset
 from ..obs.device import phase as _device_phase
+from ..obs.phases import ReqWaits, SchedLedger
 from ..parallel.mesh import MeshSpec, build_mesh
 from ..parallel.sharding import cache_sharding, param_shardings
 from .sampling import SamplingParams, sample
@@ -197,6 +198,10 @@ class GenRequest:
     t_admitted: float | None = None   # slot admission (queued-phase end)
     t_first_token: float | None = None
     t_done: float | None = None
+    # What the time in the slot went to (ISSUE 41, obs/phases.py): seven
+    # buckets the scheduler's ledger credits from its own clock readings,
+    # partitioning [t_admitted, t_first_loop] and [t_first_loop, t_done].
+    waits: ReqWaits = field(default_factory=ReqWaits)
     # Flight-recorder cross-links (ISSUE 7): the seq numbers of this
     # request's admit/finish records, surfaced as trace-span attributes.
     flight_admit_seq: int = -1
@@ -215,6 +220,12 @@ class GenRequest:
     @property
     def done(self) -> bool:
         return self.finish_reason is not None
+
+    @property
+    def t_first_loop(self) -> float | None:
+        """The loop's side of ``t_first_token``: the ledger's reading at
+        the close of the prefill wait whose call finished the prompt."""
+        return self.waits.t_first_loop
 
 
 @dataclass
@@ -419,7 +430,6 @@ class InferenceEngine:
         # loop's wall partitioned into named phases, and each worker-
         # thread wait into what the worker did with it. Loop-thread only;
         # the worker reaches its call through `_device_phase`.
-        from ..obs.phases import SchedLedger
         self._sched = SchedLedger()
         # What the last compiled prefill dispatch ran (rows, bucket,
         # tokens, lowest/highest start position, KV pages walked, t0,
@@ -1728,6 +1738,8 @@ class InferenceEngine:
         self._init_state()
         for pool in self._pools:
             pool.reset_free()
+        for req in self._running.values():
+            self._sched.left(req)       # dropped unreleased: its wall ends
         self._running.clear()
         self._prefilling.clear()
         # The ledger's tracked buffers were donated/freed with the old
@@ -1863,7 +1875,9 @@ class InferenceEngine:
         with self._sched.span("admit"):
             self._admit(fl)
 
-        t_pf0 = fl.clock() if fl is not None else 0.0
+        # The prefill waits' wall as the ledger read it: one reading for its
+        # counter, the requests' credit and the prefill pool's step record.
+        pf_wall_ms = 0.0
         # 2. Advance each pending prefill by ONE chunk (chunked-prefill
         #    interleave: a long prompt never blocks decode for more than one
         #    chunk — SURVEY.md §7 hard part (6)). Same-bucket chunks group
@@ -1884,9 +1898,10 @@ class InferenceEngine:
                     # don't burn one more prefill chunk on a dead client.
                     self._finish(req, "cancelled", emit=False)
                     continue
-                with self._sched.wait("prefill_wait"):
+                with self._sched.wait("prefill_wait", (req,)):
                     prompt_done = await asyncio.to_thread(
                         self._prefill_one_chunk, req)
+                pf_wall_ms += self._sched.wait_ms
                 n_chunks += 1
                 self._record_prefill(fl)
                 if prompt_done:
@@ -1920,9 +1935,10 @@ class InferenceEngine:
                         break
                     batch = self.prefill_groups(live)[0]
                     pending = live[len(batch):]
-                    with self._sched.wait("prefill_wait"):
+                    with self._sched.wait("prefill_wait", batch):
                         dones = await asyncio.to_thread(
                             self._prefill_chunk_group, batch)
+                    pf_wall_ms += self._sched.wait_ms
                     n_chunks += 1
                     self._record_prefill(fl)
                     with self._sched.span("emit"):
@@ -1943,7 +1959,6 @@ class InferenceEngine:
             # burst below. A unified engine keeps its single combined
             # record — snapshot-identical to the pre-pool format.
             from ..obs import flight as _fl
-            pf_wall_ms = 1000.0 * (fl.clock() - t_pf0)
             self._disagg.note_prefill_wall(pf_wall_ms / n_chunks)
             fitted = self._ema_step_ms_stats
             fl.record(
@@ -2111,7 +2126,6 @@ class InferenceEngine:
                 if self._swa_ring_pages:
                     self._swa_rotate(decoding, inflight, max(1, burst) * kp1)
                 burst = max(1, burst)
-                t_dec0 = fl.clock() if fl is not None else 0.0
                 spec_acc0 = self._spec_accepted_total
                 with self._sched.wait("decode_wait"):
                     step_tokens = await asyncio.to_thread(
@@ -2135,12 +2149,11 @@ class InferenceEngine:
                 burst = max(1, burst)
                 if self._swa_ring_pages:
                     self._swa_rotate(decoding, inflight, burst)
-                t_dec0 = fl.clock() if fl is not None else 0.0
                 with self._sched.wait("decode_wait"):
                     step_tokens = await asyncio.to_thread(
                         self._decode_burst, burst)
-            dec_wall_ms = (1000.0 * (fl.clock() - t_dec0)
-                           if fl is not None else 0.0)
+            # The burst's wall is the wait's, by the ledger's readings.
+            dec_wall_ms = self._sched.wait_ms
             with self._sched.span("emit"):
                 for tokens in step_tokens:          # in generation order
                     for req in decoding:
@@ -2155,6 +2168,7 @@ class InferenceEngine:
                         req.generated.append(tok)
                         n_tok += 1
                         self._emit_token(req)
+            self._sched.decode_tokens += n_tok - n_tok_prefill
         progressed = bool(decoding) or bool(self._prefilling)
         if not progressed and self._free_slot_count() and (
                 self._head is not None or not self._queue.empty()):
@@ -2346,6 +2360,7 @@ class InferenceEngine:
             # half of TTFT — what the prefill-aware burst clamp bounds.
             # t_admitted also closes the trace's engine.queued phase.
             req.t_admitted = time.monotonic()
+            self._sched.admitted(req, req.t_admitted)
             wait_ms = 1000.0 * (req.t_admitted - req.t_submit)
             self._queue_wait_n += 1
             self._queue_wait_ema_ms = (
@@ -2458,7 +2473,7 @@ class InferenceEngine:
             # prefill program (see prefill_step) — ONE host fetch for the
             # whole group completes the TTFT path.
             if first_np is None:
-                with _device_phase("sched.fetch"):
+                with _device_phase("sched.fetch.first"):
                     first_np = np.asarray(first)
             first_id = int(first_np[i])
             req.generated.append(first_id)
@@ -2692,7 +2707,7 @@ class InferenceEngine:
                     em, _ = self._spec_step(*args)
                 _start_host_copy(em)
                 outs.append(em)
-            with _device_phase("sched.fetch"):
+            with _device_phase("sched.fetch.sync"):
                 host = np.stack([np.asarray(e) for e in outs])
         self.kernels.record(kname, steps=n_steps,
                             wall_ms=1000.0 * (time.monotonic() - t0))
@@ -2820,7 +2835,7 @@ class InferenceEngine:
         if entry is None:
             return []
         emitted, _, active_snap, epoch_snap, drafting = entry
-        with _device_phase("sched.fetch"):
+        with _device_phase("sched.fetch.spec"):
             host = np.asarray(emitted)                   # [n, B, k+1]
         live = active_snap & (epoch_snap == self._slot_epoch)
         return self._spec_walk(host, active_snap, live, drafting=drafting)
@@ -2912,7 +2927,7 @@ class InferenceEngine:
         if entry is None:
             return []
         toks_dev, n, active_snap, epoch_snap, len_snap, last_snap = entry
-        with _device_phase("sched.fetch"):
+        with _device_phase("sched.fetch.burst"):
             host = np.asarray(toks_dev)                  # [n, B (+ counters)]
         if host.shape[1] > self.B:
             seen = host[-1, self.B:].astype(np.int64)
@@ -3155,7 +3170,7 @@ class InferenceEngine:
                     self.cache = step_fn(*args)
                 _start_host_copy(self._d_tokens)
                 pending.append(self._d_tokens)
-            with _device_phase("sched.fetch"):
+            with _device_phase("sched.fetch.sync"):
                 step_tokens = [np.asarray(t) for t in pending]
         # The fetch above synchronizes, so this wall is honest per call.
         self.kernels.record(kname, steps=n_steps,
@@ -3278,6 +3293,7 @@ class InferenceEngine:
 
     def _release(self, req: GenRequest) -> None:
         if req.slot in self._running:
+            self._sched.left(req, req.t_done)
             if self.paged and self._prefix_cache is not None:
                 self._prefix_release(req)
             del self._running[req.slot]

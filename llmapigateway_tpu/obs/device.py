@@ -454,11 +454,17 @@ worker_call: contextvars.ContextVar[Any] = contextvars.ContextVar(
 
 def worker_kind(span: str) -> str | None:
     """Which of the scheduler's worker counters (obs/phases.py) a span's
-    wall belongs to. ``sched.fetch`` is a blocking device→host read;
+    wall belongs to. ``sched.fetch.*`` is a blocking device→host read,
+    named by what it reads: ``.first`` prefill's first token (no program
+    is queued behind it, so the device idles: kept apart as a part of
+    ``fetch``), ``.burst`` the lag-one burst's tokens, ``.spec`` the
+    lag-one speculative burst's, ``.sync`` the synchronous paths';
     ``prefill`` / ``decode`` / ``spec.*`` are the jitted calls with their
     argument build; any other ``sched.*`` span entered on the worker is its
     outermost one, where its own wall begins."""
-    if span == "sched.fetch":
+    if span == "sched.fetch.first":
+        return "fetch_first"
+    if span.startswith("sched.fetch"):
         return "fetch"
     if span in ("prefill", "decode") or span.startswith("spec."):
         return "dispatch"

@@ -53,6 +53,10 @@ class LocalProvider(Provider):
     # queued (submit → slot admission), prefill (admission → first token),
     # then decode/drain recorded at stream end. `parent` is the
     # provider.call span captured while complete() was current.
+    # Both spans carry what the request waited behind in them (ISSUE 41,
+    # obs/phases.py ``ReqWaits``): the buckets partition admission → the
+    # loop's first-token reading → done, so they sum to the spans' walls
+    # plus/minus the worker's tail after its first-token stamp.
 
     def _trace_admission(self, req, parent) -> None:
         if req.t_first_token is None:
@@ -75,10 +79,16 @@ class LocalProvider(Provider):
                 "engine.prefix_lookup", layer="engine",
                 start=t_admit - req.prefix_lookup_ms / 1000.0, end=t_admit,
                 parent=parent, cached_tokens=req.cached_tokens)
+        w = req.waits
+        waited = ({"own_ms": round(w.ttft_own_prefill, 3),
+                   "behind_prefill_ms": round(w.ttft_behind_prefill, 3),
+                   "behind_decode_ms": round(w.ttft_behind_decode, 3),
+                   "loop_ms": round(w.ttft_loop, 3)}
+                  if w.t_first_loop is not None else {})
         obs_trace.record_span("engine.prefill", layer="engine",
                               start=t_admit, end=req.t_first_token,
                               parent=parent,
-                              prompt_tokens=len(req.prompt_ids))
+                              prompt_tokens=len(req.prompt_ids), **waited)
         obs_trace.record_span("engine.first_token", layer="engine",
                               start=req.t_first_token, end=req.t_first_token,
                               parent=parent)
@@ -94,6 +104,11 @@ class LocalProvider(Provider):
             attrs["finish_reason"] = req.finish_reason
         if error:
             attrs["error"] = error[:200]
+        w = req.waits
+        if w.closed and w.t_first_loop is not None:
+            attrs.update(in_decode_ms=round(w.decode_in_decode, 3),
+                         behind_prefill_ms=round(w.decode_behind_prefill, 3),
+                         loop_ms=round(w.decode_loop, 3))
         obs_trace.record_span("engine.decode", layer="engine",
                               start=req.t_first_token, end=end,
                               parent=parent, **attrs)

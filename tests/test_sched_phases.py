@@ -29,10 +29,15 @@ from llmapigateway_tpu.config.schemas import LocalEngineConfig
 from llmapigateway_tpu.engine.engine import GenRequest, InferenceEngine
 from llmapigateway_tpu.obs import device as dev
 from llmapigateway_tpu.obs import flight as fl
-from llmapigateway_tpu.obs.phases import (LOOP_PHASES, WORKER_PHASES,
-                                          SchedLedger)
+from llmapigateway_tpu.obs.phases import (LOOP_PHASES, REQ_BUCKETS,
+                                          WORKER_PHASES, SchedLedger)
 
 KEYS = [f"sched_{k}_ms_total" for k in LOOP_PHASES + WORKER_PHASES]
+# ISSUE 41 (tests/test_request_waits.py): a part of ``fetch``, the requests'
+# seven totals and the two counts they are read against.
+REQ_KEYS = (["sched_fetch_first_ms_total", "req_first_tokens_total",
+             "req_decode_tokens_total"]
+            + [f"req_{k}_ms_total" for k in REQ_BUCKETS])
 
 
 class FakeClock:
@@ -59,7 +64,7 @@ def _sums(stats: dict) -> tuple[float, float, float]:
 def test_loop_counters_partition_the_wall_exactly():
     clk = FakeClock()
     led = SchedLedger(clock=clk)
-    assert set(led.stats()) == set(KEYS)
+    assert set(led.stats()) == set(KEYS) | set(REQ_KEYS)
     assert all(v == 0.0 for v in led.stats().values())
     led.start()
     clk.tick(3)                              # other
@@ -230,7 +235,9 @@ def test_a_reader_racing_the_worker_sees_whole_tuples():
 
 
 @pytest.mark.parametrize("span,kind", [
-    ("sched.fetch", "fetch"), ("prefill", "dispatch"),
+    ("sched.fetch", "fetch"), ("sched.fetch.burst", "fetch"),
+    ("sched.fetch.spec", "fetch"), ("sched.fetch.sync", "fetch"),
+    ("sched.fetch.first", "fetch_first"), ("prefill", "dispatch"),
     ("decode", "dispatch"), ("spec.verify", "dispatch"),
     ("sched.decode_burst", "worker_other"),
     ("sched.prefill_group", "worker_other"),
@@ -385,7 +392,11 @@ async def test_spans_thread_order_and_none_across_an_await(engine,
     assert not held, held
     names = {n for _, n, _ in rec.events}
     assert {"sched.admit", "sched.emit", "sched.prefill_group", "prefill",
-            "sched.decode_burst", "decode", "sched.fetch"} <= names
+            "sched.decode_burst", "decode", "sched.fetch.first"} <= names
+    # Every read is named by what it reads (ISSUE 41): no bare fetch.
+    fetches = {n for n in names if n.startswith("sched.fetch")}
+    assert fetches <= {"sched.fetch.first", "sched.fetch.burst",
+                       "sched.fetch.sync"}
     assert "sched.parked" not in names and "sched.hop" not in names
     for kind, name, tid in rec.events:
         if name in ("sched.admit", "sched.emit"):
@@ -402,16 +413,16 @@ async def test_spans_thread_order_and_none_across_an_await(engine,
             if st:
                 inside.add((st[-1], name))
             else:
-                assert name.startswith("sched.") and name != "sched.fetch", \
-                    name
+                assert name.startswith("sched.") \
+                    and not name.startswith("sched.fetch"), name
             st.append(name)
         else:
             assert st.pop() == name
     assert ("sched.prefill_group", "prefill") in inside
-    assert ("sched.prefill_group", "sched.fetch") in inside
+    assert ("sched.prefill_group", "sched.fetch.first") in inside
     assert ("sched.decode_burst", "decode") in inside
-    assert (("sched.decode_burst", "sched.fetch") in inside
-            or ("decode", "sched.fetch") in inside)
+    assert (("sched.decode_burst", "sched.fetch.burst") in inside
+            or ("decode", "sched.fetch.sync") in inside)
     # Admission comes before the first prefill, emission after it.
     order = [n for k, n, _ in rec.events if k == "enter"]
     assert order.index("sched.admit") < order.index("sched.prefill_group") \
