@@ -54,11 +54,10 @@ TPU-first decisions:
 * The expert layer has two exact forms: every held expert on every token
   (decode, small calls: the weights stream from memory either way), and a
   grouped product over the assignments sorted by expert, tile by tile,
-  whose trip count is the number of live tiles. The grouped product
-  quantises its rows once, before the loop, and brings the results back
-  to the tokens by a gather after the loop or by the loop's own add,
-  whichever the share of the experts held makes cheaper
-  (``combine_form``).
+  whose trip count is the number of live tiles: ONE Pallas kernel
+  (``ops/grouped_experts.py``) over rows quantised once a call, which adds
+  each tile's weighted results onto its tokens' rows of a float32 result
+  that stays in fast memory.
 """
 from __future__ import annotations
 
@@ -73,7 +72,8 @@ from . import mla
 from .config import ModelConfig
 from .llama import (_GATE_ACTS, apply_rope, rms_norm, rope_tables,
                     swiglu_mlp)
-from .quant import (_dynamic_int8, head_matmul, is_quantized, mm, mm_q8,
+from ..ops.grouped_experts import grouped_experts, rows_that_fit
+from .quant import (_dynamic_int8, head_matmul, is_quantized, mm,
                     moe_mm_batched, quantize_array, weight_bits)
 
 Params = dict[str, Any]
@@ -576,104 +576,90 @@ def grouped_layout(idx: jax.Array, held: int, tile: int) -> GroupedLayout:
                          jnp.stack([last_tile[-1], jnp.sum(counts)]))
 
 
-def combine_form(n_rows: int, k: int, held: int, n_experts: int,
-                 tile: int = GROUP_TILE) -> str:
-    """How ``experts_grouped`` brings the tiles' results back to the
-    tokens, from what a call can see of itself. "gather": after the loop
-    every token gathers the rows of its ``k`` assignments —
-    ``n_rows * k`` row reads, whatever landed. "add": every tile adds its
-    rows into a float32 carry — a read-modify-write of ``tile`` rows a
-    live tile, padding included; by expectation a partial tile an expert
-    held and the whole tiles of the ``held / n_experts`` of the
-    assignments that land. With every expert held each read is a row that
-    landed; with a share held ``1 / share`` reads find one, but a small
-    call still runs a tile an expert. A slot read costs 70-100 ns and an
-    added row ~230 (``tools/probe_experts.py``, PERF.md section 5), the
-    sorted buffer's zeros go with the reads, and the two forms met where
-    the reads were 4/3 of the added rows. One layer call on a v5e, int8,
-    ms (gather | add; in brackets the form before PR 39): all 64 held,
-    top-6, 1 024 rows 1.79 | 2.34 (2.81), 128 rows 1.38 | 1.87 (1.89);
-    32 of 128, top-4, 2 048 rows 2.48 | 2.52 (2.93), 1 024 rows 1.88 |
-    2.35 (2.56); 40 of 320, top-8, 2 048 rows 3.41 | 2.62 (3.03), 1 536
-    rows 2.75 | 2.50 (2.79), 1 024 rows 1.86 | 2.38 (2.59), 128 rows
-    1.59 | 2.04 (2.06): the rule picks the cheaper form at each of the
-    sixteen calls measured."""
-    adds = tile * (held + n_rows * k * held // (n_experts * tile))
-    return "gather" if 3 * n_rows * k <= 4 * adds else "add"
-
-
-def _expert_rows(xt: tuple, m: Params, act: str, dtype) -> jax.Array:
-    """One expert's gated MLP on a tile's rows. ``xt``: the rows, or the
-    int8 rows and their scales (``_dynamic_int8``'s pair) for int8
-    matrices ``m``; the hidden activation is quantised here."""
-    up = ((lambda w: mm_q8(*xt, w, dtype)) if len(xt) == 2
-          else (lambda w: xt[0] @ w))
-    return mm(_GATE_ACTS[act](up(m["wg"])) * up(m["wu"]), m["wd"])
-
-
 def experts_grouped(x: jax.Array, idx: jax.Array, w: jax.Array, lp: Params,
                     held: int, tile: int = GROUP_TILE,
-                    period: jax.Array | None = None, act: str = "silu",
-                    combine: str = "gather") -> tuple[jax.Array, jax.Array]:
+                    period: jax.Array | None = None, act: str = "silu"
+                    ) -> tuple[jax.Array, jax.Array]:
     """The held experts' part of the result with work that follows the
     assignments. x [N,D]; idx, w [N,k]: each token's experts, numbered
     from the first one held, and their weights -> ([N,D] float32, int32
     [2]: the tiles run and the rows they held).
 
     Rows are laid out expert by expert in tiles of ``tile``
-    (``grouped_layout``) and a loop runs the LIVE tiles only. ``x`` is
-    quantised ONCE, before the loop (per-row quantisation commutes with a
-    gather of rows, so every product sees the numbers ``mm`` would give
+    (``grouped_layout``) and ONE Pallas kernel
+    (``ops/grouped_experts.py``) runs the LIVE tiles only. ``x`` is
+    quantised ONCE, outside the kernel (per-row quantisation commutes with
+    a gather of rows, so every product sees the numbers ``mm`` would give
     it; the hidden activation is quantised in its tile, where alone it
-    exists); a tile gathers its int8 rows and their scales and runs its
-    expert. ``combine`` (``combine_form``) "gather": the tile writes its
-    result where it lies in expert order — a contiguous
-    ``dynamic_update_slice``, no read, no index vector, no dependence on
-    the tile before — and after the loop every token gathers the rows its
-    assignments landed in and sums them, weighted, in float32: nothing in
-    the loop scatters. "add": the tile adds its weighted rows into a
-    float32 [N + 1, D] carry (padding rows all name row N; telling the
-    add that a tile's rows are ascending and unique, on dummy rows for
-    the padding, made it dearer: 3.79 against 2.62 ms).
+    exists). In the kernel a tile's int8 rows and their scales arrive by
+    index, its three products and the gate stay in fast memory, and its
+    result — rounded to the rows' dtype, as ``mm`` returns it — is added,
+    weighted, in float32 onto its tokens' rows of the result, which lives
+    in fast memory for the whole call: nothing scatters, nothing is
+    gathered back, and an assignment that landed on no held expert costs
+    nothing (there is ONE way back to the tokens; ``combine_form`` chose
+    between two until PR 43).
 
     With ``period`` the experts' matrices are the whole stack over periods
-    and the loop reads ``[period, expert]`` of it in place: a loop handed
-    one period's slice is handed a COPY of it (0.69 ms a matrix a layer on
-    a v5e, 16 ms a prefill call at the published widths)."""
-    N, D = x.shape
-    lay = grouped_layout(idx, held, tile)
-    rows = lay.row_token.shape[0]
+    and the kernel reads ``[period, expert]`` of it in place: a sliced
+    period is a COPY of it (0.69 ms a matrix a layer on a v5e).
+
+    The call's rows and its result are resident in the kernel, so a call
+    of more rows than fit beside the streamed matrices (``rows_that_fit``:
+    3 392 rows of 4 096 at a width of 2 048, int8) runs in slices of that
+    many, each with a layout of its own.
+
+    A slice is one jitted call (``_grouped``): the expert layers a program
+    unrolls share one traced body and one lowered function, and programs
+    of the same row count share the trace — what a Pallas kernel costs in
+    set-up is paid once a program, not once a layer (PERF.md section 6,
+    PR 43)."""
     stack = {key: lp[key] for key in EXPERT_KEYS}
+    if period is None:
+        stack = jax.tree.map(lambda a: a[None], stack)
+        period = jnp.zeros((), jnp.int32)
+    quantized = is_quantized(stack["wg"])
+    wg = stack["wg"]["q"] if quantized else stack["wg"]
+    cap = rows_that_fit(*wg.shape[-2:], wg.dtype.itemsize,
+                        1 if quantized else x.dtype.itemsize)
+    parts = [_grouped(x[lo:lo + cap], idx[lo:lo + cap], w[lo:lo + cap],
+                      stack, period, held=held, tile=tile, act=act)
+             for lo in range(0, x.shape[0], cap)]
+    if len(parts) == 1:
+        return parts[0]
+    return (jnp.concatenate([out for out, _ in parts]),
+            sum(counted for _, counted in parts))
+
+
+def grouped_inputs(x, idx, w, stack, period, held: int, tile: int) -> tuple:
+    """What the kernel is handed for a call, beside the layout's counts:
+    (live tiles and period, each tile's expert, each row's token, each
+    row's weight, the rows with their zero row — quantised ONCE for int8
+    matrices —, the matrices flattened)."""
+    lay = grouped_layout(idx, held, tile)
     # Padding rows read token N: a zero row, whose result is zero.
-    x_pad = jnp.concatenate([x, jnp.zeros((1, D), x.dtype)])
-    src = _dynamic_int8(x_pad) if is_quantized(stack["wg"]) else (x_pad,)
+    x_pad = jnp.concatenate([x, jnp.zeros((1, x.shape[1]), x.dtype)])
+    if is_quantized(stack["wg"]):
+        src = _dynamic_int8(x_pad)
+        mats = tuple(stack[key][part] for key in EXPERT_KEYS
+                     for part in ("q", "s"))
+    else:
+        src = (x_pad,)
+        mats = tuple(stack[key] for key in EXPERT_KEYS)
+    meta = jnp.stack([lay.counted[0], period.astype(jnp.int32)])
+    row_weight = jnp.zeros(lay.row_token.shape, jnp.float32).at[
+        lay.dest.reshape(-1)].set(w.reshape(-1), mode="drop")
+    return (meta, lay.tile_expert, lay.row_token, row_weight, src,
+            mats), lay.counted
 
-    def run(i):
-        """Tile ``i``: the tokens of its rows, their results [tile, D]."""
-        m = _at(_at(stack, period), lay.tile_expert[i])
-        at = jax.lax.dynamic_slice_in_dim(lay.row_token, i * tile, tile)
-        return at, _expert_rows(tuple(a[at] for a in src), m, act, x.dtype)
 
-    if combine == "add":
-        row_weight = jnp.zeros((rows,), jnp.float32).at[
-            lay.dest.reshape(-1)].set(w.reshape(-1), mode="drop")
-
-        def add(i, out):
-            at, y = run(i)
-            wt = jax.lax.dynamic_slice_in_dim(row_weight, i * tile, tile)
-            return out.at[at].add(wt[:, None] * y.astype(jnp.float32))
-        out = jax.lax.fori_loop(0, lay.counted[0], add,
-                                jnp.zeros((N + 1, D), jnp.float32))
-        return out[:N], lay.counted
-
-    def write(i, ys):
-        return jax.lax.dynamic_update_slice_in_dim(ys, run(i)[1], i * tile, 0)
-    # One more row than the tiles fill: the zero row that assignments to
-    # experts not held here read.
-    ys = jax.lax.fori_loop(0, lay.counted[0], write,
-                           jnp.zeros((rows + 1, D), x.dtype))
-    out = jnp.sum(w[:, :, None] * ys[lay.dest].astype(jnp.float32), axis=1)
-    return out, lay.counted
+@partial(jax.jit, static_argnames=("held", "tile", "act"))
+def _grouped(x, idx, w, stack, period, *, held, tile, act):
+    """``experts_grouped`` on a period-stacked tree: the layout, the
+    rows' one rounding, each row's weight, the kernel."""
+    given, counted = grouped_inputs(x, idx, w, stack, period, held, tile)
+    return grouped_experts(*given, tile=tile, act=act,
+                           dtype=x.dtype), counted
 
 
 def moe_block(x: jax.Array, lp: Params, c: ModelConfig,
@@ -704,9 +690,7 @@ def moe_block(x: jax.Array, lp: Params, c: ModelConfig,
         else:
             y, tiled = experts_grouped(
                 xf, idx - c.first_expert_held, w, lp, c.experts_held,
-                period=period, act=c.moe_act,
-                combine=combine_form(B * T, c.experts_per_token,
-                                     c.experts_held, c.n_experts))
+                period=period, act=c.moe_act)
         y = y.reshape(B, T, D).astype(x.dtype)
     with jax.named_scope("moe.shared"):
         if c.n_shared_experts:

@@ -586,3 +586,45 @@ def test_latent_kernels_compile_at_the_served_geometry(chips, rows, tokens):
     assert la.latent_block_t(tokens, heads) == min(tokens, 64)
     # q in, the latent-wide out, and nothing the size of a layer's pool.
     assert attend.memory_analysis().temp_size_in_bytes < pages * width * PAGE
+
+
+# -- the grouped expert product's kernel (PR 43) -------------------------------
+
+@pytest.mark.parametrize("rows, D, F, held, k, act", [
+    (1024, 2560, 768, 64, 6, "relu"),       # smallthinker-21b-pp3
+    (2048, 4096, 1280, 40, 8, "silu"),      # solar-open2-250b-ep8
+    (128, 4096, 1280, 40, 8, "silu"),       # its smallest bucket
+    (2048, 4096, 2048, 32, 4, "silu"),      # mistral-small4-119b-ep4
+], ids=["cell5", "solar", "solar-128", "small4"])
+def test_the_grouped_expert_kernel_compiles_at_the_cells_widths(
+        chips, monkeypatch, rows, D, F, held, k, act):
+    """``experts_grouped`` at the three expert cells' published widths,
+    int8, the stack of two periods read at an index: the chip's compiler
+    takes the kernel — rows gathered as 32-bit words, a resident float32
+    result, up to 100 MiB of fast memory (small4's width in two blocks) —
+    and the compiled program holds ONE kernel and no copy of a matrix."""
+    import functools
+    from llmapigateway_tpu.models import hybrid
+    from llmapigateway_tpu.ops import grouped_experts as ge
+    monkeypatch.setattr(hybrid, "grouped_experts", functools.partial(
+        ge.grouped_experts, interpret=False))
+    hybrid._grouped.clear_cache()
+    one = SingleDeviceSharding(chips[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def stack(din, dout):
+        return {"q": sds((2, held, din, dout), jnp.int8),
+                "s": sds((2, held, dout), jnp.float32)}
+    lp = {"wg": stack(D, F), "wu": stack(D, F), "wd": stack(F, D)}
+    compiled = jax.jit(
+        lambda x, idx, w, lp, period: hybrid.experts_grouped(
+            x, idx, w, lp, held, period=period, act=act)).lower(
+        sds((rows, D), jnp.bfloat16), sds((rows, k), jnp.int32),
+        sds((rows, k), jnp.float32), lp, sds((), jnp.int32)).compile()
+    hybrid._grouped.clear_cache()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    # Temporaries: the packed rows, the result, the layout — never a
+    # matrix of the stack (the smallest is 2 x held x D x F bytes).
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * held * D * F
