@@ -448,6 +448,12 @@ class InferenceEngine:
         # call's over its rows and positions. Monotone; worker thread.
         self._mla_decode_keys = 0
         self._mla_prefill_keys = 0
+        # The keys the decode programs' paged kernel calls attended
+        # (ISSUE 44), kept the same way: per layer of a K/V cache group,
+        # summed over steps and active slots — a global group's step sees
+        # the slot's whole context and itself, a windowed group's what of
+        # that lies inside the window. Monotone; worker thread.
+        self._attn_decode_keys = {"global": 0, "window": 0}
         # Device observability plane (ISSUE 8): per-kernel cost registry
         # (worker thread records, lock-guarded internally), the HBM
         # memory ledger, and the process-wide XLA compile monitor. The
@@ -573,10 +579,14 @@ class InferenceEngine:
 
     @property
     def allocator(self):
-        """The first cache group's allocator: the only one wherever a
-        feature needs a single page table (prefix cache, disaggregation,
-        speculation — each refused beside several groups)."""
-        return self.kv_groups.groups[0].allocator
+        """The whole-context cache group's allocator (looked up by its
+        window, not by its place: a family whose windowed layers come
+        first has the RING as group 0), else the only group's: the one
+        wherever a feature needs a single page table (prefix cache,
+        disaggregation, speculation — each refused beside several
+        groups), and whose free pages the flight records and ``stats()``
+        report."""
+        return self.kv_groups.whole_context.allocator
 
     @property
     def _swa_ring_pages(self) -> int:
@@ -3119,7 +3129,7 @@ class InferenceEngine:
                 self.lengths.copy(), self.last_token.copy())
             # Host length mirror advances at DISPATCH time — the burst-
             # capping logic in _step must see the device-true lengths.
-            self._count_mla_decode(n_steps)
+            self._count_decode_keys(n_steps)
             self.lengths[self.active] += n_steps
             if self.spec_k:
                 self._d_hist_fresh = False
@@ -3186,20 +3196,29 @@ class InferenceEngine:
                 for t in range(m):
                     self.hist[slot, L + 1 + t] = int(step_tokens[t][slot])
             self.last_token[slot] = int(step_tokens[-1][slot])
-        self._count_mla_decode(n_steps)
+        self._count_decode_keys(n_steps)
         self.lengths[self.active] += n_steps
         if self.spec_k:
             self._d_hist_fresh = False
         return pre + step_tokens
 
-    def _count_mla_decode(self, n_steps: int) -> None:
-        """Add a burst of ``n_steps`` to a latent layer's decode keys:
-        step ``i`` of an active slot at length ``n`` sees ``n + i + 1``."""
-        if self.model_cfg.is_mla:
-            live = self.lengths[self.active].astype(np.int64)
-            self._mla_decode_keys += int(
-                n_steps * live.sum()
-                + live.size * n_steps * (n_steps + 1) // 2)
+    def _count_decode_keys(self, n_steps: int) -> None:
+        """Add a burst of ``n_steps`` to the decode keys of ONE layer of
+        each cache group: step ``i`` of an active slot at length ``n``
+        sees ``n + i + 1`` keys in a latent or a global group, and what
+        of them lies inside the window in a windowed one."""
+        if not self.paged:
+            return
+        live = self.lengths[self.active].astype(np.int64)
+        seen = live[:, None] + np.arange(1, n_steps + 1)    # [slots, steps]
+        for g in self.kv_groups:
+            if g.kind == "latent":
+                self._mla_decode_keys += int(seen.sum())
+            elif g.window:
+                self._attn_decode_keys["window"] += int(
+                    np.minimum(seen, g.window).sum())
+            else:
+                self._attn_decode_keys["global"] += int(seen.sum())
 
     # -- emission / lifecycle (event-loop thread only) ------------------------
     def _emit_token(self, req: GenRequest) -> None:
@@ -3450,7 +3469,8 @@ class InferenceEngine:
                 kv_pools[name] = (g.layers * g.allocator.num_pages * page
                                   * token_bytes)
             kv_pool = sum(kv_pools.values())
-            page_bytes = self.kv_groups.groups[0].layers * page * token_bytes
+            page_bytes = (self.kv_groups.whole_context.layers * page
+                          * token_bytes)
         else:
             kv_pool = c.n_kv_layers * self.B * self.S * token_bytes
             page_bytes = 0
@@ -3551,6 +3571,12 @@ class InferenceEngine:
             # The paged prefill kernel's walk (_count_prefill_walk).
             out["prefill_kv_pages_walked_total"] = self._prefill_pages_walked
             out["prefill_kv_pages_table_total"] = self._prefill_pages_table
+            # One layer's keys of a global and of a windowed K/V group
+            # that the decode programs attended (_count_decode_keys).
+            out["attn_decode_keys_global_total"] = \
+                self._attn_decode_keys["global"]
+            out["attn_decode_keys_window_total"] = \
+                self._attn_decode_keys["window"]
             if self.kv_ppb > 1:
                 out["pages_per_block"] = self.kv_ppb
             if self._prefix_cache is not None:

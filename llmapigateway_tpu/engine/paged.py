@@ -376,9 +376,10 @@ class CacheGroups:
     allocator: a request is admitted into every group or into none, and
     leaves them all. Mistral is one windowed group, a model without a
     window one global group, a model that mixes both has one of each
-    (``ModelConfig.cache_groups``). ``shared_pages`` (a prefix-cache hit)
-    belong to the first group: the engine builds no prefix cache beside
-    several groups."""
+    (``ModelConfig.cache_groups``, in the order of a period: the ring
+    comes first where the windowed layers do). ``shared_pages`` (a
+    prefix-cache hit) belong to the first group: the engine builds no
+    prefix cache beside several groups."""
 
     def __init__(self, groups: list[CacheGroup]):
         self.groups = list(groups)
@@ -388,6 +389,15 @@ class CacheGroups:
 
     def __len__(self) -> int:
         return len(self.groups)
+
+    @property
+    def whole_context(self) -> CacheGroup:
+        """The group that keeps the whole context (window 0; a latent
+        group is one), else — a model of windowed layers only — the first.
+        Found by its window, never by its place: a model whose windowed
+        layers come first in a period has the ring as group 0."""
+        return next((g for g in self.groups if not g.window),
+                    self.groups[0])
 
     def can_admit(self, total_tokens: int, shared_pages: int = 0) -> bool:
         return all(g.allocator.can_admit(total_tokens, g.ring_pages,

@@ -67,7 +67,18 @@ class RopeScaling:
 @dataclass(frozen=True)
 class ModelConfig:
     # "llama" | "qwen2" | "gemma" | "mixtral" | "hybrid" | "smallthinker"
-    # | "mistral4"
+    # | "mistral4" | "cohere2_moe". The last four are the PERIOD families
+    # (``layer_period`` > 0, models/hybrid.py); "cohere2_moe" (Command A+)
+    # is the one whose block is parallel: one LayerNorm feeds attention,
+    # routed and shared experts side by side, one residual add a layer;
+    # three windowed rotary layers then a global NoPE layer a period (its
+    # first cache group is the RING), four shared experts averaged, the
+    # head tied to the embedding. Like the other period families it is
+    # served from page pools on one device and refuses, at engine build
+    # and with the reason, a contiguous cache, any mesh axis, the prefix
+    # cache, speculation, disaggregation and ``model_path``. Presets:
+    # "command-a-plus" (published), "command-a-plus-218b-ep8" (one chip of
+    # eight that share a layer), "tiny-cohere2-test".
     family: str = "llama"
     vocab_size: int = 32000
     d_model: int = 2048
@@ -108,7 +119,10 @@ class ModelConfig:
     n_experts_held: int = 0
     first_expert_held: int = 0
     d_ff_expert: int = 0           # routed (and shared) expert width
-    n_shared_experts: int = 0      # always-on experts of width d_ff_expert
+    # Always-on experts of width d_ff_expert; several join the routed sum
+    # as the MEAN of their outputs (Command A+'s "average", the one preset
+    # with more than one; its ``logit_scale`` is 1 and has no field).
+    n_shared_experts: int = 0
     layer_period: int = 0          # layers per period; position 0 is softmax
     lin_heads: int = 0             # linear-attention heads ...
     lin_head_dim: int = 0          # ... their key/value size ...
@@ -141,9 +155,19 @@ class ModelConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
-    rope_interleave: bool = False   # rotary pairs (2i, 2i+1), not (i, i+half)
+    # Rotary pairs (2i, 2i+1), not (i, i+half): the latent layers' rotary
+    # part and, in the period families, the softmax layers' whole head.
+    rope_interleave: bool = False
     # Queries scaled by 1 + beta ln(1 + floor(pos / original_max_seq)).
     query_scale_beta: float = 0.0
+    # The parallel block of the period families (models/hybrid.py): ONE
+    # norm a layer whose result feeds attention, the routed and the shared
+    # experts side by side, and ONE residual add, x + A + R + S.
+    parallel_block: bool = False
+    # The period families' norm: "rms" (``rms_eps``) or "layernorm" — mean
+    # removed, no bias, ``layer_norm_eps``.
+    norm_kind: str = "rms"
+    layer_norm_eps: float = 1e-5
 
     def __post_init__(self):
         for name in ("window_layout", "rope_layout"):
@@ -156,6 +180,8 @@ class ModelConfig:
             raise ValueError(f"unknown moe_router {self.moe_router!r}")
         if self.moe_act not in ("silu", "relu"):
             raise ValueError(f"unknown moe_act {self.moe_act!r}")
+        if self.norm_kind not in ("rms", "layernorm"):
+            raise ValueError(f"unknown norm_kind {self.norm_kind!r}")
 
     @property
     def head_dim(self) -> int:
@@ -399,6 +425,38 @@ PRESETS["tiny-mistral4-test"] = replace(
 PRESETS["mistral-small4-119b-ep4"] = replace(
     PRESETS["mistral-small4-119b"], n_layers=12, vocab_size=32768,
     n_experts_held=32)
+
+# Command A+ (HF: CohereLabs/command-a-plus-05-2026, ``cohere2_moe``) at its
+# PUBLISHED sizes: 32 layers in periods of 4 — three layers that rotate q and
+# k (interleaved pairs, theta 50000) and attend inside a 4096 window, then a
+# global layer without rotary embedding — 128 query heads over 8 KV heads,
+# each layer a PARALLEL block: one LayerNorm (no bias, eps 1e-5; the config's
+# rms_norm_eps is null, so ``rms_eps`` is 0 and unused) feeds attention, 128
+# sigmoid-routed experts of width 4096 (top-8, normalised) and four shared
+# experts whose outputs are averaged; one residual add; a tied head. Two
+# cache groups, the RING first (ModelConfig.cache_groups).
+PRESETS["command-a-plus"] = ModelConfig(
+    family="cohere2_moe", vocab_size=262144, d_model=4096, n_layers=32,
+    n_heads=128, n_kv_heads=8, head_dim_override=128, d_ff=4096,
+    rope_theta=50000.0, rms_eps=0.0, max_seq_len=200000, sliding_window=4096,
+    tie_embeddings=True, n_experts=128, experts_per_token=8, d_ff_expert=4096,
+    n_shared_experts=4, layer_period=4, window_layout=(1, 1, 1, 0),
+    rope_layout=(1, 1, 1, 0), moe_router="sigmoid", rope_interleave=True,
+    parallel_block=True, norm_kind="layernorm", layer_norm_eps=1e-5)
+# The same block at CPU-test size: two periods, 16 experts top-4, two shared.
+PRESETS["tiny-cohere2-test"] = replace(
+    PRESETS["command-a-plus"], vocab_size=512, d_model=64, n_layers=8,
+    n_heads=8, n_kv_heads=2, head_dim_override=16, d_ff=32,
+    rope_theta=10000.0, max_seq_len=256, sliding_window=16, n_experts=16,
+    experts_per_token=4, d_ff_expert=32, n_shared_experts=2)
+# What ONE v5e chip holds of it as one of 8 that share each layer of a
+# 4-stage pipeline (benchmark/configs/command-a-plus-218b-ep8.json): two
+# whole periods, 16 of the 128 experts, an eighth of the vocabulary rows.
+# Every width, the router's 128 outputs, its 8 experts per token and the
+# four shared experts stay.
+PRESETS["command-a-plus-218b-ep8"] = replace(
+    PRESETS["command-a-plus"], n_layers=8, vocab_size=32768,
+    n_experts_held=16)
 
 
 def get_preset(name: str) -> ModelConfig:

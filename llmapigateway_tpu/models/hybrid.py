@@ -33,6 +33,25 @@ group a latent pool (``HybridCache.k`` holds it, ``v`` is empty) that BOTH
 step programs carry through the scan and write in place, and the expert
 layer is the one above with a softmax router and a shared expert.
 
+Those blocks are SEQUENTIAL: a norm, the attention (or linear) sub-block,
+an add; another norm, the expert layer, another add. A period of softmax
+layers may instead be PARALLEL blocks (``ModelConfig.parallel_block``;
+Command A+, family "cohere2_moe")::
+
+    h  = norm(x)                 ONE norm a layer (a LayerNorm without bias
+                                 where ``norm_kind`` says so), scope block.norm
+    x' = x + A(h) + R(h) + S(h)  ONE add: attention, the routed experts held
+                                 here, the shared experts
+
+``A`` is the softmax sub-block without its norm (rotary on interleaved pairs
+under ``rope_interleave``), ``R`` and ``S`` are ``moe_block`` handed the
+normed input (``normed``), ``S`` the MEAN of the shared experts' outputs.
+Either way a sub-block returns its BRANCH and ``period_step`` adds it to
+the stream. That family's windowed layers come FIRST in a period, so its
+first cache group is the ring and the global group the second; its head is
+the embedding (``tie_embeddings``: no ``lm_head``; under quant an int8 copy
+of the embedding's own rows, ``lm_head_q8``, as the llama family keeps it).
+
 TPU-first decisions:
 
 * ``lax.scan`` over PERIODS, one compiled body whatever the depth. Every
@@ -70,8 +89,8 @@ import jax.numpy as jnp
 
 from . import mla
 from .config import ModelConfig
-from .llama import (_GATE_ACTS, apply_rope, rms_norm, rope_tables,
-                    swiglu_mlp)
+from .llama import (_GATE_ACTS, _select_head, apply_rope, rms_norm,
+                    rope_tables, swiglu_mlp)
 from ..ops.grouped_experts import grouped_experts, rows_that_fit
 from .quant import (_dynamic_int8, head_matmul, is_quantized, mm,
                     moe_mm_batched, quantize_array, weight_bits)
@@ -164,7 +183,9 @@ def init_params(config: ModelConfig, key: jax.Array,
     Layout (P periods of ``per`` layers; D model, H heads of Dh, KV heads;
     Hl linear heads of dk; r gate rank; E experts of which ``held`` live
     here, width F; Fs shared width):
-      embed [V, D]; final_norm [D]; lm_head [V, D]
+      embed [V, D]; final_norm [D]; lm_head [V, D] (a tied head: none,
+                   and under quant ``lm_head_q8``, the embedding's own
+                   rows in int8)
       layers/attn/{norm [P,D], wq [P,D,H*Dh], wk, wv [P,D,KV*Dh],
                    wgate [P,D,H*Dh], wo [P,H*Dh,D], mlp/...}
                    (``wgate`` with ``attn_gate`` only; a period of several
@@ -178,7 +199,9 @@ def init_params(config: ModelConfig, key: jax.Array,
                    stacked [P, ...], with its mlp/...
       .../mlp/{norm [P,D], router [P,D,E], wg, wu [P,held,D,F],
                wd [P,held,F,D], sg, su [P,D,Fs], sd [P,Fs,D]}
-               (``sg``, ``su``, ``sd`` with shared experts only)
+               (``sg``, ``su``, ``sd`` with shared experts only: the shared
+               experts side by side, Fs = their number x F; no ``norm``
+               in a parallel block, whose one norm is the layer's)
     The residual stream is drawn at unit scale and every projection back
     into it (``wo``, ``wd``, ``sd``) at ``(2 n_layers)^-1/2`` of the usual
     (the GPT-2 convention): a delta rule with b up to 2 and slow decays
@@ -189,6 +212,16 @@ def init_params(config: ModelConfig, key: jax.Array,
     lies in 0.9-0.999 (``a_log = log U(1,8)``, ``softplus(f_bias)`` log-
     uniform in 0.001-0.05, a small ``wf_up``): with unit-scale gates the
     layer would forget in a token and no comparison could see its state.
+    Under a TIED head the final norm's gain is drawn as random SIGNS at
+    ``D^-1/2``. The magnitude brings the unit-variance embedding's logits
+    to the unit variance an untied head's rows are drawn for. The signs
+    are there because the one matrix is read at both ends: the stream
+    carries the input token's own row to the head, and under a gain of one
+    sign that token's logit (``|row|^2 D^-1/2`` = 64 at D = 4096, the
+    others of unit variance) would be the maximum at every position
+    whatever the layers computed — a comparison of served tokens would
+    compare nothing. Under random signs it is one more unit-variance
+    number (a trained model's learned gain and moved stream do the same).
     """
     c = config
     if not c.layer_period or c.n_layers % c.layer_period:
@@ -198,6 +231,9 @@ def init_params(config: ModelConfig, key: jax.Array,
         raise ValueError("the hybrid family's softmax layers carry no "
                          "rotary embedding and gate their output: use_rope "
                          "must be False, attn_gate True")
+    if c.parallel_block and (c.lin_heads or c.is_mla):
+        raise ValueError("a parallel block is a period of softmax layers: "
+                         "no linear-attention and no latent layer has one")
     per, P = c.layer_period, c.n_layers // c.layer_period
     n_soft = len(c.softmax_positions)
     D, dh, dk, Hl, r = (c.d_model, c.head_dim, c.lin_head_dim, c.lin_heads,
@@ -223,8 +259,9 @@ def init_params(config: ModelConfig, key: jax.Array,
                     "wu": dense(ku, D, F, name="wu"),
                     "wd": dense(kd, F, D, scale=back, name="wd")}
         out = jax.lax.map(expert, jax.random.split(ks[0], held))
-        out.update(
-            norm=jnp.ones((D,), dtype), router=dense(ks[1], D, c.n_experts))
+        if not c.parallel_block:        # its one norm is the layer's
+            out.update(norm=jnp.ones((D,), dtype))
+        out.update(router=dense(ks[1], D, c.n_experts))
         if Fs:
             out.update(sg=dense(ks[2], D, Fs, name="sg"),
                        su=dense(ks[3], D, Fs, name="su"),
@@ -276,16 +313,24 @@ def init_params(config: ModelConfig, key: jax.Array,
                 "lin": tuple(map(lin_layer, ks[n_soft:]))}
 
     k_embed, k_head, k_layers = jax.random.split(key, 3)
-    head = (jax.random.normal(k_head, (c.vocab_size, D), jnp.float32)
-            / math.sqrt(D)).astype(dtype)
-    return {
-        "embed": jax.random.normal(k_embed, (c.vocab_size, D),
-                                   jnp.float32).astype(dtype),
-        "final_norm": jnp.ones((D,), dtype),
-        "lm_head": (quantize_array(head, 1, bits=weight_bits(quant, "lm_head"))
-                    if quant else head),
-        "layers": jax.lax.map(period, jax.random.split(k_layers, P)),
-    }
+    tied = c.tie_embeddings
+    head = None if tied else (
+        jax.random.normal(k_head, (c.vocab_size, D), jnp.float32)
+        / math.sqrt(D)).astype(dtype)
+    embed = jax.random.normal(k_embed, (c.vocab_size, D),
+                              jnp.float32).astype(dtype)
+    if tied:
+        # ONE matrix: the head is the embedding; under quant the head's
+        # product reads an int8 copy of the embedding's own rows.
+        signs = jnp.where(jax.random.bernoulli(k_head, 0.5, (D,)), 1.0, -1.0)
+        final_norm = (signs * D ** -0.5).astype(dtype)
+        top = {"lm_head_q8": quantize_array(embed, 1)} if quant else {}
+    else:
+        final_norm = jnp.ones((D,), dtype)
+        top = {"lm_head": (quantize_array(
+            head, 1, bits=weight_bits(quant, "lm_head")) if quant else head)}
+    return {"embed": embed, "final_norm": final_norm, **top,
+            "layers": jax.lax.map(period, jax.random.split(k_layers, P))}
 
 
 # ---------------------------------------------------------------------------
@@ -481,6 +526,28 @@ def linear_block(h, lp, c: ModelConfig, s0, tail, n_valid, keep):
 
 
 # ---------------------------------------------------------------------------
+# The block's norm
+# ---------------------------------------------------------------------------
+
+def layer_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
+    """LayerNorm without bias, float32 inside: ``w (x - mean) / sqrt(var +
+    eps)`` over the last axis, in ``x``'s dtype."""
+    xf = x.astype(jnp.float32)
+    xc = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    normed = xc * jax.lax.rsqrt(jnp.mean(xc * xc, axis=-1, keepdims=True)
+                                + eps)
+    return (normed * weight.astype(jnp.float32)).astype(x.dtype)
+
+
+def block_norm(x: jax.Array, weight: jax.Array, c: ModelConfig) -> jax.Array:
+    """The norm of ``ModelConfig.norm_kind`` on a block's (or the head's)
+    input."""
+    if c.norm_kind == "layernorm":
+        return layer_norm(x, weight, c.layer_norm_eps)
+    return rms_norm(x, weight, c.rms_eps)
+
+
+# ---------------------------------------------------------------------------
 # The expert layer
 # ---------------------------------------------------------------------------
 
@@ -665,19 +732,25 @@ def _grouped(x, idx, w, stack, period, *, held, tile, act):
 def moe_block(x: jax.Array, lp: Params, c: ModelConfig,
               count: jax.Array | None = None,
               period: jax.Array | None = None,
-              route_on: jax.Array | None = None
+              route_on: jax.Array | None = None,
+              normed: jax.Array | None = None
               ) -> tuple[jax.Array, jax.Array]:
-    """x [B,T,D] (the residual stream) -> (x + MLP(norm(x)), int32
-    [``N_COUNTERS``]: of rows where ``count`` [B] is True the routed
-    assignments, all and those landing on a held expert, and the held
+    """x [B,T,D] (the residual stream) -> (the BRANCH ``R + S`` of
+    ``norm(x)`` — the routed experts held here and the shared ones, several
+    of those as the MEAN of their outputs; the caller adds it to the
+    stream —, int32 [``N_COUNTERS``]: of rows where ``count`` [B] is True
+    the routed assignments, all and those landing on a held expert, the held
     experts with at least one of them (zeros without ``count``); then the
     tiles the grouped product ran and the rows they held (zeros from the
     dense form)). ``period``: the routed experts' matrices
     (``EXPERT_KEYS``) are stacked over periods and this is the index to
     read. ``route_on`` [B,T,D]: what the router reads instead of the
-    MLP's normalised input (the block's input, before attention)."""
+    MLP's normalised input (the block's input, before attention).
+    ``normed`` [B,T,D] float32: a PARALLEL block's one normed input, given
+    — the layer norms nothing of its own (``lp`` has no ``norm``)."""
     B, T, D = x.shape
-    hf = rms_norm(x.astype(jnp.float32), lp["norm"], c.rms_eps)
+    hf = (block_norm(x.astype(jnp.float32), lp["norm"], c)
+          if normed is None else normed)
     h = hf.astype(x.dtype)
     with jax.named_scope("moe.experts"):
         seen = hf if route_on is None else route_on.astype(jnp.float32)
@@ -694,7 +767,12 @@ def moe_block(x: jax.Array, lp: Params, c: ModelConfig,
         y = y.reshape(B, T, D).astype(x.dtype)
     with jax.named_scope("moe.shared"):
         if c.n_shared_experts:
-            y = y + swiglu_mlp(h, lp["sg"], lp["su"], lp["sd"])
+            shared = swiglu_mlp(h, lp["sg"], lp["su"], lp["sd"])
+            if c.n_shared_experts > 1:
+                # ``sd`` contracts the shared experts side by side: their
+                # SUM. A power of two when they are four: exact.
+                shared = shared * (1.0 / c.n_shared_experts)
+            y = y + shared
     routed = jnp.zeros((3,), jnp.int32)
     if count is not None:
         on = jnp.repeat(count, T)
@@ -703,7 +781,7 @@ def moe_block(x: jax.Array, lp: Params, c: ModelConfig,
             jnp.sum(on, dtype=jnp.int32) * c.experts_per_token,
             jnp.sum(landed, dtype=jnp.int32),
             jnp.sum(jnp.any(landed, axis=0), dtype=jnp.int32)])
-    return x + y, jnp.concatenate([routed, tiled])
+    return y, jnp.concatenate([routed, tiled])
 
 
 # ---------------------------------------------------------------------------
@@ -786,10 +864,19 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
         cos, sin = rope_tables(lengths[:, None] + jnp.arange(T)[None, :],
                                dh, c.rope_theta, c.rope_scaling)
 
-    def softmax_layer(x, pool, lp, at, position):
-        """``at``: the layer's index in its group's pool, or its (K, V)
-        slice of that pool; ``pool``: the group's stacked pool (under
-        ``prefill_at`` the carried one)."""
+    def rotary(a):
+        if c.rope_interleave:   # pairs (2i, 2i+1); q and k leave alike
+            return mla.rotate(a.astype(jnp.float32), cos, sin,
+                              True).astype(a.dtype)
+        return apply_rope(a, cos, sin)
+
+    def softmax_layer(x, pool, lp, at, position, normed=None):
+        """-> (the attention BRANCH, which the caller adds to the stream,
+        the pool, the layer's new K/V). ``at``: the layer's index in its
+        group's pool, or its (K, V) slice of that pool; ``pool``: the
+        group's stacked pool (under ``prefill_at`` the carried one).
+        ``normed``: a parallel block's normed input, in place of the
+        sub-block's own norm."""
         fn = fns[place[position][0]]
         if c.is_mla:
             with jax.named_scope(f"{scope}.attention"), \
@@ -798,12 +885,12 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
                                        active), None)
         kind = "attn.window" if c.window_at(position) else "attn.global"
         with jax.named_scope(f"{scope}.attention"), jax.named_scope(kind):
-            h = rms_norm(x, lp["norm"], c.rms_eps)
+            h = block_norm(x, lp["norm"], c) if normed is None else normed
             q = mm(h, lp["wq"]).reshape(B, T, c.n_heads, dh)
             k = mm(h, lp["wk"]).reshape(B, T, c.n_kv_heads, dh)
             v = mm(h, lp["wv"]).reshape(B, T, c.n_kv_heads, dh)
             if c.rope_at(position):
-                q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+                q, k = rotary(q), rotary(k)
             ys = (k, v)
             if by_prefill_at:
                 attn, pool_k, pool_v = fn.prefill_at(q, k, v, *pool, at,
@@ -819,7 +906,7 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
             if c.attn_gate:
                 gate = jax.nn.sigmoid(mm(h, lp["wgate"]).astype(jnp.float32))
                 attn = (attn.astype(jnp.float32) * gate).astype(x.dtype)
-            return x + mm(attn, lp["wo"]), pool, ys
+            return mm(attn, lp["wo"]), pool, ys
 
     # The routed experts' matrices stay OUT of the scanned slices: the
     # expert layer reads them from the whole stack at the period's index
@@ -831,10 +918,11 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
     rest = [{**lp, "mlp": {k: v for k, v in lp["mlp"].items()
                            if k not in EXPERT_KEYS}} for lp in every]
 
-    def mlp(x, lp, i, period, x_in):
+    def mlp(x, lp, i, period, x_in, normed=None):
         with jax.named_scope(f"{scope}.mlp"):
             return moe_block(x, {**lp, **held[i]}, c, count, period,
-                             x_in if c.router_reads_block_input else None)
+                             x_in if c.router_reads_block_input else None,
+                             normed)
 
     # A (K, V) pair a group; a latent group's ONE pool.
     pools = tuple(cache.k) if c.is_mla else tuple(zip(cache.k, cache.v))
@@ -859,21 +947,33 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
                 at = sides[g] if n == 1 else jax.tree.map(
                     lambda a: a[j], sides[g])
             x_in = x
-            x, pool, new[g][j] = softmax_layer(x, pool, lps[i], at, i)
+            if c.parallel_block:
+                # ONE norm feeds both branches, ONE add joins them.
+                with jax.named_scope("block.norm"):
+                    hf = block_norm(x.astype(jnp.float32), lps[i]["norm"], c)
+                attn, pool, new[g][j] = softmax_layer(
+                    x, pool, lps[i], at, i, hf.astype(x.dtype))
+                branch, more = mlp(x, lps[i]["mlp"], i, period, x_in, hf)
+                x = x + attn + branch
+            else:
+                attn, pool, new[g][j] = softmax_layer(x, pool, lps[i], at, i)
+                x = x + attn
+                branch, more = mlp(x, lps[i]["mlp"], i, period, x_in)
+                x = x + branch
             if by_prefill_at:
                 carried = (*carried[:g], pool, *carried[g + 1:])
-            x, more = mlp(x, lps[i]["mlp"], i, period, x_in)
             counted = counted + more
         states, tails = [], []
         for i, (lp, s, tail) in enumerate(zip(lps[n_soft:], s0, tail0),
                                           n_soft):
             x_in = x
             with jax.named_scope(f"{scope}.kda"):
-                h = rms_norm(x, lp["norm"], c.rms_eps)
+                h = block_norm(x, lp["norm"], c)
                 out, s, tail = linear_block(h, lp, c, s, tail, n_valid,
                                             keep)
                 x = x + out
-            x, more = mlp(x, lp["mlp"], i, period, x_in)
+            branch, more = mlp(x, lp["mlp"], i, period, x_in)
+            x = x + branch
             counted = counted + more
             states.append(s)
             tails.append(tail)
@@ -913,8 +1013,8 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
 
     if last_only:
         x = jnp.take_along_axis(x, (n_valid - 1)[:, None, None], axis=1)
-    x = rms_norm(x, params["final_norm"], c.rms_eps)
-    logits = head_matmul(x, params["lm_head"])
+    x = block_norm(x, params["final_norm"], c)
+    logits = head_matmul(x, _select_head(params, c))
     if last_only:
         logits = jnp.broadcast_to(logits, (B, T, logits.shape[-1]))
     k, v = (tuple(new_pools), ()) if c.is_mla else (

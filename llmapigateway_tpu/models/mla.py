@@ -158,8 +158,9 @@ def expanded_attention(q_nope: jax.Array, q_rope: jax.Array,
 def mla_block(x: jax.Array, lp: Params, c: ModelConfig, pool: jax.Array,
               layer: jax.Array, fn: Any, lengths: jax.Array,
               active: jax.Array | None) -> tuple[jax.Array, jax.Array]:
-    """x [B, T, D] -> (x + MLA(rmsnorm(x)), the pool with the call's rows
-    written into layer ``layer``). ``fn``: the group's
+    """x [B, T, D] -> (the branch MLA(rmsnorm(x)), which the caller adds
+    to the stream, the pool with the call's rows written into layer
+    ``layer``). ``fn``: the group's
     ``ops.latent_attention.LatentAttention``. Insert, then attend: the
     call's own keys are read back as the bytes that were written. A row
     that is not ``active`` writes to the trash page and attends from
@@ -181,4 +182,4 @@ def mla_block(x: jax.Array, lp: Params, c: ModelConfig, pool: jax.Array,
     else:
         out = expanded_attention(q_nope, q_rope, fn.gather(pool, layer),
                                  lp["wkvb"], start, c).astype(x.dtype)
-    return x + mm(out, lp["wo"]), pool
+    return mm(out, lp["wo"]), pool

@@ -199,11 +199,40 @@ def test_tp4_attention_compiles_on_the_engine_mesh(chips, quant, kind):
 SERVED = {
     "mistral-7b": (32, 169, 8, 32, 8, 32, 4096),
     "solar-open2-ep8": (2, 1025, 8, 64, 32, 32, 0),
+    # PR 44, command-a-plus-218b-ep8: 128 query heads over 8 KV heads (16 a
+    # group, a 16,384-wide query row), 16 slots of 68 pages. Its two cache
+    # groups: six windowed layers on rings of 21 pages, two global layers
+    # on the whole context.
+    "command-a-plus-ring": (6, 16 * 21 + 1, 8, 128, 16, 68, 4096),
+    "command-a-plus-global": (2, 16 * 68 + 1, 8, 128, 16, 68, 0),
 }
 
 
 # Rows of a prefill call (``prefill_batch``) at those geometries.
-PREFILL_ROWS = {"mistral-7b": 1, "solar-open2-ep8": 4}
+PREFILL_ROWS = {"mistral-7b": 1, "solar-open2-ep8": 4,
+                "command-a-plus-ring": 2, "command-a-plus-global": 2}
+
+
+def test_the_block_shapes_at_sixteen_heads_a_group_and_at_the_older_cells():
+    """Pure shape arithmetic (no chip, no compile): at 16 query heads a KV
+    head a 512-token chunk runs 32 positions a row-block and one KV head a
+    program (512 rows), where Mistral (4 a group) gets 128 positions and
+    two heads and SmallThinker (7 a group, 4 KV heads) 64 and two — the
+    shapes PR 37 gave them, which PR 44 left alone; a decode block holds
+    all 8 KV heads of a page at either grouping."""
+    def at(G, KV):
+        return pa.prefill_block_shape(T, G, KV, PAGE, DH, 2, 1, True, 1)
+    assert at(16, 8) == (32, 1)
+    assert at(4, 8) == (128, 2)
+    assert at(7, 4) == (64, 2)
+    assert pa._decode_heads_per_block(8, PAGE, DH, 1, True, 1) == 8
+    assert pa._prefill_vmem_bytes(32, 1, T, 16, PAGE, DH, 2, 1, True, 1) \
+        <= pa._PREFILL_VMEM_BYTES
+    # The walk that shape costs: four times Mistral's row-blocks a chunk.
+    starts = [8192]
+    walked = {bt: pa.prefill_pages_walked(starts, T, bt, PAGE, 4096, 68)[0]
+              for bt in (32, 128)}
+    assert walked[32] == 16 * 17 and walked[128] == 4 * 17
 
 
 def _stacked(chips, geometry):

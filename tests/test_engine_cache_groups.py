@@ -476,3 +476,186 @@ def test_what_the_latent_family_cannot_be_served_with_is_refused_at_build(
                                          f"support {says}"):
         InferenceEngine(LocalEngineConfig(**{**LATENT, **change}),
                         devices=devices or [jax.devices("cpu")[0]])
+
+
+# -- the RING as group 0 (ISSUE 44) --------------------------------------------
+# The Command A+ family: three windowed layers THEN a global one a period, so
+# the first cache group is the ring and the whole-context group the second.
+# Whatever wants "the whole-context group" finds it by its window.
+
+from benchmark.reference import command_a_plus as cref  # noqa: E402
+
+from test_model_cohere2 import TINY as COHERE  # noqa: E402
+from test_model_cohere2 import file_of as cohere_file  # noqa: E402
+
+RING_FIRST = {**BASE, "preset": "tiny-cohere2-test"}
+
+
+@pytest.fixture(scope="module")
+def ring_first(stop_engine):
+    eng = InferenceEngine(LocalEngineConfig(**RING_FIRST),
+                          devices=[jax.devices("cpu")[0]])
+    yield eng
+    stop_engine(eng)
+
+
+def ring_then_global(global_pages=2 * WHOLE + 1, ring_pages=2 * RING + 1):
+    return CacheGroups([
+        CacheGroup(6, 16, RING, PageAllocator(ring_pages, 8, 2, 128)),
+        CacheGroup(2, 0, 0, PageAllocator(global_pages, 8, 2, 128))])
+
+
+def test_the_whole_context_group_is_found_by_its_window_not_its_place():
+    ring, glob = ring_then_global().groups
+    assert ring_then_global().whole_context.window == 0
+    assert two_groups().whole_context is not two_groups().groups[1]
+    assert two_groups().whole_context.window == 0
+    # A model of windowed layers alone: the only group there is.
+    only = CacheGroups([ring])
+    assert only.whole_context is ring
+    latent = CacheGroups([CacheGroup(
+        4, 0, 0, PageAllocator(5, 8, 1, 32), kind="latent", token_bytes=80)])
+    assert latent.whole_context.kind == "latent"
+
+
+def test_admission_takes_both_or_neither_with_the_ring_first():
+    groups = ring_then_global(global_pages=WHOLE + 5)
+    ring, glob = (g.allocator for g in groups)
+    assert groups.allocate(0, 128)
+    assert (ring.free_pages, glob.free_pages) == (RING, 4)
+    for g in groups:
+        g.dirty = False
+    # The ring (group 0) has room for a second slot, the global group has
+    # not: nothing is taken from either.
+    assert ring.can_admit(128, RING) and not glob.can_admit(128)
+    assert not groups.can_admit(128) and not groups.allocate(1, 128)
+    assert (ring.free_pages, glob.free_pages) == (RING, 4)
+    assert not ring.table[1].any() and not any(g.dirty for g in groups)
+    assert groups.fresh_shortfall(128) == WHOLE - 4
+    # Rotation turns group 0 and leaves the global table alone.
+    whole = glob.table.copy()
+    groups.rotate(0, last_pos=71, floor_pos=40)
+    assert groups.groups[0].recycled == 2 and groups.groups[0].dirty
+    assert not groups.groups[1].dirty and (glob.table == whole).all()
+    groups.release(0)
+    groups.check_invariants()
+
+
+def test_the_engine_builds_the_ring_first_and_names_the_pools_by_window(
+        ring_first):
+    eng, st = ring_first, ring_first.stats()
+    token = 2 * 2 * 16 * 4                      # K+V, 2 heads of 16, f32
+    assert st["kv_groups"] == [
+        {"kind": "kv", "layers": 6, "window": 16, "token_bytes": token,
+         "pages": 3 * RING, "pages_free": 3 * RING, "pages_per_slot": RING},
+        {"kind": "kv", "layers": 2, "window": 0, "token_bytes": token,
+         "pages": 3 * WHOLE, "pages_free": 3 * WHOLE,
+         "pages_per_slot": WHOLE}]
+    assert st["hbm_kv_pools_bytes"] == {
+        "window16": 6 * (3 * RING + 1) * 8 * token,
+        "global": 2 * (3 * WHOLE + 1) * 8 * token}
+    assert st["hbm_kv_pool_bytes"] == sum(st["hbm_kv_pools_bytes"].values())
+    assert eng.cache.k[0].shape[:2] == (6, 3 * RING + 1)
+    assert eng.cache.k[1].shape[:2] == (2, 3 * WHOLE + 1)
+    # The single-table view is the whole-context group's, wherever it lies.
+    assert eng.allocator is eng.kv_groups.groups[1].allocator
+    assert (st["free_pages"], st["total_pages"]) == (3 * WHOLE, 3 * WHOLE)
+    assert eng.ledger.page_bytes == 2 * 8 * token
+    assert eng._swa_ring_pages == RING
+    assert st["moe_experts_held"] == 16
+    assert (st["attn_decode_keys_global_total"],
+            st["attn_decode_keys_window_total"]) == (0, 0)
+    # The tied head: one matrix in the tree.
+    assert "lm_head" not in eng.params and "lm_head_q8" not in eng.params
+    # Both counters stand in every paged engine's stats, at zero where the
+    # model has no group of the kind.
+    assert "attn_decode_keys_window_total" in _mk_engine().stats()
+
+
+def _cohere_gap(eng, req: GenRequest) -> float:
+    seq = np.asarray(list(req.prompt_ids) + req.generated[:-1], np.int32)
+    rows = cref.logits(eng.params, cref.sizes(COHERE, cohere_file(COHERE)),
+                       seq, last=len(req.generated))
+    return max(float(row.max() - row[t])
+               for row, t in zip(rows, req.generated))
+
+
+async def test_ring_first_contexts_past_the_ring_are_served_as_the_reference(
+        ring_first):
+    """Three requests at once: 100 and 70 tokens of prompt pass the ring's
+    56 tokens in prefill, a third passes it while decoding; every served
+    token stands at the reference's maximum, the rings turned, the global
+    group kept every page, every slot left both groups — and the decode
+    keys of both kinds are counted, monotone, a windowed layer's never
+    past its window."""
+    engine = ring_first
+    at_start = engine.stats()
+    reqs = await asyncio.gather(
+        generate(engine, prompt(100, 1), 12),
+        generate(engine, prompt(70, 2), 20),
+        generate(engine, prompt(50, 3), 30))
+    for req in reqs:
+        assert len(req.generated) == req.max_tokens
+        assert await asyncio.to_thread(_cohere_gap, engine, req) <= GAP_TOL
+    st = engine.stats()
+    assert st["kv_ring_recycled_total"] \
+        - at_start["kv_ring_recycled_total"] >= 7 + 5 + 3
+    assert [g["pages_free"] for g in st["kv_groups"]] == [3 * RING, 3 * WHOLE]
+    engine.kv_groups.check_invariants()
+    # A request of n prompt tokens and m answers decodes m - 1 steps (the
+    # first token is the prefill's), step i at n + i + 1 keys; bursts may
+    # run a few steps past a request's end, so these are lower bounds.
+    glob = st["attn_decode_keys_global_total"] \
+        - at_start["attn_decode_keys_global_total"]
+    win = st["attn_decode_keys_window_total"] \
+        - at_start["attn_decode_keys_window_total"]
+    least = sum(n + i + 1 for n, m in ((100, 12), (70, 20), (50, 30))
+                for i in range(m - 1))
+    steps = (12 - 1) + (20 - 1) + (30 - 1)
+    assert glob >= least and win >= 16 * steps
+    assert win * (least // steps) <= 16 * glob      # window 16 a step
+    assert st["moe_assignments_total"] % (4 * 8) == 0   # top-4 x 8 layers
+    again = engine.stats()
+    assert again["attn_decode_keys_global_total"] >= \
+        st["attn_decode_keys_global_total"]
+
+
+def test_the_cohere_references_own_check_counts_the_decode_keys(ring_first):
+    """``served_past_window`` (benchmark/reference/command_a_plus.py) as the
+    harness calls it in set-up: 80 tokens ((7 + 3) pages of 8 in chunks of
+    16) and 8 decode steps; the two counters give exactly the keys of its
+    steps, the window's in the ring group."""
+    case = cref.served_past_window(ring_first, cohere_file(COHERE))
+    assert case["ok"] and case["tokens"] == 80 and case["positions"] == 9
+    assert case["ring_pages_recycled"] >= 3 and case["max_abs_err"] <= GAP_TOL
+    assert case["decode_keys"] == {"global": sum(range(81, 89)),
+                                   "window": 8 * 16}
+    assert not ring_first.active.any() and not ring_first.lengths.any()
+    assert all(g["pages_free"] == g["pages"]
+               for g in ring_first.stats()["kv_groups"])
+
+
+def test_the_parallel_block_rides_the_in_place_path_under_its_scopes(
+        stop_engine):
+    eng = InferenceEngine(
+        LocalEngineConfig(**{**RING_FIRST, "attention": "pallas",
+                             "max_batch_size": 2}),
+        devices=[jax.devices("cpu")[0]])
+    try:
+        assert eng.stats()["kv_pool_in_place"] is True
+        state, key = eng._state_avals()
+        import jax.numpy as jnp
+
+        def row(dtype, *shape):
+            return jax.ShapeDtypeStruct((1, *shape), dtype)
+        lowered = eng._prefill_fn.lower(
+            *state, row(jnp.int32, 16), row(jnp.int32), row(jnp.int32),
+            row(jnp.int32), row(jnp.float32), row(jnp.float32),
+            row(jnp.int32), row(jnp.float32), row(jnp.float32), key)
+        assert lowered.as_text().count("tf.aliasing_output") >= 4 + 2
+        text = lowered.as_text(debug_info=True)
+        for name in ("block.norm", "attn.global", "attn.window",
+                     "moe.experts", "moe.shared"):
+            assert name in text
+    finally:
+        stop_engine(eng)

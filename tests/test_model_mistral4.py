@@ -317,8 +317,8 @@ def test_the_four_shares_and_the_shared_expert_once_are_the_uncut_layer(
         mine = {**lp, **{k: lp[k][4 * share:4 * share + 4]
                          for k in hybrid.EXPERT_KEYS}}
         out, _ = hybrid.moe_block(x, mine, held)
-        parts.append(out - x - shared)      # the routed part alone
-    np.testing.assert_allclose(np.asarray(x + sum(parts) + shared),
+        parts.append(out - shared)          # the routed part alone
+    np.testing.assert_allclose(np.asarray(sum(parts) + shared),
                                np.asarray(whole), atol=1e-5, rtol=0)
     assert max(float(jnp.abs(p).max()) for p in parts) > 1e-3
     # And the reference, given one share, computes that share's layer.
@@ -332,7 +332,7 @@ def test_the_four_shares_and_the_shared_expert_once_are_the_uncut_layer(
                             tuple(routed[k][None] for k in ("wg", "wu", "wd")),
                             jnp.int32(0))
     got, _ = hybrid.moe_block(x[:1], {**mine, **routed}, held)
-    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want),
+    np.testing.assert_allclose(np.asarray(x[0] + got[0]), np.asarray(want),
                                atol=1e-5, rtol=0)
 
 
