@@ -11,7 +11,7 @@ RUN python -m venv /opt/venv
 ENV PATH="/opt/venv/bin:$PATH"
 COPY pyproject.toml ./
 COPY llmapigateway_tpu ./llmapigateway_tpu
-COPY main.py bench.py ./
+COPY main.py ./
 RUN pip install --no-cache-dir .
 
 FROM ${BASE_IMAGE}
@@ -33,7 +33,7 @@ RUN if [ "$INSTALL_TPU_JAX" = "true" ]; then \
         -f https://storage.googleapis.com/jax-releases/libtpu_releases.html; \
     fi
 
-COPY --chown=gateway:gateway main.py bench.py ./
+COPY --chown=gateway:gateway main.py ./
 COPY --chown=gateway:gateway llmapigateway_tpu ./llmapigateway_tpu
 COPY --chown=gateway:gateway docker/entrypoint.sh docker/healthcheck.py ./docker/
 RUN chmod +x docker/entrypoint.sh \

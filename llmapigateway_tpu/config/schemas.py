@@ -39,7 +39,7 @@ class DisaggregationConfig(BaseModel):
     # Retry-After) when the decode pool's predicted TPOT misses the
     # request's SLO, clamp (mark-only) when only TTFT is at risk.
     # "always": admit everything the watermark allows (telemetry still
-    # flows; A/B baseline for the bench's --disagg-ab rung).
+    # flows; the baseline an A/B of admission compares against).
     admission: str = "goodput"
 
     @field_validator("admission")
@@ -125,10 +125,9 @@ class LocalEngineConfig(BaseModel):
     kv_layout: str = "paged"        # "paged" | "contiguous"
     # Page size doubles as the paged kernel's DMA block; 256 is the
     # measured optimum on v5e (2026-07-31 ladder: 1647.8 vs 1443.7
-    # tok/s at 128, TinyLlama bs=8 — bench.py's paged_sweep re-measures
-    # both every run so this default tracks the hardware). Smaller pages
-    # trade a little DMA efficiency for finer capacity granularity in
-    # the equal-HBM admission math (engine/paged.py).
+    # tok/s at 128, TinyLlama bs=8). Smaller pages trade a little DMA
+    # efficiency for finer capacity granularity in the equal-HBM
+    # admission math (engine/paged.py).
     kv_page_size: int = 256
     kv_num_pages: int = 0           # 0 → derived from max_batch_size*max_seq_len
     # Multi-page kernel blocking: fetch this many CONTIGUOUS logical pages

@@ -456,8 +456,8 @@ def make_cache_attention_fn(block_s: int | None = None,
         # Decode blocks default wider than prefill (256 vs 128): the grid
         # is (B, KV, S/bs) programs whose per-program work is one small
         # matmul — at bs=128 the launch/DMA overhead of 256 tiny programs
-        # dominates; bs=256 measured fastest on v5e (tools/profile_decode
-        # sweep: 3.0 ms/step vs 3.3 at 128, 4.1 at 512 for TinyLlama).
+        # dominates; bs=256 measured fastest on v5e (a block-size sweep:
+        # 3.0 ms/step vs 3.3 at 128, 4.1 at 512 for TinyLlama).
         bs = block_s if block_s is not None else _auto_block(S, 256)
         n_stale = lengths if active is None else jnp.where(active, lengths, 0)
         out = flash_decode_attention(

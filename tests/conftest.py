@@ -15,14 +15,33 @@ if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (
         _flags + " --xla_force_host_platform_device_count=8").strip()
 
-# Tests never use the persistent XLA compilation cache
-# (jax_enable_compilation_cache=False, set through its environment form so
-# the suite's child processes — bench-smoke, parity
-# reruns — inherit it): siblings run with different XLA flag sets and must
-# not exchange programs, and tiny-test compiles are sub-second, so nothing
-# of value is lost. The engine's cache PLACEMENT has its own tests
+# One XLA compilation cache a PROCESS, thrown away with it (every compile
+# goes in: no floor on its seconds or its bytes). Engines and kernels of
+# one configuration are built again and again by the cases of a file,
+# each build traces anew, and an interpreted Pallas kernel compiles for
+# seconds: with the cache the second build of a program is a read, which
+# halves the engine files (PR 45: 68 s -> 30 s for six cases of
+# test_speculative.py). Not one directory for the run: xdist's workers
+# would write entries side by side (jax writes them in place, not by
+# rename), and a parity rerun's fresh process (below) has to compile
+# afresh. The directory is set whatever the environment says, so no run
+# reads what another left. The engine's cache PLACEMENT has its own tests
 # (test_compilation_cache.py).
-os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "0"
+import atexit           # noqa: E402
+import shutil           # noqa: E402
+import tempfile         # noqa: E402
+
+if os.environ.get("_TIER1_XLA_CACHE_OF") != str(os.getpid()):
+    # (not again when a test imports this file as ``tests.conftest``)
+    os.environ["_TIER1_XLA_CACHE_OF"] = str(os.getpid())
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = tempfile.mkdtemp(
+        prefix="tier1-xla-cache-")
+    atexit.register(shutil.rmtree, os.environ["JAX_COMPILATION_CACHE_DIR"],
+                    ignore_errors=True)
+_XLA_CACHE = os.environ["JAX_COMPILATION_CACHE_DIR"]
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "1"
+os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
+os.environ["JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES"] = "-1"
 
 import jax  # noqa: E402
 
@@ -32,7 +51,10 @@ def cpu_devices():
     return jax.devices("cpu")
 
 import asyncio          # noqa: E402
+import faulthandler     # noqa: E402
 import inspect          # noqa: E402
+import signal           # noqa: E402
+import threading        # noqa: E402
 from pathlib import Path  # noqa: E402
 
 import pytest           # noqa: E402
@@ -97,6 +119,39 @@ def stop_engine():
     return _stop
 
 
+# ``--dist loadfile`` hands a worker whole files in collection order, two
+# at a time, so a long file late in the alphabet starts in the run's last
+# minutes and is the wall's tail with five workers idle (PR 45's first
+# whole run: test_prefill_pool_carried.py, then 268 s, ended a run of
+# 6,085 worker-seconds at 1,135 s). The files that hold a worker longest
+# go out first, longest first (seconds on a worker in that run, a file
+# split since by its cases'); the rest keep their order, a file its
+# cases' order. A stale list costs balance, nothing else.
+LONGEST_FIRST = (
+    "test_spec_discovery.py",               # 373 (tests/bench_harness/)
+    "test_model_hybrid.py",                 # 279
+    "test_quant.py",                        # 238
+    "test_ops_grouped_experts.py",          # 195
+    "test_ops_paged_decode_fold.py",        # 191
+    "test_speculative.py",                  # 189
+    "test_kv_quant.py",                     # 185
+    "test_engine_pool_in_place.py",         # 183
+    "test_model_mistral.py",                # 177
+    "test_aot_tpu_programs.py",             # 157
+    "test_engine_pool_carried.py",          # 150
+)
+
+
+def pytest_collection_modifyitems(items):
+    rank = {name: at for at, name in enumerate(LONGEST_FIRST)}
+    items.sort(key=lambda item: rank.get(item.path.name, len(rank)))
+
+
+def pytest_unconfigure(config):
+    """An xdist worker may leave without running ``atexit``."""
+    shutil.rmtree(_XLA_CACHE, ignore_errors=True)
+
+
 def pytest_pyfunc_call(pyfuncitem):
     """Run ``async def`` tests on the shared loop (no pytest-asyncio here)."""
     func = pyfuncitem.obj
@@ -106,6 +161,45 @@ def pytest_pyfunc_call(pyfuncitem):
         _shared_loop().run_until_complete(func(**kwargs))
         return True
     return None
+
+
+# The driver cuts the whole run at 1,470 s and counts only what ran before
+# the cut, so no one wait may be longer than the suite. A test that runs
+# past PER_TEST_LIMIT_S fails with its name and every thread's stack. The
+# slowest case the file serves is a 213 s rehearsal under
+# tests/bench_harness/ (six files side by side on eight cores); the static
+# case in tests/test_time_limit.py holds every subprocess and wait_for
+# timeout under tests/ to this limit, and a failed parity test below costs
+# its worker two fresh processes of PARITY_RERUN_LIMIT_S at most.
+PER_TEST_LIMIT_S = 500
+PARITY_RERUN_LIMIT_S = 180
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_call(item, limit_s=PER_TEST_LIMIT_S):
+    """Fail a test that runs past ``limit_s``. SIGALRM interrupts the
+    worker's main thread (xdist runs tests there; on any other thread a
+    handler cannot be set and the test runs unbounded, as before) and
+    keeps firing every 5 s until the failure is out — an event-loop
+    callback or an ``except BaseException`` can swallow one raise.
+    ``faulthandler`` prints the stacks from its own thread, so they
+    appear even while the main thread is inside a call that never
+    returns to the interpreter."""
+    if threading.current_thread() is not threading.main_thread():
+        return (yield)
+
+    def past_the_limit(signum, frame):
+        pytest.fail(f"{item.nodeid} ran past the per-test limit of "
+                    f"{limit_s} s (tests/conftest.py)")
+    before = signal.signal(signal.SIGALRM, past_the_limit)
+    faulthandler.dump_traceback_later(limit_s)
+    signal.setitimer(signal.ITIMER_REAL, limit_s, 5.0)
+    try:
+        return (yield)
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        faulthandler.cancel_dump_traceback_later()
+        signal.signal(signal.SIGALRM, before)
 
 
 # Exact-greedy-parity tests compare token streams between two engines
@@ -191,7 +285,8 @@ def pytest_runtest_protocol(item, nextitem):
                 sub = subprocess.run(
                     [sys.executable, "-m", "pytest", item.nodeid,
                      "-q", "-x"],
-                    capture_output=True, text=True, timeout=900,
+                    capture_output=True, text=True,
+                    timeout=PARITY_RERUN_LIMIT_S,
                     cwd=str(item.config.rootpath),
                     env={**os.environ, "_PARITY_RERUN_CHILD": "1"})
             except subprocess.TimeoutExpired:

@@ -107,6 +107,31 @@ async def test_transient_step_fault_restarts_and_serves():
         await eng.stop()
 
 
+async def test_a_crash_leaves_no_admit_without_its_finish():
+    """Two requests in flight when the step loop dies: both streams end
+    in an error delta, the supervisor ends ``serving``, the engine serves
+    again, and over the whole incident the flight ring counts a finish
+    for every admit — the crashed requests' included."""
+    eng = _mk(supervisor={"backoff_ms": 20.0, "max_restarts": 5})
+    try:
+        eng.fault_plan = FaultPlan(fail_step_after=2)
+        reqs = [await _submit(eng, max_tokens=32) for _ in range(2)]
+        ends = [(await _drain_stream(eng, r))[-1] for r in reqs]
+        assert all(d.error is not None for d in ends)
+        eng.fault_plan = None
+        await _wait_for(lambda: eng.supervisor.state == "serving",
+                        msg="supervised restart")
+        after = await _submit(eng)
+        assert (await _drain_stream(eng, after))[-1].error is None
+        assert after.finish_reason is not None
+        assert eng.supervisor.state == "serving"
+        fs = eng.flight.stats()
+        assert fs["flight_admits"] == fs["flight_finishes"] == 3
+    finally:
+        eng.fault_plan = None
+        await eng.stop()
+
+
 async def test_fake_hbm_oom_is_classified_transient():
     """XLA's RESOURCE_EXHAUSTED (HBM OOM) shape restarts rather than
     parking the engine: fragmentation events are recoverable by a pool

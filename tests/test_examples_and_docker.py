@@ -67,7 +67,7 @@ def test_healthcheck_exit_codes(tmp_path, monkeypatch):
                      # stall past the probe's default 3x4s window.
                      "HEALTHCHECK_ATTEMPTS": "8",
                      "HEALTHCHECK_TIMEOUT_S": "10"},
-                capture_output=True)
+                capture_output=True, timeout=120)
             assert ok.returncode == 0, ok.stderr
         dead = aiohttp.test_utils.unused_port()
         bad = subprocess.run([sys.executable, str(hc)],
@@ -98,3 +98,16 @@ def test_dockerfile_excludes_local_secrets():
     assert "rm -f .env providers.json models_fallback_rules.json" in df
     assert "USER gateway" in df
     assert "HEALTHCHECK" in df
+
+
+def test_dockerfile_copies_only_files_that_exist():
+    """Every source a ``COPY`` line names (not ``--from=`` another stage)
+    is in the tree: a deleted file left on a COPY line fails the build."""
+    named = []
+    for line in (REPO / "Dockerfile").read_text().splitlines():
+        if line.startswith("COPY ") and "--from=" not in line:
+            *sources, _dest = [w for w in line.split()[1:]
+                               if not w.startswith("--")]
+            named += sources
+    assert "main.py" in named and "llmapigateway_tpu" in named
+    assert not [src for src in named if not (REPO / src).exists()]

@@ -617,7 +617,7 @@ def test_cli_json_output_and_exit_codes(tmp_path):
     proc = subprocess.run(
         [sys.executable, "-m", "llmapigateway_tpu.analysis",
          str(tmp_path), "--format", "json"],
-        capture_output=True, text=True)
+        capture_output=True, text=True, timeout=120)
     assert proc.returncode == 1
     doc = json.loads(proc.stdout)
     assert doc["count"] == 1
@@ -627,7 +627,7 @@ def test_cli_json_output_and_exit_codes(tmp_path):
         "import asyncio\nasync def h(r):\n    await asyncio.sleep(1)\n")
     proc = subprocess.run(
         [sys.executable, "-m", "llmapigateway_tpu.analysis", str(tmp_path)],
-        capture_output=True, text=True)
+        capture_output=True, text=True, timeout=120)
     assert proc.returncode == 0
     assert "clean" in proc.stdout
 
@@ -635,7 +635,7 @@ def test_cli_json_output_and_exit_codes(tmp_path):
 def test_cli_rule_catalog_lists_all_rules():
     proc = subprocess.run(
         [sys.executable, "-m", "llmapigateway_tpu.analysis", "--list-rules"],
-        capture_output=True, text=True)
+        capture_output=True, text=True, timeout=120)
     assert proc.returncode == 0
     for name in RULES_BY_NAME:
         assert name in proc.stdout

@@ -62,7 +62,7 @@ def test_cli_exit_codes_and_stdin(tmp_path):
     sarif_file = tmp_path / "r.sarif"
     sarif_file.write_text(json.dumps(doc))
     proc = subprocess.run([sys.executable, str(TOOL), str(sarif_file)],
-                          capture_output=True, text=True)
+                          capture_output=True, text=True, timeout=120)
     assert proc.returncode == 1               # findings present
     assert "finding(s)" in proc.stdout
 
@@ -70,10 +70,10 @@ def test_cli_exit_codes_and_stdin(tmp_path):
                        "properties": {"checkedFiles": 3}, "results": []}]}
     proc = subprocess.run([sys.executable, str(TOOL), "-"],
                           input=json.dumps(clean),
-                          capture_output=True, text=True)
+                          capture_output=True, text=True, timeout=120)
     assert proc.returncode == 0
     assert "clean" in proc.stdout
 
     proc = subprocess.run([sys.executable, str(TOOL), "/no/such.sarif"],
-                          capture_output=True, text=True)
+                          capture_output=True, text=True, timeout=120)
     assert proc.returncode == 2

@@ -21,7 +21,8 @@ from llmapigateway_tpu.models.quant import quantize_array
 # The period families' shared scaffolding: seeded params, a paged cache with
 # a table of SEQ tokens a slot in EVERY cache group, a provider a group at
 # its window, a prefill call.
-from test_model_smallthinker import paged, params_of, prefill, providers
+from test_model_smallthinker import paged, prefill, providers
+from tests.hybrid_params import params_of
 
 TINY = get_preset("tiny-cohere2-test")
 # Both sides float32 on the same weights: what is left is the order of the

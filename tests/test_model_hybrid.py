@@ -1,6 +1,8 @@
 """The hybrid family (models/hybrid.py) against the plain reference
 (benchmark/reference/solar_open2.py) on seeded random weights at the tiny
-preset: logits, not tokens. Every tolerance says where it comes from."""
+preset: logits, not tokens. Every tolerance says where it comes from. The
+expert layer's cases are tests/test_model_hybrid_experts.py (a file of
+their own, so that the two run on a worker each)."""
 import dataclasses
 
 import jax
@@ -13,6 +15,7 @@ from llmapigateway_tpu.models import hybrid
 from llmapigateway_tpu.models.config import PRESETS, get_preset
 from llmapigateway_tpu.models.quant import is_quantized
 from llmapigateway_tpu.ops.paged_attention import make_paged_attention_fn
+from tests.hybrid_params import params_of
 
 TINY = get_preset("tiny-hybrid-test")
 # Both sides float32 on the same weights: what is left is the order of the
@@ -39,11 +42,6 @@ def file_of(c) -> dict:
 
 def sizes_of(c):
     return ref.sizes(c, file_of(c))
-
-
-def params_of(c, dtype=jnp.float32, quant="", seed=1):
-    return jax.jit(lambda k: hybrid.init_params(c, k, dtype, quant))(
-        jax.random.PRNGKey(seed))
 
 
 def paged(c, slots: int, dtype=jnp.float32, kv_quant=""):
@@ -162,13 +160,14 @@ def test_prefill_in_chunks_then_decode_matches_the_reference(
     """Three chunks of 32 in a group of two rows on slots 2 and 0 — the
     second row ends 16 tokens into its last chunk, so its bucket is padded
     — then four decode steps beside an idle slot: every logit against the
-    reference's full forward over the same tokens."""
+    reference's full forward over the same tokens (ONE forward a slot,
+    over all it was fed, read at its last five positions: the model is
+    causal, and a forward a length is a compile a length)."""
     c = dataclasses.replace(TINY, n_experts_held=8)
     dt = jnp.dtype(dtype)
     params = params_of(c, dt, quant)
     cache, table = paged(c, 3, dt, kv_quant)
     toks, true_len, rows = tokens_of(2, 96), [96, 80], [2, 0]
-    sizes, errs = sizes_of(c), []
     step = jax.jit(lambda ca, t, s, nv: prefill(c, params, ca, table, t, s,
                                                 rows, nv)[::-1])
     last = {}
@@ -179,14 +178,12 @@ def test_prefill_in_chunks_then_decode_matches_the_reference(
         for r in range(2):
             if nv[r]:           # the call's logits are its LAST real token's
                 last[r] = np.asarray(lg[r, 0], np.float32)
-    for r in range(2):
-        want = ref.logits(params, sizes, toks[r, :true_len[r]], last=1)[0]
-        errs.append(np.abs(last[r] - want))
     attn = make_paged_attention_fn(table, max_seq=SEQ, impl="reference")
     decode = jax.jit(lambda ca, t, ln, a: hybrid.forward(
         params, c, t, ln, ca, active=a, attention_fn=attn)[::-1])
     lengths, active = np.array([80, 0, 96]), np.array([True, False, True])
     seqs = {0: list(toks[1, :80]), 2: list(toks[0, :96])}
+    got = {0: [last[1]], 2: [last[0]]}      # a slot's logits, call by call
     nxt = np.array([5, 0, 7])
     for _ in range(4):
         idle = [np.asarray(s[:, 1]) for s in cache.state + cache.conv]
@@ -196,11 +193,14 @@ def test_prefill_in_chunks_then_decode_matches_the_reference(
             assert (np.asarray(s[:, 1]) == before).all()        # bit-identical
         for slot in (0, 2):
             seqs[slot].append(int(nxt[slot]))
-            want = ref.logits(params, sizes, np.asarray(seqs[slot]), 1)[0]
-            errs.append(np.abs(np.asarray(lg[slot, 0], np.float32) - want))
+            got[slot].append(np.asarray(lg[slot, 0], np.float32))
         lengths = lengths + active
         nxt = np.where(active, np.asarray(lg[:, 0]).argmax(-1), 0)
-    errs = np.stack(errs)
+    sizes = sizes_of(c)
+    errs = np.concatenate([
+        np.abs(np.stack(got[slot])
+               - ref.logits(params, sizes, np.asarray(seqs[slot]), last=5))
+        for slot in (0, 2)])
     if tol is not None:
         assert errs.max() <= tol
     else:       # the bulk, as in the full forward's int8 case
@@ -244,133 +244,6 @@ def test_a_prefill_that_starts_at_zero_ignores_what_the_block_holds():
     got, after = prefill(c, params, dirty, table, toks, [0], [1])
     assert (np.asarray(got) == np.asarray(clean)).all()
     assert (np.asarray(after.state[0][:, 0]) == 7.0).all()  # slot 0 untouched
-
-
-# -- the expert layer ---------------------------------------------------------
-
-def _mlp_of(params, layer=0):
-    return jax.tree.map(lambda a: a[layer], params["layers"]["lin"][0]["mlp"])
-
-
-def _share(lp, first, held):
-    cut = {k: jax.tree.map(lambda a: a[first:first + held], lp[k])
-           for k in ("wg", "wu", "wd")}
-    return {**lp, **cut}
-
-
-def test_the_shares_of_all_chips_add_up_to_the_uncut_layer():
-    """The guide's share test: 16 experts held 8 at a time. The routed
-    parts of the two shares plus the shared expert counted ONCE equal the
-    uncut layer's result, and each share is what the reference computes
-    when it is given that share."""
-    whole = params_of(TINY)
-    lp = _mlp_of(whole)
-    x = jax.random.normal(jax.random.PRNGKey(5), (2, 40, TINY.d_model))
-    full = hybrid.moe_block(x, lp, TINY)[0]
-    no_shared = dataclasses.replace(TINY, n_shared_experts=0)
-    shared_only = full - hybrid.moe_block(x, lp, no_shared)[0]
-    routed = []
-    for first in (0, 8):
-        c = dataclasses.replace(TINY, n_experts_held=8,
-                                first_expert_held=first)
-        part = hybrid.moe_block(x, _share(lp, first, 8), c)[0]
-        routed.append(part - shared_only)
-        want = ref.expert_mlp(x.reshape(80, -1), _share(lp, first, 8),
-                              sizes_of(c))
-        np.testing.assert_allclose(part.reshape(80, -1), want, atol=2e-5)
-    np.testing.assert_allclose(routed[0] + routed[1] + shared_only, full,
-                               atol=2e-5)
-    assert float(jnp.abs(routed[0]).max()) > 0.01       # each share matters
-    assert float(jnp.abs(routed[1]).max()) > 0.01
-
-
-def _routed(c, lp, n: int, routing: str):
-    """(x [n,D], the layer's weights with the router that routes them).
-    "even": the drawn router on drawn rows. "uneven": every token picks
-    the four experts 1, 2, 5 and 9 after the first one held (a capacity
-    dispatch at factor 2 would drop three quarters of them), so the first
-    expert held gets no row and the second every row."""
-    if routing == "even":
-        return jax.random.normal(jax.random.PRNGKey(9), (n, c.d_model)), lp
-    d = jax.random.normal(jax.random.PRNGKey(3), (c.d_model,))
-    x = d + 0.05 * jax.random.normal(jax.random.PRNGKey(4), (n, c.d_model))
-    picked = (c.first_expert_held + jnp.array([1, 2, 5, 9])) % c.n_experts
-    chosen = jnp.zeros((c.n_experts,)).at[picked].set(1.0)
-    return x, {**lp, "router": jnp.outer(d, 2.0 * chosen - 1.0)
-               / jnp.linalg.norm(d)}
-
-
-@pytest.mark.parametrize("routing", ["even", "uneven"])
-@pytest.mark.parametrize("tile", [16, 128])
-@pytest.mark.parametrize("first, held", [(0, 16), (8, 4), (4, 2)],
-                         ids=["all", "quarter", "eighth"])
-def test_the_grouped_product_is_the_dense_one(first, held, tile, routing):
-    """All, a quarter and an eighth of 16 experts held; 200 rows, not a
-    multiple of either tile. Under the uneven routing one held expert has
-    no row and one has 200 (13 tiles of 16, 2 of 128): no assignment is
-    dropped, the grouped product (the kernel of ops/grouped_experts.py,
-    interpreted) equals running every held expert on every token, and the
-    block (tiles of 128) equals the reference."""
-    c = dataclasses.replace(TINY, n_experts_held=held,
-                            first_expert_held=first)
-    x, lp = _routed(c, _share(_mlp_of(params_of(TINY)), first, held), 200,
-                    routing)
-    hf = hybrid.rms_norm(x, lp["norm"], c.rms_eps)
-    idx, w = hybrid.route(hf, lp["router"], c)
-    probs = hybrid.held_weights(idx, w, c)
-    counts = np.asarray(jnp.sum(probs > 0, axis=0))
-    if routing == "uneven":
-        assert counts[0] == 0 and counts[1] == 200 > tile
-    grouped, tiles = jax.jit(lambda: hybrid.experts_grouped(
-        hf, idx - first, w, lp, held, tile=tile))()
-    assert list(np.asarray(tiles)) == [np.sum(-(-counts // tile)),
-                                       counts.sum()]
-    np.testing.assert_allclose(grouped, hybrid.experts_dense(hf, probs, lp),
-                               atol=2e-5)
-    got, counted = hybrid.moe_block(x[None], lp, c)      # the grouped path
-    np.testing.assert_allclose(got[0], ref.expert_mlp(x, lp, sizes_of(c)),
-                               atol=5e-5)
-    assert list(np.asarray(counted)) == [
-        0, 0, 0, int(np.sum(-(-counts // hybrid.GROUP_TILE))), counts.sum()]
-
-
-# How far the grouped product may stand from ``experts_dense`` on the same
-# int8 tree. float32 rows: the order of the sums (the form before PR 39 read
-# 4.5e-8 / 2.2e-8 / 2.2e-8 at all / a quarter / an eighth held; results of
-# size 0.45 / 0.31 / 0.24). bfloat16 rows: both forms round each expert's
-# result to bfloat16 and differ in WHERE the gate is rounded — the dense
-# form and the loops before PR 43 wherever XLA ends a fusion (3.443e-3 /
-# 3.080e-3 / 2.226e-3 then), the kernel once, after ``act(gate) * up`` in
-# float32 (3.885e-3 / 2.543e-3 / 2.902e-3). Against the dense form on
-# float32 rows the kernel stands 3.28e-3 / 2.78e-3 / 3.11e-3 and the
-# bfloat16 dense form 4.27e-3 / 2.78e-3 / 2.22e-3: one rounding of a
-# result of that size is 2e-3.
-INT8_DISTANCE = {(jnp.float32, 16): 1e-7, (jnp.float32, 4): 1e-7,
-                 (jnp.float32, 2): 1e-7, (jnp.bfloat16, 16): 3.89e-3,
-                 (jnp.bfloat16, 4): 3.09e-3, (jnp.bfloat16, 2): 2.91e-3}
-
-
-@pytest.mark.parametrize("tile", [16, 128])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("first, held", [(0, 16), (8, 4), (4, 2)],
-                         ids=["all", "quarter", "eighth"])
-def test_rows_quantised_once_stand_no_further_from_dense(first, held, dtype,
-                                                         tile):
-    """int8 weights: the grouped product quantises its rows ONCE, outside
-    the kernel, and every tile gathers int8 rows and their scales; per-row
-    quantisation commutes with a gather, so it stands from the dense form
-    no further than the form that quantised in every tile did."""
-    c = dataclasses.replace(TINY, n_experts_held=held,
-                            first_expert_held=first)
-    lp = _share(_mlp_of(params_of(TINY, dtype, "int8")), first, held)
-    assert is_quantized(lp["wg"])
-    x = jax.random.normal(jax.random.PRNGKey(9), (200, c.d_model)
-                          ).astype(dtype)
-    idx, w = hybrid.route(x.astype(jnp.float32), lp["router"], c)
-    dense = hybrid.experts_dense(x, hybrid.held_weights(idx, w, c), lp)
-    grouped, _ = hybrid.experts_grouped(x, idx - first, w, lp, held,
-                                        tile=tile)
-    assert float(jnp.abs(grouped - dense).max()) <= INT8_DISTANCE[dtype, held]
 
 
 # -- initialisation and presets -----------------------------------------------

@@ -16,6 +16,7 @@ from llmapigateway_tpu.models.config import (PRESETS, RopeScaling,
                                              get_preset)
 from llmapigateway_tpu.models.llama import rope_tables
 from llmapigateway_tpu.ops import latent_attention as la
+from tests.hybrid_params import params_of
 
 TINY = get_preset("tiny-mistral4-test")
 # Both sides float32 on the same weights: what is left is the order of the
@@ -46,11 +47,6 @@ def file_of(c) -> dict:
 
 
 SIZES = ref.sizes(TINY, file_of(TINY))
-
-
-def params_of(c, dtype=jnp.float32, quant="", seed=1):
-    return jax.jit(lambda k: hybrid.init_params(c, k, dtype, quant))(
-        jax.random.PRNGKey(seed))
 
 
 def paged(c, slots: int, dtype=jnp.float32):

@@ -14,6 +14,7 @@ from benchmark.reference import smallthinker as ref
 from llmapigateway_tpu.models import hybrid
 from llmapigateway_tpu.models.config import PRESETS, get_preset
 from llmapigateway_tpu.ops.paged_attention import make_paged_attention_fn
+from tests.hybrid_params import params_of
 
 TINY = get_preset("tiny-smallthinker-test")
 # Both sides float32 on the same weights: what is left is the order of the
@@ -41,11 +42,6 @@ def file_of(c) -> dict:
 
 
 SIZES = ref.sizes(TINY, file_of(TINY))
-
-
-def params_of(c, dtype=jnp.float32, quant="", seed=1):
-    return jax.jit(lambda k: hybrid.init_params(c, k, dtype, quant))(
-        jax.random.PRNGKey(seed))
 
 
 def paged(c, slots: int, dtype=jnp.float32, kv_quant=""):

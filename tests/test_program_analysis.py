@@ -282,7 +282,7 @@ def test_cache_key_invalidates_on_rule_set_change(tmp_path):
 def _cli(*args, cwd=None):
     return subprocess.run(
         [sys.executable, "-m", "llmapigateway_tpu.analysis", *args],
-        capture_output=True, text=True, cwd=cwd)
+        capture_output=True, text=True, cwd=cwd, timeout=120)
 
 
 def test_cli_sarif_output(tmp_path):
@@ -320,14 +320,16 @@ def test_cli_changed_mode_with_shared_cache(tmp_path):
     pkg = repo / "llmapigateway_tpu" / "server"
     pkg.mkdir(parents=True)
     git = ["git", "-C", str(repo)]
-    subprocess.run(["git", "init", "-q", str(repo)], check=True)
-    subprocess.run([*git, "config", "user.email", "t@t"], check=True)
-    subprocess.run([*git, "config", "user.name", "t"], check=True)
+    subprocess.run(["git", "init", "-q", str(repo)], check=True, timeout=60)
+    subprocess.run([*git, "config", "user.email", "t@t"], check=True,
+                   timeout=60)
+    subprocess.run([*git, "config", "user.name", "t"], check=True,
+                   timeout=60)
     clean = pkg / "clean.py"
     clean.write_text("import asyncio\nasync def ok(r):\n"
                      "    await asyncio.sleep(0)\n")
-    subprocess.run([*git, "add", "-A"], check=True)
-    subprocess.run([*git, "commit", "-qm", "seed"], check=True)
+    subprocess.run([*git, "add", "-A"], check=True, timeout=60)
+    subprocess.run([*git, "commit", "-qm", "seed"], check=True, timeout=60)
     # New (untracked) file with a violation + an unchanged clean file.
     bad = pkg / "bad.py"
     bad.write_text("import time\nasync def h(r):\n    time.sleep(1)\n")

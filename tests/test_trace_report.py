@@ -75,7 +75,7 @@ def test_cli_json_and_exit_codes(tmp_path):
     doc = write_doc(tmp_path)
     proc = subprocess.run(
         [sys.executable, "tools/trace_report.py", "--json", str(doc)],
-        capture_output=True, text=True,
+        capture_output=True, text=True, timeout=120,
         cwd=Path(__file__).resolve().parent.parent)
     assert proc.returncode == 0
     rows = json.loads(proc.stdout)
@@ -86,6 +86,6 @@ def test_cli_json_and_exit_codes(tmp_path):
     bad.write_text(json.dumps({"value": 1}))
     proc = subprocess.run(
         [sys.executable, "tools/trace_report.py", str(bad)],
-        capture_output=True, text=True,
+        capture_output=True, text=True, timeout=120,
         cwd=Path(__file__).resolve().parent.parent)
     assert proc.returncode != 0

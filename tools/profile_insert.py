@@ -1,7 +1,7 @@
 """Microbench KV-insert strategies for the decode step (T=1).
 
 The engine's vmap(dynamic_update_slice) insert lowers to a TPU scatter that
-costs ~5.5 ms/step at L22 B8 KV4 S1024 Dh64 (tools/profile_decode.py).
+costs ~5.5 ms/step at L22 B8 KV4 S1024 Dh64.
 Candidates measured here, each as a scan over L layers like the model's
 layer scan, 32-step burst:
 

@@ -3,8 +3,7 @@
 ``GET /v1/api/trace/{request_id}`` returns one request's span tree —
 gateway root → router attempt N → provider call → engine phases. This tool
 flattens that JSON into an indented waterfall so "where did request X
-spend its 742 ms" is a table you read top to bottom, mirroring
-``tools/roofline_report.py``'s role for bench ladders:
+spend its 742 ms" is a table you read top to bottom:
 
     curl -s localhost:9100/v1/api/trace/<id> > trace.json
     python tools/trace_report.py trace.json
