@@ -352,12 +352,7 @@ class InferenceEngine:
                     f"spec_draft_len must be one of 1, 3, 7 (verify width "
                     f"k+1 must be a power of two), got {self.spec_k}")
 
-        if model_cfg.n_lin_layers:
-            self._refuse_for_recurrent_state()
-        if len(model_cfg.cache_groups) > 1:
-            self._refuse_for_cache_groups()
-        if model_cfg.is_mla:
-            self._refuse_for_latent_cache()
+        self._refuse_unsupported()
 
         self.tokenizer = load_tokenizer(
             engine_cfg.tokenizer_path or engine_cfg.model_path or None,
@@ -448,6 +443,9 @@ class InferenceEngine:
         # call's over its rows and positions. Monotone; worker thread.
         self._mla_decode_keys = 0
         self._mla_prefill_keys = 0
+        # The state blocks the decode steps rewrote: active rows x linear
+        # layers a step (ISSUE 46), counted the same way.
+        self._lin_decode_state_updates = 0
         # The keys the decode programs' paged kernel calls attended
         # (ISSUE 44), kept the same way: per layer of a K/V cache group,
         # summed over steps and active slots — a global group's step sees
@@ -477,105 +475,96 @@ class InferenceEngine:
         self._watchdog_task: asyncio.Task | None = None
         self._clean_steps = 0                           # guarded-by: loop
 
-    def _refuse_for_recurrent_state(self) -> None:
-        """What a family with a block of recurrent state per slot
-        (models/hybrid.py) cannot be served with. Each is refused here,
-        at build, with its reason — none is silently switched off."""
-        cfg, why = self.cfg, None
-        if not self.paged:
-            why = ("kv_layout 'contiguous': its softmax layers are served "
-                   "from the page pool only")
-        elif cfg.prefix_cache:
-            why = ("prefix_cache: a cached prefix holds KV pages but not "
-                   "the recurrent state at its end; set prefix_cache false")
-        elif self.spec_k:
-            why = ("spec_draft_len: a rejected draft cannot be rolled out "
-                   "of the recurrent state")
-        elif self.model_cfg.sliding_window:
-            why = ("a sliding window: the page ring is not wired to the "
-                   "pool of the softmax layers")
-        elif self.mesh.size > 1:
-            why = (f"mesh {dict(self.mesh.shape)}: the state block and the "
-                   f"held experts have no sharding rule yet")
-        elif cfg.disaggregation.enabled:
-            why = ("disaggregation: a handoff moves pages between slots, "
-                   "not the state block")
-        elif cfg.model_path:
-            why = ("model_path: no checkpoint mapping for this family "
-                   "(engine/checkpoint.py)")
-        if why:
-            raise ValueError(
-                f"the {self.model_cfg.family!r} family does not support "
-                f"{why}")
+    # What a period family cannot be served with, by the kind of per-slot
+    # storage that stands in the way, each feature with that storage's
+    # reason. A family with several kinds (a latent pool AND state) is
+    # refused the union, each feature with every reason it has.
+    _REFUSED_BESIDE = {
+        # Whatever the storage: a period family of any kind.
+        "any": {
+            "model_path": "no checkpoint mapping for this family "
+                          "(engine/checkpoint.py)"},
+        # A block of recurrent state a slot (models/hybrid.py).
+        "state": {
+            "contiguous": "its softmax layers are served from the page "
+                          "pool only",
+            "prefix_cache": "a cached prefix holds KV pages but not the "
+                            "recurrent state at its end; set prefix_cache "
+                            "false",
+            "spec": "a rejected draft cannot be rolled out of the "
+                    "recurrent state",
+            "window": "the page ring is not wired to the pool of the "
+                      "softmax layers",
+            "mesh": "the state block and the held experts have no "
+                    "sharding rule yet",
+            "disagg": "a handoff moves pages between slots, not the "
+                      "state block"},
+        # SEVERAL cache groups (window and global layers in one model).
+        "groups": {
+            "contiguous": "a dense cache keeps every layer's whole "
+                          "context; the groups are page pools",
+            "prefix_cache": "the ring re-targets a windowed group's pages, "
+                            "and a cached prefix would need the global "
+                            "group's pages AND the window's last tokens; "
+                            "set prefix_cache false",
+            "spec": "the verify path reads one pool at one window",
+            "mesh": "the page ring runs on one device, and the experts "
+                    "have no sharding rule yet",
+            "disagg": "a handoff cannot move a ring slot "
+                      "(PageAllocator.transfer)"},
+        # A LATENT pool (``latent_width`` numbers a token, models/mla.py).
+        "latent": {
+            "contiguous": "the latent cache is a page pool, and no dense "
+                          "layout of it exists",
+            "kv_quant": "the latent pool is bfloat16; an int8 latent needs "
+                        "scale planes the latent kernel does not read. Set "
+                        "kv_quant ''",
+            "prefix_cache": "the radix cache shares K/V pages, and has no "
+                            "rule yet for sharing latent pages; set "
+                            "prefix_cache false",
+            "spec": "the verify path reads a K and a V pool, not a latent "
+                    "one",
+            "mesh": "the latent pool has one key head, which no axis "
+                    "divides, and the held experts have no sharding rule "
+                    "yet",
+            "disagg": "a handoff of latent pages between pools is not "
+                      "wired",
+            "page_lanes": "a latent page lies token-minor, and the chip's "
+                          "kernels move whole tiles of 128 lanes"},
+    }
 
-    def _refuse_for_cache_groups(self) -> None:
-        """What a family whose softmax layers fall into SEVERAL cache
-        groups (window and global layers in one model) cannot be served
-        with — refused at build, each with its reason."""
-        cfg, why = self.cfg, None
-        if not self.paged:
-            why = ("kv_layout 'contiguous': a dense cache keeps every "
-                   "layer's whole context; the groups are page pools")
-        elif cfg.prefix_cache:
-            why = ("prefix_cache: the ring re-targets a windowed group's "
-                   "pages, and a cached prefix would need the global "
-                   "group's pages AND the window's last tokens; set "
-                   "prefix_cache false")
-        elif self.spec_k:
-            why = ("spec_draft_len: the verify path reads one pool at one "
-                   "window")
-        elif self.mesh.size > 1:
-            why = (f"mesh {dict(self.mesh.shape)}: the page ring runs on "
-                   f"one device, and the experts have no sharding rule yet")
-        elif cfg.disaggregation.enabled:
-            why = ("disaggregation: a handoff cannot move a ring slot "
-                   "(PageAllocator.transfer)")
-        elif cfg.model_path:
-            why = ("model_path: no checkpoint mapping for this family "
-                   "(engine/checkpoint.py)")
-        if why:
-            raise ValueError(
-                f"the {self.model_cfg.family!r} family does not support "
-                f"{why}")
-
-    def _refuse_for_latent_cache(self) -> None:
-        """What a family whose cache group is a LATENT pool (one pool of
-        ``latent_width`` numbers a token, models/mla.py) cannot be served
-        with yet — refused at build, each with its reason."""
-        cfg, why = self.cfg, None
-        if not self.paged:
-            why = ("kv_layout 'contiguous': the latent cache is a page "
-                   "pool, and no dense layout of it exists")
-        elif self.kv_quant:
-            why = ("kv_quant 'int8': the latent pool is bfloat16; an int8 "
-                   "latent needs scale planes the latent kernel does not "
-                   "read. Set kv_quant ''")
-        elif cfg.prefix_cache:
-            why = ("prefix_cache: the radix cache shares K/V pages, and "
-                   "has no rule yet for sharing latent pages; set "
-                   "prefix_cache false")
-        elif self.spec_k:
-            why = ("spec_draft_len: the verify path reads a K and a V "
-                   "pool, not a latent one")
-        elif self.mesh.size > 1:
-            why = (f"mesh {dict(self.mesh.shape)}: the latent pool has one "
-                   f"key head, which no axis divides, and the held experts "
-                   f"have no sharding rule yet")
-        elif cfg.disaggregation.enabled:
-            why = ("disaggregation: a handoff of latent pages between "
-                   "pools is not wired")
-        elif cfg.model_path:
-            why = ("model_path: no checkpoint mapping for this family "
-                   "(engine/checkpoint.py)")
-        elif (self.kv_page % 128 and jax.default_backend() == "tpu"
-              and self._resolve_attention_impl() == "pallas"):
-            why = (f"kv_page_size {self.kv_page}: a latent page lies "
-                   f"token-minor, and the chip's kernels move whole tiles "
-                   f"of 128 lanes")
-        if why:
-            raise ValueError(
-                f"the {self.model_cfg.family!r} family does not support "
-                f"{why}")
+    def _refuse_unsupported(self) -> None:
+        """Refuse at build, with every reason it has, the first feature
+        the configuration asks for that the model's per-slot storage
+        cannot be served with — none is silently switched off."""
+        cfg, c = self.cfg, self.model_cfg
+        kinds = [kind for kind, has in (
+            ("state", c.n_lin_layers), ("groups", len(c.cache_groups) > 1),
+            ("latent", c.is_mla)) if has]
+        if not kinds:
+            return
+        kinds.append("any")
+        asked = {       # in the order they are refused
+            "contiguous": (not self.paged, "kv_layout 'contiguous'"),
+            "kv_quant": (bool(self.kv_quant), "kv_quant 'int8'"),
+            "prefix_cache": (cfg.prefix_cache, "prefix_cache"),
+            "spec": (bool(self.spec_k), "spec_draft_len"),
+            "window": (bool(c.sliding_window), "a sliding window"),
+            "mesh": (self.mesh.size > 1, f"mesh {dict(self.mesh.shape)}"),
+            "disagg": (cfg.disaggregation.enabled, "disaggregation"),
+            "model_path": (bool(cfg.model_path), "model_path"),
+            "page_lanes": (self.kv_page % 128 != 0
+                           and jax.default_backend() == "tpu"
+                           and self._resolve_attention_impl() == "pallas",
+                           f"kv_page_size {self.kv_page}"),
+        }
+        for feature, (wanted, label) in asked.items():
+            whys = [self._REFUSED_BESIDE[kind][feature] for kind in kinds
+                    if feature in self._REFUSED_BESIDE[kind]]
+            if wanted and whys:
+                raise ValueError(
+                    f"the {c.family!r} family does not support {label}: "
+                    + "; and ".join(whys))
 
     @property
     def allocator(self):
@@ -767,7 +756,7 @@ class InferenceEngine:
             # superpage instead.
             n_trash = self.kv_ppb
             from .paged import CacheGroup, CacheGroups
-            periods = c.n_layers // max(1, c.layer_period)
+            periods = c.n_periods if c.layer_period else c.n_layers
             groups = []
             for (window, positions), ring in zip(c.cache_groups, rings):
                 # The most pages one slot ever holds — the ring where it
@@ -815,13 +804,15 @@ class InferenceEngine:
             if c.layer_period:
                 # A pool a cache group, of the softmax layers only;
                 # beside them a fixed block of recurrent state and a conv
-                # tail per slot for every linear layer of a period
+                # tail per slot for every linear layer of a period, and
+                # one more stack for the leading layers
                 # (models/hybrid.py HybridCache). A prefill that starts at
                 # position 0 starts from zero state whatever the block
                 # holds, so release, cancel and rebuild do no state work.
                 from ..models.hybrid import HybridCache
                 rep_sh = NamedSharding(self.mesh, P())
-                n_lin = c.layer_period - len(c.softmax_positions)
+                n_lin = (c.layer_period - len(c.softmax_positions)
+                         + bool(c.leading_dense))
                 k_sh = v_sh = (side,) * len(self.kv_groups)
                 if c.is_mla:        # ONE latent pool, no V side
                     k_sh, v_sh = (rep_sh,), ()
@@ -3206,10 +3197,13 @@ class InferenceEngine:
         """Add a burst of ``n_steps`` to the decode keys of ONE layer of
         each cache group: step ``i`` of an active slot at length ``n``
         sees ``n + i + 1`` keys in a latent or a global group, and what
-        of them lies inside the window in a windowed one."""
+        of them lies inside the window in a windowed one. And to the
+        state blocks rewritten: one a linear layer, active slot and step."""
         if not self.paged:
             return
         live = self.lengths[self.active].astype(np.int64)
+        self._lin_decode_state_updates += (
+            n_steps * len(live) * self.model_cfg.n_lin_layers)
         seen = live[:, None] + np.arange(1, n_steps + 1)    # [slots, steps]
         for g in self.kv_groups:
             if g.kind == "latent":
@@ -3590,6 +3584,8 @@ class InferenceEngine:
             # Recurrent state beside the pool.
             out["state_bytes_resident"] = self._state_bytes()
             out["state_slots"] = self.B
+            out["lin_decode_state_updates_total"] = \
+                self._lin_decode_state_updates
         if self.model_cfg.layer_period:
             # The expert layer's share: the assignments the decode steps
             # routed, those that landed on an expert held here (their

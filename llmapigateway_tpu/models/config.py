@@ -66,19 +66,15 @@ class RopeScaling:
 
 @dataclass(frozen=True)
 class ModelConfig:
-    # "llama" | "qwen2" | "gemma" | "mixtral" | "hybrid" | "smallthinker"
-    # | "mistral4" | "cohere2_moe". The last four are the PERIOD families
-    # (``layer_period`` > 0, models/hybrid.py); "cohere2_moe" (Command A+)
-    # is the one whose block is parallel: one LayerNorm feeds attention,
-    # routed and shared experts side by side, one residual add a layer;
-    # three windowed rotary layers then a global NoPE layer a period (its
-    # first cache group is the RING), four shared experts averaged, the
-    # head tied to the embedding. Like the other period families it is
-    # served from page pools on one device and refuses, at engine build
-    # and with the reason, a contiguous cache, any mesh axis, the prefix
-    # cache, speculation, disaggregation and ``model_path``. Presets:
-    # "command-a-plus" (published), "command-a-plus-218b-ep8" (one chip of
-    # eight that share a layer), "tiny-cohere2-test".
+    # "llama" | "qwen2" | "gemma" | "mixtral", and the five PERIOD families
+    # (``layer_period`` > 0, models/hybrid.py): "hybrid" (Solar-Open2),
+    # "smallthinker", "mistral4", "cohere2_moe" (Command A+, the parallel
+    # block) and "gigachat3_5" (a latent layer then three gated-delta-net
+    # layers a period behind ``leading_dense`` layers of a linear mixer and
+    # a dense MLP, every sub-block normed before and after). A period
+    # family is served from page pools on one device and refuses, at engine
+    # build and with the reason, a contiguous cache, any mesh axis, the
+    # prefix cache, speculation, disaggregation and ``model_path``.
     family: str = "llama"
     vocab_size: int = 32000
     d_model: int = 2048
@@ -124,6 +120,7 @@ class ModelConfig:
     # with more than one; its ``logit_scale`` is 1 and has no field).
     n_shared_experts: int = 0
     layer_period: int = 0          # layers per period; position 0 is softmax
+    #                                (a latent layer where ``is_mla``)
     lin_heads: int = 0             # linear-attention heads ...
     lin_head_dim: int = 0          # ... their key/value size ...
     lin_conv_taps: int = 0         # ... causal depthwise conv before q/k/v
@@ -164,10 +161,32 @@ class ModelConfig:
     # norm a layer whose result feeds attention, the routed and the shared
     # experts side by side, and ONE residual add, x + A + R + S.
     parallel_block: bool = False
-    # The period families' norm: "rms" (``rms_eps``) or "layernorm" — mean
-    # removed, no bias, ``layer_norm_eps``.
+    # The period families' norm: "rms" (``rms_eps``), "layernorm" — mean
+    # removed, no bias, ``layer_norm_eps`` — or "rms_2sigmoid": an RMS norm
+    # whose gain is ``2 sigmoid(w)`` (1 at w = 0; the tree keeps the raw
+    # ``w``). ``post_norm``: every sub-block's branch is normed AGAIN before
+    # it joins the stream, x + N(f(N(x))).
     norm_kind: str = "rms"
     layer_norm_eps: float = 1e-5
+    post_norm: bool = False
+    # Layers in FRONT of the periods (``n_layers`` counts them): each a
+    # linear-attention mixer and a dense gated MLP of width ``d_ff``.
+    leading_dense: int = 0
+    # The linear layer's kind. "kda": as many key heads as value heads, a
+    # decay per channel through a rank-``lin_gate_rank`` pair, b = 2 sigmoid,
+    # an output gate sigmoid(pair). "gated_delta": ``lin_key_heads`` key
+    # heads, each serving ``lin_heads / lin_key_heads`` value heads; ONE
+    # decay a value head, exp(-exp(A) softplus(W_a h + dt)); b = sigmoid;
+    # the output gate ``2 sigmoid(h W_z)`` at full width.
+    lin_kind: str = "kda"
+    lin_key_heads: int = 0         # 0: as many as value heads (lin_heads)
+    # The period families' router: the selected weights are multiplied by
+    # ``routed_scale``; with ``router_bias`` the top-k is taken of score +
+    # e (a bias an expert, selection only: the weights stay the scores).
+    routed_scale: float = 1.0
+    router_bias: bool = False
+    # > 0: every gated MLP is (act(min(g, L)) * clip(u, -L, L)) W_d.
+    swiglu_limit: float = 0.0
 
     def __post_init__(self):
         for name in ("window_layout", "rope_layout"):
@@ -180,8 +199,13 @@ class ModelConfig:
             raise ValueError(f"unknown moe_router {self.moe_router!r}")
         if self.moe_act not in ("silu", "relu"):
             raise ValueError(f"unknown moe_act {self.moe_act!r}")
-        if self.norm_kind not in ("rms", "layernorm"):
+        if self.norm_kind not in ("rms", "layernorm", "rms_2sigmoid"):
             raise ValueError(f"unknown norm_kind {self.norm_kind!r}")
+        if self.lin_kind not in ("kda", "gated_delta"):
+            raise ValueError(f"unknown lin_kind {self.lin_kind!r}")
+        if self.leading_dense and not self.lin_heads:
+            raise ValueError("leading_dense layers carry a linear mixer: "
+                             "they need lin_heads")
 
     @property
     def head_dim(self) -> int:
@@ -239,16 +263,34 @@ class ModelConfig:
         return tuple((w, tuple(ps)) for w, ps in groups.items())
 
     @property
+    def n_periods(self) -> int:
+        """Whole periods behind the ``leading_dense`` layers (0: the
+        family has no periods)."""
+        return ((self.n_layers - self.leading_dense) // self.layer_period
+                if self.layer_period else 0)
+
+    @property
+    def lin_kheads(self) -> int:
+        """Key (and query) heads of a linear layer."""
+        return self.lin_key_heads or self.lin_heads
+
+    @property
+    def lin_conv_width(self) -> int:
+        """Channels of a linear layer's convolution: q, k and v side by
+        side."""
+        return (2 * self.lin_kheads + self.lin_heads) * self.lin_head_dim
+
+    @property
     def n_kv_layers(self) -> int:
-        """Layers that keep paged KV: all, or the softmax positions of
-        every period."""
-        return (self.n_layers // self.layer_period
-                * len(self.softmax_positions) if self.layer_period
-                else self.n_layers)
+        """Layers that keep paged KV (or a latent row): all, or the
+        softmax positions of every period."""
+        return (self.n_periods * len(self.softmax_positions)
+                if self.layer_period else self.n_layers)
 
     @property
     def n_lin_layers(self) -> int:
-        """Layers that keep a recurrent state block per slot."""
+        """Layers that keep a recurrent state block per slot (the leading
+        layers among them)."""
         return self.n_layers - self.n_kv_layers
 
 
@@ -457,6 +499,61 @@ PRESETS["tiny-cohere2-test"] = replace(
 PRESETS["command-a-plus-218b-ep8"] = replace(
     PRESETS["command-a-plus"], n_layers=8, vocab_size=32768,
     n_experts_held=16)
+
+
+
+# GigaChat3.5-432B-A28B (HF: ai-sage/GigaChat3.5-432B-A28B, ``gigachat3_5``)
+# at its PUBLISHED sizes: 40 layers — three leading layers of a gated-delta-
+# net mixer and a dense SwiGLU MLP of 18,432, then periods of one LATENT
+# attention layer (64 heads, 1536 / 512 bottlenecks, 128 + 64 query/key and
+# 128 value numbers, YaRN x8 over 32,768, interleaved pairs, its output gated
+# by sigmoid(W_g x)) and three gated-delta-net layers (32 key heads serving
+# 64 value heads of 128, conv 4, one decay a head), each with 256 sigmoid-
+# routed experts of width 2048 (top-8 of score + bias, weights x 2.5) beside
+# one shared expert. Every sub-block is normed before and after by an RMS
+# norm of gain 2 sigmoid(w); every gated MLP is clamped at 10. One latent
+# cache group of ``n_periods`` layers beside ``leading_dense + 3 n_periods``
+# state blocks a slot. (The published latent layers are 3, 7, ..., 39:
+# behind the three leading layers the pattern IS [latent, linear x 3] from
+# layer 3 on, and the 40th layer is a tenth latent layer with no linear
+# layers behind it — which the whole-period scan does not build: the
+# published preset is a table of sizes, a deployment states whole periods.)
+# The two next-token-prediction modules are not served.
+PRESETS["gigachat35-432b"] = ModelConfig(
+    family="gigachat3_5", vocab_size=128256, d_model=7168, n_layers=40,
+    n_heads=64, n_kv_heads=64, head_dim_override=128, d_ff=18432,
+    rope_theta=100000.0, rms_eps=1e-6, max_seq_len=262144,
+    rope_scaling=RopeScaling(rope_type="yarn", factor=8.0,
+                             original_max_seq=32768, beta_fast=32.0,
+                             beta_slow=1.0, mscale=1.0, mscale_all_dim=1.0),
+    n_experts=256, experts_per_token=8, d_ff_expert=2048, n_shared_experts=1,
+    layer_period=4, lin_heads=64, lin_head_dim=128, lin_conv_taps=4,
+    lin_kind="gated_delta", lin_key_heads=32, attn_gate=True,
+    moe_router="sigmoid", q_lora_rank=1536, kv_lora_rank=512,
+    qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+    rope_interleave=True, norm_kind="rms_2sigmoid", post_norm=True,
+    leading_dense=3, routed_scale=2.5, router_bias=True, swiglu_limit=10.0)
+# The same pattern at CPU-test size: two leading layers, two periods, 16
+# experts top-4, two key heads serving four value heads; YaRN x8 over 32.
+PRESETS["tiny-gigachat35-test"] = replace(
+    PRESETS["gigachat35-432b"], vocab_size=512, d_model=64, n_layers=10,
+    n_heads=4, n_kv_heads=4, head_dim_override=16, d_ff=96, max_seq_len=256,
+    rope_theta=10000.0,
+    rope_scaling=RopeScaling(rope_type="yarn", factor=8.0,
+                             original_max_seq=32, beta_fast=4.0,
+                             beta_slow=1.0, mscale=1.0, mscale_all_dim=1.0),
+    n_experts=16, experts_per_token=4, d_ff_expert=32, lin_heads=4,
+    lin_head_dim=16, lin_key_heads=2, q_lora_rank=32, kv_lora_rank=32,
+    qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=16, leading_dense=2,
+    swiglu_limit=1.0)
+# What ONE v5e chip holds of it as one of 8 that share each layer of the
+# FIRST of 7 pipeline stages (benchmark/configs/gigachat35-432b-ep8.json):
+# the three leading layers and one whole period, 32 of the 256 experts, an
+# eighth of the vocabulary rows. Every width, the router's 256 outputs and
+# its 8 experts per token stay.
+PRESETS["gigachat35-432b-ep8"] = replace(
+    PRESETS["gigachat35-432b"], n_layers=7, vocab_size=16032,
+    n_experts_held=32)
 
 
 def get_preset(name: str) -> ModelConfig:

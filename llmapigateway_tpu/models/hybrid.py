@@ -1,10 +1,24 @@
-"""The "hybrid" family (Solar-Open2): layers of two kinds in one forward,
-and an expert layer that holds a share of its experts.
+"""The PERIOD families: layers of several kinds in one forward, and an
+expert layer that holds a share of its experts. ONE scanned body serves
+five shapes of period (``ModelConfig``, one family each):
 
-A period of ``layer_period`` layers is one softmax layer — grouped-query
-attention with no rotary embedding, its output gated by ``sigmoid(W x)`` —
-followed by linear-attention layers: a gated delta rule with a per-channel
-decay. Per head the linear layer keeps a state ``S`` [dk, dv]::
+* "hybrid" (Solar-Open2): one gated NoPE softmax layer, then linear-attention
+  layers — K/V pages beside recurrent state;
+* "smallthinker": softmax layers ONLY, in two cache groups (a global NoPE
+  layer, then rotary layers inside a window);
+* "mistral4": ONE latent-attention layer — a latent pool, no state;
+* "cohere2_moe" (Command A+): softmax layers in PARALLEL blocks, the ring
+  the first cache group, a tied head;
+* "gigachat3_5": one LATENT layer, then linear-attention layers of the
+  gated-delta kind — a latent pool AND recurrent state in one slot —
+  behind ``leading_dense`` layers of their own (a linear mixer and a dense
+  MLP), every sub-block normed before and after.
+
+The first of them, in full. A period of ``layer_period`` layers is one
+softmax layer — grouped-query attention with no rotary embedding, its
+output gated by ``sigmoid(W x)`` — followed by linear-attention layers: a
+gated delta rule with a per-channel decay. Per head the linear layer keeps
+a state ``S`` [dk, dv]::
 
     S_t = (I - b_t k_t k_t^T) diag(a_t) S_{t-1} + b_t k_t v_t^T
     o_t = S_t^T q_t
@@ -27,11 +41,26 @@ grouped by the KV they must keep (``cache_groups``: one page pool, page
 table and provider a group), the router may score by softmax and read the
 block's input, and the experts' gate may be a ReLU.
 
-And it serves a period of ONE latent-attention layer (``ModelConfig.is_mla``;
-Mistral-Small-4): the attention sub-block is ``models/mla.py``'s, its cache
-group a latent pool (``HybridCache.k`` holds it, ``v`` is empty) that BOTH
-step programs carry through the scan and write in place, and the expert
-layer is the one above with a softmax router and a shared expert.
+And it serves a period whose position 0 is a latent-attention layer
+(``ModelConfig.is_mla``): the attention sub-block is ``models/mla.py``'s,
+its cache group a latent pool (``HybridCache.k`` holds it, ``v`` is empty)
+that BOTH step programs carry through the scan and write in place, and the
+expert layer is the one above. The period is that layer ALONE
+(Mistral-Small-4: a softmax router, no state), or that layer and
+linear-attention layers behind it (GigaChat 3.5): then the pool is carried
+while the state blocks and conv tails are gathered and scattered by slot as
+in the first family. There the linear layer is ``lin_kind`` "gated_delta":
+fewer key heads than value heads, ONE decay a value head (broadcast into
+the same three forms of the rule), ``b = sigmoid``, the output gated by
+``2 sigmoid(h W_z)`` at full width; ``leading_dense`` layers of that mixer
+and a dense gated MLP run in FRONT of the periods, one scanned body over a
+stacked tree of their own (``params["lead"]``; their state is the last
+entry of the cache's tuples); the norm's gain is ``2 sigmoid(w)`` and a
+sub-block's branch is normed again before it joins the stream
+(``post_norm``); the router selects by score + bias and scales its weights
+(``router_bias``, ``routed_scale``); every gated MLP is clamped
+(``swiglu_limit``). Each of those is a trace-time branch: a family that
+states none computes what it computed.
 
 Those blocks are SEQUENTIAL: a norm, the attention (or linear) sub-block,
 an add; another norm, the expert layer, another add. A period of softmax
@@ -89,8 +118,8 @@ import jax.numpy as jnp
 
 from . import mla
 from .config import ModelConfig
-from .llama import (_GATE_ACTS, _select_head, apply_rope, rms_norm,
-                    rope_tables, swiglu_mlp)
+from .llama import (_select_head, apply_rope, block_norm, gated_hidden,
+                    layer_norm, rms_norm, rope_tables, swiglu_mlp)
 from ..ops.grouped_experts import grouped_experts, rows_that_fit
 from .quant import (_dynamic_int8, head_matmul, is_quantized, mm,
                     moe_mm_batched, quantize_array, weight_bits)
@@ -106,10 +135,10 @@ N_COUNTERS = 5          # HybridCache.counters, moe_block's counted vector
 EXPERT_KEYS = ("wg", "wu", "wd")    # the routed experts' matrices
 # Stored int8 under quant (contraction axis second to last): the projections
 # of both layer kinds, the softmax gate, routed and shared experts. NOT the
-# router, the rank-r gate pairs, W_b, the conv taps, A, the bias or the
-# norms: small, and they decide routing and decay.
-QUANT_KEYS = frozenset({"wq", "wk", "wv", "wo", "wgate", "sg", "su", "sd",
-                        *EXPERT_KEYS})
+# router and its bias, the rank-r gate pairs, W_a, W_b, the conv taps, A,
+# the decay's bias or the norms: small, and they decide routing and decay.
+QUANT_KEYS = frozenset({"wq", "wk", "wv", "wz", "wo", "wgate", "sg", "su",
+                        "sd", *EXPERT_KEYS})
 
 
 class HybridCache(NamedTuple):
@@ -122,7 +151,9 @@ class HybridCache(NamedTuple):
     ``state`` and ``conv``: the linear layers' recurrent state and the
     last inputs of their convolutions, one fixed block per slot — a tuple
     over a period's linear layers of [P, B, H, dk, dv] float32 and of
-    [P, B, taps-1, 3*H*dk]. ``counters``
+    [P, B, taps-1, ``lin_conv_width``], and LAST, where the family has
+    ``leading_dense`` layers, theirs stacked the same way ([leading, B,
+    ...]). A family may hold a latent pool AND state. ``counters``
     int32 [``N_COUNTERS``], running totals that wrap: of the DECODE steps
     so far, the routed assignments (all; landing on a held expert) and,
     summed over layers, the held experts that at least one active row was
@@ -145,27 +176,28 @@ class HybridCache(NamedTuple):
         c = config
         if isinstance(num_pages, int):
             num_pages = (num_pages,) * len(c.cache_groups)
-        periods = c.n_layers // c.layer_period
+        periods = c.n_periods
         if c.is_mla:
             from ..ops.latent_attention import create_latent_pool
-            return cls(k=(create_latent_pool(
-                c.n_layers, num_pages[0], page_size, c.latent_width, dtype),),
-                v=(), state=(), conv=(),
-                counters=jnp.zeros((N_COUNTERS,), jnp.int32))
-        pools = [PagedKVCache.create(
-            replace(c, n_layers=periods * len(positions)), pages, page_size,
-            dtype, kv_quant)
-            for (_, positions), pages in zip(c.cache_groups, num_pages)]
-        lead = (periods, batch)
-        lin = range(c.layer_period - len(c.softmax_positions))
+            k, v = (create_latent_pool(c.n_kv_layers, num_pages[0], page_size,
+                                       c.latent_width, dtype),), ()
+        else:
+            pools = [PagedKVCache.create(
+                replace(c, n_layers=periods * len(positions)), pages,
+                page_size, dtype, kv_quant)
+                for (_, positions), pages in zip(c.cache_groups, num_pages)]
+            k, v = tuple(p.k for p in pools), tuple(p.v for p in pools)
+        # A stack of blocks a linear position of a period, then the
+        # leading layers' stack.
+        stacks = [periods] * (c.layer_period - len(c.softmax_positions))
+        stacks += [c.leading_dense] if c.leading_dense else []
         return cls(
-            k=tuple(p.k for p in pools), v=tuple(p.v for p in pools),
-            state=tuple(jnp.zeros(lead + (c.lin_heads, c.lin_head_dim,
-                                          c.lin_head_dim), jnp.float32)
-                        for _ in lin),
-            conv=tuple(jnp.zeros(lead + (c.lin_conv_taps - 1,
-                                         3 * c.lin_heads * c.lin_head_dim),
-                                 dtype) for _ in lin),
+            k=k, v=v,
+            state=tuple(jnp.zeros((n, batch, c.lin_heads, c.lin_head_dim,
+                                   c.lin_head_dim), jnp.float32)
+                        for n in stacks),
+            conv=tuple(jnp.zeros((n, batch, c.lin_conv_taps - 1,
+                                  c.lin_conv_width), dtype) for n in stacks),
             counters=jnp.zeros((N_COUNTERS,), jnp.int32))
 
 
@@ -197,6 +229,15 @@ def init_params(config: ModelConfig, key: jax.Array,
                   out_norm [P,dk], wo [P,Hl*dk,D], mlp/...}
       layers/attn of a latent layer: models/mla.py ``init_layer``'s tree,
                    stacked [P, ...], with its mlp/...
+      layers/lin of ``lin_kind`` "gated_delta" (Hk key heads, Hv value
+                  heads): {norm, wq, wk [P,D,Hk*dk], wv, wz [P,D,Hv*dk],
+                  conv [P,taps,(2Hk+Hv)*dk], wa, wbeta [P,D,Hv],
+                  dt_bias, a_log [P,Hv], out_norm [P,dk], wo, mlp/...}
+      lead/{the linear layer's keys, mlp/{norm [L,D], wg, wu [L,D,d_ff],
+            wd [L,d_ff,D]}} stacked over the ``leading_dense`` layers
+      ``post_norm`` [.., D] beside every ``norm`` under
+                  ``ModelConfig.post_norm``; mlp/router_bias [P,E] float32
+                  under ``router_bias``
       .../mlp/{norm [P,D], router [P,D,E], wg, wu [P,held,D,F],
                wd [P,held,F,D], sg, su [P,D,Fs], sd [P,Fs,D]}
                (``sg``, ``su``, ``sd`` with shared experts only: the shared
@@ -212,6 +253,22 @@ def init_params(config: ModelConfig, key: jax.Array,
     lies in 0.9-0.999 (``a_log = log U(1,8)``, ``softplus(f_bias)`` log-
     uniform in 0.001-0.05, a small ``wf_up``): with unit-scale gates the
     layer would forget in a token and no comparison could see its state.
+    A norm is drawn at gain 1 (raw weight 0 where the gain is ``2
+    sigmoid(w)``); a POST norm at the gain ``(2 n_layers)^-1/2 / 2``, for
+    it sets its branch's size whatever ``wo`` is drawn at — and HALF the
+    convention's scale is the size a branch has in the families without
+    one, where a unit input leaves a sigmoid gate or a SiLU-gated product
+    at an rms of 0.54 before its ``wo`` (a post norm at the full scale
+    doubles every branch, and with it what a rounded stream or a flipped
+    eighth expert moves a logit by). A router's selection bias is drawn
+    N(0, 0.1^2): enough to change a token's eighth expert. Where the family
+    clamps its gated MLPs (``swiglu_limit`` L), their gate and up matrices
+    are drawn at L / 1.25 of the usual scale when that is more than 1, so
+    that the clamp bites at 1.25 standard deviations — a fifth of the up
+    products, a tenth of the gates — and a comparison can see it (the post
+    norm takes the scale out again; at 2.5 standard deviations, one product
+    in a hundred, an un-clamped reference read like the sound one on the
+    chip: PERF.md section 6, PR 46).
     Under a TIED head the final norm's gain is drawn as random SIGNS at
     ``D^-1/2``. The magnitude brings the unit-variance embedding's logits
     to the unit variance an untied head's rows are drawn for. The signs
@@ -224,23 +281,39 @@ def init_params(config: ModelConfig, key: jax.Array,
     number (a trained model's learned gain and moved stream do the same).
     """
     c = config
-    if not c.layer_period or c.n_layers % c.layer_period:
+    if (not c.layer_period
+            or (c.n_layers - c.leading_dense) % c.layer_period):
         raise ValueError("hybrid.init_params needs a layer_period and "
-                         "whole periods of layers")
-    if c.lin_heads and (c.use_rope or not c.attn_gate):
+                         "whole periods of layers behind the leading ones")
+    if c.lin_heads and not c.is_mla and (c.use_rope or not c.attn_gate):
         raise ValueError("the hybrid family's softmax layers carry no "
                          "rotary embedding and gate their output: use_rope "
                          "must be False, attn_gate True")
     if c.parallel_block and (c.lin_heads or c.is_mla):
         raise ValueError("a parallel block is a period of softmax layers: "
                          "no linear-attention and no latent layer has one")
-    per, P = c.layer_period, c.n_layers // c.layer_period
+    per, P = c.layer_period, c.n_periods
     n_soft = len(c.softmax_positions)
     D, dh, dk, Hl, r = (c.d_model, c.head_dim, c.lin_head_dim, c.lin_heads,
                         c.lin_gate_rank)
+    Hk = c.lin_kheads
     F, held = c.d_ff_expert, c.experts_held
     Fs = c.n_shared_experts * F
     back = (2 * c.n_layers) ** -0.5     # projections into the residual
+    two_sigmoid = c.norm_kind == "rms_2sigmoid"
+    gated = max(1.0, c.swiglu_limit / 1.25)     # gate and up matrices
+
+    def norms():
+        """A sub-block's norm at gain 1 and, where it has one, its post
+        norm at gain ``back / 2`` (as raw weights of ``2 sigmoid(w)``: 0,
+        and the logit of a quarter of ``back``)."""
+        gain = back / 2.0
+        unit, post = (0.0, math.log(gain / (2.0 - gain))) if two_sigmoid \
+            else (1.0, gain)
+        out = {"norm": jnp.full((D,), unit, dtype)}
+        if c.post_norm:
+            out["post_norm"] = jnp.full((D,), post, dtype)
+        return out
 
     def dense(k, *shape, scale=1.0, name=""):
         w = (jax.random.normal(k, shape, jnp.float32)
@@ -255,56 +328,74 @@ def init_params(config: ModelConfig, key: jax.Array,
 
         def expert(ke):
             kg, ku, kd = jax.random.split(ke, 3)
-            return {"wg": dense(kg, D, F, name="wg"),
-                    "wu": dense(ku, D, F, name="wu"),
+            return {"wg": dense(kg, D, F, scale=gated, name="wg"),
+                    "wu": dense(ku, D, F, scale=gated, name="wu"),
                     "wd": dense(kd, F, D, scale=back, name="wd")}
         out = jax.lax.map(expert, jax.random.split(ks[0], held))
         if not c.parallel_block:        # its one norm is the layer's
-            out.update(norm=jnp.ones((D,), dtype))
+            out.update(norms())
         out.update(router=dense(ks[1], D, c.n_experts))
+        if c.router_bias:
+            out.update(router_bias=0.1 * jax.random.normal(
+                ks[5], (c.n_experts,), jnp.float32))
         if Fs:
-            out.update(sg=dense(ks[2], D, Fs, name="sg"),
-                       su=dense(ks[3], D, Fs, name="su"),
+            out.update(sg=dense(ks[2], D, Fs, scale=gated, name="sg"),
+                       su=dense(ks[3], D, Fs, scale=gated, name="su"),
                        sd=dense(ks[4], Fs, D, scale=back, name="sd"))
         return out
 
     def attn_layer(k):
         ks = jax.random.split(k, 6)
         if c.is_mla:
-            return {**mla.init_layer(c, ks[:5], dense, dtype),
+            return {**mla.init_layer(c, ks[:5], dense, dtype), **norms(),
                     "mlp": mlp(ks[5])}
         gate = {"wgate": dense(ks[3], D, c.n_heads * dh, name="wgate")
                 } if c.attn_gate else {}
-        return {"norm": jnp.ones((D,), dtype), **gate,
+        return {**norms(), **gate,
                 "wq": dense(ks[0], D, c.n_heads * dh, name="wq"),
                 "wk": dense(ks[1], D, c.n_kv_heads * dh, name="wk"),
                 "wv": dense(ks[2], D, c.n_kv_heads * dh, name="wv"),
                 "wo": dense(ks[4], c.n_heads * dh, D, scale=back, name="wo"),
                 "mlp": mlp(ks[5])}
 
-    def lin_layer(k):
+    def lin_layer(k, mlp=mlp):
         ks = jax.random.split(k, 13)
+        per_head = c.lin_kind == "gated_delta"     # one decay a value head
         step = jnp.exp(jax.random.uniform(
-            ks[8], (Hl * dk,), jnp.float32, math.log(1e-3), math.log(5e-2)))
-        return {"norm": jnp.ones((D,), dtype),
-                "wq": dense(ks[0], D, Hl * dk, name="wq"),
-                "wk": dense(ks[1], D, Hl * dk, name="wk"),
-                "wv": dense(ks[2], D, Hl * dk, name="wv"),
-                "conv": jax.random.normal(
-                    ks[3], (c.lin_conv_taps, 3 * Hl * dk), jnp.float32)
-                / math.sqrt(c.lin_conv_taps),
+            ks[8], (Hl if per_head else Hl * dk,), jnp.float32,
+            math.log(1e-3), math.log(5e-2)))
+        shared = {**norms(),
+                  "wq": dense(ks[0], D, Hk * dk, name="wq"),
+                  "wk": dense(ks[1], D, Hk * dk, name="wk"),
+                  "wv": dense(ks[2], D, Hl * dk, name="wv"),
+                  "conv": jax.random.normal(
+                      ks[3], (c.lin_conv_taps, c.lin_conv_width), jnp.float32)
+                  / math.sqrt(c.lin_conv_taps),
+                  "a_log": jnp.log(jax.random.uniform(
+                      ks[7], (Hl,), jnp.float32, 1.0, 8.0)),
+                  "wbeta": dense(ks[6], D, Hl),
+                  "out_norm": jnp.ones((dk,), dtype),
+                  "wo": dense(ks[11], Hl * dk, D, scale=back, name="wo"),
+                  "mlp": mlp(ks[12])}
+        if per_head:
+            return {**shared,
+                    "wz": dense(ks[9], D, Hl * dk, name="wz"),
+                    "wa": dense(ks[4], D, Hl, scale=0.5),
+                    # softplus(dt_bias) = step
+                    "dt_bias": jnp.log(jnp.expm1(step))}
+        return {**shared,
                 "wf_down": dense(ks[4], D, r),
                 "wf_up": dense(ks[5], r, Hl * dk, scale=0.5),
                 # softplus(f_bias) = step
                 "f_bias": jnp.log(jnp.expm1(step)),
-                "a_log": jnp.log(jax.random.uniform(
-                    ks[7], (Hl,), jnp.float32, 1.0, 8.0)),
-                "wbeta": dense(ks[6], D, Hl),
                 "wg_down": dense(ks[9], D, r),
-                "wg_up": dense(ks[10], r, Hl * dk),
-                "out_norm": jnp.ones((dk,), dtype),
-                "wo": dense(ks[11], Hl * dk, D, scale=back, name="wo"),
-                "mlp": mlp(ks[12])}
+                "wg_up": dense(ks[10], r, Hl * dk)}
+
+    def dense_mlp(k):
+        kg, ku, kd = jax.random.split(k, 3)
+        return {**norms(), "wg": dense(kg, D, c.d_ff, scale=gated, name="wg"),
+                "wu": dense(ku, D, c.d_ff, scale=gated, name="wu"),
+                "wd": dense(kd, c.d_ff, D, scale=back, name="wd")}
 
     def period(k):
         ks = list(jax.random.split(k, per))
@@ -329,6 +420,12 @@ def init_params(config: ModelConfig, key: jax.Array,
         final_norm = jnp.ones((D,), dtype)
         top = {"lm_head": (quantize_array(
             head, 1, bits=weight_bits(quant, "lm_head")) if quant else head)}
+    if two_sigmoid:
+        final_norm = jnp.zeros((D,), dtype)
+    if c.leading_dense:
+        top["lead"] = jax.lax.map(
+            partial(lin_layer, mlp=dense_mlp), jax.random.split(
+                jax.random.fold_in(k_layers, 1), c.leading_dense))
     return {"embed": embed, "final_norm": final_norm, **top,
             "layers": jax.lax.map(period, jax.random.split(k_layers, P))}
 
@@ -342,7 +439,8 @@ def kda_recurrent(q, k, v, log_a, beta, s0):
     ``kda_decode_update`` are held to, and what ``kda_chunked`` runs for a
     call that is not whole sub-chunks.
     q, k [B,T,H,dk] (normalised, q scaled), v [B,T,H,dv], log_a [B,T,H,dk]
-    (<= 0), beta [B,T,H], s0 [B,H,dk,dv]; all float32.
+    (<= 0; [B,T,H,1]: ONE decay a head, broadcast over its channels in
+    all three forms), beta [B,T,H], s0 [B,H,dk,dv]; all float32.
     Returns (o [B,T,H,dv], s_T)."""
     def step(s, x):
         o, s = kda_decode_update(*x, s)
@@ -416,8 +514,8 @@ def kda_chunked(q, k, v, log_a, beta, s0, chunk: int = KDA_CHUNK,
     def step(s, x):
         with jax.named_scope("kda.prefill_chunk"):
             qc, kc, vc, lc, bc = x                      # [B,H,C,..]
-            g = jnp.cumsum(lc, axis=2)                  # [B,H,C,dk]
-            gb = g.reshape(B, H, nb, bl, dk)
+            g = jnp.cumsum(lc, axis=2)                  # [B,H,C,dk or 1]
+            gb = g.reshape(B, H, nb, bl, g.shape[-1])
             qb, kb = (a.reshape(B, H, nb, bl, dk) for a in (qc, kc))
             bb = bc.reshape(B, H, nb, bl)
             # Across blocks: split at ref_i = g just before block i.
@@ -483,30 +581,49 @@ def _l2norm(x: jax.Array) -> jax.Array:
 
 def linear_block(h, lp, c: ModelConfig, s0, tail, n_valid, keep):
     """One linear-attention layer on normalised input ``h`` [B,T,D].
-    ``s0`` [B,H,dk,dv] and ``tail`` [B,taps-1,3*H*dk]: the rows' state on
-    entry. ``n_valid`` [B] (prefill): tokens past it are padding and move
-    neither the state nor the tail (b = 0, a = 1, the tail taken at the
-    true length). ``keep`` [B] bool (decode): rows that are False leave
-    with the state and the tail they came with. Returns (out [B,T,H*dk],
-    state, tail)."""
+    ``s0`` [B,H,dk,dv] and ``tail`` [B,taps-1,``lin_conv_width``]: the
+    rows' state on entry. ``n_valid`` [B] (prefill): tokens past it are
+    padding and move neither the state nor the tail (b = 0, a = 1, the
+    tail taken at the true length). ``keep`` [B] bool (decode): rows that
+    are False leave with the state and the tail they came with. Returns
+    (out [B,T,D], state, tail).
+
+    ``lin_kind`` "kda": H key heads, a decay a channel through a low-rank
+    pair, b in (0, 2), the output gated by sigmoid(low-rank pair).
+    "gated_delta": ``lin_kheads`` key heads, key head ``j`` serving value
+    heads ``j H/Hk ..``; ONE decay a value head (``log_a`` [B,T,H,1],
+    broadcast inside the same three forms of the rule), b in (0, 1), the
+    output gated by ``2 sigmoid(h W_z)`` at full width."""
     B, T, _ = h.shape
-    H, dk, taps = c.lin_heads, c.lin_head_dim, c.lin_conv_taps
+    H, Hk, dk = c.lin_heads, c.lin_kheads, c.lin_head_dim
+    taps = c.lin_conv_taps
     f32 = jnp.float32
     x = jnp.concatenate([mm(h, lp["wq"]), mm(h, lp["wk"]), mm(h, lp["wv"])],
-                        axis=-1)                                # [B,T,3*H*dk]
+                        axis=-1)                    # [B,T,(2Hk+H)*dk]
     x_ext = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
-    qkv = _conv_silu(x_ext, lp["conv"]).reshape(B, T, 3, H, dk)
-    q = _l2norm(qkv[:, :, 0]) * dk ** -0.5
-    k, v = _l2norm(qkv[:, :, 1]), qkv[:, :, 2]
+    qkv = _conv_silu(x_ext, lp["conv"])
+    q, k, v = (a.reshape(B, T, -1, dk) for a in jnp.split(
+        qkv, (Hk * dk, 2 * Hk * dk), axis=-1))
+    q, k = _l2norm(q) * dk ** -0.5, _l2norm(k)
+    if Hk != H:
+        q, k = (jnp.repeat(a, H // Hk, axis=2) for a in (q, k))
     low = partial(jnp.einsum, preferred_element_type=f32)
-    z = low("btr,rf->btf", low("btd,dr->btr", h, lp["wf_down"]).astype(h.dtype),
-            lp["wf_up"]) + lp["f_bias"].astype(f32)
-    log_a = (-jnp.exp(lp["a_log"].astype(f32))[:, None]
-             * jax.nn.softplus(z.reshape(B, T, H, dk)))
-    beta = 2.0 * jax.nn.sigmoid(low("btd,dh->bth", h, lp["wbeta"]))
-    gate = jax.nn.sigmoid(
-        low("btr,rf->btf", low("btd,dr->btr", h, lp["wg_down"]).astype(h.dtype),
-            lp["wg_up"]))
+    rate = -jnp.exp(lp["a_log"].astype(f32))[:, None]               # [H,1]
+    if c.lin_kind == "gated_delta":
+        z = low("btd,dh->bth", h, lp["wa"]) + lp["dt_bias"].astype(f32)
+        log_a = rate * jax.nn.softplus(z)[..., None]                # [B,T,H,1]
+        beta = jax.nn.sigmoid(low("btd,dh->bth", h, lp["wbeta"]))
+        gate = 2.0 * jax.nn.sigmoid(mm(h, lp["wz"]).astype(f32))
+    else:
+        z = low("btr,rf->btf",
+                low("btd,dr->btr", h, lp["wf_down"]).astype(h.dtype),
+                lp["wf_up"]) + lp["f_bias"].astype(f32)
+        log_a = rate * jax.nn.softplus(z.reshape(B, T, H, dk))
+        beta = 2.0 * jax.nn.sigmoid(low("btd,dh->bth", h, lp["wbeta"]))
+        gate = jax.nn.sigmoid(
+            low("btr,rf->btf",
+                low("btd,dr->btr", h, lp["wg_down"]).astype(h.dtype),
+                lp["wg_up"]))
     if T == 1 and keep is not None:
         o, s = kda_decode_update(q[:, 0], k[:, 0], v[:, 0], log_a[:, 0],
                                  beta[:, 0], s0)
@@ -526,45 +643,31 @@ def linear_block(h, lp, c: ModelConfig, s0, tail, n_valid, keep):
 
 
 # ---------------------------------------------------------------------------
-# The block's norm
-# ---------------------------------------------------------------------------
-
-def layer_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
-    """LayerNorm without bias, float32 inside: ``w (x - mean) / sqrt(var +
-    eps)`` over the last axis, in ``x``'s dtype."""
-    xf = x.astype(jnp.float32)
-    xc = xf - jnp.mean(xf, axis=-1, keepdims=True)
-    normed = xc * jax.lax.rsqrt(jnp.mean(xc * xc, axis=-1, keepdims=True)
-                                + eps)
-    return (normed * weight.astype(jnp.float32)).astype(x.dtype)
-
-
-def block_norm(x: jax.Array, weight: jax.Array, c: ModelConfig) -> jax.Array:
-    """The norm of ``ModelConfig.norm_kind`` on a block's (or the head's)
-    input."""
-    if c.norm_kind == "layernorm":
-        return layer_norm(x, weight, c.layer_norm_eps)
-    return rms_norm(x, weight, c.rms_eps)
-
-
-# ---------------------------------------------------------------------------
 # The expert layer
 # ---------------------------------------------------------------------------
 
-def route(hf: jax.Array, router: jax.Array, c: ModelConfig
-          ) -> tuple[jax.Array, jax.Array]:
+def route(hf: jax.Array, router: jax.Array, c: ModelConfig,
+          bias: jax.Array | None = None) -> tuple[jax.Array, jax.Array]:
     """hf [N,D] float32 (un-quantised) -> the top-k experts of ALL
-    ``n_experts``, [N,k] ids and weights that sum to 1 (float32,
-    full-precision product: a rounded score flips the 8th and 9th
-    expert). ``moe_router`` "sigmoid": by sigmoid score, the selected
-    scores normalised; "softmax": by logit, softmax over the selected (=
-    softmax over all, renormalised on the selected)."""
+    ``n_experts``, [N,k] ids and weights that sum to ``routed_scale``
+    (float32, full-precision product: a rounded score flips the 8th and
+    9th expert). ``moe_router`` "sigmoid": by sigmoid score — plus
+    ``bias`` [E] where the router has one, for the SELECTION only —, the
+    selected scores normalised; "softmax": by logit, softmax over the
+    selected (= softmax over all, renormalised on the selected)."""
     logits = jnp.dot(hf, router.astype(jnp.float32), precision=HIGHEST)
     if c.moe_router == "softmax":
         top, idx = jax.lax.top_k(logits, c.experts_per_token)
-        return idx, jax.nn.softmax(top, axis=-1)
-    top, idx = jax.lax.top_k(jax.nn.sigmoid(logits), c.experts_per_token)
-    return idx, top / jnp.sum(top, axis=-1, keepdims=True)
+        w = jax.nn.softmax(top, axis=-1)
+    else:
+        scores = jax.nn.sigmoid(logits)
+        if bias is None:
+            top, idx = jax.lax.top_k(scores, c.experts_per_token)
+        else:
+            _, idx = jax.lax.top_k(scores + bias, c.experts_per_token)
+            top = jnp.take_along_axis(scores, idx, axis=-1)
+        w = top / jnp.sum(top, axis=-1, keepdims=True)
+    return idx, w if c.routed_scale == 1.0 else w * c.routed_scale
 
 
 def held_weights(idx: jax.Array, w: jax.Array, c: ModelConfig) -> jax.Array:
@@ -585,19 +688,20 @@ def _at(tree: Any, i: jax.Array | None) -> Any:
 
 
 def experts_dense(x: jax.Array, probs: jax.Array, lp: Params,
-                  period: jax.Array | None = None, act: str = "silu"
-                  ) -> jax.Array:
+                  period: jax.Array | None = None, act: str = "silu",
+                  limit: float = 0.0) -> jax.Array:
     """Every held expert on every token. x [N,D], probs [N,held] -> [N,D]
     float32. With ``period`` the experts' matrices are stacked over
-    periods and read at that index. ``act``: the gate's activation."""
+    periods and read at that index. ``act``: the gate's activation;
+    ``limit``: its clamp (``llama.gated_hidden``)."""
     lp = _at({k: lp[k] for k in EXPERT_KEYS}, period)
     # The expert axis is a BATCH axis of all three products, so the weights
     # are read where they lie ([held, D, F]: a product that contracts D
     # with the experts as a free axis has them re-laid out first, a copy of
     # every expert's weights a step).
     xe = jnp.broadcast_to(x, (probs.shape[1], *x.shape))
-    hid = (_GATE_ACTS[act](moe_mm_batched(xe, lp["wg"]))
-           * moe_mm_batched(xe, lp["wu"]))
+    hid = gated_hidden(act, moe_mm_batched(xe, lp["wg"]),
+                       moe_mm_batched(xe, lp["wu"]), limit)
     y = moe_mm_batched(hid, lp["wd"])                       # [held,N,D]
     return jnp.einsum("end,ne->nd", y.astype(jnp.float32), probs)
 
@@ -645,8 +749,8 @@ def grouped_layout(idx: jax.Array, held: int, tile: int) -> GroupedLayout:
 
 def experts_grouped(x: jax.Array, idx: jax.Array, w: jax.Array, lp: Params,
                     held: int, tile: int = GROUP_TILE,
-                    period: jax.Array | None = None, act: str = "silu"
-                    ) -> tuple[jax.Array, jax.Array]:
+                    period: jax.Array | None = None, act: str = "silu",
+                    limit: float = 0.0) -> tuple[jax.Array, jax.Array]:
     """The held experts' part of the result with work that follows the
     assignments. x [N,D]; idx, w [N,k]: each token's experts, numbered
     from the first one held, and their weights -> ([N,D] float32, int32
@@ -690,7 +794,8 @@ def experts_grouped(x: jax.Array, idx: jax.Array, w: jax.Array, lp: Params,
     cap = rows_that_fit(*wg.shape[-2:], wg.dtype.itemsize,
                         1 if quantized else x.dtype.itemsize)
     parts = [_grouped(x[lo:lo + cap], idx[lo:lo + cap], w[lo:lo + cap],
-                      stack, period, held=held, tile=tile, act=act)
+                      stack, period, held=held, tile=tile, act=act,
+                      limit=limit)
              for lo in range(0, x.shape[0], cap)]
     if len(parts) == 1:
         return parts[0]
@@ -720,13 +825,13 @@ def grouped_inputs(x, idx, w, stack, period, held: int, tile: int) -> tuple:
             mats), lay.counted
 
 
-@partial(jax.jit, static_argnames=("held", "tile", "act"))
-def _grouped(x, idx, w, stack, period, *, held, tile, act):
+@partial(jax.jit, static_argnames=("held", "tile", "act", "limit"))
+def _grouped(x, idx, w, stack, period, *, held, tile, act, limit=0.0):
     """``experts_grouped`` on a period-stacked tree: the layout, the
     rows' one rounding, each row's weight, the kernel."""
     given, counted = grouped_inputs(x, idx, w, stack, period, held, tile)
-    return grouped_experts(*given, tile=tile, act=act,
-                           dtype=x.dtype), counted
+    return grouped_experts(*given, tile=tile, act=act, dtype=x.dtype,
+                           limit=limit), counted
 
 
 def moe_block(x: jax.Array, lp: Params, c: ModelConfig,
@@ -738,7 +843,8 @@ def moe_block(x: jax.Array, lp: Params, c: ModelConfig,
     """x [B,T,D] (the residual stream) -> (the BRANCH ``R + S`` of
     ``norm(x)`` — the routed experts held here and the shared ones, several
     of those as the MEAN of their outputs; the caller adds it to the
-    stream —, int32 [``N_COUNTERS``]: of rows where ``count`` [B] is True
+    stream, through the post norm where the family has one —, int32
+    [``N_COUNTERS``]: of rows where ``count`` [B] is True
     the routed assignments, all and those landing on a held expert, the held
     experts with at least one of them (zeros without ``count``); then the
     tiles the grouped product ran and the rows they held (zeros from the
@@ -754,20 +860,23 @@ def moe_block(x: jax.Array, lp: Params, c: ModelConfig,
     h = hf.astype(x.dtype)
     with jax.named_scope("moe.experts"):
         seen = hf if route_on is None else route_on.astype(jnp.float32)
-        idx, w = route(seen.reshape(B * T, D), lp["router"], c)
+        idx, w = route(seen.reshape(B * T, D), lp["router"], c,
+                       lp.get("router_bias"))
         probs = held_weights(idx, w, c)
         xf = h.reshape(B * T, D)
         if B * T <= DENSE_MAX_TOKENS:
-            y = experts_dense(xf, probs, lp, period, c.moe_act)
+            y = experts_dense(xf, probs, lp, period, c.moe_act,
+                              c.swiglu_limit)
             tiled = jnp.zeros((2,), jnp.int32)
         else:
             y, tiled = experts_grouped(
                 xf, idx - c.first_expert_held, w, lp, c.experts_held,
-                period=period, act=c.moe_act)
+                period=period, act=c.moe_act, limit=c.swiglu_limit)
         y = y.reshape(B, T, D).astype(x.dtype)
     with jax.named_scope("moe.shared"):
         if c.n_shared_experts:
-            shared = swiglu_mlp(h, lp["sg"], lp["su"], lp["sd"])
+            shared = swiglu_mlp(h, lp["sg"], lp["su"], lp["sd"],
+                                limit=c.swiglu_limit)
             if c.n_shared_experts > 1:
                 # ``sd`` contracts the shared experts side by side: their
                 # SUM. A power of two when they are four: exact.
@@ -841,6 +950,11 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
                                  and hasattr(fns[0], "prefill_at"))
     by_index = by_decode_at or by_prefill_at
     scope = "decode" if decoding else "prefill"
+
+    def post(y, lp):
+        """A sub-block's branch as it joins the stream."""
+        return block_norm(y, lp["post_norm"], c) if c.post_norm else y
+
     last_only = not decoding and n_valid is not None
     if decoding:
         s_in, tail_in = cache.state, cache.conv
@@ -860,6 +974,29 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
         keep = count = None
 
     x = jnp.take(params["embed"], tokens, axis=0)               # [B,T,D]
+
+    def linear_layer(x, lp, s, tail):
+        with jax.named_scope(f"{scope}.kda"):
+            out, s, tail = linear_block(block_norm(x, lp["norm"], c), lp, c,
+                                        s, tail, n_valid, keep)
+            return x + post(out, lp), s, tail
+
+    if c.leading_dense:
+        # In FRONT of the periods: a linear mixer and a dense gated MLP a
+        # layer, one scanned body over their own stacked tree; their state
+        # is the LAST entry of the cache's tuples.
+        def lead_step(x, scanned):
+            lp, s, tail = scanned
+            x, s, tail = linear_layer(x, lp, s, tail)
+            with jax.named_scope(f"{scope}.mlp"), \
+                    jax.named_scope("mlp.dense"):
+                m = lp["mlp"]
+                y = swiglu_mlp(block_norm(x, m["norm"], c), m["wg"], m["wu"],
+                               m["wd"], limit=c.swiglu_limit)
+                return x + post(y, m), (s, tail)
+        x, lead_out = jax.lax.scan(
+            lead_step, x, (params["lead"], s_in[-1], tail_in[-1]))
+        s_in, tail_in = s_in[:-1], tail_in[:-1]
     if any(c.rope_at(p) for p in c.softmax_positions):
         cos, sin = rope_tables(lengths[:, None] + jnp.arange(T)[None, :],
                                dh, c.rope_theta, c.rope_scaling)
@@ -957,9 +1094,9 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
                 x = x + attn + branch
             else:
                 attn, pool, new[g][j] = softmax_layer(x, pool, lps[i], at, i)
-                x = x + attn
+                x = x + post(attn, lps[i])
                 branch, more = mlp(x, lps[i]["mlp"], i, period, x_in)
-                x = x + branch
+                x = x + post(branch, lps[i]["mlp"])
             if by_prefill_at:
                 carried = (*carried[:g], pool, *carried[g + 1:])
             counted = counted + more
@@ -967,13 +1104,9 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
         for i, (lp, s, tail) in enumerate(zip(lps[n_soft:], s0, tail0),
                                           n_soft):
             x_in = x
-            with jax.named_scope(f"{scope}.kda"):
-                h = block_norm(x, lp["norm"], c)
-                out, s, tail = linear_block(h, lp, c, s, tail, n_valid,
-                                            keep)
-                x = x + out
+            x, s, tail = linear_layer(x, lp, s, tail)
             branch, more = mlp(x, lp["mlp"], i, period, x_in)
-            x = x + branch
+            x = x + post(branch, lp["mlp"])
             counted = counted + more
             states.append(s)
             tails.append(tail)
@@ -981,7 +1114,7 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
 
     (x, carried), (new, s_out, tail_out, counts) = jax.lax.scan(
         period_step, (x, pools if by_prefill_at else None),
-        (jnp.arange(c.n_layers // c.layer_period), rest,
+        (jnp.arange(c.n_periods), rest,
          None if by_index else tuple(
              by_period(pool, len(ps)) for pool, (_, ps) in zip(pools, groups)),
          s_in, tail_in))
@@ -1002,6 +1135,8 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
             for fn, pool, parts in zip(fns, pools, new))
     else:
         new_pools = tuple(of_group(parts) for parts in new)
+    if c.leading_dense:
+        s_out, tail_out = (*s_out, lead_out[0]), (*tail_out, lead_out[1])
     if decoding:
         state, conv = s_out, tail_out
     else:

@@ -103,6 +103,29 @@ def rms_norm(x: jax.Array, weight: jax.Array, eps: float,
     return (normed * (offset + weight.astype(jnp.float32))).astype(x.dtype)
 
 
+def layer_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
+    """LayerNorm without bias, float32 inside: ``w (x - mean) / sqrt(var +
+    eps)`` over the last axis, in ``x``'s dtype."""
+    xf = x.astype(jnp.float32)
+    xc = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    normed = xc * jax.lax.rsqrt(jnp.mean(xc * xc, axis=-1, keepdims=True)
+                                + eps)
+    return (normed * weight.astype(jnp.float32)).astype(x.dtype)
+
+
+def block_norm(x: jax.Array, weight: jax.Array, c: ModelConfig) -> jax.Array:
+    """The period families' norm of ``ModelConfig.norm_kind`` (a block's,
+    a bottleneck's or the head's input): "rms", "layernorm" (no bias), or
+    "rms_2sigmoid" — an RMS norm whose gain is ``2 sigmoid(w)``, 1 at the
+    raw weight 0."""
+    if c.norm_kind == "layernorm":
+        return layer_norm(x, weight, c.layer_norm_eps)
+    if c.norm_kind == "rms_2sigmoid":
+        return rms_norm(x, 2.0 * jax.nn.sigmoid(weight.astype(jnp.float32)),
+                        c.rms_eps)
+    return rms_norm(x, weight, c.rms_eps)
+
+
 def rope_tables(positions: jax.Array, head_dim: int, theta: float,
                 scaling=None) -> tuple[jax.Array, jax.Array]:
     """cos/sin tables [..., head_dim/2] (fp32) for given absolute positions.
@@ -586,13 +609,25 @@ _GATE_ACTS = {
 }
 
 
+def gated_hidden(act: str, gate: jax.Array, up: jax.Array,
+                 limit: float = 0.0) -> jax.Array:
+    """``act(gate) * up``, the hidden rows of a gated MLP. ``limit`` > 0
+    (``ModelConfig.swiglu_limit``): ``act(min(gate, L)) * clip(up, -L,
+    L)``. 0 is a trace-time branch: the product as it always was."""
+    if limit:
+        gate = jnp.minimum(gate, limit)
+        up = jnp.clip(up, -limit, limit)
+    return _GATE_ACTS[act](gate) * up
+
+
 def swiglu_mlp(x: jax.Array, wg: jax.Array, wu: jax.Array,
-               wd: jax.Array, act: str = "silu") -> jax.Array:
-    """Gated MLP (SwiGLU for llama/qwen2, GeGLU for gemma via ``act``).
-    Each weight is a plain array or an int8 ``{"q","s"}`` dict
-    (models/quant.py) — ``mm`` dispatches."""
-    gate = _GATE_ACTS[act](mm(x, wg))
-    return mm(gate * mm(x, wu), wd)
+               wd: jax.Array, act: str = "silu", limit: float = 0.0
+               ) -> jax.Array:
+    """Gated MLP (SwiGLU for llama/qwen2, GeGLU for gemma via ``act``;
+    clamped where ``limit`` says so, ``gated_hidden``). Each weight is a
+    plain array or an int8 ``{"q","s"}`` dict (models/quant.py) — ``mm``
+    dispatches."""
+    return mm(gated_hidden(act, mm(x, wg), mm(x, wu), limit), wd)
 
 
 def qkv_proj(h: jax.Array, lp: dict, config: ModelConfig

@@ -1,6 +1,7 @@
 """Latent attention (MLA): ONE attention in two forms that must agree.
 
-Per token, with ``x' = rmsnorm(x)``::
+Per token, with ``x' = norm(x)`` (``norm``: the config's ``norm_kind``, for
+the block's input and both bottlenecks)::
 
     c_q = rmsnorm(x' W_qa)                    [q_lora_rank]
     q_h = c_q W_qb -> [q_nope_h | q_rope_h]   per head
@@ -9,6 +10,8 @@ Per token, with ``x' = rmsnorm(x)``::
     score_h(t, s) = a_t sigma (q_nope_h(t) . k_nope_h(s)
                                + rope(q_rope_h)(t) . rope(k_r)(s))
     out = concat_h(sum_s p_h(t, s) v_h(s)) W_o
+          (under ``attn_gate``: the concatenation times sigmoid(x' W_g),
+          per element, before W_o)
 
 Rotary on ``q_rope`` and ``k_r`` only (YaRN by parts where the config says
 so, pairs ``(2i, 2i+1)`` under ``rope_interleave``); ``sigma`` is
@@ -33,31 +36,37 @@ import jax
 import jax.numpy as jnp
 
 from .config import ModelConfig
-from .llama import rms_norm, rope_tables
+from .llama import block_norm, rope_tables
 from .quant import mm
 
 Params = dict[str, Any]
 # Stored int8 under quant: the four projections a token's activations
 # meet. ``wkvb`` stays in the compute dtype: the absorbed form multiplies
 # it into queries and outputs a head at a time.
-QUANT_KEYS = frozenset({"wqa", "wqb", "wkva", "wo"})
+QUANT_KEYS = frozenset({"wqa", "wqb", "wkva", "wo", "wgate"})
 
 
 def init_layer(c: ModelConfig, keys, dense: Callable, dtype) -> Params:
-    """One latent layer's attention weights (``dense(key, *shape, scale=,
-    name=)`` draws a matrix and quantises it where its name says so):
-      norm [D], wqa [D, rq], q_norm [rq], wqb [rq, H (dn + dr)],
+    """One latent layer's attention weights but for the block's own norm,
+    which the caller draws with the other layers' (``dense(key, *shape,
+    scale=, name=)`` draws a matrix and quantises it where its name says so):
+      wqa [D, rq], q_norm [rq], wqb [rq, H (dn + dr)],
       wkva [D, r + dr], kv_norm [r], wkvb [r, H, dn + dv],
-      wo [H dv, D] (scaled as every projection into the residual)."""
+      wo [H dv, D] (scaled as every projection into the residual),
+      wgate [D, H dv] under ``attn_gate``. A norm's weight is drawn at
+      gain 1: ones, or zeros where the gain is ``2 sigmoid(w)``."""
     D, H = c.d_model, c.n_heads
     dn, dr, dv = c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim
     back = (2 * c.n_layers) ** -0.5
-    return {"norm": jnp.ones((D,), dtype),
+    unit = jnp.zeros if c.norm_kind == "rms_2sigmoid" else jnp.ones
+    gate = {"wgate": dense(jax.random.fold_in(keys[3], 1), D, H * dv,
+                           name="wgate")} if c.attn_gate else {}
+    return {**gate,
             "wqa": dense(keys[0], D, c.q_lora_rank, name="wqa"),
-            "q_norm": jnp.ones((c.q_lora_rank,), dtype),
+            "q_norm": unit((c.q_lora_rank,), dtype),
             "wqb": dense(keys[1], c.q_lora_rank, H * (dn + dr), name="wqb"),
             "wkva": dense(keys[2], D, c.kv_lora_rank + dr, name="wkva"),
-            "kv_norm": jnp.ones((c.kv_lora_rank,), dtype),
+            "kv_norm": unit((c.kv_lora_rank,), dtype),
             "wkvb": dense(keys[3], c.kv_lora_rank, H * (dn + dv)
                           ).reshape(c.kv_lora_rank, H, dn + dv),
             "wo": dense(keys[4], H * dv, D, scale=back, name="wo")}
@@ -94,26 +103,25 @@ def rotate(x: jax.Array, cos: jax.Array, sin: jax.Array,
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
 
 
-def project(x: jax.Array, lp: Params, c: ModelConfig, lengths: jax.Array
+def project(h: jax.Array, lp: Params, c: ModelConfig, lengths: jax.Array
             ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """x [B, T, D] (the residual stream) at positions ``lengths + t`` ->
+    """h [B, T, D] (the NORMED block input) at positions ``lengths + t`` ->
     (q_nope [B, T, H, dn], q_rope [B, T, H, dr], both float32 with ``a_t
     sigma`` multiplied in; the token's cache row ``[c | rope(k_r)]``
-    [B, T, latent_width] in x's dtype)."""
-    B, T, _ = x.shape
+    [B, T, latent_width] in h's dtype)."""
+    B, T, _ = h.shape
     H, dn, dr = c.n_heads, c.qk_nope_head_dim, c.qk_rope_head_dim
-    h = rms_norm(x, lp["norm"], c.rms_eps)
-    q = mm(rms_norm(mm(h, lp["wqa"]), lp["q_norm"], c.rms_eps), lp["wqb"])
+    q = mm(block_norm(mm(h, lp["wqa"]), lp["q_norm"], c), lp["wqb"])
     q = q.reshape(B, T, H, dn + dr).astype(jnp.float32)
     kva = mm(h, lp["wkva"])
-    latent = rms_norm(kva[..., :c.kv_lora_rank], lp["kv_norm"], c.rms_eps)
+    latent = block_norm(kva[..., :c.kv_lora_rank], lp["kv_norm"], c)
     positions = lengths[:, None] + jnp.arange(T)[None, :]
     cos, sin = rope_tables(positions, dr, c.rope_theta, c.rope_scaling)
     scale = query_scale(positions, c)[:, :, None, None]
     q_rope = rotate(q[..., dn:], cos, sin, c.rope_interleave) * scale
     k_rope = rotate(kva[..., None, c.kv_lora_rank:].astype(jnp.float32),
                     cos, sin, c.rope_interleave)[:, :, 0]
-    row = jnp.concatenate([latent, k_rope.astype(x.dtype)], axis=-1)
+    row = jnp.concatenate([latent, k_rope.astype(h.dtype)], axis=-1)
     return q[..., :dn] * scale, q_rope, row
 
 
@@ -158,7 +166,7 @@ def expanded_attention(q_nope: jax.Array, q_rope: jax.Array,
 def mla_block(x: jax.Array, lp: Params, c: ModelConfig, pool: jax.Array,
               layer: jax.Array, fn: Any, lengths: jax.Array,
               active: jax.Array | None) -> tuple[jax.Array, jax.Array]:
-    """x [B, T, D] -> (the branch MLA(rmsnorm(x)), which the caller adds
+    """x [B, T, D] -> (the branch MLA(norm(x)), which the caller adds
     to the stream, the pool with the call's rows written into layer
     ``layer``). ``fn``: the group's
     ``ops.latent_attention.LatentAttention``. Insert, then attend: the
@@ -173,7 +181,8 @@ def mla_block(x: jax.Array, lp: Params, c: ModelConfig, pool: jax.Array,
     kernel of its own (PERF.md section 5 has both measured)."""
     dn = c.qk_nope_head_dim
     start = lengths if active is None else jnp.where(active, lengths, 0)
-    q_nope, q_rope, row = project(x, lp, c, start)
+    h = block_norm(x, lp["norm"], c)
+    q_nope, q_rope, row = project(h, lp, c, start)
     pool = fn.write(pool, row, layer, lengths, active)
     if fn.absorbed:
         q = absorb_queries(q_nope, q_rope, lp["wkvb"], x.dtype)
@@ -182,4 +191,7 @@ def mla_block(x: jax.Array, lp: Params, c: ModelConfig, pool: jax.Array,
     else:
         out = expanded_attention(q_nope, q_rope, fn.gather(pool, layer),
                                  lp["wkvb"], start, c).astype(x.dtype)
+    if c.attn_gate:
+        gate = jax.nn.sigmoid(mm(h, lp["wgate"]).astype(jnp.float32))
+        out = (out.astype(jnp.float32) * gate).astype(x.dtype)
     return mm(out, lp["wo"]), pool

@@ -46,7 +46,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..models.llama import _GATE_ACTS
+from ..models.llama import gated_hidden
 
 # What the kernel may ask of a v5e's 128 MiB of fast memory, and what of
 # that is neither the resident rows and result nor the streamed blocks: the
@@ -109,17 +109,19 @@ def _unpack_rows(words: jax.Array, dtype) -> jax.Array:
     return jnp.concatenate(planes, axis=1)
 
 
-def _gated(act: str, gate: jax.Array, up: jax.Array) -> jax.Array:
-    """``act(gate) * up`` in the rows' dtype, computed in float32 and
-    rounded once (the chip has no 16-bit vector unit and its compiler
-    takes no 16-bit logistic; XLA keeps the same excess precision inside
-    a fusion)."""
-    return (_GATE_ACTS[act](gate.astype(jnp.float32))
-            * up.astype(jnp.float32)).astype(gate.dtype)
+def _gated(act: str, gate: jax.Array, up: jax.Array,
+           limit: float = 0.0) -> jax.Array:
+    """``act(gate) * up`` in the rows' dtype (``llama.gated_hidden``, with
+    its clamp where ``limit`` > 0), computed in float32 and rounded once
+    (the chip has no 16-bit vector unit and its compiler takes no 16-bit
+    logistic; XLA keeps the same excess precision inside a fusion)."""
+    return gated_hidden(act, gate.astype(jnp.float32),
+                        up.astype(jnp.float32), limit).astype(gate.dtype)
 
 
 def _kernel(meta, tile_expert, row_token, row_weight, x32, *refs, tile: int,
-            n_blocks: int, act: str, quantized: bool, x_dtype):
+            n_blocks: int, act: str, limit: float, quantized: bool,
+            x_dtype):
     """meta int32 [2]: (live tiles, period); tile_expert [n_tiles];
     row_token, row_weight [n_tiles * tile]: the token a row holds (the
     result's row count: a padding row) and its weight; x32: the call's
@@ -239,7 +241,7 @@ def _kernel(meta, tile_expert, row_token, row_weight, x32, *refs, tile: int,
                     return jnp.dot(x, gu_v[slot, n],
                                    preferred_element_type=jnp.float32
                                    ).astype(dtype)
-            hidden = _gated(act, product(0), product(1))
+            hidden = _gated(act, product(0), product(1), limit)
             start_if_live(up_copies, q + 2)
             h_v[j] = hidden
             return jnp.maximum(amax, jnp.max(
@@ -304,7 +306,8 @@ def rows_that_fit(D: int, F: int, w_itemsize: int, x_itemsize: int) -> int:
 def grouped_experts(meta: jax.Array, tile_expert: jax.Array,
                     row_token: jax.Array, row_weight: jax.Array, src: tuple,
                     mats: tuple, *, tile: int, act: str, dtype,
-                    interpret: bool | None = None) -> jax.Array:
+                    limit: float = 0.0, interpret: bool | None = None
+                    ) -> jax.Array:
     """The held experts' weighted results, summed a token: float32 [N, D].
     ``meta`` int32 [2]: the live tiles and the period to read;
     ``tile_expert`` [n_tiles]; ``row_token``, ``row_weight``
@@ -314,7 +317,8 @@ def grouped_experts(meta: jax.Array, tile_expert: jax.Array,
     gate, up, down over periods and experts, each ([P, E, din, dout] int8,
     [P, E, dout] float32) flattened in that order, or plain
     [P, E, din, dout]; ``dtype``: what the rows were before they were
-    quantised — an expert's hidden rows and result are rounded to it."""
+    quantised — an expert's hidden rows and result are rounded to it;
+    ``limit``: the gated product's clamp (0: none)."""
     quantized = len(src) == 2
     wg = mats[0]
     D, F = wg.shape[-2:]
@@ -346,6 +350,7 @@ def grouped_experts(meta: jax.Array, tile_expert: jax.Array,
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     out = pl.pallas_call(
         functools.partial(_kernel, tile=tile, n_blocks=n_blocks, act=act,
+                          limit=limit,
                           quantized=quantized, x_dtype=src[0].dtype),
         out_shape=jax.ShapeDtypeStruct((-(-N // 8) * 8, D), jnp.float32),
         in_specs=([smem] * 4 + [vmem]

@@ -42,6 +42,10 @@ from .paged_attention import NEG_INF, _insert_positions
 
 # Lanes a write moves at a time: a whole lane tile where the page has one.
 _INSERT_LANES = 128
+# What the chip's compiler gives a kernel's scratch unasked, and the room
+# the write asks for beside its patch buffers where they pass it.
+_DEFAULT_VMEM_BYTES = 16 * 2 ** 20
+_INSERT_VMEM_ROOM = 4 * 2 ** 20
 # Query rows (positions x heads) a program of the attention kernel holds,
 # the pages it copies a step (all in flight together, into one of two
 # buffers), and what it may take of VMEM: q and out blocks twice over, the
@@ -182,6 +186,7 @@ def latent_insert_in_place(pool: jax.Array, new: jax.Array,
 
     scalars = (jnp.asarray(layer, jnp.int32).reshape(1), lengths,
                tile_of(phys), tile_of(off) // cols * cols)
+    buf_bytes = K * 4 * W * cols * pool.dtype.itemsize
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     return pl.pallas_call(
         functools.partial(_latent_insert_kernel, K=K, T=T, cols=cols,
@@ -193,6 +198,11 @@ def latent_insert_in_place(pool: jax.Array, new: jax.Array,
                             pltpu.SemaphoreType.DMA((K, 3, 2))]),
         out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
         input_output_aliases={len(scalars) + 1: 0},
+        # The patch buffers of a wide row and many slots (32 slots of 576:
+        # 18 MiB) pass the compiler's default of 16 MiB a kernel.
+        compiler_params=(pltpu.CompilerParams(
+            vmem_limit_bytes=buf_bytes + _INSERT_VMEM_ROOM)
+            if buf_bytes + _INSERT_VMEM_ROOM > _DEFAULT_VMEM_BYTES else None),
         interpret=_paged._interpret_default() if interpret is None else interpret,
     )(*scalars, tiles, pool)
 

@@ -261,17 +261,27 @@ def test_prefill_step_attends_a_layer_in_one_call_with_no_page_axis(
 # PR 38: the latent pool's kernels at the served geometry — 12 layers of
 # 8 slots x 128 pages of [320, 256] bfloat16 (2.0 GB), 32 query heads.
 
-@pytest.mark.parametrize("rows, tokens", [(1, 512), (4, 512), (8, 1)],
-                         ids=["chunk", "four-chunks", "decode"])
-def test_latent_kernels_compile_at_the_served_geometry(chips, rows, tokens):
+# PR 46: the same kernels at 1 layer of 32 slots x 80 pages of [576, 256]
+# (0.75 GB), 64 query heads: 32 positions a row-block.
+LATENT_GEOMETRY = {
+    "small4": (12, 8 * 128 + 1, 320, 256, 32, 128),
+    "gigachat35": (1, 32 * 80 + 1, 576, 512, 64, 80)}
+
+
+@pytest.mark.parametrize("rows, tokens, cell", [
+    (1, 512, "small4"), (4, 512, "small4"), (8, 1, "small4"),
+    (32, 1, "gigachat35")],     # its chunk: the step program below
+    ids=["chunk", "four-chunks", "decode", "decode-576x64"])
+def test_latent_kernels_compile_at_the_served_geometry(chips, rows, tokens,
+                                                       cell):
     """The in-place write with the pool donated (every byte aliased, the
     temporaries the call's own rows cut into tiles) and the absorbed
     attention kernel on the whole stacked pool at a traced layer's index,
     as a prefill chunk (2 048 query rows a program) and as a decode step
-    (32): the chip's compiler finds room for both, and no slice of the
-    pool is among the operands."""
+    (a slot's heads): the chip's compiler finds room for both, and no
+    slice of the pool is among the operands."""
     from llmapigateway_tpu.ops import latent_attention as la
-    layers, pages, width, value, heads, table = 12, 8 * 128 + 1, 320, 256, 32, 128
+    layers, pages, width, value, heads, table = LATENT_GEOMETRY[cell]
     place = SingleDeviceSharding(chips[0])
 
     def sds(shape, dtype):
@@ -296,9 +306,86 @@ def test_latent_kernels_compile_at_the_served_geometry(chips, rows, tokens):
         sds((rows, tokens, heads, width), jnp.bfloat16), pool, tbl, start,
         sds((), jnp.int32)).compile()
     assert "tpu_custom_call" in attend.as_text()
-    assert la.latent_block_t(tokens, heads) == min(tokens, 64)
+    assert la.latent_block_t(tokens, heads) == min(tokens, 2048 // heads)
     # q in, the latent-wide out, and nothing the size of a layer's pool.
     assert attend.memory_analysis().temp_size_in_bytes < pages * width * PAGE
+
+
+# -- a family with a latent pool AND recurrent state (PR 46) -------------------
+
+@pytest.mark.parametrize("program", ["decode", "prefill-4"])
+def test_the_latent_and_state_familys_step_programs_fit_the_chip(
+        chips, monkeypatch, program):
+    """The ENGINE'S OWN step programs at ``gigachat35-432b-ep8``'s served
+    geometry — 7 layers at the published widths, int8, 32 slots of 20,480
+    positions, a latent pool of one layer beside six float32 state blocks
+    a slot — compiled for the described chip: the chip's compiler takes
+    every kernel (the latent write and attention at 576 x 64 heads, the
+    grouped expert product at 7168 x 2048) and arguments plus temporaries
+    fit 16 GB of HBM."""
+    import types
+
+    from llmapigateway_tpu.engine.engine import InferenceEngine
+    from llmapigateway_tpu.engine.sampling import SamplingParams
+    from llmapigateway_tpu.models import PRESETS, hybrid
+
+    monkeypatch.setattr(pa, "_interpret_default", lambda: False)
+    hybrid._grouped.clear_cache()
+    config = PRESETS["gigachat35-432b-ep8"]
+    slots, per_slot, depth = 32, 80, 8
+    pages = slots * per_slot + 1
+    mesh = build_mesh({}, devices=chips[:1])
+    engine = types.SimpleNamespace(
+        model_cfg=config, quant="int8", dtype=jnp.bfloat16, mesh=mesh,
+        attention_impl="pallas", kv_ppb=1, S=per_slot * PAGE, B=slots,
+        spec_k=0, decode_burst=depth, _burst_depths=(depth,),
+        allocator=types.SimpleNamespace(num_pages=pages, page_size=PAGE))
+    InferenceEngine._compile_paged(engine)
+    init, key = InferenceEngine._random_init_program(engine)
+    placed = NamedSharding(mesh, P())
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=placed)
+
+    def shapes(tree):
+        return jax.tree.map(lambda s: sds(s.shape, s.dtype), tree)
+    cache = shapes(jax.eval_shape(lambda: hybrid.HybridCache.create(
+        config, pages, PAGE, slots, jnp.bfloat16)))
+    assert [a.shape for a in cache.k] == [(1, pages, 576, PAGE)]
+    assert [a.shape[0] for a in cache.state] == [1, 1, 1, 3]
+    state = (shapes(jax.eval_shape(init, key)), cache,
+             sds((slots, config.vocab_size), jnp.int32),
+             (sds((slots, per_slot), jnp.int32),))
+    rng = jax.random.key(0)
+    rng = jax.ShapeDtypeStruct(rng.shape, rng.dtype)
+    if program == "decode":
+        vec = lambda dtype: sds((slots,), dtype)
+        sampling = SamplingParams(
+            temperature=vec(jnp.float32), top_p=vec(jnp.float32),
+            top_k=vec(jnp.int32), presence_penalty=vec(jnp.float32),
+            frequency_penalty=vec(jnp.float32))
+        compiled = engine._decode_fns[True][1][depth].lower(
+            *state, vec(jnp.int32), vec(jnp.int32), vec(jnp.bool_), sampling,
+            rng).compile()
+    else:
+        rows = int(program[-1])
+        vec = lambda dtype: sds((rows,), dtype)
+        compiled = engine._prefill_fn.lower(
+            *state, sds((rows, 512), jnp.int32), vec(jnp.int32),
+            vec(jnp.int32), vec(jnp.int32), vec(jnp.float32),
+            vec(jnp.float32), vec(jnp.int32), vec(jnp.float32),
+            vec(jnp.float32), rng).compile()
+    hybrid._grouped.clear_cache()
+    text = compiled.as_text()
+    assert "kv.latent_insert" in text and "attention.latent" in text
+    if program != "decode":
+        assert "grouped_experts" in text
+    memory = compiled.memory_analysis()
+    held = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.output_size_in_bytes - memory.alias_size_in_bytes)
+    print(program, "arguments", memory.argument_size_in_bytes, "temporaries",
+          memory.temp_size_in_bytes, "held", held)
+    assert held < 15.75e9
 
 
 # -- the grouped expert product's kernel (PR 43) -------------------------------
