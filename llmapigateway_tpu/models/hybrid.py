@@ -97,8 +97,10 @@ TPU-first decisions:
 * The cache is the page pool PLUS a fixed block of recurrent state and a
   convolution tail per slot (``HybridCache``). Prefill gathers the rows of
   its slots, runs the block-parallel form over sub-chunks of 64 tokens and
-  scatters the rows back; decode rewrites the whole block once a step.
-  State is float32.
+  scatters the rows back; decode reads and writes the whole block once a
+  step, IN PLACE: the stacked blocks ride the scans' carry and each
+  linear layer's one-token update is ONE Pallas kernel whose state
+  operand is its result (``ops/delta_update.py``). State is float32.
 * The expert layer has two exact forms: every held expert on every token
   (decode, small calls: the weights stream from memory either way), and a
   grouped product over the assignments sorted by expert, tile by tile,
@@ -120,6 +122,7 @@ from . import mla
 from .config import ModelConfig
 from .llama import (_select_head, apply_rope, block_norm, gated_hidden,
                     layer_norm, rms_norm, rope_tables, swiglu_mlp)
+from ..ops.delta_update import delta_update
 from ..ops.grouped_experts import grouped_experts, rows_that_fit
 from .quant import (_dynamic_int8, head_matmul, is_quantized, mm,
                     moe_mm_batched, quantize_array, weight_bits)
@@ -434,27 +437,11 @@ def init_params(config: ModelConfig, key: jax.Array,
 # The linear layer
 # ---------------------------------------------------------------------------
 
-def kda_recurrent(q, k, v, log_a, beta, s0):
-    """The recurrence token by token — the definition ``kda_chunked`` and
-    ``kda_decode_update`` are held to, and what ``kda_chunked`` runs for a
-    call that is not whole sub-chunks.
-    q, k [B,T,H,dk] (normalised, q scaled), v [B,T,H,dv], log_a [B,T,H,dk]
-    (<= 0; [B,T,H,1]: ONE decay a head, broadcast over its channels in
-    all three forms), beta [B,T,H], s0 [B,H,dk,dv]; all float32.
-    Returns (o [B,T,H,dv], s_T)."""
-    def step(s, x):
-        o, s = kda_decode_update(*x, s)
-        return s, o
-    xs = jax.tree.map(lambda a: jnp.moveaxis(a, 1, 0), (q, k, v, log_a, beta))
-    s, o = jax.lax.scan(step, s0, xs)
-    return jnp.moveaxis(o, 0, 1), s
-
-
-def kda_decode_update(q, k, v, log_a, beta, s):
-    """One token: q, k, log_a [B,H,dk], v [B,H,dv], beta [B,H], s
+def delta_step(q, k, v, log_a, beta, s):
+    """One token, in plain jnp — the DEFINITION of the rule's step: q, k
+    [B,H,dk], log_a [B,H,dk] or [B,H,1], v [B,H,dv], beta [B,H], s
     [B,H,dk,dv] -> (o [B,H,dv], s_new). Reductions are multiply-and-sum in
-    float32 (a step is bound by reading and writing ``s``); both
-    reductions over the decayed state share its one read:
+    float32; both reductions over the decayed state share its one read:
     ``o = S_new^T q = S_dec^T q + u (k . q)``."""
     with jax.named_scope("kda.decode_update"):
         s_dec = s * jnp.exp(log_a)[..., None]
@@ -463,6 +450,41 @@ def kda_decode_update(q, k, v, log_a, beta, s):
         u = beta[..., None] * (v - r_k)
         o = r_q + u * jnp.sum(k * q, axis=-1, keepdims=True)
         return o, s_dec + k[..., None] * u[..., None, :]
+
+
+def kda_recurrent(q, k, v, log_a, beta, s0):
+    """The recurrence token by token (``delta_step`` scanned) — what
+    ``kda_chunked`` and ``kda_decode_update`` are held to, and what
+    ``kda_chunked`` runs for a call that is not whole sub-chunks.
+    q, k [B,T,H,dk] (normalised, q scaled), v [B,T,H,dv], log_a [B,T,H,dk]
+    (<= 0; [B,T,H,1]: ONE decay a head, broadcast over its channels in
+    all three forms), beta [B,T,H], s0 [B,H,dk,dv]; all float32.
+    Returns (o [B,T,H,dv], s_T)."""
+    def step(s, x):
+        o, s = delta_step(*x, s)
+        return s, o
+    xs = jax.tree.map(lambda a: jnp.moveaxis(a, 1, 0), (q, k, v, log_a, beta))
+    s, o = jax.lax.scan(step, s0, xs)
+    return jnp.moveaxis(o, 0, 1), s
+
+
+@jax.jit
+def _state_update(state, at, q, k, v, log_a, beta, keep):
+    """``delta_step`` on layer ``at`` of a STACKED state block [layers, B,
+    H, dk, dv], where ``keep`` [B]: ONE kernel that reads and writes the
+    block in place (``ops/delta_update.py``). One jitted function, so a
+    decode program traces and lowers it once however many linear layers
+    its scans' bodies unroll."""
+    with jax.named_scope("kda.decode_update"):
+        return delta_update(state, at, q, k, v, log_a, beta, keep)
+
+
+def kda_decode_update(q, k, v, log_a, beta, s):
+    """``delta_step`` (same arguments and result) as the decode programs
+    run it: the kernel, on ``s`` as a stack of one layer."""
+    o, stack = _state_update(s[None], 0, q, k, v, log_a, beta,
+                             jnp.ones(s.shape[:1], bool))
+    return o, stack[0]
 
 
 def _unit_lower_inverse(a: jax.Array) -> jax.Array:
@@ -579,14 +601,16 @@ def _l2norm(x: jax.Array) -> jax.Array:
     return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
 
 
-def linear_block(h, lp, c: ModelConfig, s0, tail, n_valid, keep):
+def linear_block(h, lp, c: ModelConfig, s0, tail, n_valid, keep, at=None):
     """One linear-attention layer on normalised input ``h`` [B,T,D].
-    ``s0`` [B,H,dk,dv] and ``tail`` [B,taps-1,``lin_conv_width``]: the
-    rows' state on entry. ``n_valid`` [B] (prefill): tokens past it are
-    padding and move neither the state nor the tail (b = 0, a = 1, the
-    tail taken at the true length). ``keep`` [B] bool (decode): rows that
-    are False leave with the state and the tail they came with. Returns
-    (out [B,T,D], state, tail).
+    ``tail`` [B,taps-1,``lin_conv_width``]: the rows' last inputs on entry.
+    PREFILL: ``s0`` [B,H,dk,dv], the rows' state on entry; ``n_valid``
+    [B]: tokens past it are padding and move neither the state nor the
+    tail (b = 0, a = 1, the tail taken at the true length). DECODE
+    (``keep`` [B] bool): ``s0`` is the STACKED block [layers,B,H,dk,dv],
+    this layer the one at index ``at``, and the block is what comes back,
+    written in place; rows that are False leave with the state and the
+    tail they came with. Returns (out [B,T,D], state, tail).
 
     ``lin_kind`` "kda": H key heads, a decay a channel through a low-rank
     pair, b in (0, 2), the output gated by sigmoid(low-rank pair).
@@ -625,10 +649,9 @@ def linear_block(h, lp, c: ModelConfig, s0, tail, n_valid, keep):
                 low("btd,dr->btr", h, lp["wg_down"]).astype(h.dtype),
                 lp["wg_up"]))
     if T == 1 and keep is not None:
-        o, s = kda_decode_update(q[:, 0], k[:, 0], v[:, 0], log_a[:, 0],
-                                 beta[:, 0], s0)
+        o, s = _state_update(s0, at, q[:, 0], k[:, 0], v[:, 0], log_a[:, 0],
+                             beta[:, 0], keep)
         o = o[:, None]
-        s = jnp.where(keep[:, None, None, None], s, s0)
         new_tail = jnp.where(keep[:, None, None], x_ext[:, 1:], tail)
     else:
         live = jnp.arange(T)[None, :] < n_valid[:, None]        # [B,T]
@@ -975,27 +998,43 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
 
     x = jnp.take(params["embed"], tokens, axis=0)               # [B,T,D]
 
-    def linear_layer(x, lp, s, tail):
+    def linear_layer(x, lp, s, tail, at=None):
         with jax.named_scope(f"{scope}.kda"):
             out, s, tail = linear_block(block_norm(x, lp["norm"], c), lp, c,
-                                        s, tail, n_valid, keep)
+                                        s, tail, n_valid, keep, at)
             return x + post(out, lp), s, tail
+
+    def ride(s):
+        """A scan's state as (what rides its CARRY, what it scans in or
+        out). DECODE: the stacked blocks ride, and a layer updates its own
+        in place (``_state_update``), as the pools do under
+        ``by_prefill_at`` — scanned out, a step would leave a fresh stack
+        for the burst's scan to copy into its carry. PREFILL scans the
+        gathered rows in and out."""
+        return (s, None) if decoding else (None, s)
 
     if c.leading_dense:
         # In FRONT of the periods: a linear mixer and a dense gated MLP a
         # layer, one scanned body over their own stacked tree; their state
         # is the LAST entry of the cache's tuples.
-        def lead_step(x, scanned):
-            lp, s, tail = scanned
-            x, s, tail = linear_layer(x, lp, s, tail)
+        def lead_step(carry, scanned):
+            x, stack = carry
+            lp, s, tail, at = scanned
+            x, s, tail = linear_layer(x, lp, stack if decoding else s, tail,
+                                      at)
+            stack, s = ride(s)
             with jax.named_scope(f"{scope}.mlp"), \
                     jax.named_scope("mlp.dense"):
                 m = lp["mlp"]
                 y = swiglu_mlp(block_norm(x, m["norm"], c), m["wg"], m["wu"],
                                m["wd"], limit=c.swiglu_limit)
-                return x + post(y, m), (s, tail)
-        x, lead_out = jax.lax.scan(
-            lead_step, x, (params["lead"], s_in[-1], tail_in[-1]))
+                return (x + post(y, m), stack), (s, tail)
+        stack, rows = ride(s_in[-1])
+        (x, stack), (rows, lead_tail) = jax.lax.scan(
+            lead_step, (x, stack),
+            (params["lead"], rows, tail_in[-1],
+             jnp.arange(c.leading_dense) if decoding else None))
+        lead_out = (stack if decoding else rows, lead_tail)
         s_in, tail_in = s_in[:-1], tail_in[:-1]
     if any(c.rope_at(p) for p in c.softmax_positions):
         cos, sin = rope_tables(lengths[:, None] + jnp.arange(T)[None, :],
@@ -1070,7 +1109,7 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
             lambda a: a.reshape(a.shape[0] // n, n, *a.shape[1:]), pool)
 
     def period_step(carry, scanned):
-        x, carried = carry
+        x, carried, stacks = carry
         period, lps, sides, s0, tail0 = scanned
         new = [[None] * len(ps) for _, ps in groups]
         counted = 0
@@ -1101,23 +1140,27 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
                 carried = (*carried[:g], pool, *carried[g + 1:])
             counted = counted + more
         states, tails = [], []
-        for i, (lp, s, tail) in enumerate(zip(lps[n_soft:], s0, tail0),
-                                          n_soft):
+        for i, (lp, s, tail) in enumerate(
+                zip(lps[n_soft:], stacks if decoding else s0, tail0), n_soft):
             x_in = x
-            x, s, tail = linear_layer(x, lp, s, tail)
+            x, s, tail = linear_layer(x, lp, s, tail,
+                                      period if decoding else None)
             branch, more = mlp(x, lp["mlp"], i, period, x_in)
             x = x + post(branch, lp["mlp"])
             counted = counted + more
             states.append(s)
             tails.append(tail)
-        return (x, carried), (new, tuple(states), tuple(tails), counted)
+        stacks, states = ride(tuple(states))
+        return (x, carried, stacks), (new, states, tuple(tails), counted)
 
-    (x, carried), (new, s_out, tail_out, counts) = jax.lax.scan(
-        period_step, (x, pools if by_prefill_at else None),
+    stacks, rows = ride(s_in)
+    (x, carried, stacks), (new, rows, tail_out, counts) = jax.lax.scan(
+        period_step, (x, pools if by_prefill_at else None, stacks),
         (jnp.arange(c.n_periods), rest,
          None if by_index else tuple(
              by_period(pool, len(ps)) for pool, (_, ps) in zip(pools, groups)),
-         s_in, tail_in))
+         rows, tail_in))
+    s_out = stacks if decoding else rows
 
     def of_group(parts):
         """A group's per-position results [P, ...] in the pool's layer

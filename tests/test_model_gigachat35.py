@@ -310,9 +310,11 @@ def test_padding_is_inert_and_an_inactive_row_leaves_bit_identical():
     np.testing.assert_allclose(full[0][1, :9], short[0][0], atol=FORM_TOL)
     np.testing.assert_allclose(full[1][1], short[1][0], atol=FORM_TOL)
     np.testing.assert_allclose(full[2][1], short[2][0], atol=0)
+    # Decode takes the STACKED block and the layer's index in it.
     out, s, new_tail = jax.jit(lambda h, s, tail, keep: hybrid.linear_block(
-        h, lp, c, s, tail, None, keep))(h[:, :1], s0, tail,
-                                        jnp.asarray([True, False]))
+        h, lp, c, s, tail, None, keep, at=0))(h[:, :1], s0[None], tail,
+                                              jnp.asarray([True, False]))
+    s = s[0]
     assert np.array_equal(np.asarray(s[1]), np.asarray(s0[1]))
     assert np.array_equal(np.asarray(new_tail[1]), np.asarray(tail[1]))
     assert not np.array_equal(np.asarray(s[0]), np.asarray(s0[0]))
