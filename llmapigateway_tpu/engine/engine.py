@@ -427,9 +427,10 @@ class InferenceEngine:
         # the worker reaches its call through `_device_phase`.
         self._sched = SchedLedger()
         # What the last compiled prefill dispatch ran (rows, bucket,
-        # tokens, lowest/highest start position, KV pages walked, t0,
-        # t1): written by the worker inside _exec_prefill, read by the
-        # loop after the await for the PREFILL flight record.
+        # tokens, lowest/highest start position, KV pages walked, the
+        # attention kernel's block, t0, t1): written by the worker inside
+        # _exec_prefill, read by the loop after the await for the PREFILL
+        # flight record.
         self._last_prefill: tuple | None = None
         # The paged prefill kernel's walk, counted at dispatch (ISSUE
         # 37): over rows and cache groups, the pages a call's row-blocks
@@ -437,6 +438,10 @@ class InferenceEngine:
         # page axis stepped through. Monotone; worker thread.
         self._prefill_pages_walked = 0
         self._prefill_pages_table = 0
+        # ... and the block each dispatched bucket's kernel ran at (ISSUE
+        # 48): bucket -> (positions a row-block, KV heads a program),
+        # worked out at a bucket's first dispatch (_prefill_block).
+        self._prefill_blocks: dict[int, tuple[int, int]] = {}
         # A latent layer's keys attended (ISSUE 38): per layer, summed
         # over calls, counted at dispatch — a decode step's over the
         # active slots (each sees its context and itself), a prefill
@@ -2247,10 +2252,11 @@ class InferenceEngine:
         if fl is None or last is None:
             return
         from ..obs import flight as _fl
-        rows, bucket, tokens, pos_lo, pos_hi, walked, t0, t1 = last
+        rows, bucket, tokens, pos_lo, pos_hi, walked, block, t0, t1 = last
         fl.record(_fl.PREFILL, t=t1, dur_ms=1000.0 * (t1 - t0), depth=rows,
                   val=float(bucket), tokens=tokens, free_pages=pos_lo,
                   spec_acc=pos_hi, chunks=min(walked, 32767),
+                  active=block[0], free_slots=block[1],
                   pool=_fl.POOL_PREFILL if self._disagg is not None else 0)
 
     def _admit(self, fl) -> None:
@@ -2542,9 +2548,16 @@ class InferenceEngine:
         # synchronous) — per-step attribution for decode comes from the
         # flight ring, prefill rows are call/FLOPs accounting.
         kname = f"prefill.b{int(bucket)}.k{K}"
+        block = self._prefill_blocks.get(int(bucket))
+        if block is None:
+            block = self._prefill_blocks[int(bucket)] = \
+                self._prefill_block(int(bucket))
         if self.kernels.needs(kname):
+            variant = {"bucket": int(bucket), "k": K}
+            if self.paged:
+                variant["block"] = "%dx%d" % block
             self.kernels.register(
-                kname, "prefill", variant={"bucket": int(bucket), "k": K},
+                kname, "prefill", variant=variant,
                 cost_fn=_kernel_cost_fn(self._prefill_fn, args))
         t0 = time.monotonic()
         with _device_phase("prefill"):
@@ -2553,32 +2566,49 @@ class InferenceEngine:
         self.kernels.record(kname, wall_ms=1000.0 * (t1 - t0))
         self._last_prefill = (K, int(bucket), sum(len(ch) for ch in chunks),
                               int(min(poss)), int(max(poss)),
-                              self._count_prefill_walk(poss, int(bucket)),
-                              t0, t1)
+                              self._count_prefill_walk(poss, int(bucket),
+                                                       block[0]),
+                              block, t0, t1)
         return first, cache
 
-    def _count_prefill_walk(self, poss: list[int], bucket: int) -> int:
+    def _prefill_block(self, bucket: int) -> tuple[int, int]:
+        """(query positions a row-block, KV heads a program) of the
+        prefill attention kernel at this bucket, by the kernel's own rule
+        over what its call sees (under a ``model`` mesh the local KV
+        heads; the latent kernel folds every head over its one latent);
+        (0, 0) for a dense cache, which runs no paged kernel."""
+        if not self.paged:
+            return 0, 0
+        c = self.model_cfg
+        if c.is_mla:
+            from ..ops.latent_attention import latent_block_t
+            return latent_block_t(bucket, c.n_heads), 1
+        from ..ops.paged_attention import prefill_block_shape
+        model = self.mesh.shape.get("model", 1)
+        kv = c.n_kv_heads // (
+            1 if c.n_kv_heads % model or c.n_heads % model else model)
+        itemsize = jnp.dtype(self.dtype).itemsize
+        return prefill_block_shape(
+            bucket, c.n_heads // c.n_kv_heads, kv, self.allocator.page_size,
+            c.head_dim, itemsize, 1 if self.kv_quant else itemsize,
+            bool(self.kv_quant), self.kv_ppb)
+
+    def _count_prefill_walk(self, poss: list[int], bucket: int,
+                            bt: int) -> int:
         """Add one dispatch to the walk's two totals and return its
         walked pages: per row and cache group what
-        ``ops.paged_attention.prefill_pages_walked`` counts for the
-        row-block the kernel's own rule picks at this bucket (host
-        integer arithmetic; a dense cache walks no pages)."""
+        ``ops.paged_attention.prefill_pages_walked`` counts for row-blocks
+        of ``bt`` positions, the kernel's own at this bucket
+        (:meth:`_prefill_block`; host integer arithmetic; a dense cache
+        walks no pages)."""
         if not self.paged:
             return 0
         from ..ops import paged_attention as pa
-        c, page = self.model_cfg, self.allocator.page_size
-        itemsize = jnp.dtype(self.dtype).itemsize
-        if c.is_mla:
-            from ..ops.latent_attention import latent_block_t
-            bt = latent_block_t(bucket, c.n_heads)
+        page = self.allocator.page_size
+        if self.model_cfg.is_mla:
             # Keys a layer's call attends: row b's query t sees pos + t + 1.
             self._mla_prefill_keys += sum(
                 bucket * int(p) + bucket * (bucket + 1) // 2 for p in poss)
-        else:
-            bt = pa.prefill_block_shape(
-                bucket, c.n_heads // c.n_kv_heads, c.n_kv_heads, page,
-                c.head_dim, itemsize, 1 if self.kv_quant else itemsize,
-                bool(self.kv_quant), self.kv_ppb)[0]
         walked = 0
         for g in self.kv_groups:
             w, t = pa.prefill_pages_walked(
@@ -3565,6 +3595,9 @@ class InferenceEngine:
             # The paged prefill kernel's walk (_count_prefill_walk).
             out["prefill_kv_pages_walked_total"] = self._prefill_pages_walked
             out["prefill_kv_pages_table_total"] = self._prefill_pages_table
+            out["prefill_kernel_blocks"] = {
+                str(b): "%dx%d" % blk for b, blk in sorted(
+                    self._prefill_blocks.items())}
             # One layer's keys of a global and of a windowed K/V group
             # that the decode programs attended (_count_decode_keys).
             out["attn_decode_keys_global_total"] = \

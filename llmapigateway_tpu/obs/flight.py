@@ -97,7 +97,9 @@ _DTYPE = np.dtype([
     ("chunks", np.int16),       # prefill chunk dispatches this step
                                 # (PREFILL: KV pages the kernel walked)
     ("active", np.int16),       # running requests after the step
-    ("free_slots", np.int16),
+                                # (PREFILL: query positions a row-block
+                                # of the attention kernel)
+    ("free_slots", np.int16),   # (PREFILL: KV heads a program of it)
     ("queued", np.int16),       # admission queue depth (+ parked head)
     ("free_pages", np.int32),   # paged pool headroom; -1 = dense layout
                                 # (PREFILL: lowest start position)
@@ -276,6 +278,12 @@ class FlightRecorder:
                 d["pos_lo"] = int(row["free_pages"])
                 d["pos_hi"] = int(row["spec_acc"])
                 d["pages_walked"] = int(row["chunks"])
+                if row["active"]:
+                    # The attention kernel's block (ISSUE 48): positions a
+                    # row-block x KV heads a program. A dense cache runs
+                    # no paged kernel and names none.
+                    d["block"] = "%dx%d" % (row["active"],
+                                            row["free_slots"])
             elif kind == PROF:
                 # Profiler capture boundary (ISSUE 8): the rid carries
                 # the capture's trace directory, so a Perfetto timeline

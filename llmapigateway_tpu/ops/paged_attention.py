@@ -955,12 +955,17 @@ def paged_decode_attention(q: jax.Array, k_new: jax.Array,
 # (``G x bt``) and of all a program's heads that meet a page in one pass,
 # and what the program may take of VMEM by :func:`_prefill_vmem_bytes`'
 # count, which is generous (a v5e kernel is lent 16 MiB unless it asks:
-# the call asks for ``_PREFILL_VMEM_LIMIT_BYTES``). From two sweeps on
-# the chip over row-blocks of 32-512 positions and 1-8 heads at the
-# served folds of 4, 7 and 8 (PERF.md, PR 37): one KV head a program is
-# 1.4-1.6x slower than two at any row-block (inferred: a lone head's
-# dots and vector passes wait for each other, a second head's fill the
-# gaps); two heads of ~512 rows are within a tenth of the best shape
+# the call asks for ``_PREFILL_VMEM_LIMIT_BYTES``). A program holds ONE
+# row-block's q and out (and the next in flight: the grid's last axis is
+# the row-block, so the pipeline moves them), its pages and its state —
+# nothing by the row, so the count does not grow with ``T`` and the fold
+# at 16 query heads a KV head gets two heads like every other (a whole
+# row's q and out would be 8 MiB a head there; PERF.md, PR 48).
+# From two sweeps on the chip over row-blocks of 32-512 positions and 1-8
+# heads at the served folds of 4, 7 and 8 (PERF.md, PR 37): one KV head a
+# program is 1.4-1.6x slower than two at any row-block (inferred: a lone
+# head's dots and vector passes wait for each other, a second head's fill
+# the gaps); two heads of ~512 rows are within a tenth of the best shape
 # found at every fold; and the compiler unrolls a pass into one
 # instruction a vector register, so a kernel's COMPILE follows the block
 # (3.2-3.9 s at 4 096 rows against 0.3-0.5 at 1 024: a checkout's first
@@ -972,17 +977,21 @@ _PREFILL_VMEM_BYTES = 16 * 2 ** 20
 _PREFILL_VMEM_LIMIT_BYTES = 32 * 2 ** 20
 
 
-def _prefill_vmem_bytes(bt: int, heads: int, T: int, G: int, page: int,
-                        Dh: int, q_itemsize: int, kv_itemsize: int,
-                        quant: bool, ppb: int) -> int:
+def _prefill_vmem_bytes(bt: int, heads: int, G: int, page: int, Dh: int,
+                        q_itemsize: int, kv_itemsize: int, quant: bool,
+                        ppb: int) -> int:
     """What a program of the paged prefill kernel holds in VMEM with
-    ``bt`` query positions a row-block and ``heads`` KV heads folded."""
+    ``bt`` query positions a row-block and ``heads`` KV heads folded:
+    the row-block's state and temporaries, its q and out blocks (two
+    buffers each — this row-block's and the next one's, in flight) and
+    the pages' buffers. Nothing is held by the row, so the chunk's
+    length is no argument."""
     rows = heads * G * bt
     # Scores, their exponentials and the scaled probabilities (float32
     # [rows, page] each); the accumulator; m and l (a lane-padded column
     # each).
     body = rows * (3 * page * 4 + Dh * 4 + 2 * 128 * 4)
-    q_and_out = 2 * 2 * heads * G * T * Dh * q_itemsize
+    q_and_out = 2 * 2 * rows * Dh * q_itemsize
     # K and V, two buffers each, and a page of each converted for the dots.
     pages = heads * (2 * 2 * _kv_block_bytes(page, Dh, kv_itemsize, quant,
                                              ppb)
@@ -1005,7 +1014,7 @@ def prefill_block_shape(T: int, G: int, KV: int, page: int, Dh: int,
     both give way, ``bt`` down to eight positions and ``heads`` to one,
     until the program fits ``_PREFILL_VMEM_BYTES``."""
     def fits(bt, heads):
-        return _prefill_vmem_bytes(bt, heads, T, G, page, Dh, q_itemsize,
+        return _prefill_vmem_bytes(bt, heads, G, page, Dh, q_itemsize,
                                    kv_itemsize, quant, ppb
                                    ) <= _PREFILL_VMEM_BYTES
     if block_t is None:
@@ -1055,31 +1064,34 @@ def prefill_pages_walked(starts, T: int, bt: int, page: int, window: int,
 def _paged_prefill_kernel(pt_ref, start_ref, layer_ref, q_ref, *refs,
                           block_t: int, page: int, window: int,
                           pages_per_block: int, n_table_blocks: int):
-    """Program ``(row b, group of folded KV heads)``: for each row-block of
-    ``block_t`` query positions in turn, walk the blocks of pages its
-    queries can see (:func:`_prefill_live_blocks`) and only those. The
-    pools stay in HBM; a block — a run of pages for the program's heads —
-    is copied into one of two VMEM buffers while the block before it is
-    attended, and the walk is one sequence over (row-block, block): a
-    row-block's last block prefetches the next row-block's first.
-    ``q_ref``/``o_ref``: ``[1, T // block_t, heads, G * block_t, Dh]``, a
-    head's ``G`` query heads folded into the rows (row ``g * block_t + i``
-    is query head ``g`` at position ``first_q + i``). ``refs``: the
-    LAYER-STACKED pool sides in HBM (K, V; int8: K, its scale plane, V,
-    its scale plane), of which only layer ``layer_ref[0]`` is read, the
-    output block, a VMEM buffer pair per pool side in the same order,
-    the online-softmax state (m, l, acc) and the DMA semaphores
-    ``[buffer, side]``."""
-    n_sides = (len(refs) - 5) // 2       # K, V (int8: + their scale planes)
+    """Program ``(row b, group of folded KV heads, row-block t)``: walk the
+    blocks of pages the row-block's ``block_t`` queries can see
+    (:func:`_prefill_live_blocks`) and only those. The pools stay in HBM;
+    a block — a run of pages for the program's heads — is copied into one
+    of two VMEM buffers while the block before it is attended, and the
+    walk is one sequence over (row-block, block): the grid runs a row's
+    row-blocks in turn, a row-block's last block prefetches the next
+    row-block's first, and how many blocks the row has walked (which
+    buffer the next one lands in) is carried in SMEM from one row-block
+    to the next. ``q_ref``/``o_ref``: ``[1, 1, heads, G * block_t, Dh]``,
+    THIS row-block's — the pipeline fetches the next one's q and writes
+    the last one's out meanwhile — a head's ``G`` query heads folded into
+    the rows (row ``g * block_t + i`` is query head ``g`` at position
+    ``first_q + i``). ``refs``: the LAYER-STACKED pool sides in HBM (K, V;
+    int8: K, its scale plane, V, its scale plane), of which only layer
+    ``layer_ref[0]`` is read, the output block, a VMEM buffer pair per
+    pool side in the same order, the online-softmax state (m, l, acc),
+    the walk's count and the DMA semaphores ``[buffer, side]``."""
+    n_sides = (len(refs) - 6) // 2       # K, V (int8: + their scale planes)
     pools, o_ref = refs[:n_sides], refs[n_sides]
     bufs = refs[n_sides + 1:2 * n_sides + 1]
-    m_ref, l_ref, acc_ref, sem = refs[2 * n_sides + 1:]
+    m_ref, l_ref, acc_ref, walked_ref, sem = refs[2 * n_sides + 1:]
     if n_sides == 4:
         k_buf, ks_buf, v_buf, vs_buf = bufs
     else:
         (k_buf, v_buf), ks_buf, vs_buf = bufs, None, None
-    b, hb = pl.program_id(0), pl.program_id(1)
-    n_row_blocks, heads = q_ref.shape[1], q_ref.shape[2]
+    b, hb, t = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    n_row_blocks, heads = pl.num_programs(2), q_ref.shape[2]
     bt, ppb = block_t, pages_per_block
     layer, start = layer_ref[0], start_ref[b]
 
@@ -1099,86 +1111,86 @@ def _paged_prefill_kernel(pt_ref, start_ref, layer_ref, q_ref, *refs,
             vmem.at[buf], sem.at[buf, side])
             for side, (pool, vmem) in enumerate(zip(pools, bufs))]
 
-    for c in copies(live(0)[1], 0):
-        c.start()
+    first_q, first, n_blocks = live(t)
+    last_q = first_q + (bt - 1)
 
-    def row_block(t, walked):
-        first_q, first, n_blocks = live(t)
-        last_q = first_q + (bt - 1)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    @pl.when(t == 0)
+    def _first_of_the_row():
+        walked_ref[0] = 0
+        for c in copies(first, 0):
+            c.start()
+    walked = walked_ref[0]
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        def attend(buf, sub, mask):
-            m, l, acc = _attend_heads(
-                q_ref[0, t], k_buf[buf, sub], v_buf[buf, sub],
-                None if ks_buf is None else ks_buf[buf, sub],
-                None if vs_buf is None else vs_buf[buf, sub],
-                mask, m_ref[...], l_ref[...], acc_ref[...])
-            m_ref[...], l_ref[...], acc_ref[...] = m, l, acc
+    def attend(buf, sub, mask):
+        m, l, acc = _attend_heads(
+            q_ref[0, 0], k_buf[buf, sub], v_buf[buf, sub],
+            None if ks_buf is None else ks_buf[buf, sub],
+            None if vs_buf is None else vs_buf[buf, sub],
+            mask, m_ref[...], l_ref[...], acc_ref[...])
+        m_ref[...], l_ref[...], acc_ref[...] = m, l, acc
 
-        def block(i, carry):
-            buf = (walked + i) % 2
-            # Start the NEXT block of the walk — this row-block's, or the
-            # next one's first — into the other buffer, then wait for
-            # this one.
-            ends = i == n_blocks - 1
-            nblk = jnp.where(
-                ends, live(jnp.minimum(t + 1, n_row_blocks - 1))[1],
-                first + i + 1)
+    def block(i, carry):
+        buf = (walked + i) % 2
+        # Start the NEXT block of the walk — this row-block's, or the
+        # next one's first — into the other buffer, then wait for this
+        # one.
+        ends = i == n_blocks - 1
+        nblk = jnp.where(
+            ends, live(jnp.minimum(t + 1, n_row_blocks - 1))[1],
+            first + i + 1)
 
-            @pl.when(jnp.logical_not(ends & (t == n_row_blocks - 1)))
-            def _prefetch():
-                for c in copies(nblk, 1 - buf):
-                    c.start()
-            for c in copies(first + i, buf):
-                c.wait()
-            # Per-page attends over the block's sub-pages, unrolled
-            # (pages_per_block is compile-time), in ascending logical
-            # order: a row's updates are the per-page, per-head kernel's
-            # whatever the block shape. A page none of the queries sees
-            # (a run's tail, a run's head below the window) is skipped.
-            for sub in range(ppb):
-                lo = ((first + i) * ppb + sub) * page  # the page's first key
-                hi = lo + (page - 1)                   # ... and its last
-                visible = lo <= last_q
-                # Below the diagonal of EVERY row (and inside every
-                # row's window): the mask would select every score, so
-                # none is built — only the diagonal pages and the
-                # window's floor pages pay for iota, compare and select.
-                whole = hi <= first_q
+        @pl.when(jnp.logical_not(ends & (t == n_row_blocks - 1)))
+        def _prefetch():
+            for c in copies(nblk, 1 - buf):
+                c.start()
+        for c in copies(first + i, buf):
+            c.wait()
+        # Per-page attends over the block's sub-pages, unrolled
+        # (pages_per_block is compile-time), in ascending logical order:
+        # a row's updates are the per-page, per-head kernel's whatever
+        # the block shape. A page none of the queries sees (a run's
+        # tail, a run's head below the window) is skipped.
+        for sub in range(ppb):
+            lo = ((first + i) * ppb + sub) * page      # the page's first key
+            hi = lo + (page - 1)                       # ... and its last
+            visible = lo <= last_q
+            # Below the diagonal of EVERY row (and inside every row's
+            # window): the mask would select every score, so none is
+            # built — only the diagonal pages and the window's floor
+            # pages pay for iota, compare and select.
+            whole = hi <= first_q
+            if window:
+                visible = visible & (hi > first_q - window)
+                whole = whole & (lo > last_q - window)
+
+            def mask(scores, lo=lo):
+                row = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+                q_pos = first_q + (row & (bt - 1) if bt & (bt - 1) == 0
+                                   else row % bt)
+                s_pos = lo + jax.lax.broadcasted_iota(
+                    jnp.int32, scores.shape, 2)
+                ok = s_pos <= q_pos
                 if window:
-                    visible = visible & (hi > first_q - window)
-                    whole = whole & (lo > last_q - window)
+                    ok = ok & (s_pos > q_pos - window)
+                return jnp.where(ok, scores, NEG_INF)
 
-                def mask(scores, lo=lo):
-                    row = jax.lax.broadcasted_iota(jnp.int32,
-                                                   scores.shape, 1)
-                    q_pos = first_q + (row & (bt - 1) if bt & (bt - 1) == 0
-                                       else row % bt)
-                    s_pos = lo + jax.lax.broadcasted_iota(
-                        jnp.int32, scores.shape, 2)
-                    ok = s_pos <= q_pos
-                    if window:
-                        ok = ok & (s_pos > q_pos - window)
-                    return jnp.where(ok, scores, NEG_INF)
+            @pl.when(visible & whole)
+            def _whole(sub=sub):
+                attend(buf, sub, None)
 
-                @pl.when(visible & whole)
-                def _whole(sub=sub):
-                    attend(buf, sub, None)
+            @pl.when(visible & jnp.logical_not(whole))
+            def _edge(sub=sub, mask=mask):
+                attend(buf, sub, mask)
+        return carry
 
-                @pl.when(visible & jnp.logical_not(whole))
-                def _edge(sub=sub, mask=mask):
-                    attend(buf, sub, mask)
-            return carry
-
-        jax.lax.fori_loop(0, n_blocks, block, 0)
-        l = l_ref[...]
-        o_ref[0, t] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
-                       ).astype(o_ref.dtype)
-        return walked + n_blocks
-
-    jax.lax.fori_loop(0, n_row_blocks, row_block, 0)
+    jax.lax.fori_loop(0, n_blocks, block, 0)
+    l = l_ref[...]
+    o_ref[0, 0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
+                   ).astype(o_ref.dtype)
+    walked_ref[0] = walked + n_blocks
 
 
 def paged_prefill_attention(q: jax.Array, k_pages, v_pages,
@@ -1202,10 +1214,11 @@ def paged_prefill_attention(q: jax.Array, k_pages, v_pages,
     ``window``: sliding-window bound (0 = full causal). Returns
     [B, T, H*Dh].
 
-    One Pallas call, grid ``(B, KV // heads)`` — no page axis and no
-    query-head axis. A program holds one row's q and out for ``heads`` KV
-    heads, each head's ``G = H // KV`` query heads folded into the rows
-    of its blocks, and for each row-block of ``bt`` query positions walks
+    One Pallas call, grid ``(B, KV // heads, T // bt)`` — no page axis and
+    no query-head axis. A program holds ONE row-block's q and out for
+    ``heads`` KV heads (the next row-block's in flight), each head's
+    ``G = H // KV`` query heads folded into the rows of the block, and
+    for its ``bt`` query positions walks
     the LIVE blocks of pages (:func:`_prefill_live_blocks`: up to the
     last query's own key, from the first key the first query's window
     holds), copying each block ``(ppb, heads, page, Dh)`` from the HBM
@@ -1247,8 +1260,8 @@ def paged_prefill_attention(q: jax.Array, k_pages, v_pages,
     qb = q.reshape(B, nT, bt, KV, G, Dh).transpose(0, 1, 3, 4, 2, 5
                                                    ).reshape(B, nT, KV, rows, Dh)
 
-    q_spec = pl.BlockSpec((1, nT, heads, rows, Dh),
-                          lambda b, hb, pt, st, layer: (b, 0, hb, 0, 0))
+    q_spec = pl.BlockSpec((1, 1, heads, rows, Dh),
+                          lambda b, hb, t, pt, st, layer: (b, t, hb, 0, 0))
     kv_operands, buffers = _walk_buffers(k_pages, v_pages, ppb, heads)
 
     out = pl.pallas_call(
@@ -1257,7 +1270,7 @@ def paged_prefill_attention(q: jax.Array, k_pages, v_pages,
                           n_table_blocks=NP // ppb),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(B, KV // heads),
+            grid=(B, KV // heads, nT),
             in_specs=[q_spec,
                       *[pl.BlockSpec(memory_space=pl.ANY)] * len(kv_operands)],
             out_specs=q_spec,
@@ -1265,6 +1278,7 @@ def paged_prefill_attention(q: jax.Array, k_pages, v_pages,
                             pltpu.VMEM((heads, rows, 1), jnp.float32),
                             pltpu.VMEM((heads, rows, 1), jnp.float32),
                             pltpu.VMEM((heads, rows, Dh), jnp.float32),
+                            pltpu.SMEM((1,), jnp.int32),
                             pltpu.SemaphoreType.DMA((2, len(kv_operands)))],
         ),
         out_shape=jax.ShapeDtypeStruct(qb.shape, q.dtype),

@@ -143,7 +143,11 @@ def test_paged_prefill_compiles_for_v5e(chips, quant, window, ppb):
     ((28, 4, 8), (8, 4)),
     ((32, 8, 8), (8, 8)),
     ((64, 8, 8), (8, 8)),
-], ids=["28over4-t512", "28over4-t8", "32over8-t8", "64over8-t8"])
+    # Command A+: a fold of SIXTEEN, two heads a program since a program
+    # holds q and out by the row-block (PR 48).
+    ((128, 8, T), (32, 2)),
+], ids=["28over4-t512", "28over4-t8", "32over8-t8", "64over8-t8",
+        "128over8-t512"])
 @WINDOWS
 def test_paged_prefill_fold_compiles_for_v5e(chips, window, case):
     """PR 37: the block shapes the rule picks at the served folds are what
@@ -217,18 +221,19 @@ PREFILL_ROWS = {"mistral-7b": 1, "solar-open2-ep8": 4,
 
 def test_the_block_shapes_at_sixteen_heads_a_group_and_at_the_older_cells():
     """Pure shape arithmetic (no chip, no compile): at 16 query heads a KV
-    head a 512-token chunk runs 32 positions a row-block and one KV head a
-    program (512 rows), where Mistral (4 a group) gets 128 positions and
-    two heads and SmallThinker (7 a group, 4 KV heads) 64 and two — the
-    shapes PR 37 gave them, which PR 44 left alone; a decode block holds
-    all 8 KV heads of a page at either grouping."""
+    head a 512-token chunk runs 32 positions a row-block and TWO KV heads a
+    program (1,024 rows) since a program holds q and out by the row-block
+    (PR 48; one head until then), where Mistral (4 a group) gets 128
+    positions and two heads and SmallThinker (7 a group, 4 KV heads) 64 and
+    two — the shapes PR 37 gave them; a decode block holds all 8 KV heads
+    of a page at either grouping."""
     def at(G, KV):
         return pa.prefill_block_shape(T, G, KV, PAGE, DH, 2, 1, True, 1)
-    assert at(16, 8) == (32, 1)
+    assert at(16, 8) == (32, 2)
     assert at(4, 8) == (128, 2)
     assert at(7, 4) == (64, 2)
     assert pa._decode_heads_per_block(8, PAGE, DH, 1, True, 1) == 8
-    assert pa._prefill_vmem_bytes(32, 1, T, 16, PAGE, DH, 2, 1, True, 1) \
+    assert pa._prefill_vmem_bytes(32, 2, 16, PAGE, DH, 2, 1, True, 1) \
         <= pa._PREFILL_VMEM_BYTES
     # The walk that shape costs: four times Mistral's row-blocks a chunk.
     starts = [8192]

@@ -215,8 +215,9 @@ def test_prefill_step_attends_a_layer_in_one_call_with_no_page_axis(
     ONE Pallas call (the benchmark counts chunks by them); every pool
     side reaches it un-sliced — the whole stacked ``[L, P, KV, page, Dh]``
     pool (and scale planes), left in HBM — and its grid is ``(rows,
-    KV // heads)``: no axis steps through the table's 32 pages or the 32
-    query heads (the parent's grid was ``(1, 32, 4, 32)``)."""
+    KV // heads, row-blocks)`` (PR 48: the row-block is an axis, so q and
+    out travel by it): no axis steps through the table's 32 pages or the
+    32 query heads (PR 37's parent's grid was ``(1, 32, 4, 32)``)."""
     slots, pages, bucket = 8, 513, 512
     engine, config, state, _ = _two_layer_engine(
         chips, monkeypatch, slots, pages, 2)
@@ -244,7 +245,7 @@ def test_prefill_step_attends_a_layer_in_one_call_with_no_page_axis(
         bucket, config.n_heads // config.n_kv_heads, config.n_kv_heads,
         PAGE, DH, 2, 1, True, 1)
     assert tuple(eqn.params["grid_mapping"].grid) \
-        == (1, config.n_kv_heads // heads)
+        == (1, config.n_kv_heads // heads, bucket // bt)
     pool = {(2, pages, config.n_kv_heads, PAGE, DH): 0,
             (2, pages, config.n_kv_heads, 1, PAGE): 0}
     for var in eqn.invars:

@@ -13,6 +13,14 @@ the CPU with the kernels interpreted, as commit 5ba9ec1 (the parent of
 PR 47) gives them; ``MOVED`` the parent's digests of the two programs
 that PR set out to change, which must NOT come back.
 
+PR 48 gave the paged GQA prefill kernel's grid a row-block axis
+(``ops/paged_attention.py``): the ``prefill`` programs of the three presets
+that hold that call (``tiny-smallthinker-test``, ``tiny-cohere2-test``,
+``tiny-hybrid-test``) are recorded anew and their digests at commit aefa4b6
+(the parent of PR 48) went to ``MOVED``; every ``decode`` digest and the
+``prefill`` digests of the two latent families, which run no paged GQA
+prefill, stand unedited: nothing else moved.
+
 A PR that changes a pinned program on purpose records it anew:
 ``JAX_PLATFORMS=cpu python tests/test_hybrid_programs_pinned.py`` prints
 the table of the tree it runs in."""
@@ -26,17 +34,21 @@ import pytest
 
 PINNED = {
     ("tiny-smallthinker-test", "decode"): "1ef682edbae673474e9f2429",
-    ("tiny-smallthinker-test", "prefill"): "74d47e27d3de71f868c4c95c",
+    ("tiny-smallthinker-test", "prefill"): "c346e1e2aa1d52741cbb0807",
     ("tiny-mistral4-test", "decode"): "5c81fbba224cd6790ca3cb9a",
     ("tiny-mistral4-test", "prefill"): "9e419a710b190f05078de7a8",
     ("tiny-cohere2-test", "decode"): "fa375c9237a876ec6e0570bd",
-    ("tiny-cohere2-test", "prefill"): "22d44b265802966e5ba486a8",
-    ("tiny-hybrid-test", "prefill"): "a72e4d6d2f50468d59d25459",
+    ("tiny-cohere2-test", "prefill"): "79bc67a8af51d7c71214e630",
+    ("tiny-hybrid-test", "prefill"): "564c6e03e7fc0a3cf1002e9c",
     ("tiny-gigachat35-test", "prefill"): "6bcce1638edad7771fcfb324",
 }
 MOVED = {
     ("tiny-hybrid-test", "decode"): "42da75fd2c69ad5db6218ae7",
     ("tiny-gigachat35-test", "decode"): "1268797e7a0047d117fd9451",
+    # PR 48: the parent's prefill programs around the paged GQA kernel.
+    ("tiny-smallthinker-test", "prefill"): "74d47e27d3de71f868c4c95c",
+    ("tiny-cohere2-test", "prefill"): "22d44b265802966e5ba486a8",
+    ("tiny-hybrid-test", "prefill"): "a72e4d6d2f50468d59d25459",
 }
 
 
@@ -57,16 +69,15 @@ def lowered_digest(preset: str, program: str) -> str:
 
 @pytest.mark.parametrize("preset, program", list(PINNED),
                          ids=["-".join(k) for k in PINNED])
-def test_a_program_pr47_did_not_mean_to_move_lowers_as_at_its_parent(
-        preset, program):
+def test_a_program_no_pr_meant_to_move_lowers_as_pinned(preset, program):
     assert lowered_digest(preset, program) == PINNED[preset, program]
 
 
 @pytest.mark.parametrize("preset, program", list(MOVED),
                          ids=["-".join(k) for k in MOVED])
-def test_a_linear_familys_decode_program_is_not_the_parents(preset, program):
-    """The digest sees a change: the two programs PR 47 rebuilt differ
-    from the parent's, and hold the kernel."""
+def test_a_rebuilt_program_is_not_its_parents(preset, program):
+    """The digest sees a change: the two decode programs PR 47 rebuilt and
+    the three prefill programs PR 48 did differ from their parents'."""
     assert lowered_digest(preset, program) != MOVED[preset, program]
 
 

@@ -43,8 +43,9 @@ PAGE, DH, NP = 8, 16, 32
 S = PAGE * NP                        # 256 tokens a slot
 WINDOW = 100                         # not a multiple of the page
 RING = 16                            # pages the ring row keeps
-HEADS = pytest.mark.parametrize("H,KV", [(32, 8), (64, 8), (28, 4)],
-                                ids=["32over8", "64over8", "28over4"])
+HEADS = pytest.mark.parametrize(
+    "H,KV", [(32, 8), (64, 8), (28, 4), (128, 8)],
+    ids=["32over8", "64over8", "28over4", "128over8"])
 WINDOWS = pytest.mark.parametrize("window", [0, WINDOW],
                                   ids=["full", "windowed"])
 QUANT = pytest.mark.parametrize("quant", [True, False],
@@ -153,16 +154,24 @@ def test_every_bucket_matches_reference(window, T, B):
 # A row's result does not depend on the block's shape
 # ---------------------------------------------------------------------------
 
+@pytest.mark.parametrize("H,KV,shape,alone", [
+    # Everything folds: one row-block, every KV head a program.
+    (8, 4, (64, 4), ([0], [2], [1, 3])),
+    # Command A+'s fold, 16 query heads a KV head (PR 48): the rule's own
+    # shape is two row-blocks a row and two heads a program, so the walk
+    # crosses a row-block's end with the next one's first page in flight.
+    (128, 8, (32, 2), ())], ids=["8over4", "128over8"])
 @WINDOWS
 @QUANT
 def test_a_rows_result_is_bit_equal_across_block_shapes(monkeypatch, quant,
-                                                        window):
-    T, H, KV = 64, 8, 4
+                                                        window, H, KV,
+                                                        shape, alone):
+    T, G = 64, H // KV
     q, pk, pv, table = _inputs(4, T, H, KV, quant, seed=11)
     start = _starts(T, window)
     itemsize = 1 if quant else 2
-    assert pa.prefill_block_shape(T, H // KV, KV, PAGE, DH, 4, itemsize,
-                                  quant, 1) == (T, KV)      # the full fold
+    assert pa.prefill_block_shape(T, G, KV, PAGE, DH, 4, itemsize,
+                                  quant, 1) == shape        # the rule's fold
     full = _kernel(q, pk, pv, table, start, window)
 
     # A quarter of the chunk a row-block: rows now walk fewer pages they
@@ -173,14 +182,14 @@ def test_a_rows_result_is_bit_equal_across_block_shapes(monkeypatch, quant,
     else:
         assert np.array_equal(quarter, full)
     # A row alone against the row among others; two rows against four.
-    for rows in ([0], [2], [1, 3]):
-        alone = _kernel(q[np.asarray(rows)], pk, pv, table[np.asarray(rows)],
-                        [start[r] for r in rows], window)
-        assert np.array_equal(alone, full[rows])
+    for rows in alone:
+        one = _kernel(q[np.asarray(rows)], pk, pv, table[np.asarray(rows)],
+                      [start[r] for r in rows], window)
+        assert np.array_equal(one, full[rows])
     # ONE KV head a program: a block that holds one head and no more.
-    monkeypatch.setattr(pa, "_PREFILL_BLOCK_ROWS", H // KV * T)
-    assert pa.prefill_block_shape(T, H // KV, KV, PAGE, DH, 4, itemsize,
-                                  quant, 1) == (T, 1)
+    monkeypatch.setattr(pa, "_PREFILL_BLOCK_ROWS", G * shape[0])
+    assert pa.prefill_block_shape(T, G, KV, PAGE, DH, 4, itemsize,
+                                  quant, 1) == (shape[0], 1)
     assert np.array_equal(_kernel(q, pk, pv, table, start, window), full)
 
 
@@ -275,8 +284,13 @@ MIB = 2 ** 20
     ((512, 1, 8, 256, 128, 2, 1, True, 1), (512, 2)),
     # A bf16 pool in runs of four pages.
     ((512, 4, 8, 256, 128, 2, 2, False, 4), (128, 2)),
-    # Pages too large for the rows the rule wants: VMEM decides.
-    ((512, 4, 8, 1024, 256, 2, 2, False, 4), (32, 1)),
+    # Pages too large for the rows the rule wants: VMEM decides (13.5 MiB
+    # at 64 x 1; 17.5 at 128 x 1 and 27 at 64 x 2 — the runs of four
+    # pages and the score tiles, no longer the row's q and out).
+    ((512, 4, 8, 1024, 256, 2, 2, False, 4), (64, 1)),
+    # Command A+ (128/8), 16 a group: 32 positions and TWO heads since a
+    # program holds q and out by the row-block (PR 48; 32 x 1 until then).
+    ((512, 16, 8, 256, 128, 2, 1, True, 1), (32, 2)),
     # The tests' geometry: everything folds.
     ((64, 2, 4, 8, 16, 4, 1, True, 1), (64, 4)),
 ], ids=lambda c: "-".join(map(str, c[0])))
@@ -284,7 +298,7 @@ def test_block_shape_rule(case):
     args, (bt, heads) = case
     assert pa.prefill_block_shape(*args) == (bt, heads)
     T, G, KV = args[:3]
-    rule_args, args = args, args[:2] + args[3:]   # a program's: not KV
+    rule_args, args = args, args[1:2] + args[3:]  # a program's: not T, KV
     assert T % bt == 0 and bt & (bt - 1) == 0 and KV % heads == 0
     assert G * bt <= pa._PREFILL_HEAD_ROWS or bt == 8
     assert heads * G * bt <= pa._PREFILL_BLOCK_ROWS or heads == 1

@@ -481,11 +481,15 @@ def test_the_annotation_option_is_gone():
 def test_prefill_record_snapshot():
     rec = fl.FlightRecorder(capacity=16, clock=FakeClock(50.0))
     seq = rec.record(fl.PREFILL, t=49.5, dur_ms=166.8, depth=2, val=512.0,
-                     tokens=900, free_pages=1024, spec_acc=4096, chunks=58)
+                     tokens=900, free_pages=1024, spec_acc=4096, chunks=58,
+                     active=32, free_slots=2)
     assert rec.snapshot() == [{
         "seq": seq, "t": 49.5, "kind": "prefill", "dur_ms": 166.8,
         "rows": 2, "bucket": 512, "tokens": 900, "pos_lo": 1024,
-        "pos_hi": 4096, "pages_walked": 58}]
+        "pos_hi": 4096, "pages_walked": 58, "block": "32x2"}]
+    # A dense cache's dispatch runs no paged kernel and names no block.
+    rec.record(fl.PREFILL, t=49.6, dur_ms=1.0, depth=1, val=8.0, tokens=8)
+    assert "block" not in rec.snapshot()[-1]
     # No lifecycle counter moves, and the STEP record's shape is untouched.
     assert rec.stats()["flight_admits"] == 0
     rec.record(fl.STEP, flag=fl.F_PREFILL, chunks=1, dur_ms=1.0)
@@ -515,6 +519,16 @@ async def test_engine_leaves_one_prefill_record_per_dispatch(engine):
     # of 8: each chunk walks up to the page of its last token, where a
     # grid with a page axis stepped through the table.
     assert [r["pages_walked"] for r in pre] == [2, 4, 5]
+    # ... at the block the kernel's rule picks a bucket (ISSUE 48): the
+    # tiny model's two KV heads both fold, the whole bucket a row-block;
+    # the kernel registry's prefill rows say the same.
+    assert [r["block"] for r in pre] == ["32x2", "32x2", "8x2"]
+    assert {"8": "8x2", "32": "32x2"}.items() \
+        <= after["prefill_kernel_blocks"].items()
+    variants = {r["kernel"]: r["variant_block"]
+                for r in engine.kernels.table() if r["kind"] == "prefill"}
+    assert variants["prefill.b32.k1"] == "32x2" \
+        and variants["prefill.b8.k1"] == "8x2"
     assert after["prefill_kv_pages_walked_total"] \
         - stats["prefill_kv_pages_walked_total"] == 11
     assert after["prefill_kv_pages_table_total"] \
