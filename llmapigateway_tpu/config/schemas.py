@@ -25,8 +25,8 @@ class DisaggregationConfig(BaseModel):
     When enabled, the engine's batch slots split into a prefill pool and
     a decode pool over ONE shared paged KV pool; a completed prefill
     hands its KV to the decode pool by allocator refcount transfer (zero
-    device copies). Requires ``kv_layout: paged``; incompatible with
-    speculative decoding and SWA ring mode (rejected at engine build).
+    device copies). Incompatible with speculative decoding and SWA ring
+    mode (rejected at engine build).
     """
     model_config = ConfigDict(extra="forbid")
 
@@ -116,13 +116,20 @@ class LocalEngineConfig(BaseModel):
 
     max_batch_size: int = 8
     max_seq_len: int = 4096
-    # Paged is THE serving path since 0.19 (ISSUE 6): page-pool KV with
-    # admission-reservation backpressure, superpage kernel blocking, and
-    # the radix prefix cache all hang off it, and the page-size sweep
-    # closed the old paged-vs-contiguous decode gap (BENCH_SELF_r5b: the
-    # 256-page point beats contiguous). "contiguous" remains as a
-    # test-only numerical reference.
-    kv_layout: str = "paged"        # "paged" | "contiguous"
+    # The KV cache is a page pool; the key is accepted for old files and
+    # has one value (PR 49 removed the contiguous layout from the engine).
+    kv_layout: str = "paged"
+
+    @field_validator("kv_layout")
+    @classmethod
+    def _one_layout(cls, v: str) -> str:
+        if v != "paged":
+            raise ValueError(
+                f"kv_layout {v!r}: the KV cache is a page pool and 'paged' "
+                "is the only value; the contiguous layout was removed "
+                "(PR 49). Drop the key")
+        return v
+
     # Page size doubles as the paged kernel's DMA block; 256 is the
     # measured optimum on v5e (2026-07-31 ladder: 1647.8 vs 1443.7
     # tok/s at 128, TinyLlama bs=8). Smaller pages trade a little DMA

@@ -120,11 +120,6 @@ class DisaggController:
 
     def __init__(self, engine: "InferenceEngine", dcfg) -> None:
         B = engine.B
-        if not engine.paged:
-            raise ValueError(
-                "disaggregation requires kv_layout='paged': the KV "
-                "handoff is a page-table refcount transfer; a contiguous "
-                "cache would need a real device copy")
         if engine.spec_k:
             raise ValueError(
                 "disaggregation + spec_draft_len is not supported (v1): "

@@ -18,9 +18,10 @@ Design (SURVEY.md §2b "Serving scheduler", §7 steps 5-6):
   one burst; slot release/re-admission races are epoch-guarded
   (``_flush_entry``).
 * **Deferred-insert decode.** Decode attention reads the STALE cache plus
-  a self-column, and every layer's new K/V is inserted once per step
-  outside the layer scan (models/llama.py ``insert_kv_stacked``) — the
-  per-layer functional insert lowers to serialized TPU scatters.
+  a self-column, and every layer's new K/V is written once per step
+  outside the layer scan (the provider's ``insert_all``,
+  ops/paged_attention.py) — a per-layer functional insert lowers to
+  serialized TPU scatters.
 * **Greedy fast path + speculation.** When every active slot decodes at
   temperature 0, an argmax-only program runs (no full-vocab sort), and
   with ``spec_draft_len`` set, prompt-lookup speculative bursts verify k
@@ -34,7 +35,7 @@ Design (SURVEY.md §2b "Serving scheduler", §7 steps 5-6):
 * Per-slot sampling params live in device arrays; sampling is part of the
   decode program (no host round-trip per token beyond the sampled ids).
 
-The serving KV layout is the paged pool (ops/paged_attention.py
+The KV cache is a page pool (ops/paged_attention.py
 ``PagedKVCache`` + engine/paged.py allocator): admission reserves pages
 for a request's whole lifetime — page exhaustion is backpressure at
 admission, never a mid-generation failure — and the radix prefix cache
@@ -42,15 +43,13 @@ admission, never a mid-generation failure — and the radix prefix cache
 whose prefix is resident maps the matched blocks into its page table and
 starts prefill at the match boundary, skipping the matched span's FLOPs
 outright (insert-on-release / LRU-by-leaf eviction / refcount pinning).
-``kv_layout="contiguous"`` keeps the dense per-slot cache
-(models/llama.py ``KVCache``) as a test-only numerical reference.
 
 Two independent int8 precision knobs (models/quant.py): ``quant`` stores
 every matmul weight as per-channel int8 (W8A8 on the MXU's native int8
 path — decode is weight-bandwidth-bound, so ~2× tok/s) and ``kv_quant``
-stores K/V as per-token int8 (halves KV bandwidth and capacity; both
-layouts). Both are plain ``{"q","s"}`` dict leaves in the params/cache
-pytrees, so sharding and scanning treat them uniformly.
+stores K/V as per-token int8 (halves KV bandwidth and capacity). Both
+are plain ``{"q","s"}`` dict leaves in the params/cache pytrees, so
+sharding and scanning treat them uniformly.
 """
 from __future__ import annotations
 
@@ -69,12 +68,12 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..config.schemas import LocalEngineConfig
-from ..models import forward_fn, init_fn, llama
+from ..models import forward_fn, init_fn
 from ..models.config import ModelConfig, get_preset
 from ..obs.device import phase as _device_phase
 from ..obs.phases import ReqWaits, SchedLedger
 from ..parallel.mesh import MeshSpec, build_mesh
-from ..parallel.sharding import cache_sharding, param_shardings
+from ..parallel.sharding import param_shardings
 from .sampling import SamplingParams, sample
 from .tokenizer import IncrementalDetokenizer, load_tokenizer
 
@@ -322,13 +321,10 @@ class InferenceEngine:
                 self._burst_depths.add(max(1, self.decode_burst // frac))
             self._burst_depths.add(max(1, 3 * self.decode_burst // 4))
         self._burst_depths = tuple(sorted(self._burst_depths))
-        if engine_cfg.kv_layout not in ("contiguous", "paged"):
-            raise ValueError(f"unknown kv_layout {engine_cfg.kv_layout!r}")
-        self.paged = engine_cfg.kv_layout == "paged"
         # Effective page size, clamped to the cache extent: a page larger
-        # than S would waste a whole-page tail per slot (with paged now
-        # the DEFAULT layout, small test/dev engines would otherwise carry
-        # 256-token pages for 64-token contexts).
+        # than S would waste a whole-page tail per slot (small test/dev
+        # engines would otherwise carry 256-token pages for 64-token
+        # contexts).
         self.kv_page = max(1, min(engine_cfg.kv_page_size, self.S))
         self._swa_margin = 0            # in-flight burst margin, tokens
         # Int8 weight quantization (models/quant.py): validated here so a
@@ -374,7 +370,7 @@ class InferenceEngine:
         self.attention_impl = self._resolve_attention_impl()
         # Whether the decode programs and prefill_step leave the page pool
         # where it lies (read by layer, written through aliased operands);
-        # static per engine, set by _compile_paged — exposed in stats() too.
+        # static per engine, set by _compile — exposed in stats() too.
         self.kv_pool_in_place = False
         self._compile()
         logger.info("engine build: params %.1fs, state+programs %.1fs "
@@ -491,8 +487,6 @@ class InferenceEngine:
                           "(engine/checkpoint.py)"},
         # A block of recurrent state a slot (models/hybrid.py).
         "state": {
-            "contiguous": "its softmax layers are served from the page "
-                          "pool only",
             "prefix_cache": "a cached prefix holds KV pages but not the "
                             "recurrent state at its end; set prefix_cache "
                             "false",
@@ -506,8 +500,6 @@ class InferenceEngine:
                       "state block"},
         # SEVERAL cache groups (window and global layers in one model).
         "groups": {
-            "contiguous": "a dense cache keeps every layer's whole "
-                          "context; the groups are page pools",
             "prefix_cache": "the ring re-targets a windowed group's pages, "
                             "and a cached prefix would need the global "
                             "group's pages AND the window's last tokens; "
@@ -519,8 +511,6 @@ class InferenceEngine:
                       "(PageAllocator.transfer)"},
         # A LATENT pool (``latent_width`` numbers a token, models/mla.py).
         "latent": {
-            "contiguous": "the latent cache is a page pool, and no dense "
-                          "layout of it exists",
             "kv_quant": "the latent pool is bfloat16; an int8 latent needs "
                         "scale planes the latent kernel does not read. Set "
                         "kv_quant ''",
@@ -550,7 +540,6 @@ class InferenceEngine:
             return
         kinds.append("any")
         asked = {       # in the order they are refused
-            "contiguous": (not self.paged, "kv_layout 'contiguous'"),
             "kv_quant": (bool(self.kv_quant), "kv_quant 'int8'"),
             "prefix_cache": (cfg.prefix_cache, "prefix_cache"),
             "spec": (bool(self.spec_k), "spec_draft_len"),
@@ -585,8 +574,7 @@ class InferenceEngine:
     @property
     def _swa_ring_pages(self) -> int:
         """Pages a slot holds in the windowed group's ring (0: no ring)."""
-        return max((g.ring_pages for g in self.kv_groups), default=0) \
-            if self.paged else 0
+        return max((g.ring_pages for g in self.kv_groups), default=0)
 
     def _on_lifecycle_transition(self, frm: str, to: str,
                                  reason: str) -> None:
@@ -697,168 +685,145 @@ class InferenceEngine:
 
     def _init_state(self) -> None:
         c = self.model_cfg
-        self.kv_ppb = 1          # multi-page kernel blocking (paged only)
+        self.kv_ppb = 1          # multi-page kernel blocking
         self._prefix_cache = None       # guarded-by: loop
-        if self.paged:
-            from ..parallel.sharding import paged_cache_sharding
-            from ..ops.paged_attention import PagedKVCache
-            from .paged import PageAllocator
+        from ..parallel.sharding import paged_cache_sharding
+        from ..ops.paged_attention import PagedKVCache
+        from .paged import PageAllocator
 
-            page = self.kv_page
-            per_slot = (self.S + page - 1) // page
-            # Sliding-window RING reservation (one device), per WINDOWED
-            # cache group: the windowed kernels never read below pos −
-            # window, so a ring of O(window) physical pages serves ANY
-            # context length — ensure_mapped recycles each slot's oldest
-            # dead page onto the next logical page (mistral's rolling
-            # buffer, at page granularity). Margins: in-flight lag-one
-            # bursts may still read one burst below the current floor, and
-            # dispatch writes run one burst/chunk ahead. A global group
-            # holds the whole context.
-            # ONE copy of the margin: _swa_rotate's recycle floor must
-            # stay in lockstep with the capacity the ring was sized for,
-            # or rotation exhausts mid-stream.
-            self._swa_margin = self.decode_burst * (self.spec_k + 1)
-            span = max(self.prefill_chunk, self._swa_margin)
+        page = self.kv_page
+        per_slot = (self.S + page - 1) // page
+        # Sliding-window RING reservation (one device), per WINDOWED
+        # cache group: the windowed kernels never read below pos −
+        # window, so a ring of O(window) physical pages serves ANY
+        # context length — ensure_mapped recycles each slot's oldest
+        # dead page onto the next logical page (mistral's rolling
+        # buffer, at page granularity). Margins: in-flight lag-one
+        # bursts may still read one burst below the current floor, and
+        # dispatch writes run one burst/chunk ahead. A global group
+        # holds the whole context.
+        # ONE copy of the margin: _swa_rotate's recycle floor must
+        # stay in lockstep with the capacity the ring was sized for,
+        # or rotation exhausts mid-stream.
+        self._swa_margin = self.decode_burst * (self.spec_k + 1)
+        span = max(self.prefill_chunk, self._swa_margin)
 
-            def ring_for(window: int) -> int:
-                if not window or self.mesh.size > 1:
-                    return 0
-                ring = -(-(window + self._swa_margin + span) // page) + 2
-                if ring >= per_slot:
-                    return 0
-                logger.info(
-                    "paged SWA ring: %d pages/slot (window %d) instead "
-                    "of %d — steady-state KV footprint is O(window)",
-                    ring, window, per_slot)
-                return ring
-            rings = [ring_for(w) for w, _ in c.cache_groups]
-            # Multi-page kernel blocking (kv_pages_per_block): resolve the
-            # requested run length against what the pool can actually
-            # pack — the allocator's superpage runs are what license the
-            # kernels' gather-free index maps, so any geometry the
-            # allocator can't pack falls back to per-page blocks instead
-            # of serving wrong reads.
-            ppb_req = max(1, self.cfg.kv_pages_per_block)
-            if ppb_req > 1:
-                why = None
-                if any(rings):
-                    why = "SWA page ring (mappings rotate per page)"
-                elif per_slot % ppb_req:
-                    why = (f"pages per slot ({per_slot}) not divisible "
-                           f"by {ppb_req}")
-                elif (self.cfg.kv_num_pages
-                      and self.cfg.kv_num_pages % ppb_req):
-                    why = (f"kv_num_pages ({self.cfg.kv_num_pages}) not "
-                           f"divisible by {ppb_req}")
-                if why is None:
-                    self.kv_ppb = ppb_req
-                else:
-                    logger.warning(
-                        "kv_pages_per_block=%d falls back to per-page "
-                        "blocks: %s", ppb_req, why)
-            # One trash page; a PACKED pool reserves the whole trash
-            # superpage instead.
-            n_trash = self.kv_ppb
-            from .paged import CacheGroup, CacheGroups
-            periods = c.n_periods if c.layer_period else c.n_layers
-            groups = []
-            for (window, positions), ring in zip(c.cache_groups, rings):
-                # The most pages one slot ever holds — the ring where it
-                # runs, else the whole context — sizes the derived pool:
-                # every slot can hold a max-footprint sequence at once
-                # either way. (kv_num_pages sizes every group's pool.)
-                min_hold = ring or per_slot
-                num_pages = self.cfg.kv_num_pages or (
-                    self.B * min_hold + n_trash)
-                if num_pages - n_trash < min_hold:
-                    raise ValueError(
-                        f"kv_num_pages={num_pages} cannot hold one "
-                        f"max-footprint sequence ({min_hold} pages of "
-                        f"{page})")
-                groups.append(CacheGroup(
-                    periods * len(positions), window, ring,
-                    PageAllocator(num_pages, page, self.B, self.S,
-                                  pages_per_block=self.kv_ppb),
-                    kind="latent" if c.is_mla else "kv",
-                    token_bytes=self._kv_token_bytes()))
-            self.kv_groups = CacheGroups(groups)
-            num_pages = self.allocator.num_pages
-            # Radix prefix cache (ISSUE 6): cross-request KV reuse over
-            # the pool, block = one superpage run so the multi-page
-            # kernels apply to shared pages unchanged. Gated to the
-            # geometries where page identity is stable for a sequence's
-            # lifetime: non-SWA (ring rotation re-targets pages; windowed
-            # attention never re-reads old prefixes anyway).
-            if self.cfg.prefix_cache and not c.sliding_window:
-                from .prefix_cache import RadixPrefixCache
-                self._prefix_cache = RadixPrefixCache(
-                    self.allocator, block_tokens=self.kv_ppb * page)
-            psh = paged_cache_sharding(self.mesh, c.n_kv_heads)
-            # Layout owned by PagedKVCache.create (the one copy of the
-            # int8 {q,s} scheme); value leaves shard via psh, the rank-4
-            # [.., KV, 1, page] scale planes via the same spec with the
-            # page axis moved last (head_dim dropped, None for the unit
-            # dim). Created by ONE program with sharded outputs: the pool
-            # is the largest buffer after the weights, and zeros made on
-            # the default device and then placed would exist twice there
-            # for a moment — and whole on the first chip of a mesh.
-            ssh = NamedSharding(
-                self.mesh, P(*psh.spec[:-2], None, psh.spec[-2]))
-            side = {"q": psh, "s": ssh} if self.kv_quant else psh
-            if c.layer_period:
-                # A pool a cache group, of the softmax layers only;
-                # beside them a fixed block of recurrent state and a conv
-                # tail per slot for every linear layer of a period, and
-                # one more stack for the leading layers
-                # (models/hybrid.py HybridCache). A prefill that starts at
-                # position 0 starts from zero state whatever the block
-                # holds, so release, cancel and rebuild do no state work.
-                from ..models.hybrid import HybridCache
-                rep_sh = NamedSharding(self.mesh, P())
-                n_lin = (c.layer_period - len(c.softmax_positions)
-                         + bool(c.leading_dense))
-                k_sh = v_sh = (side,) * len(self.kv_groups)
-                if c.is_mla:        # ONE latent pool, no V side
-                    k_sh, v_sh = (rep_sh,), ()
-                self.cache = jax.jit(
-                    partial(HybridCache.create, c,
-                            tuple(g.allocator.num_pages
-                                  for g in self.kv_groups),
-                            page, self.B, self.dtype,
-                            kv_quant=self.kv_quant),
-                    out_shardings=HybridCache(
-                        k=k_sh, v=v_sh, counters=rep_sh,
-                        state=(rep_sh,) * n_lin, conv=(rep_sh,) * n_lin))()
+        def ring_for(window: int) -> int:
+            if not window or self.mesh.size > 1:
+                return 0
+            ring = -(-(window + self._swa_margin + span) // page) + 2
+            if ring >= per_slot:
+                return 0
+            logger.info(
+                "paged SWA ring: %d pages/slot (window %d) instead "
+                "of %d — steady-state KV footprint is O(window)",
+                ring, window, per_slot)
+            return ring
+        rings = [ring_for(w) for w, _ in c.cache_groups]
+        # Multi-page kernel blocking (kv_pages_per_block): resolve the
+        # requested run length against what the pool can actually
+        # pack — the allocator's superpage runs are what license the
+        # kernels' gather-free index maps, so any geometry the
+        # allocator can't pack falls back to per-page blocks instead
+        # of serving wrong reads.
+        ppb_req = max(1, self.cfg.kv_pages_per_block)
+        if ppb_req > 1:
+            why = None
+            if any(rings):
+                why = "SWA page ring (mappings rotate per page)"
+            elif per_slot % ppb_req:
+                why = (f"pages per slot ({per_slot}) not divisible "
+                       f"by {ppb_req}")
+            elif (self.cfg.kv_num_pages
+                  and self.cfg.kv_num_pages % ppb_req):
+                why = (f"kv_num_pages ({self.cfg.kv_num_pages}) not "
+                       f"divisible by {ppb_req}")
+            if why is None:
+                self.kv_ppb = ppb_req
             else:
-                self.cache = jax.jit(
-                    partial(PagedKVCache.create, c, num_pages, page,
-                            self.dtype, kv_quant=self.kv_quant),
-                    out_shardings=PagedKVCache(k=side, v=side))()
-            self._d_tables: tuple | None = None
-            self._table_dirty = True
+                logger.warning(
+                    "kv_pages_per_block=%d falls back to per-page "
+                    "blocks: %s", ppb_req, why)
+        # One trash page; a PACKED pool reserves the whole trash
+        # superpage instead.
+        n_trash = self.kv_ppb
+        from .paged import CacheGroup, CacheGroups
+        periods = c.n_periods if c.layer_period else c.n_layers
+        groups = []
+        for (window, positions), ring in zip(c.cache_groups, rings):
+            # The most pages one slot ever holds — the ring where it
+            # runs, else the whole context — sizes the derived pool:
+            # every slot can hold a max-footprint sequence at once
+            # either way. (kv_num_pages sizes every group's pool.)
+            min_hold = ring or per_slot
+            num_pages = self.cfg.kv_num_pages or (
+                self.B * min_hold + n_trash)
+            if num_pages - n_trash < min_hold:
+                raise ValueError(
+                    f"kv_num_pages={num_pages} cannot hold one "
+                    f"max-footprint sequence ({min_hold} pages of "
+                    f"{page})")
+            groups.append(CacheGroup(
+                periods * len(positions), window, ring,
+                PageAllocator(num_pages, page, self.B, self.S,
+                              pages_per_block=self.kv_ppb),
+                kind="latent" if c.is_mla else "kv",
+                token_bytes=self._kv_token_bytes()))
+        self.kv_groups = CacheGroups(groups)
+        num_pages = self.allocator.num_pages
+        # Radix prefix cache (ISSUE 6): cross-request KV reuse over
+        # the pool, block = one superpage run so the multi-page
+        # kernels apply to shared pages unchanged. Gated to the
+        # geometries where page identity is stable for a sequence's
+        # lifetime: non-SWA (ring rotation re-targets pages; windowed
+        # attention never re-reads old prefixes anyway).
+        if self.cfg.prefix_cache and not c.sliding_window:
+            from .prefix_cache import RadixPrefixCache
+            self._prefix_cache = RadixPrefixCache(
+                self.allocator, block_tokens=self.kv_ppb * page)
+        psh = paged_cache_sharding(self.mesh, c.n_kv_heads)
+        # Layout owned by PagedKVCache.create (the one copy of the
+        # int8 {q,s} scheme); value leaves shard via psh, the rank-4
+        # [.., KV, 1, page] scale planes via the same spec with the
+        # page axis moved last (head_dim dropped, None for the unit
+        # dim). Created by ONE program with sharded outputs: the pool
+        # is the largest buffer after the weights, and zeros made on
+        # the default device and then placed would exist twice there
+        # for a moment — and whole on the first chip of a mesh.
+        ssh = NamedSharding(
+            self.mesh, P(*psh.spec[:-2], None, psh.spec[-2]))
+        side = {"q": psh, "s": ssh} if self.kv_quant else psh
+        if c.layer_period:
+            # A pool a cache group, of the softmax layers only;
+            # beside them a fixed block of recurrent state and a conv
+            # tail per slot for every linear layer of a period, and
+            # one more stack for the leading layers
+            # (models/hybrid.py HybridCache). A prefill that starts at
+            # position 0 starts from zero state whatever the block
+            # holds, so release, cancel and rebuild do no state work.
+            from ..models.hybrid import HybridCache
+            rep_sh = NamedSharding(self.mesh, P())
+            n_lin = (c.layer_period - len(c.softmax_positions)
+                     + bool(c.leading_dense))
+            k_sh = v_sh = (side,) * len(self.kv_groups)
+            if c.is_mla:        # ONE latent pool, no V side
+                k_sh, v_sh = (rep_sh,), ()
+            self.cache = jax.jit(
+                partial(HybridCache.create, c,
+                        tuple(g.allocator.num_pages
+                              for g in self.kv_groups),
+                        page, self.B, self.dtype,
+                        kv_quant=self.kv_quant),
+                out_shardings=HybridCache(
+                    k=k_sh, v=v_sh, counters=rep_sh,
+                    state=(rep_sh,) * n_lin, conv=(rep_sh,) * n_lin))()
         else:
-            csh = cache_sharding(self.mesh, c.n_kv_heads, self.B)
-            shape = (c.n_layers, self.B, c.n_kv_heads, self.S, c.head_dim)
-
-            def zeros(shape, dtype, sharding):
-                return jax.device_put(jnp.zeros(shape, dtype), sharding)
-            if self.kv_quant == "int8":
-                # int8 values + per-token fp32 scales, stored rank-4
-                # [L, B, KV, 1, S] (models/llama.py KVCache): the value
-                # sharding with the S axis moved last (head_dim dropped,
-                # None for the unit dim).
-                ssh = NamedSharding(
-                    self.mesh, P(*csh.spec[:-2], None, csh.spec[-2]))
-                def qz():
-                    return {"q": zeros(shape, jnp.int8, csh),
-                            "s": zeros(shape[:-2] + (1, shape[-2]),
-                                       jnp.float32, ssh)}
-                self.cache = llama.KVCache(k=qz(), v=qz())
-            else:
-                self.cache = llama.KVCache(
-                    k=zeros(shape, self.dtype, csh),
-                    v=zeros(shape, self.dtype, csh))
+            self.cache = jax.jit(
+                partial(PagedKVCache.create, c, num_pages, page,
+                        self.dtype, kv_quant=self.kv_quant),
+                out_shardings=PagedKVCache(k=side, v=side))()
+        self._d_tables: tuple | None = None
+        self._table_dirty = True
         # Routed assignments of the decode steps and tiles of the prefill
         # calls (hybrid family): the device keeps wrapping int32 totals in
         # the cache, every burst hands them back beside its tokens, the
@@ -1031,138 +996,6 @@ class InferenceEngine:
             # of pinning speculation off forever.
             self._spec_base_fails = 0
 
-    def _compile(self) -> None:
-        if self.paged:
-            self._compile_paged()
-            return
-        c = self.model_cfg
-        family_forward = forward_fn(c)
-        attention_fn = self._pick_attention()
-        if attention_fn is None:
-            model_forward = family_forward
-        else:
-            model_forward = partial(family_forward, attention_fn=attention_fn)
-        replicated = NamedSharding(self.mesh, P())
-
-        @partial(jax.jit, donate_argnums=(1, 2))
-        def prefill_step(params, cache: llama.KVCache, counts: jax.Array,
-                         tokens: jax.Array,
-                         start_len: jax.Array, slots: jax.Array,
-                         last_idx: jax.Array, samp_t: jax.Array,
-                         samp_p: jax.Array, samp_k: jax.Array,
-                         samp_pp: jax.Array, samp_fp: jax.Array,
-                         key: jax.Array
-                         ) -> tuple[jax.Array, jax.Array, llama.KVCache]:
-            """Run one prompt chunk for each of K slots. tokens [K, C],
-            start_len/slots/last_idx/samp_* [K]. Returns (first_tokens
-            [K, replicated], cache). K=1 is the single-request path;
-            K>1 is BATCHED admission: K queued prefills run in one
-            program and pay one dispatch (what that saves on an
-            attached chip is not measured). The first token is
-            sampled INSIDE this program from each row's
-            last REAL position — prefill→row-fetch→sample-one folded
-            into one dispatch, as before. Per-k cache rows move via
-            unrolled dynamic slices (NOT a gather: the B axis may be
-            sharded over `data`, and dynamic_slice is the op GSPMD
-            already partitions correctly for the K=1 path)."""
-            K = tokens.shape[0]
-
-            def rows_of(side):
-                return jax.tree.map(
-                    lambda a: jnp.concatenate(
-                        [jax.lax.dynamic_slice_in_dim(a, slots[k], 1,
-                                                      axis=1)
-                         for k in range(K)], axis=1), side)
-            row_cache = llama.KVCache(k=rows_of(cache.k),
-                                      v=rows_of(cache.v))
-            logits, row_cache = model_forward(
-                params, c, tokens, start_len, row_cache)
-
-            def scatter(full, rows):
-                for k in range(K):
-                    full = jax.lax.dynamic_update_slice_in_dim(
-                        full, jax.lax.dynamic_slice_in_dim(
-                            rows, k, 1, axis=1), slots[k], axis=1)
-                return full
-            new_k = jax.tree.map(scatter, cache.k, row_cache.k)
-            new_v = jax.tree.map(scatter, cache.v, row_cache.v)
-            counts, count_rows = _prefill_counts(
-                counts, tokens, start_len, slots, last_idx)
-            rows = jax.lax.with_sharding_constraint(
-                jnp.take_along_axis(
-                    logits, last_idx[:, None, None], axis=1)[:, 0, :],
-                replicated)
-            samp = SamplingParams(temperature=samp_t, top_p=samp_p,
-                                  top_k=samp_k, presence_penalty=samp_pp,
-                                  frequency_penalty=samp_fp)
-            # Phase marker (ISSUE 8): trace-time op metadata only — the
-            # profiler segments sampling from the forward in Perfetto.
-            with jax.named_scope("sampling"):
-                first = jax.lax.with_sharding_constraint(
-                    sample(rows, samp, key, counts=count_rows), replicated)
-            return first, counts, llama.KVCache(k=new_k, v=new_v)
-
-        def one_step(params, cache: llama.KVCache, counts: jax.Array,
-                     tokens: jax.Array,
-                     lengths: jax.Array, active: jax.Array,
-                     samp: SamplingParams, key: jax.Array, *,
-                     greedy: bool = False
-                     ) -> tuple[jax.Array, jax.Array, jax.Array,
-                                llama.KVCache]:
-            """One decode step — the ONE copy of the forward+sample+advance
-            body; both compiled programs below are built from it. Returns
-            (next_tokens, new_lengths, cache) so the token/length feedback
-            loop stays ON DEVICE across steps — host fetches happen
-            asynchronously, steps behind. ``greedy=True`` compiles the
-            argmax-only variant — it skips the full-vocab sort the general
-            sampler pays per step; the scheduler picks it whenever every
-            active slot has temperature 0 AND zero penalties (the common
-            serving case; a penalized argmax differs from plain argmax,
-            so penalty requests ride the general path). The general path
-            counts each step's INPUT token before sampling, so the
-            penalty counts cover prompt + generated through step t when
-            sampling t+1 (engine/sampling.py apply_penalties); the
-            greedy path passes counts through untouched (aliased
-            donation, zero cost)."""
-            if not greedy:
-                counts = counts.at[jnp.arange(counts.shape[0]),
-                                   tokens].add(active.astype(jnp.int32))
-            logits, cache = model_forward(
-                params, c, tokens[:, None], lengths, cache, active=active)
-            with jax.named_scope("sampling"):
-                if greedy:
-                    next_tokens = jnp.argmax(
-                        logits[:, 0, :], axis=-1).astype(jnp.int32)
-                else:
-                    next_tokens = sample(logits[:, 0, :], samp, key,
-                                         counts=counts)
-                next_tokens = jax.lax.with_sharding_constraint(
-                    next_tokens, replicated)
-            new_lengths = jnp.where(active, lengths + 1, lengths)
-            return next_tokens, new_lengths, counts, cache
-
-        self._prefill_fn = prefill_step
-        self._decode_fns = _decode_programs(one_step, self._burst_depths)
-
-        if self.spec_k:
-            from .speculative import make_spec_burst, make_spec_step
-            # Scan depth chosen so a worst-case fully-accepted burst emits
-            # about decode_burst tokens (comparable pacing to normal mode).
-            self._spec_scan_len = max(
-                1, self.decode_burst // (self.spec_k + 1))
-            # The verify forward (T=k+1) defers its cache writes like
-            # decode does — the chunk path's per-layer functional insert
-            # costs ~2 ms/step in serialized scatters (tools/
-            # profile_insert.py), paid EVERY spec step otherwise.
-            spec_forward = partial(
-                family_forward,
-                attention_fn=_spec_verify_attention_fn(
-                    attention_fn, window=c.sliding_window))
-            self._spec_scan = make_spec_burst(
-                spec_forward, c, self.spec_k, self._spec_scan_len)
-            self._spec_step = partial(jax.jit, donate_argnums=(1,))(
-                make_spec_step(spec_forward, c, self.spec_k))
-
     def _resolve_attention_impl(self) -> str:
         """Validate cfg.attention and resolve "auto" (pallas on real TPU;
         interpret-mode Pallas on CPU is correct but slower than fused jnp)."""
@@ -1174,10 +1007,10 @@ class InferenceEngine:
             return "pallas" if jax.default_backend() == "tpu" else "reference"
         return impl
 
-    def _compile_paged(self) -> None:
-        """Compile the paged-cache step programs. The attention_fn is built
-        INSIDE each jitted step, closing over the traced page table — the
-        model forward signature stays cache-layout-agnostic."""
+    def _compile(self) -> None:
+        """Compile the step programs. The attention_fn is built INSIDE
+        each jitted step, closing over the traced page tables — the model
+        forward's signature knows nothing of pages."""
         c = self.model_cfg
         family_forward = forward_fn(c)
         from ..ops.latent_attention import LatentAttention
@@ -1240,12 +1073,18 @@ class InferenceEngine:
                          samp_k: jax.Array, samp_pp: jax.Array,
                          samp_fp: jax.Array, key: jax.Array
                          ) -> tuple[jax.Array, jax.Array, PagedKVCache]:
-            """One prompt chunk for each of K slots (dense twin's batched
-            admission — see its docstring). tokens [K, C]; the pool is
-            global, so unlike the dense path there is no per-slot cache
-            slice — each slot's page-table row does the routing, and the
-            K rows are sliced unrolled (same GSPMD-partitioned op as the
-            K=1 path)."""
+            """One prompt chunk for each of K slots. tokens [K, C],
+            start_len/slots/last_idx/samp_* [K]. Returns (first_tokens
+            [K, replicated], counts, cache). K=1 is the single-request
+            path; K>1 is BATCHED admission: K queued prefills run in one
+            program and pay one dispatch (what that saves on an attached
+            chip is not measured). The first token is sampled INSIDE this
+            program from each row's last REAL position — prefill, row
+            fetch and sample-one folded into one dispatch. The pool is
+            global, so there is no per-slot cache slice: each slot's
+            page-table row does the routing, and the K rows are sliced
+            unrolled (NOT a gather: dynamic_slice is the op GSPMD already
+            partitions correctly for the K=1 path)."""
             K = tokens.shape[0]
             rows_tbl = tuple(jnp.concatenate(
                 [jax.lax.dynamic_slice_in_dim(table, slots[k], 1, axis=0)
@@ -1273,11 +1112,24 @@ class InferenceEngine:
                      tokens: jax.Array, lengths: jax.Array,
                      active: jax.Array, samp: SamplingParams,
                      key: jax.Array, *, greedy: bool = False):
-            """Paged one-step twin (page table routes the cache rows). The
-            table is loop-invariant under the burst scan — pages are
-            reserved for a request's whole lifetime at admission, so no
-            page can change mid-burst. Penalty counts as the dense twin:
-            general path counts the input token; greedy passes through."""
+            """One decode step — the ONE copy of the forward+sample+advance
+            body; every decode program is built from it. Returns
+            (next_tokens, new_lengths, counts, cache) so the token/length
+            feedback loop stays ON DEVICE across steps — host fetches
+            happen asynchronously, steps behind. The page tables route
+            the cache rows and are loop-invariant under the burst scan:
+            pages are reserved for a request's whole lifetime at
+            admission, so no page can change mid-burst. ``greedy=True``
+            compiles the argmax-only variant — it skips the full-vocab
+            sort the general sampler pays per step; the scheduler picks
+            it whenever every active slot has temperature 0 AND zero
+            penalties (a penalized argmax differs from plain argmax, so
+            penalty requests ride the general path). The general path
+            counts each step's INPUT token before sampling, so the
+            penalty counts cover prompt + generated through step t when
+            sampling t+1 (engine/sampling.py apply_penalties); the
+            greedy path passes counts through untouched (aliased
+            donation, zero cost)."""
             if not greedy:
                 counts = counts.at[jnp.arange(counts.shape[0]),
                                    tokens].add(active.astype(jnp.int32))
@@ -1312,8 +1164,7 @@ class InferenceEngine:
             self._spec_scan_len = max(
                 1, self.decode_burst // (self.spec_k + 1))
             self._spec_scan = make_spec_burst(
-                None, c, self.spec_k, self._spec_scan_len,
-                make_forward=make_fwd)
+                make_fwd, c, self.spec_k, self._spec_scan_len)
 
             @partial(jax.jit, donate_argnums=(1,))
             def spec_step1(params, cache, table, hist, tokens, lengths,
@@ -1340,19 +1191,19 @@ class InferenceEngine:
 
     def _state_avals(self) -> tuple:
         """Avals (shape, dtype, sharding) of what every step program takes
-        first — params, cache, penalty counts, page table — and of the
+        first — params, cache, penalty counts, page tables — and of the
         PRNG key it takes last. Metadata of the live buffers only. The
         key is left unplaced, as it is at a real call."""
         def aval(x):
             return jax.ShapeDtypeStruct(
                 x.shape, x.dtype, sharding=getattr(x, "sharding", None))
         rep = NamedSharding(self.mesh, P())
-        table_a = (tuple(jax.ShapeDtypeStruct(
+        tables = tuple(jax.ShapeDtypeStruct(
             g.allocator.table.shape, jnp.int32, sharding=rep)
-            for g in self.kv_groups),) if self.paged else ()
+            for g in self.kv_groups)
         return ((jax.tree.map(aval, self.params),
                  jax.tree.map(aval, self.cache),
-                 aval(self._d_counts), *table_a),
+                 aval(self._d_counts), tables),
                 jax.ShapeDtypeStruct(self._rng.shape, self._rng.dtype))
 
     def compiled_decode(self, greedy: bool = True,
@@ -1421,28 +1272,6 @@ class InferenceEngine:
         self._d_tables = tuple(new)
         self._table_dirty = False
         return self._d_tables
-
-    def _pick_attention(self):
-        """Dense-cache attention_fn for the resolved impl ("reference" →
-        None: llama.forward's default dense jnp path)."""
-        if self.attention_impl == "pallas":
-            w = self.model_cfg.sliding_window
-            if self.mesh.size > 1:
-                # Sharded cache → the kernels must run under shard_map
-                # (pallas_call has no GSPMD partitioning rule). The
-                # wrapper's per-leaf specs cover int8 {"q","s"} caches;
-                # the sliding-window bound threads through (positions are
-                # absolute — batch/head sharding doesn't touch them).
-                from ..ops import make_sharded_cache_attention_fn
-                logger.info("attention: pallas flash kernels (shard_map over "
-                            "%s)%s", dict(self.mesh.shape),
-                            f" (sliding window {w})" if w else "")
-                return make_sharded_cache_attention_fn(self.mesh, window=w)
-            from ..ops import make_cache_attention_fn
-            logger.info("attention: pallas flash kernels%s",
-                        f" (sliding window {w})" if w else "")
-            return make_cache_attention_fn(window=w)
-        return None
 
     def _enable_debug_nans(self) -> None:
         """The numerics sanitizer (SURVEY.md §5): compiled programs raise on
@@ -2238,8 +2067,7 @@ class InferenceEngine:
                     active=len(self._running),
                     free_slots=self._free_slot_count(),
                     queued=self._queue.qsize() + (1 if self._head else 0),
-                    free_pages=(self.allocator.free_pages if self.paged
-                                else -1),
+                    free_pages=self.allocator.free_pages,
                     fitted_ms=(fitted if fitted is not None
                                else float("nan")))
         return progressed
@@ -2289,45 +2117,44 @@ class InferenceEngine:
                 req.finish_reason = "cancelled"
                 self._head = None
                 continue
-            if self.paged:
-                total = min(len(req.prompt_ids) + req.max_tokens, self.S)
-                # Radix prefix lookup (ISSUE 6): resident prompt blocks map
-                # into the new slot's table row instead of allocating +
-                # prefilling. Penalty requests bypass the cache — their
-                # token-occurrence counts are rebuilt by prefill, which a
-                # skipped span would leave incomplete. Matched nodes are
-                # pinned here; the pins drop at slot release, or right
-                # below if the request parks instead of admitting.
-                matched, shared_pages, nodes = 0, [], []
-                cache = self._prefix_cache
-                if (cache is not None and req.presence_penalty == 0
-                        and req.frequency_penalty == 0):
-                    t_lk = time.monotonic()
-                    matched, shared_pages, nodes = cache.match(
-                        req.prompt_ids)
-                    req.prefix_lookup_ms = 1000.0 * (time.monotonic()
-                                                     - t_lk)
-                ok = self.kv_groups.can_admit(
+            total = min(len(req.prompt_ids) + req.max_tokens, self.S)
+            # Radix prefix lookup (ISSUE 6): resident prompt blocks map
+            # into the new slot's table row instead of allocating +
+            # prefilling. Penalty requests bypass the cache — their
+            # token-occurrence counts are rebuilt by prefill, which a
+            # skipped span would leave incomplete. Matched nodes are
+            # pinned here; the pins drop at slot release, or right
+            # below if the request parks instead of admitting.
+            matched, shared_pages, nodes = 0, [], []
+            cache = self._prefix_cache
+            if (cache is not None and req.presence_penalty == 0
+                    and req.frequency_penalty == 0):
+                t_lk = time.monotonic()
+                matched, shared_pages, nodes = cache.match(
+                    req.prompt_ids)
+                req.prefix_lookup_ms = 1000.0 * (time.monotonic()
+                                                 - t_lk)
+            ok = self.kv_groups.can_admit(
+                total, shared_pages=len(shared_pages))
+            if not ok and cache is not None:
+                # Page pressure: reclaim cold cache entries (LRU
+                # leaves; pinned blocks are untouchable) before
+                # parking the head — the admission-side half of the
+                # overload/Retry-After machinery.
+                short = self.kv_groups.fresh_shortfall(
                     total, shared_pages=len(shared_pages))
-                if not ok and cache is not None:
-                    # Page pressure: reclaim cold cache entries (LRU
-                    # leaves; pinned blocks are untouchable) before
-                    # parking the head — the admission-side half of the
-                    # overload/Retry-After machinery.
-                    short = self.kv_groups.fresh_shortfall(
+                evicted = cache.evict(short) if short > 0 else 0
+                if evicted > 0:
+                    if fl is not None:
+                        from ..obs.flight import EVICT
+                        fl.record(EVICT, val=float(evicted),
+                                  free_pages=self.allocator.free_pages)
+                    ok = self.kv_groups.can_admit(
                         total, shared_pages=len(shared_pages))
-                    evicted = cache.evict(short) if short > 0 else 0
-                    if evicted > 0:
-                        if fl is not None:
-                            from ..obs.flight import EVICT
-                            fl.record(EVICT, val=float(evicted),
-                                      free_pages=self.allocator.free_pages)
-                        ok = self.kv_groups.can_admit(
-                            total, shared_pages=len(shared_pages))
-                if not ok:
-                    if cache is not None:
-                        cache.release_nodes(nodes)
-                    break
+            if not ok:
+                if cache is not None:
+                    cache.release_nodes(nodes)
+                break
             direct = False
             if self._disagg is not None:
                 # Direct-to-decode placement (no handoff): a warm prefix
@@ -2384,20 +2211,19 @@ class InferenceEngine:
                 self._spec_suspended[req.slot] = False
                 self._spec_slot_proposed[req.slot] = 0
                 self._spec_slot_accepted[req.slot] = 0
-            if self.paged:
-                self.kv_groups.allocate(req.slot, total,
-                                        shared_pages=shared_pages)
-                if self._prefix_cache is not None:
-                    self._prefix_cache.record_lookup(matched)
-                    req.cached_tokens = matched
-                    req.prefix_nodes = nodes
-                if matched and self.spec_k:
-                    # Prompt-lookup history for the skipped span: the
-                    # per-chunk maintenance only covers chunks that
-                    # actually run, and its pos==0 reset never fires on a
-                    # warm admission.
-                    self.hist[req.slot, :] = 0
-                    self.hist[req.slot, :matched] = req.prompt_ids[:matched]
+            self.kv_groups.allocate(req.slot, total,
+                                    shared_pages=shared_pages)
+            if self._prefix_cache is not None:
+                self._prefix_cache.record_lookup(matched)
+                req.cached_tokens = matched
+                req.prefix_nodes = nodes
+            if matched and self.spec_k:
+                # Prompt-lookup history for the skipped span: the
+                # per-chunk maintenance only covers chunks that
+                # actually run, and its pos==0 reset never fires on a
+                # warm admission.
+                self.hist[req.slot, :] = 0
+                self.hist[req.slot, :matched] = req.prompt_ids[:matched]
             # Warm admission starts prefill at the match boundary — the
             # matched span's prefill FLOPs are skipped outright (the
             # chunk's attention reads the shared pages through the table,
@@ -2412,8 +2238,7 @@ class InferenceEngine:
                     tokens=req.cached_tokens,
                     queued=self._queue.qsize() + (1 if self._head else 0),
                     free_slots=self._free_slot_count(),
-                    free_pages=(self.allocator.free_pages if self.paged
-                                else -1),
+                    free_pages=self.allocator.free_pages,
                     pool=req.pool,
                     rid=req.request_id or None)
 
@@ -2530,10 +2355,10 @@ class InferenceEngine:
         padded = np.zeros((K, bucket), np.int32)
         for i, ch in enumerate(chunks):
             padded[i, :len(ch)] = ch
-        table = (self._device_tables(),) if self.paged else ()
+        tables = self._device_tables()
         if key is None:
             key = _DUMMY_KEY()
-        args = (self.params, self.cache, self._d_counts, *table, padded,
+        args = (self.params, self.cache, self._d_counts, tables, padded,
                 np.asarray(poss, np.int32), np.asarray(slots, np.int32),
                 np.asarray([len(ch) - 1 for ch in chunks], np.int32),
                 np.asarray([s[0] for s in samps], np.float32),
@@ -2553,9 +2378,8 @@ class InferenceEngine:
             block = self._prefill_blocks[int(bucket)] = \
                 self._prefill_block(int(bucket))
         if self.kernels.needs(kname):
-            variant = {"bucket": int(bucket), "k": K}
-            if self.paged:
-                variant["block"] = "%dx%d" % block
+            variant = {"bucket": int(bucket), "k": K,
+                       "block": "%dx%d" % block}
             self.kernels.register(
                 kname, "prefill", variant=variant,
                 cost_fn=_kernel_cost_fn(self._prefill_fn, args))
@@ -2575,10 +2399,7 @@ class InferenceEngine:
         """(query positions a row-block, KV heads a program) of the
         prefill attention kernel at this bucket, by the kernel's own rule
         over what its call sees (under a ``model`` mesh the local KV
-        heads; the latent kernel folds every head over its one latent);
-        (0, 0) for a dense cache, which runs no paged kernel."""
-        if not self.paged:
-            return 0, 0
+        heads; the latent kernel folds every head over its one latent)."""
         c = self.model_cfg
         if c.is_mla:
             from ..ops.latent_attention import latent_block_t
@@ -2599,10 +2420,7 @@ class InferenceEngine:
         walked pages: per row and cache group what
         ``ops.paged_attention.prefill_pages_walked`` counts for row-blocks
         of ``bt`` positions, the kernel's own at this bucket
-        (:meth:`_prefill_block`; host integer arithmetic; a dense cache
-        walks no pages)."""
-        if not self.paged:
-            return 0
+        (:meth:`_prefill_block`; host integer arithmetic)."""
         from ..ops import paged_attention as pa
         page = self.allocator.page_size
         if self.model_cfg.is_mla:
@@ -2621,13 +2439,12 @@ class InferenceEngine:
 
     def _kernel_variant(self, **base) -> dict:
         """Registry variant dict for a decode/spec kernel: the caller's
-        keys plus the engine's KV identity (quantization, layout, DMA
+        keys plus the engine's KV identity (quantization, DMA
         blocking) — so the roofline table's worst_kernel() ranking can be
         filtered to e.g. the int8 decode variants (ISSUE 10's kernel-work
         driver) instead of guessing from the engine config."""
         base["kv"] = self.kv_quant or "bf16"
-        base["layout"] = "paged" if self.paged else "contiguous"
-        if self.paged and self.kv_ppb > 1:
+        if self.kv_ppb > 1:
             base["ppb"] = self.kv_ppb
         return base
 
@@ -2681,10 +2498,10 @@ class InferenceEngine:
 
         d_ok = self._spec_draft_ok(probe)
         d_ok_dev = self._upload(d_ok)
-        table = (self._device_tables(),) if self.paged else ()
+        tables = self._device_tables()
         if n_steps == self._spec_scan_len:
             t0 = time.monotonic()
-            args = (self.params, self.cache, *table, self._d_hist,
+            args = (self.params, self.cache, tables, self._d_hist,
                     self._d_tokens, self._d_lengths, self._d_active,
                     d_ok_dev)
             kname = f"spec.s{n_steps}"
@@ -2727,7 +2544,7 @@ class InferenceEngine:
         t0 = time.monotonic()
         with _device_phase("spec.verify"):
             for _ in range(n_steps):
-                args = (self.params, self.cache, *table, self._d_hist,
+                args = (self.params, self.cache, tables, self._d_hist,
                         self._d_tokens, self._d_lengths, self._d_active,
                         d_ok_dev)
                 if self.kernels.needs(kname):
@@ -3112,7 +2929,7 @@ class InferenceEngine:
             self._upload_slot_state()
             self._d_dirty = False
 
-        table = (self._device_tables(),) if self.paged else ()
+        tables = self._device_tables()
         # Greedy fast path: when every active slot decodes at temperature 0
         # with zero penalties (the common case), run the argmax-only
         # program — the general sampler's full-vocab sort costs
@@ -3131,7 +2948,7 @@ class InferenceEngine:
             # pending) fall through to the synchronous step loop below.
             t0 = time.monotonic()
             self._rng, key = jax.random.split(self._rng)
-            args = (self.params, self.cache, self._d_counts, *table,
+            args = (self.params, self.cache, self._d_counts, tables,
                     self._d_tokens, self._d_lengths, self._d_active,
                     self._d_samp, key)
             kname = (f"decode.d{n_steps}."
@@ -3189,7 +3006,7 @@ class InferenceEngine:
         with _device_phase("decode"):
             for _ in range(n_steps):
                 self._rng, key = jax.random.split(self._rng)
-                args = (self.params, self.cache, self._d_counts, *table,
+                args = (self.params, self.cache, self._d_counts, tables,
                         self._d_tokens, self._d_lengths, self._d_active,
                         self._d_samp, key)
                 if self.kernels.needs(kname):
@@ -3229,8 +3046,6 @@ class InferenceEngine:
         sees ``n + i + 1`` keys in a latent or a global group, and what
         of them lies inside the window in a windowed one. And to the
         state blocks rewritten: one a linear layer, active slot and step."""
-        if not self.paged:
-            return
         live = self.lengths[self.active].astype(np.int64)
         self._lin_decode_state_updates += (
             n_steps * len(live) * self.model_cfg.n_lin_layers)
@@ -3337,7 +3152,7 @@ class InferenceEngine:
     def _release(self, req: GenRequest) -> None:
         if req.slot in self._running:
             self._sched.left(req, req.t_done)
-            if self.paged and self._prefix_cache is not None:
+            if self._prefix_cache is not None:
                 self._prefix_release(req)
             del self._running[req.slot]
             if self.flight is not None:
@@ -3369,8 +3184,7 @@ class InferenceEngine:
                 self._disagg.clamp_release(req)
             self._slot_epoch[req.slot] += 1
             self._d_dirty = True
-            if self.paged:
-                self.kv_groups.release(req.slot)
+            self.kv_groups.release(req.slot)
 
     def _handoff(self, req: GenRequest) -> None:
         """Promote a just-completed prefill into the decode pool
@@ -3486,23 +3300,18 @@ class InferenceEngine:
         page = self.kv_page
         token_bytes = self._kv_token_bytes()
         kv_pools: dict[str, int] = {}
-        if self.paged:
-            for g in self.kv_groups:
-                name = ("latent" if g.kind == "latent" else
-                        f"window{g.window}" if g.window else "global")
-                kv_pools[name] = (g.layers * g.allocator.num_pages * page
-                                  * token_bytes)
-            kv_pool = sum(kv_pools.values())
-            page_bytes = (self.kv_groups.whole_context.layers * page
-                          * token_bytes)
-        else:
-            kv_pool = c.n_kv_layers * self.B * self.S * token_bytes
-            page_bytes = 0
+        for g in self.kv_groups:
+            name = ("latent" if g.kind == "latent" else
+                    f"window{g.window}" if g.window else "global")
+            kv_pools[name] = (g.layers * g.allocator.num_pages * page
+                              * token_bytes)
+        kv_pool = sum(kv_pools.values())
+        page_bytes = (self.kv_groups.whole_context.layers * page
+                      * token_bytes)
         aux = self.B * c.vocab_size * 4          # penalty counts [B, V]
         aux += self._state_bytes()               # recurrent state, conv tails
-        if self.paged:                           # device page tables
-            aux += sum(int(g.allocator.table.size)
-                       for g in self.kv_groups) * 4
+        aux += sum(int(g.allocator.table.size)     # device page tables
+                   for g in self.kv_groups) * 4
         spec = self.B * self.S * 4 if self.spec_k else 0  # device hist
 
         def tracked() -> int:
@@ -3515,7 +3324,7 @@ class InferenceEngine:
                             else leaf.dtype.itemsize)
                 total += int(np.prod(leaf.shape) * itemsize)
             for extra in (self._d_counts, getattr(self, "_d_hist", None),
-                          *((self._d_tables or ()) if self.paged else ())):
+                          *(self._d_tables or ())):
                 if extra is not None:
                     total += int(np.prod(extra.shape)
                                  * extra.dtype.itemsize)
@@ -3581,38 +3390,37 @@ class InferenceEngine:
             out["quant"] = self.quant
         if self.kv_quant:
             out["kv_quant"] = self.kv_quant
-        if self.paged:
-            out["free_pages"] = self.allocator.free_pages
-            out["total_pages"] = (self.allocator.num_pages
-                                  - self.allocator.pages_per_block)
-            out["page_size"] = self.allocator.page_size
-            # The cache groups (engine/paged.py): one pool, page table
-            # and allocator a group of layers that keep the same KV; the
-            # pages the rings re-targeted, monotone.
-            out["kv_groups"] = [g.stats() for g in self.kv_groups]
-            out["kv_ring_recycled_total"] = sum(
-                g.recycled for g in self.kv_groups)
-            # The paged prefill kernel's walk (_count_prefill_walk).
-            out["prefill_kv_pages_walked_total"] = self._prefill_pages_walked
-            out["prefill_kv_pages_table_total"] = self._prefill_pages_table
-            out["prefill_kernel_blocks"] = {
-                str(b): "%dx%d" % blk for b, blk in sorted(
-                    self._prefill_blocks.items())}
-            # One layer's keys of a global and of a windowed K/V group
-            # that the decode programs attended (_count_decode_keys).
-            out["attn_decode_keys_global_total"] = \
-                self._attn_decode_keys["global"]
-            out["attn_decode_keys_window_total"] = \
-                self._attn_decode_keys["window"]
-            if self.kv_ppb > 1:
-                out["pages_per_block"] = self.kv_ppb
-            if self._prefix_cache is not None:
-                # Radix prefix cache (ISSUE 6): hit/miss/cached-token
-                # totals plus residency/pin gauges — the obs collector
-                # bridges these onto the engine_prefix_* /metrics series,
-                # and the bench's shared-prefix rung asserts skipped
-                # prefill from them (not from wall clock).
-                out.update(self._prefix_cache.stats())
+        out["free_pages"] = self.allocator.free_pages
+        out["total_pages"] = (self.allocator.num_pages
+                              - self.allocator.pages_per_block)
+        out["page_size"] = self.allocator.page_size
+        # The cache groups (engine/paged.py): one pool, page table
+        # and allocator a group of layers that keep the same KV; the
+        # pages the rings re-targeted, monotone.
+        out["kv_groups"] = [g.stats() for g in self.kv_groups]
+        out["kv_ring_recycled_total"] = sum(
+            g.recycled for g in self.kv_groups)
+        # The paged prefill kernel's walk (_count_prefill_walk).
+        out["prefill_kv_pages_walked_total"] = self._prefill_pages_walked
+        out["prefill_kv_pages_table_total"] = self._prefill_pages_table
+        out["prefill_kernel_blocks"] = {
+            str(b): "%dx%d" % blk for b, blk in sorted(
+                self._prefill_blocks.items())}
+        # One layer's keys of a global and of a windowed K/V group
+        # that the decode programs attended (_count_decode_keys).
+        out["attn_decode_keys_global_total"] = \
+            self._attn_decode_keys["global"]
+        out["attn_decode_keys_window_total"] = \
+            self._attn_decode_keys["window"]
+        if self.kv_ppb > 1:
+            out["pages_per_block"] = self.kv_ppb
+        if self._prefix_cache is not None:
+            # Radix prefix cache (ISSUE 6): hit/miss/cached-token
+            # totals plus residency/pin gauges — the obs collector
+            # bridges these onto the engine_prefix_* /metrics series,
+            # and the bench's shared-prefix rung asserts skipped
+            # prefill from them (not from wall clock).
+            out.update(self._prefix_cache.stats())
         if self.model_cfg.n_lin_layers:
             # Recurrent state beside the pool.
             out["state_bytes_resident"] = self._state_bytes()
@@ -3768,29 +3576,6 @@ class InferenceEngine:
         return out
 
 
-def _spec_verify_attention_fn(base, window: int = 0):
-    """Attention provider for the speculative verify forward: the engine's
-    configured attention (``base``; None = family default), extended with
-    ``.verify`` so the T=k+1 verify step runs deferred-insert block
-    attention (llama.dense_verify_attention) instead of the chunk path's
-    insert-then-attend. A separate provider — adding ``.verify`` to the
-    shared one would silently reroute PREFILL chunks off the Pallas causal
-    kernel too (llama.forward dispatches on the attribute for any T>1).
-    ``window``: sliding-window bound for mistral-family engines — threads
-    through the default base AND the verify twin."""
-    if base is None:
-        base = llama.windowed_dense_attention(window) if window \
-            else llama.dense_cache_attention
-
-    def attn(q, k_new, v_new, layer_k, layer_v, lengths, active=None):
-        return base(q, k_new, v_new, layer_k, layer_v, lengths, active)
-    attn.verify = partial(llama.dense_verify_attention, window=window) \
-        if window else llama.dense_verify_attention
-    attn.decode = getattr(base, "decode", llama.dense_decode_attention)
-    attn.insert_all = getattr(base, "insert_all", llama.insert_kv_stacked)
-    return attn
-
-
 def _prefill_counts(counts, tokens, start_len, slots, last_idx):
     """Penalty-count maintenance for a prefill chunk group: reset each
     slot's row at prompt start (start_len == 0), add the chunk's REAL
@@ -3818,7 +3603,7 @@ def _decode_programs(one_step, burst_lens: tuple[int, ...],
     lengths are compiled in practice: the deep throughput burst
     and the shallow "busy" burst used while prefill work is interleaving
     (so busy-mode decode stays pipelined instead of dropping to
-    synchronous single steps). `one_step(params, cache, counts, [table,]
+    synchronous single steps). `one_step(params, cache, counts, tables,
     tokens, lengths, active, samp, key, greedy=) -> (next_tokens,
     new_lengths, counts, cache)`; the penalty-count state rides the
     scan carry beside the cache (donated like it).
@@ -3834,14 +3619,14 @@ def _decode_programs(one_step, burst_lens: tuple[int, ...],
 
         def make_scan(n_burst: int):
             @partial(jax.jit, donate_argnums=(1, 2))
-            def decode_scan(params, cache, counts, *rest):
-                *table, tokens, lengths, active, samp, key = rest
+            def decode_scan(params, cache, counts, tables, tokens, lengths,
+                            active, samp, key):
 
                 def body(carry, _):
                     cache, counts, tokens, lengths, key = carry
                     key, sub = jax.random.split(key)
                     nt, nl, counts, cache = step(
-                        params, cache, counts, *table, tokens,
+                        params, cache, counts, tables, tokens,
                         lengths, active, samp, sub)
                     # ``counters``: the cache carries device-side counters
                     # (models/hybrid.py) and hands them over beside the

@@ -124,37 +124,19 @@ def make_spec_step(model_forward, config, k: int):
     return step
 
 
-def make_spec_burst(model_forward, config, k: int, n_steps: int,
-                    make_forward=None):
+def make_spec_burst(make_forward, config, k: int, n_steps: int):
     """Fused scan over ``n_steps`` speculative steps (ONE dispatch).
 
-    Returns ``burst(params, cache, [table,] hist, tokens, lengths, active,
+    Returns ``burst(params, cache, tables, hist, tokens, lengths, active,
     draft_ok) -> (emitted [n_steps, B, k+1], cache, hist, tokens,
     lengths)``; lengths and the emitted counts are data-dependent, so the
     caller syncs host mirrors from the fetched ``emitted`` (count =
     tokens >= 0 per row). ``draft_ok`` [B] bool (the per-slot adaptive
     drafting gate, see make_spec_step) is burst-invariant: suspension
-    decisions happen on the host between bursts. ``make_forward(table) ->
-    model_forward`` supports the paged layout, whose attention closes
-    over the traced page table (the table becomes an extra positional arg
-    and ``model_forward`` is ignored).
+    decisions happen on the host between bursts. ``make_forward(tables)
+    -> model_forward``: the attention closes over the traced page tables,
+    which are a positional argument of the burst.
     """
-    if make_forward is None:
-        step = make_spec_step(model_forward, config, k)
-
-        @partial(jax.jit, donate_argnums=(1,))
-        def burst(params, cache, hist, tokens, lengths, active, draft_ok):
-            def body(carry, _):
-                cache, hist, tokens, lengths = carry
-                nt, nl, cache, hist, emitted, _ = step(
-                    params, cache, hist, tokens, lengths, active, draft_ok)
-                return (cache, hist, nt, nl), emitted
-            (cache, hist, tokens, lengths), emitted = jax.lax.scan(
-                body, (cache, hist, tokens, lengths), None, length=n_steps)
-            return emitted, cache, hist, tokens, lengths
-
-        return burst
-
     @partial(jax.jit, donate_argnums=(1,))
     def paged_burst(params, cache, table, hist, tokens, lengths, active,
                     draft_ok):

@@ -73,8 +73,8 @@ class ModelConfig:
     # layers a period behind ``leading_dense`` layers of a linear mixer and
     # a dense MLP, every sub-block normed before and after). A period
     # family is served from page pools on one device and refuses, at engine
-    # build and with the reason, a contiguous cache, any mesh axis, the
-    # prefix cache, speculation, disaggregation and ``model_path``.
+    # build and with the reason, any mesh axis, the prefix cache,
+    # speculation, disaggregation and ``model_path``.
     family: str = "llama"
     vocab_size: int = 32000
     d_model: int = 2048

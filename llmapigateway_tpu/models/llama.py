@@ -303,7 +303,7 @@ def insert_kv_stacked(cache_k, cache_v,
     One vmap(dynamic_update_slice) over B for ALL layers costs ~40× less
     than a per-layer insert inside the scan: the per-layer form lowers to
     2·L serialized TPU scatters per step (~2 ms/step at L=22), the stacked
-    form to one (~0.1 ms) — measured in tools/profile_insert.py. Inactive
+    form to one (~0.1 ms). Inactive
     rows reuse insert_kv's clamp-to-tail trick (see there for the
     visibility argument)."""
     quant = isinstance(cache_k, dict)
@@ -671,10 +671,8 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
     dh = c.head_dim
     if c.sliding_window and attention_fn is dense_cache_attention:
         # Mistral-family sliding window, threaded through the default
-        # dense provider. Explicit providers must carry the window
-        # themselves: the engine builds the flash kernels with it
-        # (single-device), and excludes seq/paged/multi-chip-pallas for
-        # SWA models at build.
+        # dense provider. Explicit providers carry the window themselves
+        # (the engine builds each cache group's paged provider with it).
         attention_fn = windowed_dense_attention(c.sliding_window)
 
     x = jnp.take(params["embed"], tokens, axis=0)   # [B, T, D]
@@ -695,7 +693,7 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
     # verify path), and an ``.insert_all`` (one stacked insert for every
     # layer's new tokens). This keeps the full-extent cache OUT of the
     # layer scan's ys — the per-layer functional cache update costs
-    # ~2 ms/step in serialized scatters at L=22 (tools/profile_insert.py);
+    # ~2 ms/step in serialized scatters at L=22;
     # the deferred form stacks only the tiny [L,B,T,KV,Dh] new tokens and
     # inserts once. Providers WITHOUT ``.verify`` (the prefill chunk path,
     # Pallas causal kernels) keep insert-then-attend for T>1.

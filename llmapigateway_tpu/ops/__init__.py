@@ -2,18 +2,8 @@
 
 The reference has no native code at all (SURVEY.md §2: 100% Python); here
 the hand-written machine-code tier is Pallas kernels compiled by Mosaic for
-the TPU's MXU/VPU, replacing the hot jnp attention path in models/llama.py.
+the TPU's MXU/VPU: the paged attention kernels (paged_attention.py), the
+latent attention kernels (latent_attention.py), the grouped expert product
+(grouped_experts.py) and the delta rule's one-token state update
+(delta_update.py).
 """
-from .flash_attention import (
-    flash_decode_attention,
-    flash_prefill_attention,
-    make_cache_attention_fn,
-    make_sharded_cache_attention_fn,
-)
-
-__all__ = [
-    "flash_decode_attention",
-    "flash_prefill_attention",
-    "make_cache_attention_fn",
-    "make_sharded_cache_attention_fn",
-]

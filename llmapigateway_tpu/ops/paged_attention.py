@@ -75,11 +75,11 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..models.config import ModelConfig
-from .flash_attention import shard_map
 
 NEG_INF = -1e30
 
@@ -632,7 +632,9 @@ def _walk_buffers(k_pages, v_pages, ppb: int, heads: int):
     ``(ppb, heads, page, Dh)`` of the stacked pool: K, V — int8: K, its
     scale plane, V, its scale plane. Scales are STORED [L, P, KV, 1,
     page], so a block's scale plane is the same slice of the pool as its
-    values (see flash_attention.attend_block on why the unit dim)."""
+    values (the unit dim keeps a block's trailing two dims ``(1, page)``
+    legal under the TPU's (8, 128) tiling: rank 3 would put a block of 1
+    on the KV dim, which Mosaic refuses and interpret mode never sees)."""
     quant = isinstance(k_pages, dict)
     kq = k_pages["q"] if quant else k_pages
     page, Dh = kq.shape[3:]
@@ -665,7 +667,7 @@ def _decode_live_blocks(n_valid, bs: int, window: int, n_table_blocks: int):
     ``n_valid - p < window`` (``ceil((window - 1) / bs) + 1`` of them at
     most, however the window is aligned). ``last`` is clamped into the
     table, so a fresh slot (nothing stale, only the self column counts)
-    still names a block; flash_attention._live_range is the dense twin."""
+    still names a block."""
     last = jnp.clip((n_valid + bs - 1) // bs - 1, 0, n_table_blocks - 1)
     if window:
         first = jnp.minimum(jnp.maximum(n_valid - (window - 1), 0) // bs,
@@ -715,9 +717,8 @@ def _softmax_update(scores, v, vs, m, l, acc):
 
 
 def _attend_heads(q, k, v, ks, vs, mask, m, l, acc):
-    """One online-softmax update of EVERY folded head against one page:
-    flash_attention.attend_block's arithmetic with the heads as the
-    leading batch dimension of both dots (``q`` [heads, G, Dh], ``k``/``v``
+    """One online-softmax update of EVERY folded head against one page,
+    the heads the leading batch dimension of both dots (``q`` [heads, G, Dh], ``k``/``v``
     [heads, page, Dh], int8 scales ``ks``/``vs`` [heads, 1, page] or None,
     state ``m``/``l`` [heads, G, 1] and ``acc`` [heads, G, Dh]; the
     prefill kernel's rows are ``G x bt``; ``mask`` None where every score
@@ -781,12 +782,11 @@ def _paged_decode_kernel(pt_ref, nvalid_ref, layer_ref, q_ref, kn_ref,
 
     def slot(b, walked):
         n_valid, first, n_blocks = live(b)
-        # Sliding window (ops/flash_attention.py _decode_kernel is the
-        # dense twin): the query at position n_valid sees stale keys p
+        # Sliding window: the query at position n_valid sees stale keys p
         # with n_valid - p < window, i.e. p >= w0.
         w0 = jnp.maximum(n_valid - (window - 1), 0) if window else 0
         q = q_ref[b]                                   # [heads, G, Dh]
-        # The SELF column (flash_attention.self_column_init, per head):
+        # The SELF column, per head:
         # m = q·k_new, l = 1, acc = v_new — the current token's K/V never
         # touched HBM (deferred-insert decode protocol).
         qf = q.astype(jnp.float32)

@@ -116,7 +116,59 @@ def stop_engine():
     instance with a second (wrong) loop."""
     def _stop(eng):
         _shared_loop().run_until_complete(eng.stop())
+        assert_all_free(eng)
     return _stop
+
+
+def assert_all_free(eng) -> None:
+    """What only a page pool can state, after a case or a stop: no request
+    holds a slot, and every cache group's allocator has all its pages back
+    (free, or resident in the radix cache, which keeps finished prefixes)
+    with its books in order. A page leaked on cancel, on ``max_tokens``, on
+    a fault's rebuild or on a supervisor restart fails here by name."""
+    assert not eng._running and not eng._prefilling, (
+        eng._running, eng._prefilling)
+    assert eng._free_slot_count() == eng.B, [p.free for p in eng._pools]
+    for g in eng.kv_groups:
+        a = g.allocator
+        cache = eng._prefix_cache if a is eng.allocator else None
+        kept = cache.resident_pages if cache is not None else 0
+        assert a.free_pages + kept == a.num_pages - a.pages_per_block, (
+            g.stats(), a.free_pages, kept)
+        # The radix cache checks its allocator with its pins counted.
+        (cache or a).check_invariants()
+
+
+@pytest.fixture(scope="session")
+def all_free():
+    """:func:`assert_all_free`, for a case that checks in mid-flight (a
+    fixture for the reason ``stop_engine`` is one)."""
+    return assert_all_free
+
+
+@pytest.fixture
+def engine(shared_engine):
+    """A file's module-scoped ``shared_engine``, held after every case that
+    used it to :func:`assert_all_free` (a file with an ``engine`` fixture
+    of its own overrides this one)."""
+    yield shared_engine
+    assert_all_free(shared_engine)
+
+
+@pytest.fixture
+def build_engine(stop_engine):
+    """``build_engine(cfg, **kw) -> InferenceEngine``; whatever a case
+    built is stopped on the shared loop when the case ends, and must then
+    have given every page and slot back."""
+    from llmapigateway_tpu.engine.engine import InferenceEngine
+    built = []
+
+    def build(cfg, **kw):
+        built.append(InferenceEngine(cfg, **kw))
+        return built[-1]
+    yield build
+    for eng in built:
+        stop_engine(eng)
 
 
 # ``--dist loadfile`` hands a worker whole files in collection order, two
@@ -124,21 +176,31 @@ def stop_engine():
 # minutes and is the wall's tail with five workers idle (PR 45's first
 # whole run: test_prefill_pool_carried.py, then 268 s, ended a run of
 # 6,085 worker-seconds at 1,135 s). The files that hold a worker longest
-# go out first, longest first (seconds on a worker in that run, a file
-# split since by its cases'); the rest keep their order, a file its
-# cases' order. A stale list costs balance, nothing else.
+# go out first, longest first (seconds on a worker in PR 49's whole run:
+# 1,316 s, 7,565 worker-seconds in 90 files); the rest keep their order,
+# a file its cases' order. A stale list costs balance, nothing else.
 LONGEST_FIRST = (
-    "test_spec_discovery.py",               # 373 (tests/bench_harness/)
-    "test_model_hybrid.py",                 # 279
-    "test_quant.py",                        # 238
-    "test_ops_grouped_experts.py",          # 195
-    "test_ops_paged_decode_fold.py",        # 191
-    "test_speculative.py",                  # 189
-    "test_kv_quant.py",                     # 185
-    "test_engine_pool_in_place.py",         # 183
-    "test_model_mistral.py",                # 177
-    "test_aot_tpu_programs.py",             # 157
-    "test_engine_pool_carried.py",          # 150
+    "test_spec_discovery.py",               # 477 (tests/bench_harness/)
+    "test_aot_tpu_programs.py",             # 445
+    "test_quant.py",                        # 276
+    "test_model_hybrid.py",                 # 254
+    "test_ops_grouped_experts.py",          # 217
+    "test_speculative.py",                  # 215
+    "test_command_a_plus_rehearsal.py",     # 201 (tests/bench_harness/)
+    "test_gigachat35_rehearsal.py",         # 198 (tests/bench_harness/)
+    "test_engine_cache_groups.py",          # 196
+    "test_model_cohere2.py",                # 190
+    "test_kv_quant.py",                     # 189
+    "test_ops_paged_decode_fold.py",        # 185
+    "test_mistral_small4_rehearsal.py",     # 182 (tests/bench_harness/)
+    "test_model_hybrid_experts.py",         # 175
+    "test_ops_paged_prefill_fold.py",       # 174
+    "test_engine_hybrid.py",                # 173
+    "test_queued_metric_files.py",          # 170 (tests/bench_harness/)
+    "test_model_mistral.py",                # 169
+    "test_engine.py",                       # 168
+    "test_ops_paged_chunk_write.py",        # 158
+    "test_model_mistral4.py",               # 152
 )
 
 
@@ -229,8 +291,8 @@ _PARITY_RERUN_TESTS = {
     "test_tp_serving_engages_sharded_pallas_kernels",
     # test_engine_paged.py
     "test_paged_concurrent_batching_no_corruption",
-    "test_paged_matches_contiguous_greedy",
-    "test_swa_paged_matches_contiguous_greedy",
+    "test_pool_matches_dense_reference_greedy",
+    "test_swa_pool_matches_dense_reference_greedy",
     "test_swa_ring_serves_full_context_from_small_pool",
     # test_kv_quant.py
     "test_engine_pallas_with_kv_quant_matches_reference",
@@ -239,8 +301,6 @@ _PARITY_RERUN_TESTS = {
     "test_engine_swa_paged_pallas_matches_reference",
     "test_engine_swa_paged_sharded_pallas_matches_reference",
     "test_engine_swa_paged_spec_ring_matches_reference",
-    "test_engine_swa_pallas_matches_reference",
-    "test_engine_swa_sharded_pallas_matches_reference",
     # test_speculative.py
     "test_adaptive_gate_closes_on_low_acceptance",
     "test_spec_engine_serves_sampled_via_normal_path",

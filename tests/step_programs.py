@@ -1,5 +1,5 @@
 """The ENGINE'S OWN step programs of a period family, lowered from shapes
-alone: ``InferenceEngine._compile_paged`` on a stand-in that carries what
+alone: ``InferenceEngine._compile`` on a stand-in that carries what
 it reads, its parameters, cache, penalty counts and page tables as
 ``ShapeDtypeStruct`` placed on the given device (a CPU, or a chip that is
 described and not attached). Nothing runs and nothing is allocated."""
@@ -31,7 +31,7 @@ def lower_step_program(config, device, program: str, *, quant: str,
         mesh=mesh, attention_impl="pallas", kv_ppb=1, S=per_slot * page,
         B=slots, spec_k=0, decode_burst=depth, _burst_depths=(depth,),
         allocator=types.SimpleNamespace(num_pages=pages, page_size=page))
-    InferenceEngine._compile_paged(engine)
+    InferenceEngine._compile(engine)
     init, key = InferenceEngine._random_init_program(engine)
     placed = NamedSharding(mesh, P())
 

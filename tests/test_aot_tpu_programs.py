@@ -72,7 +72,7 @@ def _loop_arrays(text: str, at_least: int) -> list[tuple[str, str, str]]:
 
 def _two_layer_engine(chips, monkeypatch, slots: int, pages: int,
                       depth: int):
-    """The ENGINE'S OWN step programs (its ``_compile_paged`` on a stand-in
+    """The ENGINE'S OWN step programs (its ``_compile`` on a stand-in
     that carries what it reads) at Mistral-7B's widths, two layers, int8
     weights and pool, with the shapes of what every program takes first
     (params, cache, penalty counts, page table) placed on the described
@@ -95,7 +95,7 @@ def _two_layer_engine(chips, monkeypatch, slots: int, pages: int,
         attention_impl="pallas", kv_ppb=1, S=8192, B=slots, spec_k=0,
         decode_burst=depth, _burst_depths=(depth,),
         allocator=types.SimpleNamespace(num_pages=pages, page_size=PAGE))
-    InferenceEngine._compile_paged(engine)
+    InferenceEngine._compile(engine)
     assert engine.kv_pool_in_place
     init, key = InferenceEngine._random_init_program(engine)
     placed = NamedSharding(mesh, P())

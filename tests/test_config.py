@@ -46,6 +46,8 @@ def test_local_provider_entry():
     ({"mesh": {"tensor": 8}},
      "unknown mesh axis 'tensor': the axes are data, expert, model"),
     ({"seq_attention": "ulysses"}, "seq_attention"),
+    ({"kv_layout": "contiguous"},
+     "'paged' is the only value; the contiguous layout was removed"),
 ])
 def test_local_provider_refuses_a_mode_the_engine_no_longer_has(engine,
                                                                 message):
@@ -57,6 +59,12 @@ def test_local_provider_refuses_a_mode_the_engine_no_longer_has(engine,
         parse_providers([{"local_tpu": {"type": "local", "engine": {
             "preset": "tinyllama-1.1b", **engine}}}])
     assert "provider 'local_tpu' invalid" in str(err.value)
+
+
+def test_kv_layout_is_accepted_for_old_files_with_its_one_value():
+    providers = parse_providers([{"local_tpu": {"type": "local", "engine": {
+        "preset": "tinyllama-1.1b", "kv_layout": "paged"}}}])
+    assert providers["local_tpu"].engine.kv_layout == "paged"
 
 
 def test_local_provider_requires_engine():

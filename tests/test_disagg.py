@@ -58,11 +58,6 @@ def test_config_rejects_unknown_admission_policy():
                                           "admission": "vibes"})
 
 
-def test_contiguous_layout_rejected():
-    with pytest.raises(ValueError, match="paged"):
-        _mk_engine(disagg=True, kv_layout="contiguous")
-
-
 def test_prefill_slots_must_leave_decode_slots():
     with pytest.raises(ValueError, match="both pools non-empty"):
         _mk_engine(disagg=True, prefill_slots=4)

@@ -296,7 +296,6 @@ def test_the_in_place_pool_path_is_on_for_both_groups():
 @pytest.mark.parametrize("change, says", [
     ({"prefix_cache": True}, "prefix_cache: the ring re-targets"),
     ({"spec_draft_len": 3}, "spec_draft_len: the verify path reads one pool"),
-    ({"kv_layout": "contiguous"}, "kv_layout 'contiguous': a dense cache"),
     ({"mesh": {"model": 2}}, "mesh .*the page ring runs on one device"),
     ({"disaggregation": {"enabled": True, "prefill_slots": 1}},
      "disaggregation: a handoff cannot move a ring slot"),
@@ -461,8 +460,6 @@ def test_the_latent_pool_rides_the_in_place_path_of_both_programs(
     ({"kv_quant": "int8"}, "kv_quant 'int8': the latent pool is bfloat16"),
     ({"prefix_cache": True}, "prefix_cache: the radix cache shares K/V"),
     ({"spec_draft_len": 3}, "spec_draft_len: the verify path reads a K"),
-    ({"kv_layout": "contiguous"}, "kv_layout 'contiguous': the latent "
-                                  "cache is a page pool"),
     ({"mesh": {"model": 2}}, "mesh .*the latent pool has one key head"),
     ({"disaggregation": {"enabled": True, "prefill_slots": 1}},
      "disaggregation: a handoff of latent pages"),

@@ -196,7 +196,6 @@ async def test_the_tiles_of_the_grouped_product_are_counted():
 @pytest.mark.parametrize("change, says", [
     ({"prefix_cache": True}, "prefix_cache: a cached prefix holds KV pages"),
     ({"spec_draft_len": 3}, "spec_draft_len: a rejected draft"),
-    ({"kv_layout": "contiguous"}, "kv_layout 'contiguous': its softmax"),
     ({"mesh": {"model": 2}}, "mesh .*no sharding rule yet"),
     ({"disaggregation": {"enabled": True, "prefill_slots": 1}},
      "disaggregation: a handoff moves pages"),

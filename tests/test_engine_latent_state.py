@@ -154,9 +154,6 @@ def test_a_burst_leaves_an_inactive_slots_storage_bit_identical(engine):
 
 
 REFUSED = {
-    "kv_layout": (dict(kv_layout="contiguous"), "kv_layout 'contiguous'", [
-        "its softmax layers are served from the page pool only",
-        "the latent cache is a page pool, and no dense layout"]),
     "kv_quant": (dict(kv_quant="int8"), "kv_quant 'int8'", [
         "the latent pool is bfloat16"]),
     "prefix_cache": (dict(prefix_cache=True), "prefix_cache", [

@@ -1,6 +1,6 @@
-"""Paged-KV engine: generation parity with the contiguous layout, page
-reservation backpressure at admission, allocator bookkeeping across the
-request lifecycle."""
+"""The engine on its page pool: generation parity with the models' dense
+forward (tests/dense_reference.py), page reservation backpressure at
+admission, allocator bookkeeping across the request lifecycle."""
 import asyncio
 
 import jax
@@ -8,16 +8,19 @@ import pytest
 
 from llmapigateway_tpu.config.schemas import LocalEngineConfig
 from llmapigateway_tpu.engine.engine import GenRequest, InferenceEngine
+from tests.dense_reference import greedy_tokens
 from tests.mesh_parity import serve, split_dims
 
 
-def _mk_engine(**kw):
+def _cfg(**kw) -> LocalEngineConfig:
     base = dict(preset="tiny-test", max_batch_size=4, max_seq_len=128,
-                prefill_chunk=32, dtype="float32", kv_layout="paged",
-                kv_page_size=16)
+                prefill_chunk=32, dtype="float32", kv_page_size=16)
     base.update(kw)
-    return InferenceEngine(LocalEngineConfig(**base),
-                           devices=[jax.devices("cpu")[0]])
+    return LocalEngineConfig(**base)
+
+
+def _mk_engine(**kw):
+    return InferenceEngine(_cfg(**kw), devices=[jax.devices("cpu")[0]])
 
 
 @pytest.fixture(scope="module")
@@ -36,21 +39,13 @@ async def _generate(eng, prompt="hello", max_tokens=8, **kw) -> GenRequest:
     return req
 
 
-async def test_paged_matches_contiguous_greedy(paged_engine):
-    """Same prompt, greedy: the paged engine must produce exactly the dense
-    engine's tokens (same weights — both init from PRNGKey(0))."""
-    dense = InferenceEngine(
-        LocalEngineConfig(preset="tiny-test", max_batch_size=4,
-                          max_seq_len=128, prefill_chunk=32,
-                          dtype="float32", kv_layout="contiguous"),
-        devices=[jax.devices("cpu")[0]])
-    try:
-        for prompt in ("hello world", "a much longer prompt " * 5):
-            r_paged = await _generate(paged_engine, prompt, max_tokens=6)
-            r_dense = await _generate(dense, prompt, max_tokens=6)
-            assert r_paged.generated == r_dense.generated, prompt
-    finally:
-        await dense.stop()
+async def test_pool_matches_dense_reference_greedy(paged_engine):
+    """Same prompt, greedy: the engine must produce exactly the tokens of
+    the dense forward over a contiguous cache, with its own weights."""
+    for prompt in ("hello world", "a much longer prompt " * 5):
+        req = await _generate(paged_engine, prompt, max_tokens=6)
+        assert req.generated == greedy_tokens(
+            paged_engine, req.prompt_ids, 6), prompt
 
 
 async def test_paged_slots_release_pages(paged_engine):
@@ -103,75 +98,24 @@ async def test_paged_concurrent_batching_no_corruption(paged_engine):
         assert s.generated == t.generated, p
 
 
-def test_paged_prefill_group_matches_single_calls():
-    """Paged twin of the dense group-parity test: one K=2 batched
-    prefill call (per-slot page-table rows sliced inside the program)
-    must leave the engine AND allocator in the same state as two K=1
-    calls."""
-    import numpy as np
-
-    def reqs_for(eng):
-        out = []
-        for slot, text in ((0, "paged grouped admission alpha"),
-                           (2, "another paged prompt beta")):
-            req = GenRequest(prompt_ids=eng.tokenizer.encode(text),
-                             max_tokens=4)
-            req.slot = slot
-            req.prefill_pos = 0
-            eng.allocator.allocate(slot, len(req.prompt_ids) + 4)
-            eng._table_dirty = True
-            out.append(req)
-        return out
-
-    eng_b, eng_s = _mk_engine(), _mk_engine()
-    rb, rs = reqs_for(eng_b), reqs_for(eng_s)
-    done_b = eng_b._prefill_chunk_group(rb)
-    done_s = [eng_s._prefill_chunk_group([r])[0] for r in rs]
-    assert done_b == done_s
-    for a, b in zip(rb, rs):
-        assert a.generated == b.generated
-    np.testing.assert_array_equal(eng_b.allocator.table,
-                                  eng_s.allocator.table)
-    assert eng_b.allocator.free_pages == eng_s.allocator.free_pages
-    for side in ("k", "v"):
-        for la, lb in zip(jax.tree.leaves(getattr(eng_b.cache, side)),
-                          jax.tree.leaves(getattr(eng_s.cache, side))):
-            a, b = np.asarray(la).copy(), np.asarray(lb).copy()
-            # Page 0 is the trash page: bucket-pad positions of BOTH
-            # rows scatter there, so its garbage is order-dependent BY
-            # DESIGN (one K=2 program vs two K=1 programs write it in
-            # different orders). Real pages must still match exactly.
-            a[:, 0], b[:, 0] = 0, 0
-            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
-
-
 def test_pool_too_small_for_one_request_rejected():
     with pytest.raises(ValueError, match="cannot hold"):
         _mk_engine(kv_num_pages=4)
 
 
-async def test_swa_paged_matches_contiguous_greedy(stop_engine):
-    """SWA x paged (VERDICT r4 item 6): a sliding-window model served from
-    the paged pool produces exactly the windowed dense engine's greedy
-    tokens — with generations long enough that the window (16) slides
-    across a page boundary (page=16) mid-decode."""
-    dense = InferenceEngine(
-        LocalEngineConfig(preset="tiny-mistral-test", max_batch_size=2,
-                          max_seq_len=128, prefill_chunk=16,
-                          dtype="float32", kv_layout="contiguous"),
-        devices=[jax.devices("cpu")[0]])
-    paged = _mk_engine(preset="tiny-mistral-test", max_batch_size=2,
-                       prefill_chunk=16)
-    try:
-        for prompt, n in (("hello world", 8),
-                          ("a much longer prompt " * 4, 24)):
-            r_dense = await _generate(dense, prompt, max_tokens=n)
-            r_paged = await _generate(paged, prompt, max_tokens=n)
-            assert r_paged.generated == r_dense.generated, prompt
-            assert len(r_paged.generated) >= 2
-    finally:
-        await dense.stop()
-        await paged.stop()
+async def test_swa_pool_matches_dense_reference_greedy(build_engine):
+    """A window on the pool (VERDICT r4 item 6): a sliding-window model
+    produces exactly the windowed dense forward's greedy tokens — with
+    generations long enough that the window (16) slides across a page
+    boundary (page=16) mid-decode."""
+    eng = build_engine(_cfg(preset="tiny-mistral-test", max_batch_size=2,
+                            prefill_chunk=16),
+                       devices=[jax.devices("cpu")[0]])
+    for prompt, n in (("hello world", 8),
+                      ("a much longer prompt " * 4, 24)):
+        req = await _generate(eng, prompt, max_tokens=n)
+        assert req.generated == greedy_tokens(eng, req.prompt_ids, n), prompt
+        assert len(req.generated) >= 2
 
 
 def test_ring_allocator_rotation_and_invariants():
@@ -199,33 +143,25 @@ def test_ring_allocator_rotation_and_invariants():
     assert a.free_pages == 7                # all non-trash pages back
 
 
-async def test_swa_ring_serves_full_context_from_small_pool(stop_engine):
+async def test_swa_ring_serves_full_context_from_small_pool(build_engine):
     """The capacity win: a pool far too small for whole-lifetime
     reservation (per_slot=16 pages; usable=11) serves TWO sliding-window
     requests to ~full context, because each slot's steady-state footprint
     is O(window) pages. Greedy tokens still match the windowed dense
-    engine."""
-    dense = InferenceEngine(
-        LocalEngineConfig(preset="tiny-mistral-test", max_batch_size=2,
-                          max_seq_len=256, prefill_chunk=16,
-                          decode_burst=4, dtype="float32",
-                          kv_layout="contiguous"),
-        devices=[jax.devices("cpu")[0]])
-    paged = _mk_engine(preset="tiny-mistral-test", max_batch_size=2,
-                       max_seq_len=256, prefill_chunk=16, decode_burst=4,
-                       kv_num_pages=12)
-    try:
-        assert paged._swa_ring_pages and paged._swa_ring_pages <= 5
-        prompt = "state rolls across many pages " * 4       # ~120 tokens
-        r_dense = await _generate(dense, prompt, max_tokens=96)
-        r_paged = await _generate(paged, prompt, max_tokens=96)
-        assert r_paged.generated == r_dense.generated
-        assert len(r_paged.generated) == 96
-        paged.allocator.check_invariants()
-        assert paged.allocator.free_pages == 11   # everything returned
-    finally:
-        await dense.stop()
-        await paged.stop()
+    forward's."""
+    eng = build_engine(_cfg(preset="tiny-mistral-test", max_batch_size=2,
+                            max_seq_len=256, prefill_chunk=16,
+                            decode_burst=4, kv_num_pages=12),
+                       devices=[jax.devices("cpu")[0]])
+    assert eng._swa_ring_pages and eng._swa_ring_pages <= 5
+    prompt = "state rolls across many pages " * 4       # ~120 tokens
+    reqs = await asyncio.gather(*[
+        _generate(eng, prompt, max_tokens=96) for _ in range(2)])
+    want = greedy_tokens(eng, reqs[0].prompt_ids, 96)
+    for req in reqs:
+        assert req.generated == want and len(want) == 96
+    eng.allocator.check_invariants()
+    assert eng.allocator.free_pages == 11   # everything returned
 
 
 async def test_multipage_engine_matches_per_page_tokens():

@@ -25,16 +25,36 @@ from llmapigateway_tpu.engine.engine import (
 )
 
 
+# Whole contexts and the window's page ring (pages of 8, 7 a slot of 8):
+# a restart rebuilds the pool and its tables, a drain and a park give the
+# pages back, whichever kind of cache group holds them. Disaggregation
+# refuses a ring at build, so the handoff case stays on one kind.
+KINDS = ("tiny-test", "tiny-mistral-test")
+
+
 def _cfg(**kw):
     base = dict(preset="tiny-test", max_batch_size=2, max_seq_len=64,
                 prefill_chunk=16, dtype="float32", decode_burst=2,
-                kv_layout="contiguous")
+                kv_page_size=8)
     base.update(kw)
     return LocalEngineConfig(**base)
 
 
-def _mk(**kw) -> InferenceEngine:
-    return InferenceEngine(_cfg(**kw), devices=[jax.devices("cpu")[0]])
+@pytest.fixture(scope="module", autouse=True)
+def _first_builds_off_the_loop():
+    """A preset's first build compiles its init program: seconds, more on
+    a shared CPU, and inside an async case that is the loop standing
+    still, which the sanitizer fails at five. Once each, here."""
+    for preset in KINDS:
+        InferenceEngine(_cfg(preset=preset), devices=[jax.devices("cpu")[0]])
+
+
+@pytest.fixture
+def mk(build_engine):
+    """``mk(**kw) -> InferenceEngine``, stopped when the case ends and then
+    held to every page and slot free (``assert_all_free``)."""
+    return lambda **kw: build_engine(_cfg(**kw),
+                                     devices=[jax.devices("cpu")[0]])
 
 
 async def _submit(eng, prompt_ids=(1, 2, 3), max_tokens=16) -> GenRequest:
@@ -65,180 +85,164 @@ def _supervisor_flight_states(eng):
 
 # -- crash recovery -----------------------------------------------------------
 
-async def test_transient_step_fault_restarts_and_serves():
+@pytest.mark.parametrize("preset", KINDS)
+async def test_transient_step_fault_restarts_and_serves(mk, preset):
     """A mid-decode transient crash flushes the in-flight stream with an
     in-band error delta, then the supervisor rebuilds state and the
     engine serves again — with the observability plane (HBM ledger,
     flight ring) surviving the restart."""
-    eng = _mk(supervisor={"backoff_ms": 20.0, "max_restarts": 5})
-    try:
-        eng.fault_plan = FaultPlan(fail_step_after=2)
-        req = await _submit(eng, max_tokens=32)
-        ledger_before = eng.ledger
-        deltas = await _drain_stream(eng, req)
-        assert deltas[-1].error is not None
-        assert "injected step fault" in deltas[-1].error
-        eng.fault_plan = None            # let the restarted loop live
+    eng = mk(preset=preset,
+             supervisor={"backoff_ms": 20.0, "max_restarts": 5})
+    eng.fault_plan = FaultPlan(fail_step_after=2)
+    req = await _submit(eng, max_tokens=32)
+    ledger_before = eng.ledger
+    deltas = await _drain_stream(eng, req)
+    assert deltas[-1].error is not None
+    assert "injected step fault" in deltas[-1].error
+    eng.fault_plan = None            # let the restarted loop live
 
-        await _wait_for(lambda: eng.supervisor.state == "serving",
-                        msg="supervised restart")
-        s = eng.stats()
-        assert s["supervisor_restarts_total"] >= 1
-        assert s["supervisor_last_failure_kind"] == "transient"
-        # Restart-recovery gap (ISSUE 14 satellite): the ledger was
-        # rebuilt against the new device buffers, not left tracking
-        # ghosts of the donated pre-crash cache.
-        assert eng.ledger is not ledger_before
-        assert eng.ledger.snapshot() is not None
-        # The incident is visible on the flight ring: a restarting
-        # instant carrying the classified failure as its reason, then
-        # the serving edge that closed it.
-        states = _supervisor_flight_states(eng)
-        assert ("restarting", "transient: RuntimeError: injected step "
-                "fault") in states
-        assert any(st == "serving" and "restart complete" in r
-                   for st, r in states)
+    await _wait_for(lambda: eng.supervisor.state == "serving",
+                    msg="supervised restart")
+    s = eng.stats()
+    assert s["supervisor_restarts_total"] >= 1
+    assert s["supervisor_last_failure_kind"] == "transient"
+    # Restart-recovery gap (ISSUE 14 satellite): the ledger was
+    # rebuilt against the new device buffers, not left tracking
+    # ghosts of the donated pre-crash cache.
+    assert eng.ledger is not ledger_before
+    assert eng.ledger.snapshot() is not None
+    # The incident is visible on the flight ring: a restarting
+    # instant carrying the classified failure as its reason, then
+    # the serving edge that closed it.
+    states = _supervisor_flight_states(eng)
+    assert ("restarting", "transient: RuntimeError: injected step "
+            "fault") in states
+    assert any(st == "serving" and "restart complete" in r
+               for st, r in states)
 
-        req2 = await _submit(eng)
-        deltas = await _drain_stream(eng, req2)
-        assert req2.finish_reason is not None and deltas[-1].error is None
-    finally:
-        eng.fault_plan = None
-        await eng.stop()
+    req2 = await _submit(eng)
+    deltas = await _drain_stream(eng, req2)
+    assert req2.finish_reason is not None and deltas[-1].error is None
 
 
-async def test_a_crash_leaves_no_admit_without_its_finish():
+@pytest.mark.parametrize("preset", KINDS)
+async def test_a_crash_leaves_no_admit_without_its_finish(mk, preset):
     """Two requests in flight when the step loop dies: both streams end
     in an error delta, the supervisor ends ``serving``, the engine serves
     again, and over the whole incident the flight ring counts a finish
     for every admit — the crashed requests' included."""
-    eng = _mk(supervisor={"backoff_ms": 20.0, "max_restarts": 5})
-    try:
-        eng.fault_plan = FaultPlan(fail_step_after=2)
-        reqs = [await _submit(eng, max_tokens=32) for _ in range(2)]
-        ends = [(await _drain_stream(eng, r))[-1] for r in reqs]
-        assert all(d.error is not None for d in ends)
-        eng.fault_plan = None
-        await _wait_for(lambda: eng.supervisor.state == "serving",
-                        msg="supervised restart")
-        after = await _submit(eng)
-        assert (await _drain_stream(eng, after))[-1].error is None
-        assert after.finish_reason is not None
-        assert eng.supervisor.state == "serving"
-        fs = eng.flight.stats()
-        assert fs["flight_admits"] == fs["flight_finishes"] == 3
-    finally:
-        eng.fault_plan = None
-        await eng.stop()
+    eng = mk(preset=preset,
+             supervisor={"backoff_ms": 20.0, "max_restarts": 5})
+    eng.fault_plan = FaultPlan(fail_step_after=2)
+    reqs = [await _submit(eng, max_tokens=32) for _ in range(2)]
+    ends = [(await _drain_stream(eng, r))[-1] for r in reqs]
+    assert all(d.error is not None for d in ends)
+    eng.fault_plan = None
+    await _wait_for(lambda: eng.supervisor.state == "serving",
+                    msg="supervised restart")
+    after = await _submit(eng)
+    assert (await _drain_stream(eng, after))[-1].error is None
+    assert after.finish_reason is not None
+    assert eng.supervisor.state == "serving"
+    fs = eng.flight.stats()
+    assert fs["flight_admits"] == fs["flight_finishes"] == 3
 
 
-async def test_fake_hbm_oom_is_classified_transient():
+async def test_fake_hbm_oom_is_classified_transient(mk):
     """XLA's RESOURCE_EXHAUSTED (HBM OOM) shape restarts rather than
     parking the engine: fragmentation events are recoverable by a pool
     rebuild."""
-    eng = _mk(supervisor={"backoff_ms": 10.0})
-    try:
-        eng.fault_plan = FaultPlan(
-            fail_step_after=1,
-            fail_step_msg="RESOURCE_EXHAUSTED: out of memory while trying "
-                          "to allocate 262144 bytes")
-        req = await _submit(eng)
-        deltas = await _drain_stream(eng, req)
-        assert "RESOURCE_EXHAUSTED" in deltas[-1].error
-        eng.fault_plan = None
-        await _wait_for(lambda: eng.supervisor.state == "serving",
-                        msg="restart after fake OOM")
-        assert eng.stats()["supervisor_last_failure_kind"] == "transient"
-    finally:
-        eng.fault_plan = None
-        await eng.stop()
+    eng = mk(supervisor={"backoff_ms": 10.0})
+    eng.fault_plan = FaultPlan(
+        fail_step_after=1,
+        fail_step_msg="RESOURCE_EXHAUSTED: out of memory while trying "
+                      "to allocate 262144 bytes")
+    req = await _submit(eng)
+    deltas = await _drain_stream(eng, req)
+    assert "RESOURCE_EXHAUSTED" in deltas[-1].error
+    eng.fault_plan = None
+    await _wait_for(lambda: eng.supervisor.state == "serving",
+                    msg="restart after fake OOM")
+    assert eng.stats()["supervisor_last_failure_kind"] == "transient"
 
 
-async def test_fatal_fault_parks_failed_until_admin_stop():
+@pytest.mark.parametrize("preset", KINDS)
+async def test_fatal_fault_parks_failed_until_admin_stop(mk, preset):
     """A fatal (config/programming) fault must NOT restart-loop: the
     engine parks in `failed`, admissions raise EngineUnavailable (the
     router fails over), and only an explicit administrative stop()
     un-parks it."""
-    eng = _mk()
-    try:
-        eng.fault_plan = FaultPlan(fail_step_after=0, fail_step_fatal=True,
-                                   fail_step_msg="bad lowering shape")
-        req = await _submit(eng)
-        deltas = await _drain_stream(eng, req)
-        assert deltas[-1].error is not None
-        await _wait_for(lambda: eng.supervisor.state == "failed",
-                        msg="fatal park")
-        s = eng.stats()
-        assert s["supervisor_last_failure_kind"] == "fatal"
-        assert s["supervisor_restarts_total"] == 0      # no restart burned
-        with pytest.raises(EngineUnavailable):
-            await _submit(eng)
-        with pytest.raises(EngineUnavailable):
-            await eng.start()
+    eng = mk(preset=preset)
+    eng.fault_plan = FaultPlan(fail_step_after=0, fail_step_fatal=True,
+                               fail_step_msg="bad lowering shape")
+    req = await _submit(eng)
+    deltas = await _drain_stream(eng, req)
+    assert deltas[-1].error is not None
+    await _wait_for(lambda: eng.supervisor.state == "failed",
+                    msg="fatal park")
+    s = eng.stats()
+    assert s["supervisor_last_failure_kind"] == "fatal"
+    assert s["supervisor_restarts_total"] == 0      # no restart burned
+    with pytest.raises(EngineUnavailable):
+        await _submit(eng)
+    with pytest.raises(EngineUnavailable):
+        await eng.start()
 
-        # Recovery is an explicit operator decision, not automatic.
-        eng.fault_plan = None
-        await eng.stop()
-        assert eng.supervisor.state == "stopped"
-        req2 = await _submit(eng)
-        deltas = await _drain_stream(eng, req2)
-        assert req2.finish_reason is not None and deltas[-1].error is None
-    finally:
-        eng.fault_plan = None
-        await eng.stop()
+    # Recovery is an explicit operator decision, not automatic.
+    eng.fault_plan = None
+    await eng.stop()
+    assert eng.supervisor.state == "stopped"
+    req2 = await _submit(eng)
+    deltas = await _drain_stream(eng, req2)
+    assert req2.finish_reason is not None and deltas[-1].error is None
 
 
-async def test_restart_budget_exhaustion_parks_failed():
+@pytest.mark.parametrize("preset", KINDS)
+async def test_restart_budget_exhaustion_parks_failed(mk, preset):
     """A fault that survives the restart burns the bounded budget and
     then parks — supervised restarts never loop forever."""
-    eng = _mk(supervisor={"max_restarts": 2, "backoff_ms": 1.0})
-    try:
-        eng.fault_plan = FaultPlan(fail_step_after=0)    # every step fails
-        req = await _submit(eng)
-        deltas = await _drain_stream(eng, req)
-        assert deltas[-1].error is not None
-        await _wait_for(lambda: eng.supervisor.state == "failed",
-                        msg="budget exhaustion")
-        s = eng.stats()
-        assert s["supervisor_restarts_total"] == 2
-        assert "budget exhausted" in [
-            r for st, r in _supervisor_flight_states(eng)
-            if st == "failed"][-1]
-        with pytest.raises(EngineUnavailable):
-            await _submit(eng)
-    finally:
-        eng.fault_plan = None
-        await eng.stop()
+    eng = mk(preset=preset,
+             supervisor={"max_restarts": 2, "backoff_ms": 1.0})
+    eng.fault_plan = FaultPlan(fail_step_after=0)    # every step fails
+    req = await _submit(eng)
+    deltas = await _drain_stream(eng, req)
+    assert deltas[-1].error is not None
+    await _wait_for(lambda: eng.supervisor.state == "failed",
+                    msg="budget exhaustion")
+    s = eng.stats()
+    assert s["supervisor_restarts_total"] == 2
+    assert "budget exhausted" in [
+        r for st, r in _supervisor_flight_states(eng)
+        if st == "failed"][-1]
+    with pytest.raises(EngineUnavailable):
+        await _submit(eng)
 
 
-async def test_handoff_fault_on_disagg_engine_recovers():
+async def test_handoff_fault_on_disagg_engine_recovers(mk):
     """Crash DURING the prefill→decode KV handoff on a disaggregated
     engine: the in-flight request errors, the rebuilt pool passes the
     allocator invariants, and the engine serves again."""
-    eng = _mk(kv_layout="paged", kv_page_size=16, max_batch_size=4,
+    eng = mk(kv_layout="paged", kv_page_size=16, max_batch_size=4,
               max_seq_len=128, prefill_chunk=32,
               disaggregation={"enabled": True, "prefill_slots": 1},
               supervisor={"backoff_ms": 10.0})
-    try:
-        eng.fault_plan = FaultPlan(fail_handoff_after=0)
-        req = await _submit(eng, prompt_ids=list(range(1, 20)))
-        deltas = await _drain_stream(eng, req)
-        assert "injected handoff fault" in deltas[-1].error
-        eng.fault_plan = None
-        await _wait_for(lambda: eng.supervisor.state == "serving",
-                        msg="restart after handoff crash")
-        req2 = await _submit(eng, prompt_ids=list(range(1, 20)))
-        deltas = await _drain_stream(eng, req2)
-        assert req2.finish_reason is not None and deltas[-1].error is None
-        eng._prefix_cache.check_invariants()
-    finally:
-        eng.fault_plan = None
-        await eng.stop()
+    eng.fault_plan = FaultPlan(fail_handoff_after=0)
+    req = await _submit(eng, prompt_ids=list(range(1, 20)))
+    deltas = await _drain_stream(eng, req)
+    assert "injected handoff fault" in deltas[-1].error
+    eng.fault_plan = None
+    await _wait_for(lambda: eng.supervisor.state == "serving",
+                    msg="restart after handoff crash")
+    req2 = await _submit(eng, prompt_ids=list(range(1, 20)))
+    deltas = await _drain_stream(eng, req2)
+    assert req2.finish_reason is not None and deltas[-1].error is None
+    eng._prefix_cache.check_invariants()
 
 
 # -- watchdog -----------------------------------------------------------------
 
-async def test_watchdog_recovers_silent_stall():
+@pytest.mark.parametrize("preset", KINDS)
+async def test_watchdog_recovers_silent_stall(mk, preset):
     """A silent loop stall (the loop is alive but stops stepping while
     work is pending) is the failure only the watchdog can see: it kills
     the loop, the queued request survives the supervised restart, and
@@ -250,91 +254,79 @@ async def test_watchdog_recovers_silent_stall():
     # headroom over post-restart recompiles: _rebuild_state's fresh
     # buffers can re-trigger ~1 s XLA compiles on the first steps, and a
     # deadline under that reads a legitimately slow step as a stall.
-    eng = _mk(supervisor={"watchdog_ms": 60000.0, "backoff_ms": 5.0,
+    eng = mk(preset=preset, supervisor={"watchdog_ms": 60000.0, "backoff_ms": 5.0,
                           "max_restarts": 20})
-    try:
-        warm = await _submit(eng, max_tokens=2)
-        await _drain_stream(eng, warm)
-        eng.supervisor.watchdog_ms = 2000.0
-        eng.fault_plan = FaultPlan(stall_step_after=0, stall_s=30.0)
-        req = await _submit(eng, max_tokens=4)
-        await _wait_for(
-            lambda: eng.stats()["supervisor_restarts_total"] >= 1,
-            msg="watchdog restart")
-        eng.fault_plan = None
-        # The queued-but-unstarted request was NOT errored: it stays
-        # queued across the transient restart and completes.
-        deltas = await _drain_stream(eng, req)
-        assert deltas[-1].error is None
-        assert req.finish_reason is not None
-        s = eng.stats()
-        assert s["supervisor_last_failure_kind"] == "stall"
-        assert "stalled" in s["supervisor_last_failure"]
-    finally:
-        eng.fault_plan = None
-        await eng.stop()
+    warm = await _submit(eng, max_tokens=2)
+    await _drain_stream(eng, warm)
+    eng.supervisor.watchdog_ms = 2000.0
+    eng.fault_plan = FaultPlan(stall_step_after=0, stall_s=30.0)
+    req = await _submit(eng, max_tokens=4)
+    await _wait_for(
+        lambda: eng.stats()["supervisor_restarts_total"] >= 1,
+        msg="watchdog restart")
+    eng.fault_plan = None
+    # The queued-but-unstarted request was NOT errored: it stays
+    # queued across the transient restart and completes.
+    deltas = await _drain_stream(eng, req)
+    assert deltas[-1].error is None
+    assert req.finish_reason is not None
+    s = eng.stats()
+    assert s["supervisor_last_failure_kind"] == "stall"
+    assert "stalled" in s["supervisor_last_failure"]
 
 
-async def test_idle_engine_never_trips_watchdog():
+async def test_idle_engine_never_trips_watchdog(mk):
     """An engine parked on its work event past the watchdog deadline is
     idle, not stalled."""
-    eng = _mk(supervisor={"watchdog_ms": 60000.0})
-    try:
-        req = await _submit(eng, max_tokens=2)
-        await _drain_stream(eng, req)    # compile warm, queue empty
-        eng.supervisor.watchdog_ms = 100.0
-        await asyncio.sleep(0.6)         # several deadlines of pure idle
-        s = eng.stats()
-        assert s["supervisor_state"] == "serving"
-        assert s["supervisor_restarts_total"] == 0
-    finally:
-        await eng.stop()
+    eng = mk(supervisor={"watchdog_ms": 60000.0})
+    req = await _submit(eng, max_tokens=2)
+    await _drain_stream(eng, req)    # compile warm, queue empty
+    eng.supervisor.watchdog_ms = 100.0
+    await asyncio.sleep(0.6)         # several deadlines of pure idle
+    s = eng.stats()
+    assert s["supervisor_state"] == "serving"
+    assert s["supervisor_restarts_total"] == 0
 
 
 # -- graceful drain -----------------------------------------------------------
 
-async def test_drain_restart_finishes_inflight_then_serves():
-    eng = _mk()
-    try:
-        req = await _submit(eng, max_tokens=6)
-        task = asyncio.get_running_loop().create_task(
-            eng.drain(restart=True))
-        await asyncio.sleep(0)           # drain enters "draining"
-        with pytest.raises(EngineUnavailable, match="draining"):
-            await _submit(eng)
-        summary = await task
-        assert summary["forced_cancel"] == 0 and summary["restarted"]
-        # The in-flight request finished normally under the deadline.
-        deltas = await _drain_stream(eng, req)
-        assert deltas[-1].error is None and req.finish_reason is not None
-        assert eng.supervisor.state == "serving"
-        req2 = await _submit(eng)
-        await _drain_stream(eng, req2)
-        assert req2.finish_reason is not None
-    finally:
-        await eng.stop()
+@pytest.mark.parametrize("preset", KINDS)
+async def test_drain_restart_finishes_inflight_then_serves(mk, preset):
+    eng = mk(preset=preset)
+    req = await _submit(eng, max_tokens=6)
+    task = asyncio.get_running_loop().create_task(
+        eng.drain(restart=True))
+    await asyncio.sleep(0)           # drain enters "draining"
+    with pytest.raises(EngineUnavailable, match="draining"):
+        await _submit(eng)
+    summary = await task
+    assert summary["forced_cancel"] == 0 and summary["restarted"]
+    # The in-flight request finished normally under the deadline.
+    deltas = await _drain_stream(eng, req)
+    assert deltas[-1].error is None and req.finish_reason is not None
+    assert eng.supervisor.state == "serving"
+    req2 = await _submit(eng)
+    await _drain_stream(eng, req2)
+    assert req2.finish_reason is not None
 
 
-async def test_drain_deadline_expiry_force_cancels():
+@pytest.mark.parametrize("preset", KINDS)
+async def test_drain_deadline_expiry_force_cancels(mk, preset):
     """Past the drain deadline, stragglers are force-cancelled through
     the normal scheduler path (finish_reason `cancelled`) and the engine
     stops."""
-    eng = _mk()
-    try:
-        eng.fault_plan = FaultPlan(slow_decode_s=0.05)
-        req = await _submit(eng, max_tokens=50)
-        await asyncio.sleep(0.1)         # let it get admitted + decoding
-        summary = await eng.drain(deadline_s=0.05)
-        assert summary["forced_cancel"] >= 1
-        assert summary["restarted"] is False
-        assert eng.supervisor.state == "stopped"
-        deltas = await _drain_stream(eng, req)
-        terminal = deltas[-1]
-        assert (terminal.finish_reason == "cancelled"
-                or terminal.error is not None)
-    finally:
-        eng.fault_plan = None
-        await eng.stop()
+    eng = mk(preset=preset)
+    eng.fault_plan = FaultPlan(slow_decode_s=0.05)
+    req = await _submit(eng, max_tokens=50)
+    await asyncio.sleep(0.1)         # let it get admitted + decoding
+    summary = await eng.drain(deadline_s=0.05)
+    assert summary["forced_cancel"] >= 1
+    assert summary["restarted"] is False
+    assert eng.supervisor.state == "stopped"
+    deltas = await _drain_stream(eng, req)
+    terminal = deltas[-1]
+    assert (terminal.finish_reason == "cancelled"
+            or terminal.error is not None)
 
 
 # -- failover: breaker-skip latency ------------------------------------------
@@ -468,15 +460,29 @@ class SupervisedGateway:
             "messages": [{"role": "user", "content": "hello"}], **extra})
 
     async def sse_frames(self, resp):
-        frames = []
         async for line in resp.content:
             line = line.decode().strip()
             if line.startswith("data: "):
-                frames.append(line[len("data: "):])
-        return frames
+                yield line[len("data: "):]
 
 
-async def test_acceptance_crash_failover_and_halfopen_recovery(tmp_path):
+class _LetterTokenizer:
+    """A letter an id. Random weights emit ids the byte tokenizer drops or
+    holds back as unfinished UTF-8, and a stream that has sent no text is
+    not committed: it would fail over whole, with no frame to inspect."""
+
+    def __init__(self, inner):
+        self._inner = inner
+
+    def __getattr__(self, name):
+        return getattr(self._inner, name)
+
+    def decode(self, ids):
+        return "".join(chr(97 + i % 26) for i in ids)
+
+
+async def test_acceptance_crash_failover_and_halfopen_recovery(tmp_path,
+                                                               all_free):
     """The ISSUE 14 acceptance chain, end to end on a disaggregated
     engine: step-loop crash mid-decode → in-band SSE error frame +
     partial usage row; engine parks (budget exhausted) → next requests
@@ -491,14 +497,24 @@ async def test_acceptance_crash_failover_and_halfopen_recovery(tmp_path):
         assert body["choices"][0]["message"]["content"] != "Hello world!"
         eng = g.engine
 
-        # Phase B: crash mid-decode while a stream is on the wire. The
-        # fault keeps firing through both budgeted restarts, so the
-        # engine deterministically parks in `failed`.
-        eng.fault_plan = FaultPlan(fail_step_after=3)
+        # Phase B: crash mid-decode while a stream is on the wire — at the
+        # scheduler's next step once the first frame has arrived (bursts
+        # slowed so the answer is still being decoded then; by a step
+        # count alone the crash can land before the gateway has committed
+        # the stream, and the request fails over whole). The fault keeps
+        # firing through both budgeted restarts, so the engine
+        # deterministically parks in `failed`.
+        eng.tokenizer = _LetterTokenizer(eng.tokenizer)
+        plan = eng.fault_plan = FaultPlan(slow_decode_s=0.05)
         resp = await g.chat(stream=True, max_tokens=64)
-        assert resp.status == 200        # committed before the crash
-        frames = await g.sse_frames(resp)
-        err = json.loads(frames[-1])
+        assert resp.status == 200
+        frames = []
+        async for frame in g.sse_frames(resp):
+            if not frames:
+                plan.fail_step_after = plan.step_calls
+            frames.append(frame)
+        assert len(frames) > 1           # committed before the crash
+        err = json.loads(frames[-1])     # an error frame ends the stream
         assert "error" in err            # well-formed in-band error frame
         assert err["error"]["provider"] == "tpu"
         assert "engine failure" in err["error"]["message"]
@@ -556,6 +572,6 @@ async def test_acceptance_crash_failover_and_halfopen_recovery(tmp_path):
 
         # Invariants: no leaked pages, no leaked flight admit/finish
         # pairs across the whole incident.
-        eng._prefix_cache.check_invariants()
         fs = eng.flight.stats()
         assert fs["flight_admits"] == fs["flight_finishes"]
+    all_free(eng)

@@ -19,6 +19,7 @@ def test_example_configs_validate(tmp_path):
             "local_tiny"} <= set(providers)
     assert providers["local_tpu"].type == "local"
     assert providers["local_tpu"].engine.mesh == {"data": 1, "model": 8}
+    assert providers["local_tpu"].engine.kv_layout == "paged"   # old files
     rules = loader.rules
     assert rules["free-rotation"].rotate_models is True
     chain = rules["llama-3-8b"].fallback_models

@@ -7,20 +7,35 @@ finish it instead of racing the backoff window."""
 import asyncio
 import time
 
+import jax
 import pytest
 
 from llmapigateway_tpu.config.schemas import LocalEngineConfig
 from llmapigateway_tpu.engine.engine import FaultPlan, GenRequest, InferenceEngine
 
 
-@pytest.fixture(scope="module")
-def engine(stop_engine):
-    cfg = LocalEngineConfig(kv_layout="contiguous",
-        preset="tiny-test", max_batch_size=2,
+KINDS = ("tiny-test", "tiny-mistral-test", "tiny-smallthinker-test",
+         "tiny-gigachat35-test")
+
+
+@pytest.fixture(scope="module", params=KINDS)
+def shared_engine(request, stop_engine):
+    """Whole contexts; the window's page ring (pages of 4: 9 a slot of
+    16); a family of TWO cache groups, a ring beside whole contexts; and
+    one with a LATENT pool and a block of recurrent state a slot (the
+    period families refuse the prefix cache): a rebuild after a fault
+    must rebuild every cache group, of every kind."""
+    cfg = LocalEngineConfig(preset=request.param, max_batch_size=2,
                             max_seq_len=64, prefill_chunk=8, decode_burst=2,
+                            kv_page_size=4,
+                            prefix_cache=request.param in KINDS[:2],
                             supervisor={"max_restarts": 10,
                                         "backoff_ms": 10.0})
-    eng = InferenceEngine(cfg)
+    eng = InferenceEngine(cfg, devices=[jax.devices("cpu")[0]])
+    assert bool(eng._swa_ring_pages) == (request.param in KINDS[1:3])
+    assert len(eng.kv_groups) == 1 + (request.param == KINDS[2])
+    assert (eng.kv_groups.whole_context.kind == "latent") == (
+        request.param == KINDS[3])
     yield eng
     stop_engine(eng)
 

@@ -654,6 +654,24 @@ def test_live_codebase_is_clean():
     assert not findings, f"graftlint findings in the live tree:\n{rendered}"
 
 
+def test_live_codebase_branches_on_no_kv_layout():
+    """The KV cache is a page pool and nothing else (PR 49): no condition
+    anywhere in the package reads ``kv_layout`` or a ``paged`` flag — a
+    second layout would come back as one of these first."""
+    import ast
+    for path in iter_python_files(PACKAGE_DIR):
+        for node in ast.walk(ast.parse(Path(path).read_text())):
+            test = getattr(node, "test", None) if isinstance(
+                node, (ast.If, ast.IfExp, ast.While)) else (
+                node if isinstance(node, ast.Compare) else None)
+            if test is None:
+                continue
+            read = {n.attr if isinstance(n, ast.Attribute) else n.id
+                    for n in ast.walk(test)
+                    if isinstance(n, (ast.Attribute, ast.Name))}
+            assert not read & {"kv_layout", "paged"}, (path, node.lineno)
+
+
 def test_live_codebase_program_clean():
     """graftlint v2's whole-program pass (symbol table + call graph +
     dataflow: transitive async-blocking, guarded-by inference, httpx

@@ -65,65 +65,19 @@ def test_forward_fidelity_with_int8_cache(setup):
         assert rel < 0.05, rel
 
 
-def test_pallas_q8_kernels_match_jnp_reference(setup):
-    """The flash kernels with an int8 {"q","s"} cache (interpret mode on
-    CPU) must match the dict-aware jnp reference attention."""
-    from llmapigateway_tpu.ops import (flash_decode_attention,
-                                       flash_prefill_attention)
-
-    cfg, params = setup
-    B, T, S = 2, 16, 64
-    H, KV, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    rng = np.random.default_rng(2)
-
-    # Build a filled int8 cache via the quantizing insert.
-    k_hist = jnp.asarray(rng.standard_normal((B, 48, KV, Dh)), jnp.float32)
-    v_hist = jnp.asarray(rng.standard_normal((B, 48, KV, Dh)), jnp.float32)
-    zero = {"q": jnp.zeros((B, KV, S, Dh), jnp.int8),
-            "s": jnp.zeros((B, KV, 1, S), jnp.float32)}
-    lk, lv = llama.insert_kv(dict(zero), dict(zero), k_hist, v_hist,
-                             jnp.zeros((B,), jnp.int32), None)
-
-    lengths = jnp.asarray([37, 48], jnp.int32)
-    q1 = jnp.asarray(rng.standard_normal((B, H, Dh)), jnp.float32)
-    kn = jnp.asarray(rng.standard_normal((B, KV, Dh)), jnp.float32)
-    vn = jnp.asarray(rng.standard_normal((B, KV, Dh)), jnp.float32)
-
-    got = np.asarray(flash_decode_attention(
-        q1, kn, vn, lk, lv, lengths, block_s=16, interpret=True), np.float32)
-    want = np.asarray(llama.dense_decode_attention(
-        q1[:, None], kn[:, None], vn[:, None], lk, lv, lengths)[:, 0],
-        np.float32)
-    np.testing.assert_allclose(got.reshape(want.shape), want,
-                               rtol=2e-3, atol=2e-3)
-
-    # Prefill chunk: keys already inserted at [lengths, lengths+T).
-    qT = jnp.asarray(rng.standard_normal((B, T, H, Dh)), jnp.float32)
-    kT = jnp.asarray(rng.standard_normal((B, T, KV, Dh)), jnp.float32)
-    vT = jnp.asarray(rng.standard_normal((B, T, KV, Dh)), jnp.float32)
-    start = jnp.asarray([5, 32], jnp.int32)
-    lk2, lv2 = llama.insert_kv(lk, lv, kT, vT, start, None)
-    got2 = np.asarray(flash_prefill_attention(
-        qT, lk2, lv2, start, block_t=8, block_s=16, interpret=True),
-        np.float32)
-    want2 = np.asarray(llama.dense_verify_attention(
-        qT, kT, vT, lk, lv, start), np.float32)
-    np.testing.assert_allclose(got2, want2, rtol=2e-2, atol=2e-2)
-
-
-@pytest.mark.parametrize("quant,kv_layout", [("", "contiguous"),
-                                             ("int8", "contiguous"),
-                                             ("", "paged")])
-def test_engine_e2e_with_kv_quant(quant, kv_layout):
-    """Engine serves greedily with the int8 cache — alone, combined with
-    int8 weights (the fully-quantized configuration), and on the paged
-    pool (the capacity combo: int8 pages pack 2x the tokens)."""
+@pytest.mark.parametrize("preset", ["tiny-test", "tiny-mistral-test"])
+@pytest.mark.parametrize("quant", ["", "int8"])
+def test_engine_e2e_with_kv_quant(quant, preset):
+    """Engine serves greedily from the int8 pool (int8 pages pack 2x the
+    tokens) — alone, and combined with int8 weights (the fully-quantized
+    configuration) — whole contexts and the window's page ring (what the
+    Mistral cells serve from: int8 weights, an int8 ring)."""
     from llmapigateway_tpu.engine.engine import GenRequest, InferenceEngine
 
-    cfg = LocalEngineConfig(preset="tiny-test", max_batch_size=2,
+    cfg = LocalEngineConfig(preset=preset, max_batch_size=2,
                             max_seq_len=128, prefill_chunk=16,
                             decode_burst=4, kv_quant="int8", quant=quant,
-                            kv_layout=kv_layout, kv_page_size=32,
+                            kv_page_size=8,
                             prewarm_sampler_variants=False,
                             compilation_cache_dir="off")
     engine = InferenceEngine(cfg)
@@ -211,36 +165,32 @@ def test_paged_q8_kernels_match_reference(setup):
     np.testing.assert_allclose(got2, want2, rtol=2e-3, atol=2e-3)
 
 
-async def test_engine_pallas_with_kv_quant_matches_reference():
+@pytest.mark.parametrize("preset", ["tiny-test", "tiny-mistral-test"])
+async def test_engine_pallas_with_kv_quant_matches_reference(build_engine,
+                                                             preset):
     """attention=pallas + kv_quant (the best single-chip configuration)
-    serves through the interpret-mode q8 kernels and produces the same
-    greedy tokens as the reference path on the same quantized cache."""
-    from llmapigateway_tpu.engine.engine import GenRequest, InferenceEngine
-
+    serves through the interpret-mode q8 kernels — and through the
+    WINDOWED ones from a ring, the Mistral cells' kernels — and produces
+    the greedy tokens of the models' dense forward over the same
+    quantized cache."""
+    from llmapigateway_tpu.engine.engine import GenRequest
     from tests.conftest import cpu_devices
+    from tests.dense_reference import greedy_tokens
 
-    async def run(attention):
-        cfg = LocalEngineConfig(kv_layout="contiguous",
-        preset="tiny-test", max_batch_size=1,
-                                max_seq_len=64, prefill_chunk=16,
-                                decode_burst=2, kv_quant="int8",
-                                attention=attention,
-                                prewarm_sampler_variants=False,
-                                compilation_cache_dir="off")
-        eng = InferenceEngine(cfg, devices=[cpu_devices()[0]])
-        await eng.start()
-        req = GenRequest(prompt_ids=list(range(2, 20)), max_tokens=6,
-                         temperature=0.0)
-        await eng.submit(req)
-        async for _ in eng.stream(req):
-            pass
-        await eng.stop()
-        return req
-
-    got = await run("pallas")
-    ref = await run("reference")
-    assert got.generated == ref.generated
-    assert got.finish_reason == ref.finish_reason
+    eng = build_engine(
+        LocalEngineConfig(preset=preset, max_batch_size=1,
+                          max_seq_len=64, prefill_chunk=16, decode_burst=2,
+                          kv_quant="int8", kv_page_size=4,
+                          attention="pallas", prewarm_sampler_variants=False,
+                          compilation_cache_dir="off"),
+        devices=[cpu_devices()[0]])
+    req = GenRequest(prompt_ids=list(range(2, 20)), max_tokens=6,
+                     temperature=0.0)
+    await eng.submit(req)
+    async for _ in eng.stream(req):
+        pass
+    assert req.generated == greedy_tokens(eng, req.prompt_ids, 6)
+    assert req.finish_reason == "length"
 
 
 @pytest.mark.parametrize("kv_quant", ["", "int8"])
@@ -293,16 +243,14 @@ def test_kv_quant_guardrails():
     base = dict(preset="tiny-test", max_batch_size=1, max_seq_len=64,
                 compilation_cache_dir="off")
     with pytest.raises(ValueError, match="kv_quant"):
-        InferenceEngine(LocalEngineConfig(kv_layout="contiguous",
-        kv_quant="int4", **base))
+        InferenceEngine(LocalEngineConfig(kv_page_size=16,
+                                          kv_quant="int4", **base))
     # int8 + speculation now COMPOSES (the verify self-block went
     # mixed-precision — drafted tokens quantize→dequantize exactly like
-    # the insert path): both layouts must build. Parity itself is pinned
-    # by tests/test_speculative.py's int8 parity tests.
-    for layout in ("contiguous", "paged"):
-        InferenceEngine(LocalEngineConfig(kv_layout=layout,
-                                          kv_quant="int8", spec_draft_len=3,
-                                          **base))
+    # the insert path): it must build. Parity itself is pinned by
+    # tests/test_speculative.py's int8 parity tests.
+    InferenceEngine(LocalEngineConfig(kv_quant="int8", spec_draft_len=3,
+                                      **base))
 
 
 @pytest.mark.parametrize("chips", [2, 4])

@@ -433,7 +433,6 @@ def test_layer_norm_removes_the_mean_and_has_no_bias():
 # -- what the family does not serve --------------------------------------------
 
 REFUSED = {
-    "a contiguous cache": (dict(kv_layout="contiguous"), "kv_layout"),
     "the prefix cache": (dict(prefix_cache=True), "prefix_cache"),
     "speculation": (dict(spec_draft_len=3), "spec_draft_len"),
     "a mesh axis": (dict(mesh={"model": 2}), "mesh"),

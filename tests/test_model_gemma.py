@@ -86,8 +86,8 @@ def test_gemma_engine_e2e():
     (exercises MQA GQA-grouping G=H, tied quantizable-free head, scaling)."""
     from llmapigateway_tpu.engine.engine import GenRequest, InferenceEngine
 
-    cfg = LocalEngineConfig(kv_layout="contiguous",
-        preset="tiny-gemma-test", max_batch_size=2,
+    cfg = LocalEngineConfig(kv_page_size=16,
+                            preset="tiny-gemma-test", max_batch_size=2,
                             max_seq_len=128, prefill_chunk=16,
                             decode_burst=4, prewarm_sampler_variants=False,
                             compilation_cache_dir="off")

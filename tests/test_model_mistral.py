@@ -16,6 +16,7 @@ from llmapigateway_tpu.models import llama
 from llmapigateway_tpu.models.config import ModelConfig, get_preset
 
 from tests.conftest import cpu_devices
+from tests.dense_reference import greedy_tokens
 from tests.mesh_parity import CYCLING, serve
 
 
@@ -105,7 +106,7 @@ async def _serve(mesh, devs, max_tokens=16, **kw):
     # depths = different programs = near-tie argmax flips on random
     # weights; see test_speculative._engine).
     kw.setdefault("decode_burst_busy", 4)
-    kw.setdefault("kv_layout", "contiguous")   # dense reference by default
+    kw.setdefault("kv_page_size", 16)
     cfg = LocalEngineConfig(preset="tiny-mistral-test", max_batch_size=2,
                             max_seq_len=128, prefill_chunk=32,
                             dtype="float32", decode_burst=4, mesh=mesh,
@@ -123,6 +124,12 @@ async def _serve(mesh, devs, max_tokens=16, **kw):
     return req, eng
 
 
+def _dense(req, eng):
+    """What the windowed dense forward generates for ``req`` with the
+    engine's weights (tests/dense_reference.py)."""
+    return greedy_tokens(eng, req.prompt_ids, req.max_tokens)
+
+
 async def test_engine_serves_sliding_window_model():
     req, eng = await _serve({}, [cpu_devices()[0]])
     assert req.finish_reason == "length"
@@ -131,38 +138,21 @@ async def test_engine_serves_sliding_window_model():
 
 
 async def test_engine_swa_composes_with_spec():
-    """The windowed dense paths thread through the speculative verify —
-    tokens must match the plain engine's."""
-    ref, _ = await _serve({}, [cpu_devices()[0]])
+    """The window threads through the speculative verify — tokens must
+    match the windowed dense forward's."""
     spec, eng = await _serve({}, [cpu_devices()[0]], spec_draft_len=3)
-    assert spec.generated == ref.generated
+    assert spec.generated == _dense(spec, eng)
     assert eng._spec_steps_done > 0          # speculation really engaged
 
 
-async def test_engine_swa_pallas_matches_reference():
-    """Single-device SWA engines run the WINDOWED flash kernels
-    (interpret mode on CPU) — greedy tokens must match the windowed
-    dense reference engine exactly."""
-    ref, _ = await _serve({}, [cpu_devices()[0]])
-    pal, eng = await _serve({}, [cpu_devices()[0]], attention="pallas")
-    assert pal.generated == ref.generated
-    assert eng.model_cfg.sliding_window == 16
-    # The flash path really engaged (a silent downgrade to reference
-    # would make this test compare the reference to itself).
-    assert eng._resolve_attention_impl() == "pallas"
-    assert eng._pick_attention() is not None
-
-
 async def test_engine_swa_paged_pallas_matches_reference():
-    """SWA x paged with the WINDOWED paged kernels (interpret mode on
-    CPU): greedy tokens must match the windowed dense reference engine.
-    16 generated tokens from a 40-token prompt walk the window (16)
-    across page boundaries (page=16) during decode."""
-    ref, _ = await _serve({}, [cpu_devices()[0]])
-    pag, eng = await _serve({}, [cpu_devices()[0]], attention="pallas",
-                            kv_layout="paged", kv_page_size=16)
-    assert pag.generated == ref.generated
-    assert eng.paged and eng.model_cfg.sliding_window == 16
+    """A window with the WINDOWED paged kernels (interpret mode on CPU):
+    greedy tokens must match the windowed dense forward's. 16 generated
+    tokens from a 40-token prompt walk the window (16) across page
+    boundaries (page=16) during decode."""
+    pag, eng = await _serve({}, [cpu_devices()[0]], attention="pallas")
+    assert pag.generated == _dense(pag, eng)
+    assert eng.model_cfg.sliding_window == 16
     assert eng._resolve_attention_impl() == "pallas"
 
 
@@ -171,26 +161,22 @@ def test_swa_guardrails():
     axis is gone, and the configuration itself refuses it by name."""
     with pytest.raises(ValueError, match="unknown mesh axis 'seq'"):
         LocalEngineConfig(
-            kv_layout="contiguous", preset="tiny-mistral-test",
-            max_batch_size=1, max_seq_len=64, mesh={"seq": 4},
+            preset="tiny-mistral-test", max_batch_size=1, max_seq_len=64, mesh={"seq": 4},
             compilation_cache_dir="off")
 
 
 async def test_engine_swa_paged_spec_ring_matches_reference():
-    """Speculation x SWA x paged RING: the spec verify reads the window
-    from the rotating pool and data-dependent advances stay inside the
-    ring margin — greedy tokens must match the windowed dense engine
-    exactly (gate disabled so drafting really runs). The request's
-    footprint (40 + 80 = 120 tokens) EXCEEDS the ring (6 pages × 16 =
-    96 tokens), so the slot really is ring-mode and ensure_mapped
+    """Speculation x window x the page RING: the spec verify reads the
+    window from the rotating pool and data-dependent advances stay inside
+    the ring margin — greedy tokens must match the windowed dense
+    forward's exactly (gate disabled so drafting really runs). The
+    request's footprint (40 + 80 = 120 tokens) EXCEEDS the ring (6 pages
+    × 16 = 96 tokens), so the slot really is ring-mode and ensure_mapped
     rotates pages mid-generation — a short request would be capped
     under the ring and never rotate."""
-    ref, _ = await _serve({}, [cpu_devices()[0]], max_tokens=80)
     sp, eng = await _serve({}, [cpu_devices()[0]], max_tokens=80,
-                           kv_layout="paged", kv_page_size=16,
-                           spec_draft_len=3,
-                           spec_min_tokens_per_step=0.0)
-    assert sp.generated == ref.generated and len(sp.generated) == 80
+                           spec_draft_len=3, spec_min_tokens_per_step=0.0)
+    assert sp.generated == _dense(sp, eng) and len(sp.generated) == 80
     assert eng._swa_ring_pages > 0
     # The footprint genuinely overflowed the ring (rotation occurred).
     assert eng.allocator.pages_needed(120) > eng._swa_ring_pages
@@ -198,32 +184,15 @@ async def test_engine_swa_paged_spec_ring_matches_reference():
     eng.allocator.check_invariants()
 
 
-async def test_engine_swa_sharded_pallas_matches_reference():
-    """SWA on a MULTI-CHIP mesh with the pallas kernels: the window bound
-    threads through the shard_map'd flash wrapper (head sharding on TP,
-    batch on DP never touch absolute positions) — greedy tokens must
-    match the windowed dense reference engine."""
-    ref, _ = await _serve({}, [cpu_devices()[0]])
+async def test_engine_swa_paged_sharded_pallas_matches_reference():
+    """A window on a MULTI-CHIP mesh with the WINDOWED paged kernels:
+    window x page-table indirection x model-axis shard_map — greedy
+    tokens must match the windowed dense forward's."""
     tp, eng = await _serve({"model": 2}, cpu_devices()[:2],
                            attention="pallas")
-    assert tp.generated == ref.generated
-    assert eng.model_cfg.sliding_window == 16 and eng.mesh.size == 2
+    assert tp.generated == _dense(tp, eng)
+    assert eng.model_cfg.sliding_window == 16
     assert eng.mesh.shape.get("model") == 2     # the REQUESTED mesh ran
-    assert eng._resolve_attention_impl() == "pallas"
-
-
-async def test_engine_swa_paged_sharded_pallas_matches_reference():
-    """SWA x paged on a MULTI-CHIP mesh with the WINDOWED paged kernels:
-    window x page-table indirection x model-axis shard_map is the one
-    composition the dense sharded test can't cover — greedy tokens must
-    match the windowed dense reference engine."""
-    ref, _ = await _serve({}, [cpu_devices()[0]])
-    tp, eng = await _serve({"model": 2}, cpu_devices()[:2],
-                           attention="pallas", kv_layout="paged",
-                           kv_page_size=16)
-    assert tp.generated == ref.generated
-    assert eng.paged and eng.model_cfg.sliding_window == 16
-    assert eng.mesh.shape.get("model") == 2
     assert eng._resolve_attention_impl() == "pallas"
 
 

@@ -132,10 +132,9 @@ async def test_engine_serves_moe_preset():
     from llmapigateway_tpu.config.schemas import LocalEngineConfig
     from llmapigateway_tpu.engine.engine import GenRequest, InferenceEngine
 
-    eng = InferenceEngine(LocalEngineConfig(kv_layout="contiguous",
-        
+    eng = InferenceEngine(LocalEngineConfig(
         preset="tiny-moe-test", dtype="float32", max_batch_size=2,
-        max_seq_len=64, prefill_chunk=16))
+        max_seq_len=64, prefill_chunk=16, kv_page_size=16))
     try:
         req = GenRequest(prompt_ids=[1, 2, 3, 4], max_tokens=8)
         await eng.submit(req)

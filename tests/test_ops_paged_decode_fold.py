@@ -38,9 +38,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from llmapigateway_tpu.ops import paged_attention as pa
-from llmapigateway_tpu.ops.flash_attention import (attend_block,
-                                                   self_column_init,
-                                                   unpack_kv_refs)
 
 NEG_INF = -1e30
 FEW_ULP = 16 * 2.0 ** -24            # sixteen units of fp32's last place
@@ -56,8 +53,91 @@ B = len(LENGTHS)
 
 
 # ---------------------------------------------------------------------------
-# The kernel this PR replaced, frozen: one KV head's block a grid step.
+# The kernel PR 27 replaced, frozen: one KV head's block a grid step. Its
+# block arithmetic comes first, frozen with it.
 # ---------------------------------------------------------------------------
+
+def self_column_init(q_ref, kn_ref, vn_ref, m_ref, l_ref, acc_ref) -> None:
+    """Initialize a decode kernel's online-softmax state from the SELF
+    column (the new token attending itself): m = q·k_new, l = 1,
+    acc = v_new. The cache is STALE — the current token's K/V never
+    touched HBM; its contribution lives entirely in registers (the
+    deferred-insert decode protocol, models/llama.py forward())."""
+    q = q_ref[0, 0].astype(jnp.float32)            # [G, Dh]
+    kn = kn_ref[0, 0].astype(jnp.float32)          # [1, Dh]
+    vn = vn_ref[0, 0].astype(jnp.float32)          # [1, Dh]
+    self_s = jax.lax.dot_general(
+        q, kn, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)        # [G, 1]
+    self_s *= q.shape[-1] ** -0.5
+    m_ref[:] = jnp.broadcast_to(self_s, m_ref.shape)
+    l_ref[:] = jnp.ones_like(l_ref)
+    acc_ref[:] = jnp.broadcast_to(vn, acc_ref.shape)
+
+
+def attend_block(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, mask,
+                 ks_ref=None, vs_ref=None, sub: int = 0) -> None:
+    """One online-softmax block update of the frozen kernel (bf16 and
+    int8-KV). ``mask(scores)`` applies the caller's visibility rule;
+    ``ks_ref``/``vs_ref`` are the optional int8-KV per-token scale blocks
+    ``[1, 1, 1, BS]`` (rank-4: the unit dim before the token axis keeps the
+    block's trailing two dims ``(1, BS)`` legal under the TPU (8, 128)
+    tiling rule — a ``(1, BS)`` block of a rank-3 ``[B, KV, S]`` array
+    would put a block of 1 on the KV dim, which real Mosaic lowering
+    rejects; interpret mode never catches this): the scale factors out of
+    the Dh contraction, so scores
+    multiply by ``ks`` after the QK dot and probs by ``vs`` before the PV
+    dot (after ``l`` accumulates — the softmax denominator is unscaled),
+    and no dequantized [BS, Dh] block is ever built.
+
+    ``sub`` (static) selects the K/V/scale sub-block along the leading
+    block dim: the multi-page paged kernels fetch ``pages_per_block``
+    physical pages in ONE ``(ppb, 1, page, Dh)`` block and attend them
+    per-page (ops/paged_attention.py), so each call here stays the exact
+    per-page update — only the DMA granularity grows."""
+    q = q_ref[0, 0]                                # [rows, Dh]
+    k = k_ref[sub, 0]                              # [BS, Dh] (bf16 or int8)
+    v = v_ref[sub, 0].astype(jnp.float32)
+    if k.dtype == jnp.int8:
+        # int8-KV QK dot (the worst_kernel() pick on the int8 ladder —
+        # decode.d*.greedy sat at ~0.4 of the HBM roof): dequant is fused
+        # into the dot as a cast to q's NATIVE dtype. Every int8 value is
+        # exact in bf16 (8 mantissa bits ≥ the 7 magnitude bits of ±127),
+        # so scores are bit-identical to the old `.astype(float32)` pair —
+        # but the MXU now runs one native low-precision pass with fp32
+        # accumulation instead of the multi-pass fp32×fp32 matmul the
+        # explicit upcast forced.
+        k = k.astype(q.dtype)
+    else:
+        q = q.astype(jnp.float32)
+        k = k.astype(jnp.float32)
+    scores = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)        # [rows, BS]
+    scores *= q.shape[-1] ** -0.5
+    if ks_ref is not None:
+        scores = scores * ks_ref[sub, 0]
+    scores = mask(scores)
+
+    m_prev = m_ref[:, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    e = jnp.exp(scores - m_new)                    # [rows, BS]
+    l_ref[:, :1] = alpha * l_ref[:, :1] + jnp.sum(e, axis=1, keepdims=True)
+    p = e if vs_ref is None else e * vs_ref[sub, 0]
+    acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)        # [rows, Dh]
+    m_ref[:, :1] = m_new
+
+
+def unpack_kv_refs(refs):
+    """(k, ks, v, vs, o, m, l, acc) from a kernel's trailing refs. Without
+    int8-KV the scale refs are absent (arity 6) and come back None."""
+    if len(refs) == 8:
+        return refs
+    k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
+    return k_ref, None, v_ref, None, o_ref, m_ref, l_ref, acc_ref
 
 def _frozen_per_head_kernel(pt_ref, nvalid_ref, q_ref, kn_ref, vn_ref,
                             *refs, page, window=0, pages_per_block=1):

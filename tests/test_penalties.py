@@ -24,11 +24,10 @@ LOOPING_PROMPT = "aaa bbb aaa bbb"
 
 
 @pytest.fixture(scope="module")
-def engine(stop_engine):
-    cfg = LocalEngineConfig(kv_layout="contiguous",
-        preset="tiny-test", max_batch_size=1,
+def shared_engine(stop_engine):
+    cfg = LocalEngineConfig(preset="tiny-test", max_batch_size=1,
                             max_seq_len=128, prefill_chunk=32,
-                            dtype="float32")
+                            dtype="float32", kv_page_size=16)
     eng = InferenceEngine(cfg, devices=[jax.devices("cpu")[0]])
     yield eng
     stop_engine(eng)

@@ -136,8 +136,8 @@ def test_engine_e2e_with_quant(preset):
     tied-embedding int8 head copy)."""
     from llmapigateway_tpu.engine.engine import GenRequest, InferenceEngine
 
-    cfg = LocalEngineConfig(kv_layout="contiguous",
-        preset=preset, max_batch_size=2,
+    cfg = LocalEngineConfig(kv_page_size=16,
+                            preset=preset, max_batch_size=2,
                             max_seq_len=128, prefill_chunk=16,
                             decode_burst=4, quant="int8",
                             prewarm_sampler_variants=False,
@@ -183,8 +183,8 @@ def test_checkpoint_load_quantizes_on_host(tmp_path):
     transformers.LlamaForCausalLM(hf_cfg).save_pretrained(
         tmp_path, safe_serialization=True)
 
-    cfg = LocalEngineConfig(kv_layout="contiguous",
-        model_path=str(tmp_path), max_batch_size=1,
+    cfg = LocalEngineConfig(kv_page_size=16,
+                            model_path=str(tmp_path), max_batch_size=1,
                             max_seq_len=64, prefill_chunk=16, decode_burst=2,
                             quant="int8", prewarm_sampler_variants=False,
                             compilation_cache_dir="off")
@@ -242,8 +242,8 @@ def test_checkpoint_tied_head_quantizes_on_device(tmp_path):
     transformers.LlamaForCausalLM(hf_cfg).save_pretrained(
         tmp_path, safe_serialization=True)
 
-    cfg = LocalEngineConfig(kv_layout="contiguous",
-        model_path=str(tmp_path), max_batch_size=1,
+    cfg = LocalEngineConfig(kv_page_size=16,
+                            model_path=str(tmp_path), max_batch_size=1,
                             max_seq_len=64, prefill_chunk=16, decode_burst=2,
                             quant="int8", prewarm_sampler_variants=False,
                             compilation_cache_dir="off")
@@ -326,8 +326,8 @@ def test_moe_sharded_quant_forward_matches():
 def test_moe_engine_e2e_with_quant():
     from llmapigateway_tpu.engine.engine import GenRequest, InferenceEngine
 
-    cfg = LocalEngineConfig(kv_layout="contiguous",
-        preset="tiny-moe-test", quant="int8",
+    cfg = LocalEngineConfig(kv_page_size=16,
+                            preset="tiny-moe-test", quant="int8",
                             max_batch_size=2, max_seq_len=128,
                             prefill_chunk=16, decode_burst=4,
                             prewarm_sampler_variants=False,
@@ -352,8 +352,8 @@ def test_moe_engine_e2e_with_quant():
 def test_quant_rejects_unknown_mode():
     from llmapigateway_tpu.engine.engine import InferenceEngine
 
-    cfg = LocalEngineConfig(kv_layout="contiguous",
-        preset="tiny-test", quant="int2",
+    cfg = LocalEngineConfig(kv_page_size=16,
+                            preset="tiny-test", quant="int2",
                             max_batch_size=1, max_seq_len=64,
                             compilation_cache_dir="off")
     with pytest.raises(ValueError, match="quant"):
@@ -410,8 +410,8 @@ def test_engine_e2e_with_int4(preset):
     checks the tied-head copy stays int8)."""
     from llmapigateway_tpu.engine.engine import GenRequest, InferenceEngine
 
-    cfg = LocalEngineConfig(kv_layout="contiguous",
-        preset=preset, max_batch_size=2,
+    cfg = LocalEngineConfig(kv_page_size=16,
+                            preset=preset, max_batch_size=2,
                             max_seq_len=128, prefill_chunk=16,
                             decode_burst=4, quant="int4",
                             prewarm_sampler_variants=False,
@@ -475,11 +475,10 @@ def test_int4_checkpoint_load_quantizes_on_host(tmp_path):
         "num_key_value_heads": cfg.n_kv_heads,
         "intermediate_size": cfg.d_ff}))
 
-    eng = InferenceEngine(LocalEngineConfig(kv_layout="contiguous",
-        
+    eng = InferenceEngine(LocalEngineConfig(
         model_path=str(tmp_path), max_batch_size=1, max_seq_len=64,
-        prefill_chunk=16, quant="int4", prewarm_sampler_variants=False,
-        compilation_cache_dir="off"))
+        prefill_chunk=16, kv_page_size=16, quant="int4",
+        prewarm_sampler_variants=False, compilation_cache_dir="off"))
     assert eng.params["layers"]["wq"]["q"].dtype == jnp.int4
     assert eng.params["lm_head"]["q"].dtype == jnp.int8
 
@@ -489,8 +488,8 @@ def test_moe_engine_e2e_with_int4():
     int4 with per-(expert, out-channel) scales and still serve."""
     from llmapigateway_tpu.engine.engine import GenRequest, InferenceEngine
 
-    cfg = LocalEngineConfig(kv_layout="contiguous",
-        preset="tiny-moe-test", max_batch_size=2,
+    cfg = LocalEngineConfig(kv_page_size=16,
+                            preset="tiny-moe-test", max_batch_size=2,
                             max_seq_len=128, prefill_chunk=16,
                             decode_burst=4, quant="int4",
                             prewarm_sampler_variants=False,

@@ -271,8 +271,8 @@ def engine(stop_engine):
     import jax
     from llmapigateway_tpu.config.schemas import LocalEngineConfig
     from llmapigateway_tpu.engine.engine import InferenceEngine
-    cfg = LocalEngineConfig(kv_layout="contiguous",
-        preset="tiny-test", max_batch_size=2,
+    cfg = LocalEngineConfig(kv_page_size=16,
+                            preset="tiny-test", max_batch_size=2,
                             max_seq_len=64, prefill_chunk=16,
                             dtype="float32")
     eng = InferenceEngine(cfg, devices=[jax.devices("cpu")[0]])

@@ -12,6 +12,7 @@ import pytest
 from llmapigateway_tpu.config.schemas import LocalEngineConfig
 from llmapigateway_tpu.engine.engine import GenRequest, InferenceEngine
 from llmapigateway_tpu.engine.speculative import draft_from_history
+from tests.dense_reference import greedy_tokens
 from tests.mesh_parity import CYCLING, serve
 
 
@@ -24,8 +25,9 @@ def _engine(spec=0, **kw):
     # comparisons timing-flaky (1-core repro: two stable greedy
     # continuations of the same prompt).
     kw.setdefault("decode_burst_busy", 8)
-    kw.setdefault("kv_layout", "contiguous")
-    cfg = LocalEngineConfig(preset="tiny-test", max_batch_size=2,
+    kw.setdefault("kv_page_size", 16)
+    kw.setdefault("preset", "tiny-test")
+    cfg = LocalEngineConfig(max_batch_size=2,
                             max_seq_len=192, prefill_chunk=32,
                             dtype="float32", decode_burst=8,
                             spec_draft_len=spec, **kw)
@@ -52,26 +54,25 @@ def test_draft_from_history_finds_repeats():
 
 @pytest.mark.parametrize("spec", [1, 3])
 async def test_spec_greedy_parity(spec):
-    """Spec engine's tokens must be identical to the plain engine's, on a
-    repetitive prompt (high acceptance) AND a non-repetitive one (drafts
-    mostly rejected) — both correctness regimes."""
+    """Spec engine's tokens must be identical to plain greedy decoding's
+    (the dense forward with the same weights, tests/dense_reference.py),
+    on a repetitive prompt (high acceptance) AND a non-repetitive one
+    (drafts mostly rejected) — both correctness regimes. Verify writes
+    beyond a slot's page reservation land on the trash page; the page
+    tables thread into the spec program as a traced argument."""
     rng = np.random.default_rng(0)
     repetitive = list(np.tile(rng.integers(2, 500, 6), 8))      # 48 toks
     random_p = list(rng.integers(2, 500, 40))
-    for prompt in (repetitive, random_p):
-        ref_eng = _engine(spec=0)
-        try:
-            ref = await _gen(ref_eng, prompt, max_tokens=24)
-        finally:
-            await ref_eng.stop()
-        spec_eng = _engine(spec=spec)
-        try:
+    spec_eng = _engine(spec=spec)
+    try:
+        for prompt in (repetitive, random_p):
             got = await _gen(spec_eng, prompt, max_tokens=24)
-        finally:
-            await spec_eng.stop()
-        assert got.generated == ref.generated, (
-            spec, got.generated, ref.generated)
-        assert got.finish_reason == ref.finish_reason
+            assert got.generated == greedy_tokens(spec_eng, prompt, 24), (
+                spec, got.generated)
+            assert got.finish_reason == "length"
+        assert spec_eng.stats()["spec_tokens_per_step"] >= 1.0
+    finally:
+        await spec_eng.stop()
 
 
 def _markovify(eng):
@@ -394,29 +395,14 @@ async def test_spec_engine_recovers_from_injected_fault():
             deltas.append(d)
         assert any(d.error for d in deltas)
         eng.fault_plan = None
+        # The recovery is a supervised restart (ISSUE 14): a submit while
+        # it runs is refused, so wait for it as a router's breaker would.
+        for _ in range(1000):
+            if eng.supervisor.state == "serving":
+                break
+            await asyncio.sleep(0.01)
         ok = await _gen(eng, [3, 1, 4, 1, 5], max_tokens=6)
         assert ok.finish_reason is not None and len(ok.generated) >= 1
-    finally:
-        await eng.stop()
-
-
-async def test_spec_greedy_parity_paged():
-    """Speculation over the PAGED pool (verify writes beyond a slot's page
-    reservation land on the trash page; the page table threads into the
-    spec program as a traced arg) — tokens must match the plain paged
-    engine's."""
-    rng = np.random.default_rng(4)
-    prompt = list(np.tile(rng.integers(2, 500, 6), 8))
-    ref_eng = _engine(spec=0, kv_layout="paged")
-    try:
-        ref = await _gen(ref_eng, prompt, max_tokens=20)
-    finally:
-        await ref_eng.stop()
-    eng = _engine(spec=3, kv_layout="paged")
-    try:
-        got = await _gen(eng, prompt, max_tokens=20)
-        assert got.generated == ref.generated
-        assert eng.stats()["spec_tokens_per_step"] >= 1.0
     finally:
         await eng.stop()
 
@@ -487,56 +473,31 @@ async def test_spec_acceptance_telemetry_and_metrics_bridge():
 
 @pytest.mark.parametrize("ppb", [1, 2, 4])
 async def test_spec_int8_greedy_parity_paged(ppb):
-    """Speculation over the PAGED int8 pool — the headline config — must
-    produce EXACTLY the spec-off greedy sequence, across pages_per_block
-    1/2/4. The verify self-block is mixed-precision (models/llama.py):
-    off-diagonal drafted K/V go through the SAME quantize→dequantize the
-    insert path applies, so verification judges each draft against the
-    numbers plain int8 decode would actually read; the diagonal stays
-    full precision like the decode self-column. (This combination was a
-    build-time ValueError before the fix.)"""
+    """Speculation over the int8 pool — the headline config — must
+    produce EXACTLY the spec-off greedy sequence (the dense forward over
+    an int8 cache), across pages_per_block 1/2/4, on a repetitive prompt
+    (acceptance exercised) and a random one (drafts mostly rejected — the
+    rejection numerics matter too). The verify self-block is
+    mixed-precision (models/llama.py): off-diagonal drafted K/V go
+    through the SAME quantize→dequantize the insert path applies, so
+    verification judges each draft against the numbers plain int8 decode
+    would actually read; the diagonal stays full precision like the
+    decode self-column. (This combination was a build-time ValueError
+    before the fix.)"""
     rng = np.random.default_rng(5)
-    prompt = list(np.tile(rng.integers(2, 500, 6), 8))
-    kw = dict(kv_layout="paged", kv_quant="int8", kv_page_size=16,
-              kv_pages_per_block=ppb)
-    ref_eng = _engine(spec=0, **kw)
-    try:
-        ref = await _gen(ref_eng, prompt, max_tokens=20)
-    finally:
-        await ref_eng.stop()
-    eng = _engine(spec=3, **kw)
+    repetitive = list(np.tile(rng.integers(2, 500, 6), 8))
+    random_p = list(rng.integers(2, 500, 40))
+    eng = _engine(spec=3, kv_quant="int8", kv_pages_per_block=ppb)
     try:
         assert eng.kv_ppb == ppb
-        got = await _gen(eng, prompt, max_tokens=20)
-        assert got.generated == ref.generated, (
-            ppb, got.generated, ref.generated)
-        assert got.finish_reason == ref.finish_reason
+        for prompt in (repetitive, random_p):
+            got = await _gen(eng, prompt, max_tokens=20)
+            assert got.generated == greedy_tokens(eng, prompt, 20), (
+                ppb, got.generated)
+            assert got.finish_reason == "length"
         assert eng._spec_steps_done > 0
     finally:
         await eng.stop()
-
-
-async def test_spec_int8_greedy_parity_contiguous():
-    """Same exactness over the CONTIGUOUS int8 cache (dense verify path),
-    on a repetitive prompt (acceptance exercised) and a random one
-    (drafts mostly rejected — the rejection numerics matter too)."""
-    rng = np.random.default_rng(6)
-    repetitive = list(np.tile(rng.integers(2, 500, 6), 8))
-    random_p = list(rng.integers(2, 500, 40))
-    for prompt in (repetitive, random_p):
-        ref_eng = _engine(spec=0, kv_quant="int8")
-        try:
-            ref = await _gen(ref_eng, prompt, max_tokens=20)
-        finally:
-            await ref_eng.stop()
-        eng = _engine(spec=3, kv_quant="int8")
-        try:
-            got = await _gen(eng, prompt, max_tokens=20)
-            assert got.generated == ref.generated, (
-                got.generated, ref.generated)
-            assert got.finish_reason == ref.finish_reason
-        finally:
-            await eng.stop()
 
 
 # -- per-slot adaptive drafting (spec_acceptance_floor) -----------------------
@@ -685,7 +646,7 @@ async def test_spec_composes_with_prefix_cache_insert_on_release():
                             max_seq_len=192, prefill_chunk=16,
                             dtype="float32", decode_burst=8,
                             decode_burst_busy=8, spec_draft_len=3,
-                            kv_layout="paged", kv_page_size=16,
+                            kv_page_size=16,
                             spec_wall_gate=False,
                             spec_min_tokens_per_step=0.0)
     eng = InferenceEngine(cfg, devices=[jax.devices("cpu")[0]])
@@ -704,14 +665,16 @@ async def test_spec_composes_with_prefix_cache_insert_on_release():
         await eng.stop()
 
 
-async def test_cancel_during_inflight_spec_burst_no_leaks():
+@pytest.mark.parametrize("preset", ["tiny-test", "tiny-mistral-test"])
+async def test_cancel_during_inflight_spec_burst_no_leaks(preset):
     """Chaos: cancel a request while a speculative burst is in flight
     (lag-one). The flush's epoch guard masks the dead slot's rows, the
-    slot and all its pages come back, the flight lifecycle stays
+    slot and all its pages come back — a ring's too, whose margin a spec
+    burst widens to k + 1 tokens a step —, the flight lifecycle stays
     balanced (admits == finishes), and the engine keeps serving."""
     rng = np.random.default_rng(24)
     prompt = list(np.tile(rng.integers(2, 500, 4), 10))
-    eng = _engine(spec=3, kv_layout="paged", kv_page_size=16,
+    eng = _engine(spec=3, preset=preset, kv_page_size=8,
                   prefix_cache=False, spec_wall_gate=False,
                   spec_min_tokens_per_step=0.0)
     try:
@@ -746,34 +709,24 @@ async def test_cancel_during_inflight_spec_burst_no_leaks():
         fs = eng.flight.stats()
         assert fs["flight_admits"] == fs["flight_finishes"]
         # Still serviceable, still exact: a fresh greedy request matches
-        # a clean engine's output.
+        # plain greedy decoding.
         after = await _gen(eng, prompt, max_tokens=12)
-        clean = _engine(spec=3, kv_layout="paged", kv_page_size=16,
-                        prefix_cache=False, spec_wall_gate=False,
-                        spec_min_tokens_per_step=0.0)
-        try:
-            want = await _gen(clean, prompt, max_tokens=12)
-        finally:
-            await clean.stop()
-        assert after.generated == want.generated
+        assert after.generated == greedy_tokens(eng, prompt, 12)
         fs = eng.flight.stats()
         assert fs["flight_admits"] == fs["flight_finishes"]
     finally:
         await eng.stop()
 
 
-@pytest.mark.parametrize("mesh,layout", [({"model": 4}, "paged"),
-                                         ({"model": 2}, "contiguous")])
-async def test_spec_on_a_model_mesh_matches_one_device(mesh, layout):
-    """Speculation served tensor-parallel, from either cache layout: the
-    verify forward runs over sharded heads (paged: the deferred verify
-    with the pool whole on each of four chips; contiguous: heads split
-    over two), the history drafts on-device, and the output is the
+async def test_spec_on_a_model_mesh_matches_one_device():
+    """Speculation served tensor-parallel: the verify forward runs over
+    sharded heads (the deferred verify with the pool whole on each of
+    four chips), the history drafts on-device, and the output is the
     one-device engine's — with real acceptance."""
     kw = dict(spec_draft_len=3, spec_min_tokens_per_step=0.0,
-              max_tokens=24, kv_layout=layout, kv_page_size=16,
-              decode_burst=8, prompts=CYCLING)
+              max_tokens=24, kv_page_size=16, decode_burst=8,
+              prompts=CYCLING)
     ref, _ = await serve({}, **kw)
-    got, eng = await serve(mesh, **kw)
+    got, eng = await serve({"model": 4}, **kw)
     assert got == ref
     assert eng._spec_tokens_out > eng._spec_steps_done > 0

@@ -10,12 +10,13 @@ from llmapigateway_tpu.engine.engine import Delta, GenRequest, InferenceEngine
 from llmapigateway_tpu.engine.tokenizer import ByteTokenizer, IncrementalDetokenizer
 
 
-@pytest.fixture(scope="module")
-def engine(stop_engine):
-    cfg = LocalEngineConfig(kv_layout="contiguous",
-        preset="tiny-test", max_batch_size=2,
+@pytest.fixture(scope="module", params=("tiny-test", "tiny-mistral-test"))
+def shared_engine(request, stop_engine):
+    """Whole contexts, and the window's page ring (pages of 8: 9 a slot): a
+    stop or a cancel must give back a ring's pages too."""
+    cfg = LocalEngineConfig(preset=request.param, max_batch_size=2,
                             max_seq_len=128, prefill_chunk=32,
-                            dtype="float32", decode_burst=4)
+                            dtype="float32", decode_burst=4, kv_page_size=8)
     eng = InferenceEngine(cfg, devices=[jax.devices("cpu")[0]])
     yield eng
     stop_engine(eng)

@@ -6,23 +6,20 @@ be (8, 128)-divisible or equal the array dims) at LOWERING time — which
 (2026-07-31) found exactly such a bug: the int8-KV per-token scale
 tensors' ``(1, 1, block)`` BlockSpecs put a size-1 block on the KV dim,
 killing the 8B/kv-quant/int4/SWA rungs on hardware while 264 CPU tests
-stayed green (fixed by the rank-4 ``[B, KV, 1, S]`` scale layout,
-flash_attention.py). ``jax.jit(f).trace(...).lower(lowering_platforms=
-("tpu",))`` runs that validation on a CPU-only box, so this module keeps
-the whole dense/paged x decode/prefill x bf16/int8-KV x windowed matrix
-lowerable without ever touching a chip.
+stayed green (fixed by the rank-4 ``[.., KV, 1, page]`` scale layout).
+``jax.jit(f).trace(...).lower(lowering_platforms=("tpu",))`` runs that
+validation on a CPU-only box, so this module keeps the paged decode/prefill
+x bf16/int8-KV x windowed matrix lowerable without ever touching a chip.
 
 These tests do NOT execute anything — success is "Mosaic accepted the
 kernel"; numerics are covered by the interpret-mode parity suites
-(test_ops_attention / test_ops_paged / test_kv_quant).
+(test_ops_paged* / test_kv_quant).
 """
 import jax
 import jax.numpy as jnp
 import pytest
 
 from llmapigateway_tpu.ops import paged_attention as pa
-from llmapigateway_tpu.ops.flash_attention import (
-    flash_decode_attention, flash_prefill_attention)
 
 # The int8 pools the benchmark's two configurations serve (the file that
 # COMPILES the same kernels for the described chip states them).
@@ -31,17 +28,6 @@ from test_aot_tpu_compile import SERVED as STACKED
 B, KV, G, S, Dh, T = 2, 4, 2, 256, 128, 128
 H = KV * G
 P, PAGE, NP = 16, 128, 2
-
-
-def _dense_kv(quant):
-    key = jax.random.PRNGKey(0)
-    if quant:
-        mk = lambda: {"q": jax.random.randint(key, (B, KV, S, Dh),
-                                              -127, 127, jnp.int8),
-                      "s": jnp.ones((B, KV, 1, S), jnp.float32)}
-    else:
-        mk = lambda: jax.random.normal(key, (B, KV, S, Dh), jnp.bfloat16)
-    return mk(), mk()
 
 
 def _paged_kv(quant):
@@ -57,30 +43,6 @@ def _paged_kv(quant):
 
 def _lower(fn, *args):
     jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
-
-
-@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8kv"])
-@pytest.mark.parametrize("window", [0, 96], ids=["full", "windowed"])
-def test_dense_decode_lowers_for_tpu(quant, window):
-    key = jax.random.PRNGKey(0)
-    q = jax.random.normal(key, (B, H, Dh), jnp.bfloat16)
-    kn = jax.random.normal(key, (B, KV, Dh), jnp.bfloat16)
-    vn = jax.random.normal(key, (B, KV, Dh), jnp.bfloat16)
-    lk, lv = _dense_kv(quant)
-    ns = jnp.array([100, 0], jnp.int32)
-    _lower(lambda *a: flash_decode_attention(
-        *a, window=window, interpret=False), q, kn, vn, lk, lv, ns)
-
-
-@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8kv"])
-@pytest.mark.parametrize("window", [0, 96], ids=["full", "windowed"])
-def test_dense_prefill_lowers_for_tpu(quant, window):
-    key = jax.random.PRNGKey(0)
-    qp = jax.random.normal(key, (B, T, H, Dh), jnp.bfloat16)
-    lk, lv = _dense_kv(quant)
-    st = jnp.array([0, 64], jnp.int32)
-    _lower(lambda *a: flash_prefill_attention(
-        *a, window=window, interpret=False), qp, lk, lv, st)
 
 
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8kv"])
@@ -157,39 +119,6 @@ def test_stacked_pool_kernels_lower_at_served_geometry(geometry, kernel):
                   jnp.bfloat16)
         _lower(lambda *a: pa.paged_insert_in_place(*a, interpret=False),
                side, side, new, new, table, ints, sds((slots,), jnp.bool_))
-
-
-@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8kv"])
-@pytest.mark.parametrize("window", [0, 96], ids=["full", "windowed"])
-def test_tp_sharded_decode_wrapper_lowers_for_tpu(quant, window):
-    """The shard_map'd flash decode wrapper (what a TP-sharded engine
-    actually runs) must lower for TPU too — shard_map + Mosaic compose
-    at lowering time, so this works on the CPU-device mesh. Windowed
-    variants cover the sharded-SWA configs (commit 20722ad)."""
-    from jax.sharding import Mesh
-
-    from llmapigateway_tpu.ops.flash_attention import (
-        make_sharded_cache_attention_fn)
-
-    mesh = Mesh(jax.devices("cpu")[:4], ("model",))
-    # Guard against the wrapper's silent unsharded fallback: KV and H
-    # must divide the model axis, or the test lowers the WRONG path.
-    assert KV % 4 == 0 and H % 4 == 0
-    key = jax.random.PRNGKey(0)
-    q = jax.random.normal(key, (B, 1, H, Dh), jnp.bfloat16)
-    kn = jax.random.normal(key, (B, 1, KV, Dh), jnp.bfloat16)
-    vn = jax.random.normal(key, (B, 1, KV, Dh), jnp.bfloat16)
-    lk, lv = _dense_kv(quant)
-    ns = jnp.array([100, 0], jnp.int32)
-    fn = make_sharded_cache_attention_fn(mesh, interpret=False,
-                                         window=window)
-    lowered = jax.jit(lambda *a: fn.decode(*a)).trace(
-        q, kn, vn, lk, lv, ns).lower(lowering_platforms=("tpu",))
-    # The shard_map path really ran: a Mosaic kernel is in the module
-    # (the unsharded fallback would also contain one, but the fallback
-    # is excluded by the divisibility assert above — this check instead
-    # pins that lowering went all the way to a TPU custom call).
-    assert "tpu_custom_call" in lowered.as_text()
 
 
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8kv"])
