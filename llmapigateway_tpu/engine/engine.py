@@ -453,6 +453,11 @@ class InferenceEngine:
         # the slot's whole context and itself, a windowed group's what of
         # that lies inside the window. Monotone; worker thread.
         self._attn_decode_keys = {"global": 0, "window": 0}
+        # An indexer's decode steps (ISSUE 51), ONE layer's keys, kept the
+        # same way: the index keys a step scored (each active slot's
+        # context and itself) and the K/V rows it then read (at most
+        # ``idx_topk`` a slot). Monotone; worker thread.
+        self._dsa_decode_keys = {"scored": 0, "selected": 0}
         # Device observability plane (ISSUE 8): per-kernel cost registry
         # (worker thread records, lock-guarded internally), the HBM
         # memory ledger, and the process-wide XLA compile monitor. The
@@ -526,6 +531,24 @@ class InferenceEngine:
                       "wired",
             "page_lanes": "a latent page lies token-minor, and the chip's "
                           "kernels move whole tiles of 128 lanes"},
+        # An INDEX-KEY side beside K and V, and attention over the keys an
+        # indexer selected (ops/sparse_attention.py).
+        "sparse": {
+            "kv_quant": "a gathered int8 row needs its scale plane gathered "
+                        "with it, which the selected-rows read does not do; "
+                        "set kv_quant ''",
+            "prefix_cache": "the radix cache shares K/V pages, and has no "
+                            "rule yet for sharing their index-key side; set "
+                            "prefix_cache false",
+            "spec": "the verify path has no selection: it would attend "
+                    "every cached key where decode attends the selected",
+            "mesh": "the index-key side has one head, which no axis "
+                    "divides, and the held experts have no sharding rule "
+                    "yet",
+            "disagg": "a handoff moves K/V pages between pools, not their "
+                      "index-key side",
+            "page_lanes": "an index-key page lies token-minor, and the "
+                          "chip's kernels move whole tiles of 128 lanes"},
     }
 
     def _refuse_unsupported(self) -> None:
@@ -535,7 +558,7 @@ class InferenceEngine:
         cfg, c = self.cfg, self.model_cfg
         kinds = [kind for kind, has in (
             ("state", c.n_lin_layers), ("groups", len(c.cache_groups) > 1),
-            ("latent", c.is_mla)) if has]
+            ("latent", c.is_mla), ("sparse", c.is_sparse)) if has]
         if not kinds:
             return
         kinds.append("any")
@@ -808,6 +831,7 @@ class InferenceEngine:
             k_sh = v_sh = (side,) * len(self.kv_groups)
             if c.is_mla:        # ONE latent pool, no V side
                 k_sh, v_sh = (rep_sh,), ()
+            index_sh = (rep_sh,) * len(self.kv_groups) if c.is_sparse else ()
             self.cache = jax.jit(
                 partial(HybridCache.create, c,
                         tuple(g.allocator.num_pages
@@ -815,7 +839,7 @@ class InferenceEngine:
                         page, self.B, self.dtype,
                         kv_quant=self.kv_quant),
                 out_shardings=HybridCache(
-                    k=k_sh, v=v_sh, counters=rep_sh,
+                    k=k_sh, v=v_sh, counters=rep_sh, index=index_sh,
                     state=(rep_sh,) * n_lin, conv=(rep_sh,) * n_lin))()
         else:
             self.cache = jax.jit(
@@ -1014,6 +1038,7 @@ class InferenceEngine:
         c = self.model_cfg
         family_forward = forward_fn(c)
         from ..ops.latent_attention import LatentAttention
+        from ..ops.sparse_attention import SparseAttention
         from ..ops.paged_attention import (PagedKVCache,
                                            make_paged_attention_fn,
                                            pool_in_place)
@@ -1044,6 +1069,9 @@ class InferenceEngine:
             # skips the per-layer pool scatters either way.
             if c.is_mla:
                 attn = (LatentAttention(tables[0], S, impl),)
+            elif c.is_sparse:
+                attn = tuple(SparseAttention(table, S, c.idx_topk, impl)
+                             for table in tables)
             else:
                 attn = tuple(make_paged_attention_fn(
                     table, max_seq=S, impl=impl, mesh=mesh, window=window,
@@ -3051,7 +3079,11 @@ class InferenceEngine:
             n_steps * len(live) * self.model_cfg.n_lin_layers)
         seen = live[:, None] + np.arange(1, n_steps + 1)    # [slots, steps]
         for g in self.kv_groups:
-            if g.kind == "latent":
+            if self.model_cfg.is_sparse:
+                self._dsa_decode_keys["scored"] += int(seen.sum())
+                self._dsa_decode_keys["selected"] += int(np.minimum(
+                    seen, self.model_cfg.idx_topk).sum())
+            elif g.kind == "latent":
                 self._mla_decode_keys += int(seen.sum())
             elif g.window:
                 self._attn_decode_keys["window"] += int(
@@ -3248,13 +3280,14 @@ class InferenceEngine:
     def _kv_token_bytes(self) -> int:
         """Bytes a token keeps in ONE layer of a cache group's pool: K and
         V of every KV head (int8: with their float32 scales), or a latent
-        layer's one row."""
+        layer's one row; with an indexer, the token's index key too."""
         c = self.model_cfg
         itemsize = int(np.dtype(self.dtype).itemsize)
         if c.is_mla:
             return c.latent_width * itemsize
         elem, scale = (1, 4) if self.kv_quant else (itemsize, 0)
-        return 2 * c.n_kv_heads * (c.head_dim * elem + scale)
+        return (2 * c.n_kv_heads * (c.head_dim * elem + scale)
+                + c.idx_head_dim * itemsize * c.is_sparse)
 
     def _kv_bytes_per_step(self) -> int:
         """HBM bytes one decode step reads from the KV cache: the live
@@ -3264,6 +3297,12 @@ class InferenceEngine:
         roofline model — achieved GB/s = (weights + this) / step time."""
         c = self.model_cfg
         live = self.lengths[self.active].astype(np.int64)
+        if c.is_sparse:
+            # Every live token's index key, the selected tokens' K and V.
+            index = c.idx_head_dim * int(np.dtype(self.dtype).itemsize)
+            return c.n_kv_layers * int(
+                (live * index + np.minimum(live, c.idx_topk)
+                 * (self._kv_token_bytes() - index)).sum())
         # Token reads summed over the layers of every cache group, each
         # clamped to the group's window.
         periods = c.n_kv_layers // len(c.softmax_positions)
@@ -3444,6 +3483,11 @@ class InferenceEngine:
             # burst's fetch.
             out["moe_tiles_run_total"] = self._moe_totals[3]
             out["moe_tile_rows_total"] = self._moe_totals[4]
+        if self.model_cfg.is_sparse:
+            out["dsa_decode_keys_scored_total"] = \
+                self._dsa_decode_keys["scored"]
+            out["dsa_decode_keys_selected_total"] = \
+                self._dsa_decode_keys["selected"]
         if self.model_cfg.is_mla:
             out["mla_decode_keys_total"] = self._mla_decode_keys
             out["mla_prefill_keys_total"] = self._mla_prefill_keys

@@ -335,7 +335,9 @@ class CacheGroup:
     0: the whole context's), their allocator with its host page table,
     and whether that table changed since its last upload (``dirty``).
     ``kind`` is the pool's shape — "kv": a K and a V pool ``[layers,
-    pages, KV, page, Dh]`` (ops/paged_attention.py); "latent": ONE pool
+    pages, KV, page, Dh]`` (ops/paged_attention.py), and under a learned
+    indexer a third side on the same table, the index keys ``[layers,
+    pages, W, page]`` (ops/sparse_attention.py); "latent": ONE pool
     ``[layers, pages, latent_width, page]`` (ops/latent_attention.py) —
     and ``token_bytes`` what a token keeps in one of its layers. Pages,
     tables and admission are the same for both."""

@@ -66,12 +66,14 @@ class RopeScaling:
 
 @dataclass(frozen=True)
 class ModelConfig:
-    # "llama" | "qwen2" | "gemma" | "mixtral", and the five PERIOD families
+    # "llama" | "qwen2" | "gemma" | "mixtral", and the six PERIOD families
     # (``layer_period`` > 0, models/hybrid.py): "hybrid" (Solar-Open2),
     # "smallthinker", "mistral4", "cohere2_moe" (Command A+, the parallel
     # block) and "gigachat3_5" (a latent layer then three gated-delta-net
     # layers a period behind ``leading_dense`` layers of a linear mixer and
-    # a dense MLP, every sub-block normed before and after). A period
+    # a dense MLP, every sub-block normed before and after) and "keye_vl2"
+    # (softmax layers whose learned indexer selects ``idx_topk`` keys a
+    # query: an index-key side beside the K/V pages). A period
     # family is served from page pools on one device and refuses, at engine
     # build and with the reason, any mesh axis, the prefix cache,
     # speculation, disaggregation and ``model_path``.
@@ -187,6 +189,21 @@ class ModelConfig:
     router_bias: bool = False
     # > 0: every gated MLP is (act(min(g, L)) * clip(u, -L, L)) W_d.
     swiglu_limit: float = 0.0
+    # A learned indexer on the softmax layers (ops/sparse_attention.py; 0:
+    # none): ``idx_heads`` index queries of ``idx_head_dim`` and ONE index
+    # key a token score every cached position, and a query attends the
+    # ``idx_topk`` positions of largest score only (all of them while there
+    # are fewer). The index key is a third side of the group's page pool.
+    idx_heads: int = 0
+    idx_head_dim: int = 0
+    idx_topk: int = 0
+    # A per-head RMS norm on q and k before the rotary (one weight each).
+    # ``qk_norm_draw``: what a RANDOM draw gives both weights (a checkpoint
+    # brings its own): attention logits then have standard deviation
+    # ``qk_norm_draw ** 2`` — at 1 a softmax over thousands of keys is all
+    # but even, and its output says nothing of WHICH keys it saw.
+    qk_norm: bool = False
+    qk_norm_draw: float = 1.0
 
     def __post_init__(self):
         for name in ("window_layout", "rope_layout"):
@@ -203,6 +220,8 @@ class ModelConfig:
             raise ValueError(f"unknown norm_kind {self.norm_kind!r}")
         if self.lin_kind not in ("kda", "gated_delta"):
             raise ValueError(f"unknown lin_kind {self.lin_kind!r}")
+        if self.idx_topk and not (self.idx_heads and self.idx_head_dim):
+            raise ValueError("idx_topk needs idx_heads and idx_head_dim")
         if self.leading_dense and not self.lin_heads:
             raise ValueError("leading_dense layers carry a linear mixer: "
                              "they need lin_heads")
@@ -218,6 +237,10 @@ class ModelConfig:
     @property
     def is_mla(self) -> bool:
         return self.kv_lora_rank > 0
+
+    @property
+    def is_sparse(self) -> bool:
+        return self.idx_topk > 0
 
     @property
     def latent_width(self) -> int:
@@ -553,6 +576,39 @@ PRESETS["tiny-gigachat35-test"] = replace(
 # its 8 experts per token stay.
 PRESETS["gigachat35-432b-ep8"] = replace(
     PRESETS["gigachat35-432b"], n_layers=7, vocab_size=16032,
+    n_experts_held=32)
+
+# Keye-VL-2.0-30B-A3B (HF: Kwai-Keye/Keye-VL-2.0-30B-A3B, ``KeyeVL2``), the
+# LANGUAGE model at its PUBLISHED sizes: 48 pre-norm layers of grouped-query
+# attention (32 query / 4 KV heads of 128, a per-head RMS norm on q and k,
+# half-split rotary at theta 1e7) under a learned indexer — 16 index heads of
+# 64 and one index key a token, the 2,048 best-scoring cached positions a
+# query are all it attends — each followed by 128 softmax-routed SwiGLU
+# experts of width 768, top-8, no shared expert. One global cache group whose
+# pool has a third side, the index key. The vision tower is not served.
+PRESETS["keye-vl2-30b-a3b"] = ModelConfig(
+    family="keye_vl2", vocab_size=151936, d_model=2048, n_layers=48,
+    n_heads=32, n_kv_heads=4, head_dim_override=128, d_ff=6144,
+    rope_theta=10000000.0, rms_eps=1e-6, max_seq_len=262144, n_experts=128,
+    experts_per_token=8, d_ff_expert=768, layer_period=1,
+    moe_router="softmax", idx_heads=16, idx_head_dim=64, idx_topk=2048,
+    qk_norm=True, qk_norm_draw=1.73)
+# The same block at CPU-test size: 16 experts top-4, 4 index heads of 8 that
+# keep 24 keys a query, so a context of a few pages is past the selection
+# (and a mean over 24 keys is uneven enough at a draw of 1, which W8A8 at a
+# width of 64 needs: peaked attention there reads 0.3-0.9 off by rounding).
+PRESETS["tiny-keye-vl2-test"] = replace(
+    PRESETS["keye-vl2-30b-a3b"], vocab_size=512, d_model=64, n_layers=4,
+    n_heads=4, n_kv_heads=2, head_dim_override=16, d_ff=128,
+    rope_theta=10000.0, max_seq_len=256, n_experts=16, experts_per_token=4,
+    d_ff_expert=32, idx_heads=4, idx_head_dim=8, idx_topk=24,
+    qk_norm_draw=1.0)
+# What ONE v5e chip holds of it as one of 4 that share each layer of a
+# 4-stage pipeline (benchmark/configs/keye-vl2-30b-ep4.json): 12 layers, 32
+# of the 128 experts, a quarter of the vocabulary rows. Every width, the
+# router's 128 outputs, its 8 experts per token and the indexer whole stay.
+PRESETS["keye-vl2-30b-ep4"] = replace(
+    PRESETS["keye-vl2-30b-a3b"], n_layers=12, vocab_size=37984,
     n_experts_held=32)
 
 

@@ -161,12 +161,18 @@ class HybridCache(NamedTuple):
     so far, the routed assignments (all; landing on a held expert) and,
     summed over layers, the held experts that at least one active row was
     assigned to; then, of every call that took the grouped expert product
-    (a prefill chunk), the tiles it ran and the rows they held."""
+    (a prefill chunk), the tiles it ran and the rows they held.
+    ``index``: where the softmax layers have an indexer
+    (``ModelConfig.is_sparse``), the THIRD side of each group's pool — one
+    index key a token, [layers of the group, pages of the group,
+    ``idx_head_dim``, page] (ops/sparse_attention.py) on the group's own
+    page table — else ``()``."""
     k: Any
     v: Any
     state: tuple[jax.Array, ...]
     conv: tuple[jax.Array, ...]
     counters: jax.Array
+    index: tuple[jax.Array, ...] = ()
 
     @classmethod
     def create(cls, config: ModelConfig, num_pages: int | tuple[int, ...],
@@ -190,6 +196,13 @@ class HybridCache(NamedTuple):
                 page_size, dtype, kv_quant)
                 for (_, positions), pages in zip(c.cache_groups, num_pages)]
             k, v = tuple(p.k for p in pools), tuple(p.v for p in pools)
+        index = ()
+        if c.is_sparse:
+            from ..ops.sparse_attention import create_index_pool
+            index = tuple(create_index_pool(
+                periods * len(positions), pages, page_size, c.idx_head_dim,
+                dtype) for (_, positions), pages in zip(c.cache_groups,
+                                                        num_pages))
         # A stack of blocks a linear position of a period, then the
         # leading layers' stack.
         stacks = [periods] * (c.layer_period - len(c.softmax_positions))
@@ -201,7 +214,7 @@ class HybridCache(NamedTuple):
                         for n in stacks),
             conv=tuple(jnp.zeros((n, batch, c.lin_conv_taps - 1,
                                   c.lin_conv_width), dtype) for n in stacks),
-            counters=jnp.zeros((N_COUNTERS,), jnp.int32))
+            counters=jnp.zeros((N_COUNTERS,), jnp.int32), index=index)
 
 
 # ---------------------------------------------------------------------------
@@ -230,6 +243,9 @@ def init_params(config: ModelConfig, key: jax.Array,
                   wf_up [P,r,Hl*dk], f_bias [P,Hl*dk], a_log [P,Hl],
                   wbeta [P,D,Hl], wg_down [P,D,r], wg_up [P,r,Hl*dk],
                   out_norm [P,dk], wo [P,Hl*dk,D], mlp/...}
+      layers/attn with ``qk_norm``: + q_norm, k_norm [P,Dh]; with an
+                   indexer (J index heads of W): + wqi [P,D,J*W],
+                   wki [P,D,W], wwi [P,D,J], ki_norm, ki_bias [P,W]
       layers/attn of a latent layer: models/mla.py ``init_layer``'s tree,
                    stacked [P, ...], with its mlp/...
       layers/lin of ``lin_kind`` "gated_delta" (Hk key heads, Hv value
@@ -354,6 +370,21 @@ def init_params(config: ModelConfig, key: jax.Array,
                     "mlp": mlp(ks[5])}
         gate = {"wgate": dense(ks[3], D, c.n_heads * dh, name="wgate")
                 } if c.attn_gate else {}
+        if c.qk_norm:
+            # Not ones: ``qk_norm_draw`` (models/config.py says why).
+            gate.update(q_norm=jnp.full((dh,), c.qk_norm_draw, dtype),
+                        k_norm=jnp.full((dh,), c.qk_norm_draw, dtype))
+        if c.is_sparse:
+            # The indexer, un-quantised (it decides the selection, as the
+            # router decides the routing); the index key's LayerNorm has a
+            # bias, drawn N(0, 0.1^2) so that a comparison can see it.
+            ki = jax.random.split(jax.random.fold_in(k, 1), 4)
+            J, W = c.idx_heads, c.idx_head_dim
+            gate.update(wqi=dense(ki[0], D, J * W), wki=dense(ki[1], D, W),
+                        wwi=dense(ki[2], D, J),
+                        ki_norm=jnp.ones((W,), dtype),
+                        ki_bias=(0.1 * jax.random.normal(
+                            ki[3], (W,), jnp.float32)).astype(dtype))
         return {**norms(), **gate,
                 "wq": dense(ks[0], D, c.n_heads * dh, name="wq"),
                 "wk": dense(ks[1], D, c.n_kv_heads * dh, name="wk"),
@@ -917,6 +948,64 @@ def moe_block(x: jax.Array, lp: Params, c: ModelConfig,
 
 
 # ---------------------------------------------------------------------------
+# The softmax layer with an indexer
+# ---------------------------------------------------------------------------
+
+def indexer(h: jax.Array, lp: Params, c: ModelConfig, positions: jax.Array
+            ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """h [B, T, D] (the NORMED block input) at ``positions`` [B, T] ->
+    (index queries [B, T, J, W], the token's index key [B, T, W], both
+    rotated over their whole width and in h's dtype; the heads' weights
+    [B, T, J] float32 with both scale factors multiplied in). The key goes
+    through a LayerNorm with weight and bias before its rotary."""
+    B, T, _ = h.shape
+    J, W = c.idx_heads, c.idx_head_dim
+    cos, sin = rope_tables(positions, W, c.rope_theta, c.rope_scaling)
+    qi = apply_rope(mm(h, lp["wqi"]).reshape(B, T, J, W), cos, sin)
+    ki = layer_norm(mm(h, lp["wki"]), lp["ki_norm"], c.rms_eps) \
+        + lp["ki_bias"].astype(h.dtype)
+    ki = apply_rope(ki[:, :, None, :], cos, sin)[:, :, 0]
+    w = jnp.einsum("btd,dj->btj", h, lp["wwi"],
+                   preferred_element_type=jnp.float32) * (J * W) ** -0.5
+    return qi, ki, w
+
+
+def sparse_block(x: jax.Array, lp: Params, c: ModelConfig, pool: tuple,
+                 layer: jax.Array, fn: Any, lengths: jax.Array,
+                 active: jax.Array | None) -> tuple[jax.Array, tuple]:
+    """x [B, T, D] -> (the attention branch of ``norm(x)``, which the
+    caller adds to the stream, the group's pool ``(K, V, index keys)`` with
+    the call's rows written into layer ``layer``). ``fn``: the group's
+    ``ops.sparse_attention.SparseAttention``. Insert, then select, then
+    attend the selected keys only: the same three steps in both step
+    programs (a decode step gathers its selected rows, a chunk masks the
+    page walk). A row that is not ``active`` writes to the trash page and
+    attends from position 0; what it returns is not looked at."""
+    B, T, _ = x.shape
+    dh = c.head_dim
+    start = lengths if active is None else jnp.where(active, lengths, 0)
+    positions = start[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
+    h = block_norm(x, lp["norm"], c)
+    with jax.named_scope("attn.index"):
+        qi, ki, w = indexer(h, lp, c, positions)
+    with jax.named_scope("attn.sparse"):
+        cos, sin = rope_tables(positions, dh, c.rope_theta, c.rope_scaling)
+        q = mm(h, lp["wq"]).reshape(B, T, c.n_heads, dh)
+        k = mm(h, lp["wk"]).reshape(B, T, c.n_kv_heads, dh)
+        v = mm(h, lp["wv"]).reshape(B, T, c.n_kv_heads, dh)
+        if c.qk_norm:
+            q = rms_norm(q, lp["q_norm"], c.rms_eps)
+            k = rms_norm(k, lp["k_norm"], c.rms_eps)
+        q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+        pool = fn.write(pool, k, v, ki, layer, lengths, active)
+    with jax.named_scope("attn.index"):
+        selected = fn.select(qi, w, pool[2], layer, start)
+    with jax.named_scope("attn.sparse"):
+        attn = fn.attend(q, pool, layer, start, selected)
+        return mm(attn, lp["wo"]), pool
+
+
+# ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
 
@@ -962,15 +1051,18 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
     place = {p: (g, j, len(ps)) for g, (_, ps) in enumerate(groups)
              for j, p in enumerate(ps)}
     n_soft = len(c.softmax_positions)
-    decoding = T == 1 and (c.is_mla or getattr(fns[0], "decode", None)
+    # A latent pool and a pool with an index side ride BOTH programs'
+    # scans and are written in place, layer by layer.
+    rides = c.is_mla or c.is_sparse
+    decoding = T == 1 and (rides or getattr(fns[0], "decode", None)
                            is not None)
     # ``.decode_at`` / ``.prefill_at``: the provider reads the stacked pool
     # at a layer's index, and the pool stays out of the scanned inputs —
     # in prefill it is the scan's carry, written in place (llama.forward).
     # A latent layer's pool is the carry of BOTH programs' scans.
     by_decode_at = decoding and hasattr(fns[0], "decode_at")
-    by_prefill_at = c.is_mla or (not decoding and T > 1
-                                 and hasattr(fns[0], "prefill_at"))
+    by_prefill_at = rides or (not decoding and T > 1
+                              and hasattr(fns[0], "prefill_at"))
     by_index = by_decode_at or by_prefill_at
     scope = "decode" if decoding else "prefill"
 
@@ -1059,6 +1151,10 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
                     jax.named_scope("attn.mla"):
                 return (*mla.mla_block(x, lp, c, pool, at, fn, lengths,
                                        active), None)
+        if c.is_sparse:
+            with jax.named_scope(f"{scope}.attention"):
+                return (*sparse_block(x, lp, c, pool, at, fn, lengths,
+                                      active), None)
         kind = "attn.window" if c.window_at(position) else "attn.global"
         with jax.named_scope(f"{scope}.attention"), jax.named_scope(kind):
             h = block_norm(x, lp["norm"], c) if normed is None else normed
@@ -1100,8 +1196,10 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
                              x_in if c.router_reads_block_input else None,
                              normed)
 
-    # A (K, V) pair a group; a latent group's ONE pool.
-    pools = tuple(cache.k) if c.is_mla else tuple(zip(cache.k, cache.v))
+    # A (K, V) pair a group — with an indexer (K, V, index keys); a
+    # latent group's ONE pool.
+    pools = tuple(cache.k) if c.is_mla else tuple(
+        zip(cache.k, cache.v, *([cache.index] if c.is_sparse else [])))
 
     def by_period(pool, n):
         """A group's pool [P*n, ...] as the scan slices it: [P, n, ...]."""
@@ -1198,4 +1296,6 @@ def forward(params: Params, config: ModelConfig, tokens: jax.Array,
     k, v = (tuple(new_pools), ()) if c.is_mla else (
         tuple(p[0] for p in new_pools), tuple(p[1] for p in new_pools))
     return logits, HybridCache(k=k, v=v, state=state, conv=conv,
-                               counters=counters)
+                               counters=counters,
+                               index=tuple(p[2] for p in new_pools)
+                               if c.is_sparse else ())

@@ -1063,7 +1063,8 @@ def prefill_pages_walked(starts, T: int, bt: int, page: int, window: int,
 
 def _paged_prefill_kernel(pt_ref, start_ref, layer_ref, q_ref, *refs,
                           block_t: int, page: int, window: int,
-                          pages_per_block: int, n_table_blocks: int):
+                          pages_per_block: int, n_table_blocks: int,
+                          has_keep: bool = False):
     """Program ``(row b, group of folded KV heads, row-block t)``: walk the
     blocks of pages the row-block's ``block_t`` queries can see
     (:func:`_prefill_live_blocks`) and only those. The pools stay in HBM;
@@ -1081,7 +1082,13 @@ def _paged_prefill_kernel(pt_ref, start_ref, layer_ref, q_ref, *refs,
     int8: K, its scale plane, V, its scale plane), of which only layer
     ``layer_ref[0]`` is read, the output block, a VMEM buffer pair per
     pool side in the same order, the online-softmax state (m, l, acc),
-    the walk's count and the DMA semaphores ``[buffer, side]``."""
+    the walk's count and the DMA semaphores ``[buffer, side]``. Under
+    ``has_keep`` the first of ``refs`` is the row-block's SELECTION, int8
+    ``[1, 1, block_t, table positions]``: a score stays where it is not 0
+    and nowhere else (ops/sparse_attention.py: it holds the causal bound),
+    so every visible page is masked by it."""
+    if has_keep:
+        keep_ref, refs = refs[0], refs[1:]
     n_sides = (len(refs) - 6) // 2       # K, V (int8: + their scale planes)
     pools, o_ref = refs[:n_sides], refs[n_sides]
     bufs = refs[n_sides + 1:2 * n_sides + 1]
@@ -1177,6 +1184,19 @@ def _paged_prefill_kernel(pt_ref, start_ref, layer_ref, q_ref, *refs,
                     ok = ok & (s_pos > q_pos - window)
                 return jnp.where(ok, scores, NEG_INF)
 
+            if has_keep:
+                def selected(scores, lo=lo):
+                    kept = keep_ref[0, 0, :, pl.ds(pl.multiple_of(lo, page),
+                                                   page)].astype(jnp.int32)
+                    kept = jnp.concatenate(
+                        [kept] * (scores.shape[1] // bt), axis=0)
+                    return jnp.where((kept != 0)[None], scores, NEG_INF)
+
+                @pl.when(visible)
+                def _kept(sub=sub, selected=selected):
+                    attend(buf, sub, selected)
+                continue
+
             @pl.when(visible & whole)
             def _whole(sub=sub):
                 attend(buf, sub, None)
@@ -1200,6 +1220,7 @@ def paged_prefill_attention(q: jax.Array, k_pages, v_pages,
                             block_t: int | None = None,
                             window: int = 0,
                             pages_per_block: int = 1,
+                            keep: jax.Array | None = None,
                             interpret: bool | None = None) -> jax.Array:
     """Causal chunk attention over the page pool (keys already inserted).
 
@@ -1236,6 +1257,13 @@ def paged_prefill_attention(q: jax.Array, k_pages, v_pages,
     :func:`_check_pages_per_block`). A row's result does not depend on
     ``bt``, ``heads`` or ``pages_per_block`` (per-page updates in page
     order; a page a row sees nothing of leaves its state as it was).
+
+    ``keep`` (bool [B, T, NP * page]; None: every visible key): the keys
+    each query attends, a selection that holds the causal bound and at
+    least one key a query (ops/sparse_attention.py). An OPTIONAL operand:
+    a call without it lowers to the text it lowered to before there was
+    one. With it the walk is the same and every visible page is masked by
+    the selection's int8 block ``[bt, NP * page]`` of the row-block.
     """
     B, T, H, Dh = q.shape
     quant = isinstance(k_pages, dict)
@@ -1263,15 +1291,25 @@ def paged_prefill_attention(q: jax.Array, k_pages, v_pages,
     q_spec = pl.BlockSpec((1, 1, heads, rows, Dh),
                           lambda b, hb, t, pt, st, layer: (b, t, hb, 0, 0))
     kv_operands, buffers = _walk_buffers(k_pages, v_pages, ppb, heads)
+    kept, kept_specs, kept_arg = (), [], {}
+    if keep is not None:
+        if window or ppb != 1:
+            raise ValueError("a selection masks a whole-context walk of "
+                             "single pages")
+        kept = (keep.astype(jnp.int8).reshape(B, nT, bt, NP * page),)
+        kept_specs = [pl.BlockSpec(
+            (1, 1, bt, NP * page),
+            lambda b, hb, t, pt, st, layer: (b, t, 0, 0))]
+        kept_arg = {"has_keep": True}
 
     out = pl.pallas_call(
         functools.partial(_paged_prefill_kernel, block_t=bt, page=page,
                           window=window, pages_per_block=ppb,
-                          n_table_blocks=NP // ppb),
+                          n_table_blocks=NP // ppb, **kept_arg),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(B, KV // heads, nT),
-            in_specs=[q_spec,
+            in_specs=[q_spec, *kept_specs,
                       *[pl.BlockSpec(memory_space=pl.ANY)] * len(kv_operands)],
             out_specs=q_spec,
             scratch_shapes=[*buffers,
@@ -1286,7 +1324,7 @@ def paged_prefill_attention(q: jax.Array, k_pages, v_pages,
             vmem_limit_bytes=_PREFILL_VMEM_LIMIT_BYTES),
         interpret=_interpret_default() if interpret is None else interpret,
     )(page_table.astype(jnp.int32), start.astype(jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1), qb, *kv_operands)
+      jnp.asarray(layer, jnp.int32).reshape(1), qb, *kept, *kv_operands)
     return out.reshape(B, nT, KV, G, bt, Dh).transpose(0, 1, 4, 2, 3, 5
                                                        ).reshape(B, T, H * Dh)
 

@@ -1,0 +1,431 @@
+"""Learned sparse attention over the page pool: a lightning indexer's
+scores, the exact top-``k`` selection, and attention over the selected
+rows only.
+
+A softmax layer with an indexer (``ModelConfig.idx_topk`` > 0) caches, per
+token, ONE index key of ``W`` numbers beside its K and V rows. A query at
+position ``t`` has ``J`` index queries ``qI_j`` and weights ``w_j`` and
+scores every cached position ``s <= t``::
+
+    I(t, s) = sum_j w_j relu(qI_j . kI_s)
+
+and attends the ``k`` positions of largest ``I`` only (float32 compare,
+ties to the lower position; every position while ``t < k``). One selection
+a query, shared by all heads.
+
+Layout. The index keys are a THIRD side of the group's page pool, on the
+same page table and allocator as K and V: ``[L, P, W, page]``, a page
+stored TRANSPOSED with the token axis in the lanes, as the latent pool is
+(ops/latent_attention.py) — ``W`` = 64 is half a lane tile, so token-major
+pages ``[page, W]`` would be padded to 128 columns in HBM and in every
+copy (twice the side), and transposed the score product ``qI [rows, W] @
+page [W, page]`` reads a page as it lies. Its in-place write IS the latent
+pool's (:func:`latent_insert_in_place`); K and V go in through the chunk
+write of ops/paged_attention.py. Insert, then attend, in both step
+programs: a call's own keys are read back as the bytes that were written.
+
+Two forms of the attention, one selection:
+
+* DECODE (one query a slot): scores over the slot's index keys read
+  through the page table (``W`` numbers a cached token, not its K/V rows),
+  the exact top-``k`` as a list of positions (``lax.top_k`` over the
+  slots' one row each), those positions as (physical page, offset) pairs,
+  and a TOKEN-granular gather of those K and V rows — the bytes a
+  step reads of K/V are bounded by ``k``, not by the context.
+* PREFILL (a chunk of queries a row): ONE kernel over the row's live
+  index-key pages (:func:`index_select`) — the scores, the exact ``k``-th
+  largest a query by BISECTION on the float's bits (32 counting passes, no
+  sort), the selection as an int8 mask — and the dense page walk with a
+  score kept only where the mask says so (``keep``, an optional operand of
+  ``paged_prefill_attention``).
+
+The selection is the same SET in both: ``lax.top_k``'s, ties to the lower
+position. The plain form of it is :func:`top_positions` (the list a decode
+step gathers by) and :func:`top_mask` (that list as a mask: what the
+"reference" provider attends by and what the kernel is held to).
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from . import paged_attention as _paged
+from .latent_attention import (create_latent_pool, gather_latent,
+                               latent_insert, latent_insert_in_place)
+from .paged_attention import (NEG_INF, gather_pages, paged_insert_chunk_in_place,
+                              paged_insert_kv, paged_prefill_attention)
+
+_INT_MIN = -2 ** 31
+
+
+def create_index_pool(n_layers: int, num_pages: int, page_size: int,
+                      width: int, dtype=jnp.bfloat16) -> jax.Array:
+    """The zeroed index-key side ``[L, P, W, page]``."""
+    return create_latent_pool(n_layers, num_pages, page_size, width, dtype)
+
+
+# ---------------------------------------------------------------------------
+# Scores and the selection
+# ---------------------------------------------------------------------------
+
+def index_scores(qi: jax.Array, w: jax.Array, keys: jax.Array) -> jax.Array:
+    """qi [B, T, J, W], w [B, T, J] float32 (the scale factors multiplied
+    in), keys [B, S, W] -> ``I`` [B, T, S] float32. The products run in
+    the keys' dtype with float32 accumulation, all heads in one product
+    (2 MB a row at 16 heads of 32,768 keys and one query)."""
+    s = jnp.einsum("btjw,bsw->btjs", qi.astype(keys.dtype), keys,
+                   preferred_element_type=jnp.float32)
+    return jnp.sum(w[..., None] * jnp.maximum(s, 0.0), axis=2)
+
+
+def sortable(x: jax.Array) -> jax.Array:
+    """float32 -> int32 whose signed order is the floats' (the two zeros
+    one key, as a float compare has them; no NaN comes in)."""
+    x = x.astype(jnp.float32)
+    bits = jax.lax.bitcast_convert_type(jnp.where(x == 0.0, 0.0, x),
+                                        jnp.int32)
+    return jnp.where(bits < 0, bits ^ 0x7FFFFFFF, bits)
+
+
+def top_positions(scores: jax.Array, seen: jax.Array, k: int
+                  ) -> tuple[jax.Array, jax.Array]:
+    """scores float32 [..., S], seen bool [..., S] -> (the ``k`` selected
+    positions int32 [..., k], how many of them are real [...]): the seen
+    positions of largest score through ``lax.top_k`` (the lower index of
+    equal scores first; a sort, 0.29 ms at 8 x 32,768 on the chip where a
+    bisection and a sort-free compaction of its mask took 0.81). A row that
+    sees fewer than ``k`` lists them first; the rest of its places name
+    unseen positions and are not real."""
+    scores = jnp.where(scores == 0.0, 0.0, scores)      # -0.0 IS 0.0
+    k = min(k, scores.shape[-1])
+    _, idx = jax.lax.top_k(jnp.where(seen, scores, -jnp.inf), k)
+    total = jnp.minimum(jnp.sum(seen, axis=-1, dtype=jnp.int32), k)
+    return idx.astype(jnp.int32), total
+
+
+def top_mask(scores: jax.Array, seen: jax.Array, k: int) -> jax.Array:
+    """:func:`top_positions`' set as ``keep`` bool [..., S]: the plain form
+    of a chunk's selection (every seen position of a row that sees no more
+    than ``k``)."""
+    idx, total = top_positions(scores, seen, k)
+    rows, S = idx.reshape(-1, idx.shape[-1]), scores.shape[-1]
+    real = jnp.arange(rows.shape[-1]) < total.reshape(-1, 1)
+    keep = jnp.zeros((rows.shape[0], S), bool).at[
+        jnp.arange(rows.shape[0])[:, None], rows].set(real)
+    return keep.reshape(scores.shape)
+
+
+# ---------------------------------------------------------------------------
+# A chunk's scores and selection as ONE kernel over the live pages
+# ---------------------------------------------------------------------------
+
+# Query positions a program of the chunk kernel holds (their int32 keys over
+# every table position stay in VMEM: 4 MiB at 32 x 32,768), the pages it
+# copies a step, and what it may take of VMEM.
+_SELECT_BLOCK_T = 32
+_SELECT_PAGES_PER_STEP = 4
+_SELECT_VMEM_LIMIT_BYTES = 48 * 2 ** 20
+
+
+def _index_select_kernel(pt_ref, start_ref, layer_ref, q_ref, w_ref,
+                         pool_ref, keep_ref, buf, keys_ref, sem, *,
+                         block_t: int, heads: int, page: int, ppb: int,
+                         topk: int, n_table_pages: int):
+    """Program ``(row b, row-block t)``: ``block_t`` query positions, all
+    ``heads`` index heads of each (row ``j * block_t + i`` is head ``j`` at
+    position ``first_q + i``), walk the row's index-key pages up to the last
+    query's own — ``ppb`` pages a step into one of two VMEM buffers while
+    the step before is scored — and leave each position's score as a
+    sortable int32 key in ``keys_ref`` [block_t, positions] (the least
+    int32 where the query cannot see). Then, over the LIVE pages alone: the
+    ``topk``-th largest key a query bit by bit, from the sign down (32
+    counting passes: the largest ``x`` that at least ``topk`` keys reach),
+    among the keys AT that value the position of the last one there is room
+    for (20 more passes), and the selection as int8."""
+    b, t = pl.program_id(0), pl.program_id(1)
+    bt = block_t
+    layer = layer_ref[0]
+    first_q = start_ref[b] + t * bt
+    n_live = jnp.minimum((first_q + bt - 1) // page + 1, n_table_pages)
+    n_steps = (n_live + ppb - 1) // ppb
+
+    def copy(step, sub, slot):
+        lp = jnp.minimum(step * ppb + sub, n_table_pages - 1)
+        return pltpu.make_async_copy(pool_ref.at[layer, pt_ref[b, lp]],
+                                     buf.at[slot, sub], sem.at[slot, sub])
+
+    def start(step, slot):
+        for sub in range(ppb):
+            @pl.when(step * ppb + sub < n_live)
+            def _start(sub=sub):
+                copy(step, sub, slot).start()
+
+    def columns(lp):
+        return pl.ds(pl.multiple_of(lp * page, page), page)
+
+    def position(lp):
+        return lp * page + jax.lax.broadcasted_iota(jnp.int32, (bt, page), 1)
+
+    q_pos = first_q + jax.lax.broadcasted_iota(jnp.int32, (bt, page), 0)
+
+    def score(slot, sub, lp):
+        s = jnp.dot(q_ref[0, 0], buf[slot, sub],
+                    preferred_element_type=jnp.float32)     # [heads*bt, page]
+        s = jnp.maximum(s, 0.0) * w_ref[0, 0]
+        acc = s[0:bt]
+        for j in range(1, heads):
+            acc = acc + s[j * bt:(j + 1) * bt]
+        keys_ref[:, columns(lp)] = jnp.where(position(lp) <= q_pos,
+                                             sortable(acc), _INT_MIN)
+
+    start(0, 0)
+
+    def step(i, carry):
+        slot = i % 2
+
+        @pl.when(i + 1 < n_steps)
+        def _prefetch():
+            start(i + 1, 1 - slot)
+        for sub in range(ppb):
+            lp = i * ppb + sub
+
+            @pl.when(lp < n_live)
+            def _score(sub=sub, lp=lp):
+                copy(i, sub, slot).wait()
+                score(slot, sub, lp)
+        return carry
+    jax.lax.fori_loop(0, n_steps, step, 0)
+
+    def count(pred):
+        """[bt, 1]: how many live positions of each query ``pred(keys of a
+        page, the page)`` holds for."""
+        def body(lp, acc):
+            return acc + pred(keys_ref[:, columns(lp)], lp).astype(jnp.int32)
+        acc = jax.lax.fori_loop(0, n_live, body,
+                                jnp.zeros((bt, page), jnp.int32))
+        return jnp.sum(acc, axis=1, keepdims=True)
+
+    def reach(x):
+        return count(lambda keys, lp: keys >= x) >= topk
+
+    zero = jnp.zeros((bt, 1), jnp.int32)
+    kth = jnp.where(reach(zero), zero, _INT_MIN)
+
+    def bit(i, x):
+        cand = x | jnp.left_shift(jnp.int32(1), 30 - i)
+        return jnp.where(reach(cand), cand, x)
+    kth = jax.lax.fori_loop(0, 31, bit, kth)
+    room = topk - count(lambda keys, lp: keys > kth)
+    # Among the keys AT the k-th value: the least position that ``room`` of
+    # them reach (positions fit 15 bits and more; 20 covers a 1M context).
+    def place(i, x):
+        cand = x + jnp.left_shift(jnp.int32(1), 19 - i)
+        short = count(lambda keys, lp: (keys == kth)
+                      & (position(lp) < cand)) < room
+        return jnp.where(short, cand, x)
+    last = jax.lax.fori_loop(0, 20, place, zero)
+
+    def keep(lp, carry):
+        keys = keys_ref[:, columns(lp)]
+        kept = (keys > kth) | ((keys == kth) & (position(lp) <= last)
+                               & (room > 0) & (keys > _INT_MIN))
+        keep_ref[0, :, columns(lp)] = kept.astype(jnp.int8)
+        return carry
+    jax.lax.fori_loop(0, n_live, keep, 0)
+
+    def dead(lp, carry):
+        keep_ref[0, :, columns(lp)] = jnp.zeros((bt, page), jnp.int8)
+        return carry
+    jax.lax.fori_loop(n_live, n_table_pages, dead, 0)
+
+
+def index_select(qi: jax.Array, w: jax.Array, pool_i: jax.Array,
+                 page_table: jax.Array, start: jax.Array, *,
+                 layer: jax.Array | int, topk: int,
+                 interpret: bool | None = None) -> jax.Array:
+    """A chunk's selection in one Pallas call: index queries qi [B, T, J,
+    W] with weights w [B, T, J] at positions ``start + t`` over layer
+    ``layer`` of the index-key side ``[L, P, W, page]``, read through
+    page_table [B, NP] -> ``keep`` int8 [B, T, NP * page], 1 where the
+    query attends (:func:`top_mask` of :func:`index_scores`, the same set).
+    Grid ``(B, T // bt)``; a program's work follows its LIVE pages — the
+    scores' product, the 67 counting passes over its queries' keys in VMEM
+    — and nothing is paid for the table's dead entries but their zeros."""
+    B, T, J, W = qi.shape
+    page, NP = pool_i.shape[-1], page_table.shape[1]
+    bt = min(_SELECT_BLOCK_T, T)
+    if T % bt:
+        raise ValueError(f"T={T} is not whole row-blocks of {bt}")
+    nT, ppb = T // bt, _SELECT_PAGES_PER_STEP
+    qb = qi.astype(pool_i.dtype).reshape(B, nT, bt, J, W).transpose(
+        0, 1, 3, 2, 4).reshape(B, nT, J * bt, W)
+    wb = w.astype(jnp.float32).reshape(B, nT, bt, J).transpose(
+        0, 1, 3, 2).reshape(B, nT, J * bt, 1)
+    return pl.pallas_call(
+        functools.partial(_index_select_kernel, block_t=bt, heads=J,
+                          page=page, ppb=ppb, topk=topk, n_table_pages=NP),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(B, nT),
+            in_specs=[pl.BlockSpec((1, 1, J * bt, W),
+                                   lambda b, t, pt, st, ly: (b, t, 0, 0)),
+                      pl.BlockSpec((1, 1, J * bt, 1),
+                                   lambda b, t, pt, st, ly: (b, t, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, bt, NP * page),
+                                   lambda b, t, pt, st, ly: (b, t, 0)),
+            scratch_shapes=[pltpu.VMEM((2, ppb, W, page), pool_i.dtype),
+                            pltpu.VMEM((bt, NP * page), jnp.int32),
+                            pltpu.SemaphoreType.DMA((2, ppb))]),
+        out_shape=jax.ShapeDtypeStruct((B, T, NP * page), jnp.int8),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_SELECT_VMEM_LIMIT_BYTES),
+        interpret=(_paged._interpret_default() if interpret is None
+                   else interpret),
+    )(page_table.astype(jnp.int32), start.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), qb, wb, pool_i)
+
+
+# ---------------------------------------------------------------------------
+# The two forms of the attention
+# ---------------------------------------------------------------------------
+
+def gathered_decode_attention(q: jax.Array, pool_k: jax.Array,
+                              pool_v: jax.Array, layer, phys: jax.Array,
+                              offset: jax.Array, total: jax.Array
+                              ) -> jax.Array:
+    """One query a slot over its SELECTED rows. q [B, H, Dh]; the stacked
+    pool sides [L, P, KV, page, Dh]; phys, offset [B, k]: where each
+    selected token lies in layer ``layer``; total [B]: how many of the
+    ``k`` places are real. The rows are gathered token by token — row
+    ``((layer P + phys) KV + head) page + offset`` of the pool seen as
+    ``[L P KV page, Dh]``, a view that moves nothing — and attended in
+    float32. Returns [B, H * Dh] in q's dtype."""
+    L, P, KV, page, Dh = pool_k.shape
+    B, H, _ = q.shape
+    G, k = H // KV, phys.shape[1]
+    row = (((jnp.asarray(layer, jnp.int32) * P + phys)[:, :, None] * KV
+            + jnp.arange(KV, dtype=jnp.int32)) * page + offset[:, :, None])
+    keys = jnp.take(pool_k.reshape(-1, Dh), row, axis=0)    # [B, k, KV, Dh]
+    vals = jnp.take(pool_v.reshape(-1, Dh), row, axis=0)
+    qg = q.reshape(B, KV, G, Dh)
+    scores = jnp.einsum("bhgd,bshd->bhgs", qg, keys.astype(q.dtype),
+                        preferred_element_type=jnp.float32) * Dh ** -0.5
+    real = jnp.arange(k)[None, :] < total[:, None]
+    scores = jnp.where(real[:, None, None, :], scores, NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("bhgs,bshd->bhgd", probs.astype(vals.dtype), vals,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(B, H * Dh).astype(q.dtype)
+
+
+def masked_attention_reference(q: jax.Array, dense_k: jax.Array,
+                               dense_v: jax.Array, keep: jax.Array
+                               ) -> jax.Array:
+    """Plain float32 attention over a gathered view with the selection as
+    its mask. q [B, T, H, Dh], dense_k/v [B, KV, S, Dh], keep bool
+    [B, T, S] -> [B, T, H * Dh] in q's dtype."""
+    B, T, H, Dh = q.shape
+    KV = dense_k.shape[1]
+    qg = q.astype(jnp.float32).reshape(B, T, KV, H // KV, Dh)
+    scores = jnp.einsum("bthgd,bhsd->bhgts", qg,
+                        dense_k.astype(jnp.float32)) * Dh ** -0.5
+    scores = jnp.where(keep[:, None, None], scores, NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("bhgts,bhsd->bthgd", probs, dense_v.astype(jnp.float32))
+    return out.reshape(B, T, H * Dh).astype(q.dtype)
+
+
+class SparseAttention:
+    """What a softmax layer with an indexer is handed for its cache
+    (models/hybrid.py), built INSIDE the engine's jitted step over the
+    traced page table as the other groups' providers are. ``pool`` is the
+    group's three stacked sides ``(K, V, index keys)``, which both step
+    programs carry through their layer scan. ``impl`` "pallas": the
+    in-place writes, the gathered decode and the masked page walk;
+    "reference": XLA scatters and a gathered dense view (CPU tests). Both
+    insert, then attend, over the same selection."""
+
+    def __init__(self, page_table: jax.Array, max_seq: int, topk: int,
+                 impl: str = "pallas", interpret: bool | None = None):
+        self.page_table, self.max_seq, self.topk = page_table, max_seq, topk
+        self.impl, self.interpret = impl, interpret
+
+    def write(self, pool, k_new, v_new, ki_new, layer, lengths, active=None):
+        """k_new, v_new [B, T, KV, Dh], ki_new [B, T, W] into layer
+        ``layer`` at positions ``lengths + t`` -> the pool."""
+        pool_k, pool_v, pool_i = pool
+        table = self.page_table
+        if self.impl == "pallas":
+            with jax.named_scope("kv.paged_insert"):
+                pool_k, pool_v = paged_insert_chunk_in_place(
+                    pool_k, pool_v, k_new, v_new, table, lengths, active,
+                    layer=layer, interpret=self.interpret)
+                pool_i = latent_insert_in_place(
+                    pool_i, ki_new, table, lengths, active, layer=layer,
+                    interpret=self.interpret)
+            return pool_k, pool_v, pool_i
+        layer_k, layer_v = paged_insert_kv(
+            pool_k[layer], pool_v[layer], k_new, v_new, table, lengths,
+            active)
+        return (pool_k.at[layer].set(layer_k), pool_v.at[layer].set(layer_v),
+                latent_insert(pool_i, ki_new, table, lengths, active,
+                              layer=layer))
+
+    def scores(self, qi, w, pool_i, layer):
+        """``I`` [B, T, S] of index queries qi [B, T, J, W] with weights w
+        [B, T, J] over the index keys of layer ``layer`` at every table
+        entry's positions (``S`` of them)."""
+        S = self.page_table.shape[1] * pool_i.shape[-1]
+        return index_scores(qi, w, gather_latent(
+            pool_i, self.page_table, S, layer=layer))
+
+    def select(self, qi, w, pool_i, layer, start):
+        """The queries at positions ``start + t`` over the WRITTEN index
+        keys -> what each attends. One query a row: :func:`top_positions`'
+        (positions [B, k], how many are real [B]). A chunk: ``keep`` bool
+        [B, T, S] (a position the query cannot see is never kept) — one
+        kernel over the live pages (:func:`index_select`); the plain
+        :func:`top_mask` of :meth:`scores` for the "reference" provider and
+        a chunk that is not whole row-blocks. The same set in each."""
+        T = qi.shape[1]
+        if self.impl == "pallas" and T >= 8 and T % min(
+                _SELECT_BLOCK_T, T) == 0:
+            return index_select(qi, w, pool_i, self.page_table, start,
+                                layer=layer, topk=self.topk,
+                                interpret=self.interpret).astype(bool)
+        scores = self.scores(qi, w, pool_i, layer)
+        q_pos = start[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
+        seen = jnp.arange(scores.shape[-1], dtype=jnp.int32)[None, None, :] \
+            <= q_pos[:, :, None]
+        if T == 1:
+            return top_positions(scores[:, 0], seen[:, 0], self.topk)
+        return top_mask(scores, seen, self.topk)
+
+    def attend(self, q, pool, layer, start, selected):
+        """q [B, T, H, Dh] (rotated) at positions ``start + t`` over what
+        :meth:`select` gave, in the WRITTEN pool -> [B, T, H * Dh]. One
+        query a row: the listed rows alone are gathered and read. A chunk:
+        the dense page walk, masked by ``keep``."""
+        pool_k, pool_v, pool_i = pool
+        if q.shape[1] == 1:
+            positions, total = selected
+            page = pool_i.shape[-1]
+            phys = jnp.take_along_axis(self.page_table, positions // page,
+                                       axis=1)
+            with jax.named_scope("attention.sparse_decode"):
+                return gathered_decode_attention(
+                    q[:, 0], pool_k, pool_v, layer, phys, positions % page,
+                    total)[:, None]
+        if self.impl == "pallas":
+            with jax.named_scope("attention.paged_prefill"):
+                return paged_prefill_attention(
+                    q, pool_k, pool_v, self.page_table, start, layer=layer,
+                    keep=selected, interpret=self.interpret)
+        S = selected.shape[-1]
+        dense_k = gather_pages(pool_k[layer], self.page_table, S)
+        dense_v = gather_pages(pool_v[layer], self.page_table, S)
+        return masked_attention_reference(q, dense_k, dense_v, selected)

@@ -1,0 +1,307 @@
+"""The Keye-VL-2.0 family (the period scan of models/hybrid.py with a period
+of ONE softmax layer under a learned indexer, ops/sparse_attention.py, over
+a page pool with an index-key side) against the plain reference
+(benchmark/reference/keye_vl2.py) on seeded random weights at the tiny
+preset, where 24 keys are kept of contexts of 96-160: logits, not tokens.
+Every tolerance says where it comes from."""
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.reference import keye_vl2 as ref
+from llmapigateway_tpu.models import hybrid
+from llmapigateway_tpu.models.config import PRESETS, get_preset
+from llmapigateway_tpu.models.llama import apply_rope, rope_tables
+from llmapigateway_tpu.ops import sparse_attention as sa
+from tests.hybrid_params import params_of
+
+TINY = get_preset("tiny-keye-vl2-test")
+# Both sides float32 on the same weights: what is left is the order of the
+# sums (pages against one softmax over the sequence, a gathered or masked
+# read against a dense one, a grouped or batched expert product against a
+# loop over experts), ~1e-6 relative on logits of size ~4. A query that
+# kept another key than the reference misses by ~0.1-1.
+F32_TOL = 2e-4
+PAGE, SEQ = 8, 160
+
+
+def file_of(c) -> dict:
+    """What a configuration's file states, for the reference's ``sizes``."""
+    return {"num_attention_heads": c.n_heads,
+            "num_key_value_heads": c.n_kv_heads, "head_dim": c.head_dim,
+            "rope_theta": c.rope_theta, "rms_norm_eps": c.rms_eps,
+            "num_experts_per_tok": c.experts_per_token,
+            "num_experts": c.experts_held,
+            "first_expert_held": c.first_expert_held,
+            "mlp_only_layers": [], "decoder_sparse_step": 1,
+            "norm_topk_prob": True, "attention_bias": False,
+            "tie_word_embeddings": False,
+            "rope_scaling": {"mrope_section": [16, 24, 24],
+                             "rope_type": "default"},
+            "sa_config": {"indexer_head_dim": c.idx_head_dim,
+                          "indexer_num_heads": c.idx_heads,
+                          "indexer_num_kv_heads": 1, "topk": c.idx_topk}}
+
+
+SIZES = ref.sizes(TINY, file_of(TINY))
+
+
+def serve(c, params, tokens, impl: str, chunk: int = 32, prompt: int = 96,
+          dtype=jnp.float32, spoil: float = 0.0):
+    """Row 0's ``tokens`` [n] through slot 1 of a two-slot cache: the
+    prompt in chunks of ``chunk``, then a decode step a token (row b IS
+    slot b: slot 0 stays inactive). ``spoil``: what every index key of the
+    pool holds before (a stale page's). -> logits [n, V]."""
+    per = SEQ // PAGE
+    table = jnp.arange(1, 2 * per + 1, dtype=jnp.int32).reshape(2, per)
+    cache = hybrid.HybridCache.create(c, 2 * per + 1, PAGE, 2, dtype)
+    cache = cache._replace(index=tuple(jnp.full_like(i, spoil)
+                                       for i in cache.index))
+    one = sa.SparseAttention(table[jnp.asarray([1])], SEQ, c.idx_topk, impl,
+                             interpret=True)
+    both = sa.SparseAttention(table, SEQ, c.idx_topk, impl, interpret=True)
+    prefill = jax.jit(lambda p, t, at, cache: hybrid.forward(
+        p, c, t, at, cache, attention_fn=one, slots=jnp.asarray([1])))
+    decode = jax.jit(lambda p, t, at, cache, on: hybrid.forward(
+        p, c, t, at, cache, active=on, attention_fn=both))
+    out = []
+    for pos in range(0, prompt, chunk):
+        logits, cache = prefill(
+            params, jnp.asarray(tokens[None, pos:pos + chunk]),
+            jnp.asarray([pos], jnp.int32), cache)
+        out.append(np.asarray(logits[0]))
+    for i in range(prompt, len(tokens)):
+        logits, cache = decode(
+            params, jnp.asarray([[0], [tokens[i]]], jnp.int32),
+            jnp.asarray([0, i], jnp.int32), cache,
+            jnp.asarray([False, True]))
+        out.append(np.asarray(logits[1]))
+    return np.concatenate(out)
+
+
+@pytest.fixture(scope="module")
+def f32_params():
+    return params_of(TINY)
+
+
+@pytest.fixture(scope="module")
+def tokens():
+    return np.random.default_rng(51).integers(
+        0, TINY.vocab_size, 104).astype(np.int32)
+
+
+@pytest.fixture(scope="module")
+def want(f32_params, tokens):
+    return ref.logits(f32_params, SIZES, tokens, last=len(tokens))
+
+
+def test_the_presets_are_the_published_sizes_and_one_group_of_three_sides():
+    full = PRESETS["keye-vl2-30b-a3b"]
+    assert (full.n_layers, full.d_model, full.n_heads, full.n_kv_heads,
+            full.head_dim, full.vocab_size, full.max_seq_len) == (
+                48, 2048, 32, 4, 128, 151936, 262144)
+    assert (full.n_experts, full.experts_per_token, full.d_ff_expert,
+            full.n_shared_experts, full.moe_router) == (128, 8, 768, 0,
+                                                        "softmax")
+    assert (full.idx_heads, full.idx_head_dim, full.idx_topk) == (16, 64,
+                                                                  2048)
+    assert full.qk_norm and full.rope_theta == 1e7 and full.is_sparse
+    # A random draw gives the head norms 1.73: attention logits of standard
+    # deviation 3, peaked enough for a comparison of logits to see WHICH
+    # keys were attended (the file's ``assumed``); ones at the tiny preset.
+    assert full.qk_norm_draw == 1.73 and TINY.qk_norm_draw == 1.0
+    assert full.cache_groups == ((0, (0,)),) and not full.is_mla
+    assert full.n_kv_layers == 48 and full.n_lin_layers == 0
+    cut = PRESETS["keye-vl2-30b-ep4"]
+    assert cut == dataclasses.replace(full, n_layers=12, vocab_size=37984,
+                                      n_experts_held=32)
+    assert 4 * cut.vocab_size == full.vocab_size
+    assert not PRESETS["mistral-7b"].is_sparse
+    with pytest.raises(ValueError, match="idx_topk needs idx_heads"):
+        dataclasses.replace(full, idx_heads=0)
+    cache = jax.eval_shape(lambda: hybrid.HybridCache.create(
+        TINY, 9, PAGE, 2, jnp.float32))
+    assert [a.shape for a in cache.k] == [(4, 9, 2, PAGE, 16)]
+    assert [a.shape for a in cache.index] == [(4, 9, 8, PAGE)]
+    assert jax.eval_shape(lambda: hybrid.HybridCache.create(
+        get_preset("tiny-mistral4-test"), 9, PAGE, 2)).index == ()
+
+
+@pytest.mark.parametrize("impl", ["reference", "pallas"])
+def test_prefill_then_decode_is_the_references_forward(f32_params, tokens,
+                                                       want, impl):
+    """Three chunks of 32 and eight decode steps: from position 24 on every
+    query attends a SELECTION (24 of up to 104 keys) — gathered row by row
+    in decode, a mask over the page walk in prefill (``pallas``: the
+    kernels, interpreted) — over index keys that lay as a stale page's
+    would before they were written."""
+    got = serve(TINY, f32_params, tokens, impl, spoil=50.0)
+    np.testing.assert_allclose(got, want, atol=F32_TOL, rtol=0)
+
+
+def test_the_selection_is_live_in_what_is_compared(f32_params, tokens, want):
+    """With the selection switched off, or the 24 keys of LEAST score kept,
+    the reference reads ~0.5 elsewhere (the benchmark's ``CONTROLS``): the
+    comparison above sees WHICH keys were attended."""
+    assert set(ref.CONTROLS) == {"int4_weights", "dense_attention",
+                                 "lowest_scores"}
+    for control in ("dense_attention", "lowest_scores"):
+        wrong = ref.logits(f32_params, ref.CONTROLS[control](SIZES), tokens,
+                           last=len(tokens))
+        assert np.abs(wrong[:24] - want[:24]).max() <= F32_TOL  # all kept
+        assert np.abs(wrong[40:] - want[40:]).max() > 0.1, control
+
+
+@pytest.mark.parametrize("k", [1, 24, 200])
+def test_the_selected_set_is_top_ks_ties_and_all(k):
+    """Scores with many equal values (a third of them rounded to a tenth,
+    some exactly 0, some negative zero) and a causal bound: the plain form
+    of the selection (``top_mask``, ``top_positions``' list as a mask) is
+    the first ``k`` seen positions of a STABLE descending sort — ties to
+    the lower position, the two zeros one value — and every seen key where
+    a row sees no more than ``k``; the reference's own form says the same."""
+    rng = np.random.default_rng(k)
+    scores = rng.normal(size=(2, 40, 160)).astype(np.float32)
+    scores[:, :, ::3] = np.round(scores[:, :, ::3], 1)
+    scores[:, :, 5::11] = 0.0
+    scores[:, :, 7::13] = -0.0
+    q_pos = 90 + np.arange(40)
+    seen = np.broadcast_to(np.arange(160)[None, :] <= q_pos[:, None],
+                           scores.shape)
+    got = np.asarray(jax.jit(lambda s, m: sa.top_mask(s, m, k))(scores, seen))
+    order = np.argsort(-(scores + 0.0), axis=-1, kind="stable")
+    for b, t in np.ndindex(2, 40):
+        first = [s for s in order[b, t] if seen[b, t, s]][:k]
+        assert np.flatnonzero(got[b, t]).tolist() == sorted(first)
+    for b in range(2):
+        assert (got[b] == np.asarray(ref.top_positions(
+            jnp.asarray(scores[b]), jnp.asarray(seen[b]), k))).all()
+    assert (got.sum(-1) == np.minimum(seen.sum(-1), k)).all()
+    keys = np.asarray(sa.sortable(jnp.asarray(scores)))
+    assert (np.argsort(keys[0, 0], kind="stable")
+            == np.argsort(scores[0, 0] + 0.0, kind="stable")).all()
+
+
+@pytest.mark.parametrize("start", [(0, 40), (100, 7)])
+def test_the_chunk_kernel_selects_what_the_plain_form_selects(start):
+    """``index_select`` (one kernel over the live pages: scores, bisection,
+    ties by position; interpreted here) against ``top_mask`` of
+    ``index_scores`` on index keys in shuffled pages. Queries, keys and
+    weights are small dyadic numbers, so both forms' float32 sums are exact
+    whatever their order and MANY scores tie: the sets are equal, bit for
+    bit, below the 24 keys, past them, and at a start that is no multiple
+    of the page."""
+    rng = np.random.default_rng(sum(start))
+    pages, J, W, T = 20, TINY.idx_heads, TINY.idx_head_dim, 32
+    table = jnp.asarray(rng.permutation(np.arange(1, 2 * pages + 1)).reshape(
+        2, pages).astype(np.int32))
+    pool = jnp.asarray(rng.integers(-1, 2, (2, 2 * pages + 1, W, PAGE)),
+                       jnp.float32)
+    qi = jnp.asarray(rng.integers(-1, 2, (2, T, J, W)), jnp.float32)
+    w = jnp.asarray(rng.choice([0.5, -0.5, 0.25, 1.0], (2, T, J)),
+                    jnp.float32)
+    at = jnp.asarray(start, jnp.int32)
+    plain, kernel = (sa.SparseAttention(table, pages * PAGE, TINY.idx_topk,
+                                        impl, interpret=True)
+                     for impl in ("reference", "pallas"))
+    want = np.asarray(plain.select(qi, w, pool, 1, at))
+    got = np.asarray(jax.jit(
+        lambda qi, w: kernel.select(qi, w, pool, 1, at))(qi, w))
+    assert got.dtype == bool and (got == want).all()
+    q_pos = np.asarray(start)[:, None] + np.arange(T)
+    assert (got.sum(-1) == np.minimum(q_pos + 1, TINY.idx_topk)).all()
+    # Ties were there to break: at some query the k-th score has equals.
+    scores = np.asarray(plain.scores(qi, w, pool, 1))
+    tied = [np.sum(scores[b, t, :q_pos[b, t] + 1]
+                   == scores[b, t][got[b, t]].min()) > 1
+            for b in range(2) for t in range(T) if q_pos[b, t] >= 24]
+    assert any(tied)
+
+
+def test_a_decode_steps_list_is_the_selected_set():
+    """``top_positions`` (one query a row, what the gather reads by) lists
+    exactly the reference's set, ties and signed zeros included, and says
+    how many of its places are real where a row sees fewer than ``k``."""
+    rng = np.random.default_rng(3)
+    scores = np.round(rng.normal(size=(3, 160)), 1).astype(np.float32)
+    scores[:, 3::7] = -0.0
+    scores[:, 5::11] = 0.0
+    seen = np.arange(160)[None, :] <= np.asarray([159, 4, 100])[:, None]
+    keep = np.asarray(ref.top_positions(jnp.asarray(scores),
+                                        jnp.asarray(seen), 24))
+    listed, total = map(np.asarray, jax.jit(
+        lambda s, m: sa.top_positions(s, m, 24))(scores, seen))
+    assert total.tolist() == [24, 5, 24] and listed.shape == (3, 24)
+    for b in range(3):
+        assert sorted(listed[b, :total[b]].tolist()) == \
+            np.flatnonzero(keep[b]).tolist()
+
+
+def test_equal_streams_are_the_engines_rotary_and_unequal_ones_are_not():
+    """On text the three position streams are equal and the reference's
+    multimodal rotary IS the half-split rotary the engine computes; with an
+    image's streams (height and width apart from time) it is another."""
+    rng = np.random.default_rng(9)
+    for d in (TINY.idx_head_dim, 128):
+        x = jnp.asarray(rng.normal(size=(12, 3, d)), jnp.float32)
+        pos = jnp.arange(5, 17)
+        cos, sin = rope_tables(pos[None], d, 1e7)
+        mine = apply_rope(x[None], cos, sin)[0]
+        text = ref.mrope(x, jnp.broadcast_to(pos, (3, 12)), (16, 24, 24), 1e7)
+        # The same angles up to a float32 rounding of the frequency.
+        np.testing.assert_allclose(np.asarray(text), np.asarray(mine),
+                                   atol=1e-5, rtol=0)
+        image = ref.mrope(x, jnp.stack([pos, pos // 4, pos % 4]),
+                          (16, 24, 24), 1e7)
+        assert float(jnp.abs(image - mine).max()) > 0.1
+
+
+def test_the_four_shares_are_the_uncut_layer(f32_params):
+    """The guide's share test on this family's expert layer (no shared
+    expert): what the four chips that share a layer compute for their 4
+    held experts each adds up to what the layer that holds all 16 computes;
+    and the reference, given one share, computes that share's layer."""
+    c = TINY
+    lp = jax.tree.map(lambda a: a[0], f32_params["layers"]["attn"]["mlp"])
+    x = jnp.asarray(np.random.default_rng(5).normal(size=(2, 12, c.d_model)),
+                    jnp.float32)
+    whole, _ = hybrid.moe_block(x, lp, c)
+    parts = []
+    for share in range(4):
+        held = dataclasses.replace(c, n_experts_held=4,
+                                   first_expert_held=4 * share)
+        mine = {**lp, **{k: lp[k][4 * share:4 * share + 4]
+                         for k in hybrid.EXPERT_KEYS}}
+        parts.append(hybrid.moe_block(x, mine, held)[0])
+    np.testing.assert_allclose(np.asarray(sum(parts)), np.asarray(whole),
+                               atol=1e-5, rtol=0)
+    assert min(float(jnp.abs(p).max()) for p in parts) > 1e-3
+    held = dataclasses.replace(c, n_experts_held=4, first_expert_held=8)
+    mine = {k: (v[8:12] if k in hybrid.EXPERT_KEYS else v)
+            for k, v in lp.items()}
+    routed = {k: mine.pop(k) for k in hybrid.EXPERT_KEYS}
+    with jax.default_matmul_precision("highest"):
+        want = ref._experts(x[0], mine, ref.sizes(held, file_of(held)),
+                            tuple(routed[k][None] for k in ("wg", "wu", "wd")),
+                            jnp.int32(0))
+    got, _ = hybrid.moe_block(x[:1], {**mine, **routed}, held)
+    np.testing.assert_allclose(np.asarray(x[0] + got[0]), np.asarray(want),
+                               atol=1e-5, rtol=0)
+
+
+def test_int8_weights_serve_within_the_benchmarks_bound(tokens):
+    """W8A8 as the cell serves it (int8 projections, experts and head; the
+    indexer, the router and the pool bfloat16) against the float32
+    reference on the dequantised weights: the reference's logit of every
+    position's served argmax within the harness's LOGIT_GAP_TOL of its
+    maximum."""
+    from benchmark.correctness import LOGIT_GAP_TOL
+    params = params_of(TINY, jnp.bfloat16, "int8")
+    got = serve(TINY, params, tokens, "reference", dtype=jnp.bfloat16)
+    want = ref.logits(params, SIZES, tokens, last=len(tokens))
+    served = got.argmax(-1)
+    gaps = want.max(-1) - want[np.arange(len(tokens)), served]
+    assert gaps.max() <= LOGIT_GAP_TOL and np.median(gaps) <= 0.05

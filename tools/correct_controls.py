@@ -8,10 +8,15 @@ entry of the reference module's ``CONTROLS`` (a change of the reference's
 sizes: a coarser arithmetic, a wrong layer) stands in the reference's place,
 through ``correctness.served_against_reference`` ITSELF — the harness's
 sample served anew, its comparison, the cell's limits — on ``--samples``
-samples, drawn from the seeds ``--seed`` onwards. A line ``{"phase":
-"control", ...}`` each gives the readings (``gap_max``, ``gap_p50``) beside
-the limits and ``ok``, which must be false; the sound reference's readings
-on the same samples are the lines whose ``control`` is null. The module's
+samples, drawn from the seeds ``--seed`` onwards — and, where the module
+has ``controlled_checks(engine, config, change)`` (the checks of its
+``kernel_checks`` that compare served tokens with its logits: what the
+harness's sample is too short to reach), through those too: `correct` is
+the conjunction, so a control is refused when either refuses it. A line
+``{"phase": "control", ...}`` each gives the readings (``gap_max``,
+``gap_p50``; ``checks``) beside the limits and ``ok``, which must be false;
+the sound reference's readings on the same samples are the lines whose
+``control`` is null. The module's
 ``READINGS`` — what the comparison cannot refuse, and why — are taken the
 same way and refuse nothing. The sound reference on the run's own seed
 decides its ``correct`` as in any run, unless ``--decide`` names the control
@@ -22,6 +27,7 @@ Exit code 0 when every control was refused on every sample (and, with
 from __future__ import annotations
 
 import argparse
+import asyncio
 import json
 import sys
 import types
@@ -56,6 +62,12 @@ def main() -> int:
                     logits=reference.logits)
                 got = await sound_compare(gateway, seed + sample,
                                           prompt_tokens, how, module, config)
+                if hasattr(reference, "controlled_checks"):
+                    got["checks"] = await asyncio.to_thread(
+                        reference.controlled_checks, gateway.engine, config,
+                        change)
+                    got["ok"] = got["ok"] and all(
+                        c["ok"] for c in got["checks"])
                 run.emit("control", control=name, seed=seed + sample,
                          must_refuse=name in controls,
                          **{k: v for k, v in got.items() if k != "logs"})
