@@ -456,8 +456,11 @@ class InferenceEngine:
         # An indexer's decode steps (ISSUE 51), ONE layer's keys, kept the
         # same way: the index keys a step scored (each active slot's
         # context and itself) and the K/V rows it then read (at most
-        # ``idx_topk`` a slot). Monotone; worker thread.
-        self._dsa_decode_keys = {"scored": 0, "selected": 0}
+        # ``idx_topk`` a slot) — and the pages the read walked to get them
+        # (ISSUE 52: every live page of the slot, ``ceil(context / page)``).
+        # Monotone; worker thread.
+        self._dsa_decode_keys = {"scored": 0, "selected": 0,
+                                 "pages_walked": 0}
         # Device observability plane (ISSUE 8): per-kernel cost registry
         # (worker thread records, lock-guarded internally), the HBM
         # memory ledger, and the process-wide XLA compile monitor. The
@@ -3072,8 +3075,10 @@ class InferenceEngine:
         """Add a burst of ``n_steps`` to the decode keys of ONE layer of
         each cache group: step ``i`` of an active slot at length ``n``
         sees ``n + i + 1`` keys in a latent or a global group, and what
-        of them lies inside the window in a windowed one. And to the
-        state blocks rewritten: one a linear layer, active slot and step."""
+        of them lies inside the window in a windowed one (under an
+        indexer: also the keys it kept and the pages their read walked).
+        And to the state blocks rewritten: one a linear layer, active slot
+        and step."""
         live = self.lengths[self.active].astype(np.int64)
         self._lin_decode_state_updates += (
             n_steps * len(live) * self.model_cfg.n_lin_layers)
@@ -3083,6 +3088,8 @@ class InferenceEngine:
                 self._dsa_decode_keys["scored"] += int(seen.sum())
                 self._dsa_decode_keys["selected"] += int(np.minimum(
                     seen, self.model_cfg.idx_topk).sum())
+                self._dsa_decode_keys["pages_walked"] += int(
+                    (-(-seen // self.kv_page)).sum())
             elif g.kind == "latent":
                 self._mla_decode_keys += int(seen.sum())
             elif g.window:
@@ -3488,6 +3495,8 @@ class InferenceEngine:
                 self._dsa_decode_keys["scored"]
             out["dsa_decode_keys_selected_total"] = \
                 self._dsa_decode_keys["selected"]
+            out["dsa_decode_pages_walked_total"] = \
+                self._dsa_decode_keys["pages_walked"]
         if self.model_cfg.is_mla:
             out["mla_decode_keys_total"] = self._mla_decode_keys
             out["mla_prefill_keys_total"] = self._mla_prefill_keys

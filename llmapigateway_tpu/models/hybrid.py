@@ -978,9 +978,10 @@ def sparse_block(x: jax.Array, lp: Params, c: ModelConfig, pool: tuple,
     the call's rows written into layer ``layer``). ``fn``: the group's
     ``ops.sparse_attention.SparseAttention``. Insert, then select, then
     attend the selected keys only: the same three steps in both step
-    programs (a decode step gathers its selected rows, a chunk masks the
-    page walk). A row that is not ``active`` writes to the trash page and
-    attends from position 0; what it returns is not looked at."""
+    programs (a decode step and a chunk both walk the live pages under the
+    selection's mask, each in its own kernel). A row that is not ``active``
+    writes to the trash page and attends from position 0; what it returns
+    is not looked at."""
     B, T, _ = x.shape
     dh = c.head_dim
     start = lengths if active is None else jnp.where(active, lengths, 0)

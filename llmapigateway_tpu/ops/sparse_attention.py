@@ -29,9 +29,15 @@ Two forms of the attention, one selection:
 * DECODE (one query a slot): scores over the slot's index keys read
   through the page table (``W`` numbers a cached token, not its K/V rows),
   the exact top-``k`` as a list of positions (``lax.top_k`` over the
-  slots' one row each), those positions as (physical page, offset) pairs,
-  and a TOKEN-granular gather of those K and V rows — the bytes a
-  step reads of K/V are bounded by ``k``, not by the context.
+  slots' one row each), that list as a mask of 32-bit words a table
+  position (:func:`selection_words`), and ONE kernel that walks the slot's
+  live K and V pages and keeps a score where the mask says so
+  (:func:`selected_decode_attention`). The walk reads every live page, not
+  ``k`` rows: this layout gives up nothing smaller than a tile of 8 tokens
+  of a head, XLA's row-by-row gather of the selected rows
+  (:func:`gathered_decode_attention`, the plain form the kernel is held to
+  and what the "reference" provider attends by) ran at 11 ns a 256-byte
+  row, and whole pages stream at 600-665 GB/s (PERF.md, PR 52).
 * PREFILL (a chunk of queries a row): ONE kernel over the row's live
   index-key pages (:func:`index_select`) — the scores, the exact ``k``-th
   largest a query by BISECTION on the float's bits (32 counting passes, no
@@ -40,9 +46,9 @@ Two forms of the attention, one selection:
   ``paged_prefill_attention``).
 
 The selection is the same SET in both: ``lax.top_k``'s, ties to the lower
-position. The plain form of it is :func:`top_positions` (the list a decode
-step gathers by) and :func:`top_mask` (that list as a mask: what the
-"reference" provider attends by and what the kernel is held to).
+position. The plain form of it is :func:`top_positions` (a decode step's
+list) and :func:`top_mask` (that list as a mask: what the "reference"
+provider attends a chunk by and what the chunk kernel is held to).
 """
 from __future__ import annotations
 
@@ -56,7 +62,9 @@ from jax.experimental.pallas import tpu as pltpu
 from . import paged_attention as _paged
 from .latent_attention import (create_latent_pool, gather_latent,
                                latent_insert, latent_insert_in_place)
-from .paged_attention import (NEG_INF, gather_pages, paged_insert_chunk_in_place,
+from .paged_attention import (NEG_INF, _decode_heads_per_block,
+                              _decode_live_blocks, _walk_buffers,
+                              gather_pages, paged_insert_chunk_in_place,
                               paged_insert_kv, paged_prefill_attention)
 
 _INT_MIN = -2 ** 31
@@ -297,7 +305,9 @@ def gathered_decode_attention(q: jax.Array, pool_k: jax.Array,
                               pool_v: jax.Array, layer, phys: jax.Array,
                               offset: jax.Array, total: jax.Array
                               ) -> jax.Array:
-    """One query a slot over its SELECTED rows. q [B, H, Dh]; the stacked
+    """The plain form of :func:`selected_decode_attention` (what it is
+    held to, and what the "reference" provider attends by): one query a
+    slot over its SELECTED rows, gathered. q [B, H, Dh]; the stacked
     pool sides [L, P, KV, page, Dh]; phys, offset [B, k]: where each
     selected token lies in layer ``layer``; total [B]: how many of the
     ``k`` places are real. The rows are gathered token by token — row
@@ -320,6 +330,156 @@ def gathered_decode_attention(q: jax.Array, pool_k: jax.Array,
     out = jnp.einsum("bhgs,bshd->bhgd", probs.astype(vals.dtype), vals,
                      preferred_element_type=jnp.float32)
     return out.reshape(B, H * Dh).astype(q.dtype)
+
+
+def selection_words(positions: jax.Array, total: jax.Array, n_pages: int,
+                    page: int) -> jax.Array:
+    """:func:`top_positions`' list as the mask the decode kernel reads:
+    int32 ``[B, n_pages, page]``, 1 at the ``total`` real places of a row's
+    list and 0 everywhere else — whatever the unreal places name. 32-bit
+    words, so that a page's row is one row of the resident block read at a
+    dynamic index. No scatter: a place is a (page, offset) pair, and the
+    product of the places' two one-hot rows, summed over the list, counts
+    the places that name each word (ONE matrix product a slot, ``[n_pages,
+    k] @ [k, page]``, exact in any float: XLA's scatter of the 16,384
+    places took 0.15 ms a layer on the chip and files under no scope)."""
+    k = positions.shape[1]
+    real = jnp.arange(k)[None, :] < total[:, None]
+    in_page = (positions // page)[:, :, None] == jnp.arange(n_pages)
+    at_offset = (positions % page)[:, :, None] == jnp.arange(page)
+    named = jnp.einsum("bjp,bjo->bpo",
+                       (in_page & real[:, :, None]).astype(jnp.bfloat16),
+                       at_offset.astype(jnp.bfloat16),
+                       preferred_element_type=jnp.float32)
+    return (named > 0.0).astype(jnp.int32)
+
+
+def _selected_decode_kernel(pt_ref, nkeys_ref, layer_ref, q_ref, keep_ref,
+                            k_pool, v_pool, o_ref, k_buf, v_buf, sem, *,
+                            page: int, n_table_pages: int):
+    """One program per group of folded KV heads walks EVERY slot's live
+    pages as the paged decode kernel does (ops/paged_attention.py
+    ``_paged_decode_kernel``: one sequence over (slot, page), two VMEM
+    buffers a side, a slot's last page prefetching the next slot's first)
+    and keeps a score where ``keep_ref`` [B, table pages, page] says so.
+    There is no self column — the query's own key is in the pool — so the
+    state starts empty, and a page with no kept key leaves it untouched:
+    its probabilities are ``where(kept, exp(s - m), 0)``."""
+    hb = pl.program_id(0)
+    B, heads, G, Dh = q_ref.shape
+    layer = layer_ref[0]
+
+    def n_live(b):
+        return _decode_live_blocks(nkeys_ref[b], page, 0,
+                                   n_table_pages)[1] + 1      # >= 1 page
+
+    def copies(b, lp, buf):
+        phys = pt_ref[b, lp]
+        return [pltpu.make_async_copy(
+            pool.at[layer, pl.ds(phys, 1), pl.ds(hb * heads, heads)],
+            vmem.at[buf], sem.at[buf, side])
+            for side, (pool, vmem) in enumerate(((k_pool, k_buf),
+                                                 (v_pool, v_buf)))]
+
+    for c in copies(0, 0, 0):
+        c.start()
+
+    def slot(b, walked):
+        n_pages = n_live(b)
+        q = q_ref[b]                                    # [heads, G, Dh]
+
+        def one_page(lp, state):
+            m, l, acc = state
+            buf = (walked + lp) % 2
+            ends = lp == n_pages - 1
+            nb = jnp.minimum(jnp.where(ends, b + 1, b), B - 1)
+            nlp = jnp.where(ends, 0, lp + 1)
+
+            @pl.when(jnp.logical_not(ends & (b == B - 1)))
+            def _prefetch():
+                for c in copies(nb, nlp, 1 - buf):
+                    c.start()
+            for c in copies(b, lp, buf):
+                c.wait()
+            k, v = k_buf[buf, 0], v_buf[buf, 0]         # [heads, page, Dh]
+            # ONE product in the operands' dtype, float32 accumulation.
+            s = jax.lax.dot_general(
+                q, k.astype(q.dtype), (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32) * Dh ** -0.5
+            kept = jnp.broadcast_to(
+                (keep_ref[b, pl.ds(lp, 1), :] != 0)[None], s.shape)
+            m_new = jnp.maximum(m, jnp.max(
+                jnp.where(kept, s, NEG_INF), axis=2, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.where(kept, jnp.exp(s - m_new), 0.0)
+            l = alpha * l + jnp.sum(p, axis=2, keepdims=True)
+            acc = acc * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)     # [heads, G, Dh]
+            return m_new, l, acc
+
+        _, l, acc = jax.lax.fori_loop(
+            0, n_pages, one_page,
+            (jnp.full((heads, G, 1), NEG_INF, jnp.float32),
+             jnp.zeros((heads, G, 1), jnp.float32),
+             jnp.zeros((heads, G, Dh), jnp.float32)))
+        o_ref[b] = (acc / jnp.where(l > 0.0, l, 1.0)).astype(o_ref.dtype)
+        return walked + n_pages
+
+    jax.lax.fori_loop(0, B, slot, 0)
+
+
+def selected_decode_attention(q: jax.Array, pool_k: jax.Array,
+                              pool_v: jax.Array, page_table: jax.Array,
+                              n_keys: jax.Array, keep: jax.Array, *,
+                              layer: jax.Array | int,
+                              interpret: bool | None = None) -> jax.Array:
+    """One query a slot over its SELECTED keys, read at stream speed: ONE
+    Pallas call walks each slot's live pages of layer ``layer`` of the
+    stacked pool sides [L, P, KV, page, Dh] where they lie — whole pages,
+    all the KV heads that fit a block (:func:`_decode_heads_per_block`: 4
+    of 4 at bfloat16), a page a copy a side (no packed-table promise is
+    handed here) — and attends the keys ``keep`` marks. q [B, H, Dh];
+    page_table [B, NP]; n_keys [B]: the keys a slot holds, the query's own
+    among them (its pages ``ceil(n_keys / page)`` are walked); keep int32
+    [B, NP, page], nonzero where the query attends
+    (:func:`selection_words`). The numbers are
+    :func:`gathered_decode_attention`'s: q . K one product in q's dtype
+    with float32 accumulation, a float32 softmax over the kept scores (here
+    online, a page at a time), the probabilities rounded to the pool's
+    dtype before the V product. Returns [B, H * Dh] in q's dtype.
+
+    The chip cannot copy less than 8 tokens of a head out of this layout
+    (a tile), so a read bounded by ``k`` is not to be had from it; whole
+    pages stream at the paged decode kernel's rate, which beats XLA's
+    row-by-row gather while a slot holds under ~60k keys (PERF.md, PR 52)."""
+    B, H, Dh = q.shape
+    KV, page = pool_k.shape[2], pool_k.shape[3]
+    NP, G = page_table.shape[1], H // KV
+    heads = _decode_heads_per_block(KV, page, Dh, pool_k.dtype.itemsize,
+                                    False, 1)
+    pools, buffers = _walk_buffers(pool_k, pool_v, 1, heads)
+    out = pl.pallas_call(
+        functools.partial(_selected_decode_kernel, page=page,
+                          n_table_pages=NP),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(KV // heads,),
+            in_specs=[pl.BlockSpec((B, heads, G, Dh),
+                                   lambda hb, pt, nk, ly: (0, hb, 0, 0)),
+                      pl.BlockSpec((B, NP, page),
+                                   lambda hb, pt, nk, ly: (0, 0, 0)),
+                      *[pl.BlockSpec(memory_space=pl.ANY)] * len(pools)],
+            out_specs=pl.BlockSpec((B, heads, G, Dh),
+                                   lambda hb, pt, nk, ly: (0, hb, 0, 0)),
+            scratch_shapes=[*buffers,
+                            pltpu.SemaphoreType.DMA((2, len(pools)))]),
+        out_shape=jax.ShapeDtypeStruct((B, KV, G, Dh), q.dtype),
+        interpret=(_paged._interpret_default() if interpret is None
+                   else interpret),
+    )(page_table.astype(jnp.int32), n_keys.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), q.reshape(B, KV, G, Dh),
+      keep.astype(jnp.int32), *pools)
+    return out.reshape(B, H * Dh)
 
 
 def masked_attention_reference(q: jax.Array, dense_k: jax.Array,
@@ -345,8 +505,9 @@ class SparseAttention:
     traced page table as the other groups' providers are. ``pool`` is the
     group's three stacked sides ``(K, V, index keys)``, which both step
     programs carry through their layer scan. ``impl`` "pallas": the
-    in-place writes, the gathered decode and the masked page walk;
-    "reference": XLA scatters and a gathered dense view (CPU tests). Both
+    in-place writes, the decode kernel over the selection's mask and the
+    masked page walk; "reference": XLA scatters, the gathered rows of a
+    decode step and a gathered dense view of a chunk (CPU tests). Both
     insert, then attend, over the same selection."""
 
     def __init__(self, page_table: jax.Array, max_seq: int, topk: int,
@@ -408,15 +569,23 @@ class SparseAttention:
     def attend(self, q, pool, layer, start, selected):
         """q [B, T, H, Dh] (rotated) at positions ``start + t`` over what
         :meth:`select` gave, in the WRITTEN pool -> [B, T, H * Dh]. One
-        query a row: the listed rows alone are gathered and read. A chunk:
-        the dense page walk, masked by ``keep``."""
+        query a row: the walk of the row's live pages (its own key's among
+        them: ``start + 1`` keys) under the list as a mask — the plain
+        form gathers the listed rows. A chunk: the dense page walk, masked
+        by ``keep``."""
         pool_k, pool_v, pool_i = pool
         if q.shape[1] == 1:
             positions, total = selected
             page = pool_i.shape[-1]
-            phys = jnp.take_along_axis(self.page_table, positions // page,
-                                       axis=1)
             with jax.named_scope("attention.sparse_decode"):
+                if self.impl == "pallas":
+                    keep = selection_words(
+                        positions, total, self.page_table.shape[1], page)
+                    return selected_decode_attention(
+                        q[:, 0], pool_k, pool_v, self.page_table, start + 1,
+                        keep, layer=layer, interpret=self.interpret)[:, None]
+                phys = jnp.take_along_axis(self.page_table,
+                                           positions // page, axis=1)
                 return gathered_decode_attention(
                     q[:, 0], pool_k, pool_v, layer, phys, positions % page,
                     total)[:, None]

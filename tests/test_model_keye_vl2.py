@@ -5,6 +5,7 @@ a page pool with an index-key side) against the plain reference
 preset, where 24 keys are kept of contexts of 96-160: logits, not tokens.
 Every tolerance says where it comes from."""
 import dataclasses
+import types
 
 import jax
 import jax.numpy as jnp
@@ -238,6 +239,147 @@ def test_a_decode_steps_list_is_the_selected_set():
     for b in range(3):
         assert sorted(listed[b, :total[b]].tolist()) == \
             np.flatnonzero(keep[b]).tolist()
+
+
+def _decode_pool(rng, slots: int, table_pages: int, page: int, dtype):
+    """(table [slots, table_pages] over shuffled pages, K and V sides of two
+    layers of 2 KV heads of 16, q of 8 heads) for the decode kernel's
+    cases."""
+    pages = slots * table_pages + 1
+    table = jnp.asarray(rng.permutation(np.arange(1, pages)).reshape(
+        slots, table_pages).astype(np.int32))
+    pool_k, pool_v = (jnp.asarray(rng.normal(size=(2, pages, 2, page, 16)),
+                                  dtype) for _ in range(2))
+    return table, pool_k, pool_v, jnp.asarray(
+        rng.normal(size=(slots, 8, 16)), dtype)
+
+
+def _first_page_last(scores, page):
+    """The first page holds the LEAST scores: no key of it is kept."""
+    scores[:, :page] -= 100.0
+    return scores
+
+
+def _ties_over_a_boundary(scores, page):
+    """Equal scores from four positions before a page's end to four past
+    it, the greatest but for ``k - 4`` others: the k-th is among them."""
+    scores[:, 6 * page - 4:6 * page + 4] = 50.0
+    scores[:, :20] = 60.0
+    return scores
+
+
+DECODE_CASES = {
+    # name: (keys a slot holds, k, page, what is done to the scores, dtype)
+    "under_k": ((5, 23, 9), 24, 8, None, jnp.float32),
+    "exactly_k": ((24, 24), 24, 8, None, jnp.float32),
+    "ragged": ((1, 255, 256, 257, 5000), 256, 256, None, jnp.float32),
+    "ragged_bfloat16": ((1, 255, 256, 257, 5000), 256, 256, None,
+                        jnp.bfloat16),
+    "first_page_empty": ((90, 41), 24, 8, _first_page_last, jnp.float32),
+    "ties_over_a_boundary": ((90, 33), 24, 8, _ties_over_a_boundary,
+                             jnp.float32),
+    "inactive_slot": ((70, 1, 12), 24, 8, None, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("case", list(DECODE_CASES))
+def test_the_decode_kernel_reads_what_the_gathered_form_reads(case):
+    """``selected_decode_attention`` (one kernel over a slot's live pages
+    under the selection's mask; interpreted here) against
+    ``gathered_decode_attention`` over the SAME ``(positions, total)`` of
+    ``top_positions``, on shuffled pages of a two-layer pool: a context
+    under ``k`` (every key kept), at ``k``, lengths either side of a page's
+    end and twenty pages long, a first live page none of whose keys is kept
+    (the state must pass it untouched), the k-th score tied across a page
+    boundary (the set is ``top_positions``', ties and all), and a slot that
+    is not active (one key, a table row of the trash page). float32: the
+    two differ by the order of their sums; bfloat16: by the rounding of
+    the probabilities before the V product, 2 ** -8 of values of size ~1."""
+    lengths, k, page, spoil, dtype = DECODE_CASES[case]
+    rng = np.random.default_rng(len(case))
+    table_pages = -(-max(lengths) // page) + 1
+    table, pool_k, pool_v, q = _decode_pool(rng, len(lengths), table_pages,
+                                            page, dtype)
+    if case == "inactive_slot":
+        table = table.at[1].set(0)
+    S = table_pages * page
+    scores = rng.normal(size=(len(lengths), S)).astype(np.float32)
+    if spoil is not None:
+        scores = spoil(scores, page)
+    n_keys = jnp.asarray(lengths, jnp.int32)
+    seen = jnp.arange(S)[None, :] < n_keys[:, None]
+    positions, total = sa.top_positions(jnp.asarray(scores), seen, k)
+    assert total.tolist() == [min(n, k) for n in lengths]
+    keep = sa.selection_words(positions, total, table_pages, page)
+    assert keep.shape == (len(lengths), table_pages, page)
+    assert (keep.sum((1, 2)) == total).all()
+    assert (np.asarray(keep.reshape(len(lengths), S) != 0)
+            == np.asarray(sa.top_mask(jnp.asarray(scores), seen, k))).all()
+    # Whatever the unreal places name: position 0 (the benchmark's padding).
+    padded = jnp.where(jnp.arange(positions.shape[1])[None, :]
+                       < total[:, None], positions, 0)
+    assert (sa.selection_words(padded, total, table_pages, page)
+            == keep).all()
+    if case == "first_page_empty":
+        assert int(keep[:, 0].sum()) == 0
+    if case == "ties_over_a_boundary":
+        # Four of the eight tied keys are kept: the lower positions.
+        assert keep[0, 5, -4:].tolist() == [1] * 4
+        assert int(keep[0, 6].sum()) == 0
+    phys = jnp.take_along_axis(table, positions // page, axis=1)
+    want = sa.gathered_decode_attention(q, pool_k, pool_v, 1, phys,
+                                        positions % page, total)
+    got = jax.jit(lambda q, pk, pv, keep: sa.selected_decode_attention(
+        q, pk, pv, table, n_keys, keep, layer=jnp.int32(1),
+        interpret=True))(q, pool_k, pool_v, keep)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=tol, rtol=0)
+
+
+def test_a_mask_of_every_seen_key_is_plain_causal_attention():
+    """With every key a slot holds kept — all ones over a slot of whole
+    pages — the decode kernel IS plain attention over the pool: a softmax
+    over the slot's context, the keys read through the page table."""
+    rng = np.random.default_rng(52)
+    lengths, page, table_pages = (64, 37, 1), 8, 9
+    table, pool_k, pool_v, q = _decode_pool(rng, 3, table_pages, page,
+                                            jnp.float32)
+    S = table_pages * page
+    n_keys = jnp.asarray(lengths, jnp.int32)
+    seen = jnp.arange(S)[None, :] < n_keys[:, None]
+    keep = seen.astype(jnp.int32).reshape(3, table_pages, page)
+    assert bool(keep[0, :8].all())
+    got = sa.selected_decode_attention(q, pool_k, pool_v, table, n_keys,
+                                       keep, layer=0, interpret=True)
+    dense_k, dense_v = (sa.gather_pages(side[0], table, S)
+                        for side in (pool_k, pool_v))
+    want = sa.masked_attention_reference(q[:, None], dense_k, dense_v,
+                                         seen[:, None])[:, 0]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5,
+                               rtol=0)
+
+
+def test_the_pages_a_decode_read_walks_are_counted_beside_its_keys():
+    """``_count_decode_keys`` under an indexer: a burst of two steps of
+    slots at 0, 255, 256 and 5,000 cached tokens (one of a fifth slot not
+    active) adds each step's context and itself to the scored keys,
+    ``min(that, k)`` to the selected ones and ``ceil(that / page)`` to the
+    pages the read walked — ONE layer's, as ``stats()`` hands them out."""
+    from llmapigateway_tpu.engine.engine import InferenceEngine
+    eng = types.SimpleNamespace(
+        lengths=np.asarray([0, 255, 9, 256, 5000]),
+        active=np.asarray([True, True, False, True, True]),
+        model_cfg=TINY, kv_page=256, _lin_decode_state_updates=0,
+        kv_groups=[types.SimpleNamespace(kind="kv", window=0)],
+        _dsa_decode_keys={"scored": 0, "selected": 0, "pages_walked": 0})
+    InferenceEngine._count_decode_keys(eng, 2)
+    seen = [n + i for n in (0, 255, 256, 5000) for i in (1, 2)]
+    assert eng._dsa_decode_keys == {
+        "scored": sum(seen),
+        "selected": sum(min(n, TINY.idx_topk) for n in seen),
+        "pages_walked": 1 + 1 + 1 + 2 + 2 + 2 + 20 + 20}
 
 
 def test_equal_streams_are_the_engines_rotary_and_unequal_ones_are_not():
