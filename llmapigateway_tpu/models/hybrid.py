@@ -979,9 +979,11 @@ def sparse_block(x: jax.Array, lp: Params, c: ModelConfig, pool: tuple,
     ``ops.sparse_attention.SparseAttention``. Insert, then select, then
     attend the selected keys only: the same three steps in both step
     programs (a decode step and a chunk both walk the live pages under the
-    selection's mask, each in its own kernel). A row that is not ``active``
-    writes to the trash page and attends from position 0; what it returns
-    is not looked at."""
+    selection's mask, each in its own kernel; the "pallas" provider's
+    decode step also SELECTS by a kernel that hands the read its mask
+    words, ``select_words``, where every other call takes ``select``'s plain
+    form). A row that is not ``active`` writes to the trash page and attends
+    from position 0; what it returns is not looked at."""
     B, T, _ = x.shape
     dh = c.head_dim
     start = lengths if active is None else jnp.where(active, lengths, 0)
@@ -1000,7 +1002,9 @@ def sparse_block(x: jax.Array, lp: Params, c: ModelConfig, pool: tuple,
         q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
         pool = fn.write(pool, k, v, ki, layer, lengths, active)
     with jax.named_scope("attn.index"):
-        selected = fn.select(qi, w, pool[2], layer, start)
+        select = (fn.select_words if T == 1 and fn.impl == "pallas"
+                  else fn.select)
+        selected = select(qi, w, pool[2], layer, start)
     with jax.named_scope("attn.sparse"):
         attn = fn.attend(q, pool, layer, start, selected)
         return mm(attn, lp["wo"]), pool

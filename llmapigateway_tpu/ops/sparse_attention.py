@@ -26,18 +26,27 @@ programs: a call's own keys are read back as the bytes that were written.
 
 Two forms of the attention, one selection:
 
-* DECODE (one query a slot): scores over the slot's index keys read
-  through the page table (``W`` numbers a cached token, not its K/V rows),
-  the exact top-``k`` as a list of positions (``lax.top_k`` over the
-  slots' one row each), that list as a mask of 32-bit words a table
-  position (:func:`selection_words`), and ONE kernel that walks the slot's
-  live K and V pages and keeps a score where the mask says so
-  (:func:`selected_decode_attention`). The walk reads every live page, not
-  ``k`` rows: this layout gives up nothing smaller than a tile of 8 tokens
-  of a head, XLA's row-by-row gather of the selected rows
-  (:func:`gathered_decode_attention`, the plain form the kernel is held to
-  and what the "reference" provider attends by) ran at 11 ns a 256-byte
-  row, and whole pages stream at 600-665 GB/s (PERF.md, PR 52).
+* DECODE (one query a slot): ONE kernel over the slots' LIVE index-key
+  pages (:func:`decode_select`) — the scores (``W`` numbers a cached
+  token, not its K/V rows), the exact ``k``-th largest a slot by the chunk
+  kernel's counting passes with the slots as the rows of one program, and
+  the selection as 32-bit words a table position, 1 where the query
+  attends: no scores over the table's dead entries, no sort, no list —
+  and ONE kernel that walks the slot's live K and V pages and keeps a
+  score where those words say so (:func:`selected_decode_attention`). The
+  walk reads every live page, not ``k`` rows: this layout gives up nothing
+  smaller than a tile of 8 tokens of a head, XLA's row-by-row gather of
+  the selected rows (:func:`gathered_decode_attention`, the plain form the
+  kernel is held to and what the "reference" provider attends by) ran at
+  11 ns a 256-byte row, and whole pages stream at 600-665 GB/s (PERF.md,
+  PR 52). The plain form of the selection is a LIST: scores over every
+  table position, ``lax.top_k``'s sort of a slot's one row
+  (:func:`top_positions`), and that list as the same words
+  (:func:`selection_words`) — what the kernel is held to bit for bit, what
+  ``SparseAttention.select`` still answers one query with (the benchmark's
+  reference unpacks it) and what was served until PR 53, at 0.33 ms a
+  layer on the chip where the read it fed took 0.5 and the kernel takes
+  0.05 (PERF.md, PR 53).
 * PREFILL (a chunk of queries a row): ONE kernel over the row's live
   index-key pages (:func:`index_select`) — the scores, the exact ``k``-th
   largest a query by BISECTION on the float's bits (32 counting passes, no
@@ -104,9 +113,12 @@ def top_positions(scores: jax.Array, seen: jax.Array, k: int
     """scores float32 [..., S], seen bool [..., S] -> (the ``k`` selected
     positions int32 [..., k], how many of them are real [...]): the seen
     positions of largest score through ``lax.top_k`` (the lower index of
-    equal scores first; a sort, 0.29 ms at 8 x 32,768 on the chip where a
-    bisection and a sort-free compaction of its mask took 0.81). A row that
-    sees fewer than ``k`` lists them first; the rest of its places name
+    equal scores first; a sort, 0.29 ms at 8 x 32,768 on the chip). The
+    plain form of a decode step's selection: while decode GATHERED its rows
+    it needed this list, and a bisection with a sort-free compaction of its
+    mask into one took 0.81; since the read takes a mask (PR 52) the served
+    step compacts nothing and sorts nothing (:func:`decode_select`). A row
+    that sees fewer than ``k`` lists them first; the rest of its places name
     unseen positions and are not real."""
     scores = jnp.where(scores == 0.0, 0.0, scores)      # -0.0 IS 0.0
     k = min(k, scores.shape[-1])
@@ -137,6 +149,61 @@ def top_mask(scores: jax.Array, seen: jax.Array, k: int) -> jax.Array:
 _SELECT_BLOCK_T = 32
 _SELECT_PAGES_PER_STEP = 4
 _SELECT_VMEM_LIMIT_BYTES = 48 * 2 ** 20
+
+
+def _positions(block, rows: int, width: int) -> jax.Array:
+    """int32 [rows, width]: the positions of block ``block`` of ``width``."""
+    return block * width + jax.lax.broadcasted_iota(jnp.int32, (rows, width),
+                                                    1)
+
+
+def _kth_by_counting(keys_at, n_blocks, rows: int, width: int, topk: int
+                     ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """The exact top-``topk`` of ``rows`` rows of sortable int32 keys with no
+    sort, for BOTH kernels: ``keys_at(i)`` [rows, width] is block ``i`` of
+    ``n_blocks`` (the least int32 where a row cannot see). Returns, [rows, 1]
+    each: the ``topk``-th largest key bit by bit, from the sign down (32
+    counting passes: the largest ``x`` that at least ``topk`` keys reach;
+    the least int32 where a row sees fewer), the places left for the keys AT
+    that value, and among those the position of the last one there is room
+    for (20 more passes: positions fit 15 bits and more; 20 covers a 1M
+    context)."""
+    def count(pred):
+        """[rows, 1]: how many positions of each row ``pred(keys of a
+        block, the block)`` holds for."""
+        def body(i, acc):
+            return acc + pred(keys_at(i), i).astype(jnp.int32)
+        acc = jax.lax.fori_loop(0, n_blocks, body,
+                                jnp.zeros((rows, width), jnp.int32))
+        return jnp.sum(acc, axis=1, keepdims=True)
+
+    def reach(x):
+        return count(lambda keys, i: keys >= x) >= topk
+
+    zero = jnp.zeros((rows, 1), jnp.int32)
+    kth = jnp.where(reach(zero), zero, _INT_MIN)
+
+    def bit(i, x):
+        cand = x | jnp.left_shift(jnp.int32(1), 30 - i)
+        return jnp.where(reach(cand), cand, x)
+    kth = jax.lax.fori_loop(0, 31, bit, kth)
+    room = topk - count(lambda keys, i: keys > kth)
+
+    def place(i, x):
+        cand = x + jnp.left_shift(jnp.int32(1), 19 - i)
+        short = count(lambda keys, i: (keys == kth)
+                      & (_positions(i, rows, width) < cand)) < room
+        return jnp.where(short, cand, x)
+    return kth, room, jax.lax.fori_loop(0, 20, place, zero)
+
+
+def _kept(keys, block, kth, room, last) -> jax.Array:
+    """bool: the keys of block ``block`` that :func:`_kth_by_counting`'s
+    three numbers select — every key above the k-th, and of those AT it the
+    seen ones up to the last place."""
+    return (keys > kth) | ((keys == kth)
+                           & (_positions(block, *keys.shape) <= last)
+                           & (room > 0) & (keys > _INT_MIN))
 
 
 def _index_select_kernel(pt_ref, start_ref, layer_ref, q_ref, w_ref,
@@ -176,7 +243,7 @@ def _index_select_kernel(pt_ref, start_ref, layer_ref, q_ref, w_ref,
         return pl.ds(pl.multiple_of(lp * page, page), page)
 
     def position(lp):
-        return lp * page + jax.lax.broadcasted_iota(jnp.int32, (bt, page), 1)
+        return _positions(lp, bt, page)
 
     q_pos = first_q + jax.lax.broadcasted_iota(jnp.int32, (bt, page), 0)
 
@@ -208,39 +275,11 @@ def _index_select_kernel(pt_ref, start_ref, layer_ref, q_ref, w_ref,
         return carry
     jax.lax.fori_loop(0, n_steps, step, 0)
 
-    def count(pred):
-        """[bt, 1]: how many live positions of each query ``pred(keys of a
-        page, the page)`` holds for."""
-        def body(lp, acc):
-            return acc + pred(keys_ref[:, columns(lp)], lp).astype(jnp.int32)
-        acc = jax.lax.fori_loop(0, n_live, body,
-                                jnp.zeros((bt, page), jnp.int32))
-        return jnp.sum(acc, axis=1, keepdims=True)
-
-    def reach(x):
-        return count(lambda keys, lp: keys >= x) >= topk
-
-    zero = jnp.zeros((bt, 1), jnp.int32)
-    kth = jnp.where(reach(zero), zero, _INT_MIN)
-
-    def bit(i, x):
-        cand = x | jnp.left_shift(jnp.int32(1), 30 - i)
-        return jnp.where(reach(cand), cand, x)
-    kth = jax.lax.fori_loop(0, 31, bit, kth)
-    room = topk - count(lambda keys, lp: keys > kth)
-    # Among the keys AT the k-th value: the least position that ``room`` of
-    # them reach (positions fit 15 bits and more; 20 covers a 1M context).
-    def place(i, x):
-        cand = x + jnp.left_shift(jnp.int32(1), 19 - i)
-        short = count(lambda keys, lp: (keys == kth)
-                      & (position(lp) < cand)) < room
-        return jnp.where(short, cand, x)
-    last = jax.lax.fori_loop(0, 20, place, zero)
+    kth, room, last = _kth_by_counting(
+        lambda lp: keys_ref[:, columns(lp)], n_live, bt, page, topk)
 
     def keep(lp, carry):
-        keys = keys_ref[:, columns(lp)]
-        kept = (keys > kth) | ((keys == kth) & (position(lp) <= last)
-                               & (room > 0) & (keys > _INT_MIN))
+        kept = _kept(keys_ref[:, columns(lp)], lp, kth, room, last)
         keep_ref[0, :, columns(lp)] = kept.astype(jnp.int8)
         return carry
     jax.lax.fori_loop(0, n_live, keep, 0)
@@ -295,6 +334,163 @@ def index_select(qi: jax.Array, w: jax.Array, pool_i: jax.Array,
                    else interpret),
     )(page_table.astype(jnp.int32), start.astype(jnp.int32),
       jnp.asarray(layer, jnp.int32).reshape(1), qb, wb, pool_i)
+
+
+# ---------------------------------------------------------------------------
+# A decode step's scores and selection as ONE kernel over the live pages
+# ---------------------------------------------------------------------------
+
+# Pages the decode kernel copies a step (its copies in flight: a page of the
+# index side is 32 KB, and one step ahead of 4 / 8 / 16 of them walked 8
+# slots of 20k keys in 0.089 / 0.065 / 0.055 ms on the chip, PERF.md PR 53),
+# and never more than an eighth of a slot's table: a slot's last step copies
+# its last page again in every place past it.
+_DECODE_SELECT_PAGES_PER_STEP = 16
+
+
+def _decode_select_kernel(pt_ref, start_ref, layer_ref, q_ref, w_ref,
+                          pool_ref, keep_ref, buf, keys_ref, sem,
+                          *, heads: int, page: int, ppb: int, topk: int,
+                          n_table_pages: int):
+    """ONE program, the SLOTS its rows: walks every slot's live index-key
+    pages in one sequence over (slot, step) — ``ppb`` pages a step side by
+    side in one of two VMEM buffers while the step before is scored, a
+    slot's last step prefetching the next slot's first; the walk is bound
+    by its copies in flight, so a step is many pages and holds no branch —
+    and scores a step as the chunk kernel scores a page (one product in the
+    pool's dtype, float32 accumulation, ``relu``, times ``w``, the heads
+    summed in their order), leaving slot ``b``'s sortable int32 keys in row
+    ``b`` of ``keys_ref`` [slots, positions] (the least int32 where it
+    cannot see). Then the chunk kernel's counting passes over the LONGEST
+    slot's live blocks, all slots a pass (:func:`_kth_by_counting`), and
+    the selection as int32 words, a row of ``keep_ref`` [slots, table
+    pages, page] a live page; every dead page reads 0."""
+    B = q_ref.shape[0]
+    width = ppb * page
+    layer = layer_ref[0]
+
+    def n_live(b):
+        return jnp.minimum(start_ref[b] // page + 1, n_table_pages)
+
+    def n_steps(b):
+        return (n_live(b) + ppb - 1) // ppb
+
+    def block(i):
+        return pl.ds(pl.multiple_of(i * width, width), width)
+
+    def copies(b, step, slot):
+        # A step's pages past the slot's last are its last page again: every
+        # step starts and waits for ``ppb`` copies, no branch among them.
+        last = n_live(b) - 1
+        return [pltpu.make_async_copy(
+            pool_ref.at[layer, pt_ref[b, jnp.minimum(step * ppb + sub,
+                                                     last)]],
+            buf.at[slot, :, pl.ds(sub * page, page)], sem.at[slot, sub])
+            for sub in range(ppb)]
+
+    def start(b, step, slot):
+        for c in copies(b, step, slot):
+            c.start()
+
+    # The longest slot's blocks are what a counting pass reads of EVERY
+    # slot: a shorter one's rows read the least int32 past its own steps.
+    n_blocks = n_steps(0)
+    for b in range(1, B):
+        n_blocks = jnp.maximum(n_blocks, n_steps(b))
+
+    def unseen(i, carry):
+        keys_ref[:, block(i)] = jnp.full((B, width), _INT_MIN, jnp.int32)
+        return carry
+    jax.lax.fori_loop(0, n_blocks, unseen, 0)
+
+    start(0, 0, 0)
+
+    def slot_walk(b, walked):
+        steps = n_steps(b)
+
+        def step(i, carry):
+            slot = (walked + i) % 2
+            ends = i == steps - 1
+            nb = jnp.minimum(jnp.where(ends, b + 1, b), B - 1)
+
+            @pl.when(jnp.logical_not(ends & (b == B - 1)))
+            def _prefetch():
+                start(nb, jnp.where(ends, 0, i + 1), 1 - slot)
+            for c in copies(b, i, slot):
+                c.wait()
+            s = jnp.dot(q_ref[b], buf[slot],
+                        preferred_element_type=jnp.float32)  # [heads, width]
+            s = jnp.maximum(s, 0.0) * w_ref[b]
+            acc = s[0:1]
+            for j in range(1, heads):
+                acc = acc + s[j:j + 1]
+            keys_ref[pl.ds(b, 1), block(i)] = jnp.where(
+                _positions(i, 1, width) <= start_ref[b], sortable(acc),
+                _INT_MIN)
+            return carry
+        jax.lax.fori_loop(0, steps, step, 0)
+        return walked + steps
+    jax.lax.fori_loop(0, B, slot_walk, 0)
+
+    kth, room, last = _kth_by_counting(lambda i: keys_ref[:, block(i)],
+                                       n_blocks, B, width, topk)
+
+    def words(i, carry):
+        kept = _kept(keys_ref[:, block(i)], i, kth, room, last)
+        keys_ref[:, block(i)] = kept.astype(jnp.int32)
+        return carry
+    jax.lax.fori_loop(0, n_blocks, words, 0)
+
+    keep_ref[...] = jnp.zeros(keep_ref.shape, jnp.int32)
+
+    def live_pages(b, carry):
+        def live_page(lp, carry):
+            keep_ref[b, pl.ds(lp, 1), :] = keys_ref[
+                pl.ds(b, 1), pl.ds(pl.multiple_of(lp * page, page), page)]
+            return carry
+        return jax.lax.fori_loop(0, n_live(b), live_page, carry)
+    jax.lax.fori_loop(0, B, live_pages, 0)
+
+
+def decode_select(qi: jax.Array, w: jax.Array, pool_i: jax.Array,
+                  page_table: jax.Array, start: jax.Array, *,
+                  layer: jax.Array | int, topk: int,
+                  interpret: bool | None = None) -> jax.Array:
+    """A decode step's selection in one Pallas call: ONE index query a slot
+    qi [B, J, W] with weights w [B, J] at position ``start`` over layer
+    ``layer`` of the index-key side ``[L, P, W, page]``, read through
+    page_table [B, NP] -> int32 words [B, NP, page], 1 where the query
+    attends and 0 at every other place, dead pages among them: exactly
+    ``selection_words(*top_positions(index_scores(...), seen, topk), NP,
+    page)``, what :func:`selected_decode_attention` takes as ``keep``. The
+    query's own key is in the pool (insert, then select): a slot's
+    ``start // page + 1`` live pages are read and nothing of the table's
+    dead entries; a slot at ``start`` 0 keeps position 0 alone. No sort, no
+    list: the chunk kernel's scores and counting passes with the slots as
+    the rows of one program."""
+    B, J, W = qi.shape
+    page, NP = pool_i.shape[-1], page_table.shape[1]
+    ppb = min(_DECODE_SELECT_PAGES_PER_STEP, max(1, NP // 8))
+    positions = -(-NP // ppb) * ppb * page      # whole steps of pages
+    return pl.pallas_call(
+        functools.partial(_decode_select_kernel, heads=J, page=page, ppb=ppb,
+                          topk=topk, n_table_pages=NP),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(1,),
+            in_specs=[pl.BlockSpec((B, J, W), lambda i, pt, st, ly: (0, 0, 0)),
+                      pl.BlockSpec((B, J, 1), lambda i, pt, st, ly: (0, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((B, NP, page),
+                                   lambda i, pt, st, ly: (0, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((2, W, ppb * page), pool_i.dtype),
+                            pltpu.VMEM((B, positions), jnp.int32),
+                            pltpu.SemaphoreType.DMA((2, ppb))]),
+        out_shape=jax.ShapeDtypeStruct((B, NP, page), jnp.int32),
+        interpret=(_paged._interpret_default() if interpret is None
+                   else interpret),
+    )(page_table.astype(jnp.int32), start.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), qi.astype(pool_i.dtype),
+      w.astype(jnp.float32)[..., None], pool_i)
 
 
 # ---------------------------------------------------------------------------
@@ -505,10 +701,12 @@ class SparseAttention:
     traced page table as the other groups' providers are. ``pool`` is the
     group's three stacked sides ``(K, V, index keys)``, which both step
     programs carry through their layer scan. ``impl`` "pallas": the
-    in-place writes, the decode kernel over the selection's mask and the
-    masked page walk; "reference": XLA scatters, the gathered rows of a
-    decode step and a gathered dense view of a chunk (CPU tests). Both
-    insert, then attend, over the same selection."""
+    in-place writes, a selection kernel and a read kernel in each step
+    program (a decode step's mask words and the walk under them, a chunk's
+    mask and the masked page walk); "reference": XLA scatters, the sorted
+    list and the gathered rows of a decode step and a gathered dense view
+    of a chunk (CPU tests). Both insert, then attend, over the same
+    selection."""
 
     def __init__(self, page_table: jax.Array, max_seq: int, topk: int,
                  impl: str = "pallas", interpret: bool | None = None):
@@ -547,11 +745,14 @@ class SparseAttention:
     def select(self, qi, w, pool_i, layer, start):
         """The queries at positions ``start + t`` over the WRITTEN index
         keys -> what each attends. One query a row: :func:`top_positions`'
-        (positions [B, k], how many are real [B]). A chunk: ``keep`` bool
-        [B, T, S] (a position the query cannot see is never kept) — one
-        kernel over the live pages (:func:`index_select`); the plain
-        :func:`top_mask` of :meth:`scores` for the "reference" provider and
-        a chunk that is not whole row-blocks. The same set in each."""
+        (positions [B, k], how many are real [B]) of :meth:`scores` — the
+        PLAIN list form for either provider (the "reference" provider's
+        decode step, and what the served kernel, :meth:`select_words`, is
+        held to). A chunk: ``keep`` bool [B, T, S] (a position the query
+        cannot see is never kept) — one kernel over the live pages
+        (:func:`index_select`); the plain :func:`top_mask` of :meth:`scores`
+        for the "reference" provider and a chunk that is not whole
+        row-blocks. The same set in each."""
         T = qi.shape[1]
         if self.impl == "pallas" and T >= 8 and T % min(
                 _SELECT_BLOCK_T, T) == 0:
@@ -566,24 +767,38 @@ class SparseAttention:
             return top_positions(scores[:, 0], seen[:, 0], self.topk)
         return top_mask(scores, seen, self.topk)
 
+    def select_words(self, qi, w, pool_i, layer, start):
+        """What a DECODE step of the "pallas" provider selects by: ONE
+        query a row, qi [B, 1, J, W] with weights w [B, 1, J] at position
+        ``start``, over the WRITTEN index keys -> :meth:`select`'s set as
+        the read kernel's mask, int32 words [B, NP, page] — one kernel over
+        the slots' live pages (:func:`decode_select`), no scores over the
+        table, no sort, no list."""
+        with jax.named_scope("attention.index_decode"):
+            return decode_select(qi[:, 0], w[:, 0], pool_i, self.page_table,
+                                 start, layer=layer, topk=self.topk,
+                                 interpret=self.interpret)
+
     def attend(self, q, pool, layer, start, selected):
         """q [B, T, H, Dh] (rotated) at positions ``start + t`` over what
-        :meth:`select` gave, in the WRITTEN pool -> [B, T, H * Dh]. One
-        query a row: the walk of the row's live pages (its own key's among
-        them: ``start + 1`` keys) under the list as a mask — the plain
-        form gathers the listed rows. A chunk: the dense page walk, masked
-        by ``keep``."""
+        :meth:`select` or :meth:`select_words` gave, in the WRITTEN pool ->
+        [B, T, H * Dh]. One query a row: the walk of the row's live pages
+        (its own key's among them: ``start + 1`` keys) under the selection's
+        words — :meth:`select_words`' as they are, a ``(positions, total)``
+        list through :func:`selection_words` — where the plain form gathers
+        the listed rows. A chunk: the dense page walk, masked by ``keep``."""
         pool_k, pool_v, pool_i = pool
         if q.shape[1] == 1:
-            positions, total = selected
             page = pool_i.shape[-1]
             with jax.named_scope("attention.sparse_decode"):
                 if self.impl == "pallas":
                     keep = selection_words(
-                        positions, total, self.page_table.shape[1], page)
+                        *selected, self.page_table.shape[1], page) \
+                        if isinstance(selected, tuple) else selected
                     return selected_decode_attention(
                         q[:, 0], pool_k, pool_v, self.page_table, start + 1,
                         keep, layer=layer, interpret=self.interpret)[:, None]
+                positions, total = selected
                 phys = jnp.take_along_axis(self.page_table,
                                            positions // page, axis=1)
                 return gathered_decode_attention(

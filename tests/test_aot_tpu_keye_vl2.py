@@ -1,8 +1,9 @@
 """Compile for a described v5e what the indexed family (``keye_vl2``:
 softmax layers under a learned indexer, ops/sparse_attention.py) adds at
 ``keye-vl2-30b-ep4``'s served geometry: the ENGINE'S OWN ``decode_scan``
-(the walk of the selected keys' pages beside three in-place writes, no copy
-of a pool side),
+(the selection's kernel over the live index-key pages and the walk of the
+selected keys' pages beside three in-place writes: no sort, no gathered view
+of the index side, no copy of a pool side),
 and the two kernels of a prefill chunk — the selection and the masked page
 walk. A file of its own so that a worker can take it beside
 tests/test_aot_tpu_programs.py, whose fixtures and reader it borrows.
@@ -24,11 +25,16 @@ def test_the_indexed_familys_decode_scan_fits_the_chip_and_walks_pages(
     """The ENGINE'S OWN ``decode_scan`` at ``keye-vl2-30b-ep4``'s served
     geometry — 12 layers at the published widths, int8, 8 slots of 32,768
     positions, a pool of K, V and index-key sides — compiled for the
-    described chip: the compiler takes the two in-place writes and the
-    kernel that reads the selected keys (``selected_decode_attention``, a
-    custom call under ``attention.sparse_decode``), both scopes are in the
-    program, nothing in its loops is the size of a pool side but the
-    aliased writes' results, and arguments plus temporaries fit."""
+    described chip: the compiler takes the two in-place writes, the kernel
+    that selects (``decode_select``, a custom call under
+    ``attention.index_decode``: Mosaic takes its one-row stores at a slot's
+    row and a page's) and the kernel that reads the selected keys
+    (``selected_decode_attention``, a custom call under
+    ``attention.sparse_decode``), both scopes are in the program, the
+    indexer's scope holds NO sort and no gather — no scores over every table
+    position, nothing the shape of a slot's index keys over its whole table
+    row —, nothing in the loops is the size of a pool side but the aliased
+    writes' results, and arguments plus temporaries fit."""
     from llmapigateway_tpu.models import PRESETS, hybrid
     from step_programs import lower_step_program
 
@@ -46,8 +52,13 @@ def test_the_indexed_familys_decode_scan_fits_the_chip_and_walks_pages(
     text = compiled.as_text()
     assert "attn.index" in text and "attn.sparse" in text
     assert "attention.sparse_decode" in text and "kv.paged_insert" in text
-    assert any("tpu_custom_call" in ln and "attention.sparse_decode" in ln
-               for ln in text.splitlines())
+    lines = text.splitlines()
+    for kernel in ("attention.index_decode", "attention.sparse_decode"):
+        assert any("tpu_custom_call" in ln and kernel in ln for ln in lines)
+    indexer = [ln for ln in lines if "attn.index" in ln]
+    assert not [ln for ln in indexer if " sort(" in ln or "/top_k" in ln
+                or "/gather" in ln]
+    assert "[8,128,64,%d]" % PAGE not in text and "[8,32768," not in text
     side = 12 * pages * 64 * PAGE * 2           # the SMALLEST side, bytes
     made = [(n, op) for n, op, ln in _loop_arrays(text, side)
             if not (op == "custom-call" and "kv.paged_insert" in ln)]

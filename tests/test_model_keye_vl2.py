@@ -5,6 +5,7 @@ a page pool with an index-key side) against the plain reference
 preset, where 24 keys are kept of contexts of 96-160: logits, not tokens.
 Every tolerance says where it comes from."""
 import dataclasses
+import functools
 import types
 
 import jax
@@ -239,6 +240,134 @@ def test_a_decode_steps_list_is_the_selected_set():
     for b in range(3):
         assert sorted(listed[b, :total[b]].tolist()) == \
             np.flatnonzero(keep[b]).tolist()
+
+
+# A decode step's selection as ONE kernel (``decode_select``): six slots of
+# 20 table pages of 16, 4 index heads of 16, 24 keys kept, layer 1 of two —
+# one shape, so the cases share the interpreted kernel's compile.
+WORDS_PAGE, WORDS_PAGES, WORDS_K = 16, 20, 24
+WORDS_S = WORDS_PAGE * WORDS_PAGES
+
+
+def _every_fifth_is_its_neighbour(keys, w, live):
+    """Every fifth key IS its left neighbour's: equal scores all along, the
+    k-th among them (the lower position wins)."""
+    keys[:, 5::5] = keys[:, 4:-1:5]
+
+
+def _zero_scores_of_both_signs(keys, w, live):
+    """Half the keys hold nothing, so their scores are zeros and the largest
+    there are under weights that are all negative (rows 1 and 3): -0.0
+    there, 0.0 in the rows beside them whose weights have both signs."""
+    keys[:, ::2] = 0.0
+    w[1], w[3] = -0.5, -0.25
+    w[0, 0], w[2, 0], w[4, 0] = 1.0, 0.5, 1.0
+
+
+def _stale_keys_of_large_score(keys, w, live):
+    """Every position a slot does not hold yet — the rest of its last page
+    and every dead page of its table row — holds an earlier request's keys,
+    and they score far above anything live."""
+    keys[~live] *= 8.0
+
+
+WORDS_CASES = {
+    # name: (each slot's tokens before the call, what is done to the keys)
+    "contexts_under_at_and_over_k": ((0, 5, 23, 24, 100, 303), None),
+    "a_pages_last_column_and_its_first": ((15, 16, 31, 32, 159, 160), None),
+    "inactive_slots": ((0, 200, 0, 77, 0, 319), None),
+    "ties_at_the_threshold": ((4, 23, 24, 99, 250, 319),
+                              _every_fifth_is_its_neighbour),
+    "signed_zeros": ((40, 141, 100, 201, 300, 3), _zero_scores_of_both_signs),
+    "stale_dead_pages": ((0, 17, 23, 48, 150, 290),
+                         _stale_keys_of_large_score),
+    "unit_normal": ((0, 17, 24, 100, 250, 319), None),
+}
+
+
+@jax.jit
+def _both_selections(qi, w, pool, table, start):
+    """(the served kernel's words, the plain form's: ``lax.top_k``'s list of
+    scores over every table position as words, the plain scores)."""
+    kernel, plain = (sa.SparseAttention(table, WORDS_S, WORDS_K, impl,
+                                        interpret=True)
+                     for impl in ("pallas", "reference"))
+    listed = plain.select(qi, w, pool, 1, start)
+    return (kernel.select_words(qi, w, pool, 1, start),
+            sa.selection_words(*listed, WORDS_PAGES, WORDS_PAGE),
+            plain.scores(qi, w, pool, 1)[:, 0])
+
+
+@pytest.mark.parametrize("case", list(WORDS_CASES))
+def test_a_decode_steps_kernel_selects_what_the_list_selects(case):
+    """``SparseAttention.select_words`` (``decode_select``: ONE kernel over
+    the slots' live index-key pages — scores, the k-th largest by counting,
+    the mask words; interpreted here) against ``selection_words(
+    *top_positions(index_scores(...)))`` over every table position, on a
+    shuffled table of a two-layer pool whose every page holds keys. Small
+    dyadic numbers, so both forms' float32 sums are exact whatever their
+    order and the words are equal BIT FOR BIT: slots of different contexts
+    in one call, under ``k``, at it and past it, a context that ends on a
+    page's last column and one on its first, slots that are not active
+    (start 0: position 0 alone), ties at the threshold, zeros of both
+    signs, stale keys of large score wherever a slot holds nothing. On
+    unit-normal bfloat16 inputs the two may differ only where a score lies
+    within ``INDEX_SCORE_TOL`` of the slot's k-th: the harness's own rule."""
+    starts, spoil = WORDS_CASES[case]
+    rng = np.random.default_rng(len(case))
+    B, J, W, page, NP, S = (len(starts), 4, 16, WORDS_PAGE, WORDS_PAGES,
+                            WORDS_S)
+    exact = case != "unit_normal"
+    draw = ((lambda *shape: rng.integers(-2, 3, shape).astype(np.float32))
+            if exact else (lambda *shape: rng.normal(size=shape)))
+    keys, qi = draw(B, S, W), draw(B, 1, J, W)
+    w = (rng.choice([0.5, -0.5, 0.25, 1.0], (B, J)) if exact
+         else rng.normal(size=(B, J)) / 8).astype(np.float32)
+    start = np.asarray(starts)
+    live = np.arange(S)[None, :] <= start[:, None]
+    if spoil is not None:
+        spoil(keys, w, live)
+    table = rng.permutation(np.arange(1, B * NP + 1)).reshape(B, NP)
+    pool = draw(2, B * NP + 1, W, page)             # layer 0: another's
+    pool[1][table] = keys.reshape(B, NP, page, W).transpose(0, 1, 3, 2)
+    if case == "inactive_slots":
+        table[start == 0] = 0                       # the trash page's row
+    got, want, scores = map(np.asarray, _both_selections(
+        jnp.asarray(qi, jnp.bfloat16), jnp.asarray(w)[:, None],
+        jnp.asarray(pool, jnp.bfloat16), jnp.asarray(table, jnp.int32),
+        jnp.asarray(start, jnp.int32)))
+    assert got.shape == (B, NP, page) and got.dtype == np.int32
+    got, want = got.reshape(B, S), want.reshape(B, S)
+    assert (got.sum(-1) == np.minimum(start + 1, WORDS_K)).all()
+    assert not got[~live].any()
+    kth = np.where(want != 0, scores, np.inf).min(-1, keepdims=True)
+    if exact:
+        assert (got == want).all(), np.argwhere(got != want)
+    else:
+        apart = (got != want) & (np.abs(scores - kth) > ref.INDEX_SCORE_TOL)
+        assert not apart.any(), np.argwhere(apart)
+    assert (got[start == 0, 0] == 1).all()
+    # What a case is there for was there.
+    tied = ((scores == kth) & live).sum(-1) > 1
+    if case == "ties_at_the_threshold":
+        pair = np.arange(5, S, 5)           # pair and pair - 1 tie
+        assert not (got[:, pair] & ~got[:, pair - 1]).any()
+        assert (got[:, pair - 1] & ~got[:, pair] & live[:, pair]).any()
+        assert tied[3:].any()
+    if case == "signed_zeros":
+        # The kernel's own sum starts from head 0's term, not from 0.0: a
+        # row of negative weights scores a key that holds nothing -0.0.
+        by_head = np.maximum(np.einsum("bjw,bsw->bjs", qi[:, 0], keys), 0.0)
+        summed = functools.reduce(np.add, np.moveaxis(
+            by_head * w[:, :, None], 1, 0))
+        assert (summed == scores).all()
+        zeros = (summed == 0.0) & live
+        assert np.signbit(summed[[1, 3]][zeros[[1, 3]]]).all()
+        assert not np.signbit(summed[[0, 2, 4]][zeros[[0, 2, 4]]]).any()
+        assert (kth[[1, 3]] == 0.0).all() and tied[[1, 3]].all()
+    if case == "stale_dead_pages":
+        assert (np.where(live, -np.inf, scores).max(-1)
+                > np.where(live, scores, -np.inf).max(-1)).all()
 
 
 def _decode_pool(rng, slots: int, table_pages: int, page: int, dtype):

@@ -9,10 +9,14 @@ masked page walk beside the unmasked one — and a decode step's READ of its
 selected keys in three forms at 8k / 20k / 32k of context a slot
 (``read``): the gathered rows (``gathered_decode_attention``, the plain
 form), the walk of the live pages under the selection's mask
-(``selected_decode_attention``; its mask as served, ``selection_words``'
-product of one-hot rows, beside the two ways that lost: a scatter of the
-list, and a threshold on the scores with a running count of the ties), and
-XLA's gather asked for one slice a token across the KV heads.
+(``selected_decode_attention``; its mask here the plain form's,
+``selection_words`` of ``lax.top_k``'s list), and
+XLA's gather asked for one slice a token across the KV heads — and a decode
+step's SELECTION, from ``qi``, ``w`` and the index pool to the read kernel's
+mask words, in two forms at the same three contexts (``select``): the one
+kernel over the live pages that is served (``SparseAttention.select_words``)
+beside the span it replaced (scores over every table position, ``lax.top_k``'s
+list, ``selection_words``).
 ``chiprun -- python3 tools/probe_sparse_attention.py``; results on stdout
 and in chiprun_out/probe_sparse_attention.json. Fails without a TPU."""
 from __future__ import annotations
@@ -31,6 +35,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 from llmapigateway_tpu.ops import sparse_attention as sa       # noqa: E402
 from llmapigateway_tpu.ops.paged_attention import (            # noqa: E402
     paged_prefill_attention)
+from probe_experts import device_ms, program                   # noqa: E402
 
 L, SLOTS, S, PAGE, H, KV, DH, J, W, K, T = (12, 8, 32768, 256, 32, 4, 128,
                                             16, 64, 2048, 512)
@@ -38,26 +43,6 @@ NP = S // PAGE
 
 
 READ_CONTEXTS = (8192, 20000, 32767)
-
-
-def scatter_words(positions, total):
-    """:func:`sa.selection_words`' mask by XLA's scatter of the list."""
-    real = (jnp.arange(K)[None, :] < total[:, None]).astype(jnp.int32)
-    return jnp.zeros((SLOTS, S), jnp.int32).at[
-        jnp.arange(SLOTS)[:, None], positions].max(real).reshape(
-            SLOTS, NP, PAGE)
-
-
-def threshold_words(scores, seen, k):
-    """:func:`sa.top_positions`' set as the decode kernel's mask with no
-    scatter: every seen score above the ``k``-th largest and the first of
-    those AT it that there is room for, by a running count."""
-    masked = jnp.where(seen, jnp.where(scores == 0.0, 0.0, scores), -jnp.inf)
-    kth = jax.lax.top_k(masked, k)[0][..., -1:]
-    above, at = masked > kth, (masked == kth) & seen
-    room = k - jnp.sum(above, axis=-1, keepdims=True, dtype=jnp.int32)
-    keep = above | (at & (jnp.cumsum(at, axis=-1, dtype=jnp.int32) <= room))
-    return keep.astype(jnp.int32).reshape(scores.shape[0], NP, PAGE)
 
 
 def slice_gathered_attention(q, pool_k, pool_v, layer, phys, offset, total):
@@ -101,27 +86,17 @@ def probe_reads(ms, out, table, pools, q, keys):
             return sa.selected_decode_attention(
                 q, pk, pv, table, n_keys, keep, layer=layer)
 
-        def words(scores, how):
+        def words(scores):
             positions, _, _, total = listed(scores)
-            return how(positions, total)
-
-        def served(positions, total):
             return sa.selection_words(positions, total, NP, PAGE)
         forms = {
             "gathered": lambda s, pk, pv: sa.gathered_decode_attention(
                 q, pk, pv, layer, *listed(s)[1:]),
             "slice_gathered": lambda s, pk, pv: slice_gathered_attention(
                 q, pk, pv, layer, *listed(s)[1:]),
-            "walk": lambda s, pk, pv: walk(words(s, served), pk, pv),
-            "walk_scatter": lambda s, pk, pv: walk(
-                words(s, scatter_words), pk, pv),
-            "walk_threshold": lambda s, pk, pv: walk(
-                threshold_words(s, seen, K), pk, pv),
+            "walk": lambda s, pk, pv: walk(words(s), pk, pv),
             "list_alone": lambda s, pk, pv: listed(s),
-            "mask_alone": lambda s, pk, pv: words(s, served),
-            "mask_scatter_alone": lambda s, pk, pv: words(s, scatter_words),
-            "mask_threshold_alone": lambda s, pk, pv: threshold_words(
-                s, seen, K),
+            "mask_alone": lambda s, pk, pv: words(s),
         }
         got = {}
         for name, fn in forms.items():
@@ -132,14 +107,58 @@ def probe_reads(ms, out, table, pools, q, keys):
         keep = got["mask_alone"]
         ms[f"read.walk_alone@{ctx}"] = timed(jax.jit(walk), keep, pool_k,
                                              pool_v, n=20)
-        out[f"read.masks_differ_at@{ctx}"] = [
-            int((keep != got[name]).sum())
-            for name in ("mask_scatter_alone", "mask_threshold_alone")]
         want = got["gathered"].astype(jnp.float32)
-        for name in ("slice_gathered", "walk", "walk_scatter",
-                     "walk_threshold"):
+        for name in ("slice_gathered", "walk"):
             out[f"read.{name}_off_by@{ctx}"] = float(jnp.abs(
                 got[name].astype(jnp.float32) - want).max())
+        print(json.dumps(out), flush=True)
+
+
+def probe_selects(ms, out, table, pool_i, qi, w):
+    """A decode step's selection, from the index queries to the mask words,
+    as served (one kernel over the live pages) and as it was (scores over
+    the table, the sorted list, the list's words), 8 slots at one context
+    and at a mix of them as the cell holds. DEVICE time of a program's
+    execution, off the profiler's module line (``select.*``: a call of
+    either form through the host costs 0.2 ms of dispatch whatever it
+    holds, beside them as ``select_host.*``), the listed form's largest
+    ops by category, and where the two sets differ, whether the score there
+    lies within 1e-4 of the slot's k-th (two sound selections may)."""
+    fn = sa.SparseAttention(table, S, K, "pallas")
+    layer = jnp.int32(5)
+    forms = {
+        "words": lambda qi, w, p, at: fn.select_words(qi, w, p, layer, at),
+        "listed": lambda qi, w, p, at: sa.selection_words(
+            *fn.select(qi, w, p, layer, at), NP, PAGE),
+    }
+    forms = {name: program(f"select_{name}", form)
+             for name, form in forms.items()}
+    mixed = jnp.asarray([0, 8192, 12000, 16000, 20000, 24000, 28000, 0],
+                        jnp.int32)
+    for ctx in (*READ_CONTEXTS, "mixed"):
+        start = mixed if ctx == "mixed" else jnp.full((SLOTS,), ctx,
+                                                      jnp.int32)
+        got = {}
+        for name, form in forms.items():
+            ms[f"select_host.{name}@{ctx}"] = timed(form, qi, w, pool_i,
+                                                    start, n=20)
+            got[name] = form(qi, w, pool_i, start).reshape(SLOTS, S)
+        # A trace a context: the context is an ARGUMENT of the two programs,
+        # and the module line tells executions apart by program alone.
+        device, parts = device_ms({f"select_{name}": (
+            form, (qi, w, pool_i, start)) for name, form in forms.items()},
+            n=10)
+        for name in forms:
+            ms[f"select.{name}@{ctx}"] = device[f"select_{name}"]
+        out[f"select.listed_by_category@{ctx}"] = parts["select_listed"]
+        scores = fn.scores(qi, w, pool_i, layer)[:, 0]
+        kth = jnp.where(got["listed"] != 0, scores, jnp.inf).min(
+            -1, keepdims=True)
+        differ = got["words"] != got["listed"]
+        out[f"select.words_differ_at@{ctx}"] = int(differ.sum())
+        out[f"select.words_apart_at@{ctx}"] = int(
+            (differ & (jnp.abs(scores - kth) > 1e-4)).sum())
+        out[f"select.words_kept@{ctx}"] = got["words"].sum(-1).tolist()
         print(json.dumps(out), flush=True)
 
 
@@ -198,11 +217,21 @@ def main() -> int:
                                    q[:, 0], pk, pv, layer, ph, off, tot))
             ms["decode.gather_and_attend"] = timed(
                 gathered, q, pool_k, pool_v, phys, offset, total)
-            whole = jax.jit(lambda qi, w, q, pool: fn.attend(
-                q, pool, layer, start, fn.select(qi, w, pool[2], layer,
-                                                 start)))
-            ms["decode.select_and_attend"] = timed(
-                whole, qi, w, q, (pool_k, pool_v, pool_i))
+            # All of a layer's decode, as served (the two kernels) and
+            # through the list: by the host's clock, then on the device.
+            pool = (pool_k, pool_v, pool_i)
+            whole = {name: program(
+                f"decode_{name}", lambda qi, w, q, pool, select=select:
+                fn.attend(q, pool, layer, start,
+                          select(qi, w, pool[2], layer, start)))
+                for name, select in (("select_and_attend", fn.select_words),
+                                     ("listed_and_attend", fn.select))}
+            for name, form in whole.items():
+                ms[f"decode.{name}"] = timed(form, qi, w, q, pool)
+            device, _ = device_ms({f"decode_{name}": (form, (qi, w, q, pool))
+                                   for name, form in whole.items()}, n=10)
+            for name in whole:
+                ms[f"decode_device.{name}"] = device[f"decode_{name}"]
         else:
             kernel = jax.jit(lambda qi, w, p: sa.index_select(
                 qi, w, p, table[:B], start, layer=layer, topk=K))
@@ -217,6 +246,7 @@ def main() -> int:
                 ms[f"prefill.walk_{name}"] = timed(walk, q, pool_k, pool_v)
         print(json.dumps(out), flush=True)
         if kind == "decode":
+            probe_selects(ms, out, table, pool_i, qi, w)
             probe_reads(ms, out, table, (pool_k, pool_v), q, keys)
     path = Path("chiprun_out/probe_sparse_attention.json")
     path.parent.mkdir(exist_ok=True)
