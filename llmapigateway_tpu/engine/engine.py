@@ -54,6 +54,7 @@ sharding and scanning treat them uniformly.
 from __future__ import annotations
 
 import asyncio
+import inspect
 import logging
 import os
 import time
@@ -286,7 +287,8 @@ class InferenceEngine:
                 model_cfg = _config_from_checkpoint(engine_cfg.model_path)
             else:
                 raise ValueError("local engine needs 'preset' or 'model_path'")
-        self.model_cfg = model_cfg
+        # The geometry SERVED (differential attention: folded K/V pairs).
+        self.model_cfg = model_cfg = model_cfg.served()
         self.dtype = jnp.bfloat16 if engine_cfg.dtype == "bfloat16" else \
             jnp.dtype(engine_cfg.dtype)
 
@@ -447,6 +449,14 @@ class InferenceEngine:
         # The state blocks the decode steps rewrote: active rows x linear
         # layers a step (ISSUE 46), counted the same way.
         self._lin_decode_state_updates = 0
+        # A cross decoder's (ISSUE 54), counted the same way: the keys its
+        # decode steps read of a pool that MORE layers read than keep (the
+        # ONE full-context K/V: the full layer and the cross layers), times
+        # those layers; and the prompt rows that ended half-way up the
+        # stack — every row of a chunk but a prompt's last (``_rows_stop``,
+        # which ``_compile`` reads off the family's forward).
+        self._cross_decode_keys_read = 0
+        self._prefill_rows_stopped = 0
         # The keys the decode programs' paged kernel calls attended
         # (ISSUE 44), kept the same way: per layer of a K/V cache group,
         # summed over steps and active slots — a global group's step sees
@@ -500,12 +510,15 @@ class InferenceEngine:
                             "false",
             "spec": "a rejected draft cannot be rolled out of the "
                     "recurrent state",
-            "window": "the page ring is not wired to the pool of the "
-                      "softmax layers",
             "mesh": "the state block and the held experts have no "
                     "sharding rule yet",
             "disagg": "a handoff moves pages between slots, not the "
                       "state block"},
+        # ... served by the PERIOD scan (models/hybrid.py), which wires no
+        # ring beside its state; a cross decoder's own forward does.
+        "period_state": {
+            "window": "the page ring is not wired to the pool of the "
+                      "softmax layers"},
         # SEVERAL cache groups (window and global layers in one model).
         "groups": {
             "prefix_cache": "the ring re-targets a windowed group's pages, "
@@ -552,6 +565,23 @@ class InferenceEngine:
                       "index-key side",
             "page_lanes": "an index-key page lies token-minor, and the "
                           "chip's kernels move whole tiles of 128 lanes"},
+        # ONE layer's K/V read by other layers, under queries that pair
+        # (models/sambay.py): a cross decoder. It has "state" and "groups"
+        # too, and their reasons beside these.
+        "cross": {
+            "kv_quant": "two softmax maps are SUBTRACTED, and int8 K/V "
+                        "rounds each by more than their difference keeps; "
+                        "set kv_quant ''",
+            "prefix_cache": "a cached prefix would need the memory layer's "
+                            "state at its end and the ring's last window "
+                            "beside the full layer's pages; set "
+                            "prefix_cache false",
+            "spec": "the verify path runs every layer over every draft "
+                    "row; this family's upper half runs on one row",
+            "mesh": "the folded K/V heads and the scan's channels have no "
+                    "sharding rule yet",
+            "disagg": "a handoff moves one group's pages, not the ring, "
+                      "the state block and the memory"},
     }
 
     def _refuse_unsupported(self) -> None:
@@ -560,8 +590,11 @@ class InferenceEngine:
         cannot be served with — none is silently switched off."""
         cfg, c = self.cfg, self.model_cfg
         kinds = [kind for kind, has in (
-            ("state", c.n_lin_layers), ("groups", len(c.cache_groups) > 1),
-            ("latent", c.is_mla), ("sparse", c.is_sparse)) if has]
+            ("state", c.n_lin_layers),
+            ("period_state", c.n_lin_layers and not c.cross_decoder),
+            ("groups", len(c.cache_groups) > 1),
+            ("latent", c.is_mla), ("sparse", c.is_sparse),
+            ("cross", c.cross_decoder)) if has]
         if not kinds:
             return
         kinds.append("any")
@@ -774,9 +807,10 @@ class InferenceEngine:
         # superpage instead.
         n_trash = self.kv_ppb
         from .paged import CacheGroup, CacheGroups
-        periods = c.n_periods if c.layer_period else c.n_layers
         groups = []
-        for (window, positions), ring in zip(c.cache_groups, rings):
+        for (window, _), n_layers, readers, chunk_readers, ring in zip(
+                c.cache_groups, c.group_layers, c.group_readers,
+                c.group_chunk_readers, rings):
             # The most pages one slot ever holds — the ring where it
             # runs, else the whole context — sizes the derived pool:
             # every slot can hold a max-footprint sequence at once
@@ -790,11 +824,12 @@ class InferenceEngine:
                     f"max-footprint sequence ({min_hold} pages of "
                     f"{page})")
             groups.append(CacheGroup(
-                periods * len(positions), window, ring,
+                n_layers, window, ring,
                 PageAllocator(num_pages, page, self.B, self.S,
                               pages_per_block=self.kv_ppb),
                 kind="latent" if c.is_mla else "kv",
-                token_bytes=self._kv_token_bytes()))
+                token_bytes=self._kv_token_bytes(), readers=readers,
+                chunk_readers=chunk_readers))
         self.kv_groups = CacheGroups(groups)
         num_pages = self.allocator.num_pages
         # Radix prefix cache (ISSUE 6): cross-request KV reuse over
@@ -829,19 +864,19 @@ class InferenceEngine:
             # holds, so release, cancel and rebuild do no state work.
             from ..models.hybrid import HybridCache
             rep_sh = NamedSharding(self.mesh, P())
-            n_lin = (c.layer_period - len(c.softmax_positions)
-                     + bool(c.leading_dense))
             k_sh = v_sh = (side,) * len(self.kv_groups)
             if c.is_mla:        # ONE latent pool, no V side
                 k_sh, v_sh = (rep_sh,), ()
             index_sh = (rep_sh,) * len(self.kv_groups) if c.is_sparse else ()
+            create = partial(HybridCache.create, c,
+                             tuple(g.allocator.num_pages
+                                   for g in self.kv_groups),
+                             page, self.B, self.dtype, kv_quant=self.kv_quant)
+            # (As many state blocks and conv tails as the family's cache
+            # lays out: its own shapes say.)
+            n_lin = len(jax.eval_shape(create).state)
             self.cache = jax.jit(
-                partial(HybridCache.create, c,
-                        tuple(g.allocator.num_pages
-                              for g in self.kv_groups),
-                        page, self.B, self.dtype,
-                        kv_quant=self.kv_quant),
-                out_shardings=HybridCache(
+                create, out_shardings=HybridCache(
                     k=k_sh, v=v_sh, counters=rep_sh, index=index_sh,
                     state=(rep_sh,) * n_lin, conv=(rep_sh,) * n_lin))()
         else:
@@ -1040,6 +1075,10 @@ class InferenceEngine:
         forward's signature knows nothing of pages."""
         c = self.model_cfg
         family_forward = forward_fn(c)
+        # A forward whose upper layers run on a prompt's last row alone
+        # asks which rows' chunks END their prompts (``final``).
+        self._rows_stop = "final" in inspect.signature(
+            family_forward).parameters
         from ..ops.latent_attention import LatentAttention
         from ..ops.sparse_attention import SparseAttention
         from ..ops.paged_attention import (PagedKVCache,
@@ -1102,7 +1141,8 @@ class InferenceEngine:
                          slots: jax.Array, last_idx: jax.Array,
                          samp_t: jax.Array, samp_p: jax.Array,
                          samp_k: jax.Array, samp_pp: jax.Array,
-                         samp_fp: jax.Array, key: jax.Array
+                         samp_fp: jax.Array, key: jax.Array,
+                         final: jax.Array | None = None
                          ) -> tuple[jax.Array, jax.Array, PagedKVCache]:
             """One prompt chunk for each of K slots. tokens [K, C],
             start_len/slots/last_idx/samp_* [K]. Returns (first_tokens
@@ -1115,7 +1155,9 @@ class InferenceEngine:
             global, so there is no per-slot cache slice: each slot's
             page-table row does the routing, and the K rows are sliced
             unrolled (NOT a gather: dynamic_slice is the op GSPMD already
-            partitions correctly for the K=1 path)."""
+            partitions correctly for the K=1 path). ``final`` [K] bool (a
+            forward that takes it): which rows' chunks END their prompts —
+            a call none of whose rows does runs no upper half at all."""
             K = tokens.shape[0]
             rows_tbl = tuple(jnp.concatenate(
                 [jax.lax.dynamic_slice_in_dim(table, slots[k], 1, axis=0)
@@ -1123,11 +1165,15 @@ class InferenceEngine:
             logits, cache = call_forward(
                 params, cache, rows_tbl, tokens, start_len,
                 **({"slots": slots, "n_valid": last_idx + 1}
-                   if rows_known else {}))
+                   if rows_known else {}),
+                **({"final": final} if self._rows_stop else {}))
             counts, count_rows = _prefill_counts(
                 counts, tokens, start_len, slots, last_idx)
+            # (A forward that ran its head on a row's last real position
+            # alone hands back that ONE position.)
             rows = jax.lax.with_sharding_constraint(
-                jnp.take_along_axis(
+                logits[:, 0, :] if logits.shape[1] == 1
+                else jnp.take_along_axis(
                     logits, last_idx[:, None, None], axis=1)[:, 0, :],
                 replicated)
             samp = SamplingParams(temperature=samp_t, top_p=samp_p,
@@ -1274,7 +1320,7 @@ class InferenceEngine:
             *state, row(jnp.int32, bucket), row(jnp.int32), row(jnp.int32),
             row(jnp.int32), row(jnp.float32), row(jnp.float32),
             row(jnp.int32), row(jnp.float32), row(jnp.float32),
-            key).compile()
+            key, *([row(jnp.bool_)] if self._rows_stop else [])).compile()
 
     def _upload(self, host: np.ndarray) -> jax.Array:
         """Replicated device copy of a host mirror — of a PRIVATE copy of
@@ -2299,7 +2345,7 @@ class InferenceEngine:
         dispatch. The scheduler's grouper
         guarantees every request here shares one compile bucket.
         Returns per-request prompt-complete flags."""
-        slots, poss, chunks, samps = [], [], [], []
+        slots, poss, chunks, samps, final = [], [], [], [], []
         for req in reqs:
             slot = req.slot
             ids = req.prompt_ids
@@ -2322,9 +2368,10 @@ class InferenceEngine:
             chunks.append(chunk)
             samps.append((req.temperature, req.top_p, req.top_k,
                           req.presence_penalty, req.frequency_penalty))
+            final.append(pos + len(chunk) >= len(ids))
         self._rng, key = jax.random.split(self._rng)
         first, self.cache = self._exec_prefill(
-            slots, poss, chunks, samp=samps, key=key)
+            slots, poss, chunks, samp=samps, key=key, final=final)
         done: list[bool] = []
         first_np: np.ndarray | None = None
         for i, req in enumerate(reqs):
@@ -2357,7 +2404,8 @@ class InferenceEngine:
         return done
 
     def _exec_prefill(self, slot, pos, chunk,
-                      samp=None, key: jax.Array | None = None):
+                      samp=None, key: jax.Array | None = None,
+                      final=None):
         """The one compiled-prefill call. A caller that only fills the
         cache (the benchmark's warm-up, a profile) passes no sampling
         state and ignores the sampled token.
@@ -2372,6 +2420,9 @@ class InferenceEngine:
         dynamic_update_slice starts, so an overrunning padded chunk
         would silently shift and corrupt earlier KV entries. (Paged
         layout: out-of-range pad positions land on the trash page.)
+        ``final`` (a list a row, default all): whether the chunk ENDS its
+        prompt; only a program whose upper layers run on a prompt's last
+        row alone is told.
         Returns (first_tokens [K, replicated device array], cache)."""
         single = np.isscalar(slot) or isinstance(slot, (int, np.integer))
         slots = [slot] if single else list(slot)
@@ -2397,6 +2448,12 @@ class InferenceEngine:
                 np.asarray([s[2] for s in samps], np.int32),
                 np.asarray([s[3] for s in samps], np.float32),
                 np.asarray([s[4] for s in samps], np.float32), key)
+        if self._rows_stop:
+            ends = np.ones((K,), bool) if final is None else \
+                np.asarray(final, bool)
+            args += (ends,)
+            self._prefill_rows_stopped += sum(
+                len(ch) - int(end) for ch, end in zip(chunks, ends))
         # Kernel registry (ISSUE 8): one row per (bucket, K) prefill
         # program; the aval capture + cost closure is paid once per
         # variant. The wall is the dispatch wall (on an async backend the
@@ -2460,6 +2517,8 @@ class InferenceEngine:
                 bucket * int(p) + bucket * (bucket + 1) // 2 for p in poss)
         walked = 0
         for g in self.kv_groups:
+            if not g.chunk_readers:
+                continue    # no chunk attends there: one row reads it
             w, t = pa.prefill_pages_walked(
                 poss, bucket, bt, page, g.window,
                 g.allocator.pages_per_slot, self.kv_ppb)
@@ -3097,6 +3156,9 @@ class InferenceEngine:
                     np.minimum(seen, g.window).sum())
             else:
                 self._attn_decode_keys["global"] += int(seen.sum())
+                if g.readers > g.layers:    # a pool other layers read too
+                    self._cross_decode_keys_read += \
+                        int(seen.sum()) * g.readers
 
     # -- emission / lifecycle (event-loop thread only) ------------------------
     def _emit_token(self, req: GenRequest) -> None:
@@ -3312,11 +3374,9 @@ class InferenceEngine:
                  * (self._kv_token_bytes() - index)).sum())
         # Token reads summed over the layers of every cache group, each
         # clamped to the group's window.
-        periods = c.n_kv_layers // len(c.softmax_positions)
         reads = sum(
-            periods * len(ps)
-            * int((np.minimum(live, w) if w else live).sum())
-            for w, ps in c.cache_groups)
+            n * int((np.minimum(live, w) if w else live).sum())
+            for (w, _), n in zip(c.cache_groups, c.group_readers))
         # np.dtype (in _kv_token_bytes), not jnp: host metadata — stats()
         # runs on the event loop and must not even look like a device sync
         # (graftlint v2 chases this call from the async stats handlers).
@@ -3473,7 +3533,12 @@ class InferenceEngine:
             out["state_slots"] = self.B
             out["lin_decode_state_updates_total"] = \
                 self._lin_decode_state_updates
-        if self.model_cfg.layer_period:
+        if any(g.readers > g.layers for g in self.kv_groups):
+            out["cross_decode_keys_read_total"] = \
+                self._cross_decode_keys_read
+        if self._rows_stop:
+            out["prefill_rows_stopped_total"] = self._prefill_rows_stopped
+        if self.model_cfg.layer_period and self.model_cfg.n_experts:
             # The expert layer's share: the assignments the decode steps
             # routed, those that landed on an expert held here (their
             # ratio is the share of the experts held when routing is

@@ -340,11 +340,16 @@ class CacheGroup:
     pages, W, page]`` (ops/sparse_attention.py); "latent": ONE pool
     ``[layers, pages, latent_width, page]`` (ops/latent_attention.py) —
     and ``token_bytes`` what a token keeps in one of its layers. Pages,
-    tables and admission are the same for both."""
+    tables and admission are the same for both. ``readers``: the layers
+    that READ the pool a decode step, ``chunk_readers``: those whose
+    prefill chunks attend it (both default to ``layers``; a pool that
+    other layers read too has more of the first, and one that is written
+    a chunk at a time but attended from one row none of the second)."""
 
     def __init__(self, layers: int, window: int, ring_pages: int,
                  allocator: PageAllocator, kind: str = "kv",
-                 token_bytes: int = 0):
+                 token_bytes: int = 0, readers: int | None = None,
+                 chunk_readers: int | None = None):
         if kind not in ("kv", "latent"):
             raise ValueError(f"unknown cache group kind {kind!r}")
         if kind == "latent" and (window or ring_pages):
@@ -353,6 +358,9 @@ class CacheGroup:
         self.kind = kind
         self.token_bytes = token_bytes
         self.layers = layers
+        self.readers = layers if readers is None else readers
+        self.chunk_readers = (layers if chunk_readers is None
+                              else chunk_readers)
         self.window = window
         self.ring_pages = ring_pages
         self.allocator = allocator

@@ -6,6 +6,9 @@ from .config import ModelConfig, PRESETS, get_preset
 def forward_fn(config: ModelConfig):
     """The forward callable for a family, uniform signature:
     (params, config, tokens, lengths, cache, active=None) → (logits, cache)."""
+    if config.cross_decoder:        # three runs of pairs, not one period
+        from . import sambay
+        return sambay.forward
     if config.layer_period:         # the period families
         from . import hybrid
         return hybrid.forward
@@ -18,6 +21,9 @@ def forward_fn(config: ModelConfig):
 
 def init_fn(config: ModelConfig):
     """Random-init callable for a family: (config, key, dtype) → params."""
+    if config.cross_decoder:
+        from . import sambay
+        return sambay.init_params
     if config.layer_period:
         from . import hybrid
         return hybrid.init_params

@@ -203,7 +203,31 @@ class ModelConfig:
     # ``qk_norm_draw ** 2`` — at 1 a softmax over thousands of keys is all
     # but even, and its output says nothing of WHICH keys it saw.
     qk_norm: bool = False
-    qk_norm_draw: float = 1.0
+    qk_norm_draw: float = 1.0   # (differential attention, which has no such
+    #                             norm, draws its q and k projections at it)
+    # The decoder-hybrid-decoder family (models/sambay.py; "phi4flash"):
+    # ``layer_period`` 2 — the even layer of a pair holds the state side,
+    # the odd one attends — in THREE runs: ``n_self_pairs`` of [selective
+    # scan, attention inside ``sliding_window``], ONE pair [selective scan
+    # that also hands its gated output up the stack (the MEMORY), attention
+    # over the whole context, whose K/V is the one full-context cache], and
+    # ``n_cross_pairs`` of [a gate on the memory, attention whose K/V are
+    # that one layer's]. Every MLP is dense. A prompt's rows stop at the
+    # full layer's K/V: everything above runs on a prompt's last row only.
+    cross_decoder: bool = False
+    # ``lin_kind`` "mamba" (the selective scan, Mamba-1): a diagonal state
+    # of ``ssm_state`` numbers a channel of ``ssm_expand * d_model``
+    # channels, the step, B and C read from the token through a
+    # rank-``ssm_dt_rank`` bottleneck; ``lin_conv_taps`` taps before it.
+    ssm_state: int = 0
+    ssm_expand: int = 0
+    ssm_dt_rank: int = 0
+    # Its attention is DIFFERENTIAL: query heads (2i, 2i+1) and K/V heads
+    # (2j, 2j+1) pair; a pair's two softmax maps read the pair's V side by
+    # side and are SUBTRACTED under a learned weight, then normed. SERVED
+    # folded (``served()``): a pair of K/V heads is ONE head of twice the
+    # width and a query head is half zeros, which IS grouped-query
+    # attention on the paged kernels as they are.
 
     def __post_init__(self):
         for name in ("window_layout", "rope_layout"):
@@ -216,15 +240,39 @@ class ModelConfig:
             raise ValueError(f"unknown moe_router {self.moe_router!r}")
         if self.moe_act not in ("silu", "relu"):
             raise ValueError(f"unknown moe_act {self.moe_act!r}")
-        if self.norm_kind not in ("rms", "layernorm", "rms_2sigmoid"):
+        if self.norm_kind not in ("rms", "layernorm", "rms_2sigmoid",
+                                  "layernorm_bias"):
             raise ValueError(f"unknown norm_kind {self.norm_kind!r}")
-        if self.lin_kind not in ("kda", "gated_delta"):
+        if self.lin_kind not in ("kda", "gated_delta", "mamba"):
             raise ValueError(f"unknown lin_kind {self.lin_kind!r}")
         if self.idx_topk and not (self.idx_heads and self.idx_head_dim):
             raise ValueError("idx_topk needs idx_heads and idx_head_dim")
         if self.leading_dense and not self.lin_heads:
             raise ValueError("leading_dense layers carry a linear mixer: "
                              "they need lin_heads")
+        if self.cross_decoder and (
+                self.layer_period != 2 or self.n_layers % 4
+                or self.n_layers < 8 or self.lin_kind != "mamba"
+                or not (self.ssm_state and self.ssm_expand
+                        and self.ssm_dt_rank and self.lin_conv_taps)
+                or not self.sliding_window):
+            raise ValueError(
+                "a cross decoder is pairs of [state, attention] layers in "
+                "three runs (n_layers a multiple of 4, 8 or more), its "
+                "state layers selective scans with their sizes, its lower "
+                "attention layers inside a sliding_window")
+
+    def served(self) -> "ModelConfig":
+        """The geometry the engine SERVES (itself for most families). A
+        cross decoder's differential attention is served FOLDED: K/V heads
+        pair into heads of twice the width (the same bytes a token), the
+        query heads keep their number at that width, half of each zeros.
+        A preset states the PUBLISHED heads and no ``head_dim_override``;
+        the folded config has one, and is its own ``served()``."""
+        if not self.cross_decoder or self.head_dim_override:
+            return self
+        return replace(self, n_kv_heads=self.n_kv_heads // 2,
+                       head_dim_override=2 * self.head_dim)
 
     @property
     def head_dim(self) -> int:
@@ -257,6 +305,8 @@ class ModelConfig:
         """The positions of a period that are softmax layers (they keep
         paged KV): position 0 where the others are linear-attention
         layers, else every one."""
+        if self.cross_decoder:
+            return (1,)
         if self.layer_period and not self.lin_heads:
             return tuple(range(self.layer_period))
         return (0,)
@@ -280,10 +330,57 @@ class ModelConfig:
         one allocator (engine/paged.py CacheGroups): a windowed group may
         recycle a slot's pages below the window, a global one (window 0)
         keeps the whole context."""
+        if self.cross_decoder:
+            # The ring first: the lower pairs' windowed layers, then the ONE
+            # full-context layer (which the cross layers read).
+            return ((self.sliding_window, (1,)), (0, (1,)))
         groups: dict[int, list[int]] = {}
         for p in self.softmax_positions:
             groups.setdefault(self.window_at(p), []).append(p)
         return tuple((w, tuple(ps)) for w, ps in groups.items())
+
+    @property
+    def group_layers(self) -> tuple[int, ...]:
+        """Layers whose K/V each cache group's pool holds, in the order of
+        ``cache_groups``."""
+        if self.cross_decoder:
+            return (self.n_self_pairs, 1)
+        if not self.layer_period:
+            return (self.n_layers,)
+        return tuple(self.n_periods * len(ps) for _, ps in self.cache_groups)
+
+    @property
+    def group_readers(self) -> tuple[int, ...]:
+        """Layers that READ each cache group's pool a decode step: those
+        that keep it and, in a cross decoder's full-context group, the
+        cross layers too."""
+        if self.cross_decoder:
+            return (self.n_self_pairs, 1 + self.n_cross_pairs)
+        return self.group_layers
+
+    @property
+    def group_chunk_readers(self) -> tuple[int, ...]:
+        """Layers whose PREFILL CHUNKS attend each cache group's pool: those
+        that keep it — but not a cross decoder's full-context layer, which
+        writes a chunk's rows and attends from a prompt's last alone."""
+        if self.cross_decoder:
+            return (self.n_self_pairs, 0)
+        return self.group_layers
+
+    @property
+    def n_self_pairs(self) -> int:
+        """A cross decoder's lower pairs [scan, window attention]."""
+        return self.n_layers // 4
+
+    @property
+    def n_cross_pairs(self) -> int:
+        """A cross decoder's upper pairs [memory gate, cross attention]."""
+        return self.n_layers // 4 - 1
+
+    @property
+    def ssm_inner(self) -> int:
+        """Channels of a selective-scan layer."""
+        return self.ssm_expand * self.d_model
 
     @property
     def n_periods(self) -> int:
@@ -307,13 +404,20 @@ class ModelConfig:
     def n_kv_layers(self) -> int:
         """Layers that keep paged KV (or a latent row): all, or the
         softmax positions of every period."""
-        return (self.n_periods * len(self.softmax_positions)
-                if self.layer_period else self.n_layers)
+        return sum(self.group_layers)
+
+    @property
+    def n_attn_layers(self) -> int:
+        """Layers that ATTEND paged KV: those that keep it and, in a cross
+        decoder, the layers that read another layer's."""
+        return sum(self.group_readers)
 
     @property
     def n_lin_layers(self) -> int:
         """Layers that keep a recurrent state block per slot (the leading
-        layers among them)."""
+        layers among them; a cross decoder's selective scans)."""
+        if self.cross_decoder:
+            return self.n_self_pairs + 1
         return self.n_layers - self.n_kv_layers
 
 
@@ -610,6 +714,32 @@ PRESETS["tiny-keye-vl2-test"] = replace(
 PRESETS["keye-vl2-30b-ep4"] = replace(
     PRESETS["keye-vl2-30b-a3b"], n_layers=12, vocab_size=37984,
     n_experts_held=32)
+
+# Phi-4-mini-flash-reasoning (HF: microsoft/Phi-4-mini-flash-reasoning,
+# ``phi4flash``; arXiv:2507.06607, the SambaY architecture) at its PUBLISHED
+# sizes: 32 layers with LayerNorms (weight and bias) and dense SwiGLU MLPs of
+# 10,240, no positional encoding, a tied head. Even layers 0-16 are Mamba-1
+# selective scans (5,120 channels x 16 state numbers, conv 4, dt rank 160 —
+# the family's defaults, which the config leaves unsaid); odd layers 1-15
+# differential attention (40 query / 20 KV heads of 64, paired) inside a
+# 512 window; layer 17 the same over the WHOLE context, its K/V the one
+# full-context cache; layers 18-30 gate layer 16's memory, layers 19-31
+# attend layer 17's K/V with queries of their own. Two cache groups (a ring
+# of 8 layers, a global group of ONE) beside 9 state blocks a slot. The whole
+# model fits one v5e chip in int8 (benchmark/configs/phi4-mini-flash-3.8b.json).
+PRESETS["phi4-mini-flash-3.8b"] = ModelConfig(
+    family="phi4flash", vocab_size=200064, d_model=2560, n_layers=32,
+    n_heads=40, n_kv_heads=20, d_ff=10240, max_seq_len=262144,
+    sliding_window=512, tie_embeddings=True, attn_bias=True, use_rope=False,
+    layer_period=2, lin_kind="mamba", lin_conv_taps=4, ssm_state=16,
+    ssm_expand=2, ssm_dt_rank=160, norm_kind="layernorm_bias",
+    layer_norm_eps=1e-5, cross_decoder=True, qk_norm_draw=1.73)
+# The same three runs at CPU-test size: 8 layers — scan, window 16, scan,
+# window, scan -> memory, full, gate, cross.
+PRESETS["tiny-phi4flash-test"] = replace(
+    PRESETS["phi4-mini-flash-3.8b"], vocab_size=512, d_model=64, n_layers=8,
+    n_heads=4, n_kv_heads=2, d_ff=128, max_seq_len=256, sliding_window=16,
+    ssm_state=8, ssm_dt_rank=4, qk_norm_draw=1.0)
 
 
 def get_preset(name: str) -> ModelConfig:

@@ -185,6 +185,10 @@ class HybridCache(NamedTuple):
         c = config
         if isinstance(num_pages, int):
             num_pages = (num_pages,) * len(c.cache_groups)
+        if c.cross_decoder:     # its own layout of the same type
+            from .sambay import create_cache
+            return create_cache(c, num_pages, page_size, batch, dtype,
+                                kv_quant)
         periods = c.n_periods
         if c.is_mla:
             from ..ops.latent_attention import create_latent_pool
@@ -618,13 +622,17 @@ def kda_chunked(q, k, v, log_a, beta, s0, chunk: int = KDA_CHUNK,
     return jnp.moveaxis(jnp.moveaxis(o, 0, 1), 2, 3).reshape(B, T, H, dv), s
 
 
-def _conv_silu(x_ext: jax.Array, taps: jax.Array) -> jax.Array:
-    """Causal depthwise convolution then SiLU. x_ext [B, T+taps-1, C] (the
-    tail of the previous inputs first), taps [taps, C] -> [B, T, C] f32."""
+def _conv_silu(x_ext: jax.Array, taps: jax.Array,
+               bias: jax.Array | None = None) -> jax.Array:
+    """Causal depthwise convolution (and its ``bias`` [C], where the layer
+    has one) then SiLU. x_ext [B, T+taps-1, C] (the tail of the previous
+    inputs first), taps [taps, C] -> [B, T, C] f32."""
     n = taps.shape[0]
     T = x_ext.shape[1] - (n - 1)
     xf = x_ext.astype(jnp.float32)
     y = sum(taps[j].astype(jnp.float32) * xf[:, j:j + T] for j in range(n))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     return jax.nn.silu(y)
 
 

@@ -103,14 +103,19 @@ def rms_norm(x: jax.Array, weight: jax.Array, eps: float,
     return (normed * (offset + weight.astype(jnp.float32))).astype(x.dtype)
 
 
-def layer_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
-    """LayerNorm without bias, float32 inside: ``w (x - mean) / sqrt(var +
-    eps)`` over the last axis, in ``x``'s dtype."""
+def layer_norm(x: jax.Array, weight: jax.Array, eps: float,
+               bias: jax.Array | None = None) -> jax.Array:
+    """LayerNorm, float32 inside: ``w (x - mean) / sqrt(var + eps)`` over
+    the last axis (``+ bias`` where the family's norm has one), in ``x``'s
+    dtype."""
     xf = x.astype(jnp.float32)
     xc = xf - jnp.mean(xf, axis=-1, keepdims=True)
     normed = xc * jax.lax.rsqrt(jnp.mean(xc * xc, axis=-1, keepdims=True)
                                 + eps)
-    return (normed * weight.astype(jnp.float32)).astype(x.dtype)
+    normed = normed * weight.astype(jnp.float32)
+    if bias is not None:
+        normed = normed + bias.astype(jnp.float32)
+    return normed.astype(x.dtype)
 
 
 def block_norm(x: jax.Array, weight: jax.Array, c: ModelConfig) -> jax.Array:

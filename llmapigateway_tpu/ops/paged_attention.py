@@ -1596,8 +1596,25 @@ def make_paged_attention_fn(page_table: jax.Array, max_seq: int,
         return paged_insert_all(pool_k, pool_v, k_news, v_news,
                                 page_table, lengths, active)
 
+    def write_at(k_new, v_new, pool_k, pool_v, layer, lengths, active=None):
+        """A chunk's rows into layer ``layer`` of the stacked pool and
+        NOTHING attended: a layer whose K/V other layers read (a cross
+        decoder's full-context layer, models/sambay.py) writes every row
+        of a chunk and attends from one. Returns the pool whole."""
+        with jax.named_scope("kv.paged_insert"):
+            if in_place:
+                return paged_insert_chunk_in_place(
+                    pool_k, pool_v, k_new, v_new, page_table, lengths,
+                    active, layer=layer, interpret=interpret)
+            side = jax.tree.map(lambda a: a[layer], (pool_k, pool_v))
+            side = paged_insert_kv(*side, k_new, v_new, page_table, lengths,
+                                   active)
+            return jax.tree.map(lambda a, new: a.at[layer].set(new),
+                                (pool_k, pool_v), side)
+
     attention_fn.decode = decode
     attention_fn.insert_all = insert_all
+    attention_fn.write_at = write_at
     if in_place:
         attention_fn.decode_at = decode_at
         attention_fn.prefill_at = prefill_at

@@ -14,17 +14,21 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 def lower_step_program(config, device, program: str, *, quant: str,
                        kv_quant: str, dtype, page: int, slots: int,
-                       per_slot: int, depth: int, chunk: int = 512):
+                       per_slot: int, depth: int, chunk: int = 512,
+                       group_pages: tuple[int, ...] | None = None):
     """``decode``: the greedy ``decode_scan`` of ``depth`` steps;
     ``prefill-<rows>``: ``prefill_step`` on that many rows of ``chunk``
     tokens. ``slots`` slots of ``per_slot`` pages of ``page`` tokens (and
-    the trash page). Returns (the lowered program, the cache's shapes)."""
+    the trash page; ``group_pages``: the pages of each cache group's pool
+    instead, where a ring's pool is smaller than a whole context's).
+    Returns (the lowered program, the cache's shapes)."""
     from llmapigateway_tpu.engine.engine import InferenceEngine
     from llmapigateway_tpu.engine.sampling import SamplingParams
     from llmapigateway_tpu.models import hybrid
     from llmapigateway_tpu.parallel.mesh import build_mesh
 
     pages = slots * per_slot + 1
+    config = config.served()
     mesh = build_mesh({}, devices=[device])
     engine = types.SimpleNamespace(
         model_cfg=config, quant=quant, kv_quant=kv_quant, dtype=dtype,
@@ -41,7 +45,7 @@ def lower_step_program(config, device, program: str, *, quant: str,
     def shapes(tree):
         return jax.tree.map(lambda s: sds(s.shape, s.dtype), tree)
     cache = shapes(jax.eval_shape(lambda: hybrid.HybridCache.create(
-        config, pages, page, slots, dtype, kv_quant)))
+        config, group_pages or pages, page, slots, dtype, kv_quant)))
     state = (shapes(jax.eval_shape(init, key)), cache,
              sds((slots, config.vocab_size), jnp.int32),
              tuple(sds((slots, per_slot), jnp.int32)
@@ -62,4 +66,5 @@ def lower_step_program(config, device, program: str, *, quant: str,
     return engine._prefill_fn.lower(
         *state, sds((rows, chunk), jnp.int32), vec(jnp.int32),
         vec(jnp.int32), vec(jnp.int32), vec(jnp.float32), vec(jnp.float32),
-        vec(jnp.int32), vec(jnp.float32), vec(jnp.float32), rng), cache
+        vec(jnp.int32), vec(jnp.float32), vec(jnp.float32), rng,
+        *([vec(jnp.bool_)] if engine._rows_stop else [])), cache

@@ -117,7 +117,7 @@ def test_the_presets_count_a_latent_group_beside_the_state_blocks():
         12, 12, 0)
     assert PRESETS["mistral-7b"].n_periods == 0
     with pytest.raises(ValueError, match="unknown lin_kind"):
-        ModelConfig(lin_kind="mamba")
+        ModelConfig(lin_kind="rwkv")
     with pytest.raises(ValueError, match="they need lin_heads"):
         ModelConfig(leading_dense=2)
     with pytest.raises(ValueError, match="whole periods of layers behind "
