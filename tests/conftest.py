@@ -2,10 +2,11 @@
 
 JAX runs on 8 virtual CPU devices (the standard trick for exercising
 multi-chip mesh/collective code without TPU hardware — SURVEY.md §4c).
-Both environment settings must be in place before any jax import, hence
-here at conftest import time: ``JAX_PLATFORMS=cpu`` keeps every test on
-the host (fp32 numerics comparisons need the CPU's matmul precision), and
-the XLA flag provides the devices.
+Every setting below must be in place before any jax import, hence here at
+conftest import time, and in the ENVIRONMENT, so that a child process a
+test starts compiles and imports as its worker does: ``JAX_PLATFORMS=cpu``
+keeps every test on the host (fp32 numerics comparisons need the CPU's
+matmul precision), and the XLA flag provides the devices.
 """
 import os
 
@@ -14,17 +15,26 @@ _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (
         _flags + " --xla_force_host_platform_device_count=8").strip()
+# A test's program is a two-layer model a few dozen wide, run for a handful
+# of steps: compiling it as if it would serve is most of a file's seconds.
+# jax's own setting for that case (backend optimisation level 0, LLVM's
+# expensive passes off). What asserts on a COMPILED program's text or cost
+# restores full effort for its module: ``full_effort_uncached`` in
+# tests/test_aot_tpu_compile.py.
+os.environ["JAX_DISABLE_MOST_OPTIMIZATIONS"] = "1"
+# ``transformers`` loads TensorFlow and Flax where it finds them; the files
+# that build a tiny HF checkpoint use torch alone.
+os.environ["USE_TF"] = "0"
+os.environ["USE_FLAX"] = "0"
 
 # One XLA compilation cache a PROCESS, thrown away with it (every compile
 # goes in: no floor on its seconds or its bytes). Engines and kernels of
 # one configuration are built again and again by the cases of a file,
 # each build traces anew, and an interpreted Pallas kernel compiles for
 # seconds: with the cache the second build of a program is a read, which
-# halves the engine files (PR 45: 68 s -> 30 s for six cases of
-# test_speculative.py). Not one directory for the run: xdist's workers
+# halves the engine files. Not one directory for the run: xdist's workers
 # would write entries side by side (jax writes them in place, not by
-# rename), and a parity rerun's fresh process (below) has to compile
-# afresh. The directory is set whatever the environment says, so no run
+# rename). The directory is set whatever the environment says, so no run
 # reads what another left. The engine's cache PLACEMENT has its own tests
 # (test_compilation_cache.py).
 import atexit           # noqa: E402
@@ -173,36 +183,56 @@ def build_engine(stop_engine):
 
 # ``--dist loadfile`` hands a worker whole files in collection order, two
 # at a time, so a long file late in the alphabet starts in the run's last
-# minutes and is the wall's tail with five workers idle (PR 45's first
-# whole run: test_prefill_pool_carried.py, then 268 s, ended a run of
-# 6,085 worker-seconds at 1,135 s). The files that hold a worker longest
-# go out first, longest first (seconds on a worker in PR 49's whole run:
-# 1,316 s, 7,565 worker-seconds in 90 files); the rest keep their order,
-# a file its cases' order. A stale list costs balance, nothing else.
+# minutes and is the wall's tail with five workers idle. The files that
+# hold a worker longest go out first, longest first (seconds on a worker,
+# the sum of a file's cases in a whole run's junit XML; PERF.md section 6
+# has the run); the rest keep their order, a file its cases' order. A
+# stale list costs balance, nothing else.
 LONGEST_FIRST = (
-    "test_spec_discovery.py",               # 477 (tests/bench_harness/)
-    "test_aot_tpu_programs.py",             # 445
-    "test_quant.py",                        # 276
-    "test_model_hybrid.py",                 # 254
-    "test_ops_grouped_experts.py",          # 217
-    "test_speculative.py",                  # 215
-    "test_command_a_plus_rehearsal.py",     # 201 (tests/bench_harness/)
-    "test_gigachat35_rehearsal.py",         # 198 (tests/bench_harness/)
-    "test_engine_cache_groups.py",          # 196
-    "test_model_cohere2.py",                # 190
-    "test_kv_quant.py",                     # 189
-    "test_ops_paged_decode_fold.py",        # 185
-    "test_mistral_small4_rehearsal.py",     # 182 (tests/bench_harness/)
-    "test_model_hybrid_experts.py",         # 175
-    "test_ops_paged_prefill_fold.py",       # 174
-    "test_engine_hybrid.py",                # 173
-    "test_queued_metric_files.py",          # 170 (tests/bench_harness/)
-    "test_model_mistral.py",                # 169
-    "test_engine.py",                       # 168
-    "test_ops_paged_chunk_write.py",        # 158
-    "test_model_mistral4.py",               # 152
-    "test_phi4_flash_rehearsal.py",         # ~150 (tests/bench_harness/;
-    #                                         100 s alone, PR 54)
+    "test_spec_discovery.py",               # 373 (tests/bench_harness/)
+    "test_kv_quant.py",                     # 265
+    "test_model_hybrid.py",                 # 224
+    "test_aot_tpu_state_families.py",       # 221
+    "test_keye_vl2_rehearsal.py",           # 182 (tests/bench_harness/)
+    "test_ops_paged_decode_fold.py",        # 178
+    "test_solar_open2_rehearsal.py",        # 178 (tests/bench_harness/)
+    "test_speculative.py",                  # 174
+    "test_ops_grouped_experts.py",          # 172
+    "test_engine_pool_in_place.py",         # 163
+    "test_engine_hybrid.py",                # 158
+    "test_ops_paged_prefill_fold.py",       # 157
+    "test_smallthinker_rehearsal.py",       # 150 (tests/bench_harness/)
+    "test_phi4_flash_rehearsal.py",         # 145 (tests/bench_harness/)
+    "test_aot_tpu_programs.py",             # 144
+    "test_model_cohere2.py",                # 139
+    "test_model_phi4_flash.py",             # 135
+    "test_quant.py",                        # 134
+    "test_ops_paged_chunk_write.py",        # 133
+    "test_model_keye_vl2.py",               # 132
+    "test_queued_metric_files.py",          # 131 (tests/bench_harness/)
+    "test_engine.py",                       # 128
+    "test_model_smallthinker.py",           # 127
+    "test_command_a_plus_rehearsal.py",     # 127 (tests/bench_harness/)
+    "test_engine_cache_groups.py",          # 122
+    "test_aot_tpu_phi4_flash.py",           # 122
+    "test_engine_paged.py",                 # 121
+    "test_chip_smoke.py",                   # 118
+    "test_ops_grouped_experts_edges.py",    # 117
+    "test_ops_paged_in_place.py",           # 115
+    "test_model_hybrid_experts.py",         # 115
+    "test_gigachat35_rehearsal.py",         # 114 (tests/bench_harness/)
+    "test_model_gigachat35.py",             # 114
+    "test_model_mistral4.py",               # 113
+    "test_engine_pool_carried.py",          # 109
+    "test_ops_paged.py",                    # 100
+    "test_prefill_pool_carried.py",         # 99
+    "test_aot_tpu_compile.py",              # 97
+    "test_request_wait_metric_files.py",    # 89 (tests/bench_harness/)
+    "test_ops_paged_multipage.py",          # 88
+    "test_model_mistral.py",                # 88
+    "test_mistral_small4_rehearsal.py",     # 81 (tests/bench_harness/)
+    "test_hybrid_programs_pinned.py",       # 81
+    "test_engine_supervision.py",           # 75
 )
 
 
@@ -253,10 +283,8 @@ def pytest_pyfunc_call(pyfuncitem):
 # slowest case the file serves is a 213 s rehearsal under
 # tests/bench_harness/ (six files side by side on eight cores); the static
 # case in tests/test_time_limit.py holds every subprocess and wait_for
-# timeout under tests/ to this limit, and a failed parity test below costs
-# its worker two fresh processes of PARITY_RERUN_LIMIT_S at most.
+# timeout under tests/ to this limit.
 PER_TEST_LIMIT_S = 500
-PARITY_RERUN_LIMIT_S = 180
 
 
 @pytest.hookimpl(wrapper=True)
@@ -284,145 +312,6 @@ def pytest_runtest_call(item, limit_s=PER_TEST_LIMIT_S):
         signal.setitimer(signal.ITIMER_REAL, 0)
         faulthandler.cancel_dump_traceback_later()
         signal.signal(signal.SIGALRM, before)
-
-
-# Exact-greedy-parity tests compare token streams between two engines
-# whose programs are compiled independently. XLA CPU compilation is not
-# bit-deterministic across compiles WITHIN one process (isolated repro:
-# bit-identical post-prefill state + the same burst depth, fresh engine
-# per iteration, zero async timing in between -> ~10% of iterations
-# produce a second, internally-deterministic token stream; fresh
-# PROCESSES always produce the first one, and single-threaded Eigen /
-# fast-math-off don't change it — i.e. a compile-instance 1-ulp
-# variation, not an engine race). On random tiny-test weights a 1-ulp
-# logit shift flips near-tie argmaxes, so a parity test can observe two
-# CORRECT-but-different greedy continuations. Rerun exactly those tests
-# on failure IN A FRESH SUBPROCESS (fresh processes deterministically
-# get the first compile; an in-process rerun re-observes the same
-# flipped stream): an extrinsic compile flip passes in the fresh
-# process; a real protocol bug (token loss, mirror desync — what these
-# tests exist to catch) fails there too. Scoped by TEST NAME, not file,
-# so a genuinely intermittent failure in any other test is never masked.
-_PARITY_RERUN_TESTS = {
-    # test_engine.py
-    "test_batched_admission_matches_sequential",
-    "test_prefill_group_matches_single_calls",
-    "test_concurrent_batching", "test_deterministic_greedy",
-    "test_pipelined_bursts_match_sync_engine",
-    "test_pipelined_slot_reuse_no_token_bleed",
-    "test_tp_serving_engages_sharded_pallas_kernels",
-    # test_engine_paged.py
-    "test_paged_concurrent_batching_no_corruption",
-    "test_pool_matches_dense_reference_greedy",
-    "test_swa_pool_matches_dense_reference_greedy",
-    "test_swa_ring_serves_full_context_from_small_pool",
-    # test_kv_quant.py
-    "test_engine_pallas_with_kv_quant_matches_reference",
-    # test_model_mistral.py
-    "test_engine_swa_composes_with_spec",
-    "test_engine_swa_paged_pallas_matches_reference",
-    "test_engine_swa_paged_sharded_pallas_matches_reference",
-    "test_engine_swa_paged_spec_ring_matches_reference",
-    # test_speculative.py
-    "test_adaptive_gate_closes_on_low_acceptance",
-    "test_spec_engine_serves_sampled_via_normal_path",
-    "test_spec_greedy_parity", "test_spec_greedy_parity_paged",
-}
-
-
-# Parity-rerun adjudications recorded this session: (nodeid, verdict,
-# detail). Surfaced two ways so subprocess-retry-masked in-process
-# failures stay visible in CI logs: on the passed call report's
-# ``user_properties`` (machine-readable — junitxml emits them) and in a
-# terminal-summary section at the end of the run.
-_PARITY_ADJUDICATIONS: list[tuple[str, str, str]] = []
-
-
-def pytest_runtest_protocol(item, nextitem):
-    import subprocess
-    import sys
-    from _pytest.runner import runtestprotocol
-    if getattr(item, "originalname", None) not in _PARITY_RERUN_TESTS:
-        return None
-    if os.environ.get("_PARITY_RERUN_CHILD") == "1":
-        return None     # the fresh-process retry must not retry again
-    item.ihook.pytest_runtest_logstart(nodeid=item.nodeid,
-                                       location=item.location)
-    reports = runtestprotocol(item, nextitem=nextitem, log=False)
-    if any(r.failed for r in reports):
-        # Retry in a FRESH SUBPROCESS, not in-process: the root-caused
-        # flake mode (see note above) is an in-process engine rebuild
-        # latching a second, internally-deterministic compile instance —
-        # an in-process rerun re-observes the same flipped stream and
-        # fails deterministically, while fresh processes were measured
-        # bit-stable 14/14. A real protocol bug fails in the fresh
-        # process too.
-        sys.stderr.write(
-            f"\n[parity-rerun] {item.nodeid} failed; retrying in a fresh "
-            "process (XLA-CPU compile nondeterminism can flip near-tie "
-            "argmax on random weights — see conftest)\n")
-        sub = None
-        for _attempt in range(2):       # two fresh processes: one can hit
-            try:                        # transient load/contention noise
-                sub = subprocess.run(
-                    [sys.executable, "-m", "pytest", item.nodeid,
-                     "-q", "-x"],
-                    capture_output=True, text=True,
-                    timeout=PARITY_RERUN_LIMIT_S,
-                    cwd=str(item.config.rootpath),
-                    env={**os.environ, "_PARITY_RERUN_CHILD": "1"})
-            except subprocess.TimeoutExpired:
-                # A hung retry (the environment this policy exists for)
-                # must record the original failure, not crash the session.
-                sub = subprocess.CompletedProcess(
-                    [], returncode=124,
-                    stdout="fresh-process retry timed out")
-            if sub.returncode == 0:
-                break
-        if sub.returncode == 0:
-            # Fresh-process pass: replace the failed call report with the
-            # retry's outcome so the suite records the adjudicated result —
-            # and stamp the adjudication on the report so the masked
-            # in-process failure stays visible (user_properties + summary).
-            for r in reports:
-                if r.when == "call" and r.failed:
-                    orig = str(r.longrepr)[-800:] if r.longrepr else ""
-                    r.outcome = "passed"
-                    r.longrepr = None
-                    r.user_properties.append(
-                        ("parity_rerun", "adjudicated-pass"))
-                    r.user_properties.append(
-                        ("parity_rerun_masked_failure", orig))
-                    _PARITY_ADJUDICATIONS.append(
-                        (item.nodeid, "adjudicated-pass",
-                         "in-process failure passed in a fresh process "
-                         "(XLA-CPU compile-instance flip)"))
-        else:
-            sys.stderr.write(
-                f"[parity-rerun] fresh-process retry FAILED (real "
-                f"failure):\n{sub.stdout[-2000:]}\n")
-            for r in reports:
-                if r.when == "call" and r.failed:
-                    r.user_properties.append(
-                        ("parity_rerun", "confirmed-failure"))
-            _PARITY_ADJUDICATIONS.append(
-                (item.nodeid, "confirmed-failure",
-                 "failed in-process AND in the fresh-process retry"))
-    for r in reports:
-        item.ihook.pytest_runtest_logreport(report=r)
-    item.ihook.pytest_runtest_logfinish(nodeid=item.nodeid,
-                                        location=item.location)
-    return True
-
-
-def pytest_terminal_summary(terminalreporter):
-    """One summary line per parity-rerun adjudication, so a retry-masked
-    failure is never invisible in CI logs (warnings-summary analog)."""
-    if not _PARITY_ADJUDICATIONS:
-        return
-    terminalreporter.write_sep("=", "parity-rerun adjudications")
-    for nodeid, verdict, detail in _PARITY_ADJUDICATIONS:
-        terminalreporter.write_line(f"{verdict}: {nodeid} — {detail}")
 
 
 PROVIDERS_JSON5 = """\

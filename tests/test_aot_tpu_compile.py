@@ -49,15 +49,22 @@ def chips():
 
 
 @pytest.fixture(scope="module", autouse=True)
-def no_compile_cache():
-    """A compile for a described chip can be written to the persistent
-    cache but not read back without the chip: keep it off, and silent."""
+def full_effort_uncached():
+    """What a module that asserts on a COMPILED program needs of the
+    compiler. Full effort: tier-1 compiles its tiny programs with most
+    optimisations off (tests/conftest.py), and VMEM fit, a pool that is not
+    copied and a loop's arrays are read off the program a served engine
+    would get. No persistent cache: a compile for a described chip can be
+    written to it but not read back without the chip, and says so."""
     from jax.experimental.compilation_cache import compilation_cache
-    saved = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
+    saved = {name: jax.config.values[name] for name in (
+        "jax_disable_most_optimizations", "jax_enable_compilation_cache")}
+    for name in saved:
+        jax.config.update(name, False)
     compilation_cache.reset_cache()
     yield
-    jax.config.update("jax_enable_compilation_cache", saved)
+    for name, value in saved.items():
+        jax.config.update(name, value)
     compilation_cache.reset_cache()
 
 

@@ -16,7 +16,7 @@ from jax.sharding import SingleDeviceSharding
 
 from llmapigateway_tpu.ops import paged_attention as pa
 from test_aot_tpu_compile import (DH, PAGE, chips,      # noqa: F401
-                                  no_compile_cache)
+                                  full_effort_uncached)
 from test_aot_tpu_programs import _loop_arrays
 
 

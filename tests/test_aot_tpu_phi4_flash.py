@@ -15,7 +15,8 @@ import jax.numpy as jnp
 import pytest
 
 from llmapigateway_tpu.ops import paged_attention as pa
-from test_aot_tpu_compile import PAGE, chips, no_compile_cache  # noqa: F401
+from test_aot_tpu_compile import (PAGE, chips,          # noqa: F401
+                                  full_effort_uncached)
 
 SLOTS, PER_SLOT, RING = 32, 128, 7
 GROUP_PAGES = (SLOTS * RING + 1, SLOTS * PER_SLOT + 1)

@@ -262,7 +262,7 @@ async def test_kernel_table_acceptance_two_kernels_reconcile(engine):
     with the aggregate hbm_bytes_per_step within 10%, and a worst
     kernel is named (hbm_peak_gbps is set on this engine)."""
     await _run_one(engine, range(2, 40), rid="dev-1")
-    engine.kernels.resolve_costs()
+    await asyncio.to_thread(engine.kernels.resolve_costs)   # it compiles
     rows = engine.kernel_table()
     assert len({r["kernel"] for r in rows}) >= 2, rows
     kinds = {r["kind"] for r in rows}

@@ -13,6 +13,7 @@ plus fake remote upstreams.
 * Chaos: a deadline expiring mid-stream leaves no leaked (unclosed)
   spans.
 """
+import asyncio
 import json
 
 import jax
@@ -346,9 +347,12 @@ async def test_roofline_per_kernel_table_and_hbm_ledger(tmp_path,
                   "messages": [{"role": "user", "content": "roofline"}]})
         await read_sse_frames(resp)
 
-        # Resolve pending cost closures synchronously so the table rows
-        # carry the cost_analysis columns deterministically.
-        g.local_factory.engines["tpu"].kernels.resolve_costs()
+        # Resolve pending cost closures before the read, so that the table
+        # rows carry the cost_analysis columns deterministically — off the
+        # loop, as the server does: every closure lowers and compiles, and
+        # the sanitizer fails a loop that stood still for five seconds.
+        await asyncio.to_thread(
+            g.local_factory.engines["tpu"].kernels.resolve_costs)
         resp = await g.client.get("/v1/api/roofline")
         assert resp.status == 200
         block = (await resp.json())["engines"]["tpu"]
@@ -541,7 +545,6 @@ async def test_slo_violation_attributed_queued_metrics_db_and_usage(
     violation attributed in its usage DB row, and the SLO block in its
     usage payload. A loose-SLO request then lands on the met counter and
     the goodput gauge."""
-    import asyncio
     from llmapigateway_tpu.engine.engine import FaultPlan
     async with ObsGateway(tmp_path, local_factory) as g:
         # Saturate both slots: generation runs server-side regardless of

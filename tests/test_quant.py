@@ -7,6 +7,7 @@ No reference counterpart (the reference executes no models); test style
 follows SURVEY.md §4 (c) mesh-on-CPU and (d) numerics-fidelity patterns.
 """
 import asyncio
+from dataclasses import replace
 
 import jax
 import jax.numpy as jnp
@@ -167,21 +168,56 @@ def test_engine_e2e_with_quant(preset):
     assert len(req.generated) == 12
 
 
+def _write_llama_checkpoint(path, cfg, seed: int, tied: bool = False) -> None:
+    """A llama checkpoint of ``cfg``'s shapes as HF lays one out —
+    ``model.safetensors`` beside a ``config.json`` — drawn as HF draws a
+    fresh model: weights at 0.02, norms at one. No HF forward is compared
+    with, so neither torch nor transformers is loaded to write it."""
+    import json
+    from safetensors.numpy import save_file
+    rng = np.random.default_rng(seed)
+    D, dh = cfg.d_model, cfg.head_dim
+
+    def drawn(*shape):
+        return (rng.standard_normal(shape) * 0.02).astype(np.float32)
+    tensors = {"model.embed_tokens.weight": drawn(cfg.vocab_size, D),
+               "model.norm.weight": np.ones((D,), np.float32)}
+    if not tied:
+        tensors["lm_head.weight"] = drawn(cfg.vocab_size, D)
+    for i in range(cfg.n_layers):
+        p = f"model.layers.{i}."
+        for name in ("input_layernorm", "post_attention_layernorm"):
+            tensors[f"{p}{name}.weight"] = np.ones((D,), np.float32)
+        for name, shape in (
+                ("self_attn.q_proj", (cfg.n_heads * dh, D)),
+                ("self_attn.k_proj", (cfg.n_kv_heads * dh, D)),
+                ("self_attn.v_proj", (cfg.n_kv_heads * dh, D)),
+                ("self_attn.o_proj", (D, cfg.n_heads * dh)),
+                ("mlp.gate_proj", (cfg.d_ff, D)),
+                ("mlp.up_proj", (cfg.d_ff, D)),
+                ("mlp.down_proj", (D, cfg.d_ff))):
+            tensors[f"{p}{name}.weight"] = drawn(*shape)
+    save_file(tensors, str(path / "model.safetensors"))
+    (path / "config.json").write_text(json.dumps({
+        "model_type": "llama", "vocab_size": cfg.vocab_size,
+        "hidden_size": D, "num_hidden_layers": cfg.n_layers,
+        "num_attention_heads": cfg.n_heads,
+        "num_key_value_heads": cfg.n_kv_heads,
+        "intermediate_size": cfg.d_ff,
+        "max_position_embeddings": cfg.max_seq_len,
+        "rms_norm_eps": cfg.rms_eps, "tie_word_embeddings": tied}))
+
+
+# The two checkpoint cases below assert on a head of 128 x 64.
+CKPT_CFG = replace(get_preset("tiny-test"), vocab_size=128)
+
+
 def test_checkpoint_load_quantizes_on_host(tmp_path):
     """quant="int8" on a checkpoint engine quantizes each parameter on the
     host (the put hook receives bf16, places int8) and still serves."""
-    torch = pytest.importorskip("torch")
-    transformers = pytest.importorskip("transformers")
     from llmapigateway_tpu.engine.engine import InferenceEngine
 
-    hf_cfg = transformers.LlamaConfig(
-        vocab_size=128, hidden_size=64, intermediate_size=128,
-        num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2,
-        max_position_embeddings=256, rms_norm_eps=1e-5,
-        tie_word_embeddings=False)
-    torch.manual_seed(0)
-    transformers.LlamaForCausalLM(hf_cfg).save_pretrained(
-        tmp_path, safe_serialization=True)
+    _write_llama_checkpoint(tmp_path, CKPT_CFG, seed=0)
 
     cfg = LocalEngineConfig(kv_page_size=16,
                             model_path=str(tmp_path), max_batch_size=1,
@@ -229,18 +265,9 @@ def test_tied_head_quant_fidelity_and_structure():
 def test_checkpoint_tied_head_quantizes_on_device(tmp_path):
     """A TIED checkpoint (no lm_head tensor) under quant="int8" gets its
     head copy synthesized on device post-load (engine/_init_params)."""
-    torch = pytest.importorskip("torch")
-    transformers = pytest.importorskip("transformers")
     from llmapigateway_tpu.engine.engine import InferenceEngine
 
-    hf_cfg = transformers.LlamaConfig(
-        vocab_size=128, hidden_size=64, intermediate_size=128,
-        num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2,
-        max_position_embeddings=256, rms_norm_eps=1e-5,
-        tie_word_embeddings=True)
-    torch.manual_seed(1)
-    transformers.LlamaForCausalLM(hf_cfg).save_pretrained(
-        tmp_path, safe_serialization=True)
+    _write_llama_checkpoint(tmp_path, CKPT_CFG, seed=1, tied=True)
 
     cfg = LocalEngineConfig(kv_page_size=16,
                             model_path=str(tmp_path), max_batch_size=1,
@@ -440,40 +467,9 @@ def test_engine_e2e_with_int4(preset):
 def test_int4_checkpoint_load_quantizes_on_host(tmp_path):
     """quant="int4" on a checkpoint engine: the preprocess hook stores
     int4 at source precision; lm_head arrives int8."""
-    from safetensors.numpy import save_file
     from llmapigateway_tpu.engine.engine import InferenceEngine
 
-    cfg = get_preset("tiny-test")
-    rng = np.random.default_rng(7)
-    tensors = {}
-    D, dh = cfg.d_model, cfg.head_dim
-    tensors["model.embed_tokens.weight"] = rng.standard_normal(
-        (cfg.vocab_size, D)).astype(np.float32) * 0.02
-    tensors["model.norm.weight"] = np.ones((D,), np.float32)
-    tensors["lm_head.weight"] = rng.standard_normal(
-        (cfg.vocab_size, D)).astype(np.float32) * 0.02
-    for i in range(cfg.n_layers):
-        p = f"model.layers.{i}."
-        for name, shape in (
-                ("input_layernorm.weight", (D,)),
-                ("post_attention_layernorm.weight", (D,)),
-                ("self_attn.q_proj.weight", (cfg.n_heads * dh, D)),
-                ("self_attn.k_proj.weight", (cfg.n_kv_heads * dh, D)),
-                ("self_attn.v_proj.weight", (cfg.n_kv_heads * dh, D)),
-                ("self_attn.o_proj.weight", (D, cfg.n_heads * dh)),
-                ("mlp.gate_proj.weight", (cfg.d_ff, D)),
-                ("mlp.up_proj.weight", (cfg.d_ff, D)),
-                ("mlp.down_proj.weight", (D, cfg.d_ff))):
-            tensors[p + name] = (rng.standard_normal(shape) * 0.02
-                                 ).astype(np.float32)
-    save_file(tensors, str(tmp_path / "model.safetensors"))
-    import json as _json
-    (tmp_path / "config.json").write_text(_json.dumps({
-        "model_type": "llama", "vocab_size": cfg.vocab_size,
-        "hidden_size": D, "num_hidden_layers": cfg.n_layers,
-        "num_attention_heads": cfg.n_heads,
-        "num_key_value_heads": cfg.n_kv_heads,
-        "intermediate_size": cfg.d_ff}))
+    _write_llama_checkpoint(tmp_path, get_preset("tiny-test"), seed=7)
 
     eng = InferenceEngine(LocalEngineConfig(
         model_path=str(tmp_path), max_batch_size=1, max_seq_len=64,
