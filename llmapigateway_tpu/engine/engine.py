@@ -57,6 +57,7 @@ import asyncio
 import inspect
 import logging
 import os
+import resource
 import time
 from dataclasses import dataclass, field, replace
 from functools import partial
@@ -71,6 +72,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..config.schemas import LocalEngineConfig
 from ..models import forward_fn, init_fn
 from ..models.config import ModelConfig, get_preset
+from ..obs.device import part as _part
 from ..obs.device import phase as _device_phase
 from ..obs.phases import ReqWaits, SchedLedger
 from ..parallel.mesh import MeshSpec, build_mesh
@@ -457,6 +459,10 @@ class InferenceEngine:
         # which ``_compile`` reads off the family's forward).
         self._cross_decode_keys_read = 0
         self._prefill_rows_stopped = 0
+        # Prefill calls that returned without a read (ISSUE 56): no row
+        # ended its prompt, so nothing was fetched and the call's device
+        # time is waited out in the next wait. Monotone; worker thread.
+        self._prefill_calls_unread = 0
         # The keys the decode programs' paged kernel calls attended
         # (ISSUE 44), kept the same way: per layer of a K/V cache group,
         # summed over steps and active slots — a global group's step sees
@@ -1825,11 +1831,13 @@ class InferenceEngine:
                         self._emit_token(req)  # first token, off prefill
         else:
             groups: dict[int, list[GenRequest]] = {}
-            for req in eligible:
-                pos = req.prefill_pos
-                ch = min(self.prefill_chunk, len(req.prompt_ids) - pos)
-                bucket = min(_bucket(ch, self.prefill_chunk), self.S - pos)
-                groups.setdefault(bucket, []).append(req)
+            with _device_phase("sched.plan"):
+                for req in eligible:
+                    pos = req.prefill_pos
+                    ch = min(self.prefill_chunk, len(req.prompt_ids) - pos)
+                    bucket = min(_bucket(ch, self.prefill_chunk),
+                                 self.S - pos)
+                    groups.setdefault(bucket, []).append(req)
             for reqs in groups.values():
                 pending = reqs
                 while pending:
@@ -1891,176 +1899,14 @@ class InferenceEngine:
         decoding = [r for r in self._running.values()
                     if not r.done and r.slot not in self._prefilling]
         if decoding:
-            # Prefill-aware (DistServe/Sarathi-style interleave): any
-            # admission waiting — queued, parked at the FIFO head for a
-            # page reservation, or mid-chunked-prefill — clamps the next
-            # burst so prefill work never starves behind a deep scan.
-            busy = (self._head is not None or not self._queue.empty()
-                    or bool(self._prefilling))
-            # Speculation verifies against argmax, so it engages only while
-            # EVERY active slot is greedy (the common serving case);
-            # sampled requests flip the whole batch to the normal burst
-            # path for their lifetime — mixed batches stay correct, just
-            # unaccelerated.
-            spec_now = self.spec_k and self._all_greedy()
-            # Adaptive drafting gate: drafting only pays while accepted
-            # tokens/step clears the verify forward's overhead
-            # (config.spec_min_tokens_per_step). Below it, decode normally
-            # and re-probe with a single spec step every
-            # spec_probe_interval rounds — so enabling speculation in
-            # config is safe for non-repetitive traffic.
-            spec_probe = False
-            if spec_now and self._spec_wall_gate_on:
-                # Baseline probe: the wall gate needs a NORMAL-path step
-                # time to compare against, and spec-open traffic never
-                # runs normal bursts. Two consecutive normal rounds (a
-                # steady same-depth pair is what lands a wall sample),
-                # immediately while no baseline exists, then refreshed
-                # every 8*spec_probe_interval spec rounds.
-                if self._spec_base_rounds > 0:
-                    self._spec_base_rounds -= 1
-                    spec_now = False
-                else:
-                    est = self._step_ms_estimate()
-                    if est is not None:
-                        self._spec_base_fails = 0
-                    self._spec_base_ctr += 1
-                    # Periodic refresh only while a baseline EXISTS —
-                    # once the starvation guard trips (workload can't
-                    # land wall samples), probing again by schedule
-                    # would pay the same fruitless normal rounds
-                    # forever.
-                    if ((est is None and self._spec_base_fails < 4)
-                            or (est is not None
-                                and self._spec_base_ctr
-                                >= 8 * self.spec_probe_interval)):
-                        self._spec_base_ctr = 0
-                        if est is None:
-                            self._spec_base_fails += 1
-                        self._spec_base_rounds = 1
-                        spec_now = False
-            if spec_now and (self.spec_min_tps > 0
-                             or self._spec_wall_gate_on):
-                # A batch with NO measured slots always drafts — the burst
-                # IS the measurement. Unmeasured slots in a mixed batch
-                # count optimistically (k+1) so fresh requests can re-open
-                # the gate; one low burst closes it again. The wall-clock
-                # term applies even with the acceptance threshold
-                # disabled (spec_min_tokens_per_step=0): each protects
-                # against a different failure mode.
-                below = False
-                if self.spec_min_tps > 0:
-                    slots = [r.slot for r in decoding]
-                    if self.spec_floor > 0:
-                        # Per-slot suspension already benches poor slots —
-                        # their frozen EMAs must not drag the BATCH mean
-                        # below the threshold and close the gate on the
-                        # slots that are still profiting. (All-suspended
-                        # batches skip the burst below regardless of what
-                        # the mean says.)
-                        slots = [s for s in slots
-                                 if not self._spec_suspended[s]] or slots
-                    ema = self._spec_ema[slots]
-                    if not np.all(np.isnan(ema)):
-                        mean_tps = float(np.mean(np.where(
-                            np.isnan(ema), self.spec_k + 1, ema)))
-                        below = mean_tps < self.spec_min_tps
-                wall_lose = self._spec_wall_loses()
-                if below or wall_lose:
-                    self._spec_probe_ctr += 1
-                    if self._spec_probe_ctr >= self.spec_probe_interval:
-                        self._spec_probe_ctr = 0
-                        spec_probe = True            # 1-step re-measure
-                        # A probe re-measures ACCEPTANCE only. If the
-                        # WALL term is what closed the gate, drop the
-                        # wall gauge every few probe cycles so one full
-                        # burst can re-time it under current conditions
-                        # (bounded tax: one possibly-slow burst per 4
-                        # probe intervals). An acceptance-only close
-                        # must NOT drop it — no full spec burst would
-                        # run to re-measure, silently losing the gauge
-                        # (and its stats field) while a stale-free
-                        # baseline still protects the reopen path.
-                        if wall_lose and not below:
-                            self._spec_wall_age += 1
-                            if (self._spec_ms_per_tok is not None
-                                    and self._spec_wall_age >= 4):
-                                self._spec_wall_age = 0
-                                self._spec_ms_per_tok = None
-                    else:
-                        spec_now = False
-            if spec_now and self.spec_floor > 0 and not spec_probe:
-                # Per-slot adaptive drafting (spec_acceptance_floor):
-                # suspended slots ride along in the k+1-wide verify at a
-                # deterministic 1 token/step, so when EVERY decoding slot
-                # is suspended the burst is pure overhead — decode
-                # normally instead, and every spec_probe_interval such
-                # rounds run ONE probe burst with the mask lifted so
-                # suspended slots get re-measured (text regimes change;
-                # a permanent bench would strand them). A mixed batch
-                # keeps bursting (drafting slots still profit) and the
-                # same cadence lifts the mask for its benched slots.
-                susp = sum(bool(self._spec_suspended[r.slot])
-                           for r in decoding)
-                if susp:
-                    self._spec_suspend_probe_ctr += 1
-                    if (self._spec_suspend_probe_ctr
-                            >= self.spec_probe_interval):
-                        self._spec_suspend_probe_ctr = 0
-                        spec_probe = True        # 1-step, mask lifted
-                    elif susp == len(decoding):
-                        spec_now = False
-            # While a spec burst is in flight (lag-one), the host lengths
-            # lag dispatch by a data-dependent amount — cap against the
-            # worst case (every in-flight step fully accepted).
-            inflight = self._spec_inflight_advance() if self.spec_k else 0
+            busy, spec_now, spec_probe, burst = self._plan_burst(decoding)
             if spec_now:
-                # A slot whose dispatch-true length is within k of the
-                # cache extent can't fit a k+1-wide verify (possible when
-                # lag-one normal bursts ran it ahead of emission): fall
-                # back to the 1-wide normal path until emission retires it.
-                spec_now = all(
-                    self.S - (int(self.lengths[r.slot]) + inflight)
-                    >= self.spec_k + 1
-                    for r in decoding)
-            if spec_now:
-                # Speculative steps advance 1..k+1 positions each; cap so a
-                # fully-accepted burst fits every slot's cache reserve and
-                # token budget.
-                kp1 = self.spec_k + 1
-                burst = 1 if (busy or spec_probe) else self._spec_scan_len
-                for r in decoding:
-                    ub = int(self.lengths[r.slot]) + inflight
-                    room = (self.S - ub) // kp1
-                    dispatched = ub - len(r.prompt_ids) + 1
-                    left = max(1, r.max_tokens - dispatched)
-                    burst = min(burst, max(1, room), -(-left // kp1))
-                if self._swa_ring_pages:
-                    self._swa_rotate(decoding, inflight, max(1, burst) * kp1)
-                burst = max(1, burst)
                 spec_acc0 = self._spec_accepted_total
                 with self._sched.wait("decode_wait"):
                     step_tokens = await asyncio.to_thread(
                         self._spec_burst, burst, spec_probe)
                 spec_acc_n = self._spec_accepted_total - spec_acc0
             else:
-                burst = self._burst_depth(busy)
-                # Never burst past any slot's cache capacity or token
-                # budget — both computed from DISPATCH-TRUE state
-                # (self.lengths advances at dispatch): with lag-one
-                # pipelining, len(r.generated) lags a burst behind and
-                # would let a whole discarded burst through. `inflight`
-                # covers a pending spec burst (mode switch): its
-                # data-dependent advance lands on the host mirrors inside
-                # _decode_burst, AFTER these caps are computed.
-                for r in decoding:
-                    ub = int(self.lengths[r.slot]) + inflight
-                    dispatched = ub - len(r.prompt_ids) + 1
-                    burst = min(burst, self.S - ub,
-                                max(1, r.max_tokens - dispatched))
-                burst = max(1, burst)
-                if self._swa_ring_pages:
-                    self._swa_rotate(decoding, inflight, burst)
                 with self._sched.wait("decode_wait"):
                     step_tokens = await asyncio.to_thread(
                         self._decode_burst, burst)
@@ -2093,62 +1939,240 @@ class InferenceEngine:
             # cancellation chaos test caught it).
             progressed = True
         if fl is not None and (n_chunks or decoding):
-            # The step record: what this iteration ran, how long it took,
-            # and the scheduler's fitted step time next to the measured
-            # one — the per-decision feed the EMAs compress away.
-            from ..obs import flight as _fl
-            flag = 0
-            depth = 0
-            if n_chunks:
-                flag |= _fl.F_PREFILL
-            if decoding:
-                flag |= _fl.F_DECODE
-                depth = burst
-                if spec_now:
-                    flag |= _fl.F_SPEC
-                if busy:
-                    flag |= _fl.F_BUSY
-                if self._busy_clamps > clamps0:
-                    flag |= _fl.F_CLAMPED
-            # The steady-pair EMA gauge, not _step_ms_estimate(): the
-            # fit walks every wall sample and would cost more per step
-            # than the record itself.
-            fitted = self._ema_step_ms_stats
-            if self._disagg is not None:
-                # The prefill pool's share of this iteration already went
-                # out after phase 2; this record is the decode pool's
-                # view (dur = burst wall, so steps_overlapping() sums
-                # true decode occupancy). Prefill-only iterations emit
-                # nothing here.
+            with _device_phase("sched.plan"):
+                # The step record: what this iteration ran, how long it took,
+                # and the scheduler's fitted step time next to the measured
+                # one — the per-decision feed the EMAs compress away.
+                from ..obs import flight as _fl
+                flag = 0
+                depth = 0
+                if n_chunks:
+                    flag |= _fl.F_PREFILL
                 if decoding:
+                    flag |= _fl.F_DECODE
+                    depth = burst
+                    if spec_now:
+                        flag |= _fl.F_SPEC
+                    if busy:
+                        flag |= _fl.F_BUSY
+                    if self._busy_clamps > clamps0:
+                        flag |= _fl.F_CLAMPED
+                # The steady-pair EMA gauge, not _step_ms_estimate(): the
+                # fit walks every wall sample and would cost more per step
+                # than the record itself.
+                fitted = self._ema_step_ms_stats
+                if self._disagg is not None:
+                    # The prefill pool's share of this iteration already went
+                    # out after phase 2; this record is the decode pool's
+                    # view (dur = burst wall, so steps_overlapping() sums
+                    # true decode occupancy). Prefill-only iterations emit
+                    # nothing here.
+                    if decoding:
+                        fl.record(
+                            _fl.STEP, flag=flag & ~_fl.F_PREFILL,
+                            depth=depth, tokens=n_tok - n_tok_prefill,
+                            dur_ms=dec_wall_ms,
+                            val=dec_wall_ms,
+                            pool=_fl.POOL_DECODE,
+                            active=len(self._running),
+                            free_slots=self._free_slot_count(),
+                            queued=(self._queue.qsize()
+                                    + (1 if self._head else 0)),
+                            free_pages=self.allocator.free_pages,
+                            fitted_ms=(fitted if fitted is not None
+                                       else float("nan")))
+                else:
                     fl.record(
-                        _fl.STEP, flag=flag & ~_fl.F_PREFILL,
-                        depth=depth, tokens=n_tok - n_tok_prefill,
-                        dur_ms=dec_wall_ms,
-                        val=dec_wall_ms,
-                        pool=_fl.POOL_DECODE,
+                        _fl.STEP, flag=flag, depth=depth, tokens=n_tok,
+                        chunks=n_chunks,
+                        dur_ms=1000.0 * (fl.clock() - t_step0),
+                        spec_acc=spec_acc_n,
+                        val=dec_wall_ms if decoding else 0.0,
                         active=len(self._running),
                         free_slots=self._free_slot_count(),
-                        queued=(self._queue.qsize()
-                                + (1 if self._head else 0)),
+                        queued=self._queue.qsize() + (1 if self._head else 0),
                         free_pages=self.allocator.free_pages,
                         fitted_ms=(fitted if fitted is not None
                                    else float("nan")))
-            else:
-                fl.record(
-                    _fl.STEP, flag=flag, depth=depth, tokens=n_tok,
-                    chunks=n_chunks,
-                    dur_ms=1000.0 * (fl.clock() - t_step0),
-                    spec_acc=spec_acc_n,
-                    val=dec_wall_ms if decoding else 0.0,
-                    active=len(self._running),
-                    free_slots=self._free_slot_count(),
-                    queued=self._queue.qsize() + (1 if self._head else 0),
-                    free_pages=self.allocator.free_pages,
-                    fitted_ms=(fitted if fitted is not None
-                               else float("nan")))
         return progressed
 
+    @_device_phase("sched.plan")
+    def _plan_burst(self, decoding: list[GenRequest]
+                    ) -> tuple[bool, bool, bool, int]:
+        """The loop's synchronous prelude to a decode wait: which program
+        the burst runs and how deep. Returns ``(busy, spec_now,
+        spec_probe, burst)``. Event-loop thread; its wall is the ledger's
+        ``other``, and the span ``sched.plan`` says so on the profiler's
+        clock — an idle gap under NO program span is then the hand-off
+        between the threads."""
+        # Prefill-aware (DistServe/Sarathi-style interleave): any
+        # admission waiting — queued, parked at the FIFO head for a
+        # page reservation, or mid-chunked-prefill — clamps the next
+        # burst so prefill work never starves behind a deep scan.
+        busy = (self._head is not None or not self._queue.empty()
+                or bool(self._prefilling))
+        # Speculation verifies against argmax, so it engages only while
+        # EVERY active slot is greedy (the common serving case);
+        # sampled requests flip the whole batch to the normal burst
+        # path for their lifetime — mixed batches stay correct, just
+        # unaccelerated.
+        spec_now = self.spec_k and self._all_greedy()
+        # Adaptive drafting gate: drafting only pays while accepted
+        # tokens/step clears the verify forward's overhead
+        # (config.spec_min_tokens_per_step). Below it, decode normally
+        # and re-probe with a single spec step every
+        # spec_probe_interval rounds — so enabling speculation in
+        # config is safe for non-repetitive traffic.
+        spec_probe = False
+        if spec_now and self._spec_wall_gate_on:
+            # Baseline probe: the wall gate needs a NORMAL-path step
+            # time to compare against, and spec-open traffic never
+            # runs normal bursts. Two consecutive normal rounds (a
+            # steady same-depth pair is what lands a wall sample),
+            # immediately while no baseline exists, then refreshed
+            # every 8*spec_probe_interval spec rounds.
+            if self._spec_base_rounds > 0:
+                self._spec_base_rounds -= 1
+                spec_now = False
+            else:
+                est = self._step_ms_estimate()
+                if est is not None:
+                    self._spec_base_fails = 0
+                self._spec_base_ctr += 1
+                # Periodic refresh only while a baseline EXISTS —
+                # once the starvation guard trips (workload can't
+                # land wall samples), probing again by schedule
+                # would pay the same fruitless normal rounds
+                # forever.
+                if ((est is None and self._spec_base_fails < 4)
+                        or (est is not None
+                            and self._spec_base_ctr
+                            >= 8 * self.spec_probe_interval)):
+                    self._spec_base_ctr = 0
+                    if est is None:
+                        self._spec_base_fails += 1
+                    self._spec_base_rounds = 1
+                    spec_now = False
+        if spec_now and (self.spec_min_tps > 0
+                         or self._spec_wall_gate_on):
+            # A batch with NO measured slots always drafts — the burst
+            # IS the measurement. Unmeasured slots in a mixed batch
+            # count optimistically (k+1) so fresh requests can re-open
+            # the gate; one low burst closes it again. The wall-clock
+            # term applies even with the acceptance threshold
+            # disabled (spec_min_tokens_per_step=0): each protects
+            # against a different failure mode.
+            below = False
+            if self.spec_min_tps > 0:
+                slots = [r.slot for r in decoding]
+                if self.spec_floor > 0:
+                    # Per-slot suspension already benches poor slots —
+                    # their frozen EMAs must not drag the BATCH mean
+                    # below the threshold and close the gate on the
+                    # slots that are still profiting. (All-suspended
+                    # batches skip the burst below regardless of what
+                    # the mean says.)
+                    slots = [s for s in slots
+                             if not self._spec_suspended[s]] or slots
+                ema = self._spec_ema[slots]
+                if not np.all(np.isnan(ema)):
+                    mean_tps = float(np.mean(np.where(
+                        np.isnan(ema), self.spec_k + 1, ema)))
+                    below = mean_tps < self.spec_min_tps
+            wall_lose = self._spec_wall_loses()
+            if below or wall_lose:
+                self._spec_probe_ctr += 1
+                if self._spec_probe_ctr >= self.spec_probe_interval:
+                    self._spec_probe_ctr = 0
+                    spec_probe = True            # 1-step re-measure
+                    # A probe re-measures ACCEPTANCE only. If the
+                    # WALL term is what closed the gate, drop the
+                    # wall gauge every few probe cycles so one full
+                    # burst can re-time it under current conditions
+                    # (bounded tax: one possibly-slow burst per 4
+                    # probe intervals). An acceptance-only close
+                    # must NOT drop it — no full spec burst would
+                    # run to re-measure, silently losing the gauge
+                    # (and its stats field) while a stale-free
+                    # baseline still protects the reopen path.
+                    if wall_lose and not below:
+                        self._spec_wall_age += 1
+                        if (self._spec_ms_per_tok is not None
+                                and self._spec_wall_age >= 4):
+                            self._spec_wall_age = 0
+                            self._spec_ms_per_tok = None
+                else:
+                    spec_now = False
+        if spec_now and self.spec_floor > 0 and not spec_probe:
+            # Per-slot adaptive drafting (spec_acceptance_floor):
+            # suspended slots ride along in the k+1-wide verify at a
+            # deterministic 1 token/step, so when EVERY decoding slot
+            # is suspended the burst is pure overhead — decode
+            # normally instead, and every spec_probe_interval such
+            # rounds run ONE probe burst with the mask lifted so
+            # suspended slots get re-measured (text regimes change;
+            # a permanent bench would strand them). A mixed batch
+            # keeps bursting (drafting slots still profit) and the
+            # same cadence lifts the mask for its benched slots.
+            susp = sum(bool(self._spec_suspended[r.slot])
+                       for r in decoding)
+            if susp:
+                self._spec_suspend_probe_ctr += 1
+                if (self._spec_suspend_probe_ctr
+                        >= self.spec_probe_interval):
+                    self._spec_suspend_probe_ctr = 0
+                    spec_probe = True        # 1-step, mask lifted
+                elif susp == len(decoding):
+                    spec_now = False
+        # While a spec burst is in flight (lag-one), the host lengths
+        # lag dispatch by a data-dependent amount — cap against the
+        # worst case (every in-flight step fully accepted).
+        inflight = self._spec_inflight_advance() if self.spec_k else 0
+        if spec_now:
+            # A slot whose dispatch-true length is within k of the
+            # cache extent can't fit a k+1-wide verify (possible when
+            # lag-one normal bursts ran it ahead of emission): fall
+            # back to the 1-wide normal path until emission retires it.
+            spec_now = all(
+                self.S - (int(self.lengths[r.slot]) + inflight)
+                >= self.spec_k + 1
+                for r in decoding)
+        if spec_now:
+            # Speculative steps advance 1..k+1 positions each; cap so a
+            # fully-accepted burst fits every slot's cache reserve and
+            # token budget.
+            kp1 = self.spec_k + 1
+            burst = 1 if (busy or spec_probe) else self._spec_scan_len
+            for r in decoding:
+                ub = int(self.lengths[r.slot]) + inflight
+                room = (self.S - ub) // kp1
+                dispatched = ub - len(r.prompt_ids) + 1
+                left = max(1, r.max_tokens - dispatched)
+                burst = min(burst, max(1, room), -(-left // kp1))
+            if self._swa_ring_pages:
+                self._swa_rotate(decoding, inflight, max(1, burst) * kp1)
+            burst = max(1, burst)
+        else:
+            burst = self._burst_depth(busy)
+            # Never burst past any slot's cache capacity or token
+            # budget — both computed from DISPATCH-TRUE state
+            # (self.lengths advances at dispatch): with lag-one
+            # pipelining, len(r.generated) lags a burst behind and
+            # would let a whole discarded burst through. `inflight`
+            # covers a pending spec burst (mode switch): its
+            # data-dependent advance lands on the host mirrors inside
+            # _decode_burst, AFTER these caps are computed.
+            for r in decoding:
+                ub = int(self.lengths[r.slot]) + inflight
+                dispatched = ub - len(r.prompt_ids) + 1
+                burst = min(burst, self.S - ub,
+                            max(1, r.max_tokens - dispatched))
+            burst = max(1, burst)
+            if self._swa_ring_pages:
+                self._swa_rotate(decoding, inflight, burst)
+        return busy, bool(spec_now), spec_probe, burst
+
+    @_device_phase("sched.plan")
     def _record_prefill(self, fl) -> None:
         """The PREFILL flight record of the compiled dispatch the await
         just returned from (loop thread; the worker left the facts in
@@ -2346,62 +2370,68 @@ class InferenceEngine:
         guarantees every request here shares one compile bucket.
         Returns per-request prompt-complete flags."""
         slots, poss, chunks, samps, final = [], [], [], [], []
-        for req in reqs:
-            slot = req.slot
-            ids = req.prompt_ids
-            pos = req.prefill_pos
-            if pos == 0:
-                self.lengths[slot] = 0
-                self.active[slot] = False
-            chunk = np.asarray(ids[pos:pos + self.prefill_chunk], np.int32)
-            if self._swa_ring_pages:
-                # Map the pages this chunk writes by recycling pages wholly
-                # below the chunk's window floor (no in-flight margin: a
-                # prefilling slot has no decode burst of its own in flight,
-                # and cross-slot bursts touch only their own table rows).
-                self.kv_groups.rotate(slot, pos + len(chunk) - 1, pos)
-            if self.fault_plan:
-                self.fault_plan.on_prefill()
-            self._spec_hist_chunk(slot, pos, chunk)
-            slots.append(slot)
-            poss.append(pos)
-            chunks.append(chunk)
-            samps.append((req.temperature, req.top_p, req.top_k,
-                          req.presence_penalty, req.frequency_penalty))
-            final.append(pos + len(chunk) >= len(ids))
-        self._rng, key = jax.random.split(self._rng)
+        with _part("args"):
+            for req in reqs:
+                slot = req.slot
+                ids = req.prompt_ids
+                pos = req.prefill_pos
+                if pos == 0:
+                    self.lengths[slot] = 0
+                    self.active[slot] = False
+                chunk = np.asarray(ids[pos:pos + self.prefill_chunk],
+                                   np.int32)
+                if self._swa_ring_pages:
+                    # Map the pages this chunk writes by recycling pages
+                    # wholly below the chunk's window floor (no in-flight
+                    # margin: a prefilling slot has no decode burst of its
+                    # own in flight, and cross-slot bursts touch only their
+                    # own table rows).
+                    self.kv_groups.rotate(slot, pos + len(chunk) - 1, pos)
+                if self.fault_plan:
+                    self.fault_plan.on_prefill()
+                self._spec_hist_chunk(slot, pos, chunk)
+                slots.append(slot)
+                poss.append(pos)
+                chunks.append(chunk)
+                samps.append((req.temperature, req.top_p, req.top_k,
+                              req.presence_penalty, req.frequency_penalty))
+                final.append(pos + len(chunk) >= len(ids))
+        with _part("rng"):
+            self._rng, key = jax.random.split(self._rng)
         first, self.cache = self._exec_prefill(
             slots, poss, chunks, samp=samps, key=key, final=final)
-        done: list[bool] = []
         first_np: np.ndarray | None = None
-        for i, req in enumerate(reqs):
-            req.prefill_pos = poss[i] + len(chunks[i])
-            if req.prefill_pos < len(req.prompt_ids):
-                done.append(False)
-                continue
-            # Prompt complete: the first token was sampled inside the
+        if any(final):
+            # A prompt is complete: its first token was sampled inside the
             # prefill program (see prefill_step) — ONE host fetch for the
             # whole group completes the TTFT path.
-            if first_np is None:
-                with _device_phase("sched.fetch.first"):
-                    first_np = np.asarray(first)
-            first_id = int(first_np[i])
-            req.generated.append(first_id)
-            req.t_first_token = time.monotonic()
-            self.lengths[req.slot] = len(req.prompt_ids)
-            self.last_token[req.slot] = first_id
-            # (Token history for prompt-lookup drafting is maintained per
-            # CHUNK above; the first generated token is the input at P,
-            # written by the spec step that consumes it.)
-            self.active[req.slot] = True
-            self.samp_temperature[req.slot] = req.temperature
-            self.samp_top_p[req.slot] = req.top_p
-            self.samp_top_k[req.slot] = req.top_k
-            self.samp_presence[req.slot] = req.presence_penalty
-            self.samp_frequency[req.slot] = req.frequency_penalty
-            self._d_dirty = True
-            done.append(True)
-        return done
+            with _device_phase("sched.fetch.first"):
+                first_np = np.asarray(first)
+        else:
+            # No row ended its prompt, so nothing reads: the call's device
+            # time is waited out in whatever wait comes next.
+            self._prefill_calls_unread += 1
+        with _part("mirrors"):
+            for i, req in enumerate(reqs):
+                req.prefill_pos = poss[i] + len(chunks[i])
+                if not final[i]:
+                    continue
+                first_id = int(first_np[i])
+                req.generated.append(first_id)
+                req.t_first_token = time.monotonic()
+                self.lengths[req.slot] = len(req.prompt_ids)
+                self.last_token[req.slot] = first_id
+                # (Token history for prompt-lookup drafting is maintained
+                # per CHUNK above; the first generated token is the input
+                # at P, written by the spec step that consumes it.)
+                self.active[req.slot] = True
+                self.samp_temperature[req.slot] = req.temperature
+                self.samp_top_p[req.slot] = req.top_p
+                self.samp_top_k[req.slot] = req.top_k
+                self.samp_presence[req.slot] = req.presence_penalty
+                self.samp_frequency[req.slot] = req.frequency_penalty
+                self._d_dirty = True
+        return final
 
     def _exec_prefill(self, slot, pos, chunk,
                       samp=None, key: jax.Array | None = None,
@@ -2424,36 +2454,38 @@ class InferenceEngine:
         prompt; only a program whose upper layers run on a prompt's last
         row alone is told.
         Returns (first_tokens [K, replicated device array], cache)."""
-        single = np.isscalar(slot) or isinstance(slot, (int, np.integer))
-        slots = [slot] if single else list(slot)
-        poss = [pos] if single else list(pos)
-        chunks = [chunk] if single else list(chunk)
-        samps = ([samp] if single else list(samp)) if samp is not None \
-            else [(0.0, 1.0, 0, 0.0, 0.0)] * len(slots)
-        K = len(slots)
-        bucket = min(_bucket(max(len(ch) for ch in chunks),
-                             self.prefill_chunk),
-                     self.S - max(poss))
-        padded = np.zeros((K, bucket), np.int32)
-        for i, ch in enumerate(chunks):
-            padded[i, :len(ch)] = ch
-        tables = self._device_tables()
-        if key is None:
-            key = _DUMMY_KEY()
-        args = (self.params, self.cache, self._d_counts, tables, padded,
-                np.asarray(poss, np.int32), np.asarray(slots, np.int32),
-                np.asarray([len(ch) - 1 for ch in chunks], np.int32),
-                np.asarray([s[0] for s in samps], np.float32),
-                np.asarray([s[1] for s in samps], np.float32),
-                np.asarray([s[2] for s in samps], np.int32),
-                np.asarray([s[3] for s in samps], np.float32),
-                np.asarray([s[4] for s in samps], np.float32), key)
-        if self._rows_stop:
-            ends = np.ones((K,), bool) if final is None else \
-                np.asarray(final, bool)
-            args += (ends,)
-            self._prefill_rows_stopped += sum(
-                len(ch) - int(end) for ch, end in zip(chunks, ends))
+        with _part("tables"):
+            tables = self._device_tables()
+        with _part("args"):
+            single = np.isscalar(slot) or isinstance(slot, (int, np.integer))
+            slots = [slot] if single else list(slot)
+            poss = [pos] if single else list(pos)
+            chunks = [chunk] if single else list(chunk)
+            samps = ([samp] if single else list(samp)) if samp is not None \
+                else [(0.0, 1.0, 0, 0.0, 0.0)] * len(slots)
+            K = len(slots)
+            bucket = min(_bucket(max(len(ch) for ch in chunks),
+                                 self.prefill_chunk),
+                         self.S - max(poss))
+            padded = np.zeros((K, bucket), np.int32)
+            for i, ch in enumerate(chunks):
+                padded[i, :len(ch)] = ch
+            if key is None:
+                key = _DUMMY_KEY()
+            args = (self.params, self.cache, self._d_counts, tables, padded,
+                    np.asarray(poss, np.int32), np.asarray(slots, np.int32),
+                    np.asarray([len(ch) - 1 for ch in chunks], np.int32),
+                    np.asarray([s[0] for s in samps], np.float32),
+                    np.asarray([s[1] for s in samps], np.float32),
+                    np.asarray([s[2] for s in samps], np.int32),
+                    np.asarray([s[3] for s in samps], np.float32),
+                    np.asarray([s[4] for s in samps], np.float32), key)
+            if self._rows_stop:
+                ends = np.ones((K,), bool) if final is None else \
+                    np.asarray(final, bool)
+                args += (ends,)
+                self._prefill_rows_stopped += sum(
+                    len(ch) - int(end) for ch, end in zip(chunks, ends))
         # Kernel registry (ISSUE 8): one row per (bucket, K) prefill
         # program; the aval capture + cost closure is paid once per
         # variant. The wall is the dispatch wall (on an async backend the
@@ -2468,19 +2500,21 @@ class InferenceEngine:
         if self.kernels.needs(kname):
             variant = {"bucket": int(bucket), "k": K,
                        "block": "%dx%d" % block}
-            self.kernels.register(
-                kname, "prefill", variant=variant,
-                cost_fn=_kernel_cost_fn(self._prefill_fn, args))
+            with _part("mirrors"):
+                self.kernels.register(
+                    kname, "prefill", variant=variant,
+                    cost_fn=_kernel_cost_fn(self._prefill_fn, args))
         t0 = time.monotonic()
         with _device_phase("prefill"):
             first, self._d_counts, cache = self._prefill_fn(*args)
         t1 = time.monotonic()
-        self.kernels.record(kname, wall_ms=1000.0 * (t1 - t0))
-        self._last_prefill = (K, int(bucket), sum(len(ch) for ch in chunks),
-                              int(min(poss)), int(max(poss)),
-                              self._count_prefill_walk(poss, int(bucket),
-                                                       block[0]),
-                              block, t0, t1)
+        with _part("mirrors"):
+            self.kernels.record(kname, wall_ms=1000.0 * (t1 - t0))
+            self._last_prefill = (
+                K, int(bucket), sum(len(ch) for ch in chunks),
+                int(min(poss)), int(max(poss)),
+                self._count_prefill_walk(poss, int(bucket), block[0]),
+                block, t0, t1)
         return first, cache
 
     def _prefill_block(self, bucket: int) -> tuple[int, int]:
@@ -2581,14 +2615,17 @@ class InferenceEngine:
             # Upload needs exact host mirrors — land any in-flight spec
             # burst before reading them.
             pre += self._flush_spec_pending()
-            self._upload_slot_state()
-            self._d_hist = self._upload(self.hist)
+            with _part("state"):
+                self._upload_slot_state()
+                self._d_hist = self._upload(self.hist)
             self._d_dirty = False
             self._d_hist_fresh = True
 
-        d_ok = self._spec_draft_ok(probe)
-        d_ok_dev = self._upload(d_ok)
-        tables = self._device_tables()
+        with _part("state"):
+            d_ok = self._spec_draft_ok(probe)
+            d_ok_dev = self._upload(d_ok)
+        with _part("tables"):
+            tables = self._device_tables()
         if n_steps == self._spec_scan_len:
             t0 = time.monotonic()
             args = (self.params, self.cache, tables, self._d_hist,
@@ -2596,34 +2633,38 @@ class InferenceEngine:
                     d_ok_dev)
             kname = f"spec.s{n_steps}"
             if self.kernels.needs(kname):
-                self.kernels.register(
-                    kname, "spec", variant=self._kernel_variant(depth=n_steps),
-                    cost_fn=_kernel_cost_fn(self._spec_scan, args))
+                with _part("mirrors"):
+                    self.kernels.register(
+                        kname, "spec",
+                        variant=self._kernel_variant(depth=n_steps),
+                        cost_fn=_kernel_cost_fn(self._spec_scan, args))
             with _device_phase("spec.verify"):
                 emitted, self.cache, self._d_hist, self._d_tokens, \
                     self._d_lengths = self._spec_scan(*args)
                 _start_host_copy(emitted)
-            prev, self._spec_pending = self._spec_pending, (
-                emitted, n_steps, self.active.copy(),
-                self._slot_epoch.copy(), d_ok)
+            with _part("mirrors"):
+                prev, self._spec_pending = self._spec_pending, (
+                    emitted, n_steps, self.active.copy(),
+                    self._slot_epoch.copy(), d_ok)
             before = self._spec_tokens_out
             out = pre + self._flush_spec_entry(prev)
-            steady = prev is not None and prev[1] == n_steps
-            self.kernels.record(
-                kname, steps=n_steps,
-                wall_ms=(1000.0 * (time.monotonic() - t0) if steady
-                         else None))
-            if steady:
-                # Steady state at full spec depth: this call's wall time
-                # covers one same-depth burst (lag-one), and the flushed
-                # burst's emitted count is its token yield — feed the
-                # wall-clock gate gauge (see _spec_wall_loses).
-                toks = self._spec_tokens_out - before
-                if toks > 0:
-                    ms = 1000.0 * (time.monotonic() - t0) / toks
-                    self._spec_ms_per_tok = (
-                        ms if self._spec_ms_per_tok is None else
-                        0.7 * self._spec_ms_per_tok + 0.3 * ms)
+            with _part("mirrors"):
+                steady = prev is not None and prev[1] == n_steps
+                self.kernels.record(
+                    kname, steps=n_steps,
+                    wall_ms=(1000.0 * (time.monotonic() - t0) if steady
+                             else None))
+                if steady:
+                    # Steady state at full spec depth: this call's wall
+                    # time covers one same-depth burst (lag-one), and the
+                    # flushed burst's emitted count is its token yield —
+                    # feed the wall-clock gate gauge (see _spec_wall_loses).
+                    toks = self._spec_tokens_out - before
+                    if toks > 0:
+                        ms = 1000.0 * (time.monotonic() - t0) / toks
+                        self._spec_ms_per_tok = (
+                            ms if self._spec_ms_per_tok is None else
+                            0.7 * self._spec_ms_per_tok + 0.3 * ms)
             return out
 
         # Partial bursts (cache/budget caps, busy depth 1) stay
@@ -2647,10 +2688,11 @@ class InferenceEngine:
                 outs.append(em)
             with _device_phase("sched.fetch.sync"):
                 host = np.stack([np.asarray(e) for e in outs])
-        self.kernels.record(kname, steps=n_steps,
-                            wall_ms=1000.0 * (time.monotonic() - t0))
-        return pre + self._spec_walk(host, self.active, self.active.copy(),
-                                     drafting=d_ok)
+        with _part("mirrors"):
+            self.kernels.record(kname, steps=n_steps,
+                                wall_ms=1000.0 * (time.monotonic() - t0))
+            return pre + self._spec_walk(
+                host, self.active, self.active.copy(), drafting=d_ok)
 
     def _upload_slot_state(self) -> None:
         """Rebuild the device mirrors of the per-slot host state, each
@@ -2775,8 +2817,10 @@ class InferenceEngine:
         emitted, _, active_snap, epoch_snap, drafting = entry
         with _device_phase("sched.fetch.spec"):
             host = np.asarray(emitted)                   # [n, B, k+1]
-        live = active_snap & (epoch_snap == self._slot_epoch)
-        return self._spec_walk(host, active_snap, live, drafting=drafting)
+        with _part("mirrors"):
+            live = active_snap & (epoch_snap == self._slot_epoch)
+            return self._spec_walk(host, active_snap, live,
+                                   drafting=drafting)
 
     def _spec_walk(self, host: np.ndarray, active_snap: np.ndarray,
                    live: np.ndarray,
@@ -2867,34 +2911,36 @@ class InferenceEngine:
         toks_dev, n, active_snap, epoch_snap, len_snap, last_snap = entry
         with _device_phase("sched.fetch.burst"):
             host = np.asarray(toks_dev)                  # [n, B (+ counters)]
-        if host.shape[1] > self.B:
-            seen = host[-1, self.B:].astype(np.int64)
-            for i, d in enumerate((seen - self._moe_seen) & 0xFFFFFFFF):
-                self._moe_totals[i] += int(d)
-            self._moe_seen = seen
-            host = host[:, :self.B]
-        live = active_snap & (epoch_snap == self._slot_epoch)
-        for slot in np.nonzero(live)[0]:
-            self.last_token[slot] = int(host[-1][slot])
-            if self.spec_k:
-                # Keep the prompt-lookup history current through the
-                # NORMAL path too (mixed spec/sampled serving): the burst's
-                # inputs were [last@dispatch] + tokens at positions
-                # [L, L+n] (L = dispatch-time length snapshot).
-                L = int(len_snap[slot])
-                if L < self.S:
-                    self.hist[slot, L] = int(last_snap[slot])
-                m = min(n, self.S - (L + 1))
-                if m > 0:
-                    self.hist[slot, L + 1:L + 1 + m] = host[:m, slot]
-        if not live.all():
-            # Slots released (or released+re-admitted) since this burst's
-            # dispatch: their tokens belong to a dead request — mask with
-            # -1 so the emission loop can't attribute them to the slot's
-            # CURRENT request.
-            host = host.copy()
-            host[:, ~live] = -1
-        return [host[i] for i in range(n)]
+        with _part("mirrors"):
+            if host.shape[1] > self.B:
+                seen = host[-1, self.B:].astype(np.int64)
+                for i, d in enumerate((seen - self._moe_seen) & 0xFFFFFFFF):
+                    self._moe_totals[i] += int(d)
+                self._moe_seen = seen
+                host = host[:, :self.B]
+            live = active_snap & (epoch_snap == self._slot_epoch)
+            for slot in np.nonzero(live)[0]:
+                self.last_token[slot] = int(host[-1][slot])
+                if self.spec_k:
+                    # Keep the prompt-lookup history current through the
+                    # NORMAL path too (mixed spec/sampled serving): the
+                    # burst's inputs were [last@dispatch] + tokens at
+                    # positions [L, L+n] (L = dispatch-time length
+                    # snapshot).
+                    L = int(len_snap[slot])
+                    if L < self.S:
+                        self.hist[slot, L] = int(last_snap[slot])
+                    m = min(n, self.S - (L + 1))
+                    if m > 0:
+                        self.hist[slot, L + 1:L + 1 + m] = host[:m, slot]
+            if not live.all():
+                # Slots released (or released+re-admitted) since this
+                # burst's dispatch: their tokens belong to a dead request —
+                # mask with -1 so the emission loop can't attribute them to
+                # the slot's CURRENT request.
+                host = host.copy()
+                host[:, ~live] = -1
+            return [host[i] for i in range(n)]
 
     def _swa_rotate(self, decoding, inflight: int, advance: int) -> None:
         """Sliding-window ring: before dispatching a burst, map the logical
@@ -3016,10 +3062,12 @@ class InferenceEngine:
             # in-flight burst must land first: the upload below reads the
             # host `last_token` mirror, which that burst's tokens update.
             pre += self._flush_pending()
-            self._upload_slot_state()
+            with _part("state"):
+                self._upload_slot_state()
             self._d_dirty = False
 
-        tables = self._device_tables()
+        with _part("tables"):
+            tables = self._device_tables()
         # Greedy fast path: when every active slot decodes at temperature 0
         # with zero penalties (the common case), run the argmax-only
         # program — the general sampler's full-vocab sort costs
@@ -3037,54 +3085,39 @@ class InferenceEngine:
             # bursts (tail of a request's token budget, or prefill work
             # pending) fall through to the synchronous step loop below.
             t0 = time.monotonic()
-            self._rng, key = jax.random.split(self._rng)
+            with _part("rng"):
+                self._rng, key = jax.random.split(self._rng)
             args = (self.params, self.cache, self._d_counts, tables,
                     self._d_tokens, self._d_lengths, self._d_active,
                     self._d_samp, key)
             kname = (f"decode.d{n_steps}."
                      f"{'greedy' if greedy else 'sampled'}")
             if self.kernels.needs(kname):
-                self.kernels.register(
-                    kname, "decode",
-                    variant=self._kernel_variant(depth=n_steps, greedy=greedy),
-                    cost_fn=_kernel_cost_fn(scan_fn, args))
+                with _part("mirrors"):
+                    self.kernels.register(
+                        kname, "decode",
+                        variant=self._kernel_variant(depth=n_steps,
+                                                     greedy=greedy),
+                        cost_fn=_kernel_cost_fn(scan_fn, args))
             with _device_phase("decode"):
                 toks, self._d_tokens, self._d_lengths, self._d_counts, \
                     self.cache = scan_fn(*args)
                 _start_host_copy(toks)
-            prev, self._pending = self._pending, (
-                toks, n_steps, self.active.copy(), self._slot_epoch.copy(),
-                self.lengths.copy(), self.last_token.copy())
-            # Host length mirror advances at DISPATCH time — the burst-
-            # capping logic in _step must see the device-true lengths.
-            self._count_decode_keys(n_steps)
-            self.lengths[self.active] += n_steps
-            if self.spec_k:
-                self._d_hist_fresh = False
+            with _part("mirrors"):
+                prev, self._pending = self._pending, (
+                    toks, n_steps, self.active.copy(),
+                    self._slot_epoch.copy(), self.lengths.copy(),
+                    self.last_token.copy())
+                # Host length mirror advances at DISPATCH time — the burst-
+                # capping logic in _step must see the device-true lengths.
+                self._count_decode_keys(n_steps)
+                self.lengths[self.active] += n_steps
+                if self.spec_k:
+                    self._d_hist_fresh = False
             out = pre + self._flush_entry(prev)
-            if prev is not None and prev[1] == n_steps:
-                # Steady same-depth pair: this call's wall time covers
-                # exactly one burst at this depth (lag-one). Depth
-                # transitions (busy<->idle) are excluded — the previous
-                # burst's wait divided by the new depth would feed
-                # ~4x-off samples. Feeds BOTH the per-depth wall model
-                # (_step_ms_estimate — the ttft cap's input) and the
-                # operator stats gauge.
-                wall = 1000.0 * (time.monotonic() - t0)
-                prev_w = self._burst_walls.get(n_steps)
-                self._burst_walls[n_steps] = (
-                    wall if prev_w is None else 0.8 * prev_w + 0.2 * wall)
-                self._burst_wall_n += 1
-                self._burst_wall_stamp[n_steps] = self._burst_wall_n
-                ms_any = wall / n_steps
-                self._ema_step_ms_stats = (
-                    ms_any if self._ema_step_ms_stats is None else
-                    0.8 * self._ema_step_ms_stats + 0.2 * ms_any)
-                # Steady-pair walls are the only honest lag-one walls —
-                # transition bursts count calls but contribute no time.
-                self.kernels.record(kname, steps=n_steps, wall_ms=wall)
-            else:
-                self.kernels.record(kname, steps=n_steps)
+            with _part("mirrors"):
+                self._record_burst(kname, n_steps, t0, steady=(
+                    prev is not None and prev[1] == n_steps))
             return out
 
         # Synchronous path: flush any in-flight burst first so tokens are
@@ -3110,25 +3143,52 @@ class InferenceEngine:
                 pending.append(self._d_tokens)
             with _device_phase("sched.fetch.sync"):
                 step_tokens = [np.asarray(t) for t in pending]
-        # The fetch above synchronizes, so this wall is honest per call.
-        self.kernels.record(kname, steps=n_steps,
-                            wall_ms=1000.0 * (time.monotonic() - t0))
-        # Mirror device-side length advance on the host (+ history for
-        # mixed-mode speculative engines).
-        for slot in np.nonzero(self.active)[0]:
+        with _part("mirrors"):
+            # The fetch above synchronizes, so this wall is honest per call.
+            self.kernels.record(kname, steps=n_steps,
+                                wall_ms=1000.0 * (time.monotonic() - t0))
+            # Mirror device-side length advance on the host (+ history for
+            # mixed-mode speculative engines).
+            for slot in np.nonzero(self.active)[0]:
+                if self.spec_k:
+                    L = int(self.lengths[slot])
+                    if L < self.S:
+                        self.hist[slot, L] = int(self.last_token[slot])
+                    m = min(n_steps, self.S - (L + 1))
+                    for t in range(m):
+                        self.hist[slot, L + 1 + t] = \
+                            int(step_tokens[t][slot])
+                self.last_token[slot] = int(step_tokens[-1][slot])
+            self._count_decode_keys(n_steps)
+            self.lengths[self.active] += n_steps
             if self.spec_k:
-                L = int(self.lengths[slot])
-                if L < self.S:
-                    self.hist[slot, L] = int(self.last_token[slot])
-                m = min(n_steps, self.S - (L + 1))
-                for t in range(m):
-                    self.hist[slot, L + 1 + t] = int(step_tokens[t][slot])
-            self.last_token[slot] = int(step_tokens[-1][slot])
-        self._count_decode_keys(n_steps)
-        self.lengths[self.active] += n_steps
-        if self.spec_k:
-            self._d_hist_fresh = False
+                self._d_hist_fresh = False
         return pre + step_tokens
+
+    def _record_burst(self, kname: str, n_steps: int, t0: float,
+                      steady: bool) -> None:
+        """One lag-one burst into the kernel registry, with its wall where
+        it is honest. A steady same-depth pair: the call's wall covers
+        exactly one burst at this depth. Depth transitions (busy<->idle)
+        are excluded — the previous burst's wait divided by the new depth
+        would feed ~4x-off samples. The wall feeds BOTH the per-depth wall
+        model (_step_ms_estimate — the ttft cap's input) and the operator
+        stats gauge; transition bursts count calls but contribute no
+        time."""
+        if not steady:
+            self.kernels.record(kname, steps=n_steps)
+            return
+        wall = 1000.0 * (time.monotonic() - t0)
+        prev_w = self._burst_walls.get(n_steps)
+        self._burst_walls[n_steps] = (
+            wall if prev_w is None else 0.8 * prev_w + 0.2 * wall)
+        self._burst_wall_n += 1
+        self._burst_wall_stamp[n_steps] = self._burst_wall_n
+        ms_any = wall / n_steps
+        self._ema_step_ms_stats = (
+            ms_any if self._ema_step_ms_stats is None else
+            0.8 * self._ema_step_ms_stats + 0.2 * ms_any)
+        self.kernels.record(kname, steps=n_steps, wall_ms=wall)
 
     def _count_decode_keys(self, n_steps: int) -> None:
         """Add a burst of ``n_steps`` to the decode keys of ONE layer of
@@ -3630,8 +3690,15 @@ class InferenceEngine:
         if self._prewarm_error is not None:
             out["prewarm_error"] = self._prewarm_error
         # The scheduler's time ledger: sched_<phase>_ms_total, ten
-        # monotone counters over the loop's wall since it started.
+        # monotone counters over the loop's wall since it started, their
+        # parts, and the threads' own CPU beside the wall (obs/phases.py).
         out.update(self._sched.stats())
+        out["prefill_calls_unread_total"] = self._prefill_calls_unread
+        # Times the kernel took a core from a thread of this process that
+        # was running: beside the CPU counters, what tells a host that
+        # slowed down from a machine that ran something else.
+        out["proc_invol_ctx_switches_total"] = \
+            resource.getrusage(resource.RUSAGE_SELF).ru_nivcsw
         from ..obs.device import compile_monitor
         cm = compile_monitor().stats()
         for key in ("xla_compile_total", "xla_compile_seconds",

@@ -51,6 +51,7 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import functools
 import logging
 import math
 import threading
@@ -60,7 +61,7 @@ from typing import Any, Callable
 logger = logging.getLogger(__name__)
 
 __all__ = [
-    "HbmLedger", "KernelRegistry", "XlaCompileMonitor", "phase",
+    "HbmLedger", "KernelRegistry", "XlaCompileMonitor", "phase", "part",
     "current_phase", "install_compile_monitor", "compile_monitor",
     "device_memory_stats", "worst_kernel",
 ]
@@ -452,6 +453,15 @@ worker_call: contextvars.ContextVar[Any] = contextvars.ContextVar(
     "sched_worker_call", default=None)
 
 
+# What a worker call does with the wall that is neither its jitted call
+# nor a blocking read, as :func:`part` names it: ``state`` the upload of the
+# slots' host mirrors, ``tables`` of the page tables, ``rng`` the key split,
+# ``args`` a prefill chunk's host arrays, ``mirrors`` the host bookkeeping
+# after a dispatch or a read (snapshots, counters, the kernel registry).
+WORKER_PARTS = ("state", "tables", "rng", "args", "mirrors")
+
+
+@functools.lru_cache(maxsize=256)     # a few dozen names, asked every span
 def worker_kind(span: str) -> str | None:
     """Which of the scheduler's worker counters (obs/phases.py) a span's
     wall belongs to. ``sched.fetch.*`` is a blocking device→host read,
@@ -460,8 +470,10 @@ def worker_kind(span: str) -> str | None:
     ``fetch``), ``.burst`` the lag-one burst's tokens, ``.spec`` the
     lag-one speculative burst's, ``.sync`` the synchronous paths';
     ``prefill`` / ``decode`` / ``spec.*`` are the jitted calls with their
-    argument build; any other ``sched.*`` span entered on the worker is its
-    outermost one, where its own wall begins."""
+    argument build; ``sched.<call>.<part>`` is a named part of the call's
+    own wall (``WORKER_PARTS``: kept apart as a part of ``worker_other``);
+    any other ``sched.*`` span entered on the worker is its outermost one,
+    where its own wall begins."""
     if span == "sched.fetch.first":
         return "fetch_first"
     if span.startswith("sched.fetch"):
@@ -469,7 +481,8 @@ def worker_kind(span: str) -> str | None:
     if span in ("prefill", "decode") or span.startswith("spec."):
         return "dispatch"
     if span.startswith("sched."):
-        return "worker_other"
+        last = span.rpartition(".")[2]
+        return "worker_" + last if last in WORKER_PARTS else "worker_other"
     return None
 
 
@@ -477,6 +490,42 @@ def current_phase() -> str:
     """The phase tag of the calling thread ("" outside any phase) — what
     the compile monitor stamps as a compile event's cause."""
     return getattr(_phase_local, "name", "")
+
+
+# ``jax.profiler``, imported at the first annotation (False: no JAX here, a
+# proxy-only deployment): a span costs no import-machinery call after it.
+_profiler: Any = None
+
+
+def _annotation(name: str):
+    """An entered ``jax.profiler.TraceAnnotation``, or None where the
+    profiler is unavailable — the tag still applies."""
+    global _profiler
+    if _profiler is None:
+        try:
+            import jax.profiler
+            _profiler = jax.profiler
+        except Exception:
+            _profiler = False
+    if not _profiler:
+        return None
+    try:
+        ctx = _profiler.TraceAnnotation(name)
+        ctx.__enter__()
+        return ctx
+    except Exception:
+        return None
+
+
+def part(name: str):
+    """:func:`phase` over a named part of the enclosing worker call (one of
+    ``WORKER_PARTS``), named after the call so that an idle gap's key and a
+    compile's tag say both: ``sched.decode_burst.state``,
+    ``sched.prefill_group.args``. Under no ``sched.*`` span (a direct call
+    of a worker function's callee) it is ``sched.<name>``."""
+    parent = current_phase()
+    return phase(f"{parent}.{name}" if parent.startswith("sched.")
+                 else "sched." + name)
 
 
 @contextlib.contextmanager
@@ -493,14 +542,7 @@ def phase(name: str, annotate: bool = True):
     call = worker_call.get()
     kind = worker_kind(name) if call is not None else None
     prev_kind = call.switch(kind) if kind is not None else None
-    ctx = None
-    if annotate:
-        try:
-            import jax.profiler
-            ctx = jax.profiler.TraceAnnotation(name)
-            ctx.__enter__()
-        except Exception:       # profiler unavailable — tag still applies
-            ctx = None
+    ctx = _annotation(name) if annotate else None
     try:
         yield
     finally:

@@ -23,6 +23,25 @@ The call reaches the worker through a context variable
 function — a test, the bench — finds none and pays
 one ``ContextVar.get`` per span.
 
+A counter can have PARTS (ISSUE 41's ``fetch_first``, ISSUE 56's rest): a
+part is a kind of its own that the fold adds to its parent as well as
+keeping under its own name (``_PART_OF``), so the four worker counters
+still sum to the two waits and a part never exceeds its parent.
+``worker_other`` has five, named by ``obs.device.part`` on the worker
+(``WORKER_PARTS``); ``hop`` has two, by direction: ``hop_out`` from the
+wait's opening to the worker's first span, ``hop_back`` from the close of
+its outermost span to the fold — finished work waiting for the event loop.
+
+Beside the wall, the threads' own CPU (``time.thread_time``), each read on
+its own thread: the worker's inside ``dispatch`` and inside
+``worker_other``, at the switches that pass from one of the two to the
+other or out of both, published with the call the loop folds (closed
+segments only: the loop never reads another thread's clock), and the loop
+thread's while the ledger runs — the scheduler AND the HTTP work that
+shares the thread — at ``start``, ``stop`` and each ``stats()`` taken on
+that thread. A phase whose wall grew and whose CPU did not was not
+running.
+
 The same readings seen from the REQUEST (ISSUE 41): every closed stretch of
 the loop's wall is credited to each request that held a slot during it,
 under what the request was waiting behind (``REQ_BUCKETS``). The ledger
@@ -42,16 +61,23 @@ import threading
 import time
 from typing import Callable, Iterator, Sequence
 
-from .device import phase, worker_call
+from .device import WORKER_PARTS, phase, worker_call
 
 LOOP_PHASES = ("parked", "admit", "prefill_wait", "decode_wait", "emit",
                "other")
 WORKER_PHASES = ("hop", "dispatch", "fetch", "worker_other")
-# What a worker span can be booked under (``obs.device.worker_kind``): the
-# four counters, and the part of ``fetch`` that is prefill's first token,
-# which the loop folds into ``fetch`` AND keeps as a sub-counter.
-_WORKER_KINDS = WORKER_PHASES + ("fetch_first",)
-_KINDS = {k: i for i, k in enumerate(_WORKER_KINDS)}
+# A part's parent: the loop folds a part into its parent AND keeps it as a
+# sub-counter. ``fetch_first`` is the read of prefill's first token; the
+# hand-off by direction; the worker's remainder by what it did.
+_PART_OF = {"fetch_first": "fetch", "hop_out": "hop", "hop_back": "hop",
+            **{"worker_" + p: "worker_other" for p in WORKER_PARTS}}
+# What a worker span can be booked under (``obs.device.worker_kind``).
+_WORKER_KINDS = WORKER_PHASES + tuple(_PART_OF)
+# The worker's own CPU, by the counter the kind's wall is in; ``fetch`` is
+# a blocked thread and ``hop`` is no thread's: neither has one.
+CPU_PHASES = ("dispatch", "worker_other")
+_CPU_OF = {k: _PART_OF.get(k, k) for k in _WORKER_KINDS
+           if _PART_OF.get(k, k) in CPU_PHASES}
 # A request's life in a slot, by what it waited behind. Before its first
 # token: its own chunks' calls, other requests' chunks, decode bursts, the
 # loop's own work. After: decode bursts (its own tokens), others' chunks,
@@ -65,45 +91,67 @@ _QUEUED, _PREFILLING, _DECODING, _LEFT = range(4)
 _CLOCK_OF = {"prefill_wait": 0, "decode_wait": 1}
 
 
-def _credit(acc: tuple, kind: str, seconds: float) -> tuple:
-    """``acc`` (seconds per _WORKER_KINDS) with ``seconds`` more on
-    ``kind``, as a new tuple."""
-    i = _KINDS[kind]
-    return acc[:i] + (acc[i] + seconds,) + acc[i + 1:]
-
-
 class WorkerCall:
-    """One worker-thread call's wall, split by kind. ``live`` is
-    ``(kind, t_mark, seconds per _WORKER_KINDS)``, replaced whole at each
-    switch by the worker and read whole by the loop. The lock makes a
-    clock reading and the tuple it belongs to one step: without it a
+    """One worker-thread call's wall, split by kind: the kind that is open,
+    when it opened on the wall's clock and on the worker's CPU clock, the
+    seconds closed per kind, the CPU seconds closed per ``CPU_PHASES``.
+    The worker writes at each switch, the loop reads; the lock makes a
+    clock reading and the state it belongs to one step: without it a
     reader could book a span's first moments (the worker between its clock
-    read and its publish) under the span before, and step back at the
-    next reading. It is held for two statements, never across a call."""
+    read and its publish) under the span before, and step back at the next
+    reading. It is held for a few statements, never across a call.
 
-    __slots__ = ("_clock", "_lock", "live")
+    The call opens in ``hop_out`` (the loop made it); every switch after
+    that is the worker's, reads the worker's CPU clock where it passes from
+    one CPU counter's kinds to another's, and a switch back to the opening
+    kind is the way back, ``hop_back``."""
 
-    def __init__(self, clock: Callable[[], float], t0: float):
+    __slots__ = ("_clock", "_cpu_clock", "_lock", "_kind", "_t", "_cpu_t",
+                 "_acc", "_cpu")
+
+    def __init__(self, clock: Callable[[], float], t0: float,
+                 cpu_clock: Callable[[], float] = time.thread_time):
         self._clock = clock
+        self._cpu_clock = cpu_clock
         self._lock = threading.Lock()
-        self.live = ("hop", t0, (0.0,) * len(_KINDS))  # guarded-by: _lock
+        # guarded-by: _lock
+        self._kind, self._t = "hop_out", t0
+        self._cpu_t = 0.0       # the worker's CPU clock where a counter opened
+        self._acc = dict.fromkeys(_WORKER_KINDS, 0.0)
+        self._cpu = dict.fromkeys(CPU_PHASES, 0.0)
 
     def switch(self, kind: str) -> str:
         """Close the current kind's segment and open ``kind``'s; returns
-        the kind that was current (to restore on the way out)."""
+        the kind that was current (to restore on the way out). Worker
+        thread only."""
+        if kind == "hop_out":
+            kind = "hop_back"
         with self._lock:
-            prev, t_mark, acc = self.live
             now = self._clock()
-            self.live = (kind, now, _credit(acc, prev, now - t_mark))
+            prev = self._kind
+            self._acc[prev] += now - self._t
+            was, now_in = _CPU_OF.get(prev), _CPU_OF.get(kind)
+            if was != now_in:
+                # The CPU clock is a system call (5.5-5.8 us on the chip's
+                # host): read where the CPU's counter changes, not at a
+                # part's edges inside ``worker_other``.
+                cpu = self._cpu_clock()
+                if was is not None:
+                    self._cpu[was] += cpu - self._cpu_t
+                self._cpu_t = cpu
+            self._kind, self._t = kind, now
         return prev
 
-    def split(self) -> tuple[float, tuple[float, ...]]:
-        """``(now, seconds per _WORKER_KINDS)`` with the open segment
-        counted: they sum to ``now`` minus the wait's start."""
+    def split(self) -> tuple[float, dict[str, float], dict[str, float]]:
+        """``(now, seconds per kind, CPU seconds per CPU_PHASES)``. The
+        wall counts the open segment, so it sums to ``now`` minus the
+        wait's start; the CPU counts closed segments only (the reader is
+        another thread)."""
         with self._lock:
-            kind, t_mark, acc = self.live
             now = self._clock()
-        return now, _credit(acc, kind, now - t_mark)
+            acc, cpu = dict(self._acc), dict(self._cpu)
+            acc[self._kind] += now - self._t
+        return now, acc, cpu
 
 
 class ReqWaits:
@@ -140,12 +188,22 @@ class SchedLedger:
     ``left`` are readings of this ledger's clock taken inside the open
     segment (the engine's ``t_admitted`` and ``t_done``)."""
 
-    def __init__(self, clock: Callable[[], float] = time.monotonic):
+    def __init__(self, clock: Callable[[], float] = time.monotonic,
+                 cpu_clock: Callable[[], float] = time.thread_time):
         self._clock = clock
-        # "fetch_first" is a part of "fetch", not a phase beside it.
-        self._ms = dict.fromkeys(LOOP_PHASES + _WORKER_KINDS, 0.0)
+        self._cpu_clock = cpu_clock
+        # A part ("fetch_first", "hop_out", "worker_state") is a part of
+        # its parent, not a phase beside it; the "*_cpu" are CPU beside a
+        # wall, not walls.
+        self._ms = dict.fromkeys(
+            LOOP_PHASES + _WORKER_KINDS + ("loop_cpu",)
+            + tuple(k + "_cpu" for k in CPU_PHASES), 0.0)
         self._cur = "other"
         self._t: float | None = None        # None = the loop is not running
+        # The loop's thread, and its CPU clock where ``loop_cpu`` was last
+        # brought up to date.
+        self._tid: int | None = None
+        self._cpu_t = 0.0
         self._call: WorkerCall | None = None
         # The wall of the wait that closed last, for the flight STEP record:
         # the reading the counters and the requests were credited with.
@@ -162,21 +220,36 @@ class SchedLedger:
         if self._t is None:
             self._t, self._cur = self._clock(), "other"
             self._t_req = self._t
+            self._tid, self._cpu_t = threading.get_ident(), self._cpu_clock()
 
     def stop(self) -> None:
         if self._t is not None:
             self._advance(self._clock(), "other")
+            self._read_loop_cpu()
             self._t = None
+
+    def _read_loop_cpu(self) -> None:
+        """Bring ``loop_cpu`` up to now, if the caller is the loop's thread
+        (a thread reads no clock but its own). Not at the boundaries: the
+        CPU clock is a system call, and a step has ten."""
+        if self._t is not None and threading.get_ident() == self._tid:
+            cpu = self._cpu_clock()
+            self._ms["loop_cpu"] += 1e3 * (cpu - self._cpu_t)
+            self._cpu_t = cpu
 
     @staticmethod
     def _fold(ms: dict[str, float], call: WorkerCall) -> float:
         """Add ``call``'s split, up to now, to the worker counters of
-        ``ms``, the first-token fetch under ``fetch`` as well as under its
-        own name; returns that now."""
-        now, acc = call.split()
-        for k, s in zip(_WORKER_KINDS, acc):
-            ms[k] += 1e3 * s
-        ms["fetch"] += 1e3 * acc[-1]
+        ``ms``, a part under its parent as well as under its own name;
+        returns that now."""
+        now, acc, cpu = call.split()
+        for k, s in acc.items():
+            if s:
+                ms[k] += 1e3 * s
+                if k in _PART_OF:
+                    ms[_PART_OF[k]] += 1e3 * s
+        for k, s in cpu.items():
+            ms[k + "_cpu"] += 1e3 * s
         return now
 
     def _advance(self, now: float, phase_: str) -> str:
@@ -232,7 +305,7 @@ class SchedLedger:
             return
         self._advance(self._clock(), name)
         t0 = self._t
-        call = self._call = WorkerCall(self._clock, t0)
+        call = self._call = WorkerCall(self._clock, t0, self._cpu_clock)
         token = worker_call.set(call)
         for req in own:
             self._n_own += req.waits.life == _PREFILLING
@@ -333,7 +406,9 @@ class SchedLedger:
     def stats(self) -> dict[str, float]:
         """Flat monotone counters, milliseconds, the open segment (and the
         call in flight) counted up to now; the requests' totals up to the
-        last boundary, where they were credited."""
+        last boundary, where they were credited; the loop's CPU up to now
+        when the reader is the loop's thread, else up to its last reading."""
+        self._read_loop_cpu()
         ms = dict(self._ms)
         if self._t is not None:
             now = (self._fold(ms, self._call) if self._call is not None

@@ -1,4 +1,6 @@
 """Edge cases in the remote provider's streaming path (code-review findings)."""
+import asyncio
+import gc
 import json
 
 from aiohttp import web
@@ -25,6 +27,17 @@ async def _collect(provider, payload):
             p = SSEParser()
             frames.extend(f.data for f in p.feed(chunk))
     return frames, error, obs
+
+
+async def _settle():
+    """A stream closed before its end leaves httpx's inner byte-stream
+    generators to the collector, whose finalizer closes each as a task on
+    this loop. Let them run inside the test that made them: when this
+    file's last (synchronous) test ends a worker's session nothing turns
+    the loop again, and the sanitizer reports the tasks as leaked."""
+    gc.collect()
+    for _ in range(3):
+        await asyncio.sleep(0)
 
 
 async def test_tiny_response_data_and_done_in_one_chunk(tmp_path):
@@ -56,6 +69,7 @@ async def test_tiny_response_data_and_done_in_one_chunk(tmp_path):
         await provider.close()
     finally:
         await server.close()
+        await _settle()
 
 
 async def test_done_with_no_data_is_error(tmp_path):
@@ -79,6 +93,7 @@ async def test_done_with_no_data_is_error(tmp_path):
         await provider.close()
     finally:
         await server.close()
+        await _settle()
 
 
 def test_format_sse_multiline_spec_compliant():

@@ -38,6 +38,12 @@ KEYS = [f"sched_{k}_ms_total" for k in LOOP_PHASES + WORKER_PHASES]
 REQ_KEYS = (["sched_fetch_first_ms_total", "req_first_tokens_total",
              "req_decode_tokens_total"]
             + [f"req_{k}_ms_total" for k in REQ_BUCKETS])
+# ISSUE 56 (tests/test_sched_parts.py): the parts of ``worker_other`` and
+# of ``hop``, and the threads' CPU beside their wall.
+PART_KEYS = ([f"sched_worker_{p}_ms_total" for p in dev.WORKER_PARTS]
+             + [f"sched_{k}_ms_total" for k in (
+                 "hop_out", "hop_back", "loop_cpu", "dispatch_cpu",
+                 "worker_other_cpu")])
 
 
 class FakeClock:
@@ -64,7 +70,7 @@ def _sums(stats: dict) -> tuple[float, float, float]:
 def test_loop_counters_partition_the_wall_exactly():
     clk = FakeClock()
     led = SchedLedger(clock=clk)
-    assert set(led.stats()) == set(KEYS) | set(REQ_KEYS)
+    assert set(led.stats()) == set(KEYS) | set(REQ_KEYS) | set(PART_KEYS)
     assert all(v == 0.0 for v in led.stats().values())
     led.start()
     clk.tick(3)                              # other
@@ -399,7 +405,7 @@ async def test_spans_thread_order_and_none_across_an_await(engine,
                        "sched.fetch.sync"}
     assert "sched.parked" not in names and "sched.hop" not in names
     for kind, name, tid in rec.events:
-        if name in ("sched.admit", "sched.emit"):
+        if name in ("sched.admit", "sched.emit", "sched.plan"):
             assert tid == loop_tid, name
         else:
             assert tid != loop_tid, name
