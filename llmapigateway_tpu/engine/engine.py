@@ -448,6 +448,11 @@ class InferenceEngine:
         # call's over its rows and positions. Monotone; worker thread.
         self._mla_decode_keys = 0
         self._mla_prefill_keys = 0
+        # ... and the steps a prefill call's kernel programs walked, with
+        # those among them whose pages were all whole (ISSUE 57: attended
+        # as one straight line), per layer, counted the same way.
+        self._mla_prefill_steps = 0
+        self._mla_prefill_steps_whole = 0
         # The state blocks the decode steps rewrote: active rows x linear
         # layers a step (ISSUE 46), counted the same way.
         self._lin_decode_state_updates = 0
@@ -2549,6 +2554,12 @@ class InferenceEngine:
             # Keys a layer's call attends: row b's query t sees pos + t + 1.
             self._mla_prefill_keys += sum(
                 bucket * int(p) + bucket * (bucket + 1) // 2 for p in poss)
+            from ..ops.latent_attention import latent_steps_walked
+            steps, whole = latent_steps_walked(
+                poss, bucket, bt, page,
+                self.kv_groups.whole_context.allocator.pages_per_slot)
+            self._mla_prefill_steps += steps
+            self._mla_prefill_steps_whole += whole
         walked = 0
         for g in self.kv_groups:
             if not g.chunk_readers:
@@ -3625,6 +3636,9 @@ class InferenceEngine:
         if self.model_cfg.is_mla:
             out["mla_decode_keys_total"] = self._mla_decode_keys
             out["mla_prefill_keys_total"] = self._mla_prefill_keys
+            out["mla_prefill_steps_total"] = self._mla_prefill_steps
+            out["mla_prefill_steps_whole_total"] = \
+                self._mla_prefill_steps_whole
         gauge = (self._ema_step_ms_stats
                  if self._ema_step_ms_stats is not None
                  else self._step_ms_estimate())

@@ -48,11 +48,44 @@ _DEFAULT_VMEM_BYTES = 16 * 2 ** 20
 _INSERT_VMEM_ROOM = 4 * 2 ** 20
 # Query rows (positions x heads) a program of the attention kernel holds,
 # the pages it copies a step (all in flight together, into one of two
-# buffers), and what it may take of VMEM: q and out blocks twice over, the
-# score tile three times in float32, the accumulator, m and l.
+# buffers), and what it may take of VMEM.
+#
+# The online-softmax UPDATE (running max, ``alpha``, ``exp``, row sum, the
+# accumulator rescaled) is made once over a SPAN of pages, not once a
+# page: its lane reductions, its ``[rows, 1]`` state and the accumulator's
+# read-multiply-write cost by the ROWS, so an update over 1,024 keys pays
+# a quarter a key of what four updates over 256 do, and one basic block
+# holds the span's score products beside the vector work. A step whose
+# ``ppb`` pages are all live and wholly below the row-block's diagonal is
+# attended span by span without a branch between its pages; the step that
+# holds the diagonal or a dead page (a program's last) keeps one update a
+# page, the diagonal's masked.
+#
+# The span is the most pages of a step, a power of two, whose update
+# fits (:func:`update_span`): the span's score tiles ``[rows, page]`` side
+# by side, twice in float32 (scores, exponentials) and once in the pool's
+# dtype (the probabilities the value dot takes), beside what a program
+# holds anyway — the q and out blocks twice over (the pipeline's two
+# buffers), the float32 accumulator ``[rows, value_width]``, m and l (a
+# lane tile a row each), the two page buffers. At 2,048 rows, 256-key
+# pages, 4 pages a step: 20 MiB of tiles + 10 MiB at 32 heads x 320 / 256,
+# + 17 MiB at 64 heads x 576 / 512, of the 48 the call asks for — the
+# whole step, 1,024 keys an update, at both.
+#
+# The kernel's CODE is as scarce as its memory: at 2,048 rows one update
+# is thousands of instructions (every tile is unrolled), and a program
+# whose bodies together outgrow the core's instruction memory runs ALL of
+# them slower — the parent's eight copies of a page's update (four pages
+# x masked or not) beside the whole step's block read 2.55 ms where the
+# eight alone read 1.54 and the block with the page's two bodies in a LOOP
+# reads 1.06 (v5e, one 512-token row at 8k, PERF.md PR 57). Hence the edge
+# step's ``fori_loop``; a new body here is measured, not assumed free.
 _BLOCK_ROWS = 2048
 _PAGES_PER_STEP = 4
 _VMEM_LIMIT_BYTES = 48 * 2 ** 20
+# Left to the compiler of the limit: its own temporaries (the value
+# operand's transpose, the mask's iotas on the edge step).
+_VMEM_ROOM_BYTES = 6 * 2 ** 20
 
 
 def create_latent_pool(n_layers: int, num_pages: int, page_size: int,
@@ -211,10 +244,26 @@ def latent_insert_in_place(pool: jax.Array, new: jax.Array,
 # The attention kernel (absorbed form)
 # ---------------------------------------------------------------------------
 
+def _softmax_update(scores, m, l):
+    """One online-softmax update of the float32 score tiles ``scores``
+    (each [rows, page], side by side) under the running max ``m`` and sum
+    ``l`` [rows, 1]: the tiles' exponentials, the factor that rescales
+    what was accumulated under ``m``, the new ``m`` and ``l``. The tiles
+    are combined element-wise first: ONE lane reduction a row for the max
+    and one for the sum, however many tiles."""
+    m_new = jnp.maximum(m, jnp.max(functools.reduce(jnp.maximum, scores),
+                                   axis=1, keepdims=True))
+    alpha = jnp.exp(m - m_new)
+    e = [jnp.exp(s - m_new) for s in scores]
+    return e, alpha, m_new, alpha * l + jnp.sum(
+        functools.reduce(jnp.add, e), axis=1, keepdims=True)
+
+
 def _latent_attention_kernel(pt_ref, start_ref, layer_ref, q_ref, pool_ref,
                              o_ref, buf, m_ref, l_ref, acc_ref, sem, *,
                              block_t: int, heads: int, page: int, ppb: int,
-                             value_width: int, n_table_pages: int):
+                             span: int, value_width: int,
+                             n_table_pages: int):
     """Program ``(row b, row-block t)``: ``block_t`` query positions of all
     ``heads`` heads (row ``i * heads + h`` is head ``h`` at position
     ``first_q + i``) walk the pages up to the last query's own key, and
@@ -222,8 +271,10 @@ def _latent_attention_kernel(pt_ref, start_ref, layer_ref, q_ref, pool_ref,
     one of two VMEM buffers while the step before is attended. A page is
     ``[W, page]``: the score dot reads it as it lies and the value dot
     contracts its lanes with the probabilities' — the page's first
-    ``value_width`` rows are the value. Only a page on the row-block's
-    diagonal is masked."""
+    ``value_width`` rows are the value. A step of whole pages is ``ppb //
+    span`` updates in one straight line; the step on the row-block's
+    diagonal is a loop of one update a page, and only the diagonal's page
+    is masked."""
     b, t = pl.program_id(0), pl.program_id(1)
     bt = block_t
     layer = layer_ref[0]
@@ -248,24 +299,26 @@ def _latent_attention_kernel(pt_ref, start_ref, layer_ref, q_ref, pool_ref,
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def attend(slot, sub, lo, masked: bool):
+    def attend(slot, subs, lo=None):
+        """One update over the step's pages ``subs``; ``lo``: the first
+        key's position where the (one) page is masked."""
         q = q_ref[0, 0]                                     # [rows, W]
-        keys = buf[slot, sub]                               # [W, page]
-        scores = jnp.dot(q, keys, preferred_element_type=jnp.float32)
-        if masked:
-            row = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
+        keys = [buf[slot, sub] for sub in subs]             # [W, page] each
+        scores = [jnp.dot(q, k, preferred_element_type=jnp.float32)
+                  for k in keys]
+        if lo is not None:
+            row = jax.lax.broadcasted_iota(jnp.int32, scores[0].shape, 0)
             q_pos = first_q + (row // heads)
-            s_pos = lo + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-            scores = jnp.where(s_pos <= q_pos, scores, NEG_INF)
-        m = m_ref[...]
-        m_new = jnp.maximum(m, jnp.max(scores, axis=1, keepdims=True))
-        alpha = jnp.exp(m - m_new)
-        e = jnp.exp(scores - m_new)
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(e, axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            e.astype(keys.dtype), keys[:value_width],
-            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+            s_pos = lo + jax.lax.broadcasted_iota(jnp.int32,
+                                                  scores[0].shape, 1)
+            scores = [jnp.where(s_pos <= q_pos, scores[0], NEG_INF)]
+        e, alpha, m_ref[...], l_ref[...] = _softmax_update(
+            scores, m_ref[...], l_ref[...])
+        acc_ref[...] = acc_ref[...] * alpha + functools.reduce(jnp.add, [
+            jax.lax.dot_general(
+                p.astype(k.dtype), k[:value_width], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            for p, k in zip(e, keys)])
 
     def step(i, carry):
         slot = i % 2
@@ -273,30 +326,87 @@ def _latent_attention_kernel(pt_ref, start_ref, layer_ref, q_ref, pool_ref,
         @pl.when(i + 1 < n_steps)
         def _prefetch():
             start(i + 1, 1 - slot)
-        for sub in range(ppb):
+        # Every page live and below the diagonal of EVERY row.
+        whole_step = step_is_whole(i, first_q, n_live, page, ppb)
+
+        @pl.when(whole_step)
+        def _whole_step():
+            for sub in range(ppb):
+                copy(i, sub, slot).wait()
+            for sub in range(0, ppb, span):
+                attend(slot, range(sub, sub + span))
+
+        def edge_page(sub, carry):
             lp = i * ppb + sub
             lo = lp * page
             live = lp < n_live
-            # Below the diagonal of EVERY row: no mask is built.
-            whole = lo + (page - 1) <= first_q
+            whole = lo + (page - 1) <= first_q      # no mask is built
 
             @pl.when(live)
-            def _wait(sub=sub):
+            def _wait():
                 copy(i, sub, slot).wait()
 
             @pl.when(live & whole)
-            def _whole(sub=sub, lo=lo):
-                attend(slot, sub, lo, False)
+            def _whole():
+                attend(slot, [sub])
 
             @pl.when(live & jnp.logical_not(whole))
-            def _edge(sub=sub, lo=lo):
-                attend(slot, sub, lo, True)
+            def _edge():
+                attend(slot, [sub], lo)
+            return carry
+
+        # A LOOP over the step's pages, not ``ppb`` copies of its two
+        # bodies: the kernel's code is scarce (header).
+        @pl.when(jnp.logical_not(whole_step))
+        def _edge_step():
+            jax.lax.fori_loop(0, ppb, edge_page, 0)
         return carry
 
     jax.lax.fori_loop(0, n_steps, step, 0)
     l = l_ref[...]
     o_ref[0, 0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
                    ).astype(o_ref.dtype)
+
+
+def step_is_whole(i, first_q, n_live, page: int, ppb: int):
+    """Whether step ``i`` of a program whose first query stands at
+    ``first_q`` and that walks ``n_live`` pages holds ``ppb`` live pages
+    that all lie wholly at or below ``first_q``: the kernel's branch
+    (traced scalars) and the host's count (integers) alike."""
+    return ((i + 1) * ppb <= n_live) & ((i + 1) * ppb * page - 1 <= first_q)
+
+
+def latent_steps_walked(starts, T: int, bt: int, page: int,
+                        n_table_pages: int,
+                        ppb: int = _PAGES_PER_STEP) -> tuple[int, int]:
+    """(steps, whole steps) of ONE layer's call over rows that start at
+    ``starts`` with ``T`` new tokens each, row-blocks of ``bt`` positions:
+    the steps every program of :func:`latent_paged_attention` walks and
+    those of them it attends as one straight line (``step_is_whole``) —
+    the kernel's own arithmetic on the host, in closed form."""
+    steps = whole = 0
+    for s in starts:
+        for first_q in range(int(s), int(s) + T, bt):
+            n_live = min((first_q + bt - 1) // page + 1, n_table_pages)
+            steps += -(-n_live // ppb)
+            whole += min(n_live // ppb, (first_q + 1) // (ppb * page))
+    return steps, whole
+
+
+def update_span(rows: int, ppb: int, page: int, width: int,
+                value_width: int, itemsize: int) -> int:
+    """Pages one softmax update of a whole step spans: the largest power
+    of two dividing ``ppb`` whose score tiles fit ``_VMEM_LIMIT_BYTES``
+    beside what a program holds anyway (the header's account)."""
+    held = (2 * rows * (width + value_width) * itemsize    # q, out: twice
+            + rows * value_width * 4                       # accumulator
+            + 2 * rows * 128 * 4                           # m, l
+            + 2 * ppb * width * page * itemsize)           # page buffers
+    span = ppb & -ppb
+    while span > 1 and held + rows * span * page * (8 + itemsize) \
+            > _VMEM_LIMIT_BYTES - _VMEM_ROOM_BYTES:
+        span //= 2
+    return span
 
 
 def latent_block_t(T: int, heads: int) -> int:
@@ -327,7 +437,8 @@ def latent_paged_attention(q: jax.Array, pool: jax.Array,
     One Pallas call, grid ``(K, T // bt)``: a program's ``bt x H`` query
     rows meet each page in one bfloat16 dot with float32 accumulation, the
     probabilities are rounded to the pool's dtype for the value dot (as the
-    pool itself is), and the online-softmax state is float32. The call's
+    pool itself is), and the online-softmax state is float32, updated once
+    over the pages of a whole step (:func:`update_span`). The call's
     time is the visible (query, key) pairs' arithmetic where rows are many
     (a chunk) and the live pages' bytes where they are few (a decode
     step); nothing is paid per table entry or dead page."""
@@ -341,14 +452,15 @@ def latent_paged_attention(q: jax.Array, pool: jax.Array,
     if T % bt:
         raise ValueError(f"T={T} not a multiple of block_t={bt}")
     nT, rows, ppb = T // bt, bt * H, pages_per_step
+    span = update_span(rows, ppb, page, W, value_width, pool.dtype.itemsize)
     q_spec = pl.BlockSpec((1, 1, rows, W),
                           lambda b, t, pt, st, layer: (b, t, 0, 0))
     o_spec = pl.BlockSpec((1, 1, rows, value_width),
                           lambda b, t, pt, st, layer: (b, t, 0, 0))
     out = pl.pallas_call(
         functools.partial(_latent_attention_kernel, block_t=bt, heads=H,
-                          page=page, ppb=ppb, value_width=value_width,
-                          n_table_pages=NP),
+                          page=page, ppb=ppb, span=span,
+                          value_width=value_width, n_table_pages=NP),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(K, nT),
             in_specs=[q_spec, pl.BlockSpec(memory_space=pl.ANY)],

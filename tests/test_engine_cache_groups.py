@@ -425,35 +425,65 @@ def test_served_past_the_original_context_on_an_idle_engine(latent):
     latent.kv_groups.check_invariants()
 
 
-def test_the_latent_pool_rides_the_in_place_path_of_both_programs(
-        stop_engine):
-    """With the Pallas kernels the pool is donated, carried through the
-    layer scan of ``prefill_step`` AND of the decode programs, written by
-    an aliased custom call under ``kv.latent_insert`` and attended under
-    ``attention.latent``, all inside ``attn.mla``."""
+@pytest.fixture(scope="module")
+def latent_kernels(stop_engine):
+    """The latent engine with the Pallas kernels (interpreted here)."""
     eng = InferenceEngine(
         LocalEngineConfig(**{**LATENT, "attention": "pallas",
                              "max_batch_size": 2}),
         devices=[jax.devices("cpu")[0]])
-    try:
-        assert eng.stats()["kv_pool_in_place"] is True
-        state, key = eng._state_avals()
-        import jax.numpy as jnp
+    yield eng
+    stop_engine(eng)
 
-        def row(dtype, *shape):
-            return jax.ShapeDtypeStruct((1, *shape), dtype)
-        lowered = eng._prefill_fn.lower(
-            *state, row(jnp.int32, 16), row(jnp.int32), row(jnp.int32),
-            row(jnp.int32), row(jnp.float32), row(jnp.float32),
-            row(jnp.int32), row(jnp.float32), row(jnp.float32), key)
-        # The pool, the penalty counts and the counters come back in place.
-        assert lowered.as_text().count("tf.aliasing_output") >= 3
-        text = lowered.as_text(debug_info=True)
-        for name in ("attn.mla", "kv.latent_insert", "attention.latent",
-                     "moe.experts", "moe.shared"):
-            assert name in text
-    finally:
-        stop_engine(eng)
+
+def test_the_latent_pool_rides_the_in_place_path_of_both_programs(
+        latent_kernels):
+    """With the Pallas kernels the pool is donated, carried through the
+    layer scan of ``prefill_step`` AND of the decode programs, written by
+    an aliased custom call under ``kv.latent_insert`` and attended under
+    ``attention.latent``, all inside ``attn.mla``."""
+    eng = latent_kernels
+    assert eng.stats()["kv_pool_in_place"] is True
+    state, key = eng._state_avals()
+    import jax.numpy as jnp
+
+    def row(dtype, *shape):
+        return jax.ShapeDtypeStruct((1, *shape), dtype)
+    lowered = eng._prefill_fn.lower(
+        *state, row(jnp.int32, 16), row(jnp.int32), row(jnp.int32),
+        row(jnp.int32), row(jnp.float32), row(jnp.float32),
+        row(jnp.int32), row(jnp.float32), row(jnp.float32), key)
+    # The pool, the penalty counts and the counters come back in place.
+    assert lowered.as_text().count("tf.aliasing_output") >= 3
+    text = lowered.as_text(debug_info=True)
+    for name in ("attn.mla", "kv.latent_insert", "attention.latent",
+                 "moe.experts", "moe.shared"):
+        assert name in text
+
+
+async def test_the_steps_a_prefill_walks_are_counted_whole_and_all(
+        latent_kernels, engine):
+    """``mla_prefill_steps_total`` / ``_whole_total``: per layer, what the
+    attention kernel's programs walk over a prompt's chunks and the steps
+    among them attended in one straight line, by the kernel's own rule
+    (``latent_steps_walked``: pages of 8, 4 a step, chunks of 16); a model
+    without a latent pool counts neither."""
+    from llmapigateway_tpu.ops.latent_attention import latent_steps_walked
+    eng = latent_kernels
+    before = eng.stats()
+    assert (before["mla_prefill_steps_total"],
+            before["mla_prefill_steps_whole_total"]) == (0, 0)
+    req = await generate(eng, prompt(70, 5), 2)
+    assert len(req.generated) == 2
+    after = eng.stats()
+    chunks = latent_steps_walked(range(0, 70, 16), 16, 16, 8, WHOLE)
+    assert chunks == (1 + 1 + 2 + 2 + 3, 0 + 0 + 1 + 1 + 2)
+    assert (after["mla_prefill_steps_total"],
+            after["mla_prefill_steps_whole_total"]) == chunks
+    await generate(engine, prompt(40, 6), 2)
+    plain = engine.stats()
+    assert plain.get("mla_prefill_steps_total", 0) == 0
+    assert plain.get("mla_prefill_steps_whole_total", 0) == 0
 
 
 @pytest.mark.parametrize("change, says", [

@@ -21,6 +21,12 @@ that hold that call (``tiny-smallthinker-test``, ``tiny-cohere2-test``,
 ``prefill`` digests of the two latent families, which run no paged GQA
 prefill, stand unedited: nothing else moved.
 
+PR 57 changed the absorbed latent kernel's body (``ops/latent_attention.py``:
+a step of whole pages is ONE softmax update): the three pinned programs of
+the two latent families are recorded anew, their digests at commit 59c3c7f
+(the parent of PR 57) went to ``MOVED``, and the five programs that never
+import the module stand unedited.
+
 A PR that changes a pinned program on purpose records it anew:
 ``JAX_PLATFORMS=cpu python tests/test_hybrid_programs_pinned.py`` prints
 the table of the tree it runs in."""
@@ -35,12 +41,12 @@ import pytest
 PINNED = {
     ("tiny-smallthinker-test", "decode"): "1ef682edbae673474e9f2429",
     ("tiny-smallthinker-test", "prefill"): "c346e1e2aa1d52741cbb0807",
-    ("tiny-mistral4-test", "decode"): "5c81fbba224cd6790ca3cb9a",
-    ("tiny-mistral4-test", "prefill"): "9e419a710b190f05078de7a8",
+    ("tiny-mistral4-test", "decode"): "104c752fd8fe7c6e161aa1f1",
+    ("tiny-mistral4-test", "prefill"): "4a9667b28c6b0c4afea09014",
     ("tiny-cohere2-test", "decode"): "fa375c9237a876ec6e0570bd",
     ("tiny-cohere2-test", "prefill"): "79bc67a8af51d7c71214e630",
     ("tiny-hybrid-test", "prefill"): "564c6e03e7fc0a3cf1002e9c",
-    ("tiny-gigachat35-test", "prefill"): "6bcce1638edad7771fcfb324",
+    ("tiny-gigachat35-test", "prefill"): "1c1bacf99ffa708ea45459c5",
 }
 MOVED = {
     ("tiny-hybrid-test", "decode"): "42da75fd2c69ad5db6218ae7",
@@ -49,6 +55,10 @@ MOVED = {
     ("tiny-smallthinker-test", "prefill"): "74d47e27d3de71f868c4c95c",
     ("tiny-cohere2-test", "prefill"): "22d44b265802966e5ba486a8",
     ("tiny-hybrid-test", "prefill"): "a72e4d6d2f50468d59d25459",
+    # PR 57: the parent's programs around the absorbed latent kernel.
+    ("tiny-mistral4-test", "decode"): "5c81fbba224cd6790ca3cb9a",
+    ("tiny-mistral4-test", "prefill"): "9e419a710b190f05078de7a8",
+    ("tiny-gigachat35-test", "prefill"): "6bcce1638edad7771fcfb324",
 }
 
 
@@ -76,8 +86,9 @@ def test_a_program_no_pr_meant_to_move_lowers_as_pinned(preset, program):
 @pytest.mark.parametrize("preset, program", list(MOVED),
                          ids=["-".join(k) for k in MOVED])
 def test_a_rebuilt_program_is_not_its_parents(preset, program):
-    """The digest sees a change: the two decode programs PR 47 rebuilt and
-    the three prefill programs PR 48 did differ from their parents'."""
+    """The digest sees a change: the two decode programs PR 47 rebuilt,
+    the three prefill programs PR 48 did and the three latent programs PR
+    57 did differ from their parents'."""
     assert lowered_digest(preset, program) != MOVED[preset, program]
 
 

@@ -269,20 +269,50 @@ def test_the_in_place_write_is_the_scatter(T, starts, masked):
             np.asarray(pool[1][table[1]], np.float32))
 
 
-@pytest.mark.parametrize("T, block_t, ppb", [(1, None, 4), (16, 4, 2),
-                                             (16, 16, 4), (24, 8, 1)])
-def test_the_attention_kernel_is_the_plain_softmax(T, block_t, ppb):
-    """Decode (one token a row) and chunks, every row-block and copy
-    shape: bfloat16 pool and queries against float32 ``jax.numpy`` over
-    the gathered rows; what is left is the probabilities' rounding to
-    bfloat16 for the value product (2^-9 relative on values of size ~1)."""
-    rng = np.random.default_rng(T + ppb)
-    K, NP, H, W, wv = 2, 6, 4, 40, 32
-    pool = jnp.asarray(rng.normal(size=(2, K * NP + 1, W, 16)), jnp.bfloat16)
+def _kernel_case(T, starts, geometry, seed, dtype=jnp.bfloat16):
+    """Two rows of ``T`` queries at ``starts`` over 6 pages of 16 keys a
+    row: (q, pool, table, start, value width)."""
+    rng = np.random.default_rng(seed)
+    K, NP, (H, W, wv) = 2, 6, geometry
+    pool = jnp.asarray(rng.normal(size=(2, K * NP + 1, W, 16)), dtype)
     table = jnp.asarray(rng.permutation(np.arange(1, K * NP + 1)).reshape(
         K, NP).astype(np.int32))
-    start = jnp.asarray([5, 96 - T], jnp.int32)
-    q = jnp.asarray(rng.normal(size=(K, T, H, W)) * W ** -0.5, jnp.bfloat16)
+    q = jnp.asarray(rng.normal(size=(K, T, H, W)) * W ** -0.5, dtype)
+    return q, pool, table, jnp.asarray(starts, jnp.int32), wv
+
+
+# (T, block_t, pages a step, starts, (heads, W, value), (steps, whole
+# steps), the draw: a row of few keys can put an output past 2, where ONE
+# bfloat16 ulp of the result is over the tolerance)
+@pytest.mark.parametrize("T, block_t, ppb, starts, geometry, steps, seed", [
+    (1, None, 4, [5, 95], (4, 40, 32), (3, 1), 5),
+    (16, 4, 2, [5, 80], (4, 40, 32), (16, 8), 18),
+    (16, 16, 4, [5, 80], (4, 40, 32), (3, 1), 20),
+    (24, 8, 1, [5, 72], (4, 40, 32), (22, 15), 25),
+    # Every step whole: a token on a step's last key.
+    (1, None, 2, [31, 63], (4, 40, 32), (3, 3), 3),
+    # The last step: the diagonal in its FIRST page, three dead after it.
+    (8, 8, 4, [64, 66], (4, 40, 32), (4, 2), 12),
+    # Live pages no multiple of a step's: a whole page, the diagonal, two dead.
+    (8, 8, 4, [85, 80], (4, 40, 32), (4, 2), 12),
+    # A single edge step a program, nothing merged.
+    (16, 16, 4, [0, 0], (4, 40, 32), (2, 0), 21),
+    # gigachat35-reason's decode row: 64 heads over 576 / 512.
+    (1, None, 4, [5, 95], (64, 576, 512), (3, 1), 24),
+], ids=["decode", "blocks-of-4", "chunk", "page-a-step", "all-whole",
+        "diagonal-first", "ragged-live", "start-0", "decode-576x64"])
+def test_the_attention_kernel_is_the_plain_softmax(T, block_t, ppb, starts,
+                                                   geometry, steps, seed):
+    """Decode (one token a row) and chunks, every row-block and copy
+    shape, steps attended whole and page by page (``steps`` says how many
+    of each the case walks): bfloat16 pool and queries against float32
+    ``jax.numpy`` over the gathered rows; what is left is the
+    probabilities' rounding to bfloat16 for the value product (2^-9
+    relative on values of size ~1)."""
+    q, pool, table, start, wv = _kernel_case(T, starts, geometry, seed)
+    assert la.latent_steps_walked(
+        starts, T, block_t or la.latent_block_t(T, geometry[0]), 16, 6,
+        ppb) == steps
     got = la.latent_paged_attention(
         q, pool, table, start, value_width=wv, layer=jnp.int32(1),
         block_t=block_t, pages_per_step=ppb, interpret=True)
@@ -290,6 +320,66 @@ def test_the_attention_kernel_is_the_plain_softmax(T, block_t, ppb):
         q, la.gather_latent(pool, table, 96, layer=1), start, wv)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), atol=1e-2)
+
+
+def test_a_step_attended_whole_is_the_softmax_of_its_pages(monkeypatch):
+    """The same call with its whole steps attended in one update a step,
+    in one a page, and page by page under the edge step's branches: one
+    softmax, rescaled at other places. In float32 (pool, queries and so
+    the probabilities) the three agree to rounding."""
+    q, pool, table, start, wv = _kernel_case(8, [85, 64], (4, 40, 32), 7,
+                                             jnp.float32)
+
+    def run():
+        return np.asarray(la.latent_paged_attention(
+            q, pool, table, start, value_width=wv, layer=jnp.int32(1),
+            block_t=8, pages_per_step=4, interpret=True))
+    assert la.update_span(32, 4, 16, 40, wv, 4) == 4
+    merged = run()
+    monkeypatch.setattr(la, "update_span", lambda *shapes: 1)
+    by_page = run()
+    monkeypatch.setattr(la, "step_is_whole", lambda i, first_q, *_: first_q < 0)
+    by_branch = run()
+    assert np.abs(merged).max() > 0.1
+    np.testing.assert_allclose(merged, by_page, atol=2e-6, rtol=0)
+    np.testing.assert_allclose(merged, by_branch, atol=2e-6, rtol=0)
+    want = la.latent_attention_reference(
+        q, la.gather_latent(pool, table, 96, layer=1), start, wv)
+    np.testing.assert_allclose(merged, np.asarray(want), atol=1e-5, rtol=0)
+
+
+@pytest.mark.parametrize("page, ppb", [(8, 1), (8, 4), (16, 2), (256, 4)])
+def test_the_hosts_step_count_is_the_kernels_walk(page, ppb):
+    """``latent_steps_walked`` in closed form against a loop over the
+    kernel's own arithmetic (``n_live``, ``n_steps``, ``step_is_whole``)."""
+    table = 24
+    for T, bt in ((1, 1), (16, 4), (16, 16), (64, 32), (512, 64)):
+        for start in (0, 1, page - 1, page, 3 * page + 5, ppb * page - 1,
+                      ppb * page, 2 * ppb * page + page - T % page,
+                      max(0, table * page - T), table * page + 40):
+            steps = whole = 0
+            for first_q in range(start, start + T, bt):
+                n_live = min((first_q + bt - 1) // page + 1, table)
+                n_steps = (n_live + ppb - 1) // ppb
+                steps += n_steps
+                whole += sum(bool(la.step_is_whole(i, first_q, n_live, page,
+                                                   ppb))
+                             for i in range(n_steps))
+            assert la.latent_steps_walked([start], T, bt, page, table,
+                                          ppb) == (steps, whole)
+    assert la.latent_steps_walked([0, 7680], 512, 64, 256, 128) == (
+        8 + 64, 0 + 8 * 7)
+
+
+@pytest.mark.parametrize("rows, width, value, span", [
+    (2048, 320, 256, 4),        # mistral-small4-longctx: a chunk
+    (2048, 576, 512, 4),        # gigachat35-reason: a chunk
+    (32, 320, 256, 4), (64, 576, 512, 4),       # their decode rows
+    (4096, 320, 256, 2),        # twice the rows: half the keys
+    (8192, 576, 512, 1)])
+def test_an_update_spans_what_fits_beside_the_programs_blocks(rows, width,
+                                                              value, span):
+    assert la.update_span(rows, 4, 256, width, value, 2) == span
 
 
 def test_the_four_shares_and_the_shared_expert_once_are_the_uncut_layer(
